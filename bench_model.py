@@ -12,6 +12,8 @@ FLOP accounting (standard: Chowdhery et al. PaLM appendix B):
 
 Prints ONE JSON line:
   {"metric": "transformer_train_step_mfu", "value": <mfu %>, ...}
+MFU is a device metric: a device whose kind is not in ``_PEAK_TFLOPS``
+(the CPU included) is an error, not a default peak.
 """
 
 import json
@@ -19,7 +21,9 @@ import sys
 import time
 
 
-# Peak dense bf16 FLOP/s per CHIP by device kind (public spec sheets).
+# Peak dense bf16 FLOP/s per CHIP, keyed by the exact ``device_kind``
+# the runtime reports (public spec sheets; "TPU v5 lite" is what the
+# installed libtpu calls a v5e: 197 TFLOP/s, Google Cloud "TPU v5e").
 _PEAK_TFLOPS = {
     "TPU v2": 45.0,
     "TPU v3": 123.0,
@@ -32,15 +36,23 @@ _PEAK_TFLOPS = {
     "TPU v6 lite": 918.0,
 }
 
+#: The single-chip model configuration (chip_smoke.py trains the same
+#: one through ray_tpu.train.Trainer): kwargs of TransformerConfig
+#: minus the dtype, plus the batch.
+TPU_MODEL = dict(vocab_size=32_000, d_model=1024, n_layers=8, n_heads=16,
+                 d_ff=4096, max_seq_len=1024, remat=True)
+TPU_BATCH, TPU_SEQ = 8, 1024
 
-def _chip_peak_tflops(device) -> float:
-    kind = getattr(device, "device_kind", "")
-    for name, peak in _PEAK_TFLOPS.items():
-        if kind.startswith(name):
-            return peak
-    # Unknown kind: report against v4 so the number is comparable,
-    # and include the kind in the output for the reader.
-    return 275.0
+
+def chip_peak_tflops(device) -> float:
+    kind = device.device_kind
+    if kind not in _PEAK_TFLOPS:
+        raise ValueError(
+            f"no peak FLOP/s on record for device_kind {kind!r} "
+            f"(platform {device.platform!r}); MFU is measured on a "
+            f"known accelerator only — add the kind to _PEAK_TFLOPS "
+            f"with its source")
+    return _PEAK_TFLOPS[kind]
 
 
 def main():
@@ -52,19 +64,10 @@ def main():
                                             make_train_state,
                                             make_train_step)
 
-    on_tpu = jax.default_backend() == "tpu"
-    # Realistic single-chip size on TPU; tiny shape elsewhere so the
-    # script stays runnable (and testable) on CPU.
-    if on_tpu:
-        cfg = TransformerConfig(
-            vocab_size=32_000, d_model=1024, n_layers=8, n_heads=16,
-            d_ff=4096, max_seq_len=1024, dtype=jnp.bfloat16, remat=True)
-        batch_size, seq_len, reps = 8, 1024, 10
-    else:
-        cfg = TransformerConfig(
-            vocab_size=512, d_model=128, n_layers=2, n_heads=4,
-            d_ff=384, max_seq_len=256, dtype=jnp.float32, remat=False)
-        batch_size, seq_len, reps = 2, 128, 2
+    device = jax.devices()[0]
+    peak = chip_peak_tflops(device)      # raises before any work
+    cfg = TransformerConfig(dtype=jnp.bfloat16, **TPU_MODEL)
+    batch_size, seq_len, reps = TPU_BATCH, TPU_SEQ, 10
 
     state, tx = make_train_state(jax.random.PRNGKey(0), cfg)
     train_step = make_train_step(cfg, tx)    # jitted, donates state
@@ -95,8 +98,6 @@ def main():
     flops = 6.0 * n_params * n_tokens + \
         12.0 * cfg.n_layers * batch_size * seq_len ** 2 * cfg.d_model / 2
     achieved_tflops = flops / step_s / 1e12
-    device = jax.devices()[0]
-    peak = _chip_peak_tflops(device)
     mfu = achieved_tflops / peak * 100.0
 
     print(json.dumps({
@@ -107,8 +108,8 @@ def main():
         "step_ms": round(step_s * 1000.0, 2),
         "achieved_tflops": round(achieved_tflops, 2),
         "peak_tflops": peak,
-        "device_kind": getattr(device, "device_kind", "?"),
-        "backend": jax.default_backend(),
+        "device_kind": device.device_kind,
+        "backend": device.platform,
         "params_m": round(n_params / 1e6, 1),
         "tokens_per_step": n_tokens,
         "loss_after_warmup": round(loss0, 4),

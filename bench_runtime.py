@@ -9,7 +9,10 @@ Reference: ``benchmarks/single_node/test_single_node.py`` (MAX_ARGS
 Each row prints one JSON line; the final line is the whole envelope.
 ``--quick`` shrinks the counts ~10x for smoke runs.  The companion
 ``bench.py`` (scheduler kernel on real TPU) is separate — this file
-measures the RUNTIME's envelope on CPU.
+measures the RUNTIME's envelope on CPU.  Importing it pins nothing:
+``main()`` pins its own process to the CPU, and ``bench.py`` imports
+the kernel rows (``bench_pg_packing``, ``bench_autoscaler_solve``)
+into a process that holds the chip.
 """
 
 import argparse
@@ -17,8 +20,6 @@ import json
 import os
 import sys
 import time
-
-os.environ.setdefault("JAX_PLATFORMS", "cpu")
 
 
 def emit(metric, value, unit, **extra):
@@ -281,8 +282,7 @@ def bench_solve_scale(arms=None, ticks=3, n_classes=64):
     ``cpu_throttled``-marked and the honest claim is the CAPACITY one
     (the sharded arm solves a 10x node count through the identical
     code path that parity tests pin to the single-device kernel), not
-    the speedup one.  Run the hardware driver the moment a chip
-    cooperates (bench.py --tpu)."""
+    the speedup one.  Not measured on real devices yet (ROADMAP S7)."""
     import numpy as np
 
     import jax
@@ -320,7 +320,6 @@ def bench_solve_scale(arms=None, ticks=3, n_classes=64):
             accel_class = rng.random(C) < 0.1
             cfg.solver_shard_backend = (
                 "force" if mode == "sharded" else "off")
-            sharded_solve.reset_broken()
             solver = BatchSolver()
             solve = lambda: solver.solve_matrices(
                 avail, total, demand, counts, accel_node, accel_class,
@@ -1482,6 +1481,10 @@ def main():
                              "solve sweep (ISSUE 17); forces 8 host "
                              "devices when chipless")
     args = parser.parse_args()
+    # Host-side envelope: this process and its children stay off the
+    # chip unless the caller names another platform explicitly (the
+    # --solve-scale arm on real devices: JAX_PLATFORMS=tpu).
+    os.environ.setdefault("JAX_PLATFORMS", "cpu")
 
     if args.introspection_bench:
         # Must land before ray_tpu import: contention arming is read
@@ -1493,7 +1496,7 @@ def main():
         # 8-way host-platform split BEFORE the jax backend initializes
         # (XLA_FLAGS is read at backend init).  A real-TPU run sets
         # JAX_PLATFORMS=tpu explicitly and skips the forcing.
-        if os.environ.get("JAX_PLATFORMS", "cpu") == "cpu" and \
+        if os.environ["JAX_PLATFORMS"] == "cpu" and \
                 "host_platform_device_count" not in \
                 os.environ.get("XLA_FLAGS", ""):
             os.environ["XLA_FLAGS"] = (
@@ -1523,8 +1526,6 @@ def main():
                                        samples=args.gate_samples)
         return 0 if row.get("passed") else 1
 
-    import jax
-    jax.config.update("jax_platforms", "cpu")
     import ray_tpu
     cpus = 8
     ray_tpu.init(num_cpus=cpus, _system_config={

@@ -1,0 +1,556 @@
+"""chip_smoke.py — the quickest proof that the system still starts on the chip.
+
+Drives the two accelerator paths end to end, in ONE process (the chip
+belongs to one process; every child the runtime starts is pinned to the
+CPU), through the entry points a user calls:
+
+  1. live runtime   ``ray_tpu.init`` (default ``scheduler_backend="jax"``,
+                    thread-mode workers), an in-process ``Cluster`` plus
+                    one ``node_host`` child, a few thousand
+                    ``@ray_tpu.remote`` tasks of several resource shapes;
+  2. scheduler      the BASELINE config-5 problem as ``bench.py`` builds it
+     kernel         (1M tasks x 256 classes x 10k nodes x 8 resources): one
+                    ``solve_stream`` program and a few single live ticks,
+                    fused Pallas fill on, checked on the host and compared
+                    EQUAL to the jnp scan on the same device;
+  3. trainer        ``ray_tpu.train.Trainer(backend="jax", use_tpu=True)``
+                    taking steps on ``bench_model.py``'s full-width model,
+                    the flash kernel in the compiled step.
+
+With more than one chip visible it also runs the sharded solve and the
+dp/sp/tp + ep + pp programs on the real devices; with one it says those
+legs did not run (never that they passed).
+
+Every leg is a function of its sizes and of the platform it must find:
+``main()`` runs them at full width on ``tpu``; tests/test_chip_smoke.py
+runs the same functions at tiny sizes with ``platform="cpu"`` (Pallas
+kernels in interpret mode).  There is no fallback inside ``main()``:
+without a TPU it exits non-zero before anything runs, and any failed
+check is the process's failure.
+
+The last line of stdout is one JSON object:
+  {"ok": true, "device": {"platform": "tpu", "kind": "...", "count": 1}}
+Wall-clock figures printed before it are set-up facts, not benchmark
+results.
+"""
+
+import json
+import os
+import sys
+import time
+
+import numpy as np
+
+
+def check(cond, what: str) -> None:
+    """A failed check is the process's failure (``assert`` would vanish
+    under ``python -O``)."""
+    if not cond:
+        raise SystemExit(f"chip_smoke: FAILED: {what}")
+
+
+class CompileClock:
+    """Seconds JAX spent tracing, lowering and compiling (or fetching
+    from the persistent cache), and the cache's hit/miss counts — from
+    ``jax.monitoring``, so work on raylet and worker threads counts."""
+
+    _DURATIONS = ("/jax/core/compile/jaxpr_trace_duration",
+                  "/jax/core/compile/jaxpr_to_mlir_module_duration",
+                  "/jax/core/compile/backend_compile_duration")
+
+    def __init__(self):
+        from jax import monitoring
+        self.seconds = 0.0
+        self.hits = 0
+        self.misses = 0
+        monitoring.register_event_duration_secs_listener(self._on_duration)
+        monitoring.register_event_listener(self._on_event)
+
+    def _on_duration(self, name, secs, **_):
+        if name in self._DURATIONS:
+            self.seconds += secs
+
+    def _on_event(self, name, **_):
+        if name == "/jax/compilation_cache/cache_hits":
+            self.hits += 1
+        elif name == "/jax/compilation_cache/cache_misses":
+            self.misses += 1
+
+    def snapshot(self):
+        return self.seconds, self.hits, self.misses
+
+
+def _version(package: str) -> str:
+    from importlib import metadata
+    try:
+        return metadata.version(package)
+    except metadata.PackageNotFoundError:
+        return "n/a"
+
+
+def device_facts() -> dict:
+    import jax
+    d = jax.devices()[0]
+    return {"platform": d.platform, "kind": d.device_kind,
+            "count": len(jax.devices())}
+
+
+# ---------------------------------------------------------------------------
+# Leg 1 — live runtime.
+# ---------------------------------------------------------------------------
+
+def leg_live_runtime(platform: str = "tpu", num_nodes: int = 4,
+                     num_tasks: int = 3000, remote_tasks: int = 200) -> dict:
+    """init -> in-process Cluster (+ one node_host child) -> tasks of
+    several resource shapes -> all results fetched; then every raylet's
+    device solver must have run with zero fallbacks and zero device
+    errors, its world state resident on ``platform``."""
+    import ray_tpu
+    from ray_tpu._private.cluster import Cluster
+    from ray_tpu._private.config import get_config
+    from ray_tpu._private.debug import watchdog
+
+    cluster = Cluster(initialize_head=True, head_node_args=dict(
+        num_cpus=4, resources={"head_only": 64.0}))
+    ray_tpu.init(_cluster=cluster)
+    try:
+        check(get_config().scheduler_backend == "jax"
+              and get_config().worker_process_mode == "thread",
+              "defaults are scheduler_backend=jax, thread-mode workers")
+        for _ in range(num_nodes - 1):
+            cluster.add_node(num_cpus=4, resources={"spoke": 64.0})
+        check(cluster.wait_for_nodes(num_nodes), "in-process nodes joined")
+        raylets = cluster.raylets()
+
+        @ray_tpu.remote
+        def f(i):
+            return i + 1
+
+        # Several scheduling classes; "spoke" work cannot run on the
+        # head, so the head's solve must spill it.
+        shapes = [dict(num_cpus=1), dict(num_cpus=2), dict(num_cpus=0.5),
+                  dict(num_cpus=1, resources={"spoke": 1.0}),
+                  dict(num_cpus=0.5, resources={"spoke": 2.0}),
+                  dict(num_cpus=1, resources={"head_only": 1.0})]
+        refs = [f.options(**shapes[i % len(shapes)]).remote(i)
+                for i in range(num_tasks)]
+        check(ray_tpu.get(refs, timeout=300)
+              == [i + 1 for i in range(num_tasks)], "task results")
+
+        # One process per chip: with this process holding the device, a
+        # node_host child must come up on the CPU and run a pinned burst.
+        handle = cluster.add_remote_node(num_cpus=2,
+                                         resources={"child": 1000.0})
+
+        @ray_tpu.remote(resources={"child": 1.0})
+        def where(i):
+            import jax
+            return (os.getpid(), os.environ.get("JAX_PLATFORMS"),
+                    jax.default_backend(), i)
+
+        got = ray_tpu.get([where.remote(i) for i in range(remote_tasks)],
+                          timeout=300)
+        check([g[3] for g in got] == list(range(remote_tasks)),
+              "node_host burst results")
+        check({g[:3] for g in got} == {(handle.proc.pid, "cpu", "cpu")},
+              f"node_host child ran on the CPU in its own process: "
+              f"{sorted({g[:3] for g in got})}")
+
+        solvers = []
+        for raylet in raylets:
+            ctm = raylet.cluster_task_manager
+            check(ctm.tick_stats["jnp_fallbacks"] == 0,
+                  f"raylet {raylet.node_id.hex()[:8]} jnp_fallbacks == 0: "
+                  f"{ctm.tick_stats['jnp_fallbacks']}")
+            solver = ctm._jax_solver
+            if solver is None:
+                continue            # never saw a queue deeper than one
+            stats = solver.stats
+            check(stats["ticks"] > 0 and stats["fallbacks"] == 0
+                  and stats["device_errors"] == 0,
+                  f"solver ran clean: {stats}")
+            for key in ("avail_t", "total_t"):
+                devs = {d.platform for d in solver._state[key].devices()}
+                check(devs == {platform},
+                      f"resident {key} on {platform}: {devs}")
+            solvers.append({"path": solver.last_path, **stats})
+        check(cluster.head_node.cluster_task_manager._jax_solver is not None
+              and len(solvers) >= 2,
+              f"device session engaged on the head and on spill targets "
+              f"({len(solvers)} solvers)")
+        check(not watchdog.wedge_reports(),
+              f"no wedge reports: {watchdog.wedge_reports()}")
+        return {"nodes": num_nodes, "tasks": num_tasks,
+                "node_host_tasks": remote_tasks, "solvers": solvers}
+    finally:
+        ray_tpu.shutdown()
+
+
+# ---------------------------------------------------------------------------
+# Leg 2 — the scheduler kernel at full width.
+# ---------------------------------------------------------------------------
+
+def leg_scheduler_kernel(platform: str = "tpu", num_tasks: int = 1_000_000,
+                         classes: int = 256, nodes: int = 10_000,
+                         resources: int = 8, ticks: int = 40,
+                         live_ticks: int = 3) -> dict:
+    """``solve_stream`` (closed loop) and single live ticks through
+    ``_jit_solve_tick``; host-side validity as ``bench.py`` checks it;
+    fused fill compared EQUAL to the jnp scan on the same inputs and
+    device.  Off the chip the fused kernel runs in interpret mode."""
+    import jax
+
+    from bench import arrival_stream, build_problem
+    from ray_tpu.scheduler import jax_backend as jb
+
+    rng = np.random.default_rng(42)
+    avail, total, demand, counts, accel_node, accel_class = build_problem(
+        rng, num_tasks=num_tasks, C=classes, N=nodes, R=resources)
+    per_tick = max(1, int(0.13 * num_tasks))
+    stream = arrival_stream(rng, counts, ticks, per_tick=per_tick)
+    rho = rng.integers(2, 9, size=classes) / 16.0
+
+    def host_valid(alloc, queue, what):
+        usage = alloc.T.astype(np.float64) @ demand.astype(np.float64)
+        check((usage <= avail.astype(np.float64) + 1e-2).all(),
+              f"{what}: capacity")
+        check((alloc.sum(axis=1) <= queue).all(), f"{what}: counts")
+
+    # The entry point, whatever fill it picks for this platform.
+    solver = jb.BatchSolver()
+    solver.prepare_device(avail, total, demand, accel_node=accel_node,
+                          accel_class=accel_class, spread_threshold=0.5)
+    out = solver.solve_stream(stream, rho=rho)
+    stream_path = solver.last_path
+    check(stream_path == ("single/pallas" if platform == "tpu"
+                          else "single/jnp"),
+          f"solve_stream took {stream_path}")
+    check(out["ok"].all(), "solve_stream on-device validation bits")
+    host_valid(solver.expand_sparse(out["idx"][0], out["vals"][0]),
+               stream[0], "solve_stream tick 0")
+
+    # Both fills, explicitly, on the same device-resident inputs.
+    dev = solver._device_state
+    c_pad, n_pad, r_pad = dev["pads"]
+    check({d.platform for d in dev["avail"].devices()} == {platform},
+          f"world state on {platform}")
+    stream_args = (
+        dev["avail"], dev["total"], dev["demand"],
+        np.zeros(c_pad, np.float32),
+        jb._pad_to(stream.astype(np.float32), (ticks, c_pad)),
+        jb._pad_to(rho.astype(np.float32), (c_pad,)),
+        dev["accel_node"], dev["accel_class"], dev["thr"], dev["cost"])
+    nnz_stream = 32768
+    packed = {
+        use: np.asarray(jb._jit_waterfill_stream(
+            c_pad, n_pad, r_pad, ticks, nnz_stream, use)(*stream_args))
+        for use in (True, False)}
+    check(np.array_equal(packed[True], packed[False]),
+          "solve_stream: fused Pallas fill == jnp scan")
+
+    # Single live ticks: what a raylet runs (one tick per program,
+    # nnz_max from the solver's own buckets).
+    nnz_bound = int(out["nnz"].max())
+    nnz_max = next(b for b in jb.DeviceRuntimeSolver._NNZ_BUCKETS
+                   if b >= nnz_bound)
+    avail_t = jax.device_put(
+        jb._pad_to(avail.astype(np.float32), (n_pad, r_pad)).T.copy())
+    total_t = jax.device_put(
+        jb._pad_to(total.astype(np.float32), (n_pad, r_pad)).T.copy())
+    for k in range(live_ticks):
+        queue = stream[k % ticks]
+        tick_args = (avail_t, total_t, dev["demand"],
+                     jb._pad_to(queue.astype(np.float32), (c_pad,)),
+                     dev["accel_node"], dev["accel_class"], dev["thr"],
+                     dev["cost"])
+        fused = np.asarray(jb._jit_solve_tick(
+            c_pad, n_pad, r_pad, nnz_max, True)(*tick_args))
+        scan = np.asarray(jb._jit_solve_tick(
+            c_pad, n_pad, r_pad, nnz_max, False)(*tick_args))
+        check(np.array_equal(fused, scan),
+              f"live tick {k}: fused Pallas fill == jnp scan")
+        check(fused[2 * nnz_max + 1] > 0.5, f"live tick {k}: ok bit")
+        idx = np.rint(fused[:nnz_max]).astype(np.int64)
+        host_valid(solver.expand_sparse(idx, fused[nnz_max:2 * nnz_max]),
+                   queue, f"live tick {k}")
+    return {"shape": [num_tasks, classes, nodes, resources],
+            "padded": [c_pad, n_pad, r_pad], "stream_path": stream_path,
+            "ticks_per_program": ticks,
+            "placed_tick0": int(out["placed"][0]),
+            "nnz_max_seen": nnz_bound, "live_tick_nnz_bucket": nnz_max,
+            "live_ticks": live_ticks, "fused_equals_scan": True}
+
+
+# ---------------------------------------------------------------------------
+# Leg 3 — Trainer steps at bench_model.py's width.
+# ---------------------------------------------------------------------------
+
+def _train_func(config: dict) -> dict:
+    """Runs inside the Train worker (a thread of this process)."""
+    import jax
+    import jax.numpy as jnp
+
+    from ray_tpu import train
+    from ray_tpu.models.transformer import (TransformerConfig,
+                                            make_train_state,
+                                            make_train_step)
+
+    cfg = TransformerConfig(dtype=jnp.dtype(config["dtype"]),
+                            **config["model"])
+    state, tx = make_train_state(jax.random.PRNGKey(0), cfg)
+    step = make_train_step(cfg, tx)
+    tokens = np.random.default_rng(0).integers(
+        0, cfg.vocab_size, (config["batch"], config["seq"] + 1))
+    batch = {"tokens": jnp.asarray(tokens, jnp.int32)}
+    compiled = step.lower(state, batch).compile()
+    losses = []
+    for i in range(config["steps"]):
+        state, metrics = compiled(state, batch)
+        losses.append(float(metrics["loss"]))
+        train.report(step=i, loss=losses[-1])
+    leaf = jax.tree.leaves(state["params"])[0]
+    return {"losses": losses,
+            "mosaic_in_step": "tpu_custom_call" in compiled.as_text(),
+            "param_platforms": sorted({d.platform for d in leaf.devices()}),
+            "n_params": sum(int(np.prod(x.shape))
+                            for x in jax.tree.leaves(state["params"]))}
+
+
+def leg_trainer(platform: str = "tpu", model: dict = None, batch: int = None,
+                seq: int = None, steps: int = 5, dtype: str = "bfloat16",
+                flash_tol: float = 4e-2) -> dict:
+    """Trainer(backend="jax", num_workers=1, use_tpu=True) on a head that
+    advertises the chip; a repeated batch, so the loss must fall.  On the
+    chip the compiled step must contain the Mosaic flash kernel, and the
+    kernel must agree with ``full_attention`` (off the chip the kernel is
+    checked in interpret mode and ``attention()`` takes the reference)."""
+    import jax
+    import jax.numpy as jnp
+
+    import bench_model
+    import ray_tpu
+    from ray_tpu.ops.flash_attention import flash_attention
+    from ray_tpu.ops.ring_attention import full_attention
+    from ray_tpu.train import Trainer
+
+    model = dict(model or bench_model.TPU_MODEL)
+    batch = batch or bench_model.TPU_BATCH
+    seq = seq or bench_model.TPU_SEQ
+    on_chip = platform == "tpu"
+
+    # Kernel vs reference at the model's attention shape.
+    heads, head_dim = model["n_heads"], model["d_model"] // model["n_heads"]
+    q, k, v = (jax.random.normal(key, (batch, seq, heads, head_dim),
+                                 jnp.float32).astype(jnp.dtype(dtype))
+               for key in jax.random.split(jax.random.PRNGKey(7), 3))
+    flash = flash_attention(q, k, v, interpret=not on_chip)
+    ref = full_attention(q, k, v)
+    flash_err = float(jnp.max(jnp.abs(flash.astype(jnp.float32)
+                                      - ref.astype(jnp.float32))))
+    check(flash_err <= flash_tol,
+          f"flash forward vs full_attention: max abs err {flash_err} "
+          f"> {flash_tol} ({dtype})")
+
+    # num_tpus is passed: init() never initialises a backend to count.
+    ray_tpu.init(num_cpus=4, num_tpus=len(jax.devices()))
+    try:
+        trainer = Trainer(backend="jax", num_workers=1, use_tpu=True)
+        try:
+            (result,) = trainer.run(_train_func, config=dict(
+                model=model, batch=batch, seq=seq, steps=steps,
+                dtype=dtype))
+        finally:
+            trainer.shutdown()
+    finally:
+        ray_tpu.shutdown()
+    losses = result["losses"]
+    check(len(losses) == steps and np.isfinite(losses).all(),
+          f"finite losses: {losses}")
+    check(losses[-1] < losses[0], f"loss falls on a repeated batch: {losses}")
+    check(result["param_platforms"] == [platform],
+          f"params on {platform}: {result['param_platforms']}")
+    check(result["mosaic_in_step"] == on_chip,
+          f"Mosaic flash kernel in the compiled step: "
+          f"{result['mosaic_in_step']} (expected {on_chip})")
+    return {"model": model, "batch": batch, "seq": seq, "dtype": dtype,
+            "params_m": round(result["n_params"] / 1e6, 1),
+            "losses": [round(x, 4) for x in losses],
+            "flash_in_step": result["mosaic_in_step"],
+            "flash_vs_full_max_abs_err": flash_err, "flash_tol": flash_tol}
+
+
+# ---------------------------------------------------------------------------
+# Legs 4 and 5 — more than one device.
+# ---------------------------------------------------------------------------
+
+def leg_sharded_solve(platform: str = "tpu", nodes: int = 10_240,
+                      classes: int = 64, num_tasks: int = 100_000,
+                      tick_specs: int = 4096) -> dict:
+    """The node-sharded solve on every visible device against the
+    single-device kernel: ``BatchSolver.solve_matrices`` and one
+    ``DeviceRuntimeSolver`` tick.  ``nodes`` is a multiple of 128 x
+    devices so both pad to the same ring and must agree bit for bit."""
+    import jax
+
+    from bench import build_problem
+    from ray_tpu._private.config import get_config
+    from ray_tpu.scheduler import jax_backend as jb
+    from ray_tpu.scheduler.policy import SchedulingOptions
+    from ray_tpu.scheduler.resources import (ClusterResourceView,
+                                             NodeResources, ResourceRequest)
+
+    n_dev = len(jax.devices())
+    cfg = get_config()
+    check(n_dev > 1 and nodes % (jb._GROUP * n_dev) == 0
+          and nodes >= cfg.solver_shard_min_nodes,
+          f"{nodes} nodes shard evenly over {n_dev} devices, above the gate")
+    rng = np.random.default_rng(7)
+    avail, total, demand, counts, accel_node, accel_class = build_problem(
+        rng, num_tasks=num_tasks, C=classes, N=nodes, R=8)
+
+    class Spec:
+        def __init__(self, cpu, cls):
+            self.resources = ResourceRequest({"CPU": cpu})
+            self.scheduling_options = SchedulingOptions.hybrid()
+            self.scheduling_class = cls
+
+    view = ClusterResourceView()
+    for i in range(nodes):
+        view.add_node(f"n{i:05d}", NodeResources(
+            {"CPU": float(4 + 4 * (i % 3)), "memory": 16.0}))
+    specs = [Spec(float(1 + i % 4), 5000 + i % 4) for i in range(tick_specs)]
+
+    prev = cfg.solver_shard_backend
+    results = {}
+    try:
+        for mode in ("auto", "off"):
+            cfg.solver_shard_backend = mode
+            batch = jb.BatchSolver()
+            alloc = batch.solve_matrices(avail, total, demand, counts,
+                                         accel_node, accel_class,
+                                         spread_threshold=0.5)
+            live = jb.DeviceRuntimeSolver()
+            targets = live.solve(view, specs)
+            check(targets is not None and live.stats["device_errors"] == 0
+                  and live.stats["fallbacks"] == 0,
+                  f"live tick ({mode}) ran clean: {live.stats}")
+            results[mode] = (alloc, targets, batch.last_path, live)
+    finally:
+        cfg.solver_shard_backend = prev
+    alloc_sh, targets_sh, path_sh, live_sh = results["auto"]
+    alloc_1, targets_1, path_1, live_1 = results["off"]
+    check(path_sh == f"sharded[{n_dev}]/jnp" and path_1.startswith("single/"),
+          f"solve paths: {path_sh} vs {path_1}")
+    check(np.array_equal(alloc_sh, alloc_1),
+          "solve_matrices: sharded == single-device")
+    check(targets_sh == targets_1, "live tick: sharded == single-device")
+    check(live_sh.stats["sharded_ticks"] > 0
+          and live_1.stats["sharded_ticks"] == 0, "sharded_ticks counted")
+    shard_devs = {s.device for s in live_sh._state["avail_t"].addressable_shards}
+    check(len(shard_devs) == n_dev
+          and {d.platform for d in shard_devs} == {platform},
+          f"avail_t shards on {n_dev} distinct {platform} devices: "
+          f"{sorted(d.id for d in shard_devs)}")
+    return {"devices": n_dev, "nodes": nodes, "classes": classes,
+            "paths": [path_sh, path_1, live_sh.last_path, live_1.last_path],
+            "placed": int(alloc_sh.sum()),
+            "tick_placed": sum(t is not None for t in targets_sh),
+            "sharded_equals_single": True}
+
+
+def _model_parallel_func(config: dict) -> dict:
+    import __graft_entry__ as graft
+    return graft.dryrun_multichip(config["devices"])
+
+
+def leg_model_parallel(platform: str = "tpu", devices: int = None) -> dict:
+    """One Trainer worker holding every chip: the dp/sp/tp train step
+    (ring attention over sp), then the ep and pp programs, on the real
+    devices; parameter shards on every one of them."""
+    import jax
+
+    import ray_tpu
+    from ray_tpu.train import Trainer
+
+    devices = devices or len(jax.devices())
+    check(devices > 1, "model parallelism needs more than one device")
+    ray_tpu.init(num_cpus=4, num_tpus=devices)
+    try:
+        trainer = Trainer(backend="jax", num_workers=1,
+                          resources_per_worker={"TPU": devices})
+        try:
+            (facts,) = trainer.run(_model_parallel_func,
+                                   config=dict(devices=devices))
+        finally:
+            trainer.shutdown()
+    finally:
+        ray_tpu.shutdown()
+    check(facts["platform"] == platform, f"ran on {facts['platform']}")
+    check(len(set(facts["param_devices"])) == devices,
+          f"parameter shards on {devices} distinct devices: "
+          f"{facts['param_devices']}")
+    for key in ("loss", "moe_loss", "pp_loss"):
+        check(np.isfinite(facts[key]) and facts[key] > 0,
+              f"{key} finite: {facts[key]}")
+    return facts
+
+
+# ---------------------------------------------------------------------------
+
+def run_leg(name, clock, fn, **kwargs):
+    c0, h0, m0 = clock.snapshot()
+    t0 = time.perf_counter()
+    facts = fn(**kwargs)
+    wall = time.perf_counter() - t0
+    c1, h1, m1 = clock.snapshot()
+    # Compile seconds are summed over threads (raylets compile side by
+    # side), so "run" is what is left of the wall clock at least.
+    print(f"leg {name}: PASSED  wall {wall:.1f}s; compile {c1 - c0:.1f}s "
+          f"(persistent cache: {h1 - h0} hits, {m1 - m0} misses); run "
+          f"{max(wall - (c1 - c0), 0.0):.1f}s  "
+          f"[set-up facts, not benchmark numbers]")
+    print(f"  {json.dumps(facts)}", flush=True)
+    return facts
+
+
+def main() -> int:
+    import jax
+
+    from ray_tpu._private.device_policy import enable_compile_cache
+    cache_dir = enable_compile_cache()
+    device = device_facts()
+    if device["platform"] != "tpu":
+        print(f"chip_smoke: no TPU: jax.devices()[0].platform is "
+              f"{device['platform']!r}; this script only runs on the chip",
+              file=sys.stderr)
+        return 1
+    print(f"chip_smoke: platform={device['platform']} "
+          f"device_kind={device['kind']!r} count={device['count']} "
+          f"python={sys.version.split()[0]} jax={jax.__version__} "
+          f"jaxlib={_version('jaxlib')} libtpu={_version('libtpu')}")
+    entries_before = len(os.listdir(cache_dir)) \
+        if os.path.isdir(cache_dir) else 0
+    print(f"compile cache: {cache_dir} ({entries_before} entries at start)",
+          flush=True)
+
+    clock = CompileClock()
+    run_leg("1 live runtime", clock, leg_live_runtime)
+    run_leg("2 scheduler kernel 1M x 256 x 10k", clock, leg_scheduler_kernel)
+    run_leg("3 trainer (bench_model.py width)", clock, leg_trainer)
+    if device["count"] > 1:
+        run_leg("4 sharded solve", clock, leg_sharded_solve)
+        run_leg("5 model parallel dp/sp/tp + ep + pp", clock,
+                leg_model_parallel)
+    else:
+        print("leg 4 sharded solve: NOT RUN (one device visible)")
+        print("leg 5 model parallel: NOT RUN (one device visible)")
+    seconds, hits, misses = clock.snapshot()
+    print(f"compile cache: {cache_dir} ({len(os.listdir(cache_dir))} entries "
+          f"at end, {entries_before} at start); this run: {hits} hits, "
+          f"{misses} misses, {seconds:.1f}s tracing+lowering+compiling")
+    print(json.dumps({"ok": True, "device": device}), flush=True)
+    return 0
+
+
+if __name__ == "__main__":
+    sys.exit(main())

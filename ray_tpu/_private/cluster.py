@@ -125,12 +125,11 @@ class Cluster:
         """Spawn one NodeHost OS process; returns ``(proc, reg_token,
         name)`` without waiting for registration."""
         import json
-        import os
         import subprocess
         import sys
         import uuid
 
-        from ray_tpu._private.runtime_env import framework_import_root
+        from ray_tpu._private.device_policy import child_env
         host, port = self.start_head_service()
         total = self._assemble_totals(
             spec.get("num_cpus", 1), spec.get("num_tpus", 0),
@@ -138,9 +137,8 @@ class Cluster:
             spec.get("object_store_memory"), spec.get("resources"))
         name = spec.get("node_name") or f"remote-{uuid.uuid4().hex[:8]}"
         reg_token = uuid.uuid4().hex
-        env = dict(os.environ)
-        env["PYTHONPATH"] = framework_import_root() + os.pathsep + \
-            env.get("PYTHONPATH", "")
+        # This process holds the chip (if any): the node host solves on
+        # the CPU (device_policy — one process per chip).
         proc = subprocess.Popen(
             [sys.executable, "-m", "ray_tpu._private.node_host",
              "--head", f"{host}:{port}",
@@ -148,7 +146,7 @@ class Cluster:
              "--name", name,
              "--reg-token", reg_token,
              "--system-config", get_config().to_json()],
-            env=env)
+            env=child_env())
         return proc, reg_token, name
 
     def add_remote_nodes(self, specs, timeout: float = 60.0,

@@ -33,11 +33,14 @@ class Config:
     #: "native" = greedy per-task python/numpy policy (reference parity),
     #: "jax"    = batched TPU bin-packing kernel (the north star) with
     #:            device-resident world state and validated native
-    #:            fallback.  Default since round 3.
+    #:            fallback.  Default since round 3.  The device is the
+    #:            one the process holds: children the runtime starts
+    #:            are pinned to the CPU (_private/device_policy.py).
     scheduler_backend: str = "jax"
 
     #: Fuse the per-class waterfill into one Mosaic (Pallas) kernel on
-    #: TPU; falls back to the jnp scan path automatically on failure.
+    #: TPU.  False selects the jnp scan there too (chip_smoke.py compares
+    #: the two on the chip); a kernel failure is never a silent switch.
     scheduler_pallas_fill: bool = True
     #: Heterogeneity cost weight (Gavel-style effective-rate scaling):
     #: slower nodes (per the ray_tpu.throughput / accel_throughput node
@@ -218,6 +221,8 @@ class Config:
     #: process per host owns the TPU chips); "process" = real OS worker
     #: processes spawned via worker_main and driven over the framed-RPC
     #: wire (reference StartWorkerProcess parity, worker_pool.h:428).
+    #: Process-mode workers are pinned to the CPU (device_policy): work
+    #: that needs the chip is not supported there yet (ROADMAP D7).
     worker_process_mode: str = "thread"
     #: Soft cap of idle workers kept alive per node (ray_config_def.h:129).
     num_workers_soft_limit: int = 64

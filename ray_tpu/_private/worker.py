@@ -209,7 +209,8 @@ def _detect_tpu_chips() -> float:
     Never *initializes* a jax backend here — first backend init on a real
     TPU can take tens of seconds and must not sit on the ``init()`` path.
     Counted only from env (``RAY_TPU_CHIPS``) or from an
-    already-initialized jax backend.
+    already-initialized jax backend; a driver that has not touched JAX
+    yet passes ``init(num_tpus=...)``.
     """
     import os
     import sys
@@ -217,13 +218,9 @@ def _detect_tpu_chips() -> float:
         return float(os.environ["RAY_TPU_CHIPS"])
     jax = sys.modules.get("jax")
     if jax is not None:
-        try:
-            from jax._src import xla_bridge
-            if getattr(xla_bridge, "_backends", None):
-                return float(len([d for d in jax.devices()
-                                  if d.platform != "cpu"]))
-        except Exception:
-            return 0.0
+        from jax._src import xla_bridge
+        if xla_bridge.backends_are_initialized():
+            return float(sum(d.platform != "cpu" for d in jax.devices()))
     return 0.0
 
 

@@ -487,19 +487,16 @@ class ProcessWorker:
         self._queue: "queue.Queue" = queue.Queue()
         self._client = None
         host = pool.host_service()
-        import os
         from ray_tpu._private import runtime_env as runtime_env_mod
         self.runtime_env_hash = (runtime_env or {}).get("_hash", "")
-        env = dict(os.environ)
-        if runtime_env:
-            # Materialize working_dir/py_modules host-side, inject env
-            # vars + import paths + cwd at spawn (worker_pool.h:428:
-            # workers are started FOR an env and keyed by its hash).
-            ctx = runtime_env_mod.materialize(
-                runtime_env, node.cluster.gcs.kv)
-            env = ctx.spawn_env(env)
-        env["PYTHONPATH"] = runtime_env_mod.framework_import_root() + \
-            os.pathsep + env.get("PYTHONPATH", "")
+        # Materialize working_dir/py_modules host-side, inject env
+        # vars + import paths + cwd at spawn (worker_pool.h:428:
+        # workers are started FOR an env and keyed by its hash).  The
+        # worker is pinned to the CPU unless its runtime_env says
+        # otherwise (device_policy — one process per chip).
+        from ray_tpu._private.device_policy import child_env
+        env = child_env(runtime_env_mod.materialize(
+            runtime_env, node.cluster.gcs.kv) if runtime_env else None)
         # Unbuffered child stdio: prints must reach the tailed log file
         # as they happen, not on 8KB block-buffer flushes at exit.
         env["PYTHONUNBUFFERED"] = "1"

@@ -124,12 +124,10 @@ class JobManager:
 
         normalized = runtime_env_mod.normalize(runtime_env, self._kv) \
             if runtime_env else None
+        from ray_tpu._private.device_policy import child_env
         ctx = runtime_env_mod.materialize(normalized, self._kv)
-        env = ctx.spawn_env()
-        env["PYTHONPATH"] = runtime_env_mod.framework_import_root() + \
-            os.pathsep + env.get("PYTHONPATH", "")
+        env = child_env(ctx)
         env["RAY_TPU_JOB_ID"] = submission_id
-        env.setdefault("JAX_PLATFORMS", "cpu")
 
         log_path = self.log_path(submission_id)
         os.makedirs(os.path.dirname(log_path), exist_ok=True)

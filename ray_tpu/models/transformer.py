@@ -149,13 +149,12 @@ def _rope(x, positions, theta):
 def _attention_core(q, k, v, mesh, cfg: TransformerConfig):
     if (cfg.context_parallel and mesh is not None and
             mesh.shape.get("sp", 1) > 1):
-        from jax.experimental.shard_map import shard_map
-        fn = shard_map(
+        fn = jax.shard_map(
             functools.partial(ring_attention, axis_name="sp", causal=True),
             mesh=mesh,
             in_specs=(P("dp", "sp", "tp", None),) * 3,
             out_specs=P("dp", "sp", "tp", None),
-            check_rep=False)
+            check_vma=False)
         return fn(q, k, v)
     return flash_or_ref_attention(q, k, v, causal=True)
 

@@ -1,6 +1,8 @@
 """ctypes binding for the native shared-memory store.
 
-Builds ``shm_store.cpp`` with g++ on first use (cached .so).  Reads are
+Builds ``shm_store.cpp`` with g++ on first use; the library is named by
+a hash of the source, so a copied or checked-out tree (where mtimes
+mean nothing) never loads a library built from other source.  Reads are
 zero-copy: Python mmaps the same shm segment and returns memoryview
 slices at the (offset, size) handles the C++ side hands out — the same
 client model as plasma's mmap'd object views
@@ -10,6 +12,7 @@ client model as plasma's mmap'd object views
 from __future__ import annotations
 
 import ctypes
+import hashlib
 import mmap
 import os
 import subprocess
@@ -19,7 +22,7 @@ from typing import Optional
 
 _BUILD_LOCK = threading.Lock()
 _SRC = os.path.join(os.path.dirname(__file__), "shm_store.cpp")
-_SO = os.path.join(os.path.dirname(__file__), "_build", "libshm_store.so")
+_BUILD_DIR = os.path.join(os.path.dirname(__file__), "_build")
 
 # tmpfs pages are first-touch, so `df /dev/shm` does not reflect open
 # (sparse) segments — a sizing decision based on free space alone
@@ -53,15 +56,25 @@ def _reserve(delta: int) -> None:
 
 
 def _build() -> str:
+    with open(_SRC, "rb") as f:
+        digest = hashlib.sha256(f.read()).hexdigest()[:16]
+    so = os.path.join(_BUILD_DIR, f"libshm_store-{digest}.so")
     with _BUILD_LOCK:
-        if os.path.exists(_SO) and \
-                os.path.getmtime(_SO) >= os.path.getmtime(_SRC):
-            return _SO
-        os.makedirs(os.path.dirname(_SO), exist_ok=True)
+        if os.path.exists(so):
+            return so
+        os.makedirs(_BUILD_DIR, exist_ok=True)
+        # Node-host children may build at the same moment: each links
+        # to its own temporary name and renames into place atomically.
+        tmp = f"{so}.{os.getpid()}.tmp"
         cmd = ["g++", "-O2", "-shared", "-fPIC", "-std=c++17", _SRC,
-               "-o", _SO, "-lrt"]
-        subprocess.run(cmd, check=True, capture_output=True)
-        return _SO
+               "-o", tmp, "-lrt"]
+        try:
+            subprocess.run(cmd, check=True, capture_output=True)
+            os.replace(tmp, so)
+        finally:
+            if os.path.exists(tmp):
+                os.unlink(tmp)
+        return so
 
 
 def _load() -> ctypes.CDLL:

@@ -22,7 +22,6 @@ from typing import Dict
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec as P
 
 from ray_tpu.models.transformer import (TransformerConfig, _rms_norm,
@@ -121,10 +120,10 @@ def make_pp_loss_fn(cfg: TransformerConfig, mesh, n_micro: int):
         P(), P(), P(),                       # embed, ln_f, head
         P("dp", None) if dp > 1 else P(),    # tokens
     )
-    smapped = shard_map(
+    smapped = jax.shard_map(
         stage_loss, mesh=mesh,
         in_specs=in_specs, out_specs=P(),
-        check_rep=False)
+        check_vma=False)
 
     def loss_fn(params, batch):
         return smapped(params["layers"], params["embed"],

@@ -5,8 +5,7 @@
   single-device jit kernels and the dirty-row delta path.
 - ``sharded_solve`` — the pod-sharded solve (ISSUE 17): above
   ``solver_shard_min_nodes`` the (classes x nodes) matrices shard along
-  the node axis over a 1-D device mesh via ``shard_map``; falls back to
-  the single-device kernel on any shard failure (kill-switch).
+  the node axis over a 1-D device mesh via ``shard_map``.
 - ``bundle_packing`` — placement-group bundle packing strategies.
 - ``policy`` / ``resources`` — host-side policy glue and resource
   vector shapes.

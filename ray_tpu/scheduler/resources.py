@@ -384,13 +384,17 @@ class ClusterResourceView:
             return len(self._columns)
 
     def demand_matrix(self, requests: List[ResourceRequest]) -> np.ndarray:
-        """Pack demands into [C, R] aligned with this view's columns."""
+        """Pack demands into [C, R] aligned with this view's columns.
+        A resource no node has advertised yet gets its column here, so
+        columns are resolved before the matrix is sized."""
         with self._lock:
+            cells = [(i, self._column(name), v)
+                     for i, req in enumerate(requests)
+                     for name, v in req.quantized().items()]
             mat = np.zeros((len(requests), len(self._columns)),
                            dtype=np.float32)
-            for i, req in enumerate(requests):
-                for name, v in req.quantized().items():
-                    mat[i, self._column(name)] = v / FP_SCALE
+            for i, col, v in cells:
+                mat[i, col] = v / FP_SCALE
             return mat
 
     # ---- queries --------------------------------------------------------

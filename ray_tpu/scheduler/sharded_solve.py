@@ -41,15 +41,15 @@ first-max tie-break: each shard reports (local first-max value, local
 index); the winner is the FIRST shard attaining the global max, which
 in shard-major concatenation order is precisely the global first-max.
 
-Failure containment mirrors the Pallas kill-switch: any sharded-solve
-error flips ``_SHARD_BROKEN`` for the process and callers re-route to
-the single-device path (``plan_shards`` returns 1 from then on).
+There is no run-time switch back to the single-device path: a sharded
+compile or device error propagates from the ``BatchSolver`` entry
+points and is counted under ``DeviceRuntimeSolver.stats
+["device_errors"]`` on the live tick.
 """
 
 from __future__ import annotations
 
 import functools
-import logging
 from typing import Optional
 
 import numpy as np
@@ -59,31 +59,7 @@ from ray_tpu.scheduler.jax_backend import (
     _BIG, _COST_BUCKETS, _GROUP, _NUM_BUCKETS, _ROT_STRIDE, _UTIL_LEVELS,
     _pad_to, _round_up)
 
-logger = logging.getLogger(__name__)
-
 _AXIS = "nodes"
-
-# Flipped on the first sharded-solve failure; plan_shards then pins the
-# process to the single-device path (same pattern as _PALLAS_BROKEN).
-_SHARD_BROKEN = False
-_SHARD_BROKEN_WHY: Optional[str] = None
-
-
-def mark_broken(why: str) -> None:
-    global _SHARD_BROKEN, _SHARD_BROKEN_WHY
-    if not _SHARD_BROKEN:
-        logger.exception(
-            "sharded solve failed (%s); single-device path for the rest "
-            "of this process", why)
-    _SHARD_BROKEN = True
-    _SHARD_BROKEN_WHY = why
-
-
-def reset_broken() -> None:
-    """Test hook: re-arm the sharded path after a deliberate failure."""
-    global _SHARD_BROKEN, _SHARD_BROKEN_WHY
-    _SHARD_BROKEN = False
-    _SHARD_BROKEN_WHY = None
 
 
 def plan_shards(n_nodes: int) -> int:
@@ -91,23 +67,17 @@ def plan_shards(n_nodes: int) -> int:
 
     Gate: ``solver_shard_backend`` ("off" never, "force" whenever >1
     device, "auto" only at ``solver_shard_min_nodes`` scale — below
-    that the collective latency outweighs the per-shard shrink), the
-    process kill-switch, and the visible device count.
+    that the collective latency outweighs the per-shard shrink) and
+    the visible device count.
     """
-    if _SHARD_BROKEN:
-        return 1
     cfg = get_config()
     mode = cfg.solver_shard_backend
     if mode == "off":
         return 1
     if mode != "force" and n_nodes < cfg.solver_shard_min_nodes:
         return 1
-    try:
-        import jax
-        n = len(jax.devices())
-    except Exception:
-        return 1
-    return n if n > 1 else 1
+    import jax
+    return len(jax.devices())
 
 
 def pads_sharded(C: int, N: int, R: int, n_shards: int):
@@ -293,17 +263,16 @@ def _jit_sharded_waterfill(c_pad: int, n_pad: int, r_pad: int,
                            n_shards: int):
     """Sharded twin of ``_jit_waterfill`` ([N, R] in, allocs [C, N] out)."""
     import jax
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     mesh = _mesh(n_shards)
 
     @functools.partial(
-        shard_map, mesh=mesh,
+        jax.shard_map, mesh=mesh,
         in_specs=(P(_AXIS, None), P(_AXIS, None), P(), P(), P(_AXIS),
                   P(), P(), P(None, _AXIS), P(), P()),
         out_specs=(P(None, _AXIS), P(_AXIS, None)),
-        check_rep=False)
+        check_vma=False)
     def solve(avail, total, demand, counts, accel_node, accel_class,
               spread_threshold, cost, invert, shifts):
         av_after, allocs = _sharded_class_fill(
@@ -321,18 +290,17 @@ def _jit_sharded_solve_tick(c_pad: int, n_pad: int, r_pad: int,
     [R, N] world state in, per-shard packed rows [n_shards, 2*nnz+3]
     out (merge with ``merge_packed``)."""
     import jax
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     assert c_pad * n_pad < (1 << 24), "sparse idx must stay exact in f32"
     mesh = _mesh(n_shards)
 
     @functools.partial(
-        shard_map, mesh=mesh,
+        jax.shard_map, mesh=mesh,
         in_specs=(P(None, _AXIS), P(None, _AXIS), P(), P(), P(_AXIS),
                   P(), P(), P(None, _AXIS)),
         out_specs=P(_AXIS, None),
-        check_rep=False)
+        check_vma=False)
     def solve(avail_t, total_t, demand, counts, accel_node, accel_class,
               spread_threshold, cost):
         shifts = (np.arange(c_pad, dtype=np.int32) * _ROT_STRIDE) % n_pad
@@ -355,17 +323,16 @@ def _jit_sharded_pack_bundles(b_pad: int, n_pad: int, r_pad: int,
     argmax with the exact first-max tie-break (see module docstring).
     Outputs are replicated; the host reads shard row 0."""
     import jax
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     mesh = _mesh(n_shards)
 
     @functools.partial(
-        shard_map, mesh=mesh,
+        jax.shard_map, mesh=mesh,
         in_specs=(P(_AXIS, None), P(_AXIS, None), P(), P(_AXIS), P(_AXIS),
                   P(), P()),
         out_specs=(P(_AXIS, None), P(_AXIS, None)),
-        check_rep=False)
+        check_vma=False)
     def solve(avail, total, demand, excluded, used0, pack_w,
               strict_spread):
         import jax.numpy as jnp

@@ -35,6 +35,19 @@ class WorkerGroup:
         if num_workers <= 0:
             raise ValueError("num_workers must be positive")
         self.num_workers = num_workers
+        if num_tpus_per_worker:
+            # A TPU request no node can ever meet would wait as
+            # infeasible for ever (pg.ready() below).  The usual cause:
+            # the head counted 0 chips because the driver had not
+            # initialised JAX when it called init().
+            have = ray_tpu.cluster_resources().get("TPU", 0)
+            need = num_workers * num_tpus_per_worker
+            if have < need:
+                raise ValueError(
+                    f"workers need {need:g} TPU but the cluster advertises "
+                    f"{have:g}: pass ray_tpu.init(num_tpus=...) (or set "
+                    f"RAY_TPU_CHIPS) on the host that holds the chip, or "
+                    f"add the TPU nodes before starting the gang")
         resources = dict(additional_resources_per_worker or {})
         self._pg = None
         options: Dict[str, Any] = dict(

@@ -1,0 +1,80 @@
+"""chip_smoke.py's legs at tiny sizes on the CPU — the same functions
+``main()`` runs at full width on the chip, with ``platform="cpu"``
+(Pallas kernels in interpret mode) — and the script's refusal to run
+without a TPU."""
+
+import os
+import subprocess
+import sys
+
+import pytest
+
+_ROOT = os.path.join(os.path.dirname(os.path.abspath(__file__)), os.pardir)
+
+
+@pytest.fixture(scope="module")
+def smoke():
+    sys.path.insert(0, _ROOT)
+    try:
+        import chip_smoke
+        yield chip_smoke
+    finally:
+        sys.path.remove(_ROOT)
+
+
+def test_refuses_to_run_without_a_tpu():
+    proc = subprocess.run(
+        [sys.executable, os.path.join(_ROOT, "chip_smoke.py")],
+        env=dict(os.environ, JAX_PLATFORMS="cpu"), capture_output=True,
+        text=True, timeout=120)
+    assert proc.returncode != 0
+    assert proc.stdout == ""                 # no result line
+    reason = proc.stderr.strip().splitlines()
+    assert len(reason) == 1 and "no TPU" in reason[0], proc.stderr
+
+
+def test_leg_live_runtime(smoke):
+    facts = smoke.leg_live_runtime(platform="cpu", num_nodes=3,
+                                   num_tasks=600, remote_tasks=40)
+    assert len(facts["solvers"]) >= 2
+    assert all(s["device_errors"] == 0 and s["fallbacks"] == 0
+               for s in facts["solvers"])
+
+
+def test_leg_scheduler_kernel(smoke):
+    facts = smoke.leg_scheduler_kernel(
+        platform="cpu", num_tasks=2000, classes=16, nodes=100,
+        resources=8, ticks=3, live_ticks=2)
+    assert facts["fused_equals_scan"] and facts["placed_tick0"] > 0
+    assert facts["stream_path"] == "single/jnp"   # the CPU's choice
+
+
+def test_leg_trainer(smoke):
+    facts = smoke.leg_trainer(
+        platform="cpu", batch=2, seq=128, steps=3, dtype="float32",
+        flash_tol=1e-4,
+        model=dict(vocab_size=256, d_model=128, n_layers=2, n_heads=2,
+                   d_ff=256, max_seq_len=128, remat=True))
+    assert facts["losses"][-1] < facts["losses"][0]
+    assert not facts["flash_in_step"]             # reference off the chip
+
+
+def test_leg_sharded_solve(smoke):
+    facts = smoke.leg_sharded_solve(platform="cpu", nodes=4096, classes=8,
+                                    num_tasks=5000, tick_specs=256)
+    assert facts["sharded_equals_single"] and facts["devices"] == 8
+
+
+def test_leg_model_parallel(smoke):
+    facts = smoke.leg_model_parallel(platform="cpu", devices=4)
+    assert facts["mesh"] == {"dp": 1, "sp": 2, "tp": 2, "ep": 2, "pp": 2}
+    assert facts["param_devices"] == [0, 1, 2, 3]
+
+
+def test_a_failed_check_fails_the_process(smoke):
+    # Told to find a TPU, the leg must not accept what the CPU picked.
+    with pytest.raises(SystemExit,
+                       match="FAILED: solve_stream took single/jnp"):
+        smoke.leg_scheduler_kernel(
+            platform="tpu", num_tasks=200, classes=8, nodes=16,
+            resources=8, ticks=1, live_ticks=0)

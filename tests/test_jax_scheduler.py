@@ -1,6 +1,7 @@
 """TPU scheduling kernel tests: golden vs numpy oracle, feasibility
 invariants, end-to-end scheduler_backend=jax (runs on the virtual CPU
-mesh in CI; the same code path runs on the real chip in bench.py)."""
+mesh in CI; the same code path runs on the real chip in
+chip_smoke.py)."""
 
 import numpy as np
 import pytest
@@ -8,6 +9,25 @@ import pytest
 import ray_tpu
 from ray_tpu.scheduler.jax_backend import (BatchSolver, DeviceRuntimeSolver,
                                            stream_oracle, waterfill_oracle)
+
+
+@pytest.fixture(autouse=True)
+def _no_device_errors(monkeypatch):
+    """Every DeviceRuntimeSolver this module creates — directly or
+    inside a raylet — must finish its test with zero device errors: a
+    compile or device failure returns None like a stale view does, so
+    without this gate the greedy fallback would keep the suite green."""
+    solvers = []
+    init = DeviceRuntimeSolver.__init__
+
+    def tracking_init(self, *args, **kwargs):
+        init(self, *args, **kwargs)
+        solvers.append(self)
+
+    monkeypatch.setattr(DeviceRuntimeSolver, "__init__", tracking_init)
+    yield solvers
+    assert [s.stats["device_errors"] for s in solvers] == \
+        [0] * len(solvers)
 
 
 def random_problem(rng, C=12, N=40, R=4):
@@ -210,11 +230,28 @@ class TestDeviceRuntimeSolver:
         assert max(Counter(placed).values()) <= 2
 
 
+    def test_resource_no_node_advertises_yet(self):
+        """A class demanding a resource the view has no column for (its
+        node's first report has not arrived) makes the column and solves:
+        nothing fits, nothing raises.  This used to die with an
+        IndexError inside demand_matrix that the solver's catch-all
+        counted as an ordinary fallback."""
+        from ray_tpu.scheduler.resources import ResourceRequest
+        view = self._view()
+        solver = DeviceRuntimeSolver()
+        late = self._Spec(1.0, 9105)
+        late.resources = ResourceRequest({"CPU": 1.0, "late_resource": 1.0})
+        targets = solver.solve(view, [late, self._Spec(1.0, 9106)])
+        assert targets is not None
+        assert targets[0] is None and targets[1] is not None
+        assert solver.stats["fallbacks"] == 0
+        assert "late_resource" in view.columns
+
     def test_class_eviction_bounds_demand_matrix(self):
         """Churning through many distinct scheduling classes must not
         grow the demand matrix forever: idle classes are evicted when
         growth would widen c_cap, and the solver still solves correctly
-        afterwards (VERDICT r3 weak #7)."""
+        afterwards."""
         view = self._view(n=4, cpu=64.0)
         solver = DeviceRuntimeSolver()
         solver._CLASS_IDLE_TICKS = 4   # make staleness cheap to reach
@@ -240,6 +277,37 @@ class TestDeviceRuntimeSolver:
         solver._MAX_CLASS_ROWS = 8
         specs = [self._Spec(1.0, 30000 + i) for i in range(12)]
         assert solver.solve(view, specs) is None
+        assert solver.stats["fallbacks"] == 1
+
+    def test_device_error_is_counted_apart_and_logged_once(
+            self, monkeypatch, caplog, _no_device_errors):
+        """A device/compile error returns None like an invalid
+        assignment does, but under its own counter and with one
+        traceback in the log — and the next tick retries the device
+        path (no run-time switch to another path)."""
+        from ray_tpu.scheduler import jax_backend
+        view = self._view()
+        solver = DeviceRuntimeSolver()
+        specs = [self._Spec(1.0, 9104) for _ in range(4)]
+        real = jax_backend._jit_solve_tick
+
+        def boom(*a, **k):
+            raise RuntimeError("injected mosaic failure")
+
+        monkeypatch.setattr(jax_backend, "_jit_solve_tick", boom)
+        with caplog.at_level("ERROR", logger=jax_backend.__name__):
+            assert solver.solve(view, specs) is None
+            assert solver.solve(view, specs) is None
+        assert solver.stats["device_errors"] == 2
+        assert solver.stats["fallbacks"] == 0
+        logged = [r for r in caplog.records
+                  if "device solve failed" in r.getMessage()]
+        assert len(logged) == 1 and logged[0].exc_info is not None
+        monkeypatch.setattr(jax_backend, "_jit_solve_tick", real)
+        targets = solver.solve(view, specs)
+        assert targets is not None and all(t is not None for t in targets)
+        assert solver.last_path == "single/jnp"
+        _no_device_errors.remove(solver)   # the injected errors
 
 
 class TestJaxBackendEndToEnd:
@@ -310,8 +378,8 @@ class TestPallasClassFill:
     """The fused Mosaic kernel must compute EXACTLY what the jnp scan
     path computes (it is an independent reimplementation of the
     bucket/prefix math).  Runs in Pallas interpret mode so the CPU test
-    suite covers the kernel's semantics; the TPU runtime additionally
-    falls back to jnp on any Mosaic failure."""
+    suite covers the kernel's semantics; chip_smoke.py asks the chip
+    the same question at full width."""
 
     @pytest.mark.parametrize("seed", [0, 1, 2])
     @pytest.mark.parametrize("with_cost", [False, True])

@@ -123,7 +123,7 @@ class TestCliAgainstRunningHead:
         assert "CPU" in out.stdout
 
     def test_submit_working_dir_end_to_end(self, head_daemon, tmp_path):
-        """The VERDICT acceptance line: `submit --working-dir . script.py`
+        """The acceptance line: `submit --working-dir . script.py`
         runs end-to-end against a running head."""
         wd = tmp_path / "app"
         wd.mkdir()

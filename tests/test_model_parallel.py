@@ -1,15 +1,19 @@
 """Model + parallelism tests on the virtual 8-device CPU mesh:
 ring attention vs full attention, sharded train step, graft entry."""
 
+import os
+
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 from jax.sharding import NamedSharding, PartitionSpec as P
 
+_GRAFT_ENTRY = os.path.join(os.path.dirname(os.path.abspath(__file__)),
+                            os.pardir, "__graft_entry__.py")
+
 
 def test_ring_attention_matches_full():
-    from jax.experimental.shard_map import shard_map
 
     from ray_tpu.ops.ring_attention import full_attention, ring_attention
     from ray_tpu.parallel.mesh import MeshConfig, build_mesh
@@ -22,12 +26,12 @@ def test_ring_attention_matches_full():
     v = jnp.asarray(rng.standard_normal((B, S, H, D)), jnp.float32)
 
     want = full_attention(q, k, v, causal=True)
-    fn = shard_map(
+    fn = jax.shard_map(
         lambda q, k, v: ring_attention(q, k, v, axis_name="sp", causal=True),
         mesh=mesh,
         in_specs=(P(None, "sp", None, None),) * 3,
         out_specs=P(None, "sp", None, None),
-        check_rep=False)
+        check_vma=False)
     with mesh:
         got = jax.jit(fn)(q, k, v)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
@@ -35,7 +39,6 @@ def test_ring_attention_matches_full():
 
 
 def test_ring_attention_non_causal():
-    from jax.experimental.shard_map import shard_map
 
     from ray_tpu.ops.ring_attention import full_attention, ring_attention
     from ray_tpu.parallel.mesh import MeshConfig, build_mesh
@@ -47,11 +50,11 @@ def test_ring_attention_non_causal():
     k = jnp.asarray(rng.standard_normal((B, S, H, D)), jnp.float32)
     v = jnp.asarray(rng.standard_normal((B, S, H, D)), jnp.float32)
     want = full_attention(q, k, v, causal=False)
-    fn = shard_map(
+    fn = jax.shard_map(
         lambda q, k, v: ring_attention(q, k, v, axis_name="sp",
                                        causal=False),
         mesh=mesh, in_specs=(P(None, "sp", None, None),) * 3,
-        out_specs=P(None, "sp", None, None), check_rep=False)
+        out_specs=P(None, "sp", None, None), check_vma=False)
     with mesh:
         got = jax.jit(fn)(q, k, v)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
@@ -115,7 +118,7 @@ def test_sharded_train_step_matches_single_device():
 def test_graft_entry_single_chip():
     import importlib.util
     spec = importlib.util.spec_from_file_location(
-        "graft_entry", "/root/repo/__graft_entry__.py")
+        "graft_entry", _GRAFT_ENTRY)
     mod = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(mod)
     fn, args = mod.entry()
@@ -126,7 +129,7 @@ def test_graft_entry_single_chip():
 def test_graft_entry_dryrun_multichip():
     import importlib.util
     spec = importlib.util.spec_from_file_location(
-        "graft_entry", "/root/repo/__graft_entry__.py")
+        "graft_entry", _GRAFT_ENTRY)
     mod = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(mod)
     mod.dryrun_multichip(8)

@@ -7,6 +7,27 @@ import pytest
 from ray_tpu.native.shm_store import NativeShmStore
 
 
+def test_library_is_named_by_its_source(tmp_path, monkeypatch):
+    """A tree that was copied or checked out has meaningless mtimes:
+    the built library carries a hash of the source, is built on first
+    use, and a changed source never loads the old one."""
+    import os
+
+    from ray_tpu.native import shm_store
+    src = tmp_path / "shm_store.cpp"
+    with open(shm_store._SRC, "rb") as f:
+        src.write_bytes(f.read())
+    monkeypatch.setattr(shm_store, "_SRC", str(src))
+    monkeypatch.setattr(shm_store, "_BUILD_DIR", str(tmp_path / "_build"))
+    first = shm_store._build()
+    assert os.path.exists(first) and shm_store._build() == first
+    os.utime(first, (0, 0))                      # older than the source
+    assert shm_store._build() == first
+    src.write_bytes(src.read_bytes() + b"\n// changed\n")
+    second = shm_store._build()
+    assert second != first and os.path.exists(second)
+
+
 @pytest.fixture
 def store():
     s = NativeShmStore(capacity=16 * 1024 * 1024)

@@ -632,7 +632,7 @@ class TestSchedulerTickMetrics:
 
 class TestLatencyEnvelope:
     def test_task_roundtrip_tail_latency(self, thread_cluster):
-        """Pins the magic-timeout hazards (VERDICT r4: wait()'s 200 ms
+        """Pins the magic-timeout hazards (an earlier review: wait()'s 200 ms
         coarse-poll fallback, get's fixed pull wait): if a READY
         object's get ever falls into a polling fallback, p99 blows past
         the bound.  The bound is generous for a loaded CI box; the

@@ -527,13 +527,6 @@ class TestShardedSolveParity:
     feasibility-equal otherwise (same placed totals per class is NOT
     guaranteed node-for-node — only oracle-pinned determinism is)."""
 
-    @pytest.fixture(autouse=True)
-    def _fresh_shard_state(self):
-        from ray_tpu.scheduler import sharded_solve
-        sharded_solve.reset_broken()
-        yield
-        sharded_solve.reset_broken()
-
     def _force(self, n_shards=None):
         import jax
         cfg = get_config()
@@ -647,26 +640,36 @@ class TestShardedSolveParity:
         cfg.solver_shard_backend = "off"
         assert sharded_solve.plan_shards(100_000) == 1
 
-    def test_fallback_on_shard_failure(self, monkeypatch):
-        """A sharded-solve failure marks the backend broken and the
-        same call transparently re-solves single-device — and
-        plan_shards stays 1 until reset_broken()."""
+    def test_shard_failure_is_loud(self, monkeypatch):
+        """No run-time switch hides a sharded failure: it propagates
+        from BatchSolver, and on the live tick it is counted under
+        ``device_errors`` (apart from the validated ``fallbacks``)
+        while the sharded path stays planned."""
         from ray_tpu.scheduler import sharded_solve
         rng = np.random.default_rng(9)
         self._force()
         avail, total, demand, counts, an, ac = _random_problem(rng)
-        want = waterfill_oracle(avail, total, demand, counts, an, ac,
-                                spread_threshold=0.5)
 
         def boom(*a, **k):
             raise RuntimeError("injected shard failure")
 
         monkeypatch.setattr(sharded_solve, "solve_matrices_sharded",
                             boom)
-        got = BatchSolver().solve_matrices(
-            avail, total, demand, counts, an, ac, spread_threshold=0.5)
-        np.testing.assert_array_equal(got, want)
-        assert sharded_solve.plan_shards(10_000) == 1   # pinned broken
+        with pytest.raises(RuntimeError, match="injected shard"):
+            BatchSolver().solve_matrices(
+                avail, total, demand, counts, an, ac,
+                spread_threshold=0.5)
+
+        monkeypatch.setattr(sharded_solve, "solve_tick_sharded", boom)
+        view = _view([(f"n{i}", {"CPU": 8.0}, None) for i in range(4)])
+        solver = DeviceRuntimeSolver()
+        specs = [_Spec(1.0, 7101) for _ in range(4)]
+        assert solver.solve(view, specs) is None
+        assert solver.stats["device_errors"] == 1
+        assert solver.stats["fallbacks"] == 0
+        assert sharded_solve.plan_shards(10_000) > 1   # not switched off
         monkeypatch.undo()
-        sharded_solve.reset_broken()
-        assert sharded_solve.plan_shards(10_000) > 1
+        targets = solver.solve(view, specs)
+        assert targets is not None and all(t is not None for t in targets)
+        assert solver.stats["sharded_ticks"] == 1
+        assert solver.stats["device_errors"] == 1
