@@ -100,16 +100,29 @@ def bench_dispatch_latency(n, warm=True, reset_window=True):
     cluster = global_worker().cluster
     if warm:
         ray_tpu.get([noop.remote() for _ in range(200)])
+    def settled_stages():
+        # A task's FINISHED event can trail the get() that returned its
+        # result by a flush: wait (bounded) until every stage has the
+        # same count, so a straggler neither leaks into the next window
+        # nor reads as a coverage gap in this one.
+        deadline = time.monotonic() + 5.0
+        while True:
+            stages = summarize_tasks().get("dispatch_latency", {})
+            if len({row["count"] for row in stages.values()}) <= 1 or \
+                    time.monotonic() > deadline:
+                return stages
+            time.sleep(0.01)
+
     if reset_window:
         # One concurrency level per sample window: without the reset a
         # sweep's later rows would blend the earlier levels' samples.
         # Flush first so straggling pre-reset events can't leak into
         # the fresh window and skew the per-stage counts.
-        summarize_tasks()
+        settled_stages()
         cluster.gcs.task_event_manager.reset_stage_samples()
     lease_before = dict(cluster.head_node.lease_stats)
     ray_tpu.get([noop.remote() for _ in range(n)])
-    stages = summarize_tasks().get("dispatch_latency", {})
+    stages = settled_stages()
     total = stages.get("total", {})
     ticks = cluster.head_node.cluster_task_manager.tick_stats
     lease = cluster.head_node.lease_stats

@@ -43,6 +43,12 @@ _MAX_DISPATCH_REQUEUES = 20
 _TICK_BUCKETS = (0.0005, 0.001, 0.0025, 0.005, 0.01, 0.025, 0.05,
                  0.1, 0.25, 1.0)
 
+# Lease-batch wait (queued -> swept by a tick) histogram bounds
+# (seconds): from the wakeup debounce (1 ms) up to a batch that sat
+# behind a whole multi-second tick.
+_WAIT_BUCKETS = (0.001, 0.0025, 0.005, 0.01, 0.025, 0.05, 0.1, 0.25,
+                 1.0, 5.0)
+
 
 class _LeaseBatch:
     """Collector for one batched lease request: N entries, ONE reply.
@@ -53,10 +59,13 @@ class _LeaseBatch:
     last entry lands, carrying the ordered result vector — the
     one-round-trip shape the wire protocol needs."""
 
-    __slots__ = ("results", "_remaining", "_reply", "_lock")
+    __slots__ = ("results", "queued_at", "_remaining", "_reply", "_lock")
 
     def __init__(self, n: int, reply: Callable):
         self.results: list = [None] * n
+        # Stamped once; the tick that sweeps the batch reads it for
+        # "how long did this request wait for the loop".
+        self.queued_at = time.perf_counter()
         self._remaining = n
         self._reply = reply
         from ray_tpu._private.debug import diag_lock
@@ -140,7 +149,26 @@ class ClusterTaskManager:
                            "spillbacks_locality_override": 0,
                            "jnp_fallbacks": 0,
                            "last_batch_classes": 0, "last_batch_tasks": 0,
-                           "dispatch_errors": 0}
+                           "dispatch_errors": 0,
+                           # Entries answered, by kind.  Over one
+                           # batched pass these and ``spillbacks`` sum
+                           # to ``last_batch_tasks``: granted_local =
+                           # handed to the local dispatch path;
+                           # requeued_busy = no node this tick, some
+                           # node's total fits; parked_infeasible = no
+                           # node's total fits; spill_refused = the
+                           # solve's node failed validation against the
+                           # exact vectors (``is_feasible`` /
+                           # ``view.subtract``) — solve attempts that
+                           # were not useful outcomes;
+                           # requeued_dispatch_failed = the local
+                           # handoff raised.  (The greedy pass leaves a
+                           # busy entry queued without touching it, so
+                           # it counts only the kinds it answers.)
+                           "granted_local": 0, "requeued_busy": 0,
+                           "parked_infeasible": 0, "spill_refused": 0,
+                           "requeued_dispatch_failed": 0,
+                           "is_feasible_anywhere_calls": 0}
         # Consecutive failed dispatch handoffs per task (cleared on
         # success): past _MAX_DISPATCH_REQUEUES the lease is rejected.
         self._dispatch_failures: Dict = {}
@@ -160,6 +188,8 @@ class ClusterTaskManager:
             # instead of leaking per-node cardinality under churn.
             get_metrics_registry().claim_series(
                 "ray_tpu.scheduler.tick_latency", **label)
+            get_metrics_registry().claim_series(
+                "ray_tpu.scheduler.lease_batch_wait", **label)
         get_metrics_registry().register_collector(self, _collect)
 
     # ---- entry (HandleRequestWorkerLease -> QueueAndScheduleTask) -------
@@ -259,10 +289,23 @@ class ClusterTaskManager:
             sweep, self._pending_batches = self._pending_batches, []
         depth = self._total_queued()
         t0 = time.perf_counter()
+        # Lease wait: queued -> swept, one observation per batch.  It is
+        # what neither the submitter's clock around a whole round nor
+        # the tick's own latency can see, and it shows a burst that the
+        # wakeup debounce split over two ticks.
+        oldest_wait = 0.0
+        for batch in sweep:
+            wait = t0 - batch.queued_at
+            oldest_wait = max(oldest_wait, wait)
+            observe_internal("ray_tpu.scheduler.lease_batch_wait", wait,
+                             buckets=_WAIT_BUCKETS, node=self._node_label)
+        oldest_wait_ms = round(oldest_wait * 1000.0, 3)
         # One span per WORKING tick (idle ticks fire every
         # event_loop_tick_ms — tracing them would bury the timeline).
         span = tracing.span("scheduler.tick", category="sched",
-                            node=self._node_label, queued=depth) \
+                            node=self._node_label, queued=depth,
+                            swept_batches=len(sweep),
+                            oldest_lease_wait_ms=oldest_wait_ms) \
             if depth else None
         try:
             if span is not None:
@@ -278,7 +321,9 @@ class ClusterTaskManager:
         finally:
             # Even when the pass raised: an unreplied batch entry left
             # queued would defer the whole batch reply indefinitely.
-            self._resolve_batch_backlog(sweep)
+            if sweep:
+                with tracing.span("scheduler.backlog", category="sched"):
+                    self._resolve_batch_backlog(sweep)
             if span is not None:
                 span.__exit__(None, None, None)
             dt = time.perf_counter() - t0
@@ -298,7 +343,17 @@ class ClusterTaskManager:
                     locality_override=ts[
                         "spillbacks_locality_override"],
                     jnp_fallbacks=ts["jnp_fallbacks"],
-                    dispatch_errors=ts["dispatch_errors"])
+                    dispatch_errors=ts["dispatch_errors"],
+                    granted_local=ts["granted_local"],
+                    requeued_busy=ts["requeued_busy"],
+                    parked_infeasible=ts["parked_infeasible"],
+                    spill_refused=ts["spill_refused"],
+                    requeued_dispatch_failed=ts[
+                        "requeued_dispatch_failed"],
+                    is_feasible_anywhere_calls=ts[
+                        "is_feasible_anywhere_calls"],
+                    swept_batches=len(sweep),
+                    oldest_lease_wait_ms=oldest_wait_ms)
                 # Working ticks only (same gate as the span): idle
                 # no-op ticks fire every event_loop_tick_ms and their
                 # microsecond latencies would drown the signal the
@@ -371,10 +426,6 @@ class ClusterTaskManager:
         try:
             self.tick_stats["spillbacks"] += 1
             self.tick_stats[f"spillbacks_{reason}"] += 1
-            flight_recorder.record(
-                "sched.spillback", node=self._node_label,
-                task=spec.task_id.hex()[:12], reason=reason,
-                target=getattr(target, "hex", lambda: str(target))()[:12])
             reply({"retry_at": target})
         except Exception:
             self.tick_stats["dispatch_errors"] += 1
@@ -466,6 +517,7 @@ class ClusterTaskManager:
                                     self._queues[cls][0][0] is spec:
                                 self._queues[cls].popleft()
                                 self._infeasible[cls].append((spec, reply))
+                                self.tick_stats["parked_infeasible"] += 1
                         progress = True
                         continue
                     if target == local_id:
@@ -483,9 +535,13 @@ class ClusterTaskManager:
                                 view.add_back(local_id, spec.resources)
                                 continue
                             self._queues[cls].popleft()
-                        if not self._dispatch_local(spec, reply):
+                        if self._dispatch_local(spec, reply):
+                            self.tick_stats["granted_local"] += 1
+                        else:
                             view.add_back(local_id, spec.resources)
                             self._requeue(spec, reply)
+                            self.tick_stats[
+                                "requeued_dispatch_failed"] += 1
                         progress = True
                     else:
                         if not view.subtract(target, spec.resources):
@@ -540,12 +596,14 @@ class ClusterTaskManager:
         re-validated against the exact fixed-point vectors.
         """
         from ray_tpu.scheduler import jax_backend
+        from ray_tpu.util import tracing
         if self._jax_solver is None:
             self._jax_solver = jax_backend.DeviceRuntimeSolver(
                 node_label=self._raylet.node_id.hex()[:12],
                 locality_provider=self._arg_locality_bytes)
         view = self._raylet.cluster_view
-        with self._lock:
+        with tracing.span("scheduler.collect", category="sched"), \
+                self._lock:
             work: list = []
             for cls, q in self._queues.items():
                 work.extend(q)
@@ -556,8 +614,9 @@ class ClusterTaskManager:
         self.tick_stats["last_batch_classes"] = len(
             {spec.scheduling_class for spec, _ in work})
         try:
-            assignments = self._jax_solver.solve(
-                view, [spec for spec, _ in work])
+            with tracing.span("scheduler.solve", category="sched"):
+                assignments = self._jax_solver.solve(
+                    view, [spec for spec, _ in work])
         except Exception:
             # The solver guards its device path internally, but the
             # whole batch was already POPPED — any escaped exception
@@ -571,6 +630,17 @@ class ClusterTaskManager:
                 for spec, reply in work:
                     self._queues[spec.scheduling_class].append((spec, reply))
             return False
+        with tracing.span("scheduler.reply", category="sched"):
+            self._reply_batch(view, work, assignments)
+        return True
+
+    def _reply_batch(self, view, work, assignments) -> None:
+        """Answer every entry of a solved batch: validate against the
+        exact vectors, then dispatch, spill or requeue.  100,000 entries
+        a tick at the north-star scale, so there is no span and no
+        flight record per entry: the kinds are counted in locals and
+        folded into ``tick_stats`` once."""
+        granted = busy = infeasible = refused = failed = anywhere = 0
         local_id = self._raylet.node_id
         # LOCAL grants commit first (view.subtract), remote spills after:
         # _spillback_reason checks "could the local node still run this
@@ -591,22 +661,29 @@ class ClusterTaskManager:
                 # unrelated broadcast rescues it (or forever).
                 feasible_somewhere = view.is_feasible_anywhere(
                     spec.resources)
+                anywhere += 1
                 with self._lock:
                     if feasible_somewhere:
                         self._queues[spec.scheduling_class].append(
                             (spec, reply))
+                        busy += 1
                     else:
                         self._infeasible[spec.scheduling_class].append(
                             (spec, reply))
+                        infeasible += 1
             elif target == local_id:
                 if not view.subtract(local_id, spec.resources):
                     with self._lock:
                         self._queues[spec.scheduling_class].append(
                             (spec, reply))
+                    refused += 1
                     continue
-                if not self._dispatch_local(spec, reply):
+                if self._dispatch_local(spec, reply):
+                    granted += 1
+                else:
                     view.add_back(local_id, spec.resources)
                     self._requeue(spec, reply)
+                    failed += 1
             else:
                 # Validate against the exact vectors before committing the
                 # spill (kernel output validated by IsSchedulable,
@@ -621,7 +698,14 @@ class ClusterTaskManager:
                     with self._lock:
                         self._queues[spec.scheduling_class].append(
                             (spec, reply))
-        return True
+                    refused += 1
+        ts = self.tick_stats
+        ts["granted_local"] += granted
+        ts["requeued_busy"] += busy
+        ts["parked_infeasible"] += infeasible
+        ts["spill_refused"] += refused
+        ts["requeued_dispatch_failed"] += failed
+        ts["is_feasible_anywhere_calls"] += anywhere
 
     # ---- introspection --------------------------------------------------
     def num_queued(self) -> int:

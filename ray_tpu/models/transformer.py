@@ -27,6 +27,7 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 
 from ray_tpu.ops.flash_attention import attention as flash_or_ref_attention
 from ray_tpu.ops.ring_attention import ring_attention
+from ray_tpu.util import tracing
 
 
 @dataclasses.dataclass(frozen=True)
@@ -163,26 +164,30 @@ def apply_layer(x, lp, positions, cfg: TransformerConfig, mesh=None):
     """One transformer block on [B, S, D] activations with this
     layer's params ``lp``; returns (x, moe_aux).  Shared by the scan
     forward and the pipeline-parallel stage executor."""
-    h = _rms_norm(x, lp["ln1"])
-    q = jnp.einsum("bsd,dhk->bshk", h, lp["wq"])
-    k = jnp.einsum("bsd,dhk->bshk", h, lp["wk"])
-    v = jnp.einsum("bsd,dhk->bshk", h, lp["wv"])
-    q = _rope(q, positions, cfg.rope_theta)
-    k = _rope(k, positions, cfg.rope_theta)
-    o = _attention_core(q, k, v, mesh, cfg)
-    x = x + jnp.einsum("bshk,hkd->bsd", o, lp["wo"])
-    h = _rms_norm(x, lp["ln2"])
+    # The named scopes here and in loss_fn / train_step are metadata
+    # only: stable names for a device trace to group time by.
+    with jax.named_scope("attention"):
+        h = _rms_norm(x, lp["ln1"])
+        q = jnp.einsum("bsd,dhk->bshk", h, lp["wq"])
+        k = jnp.einsum("bsd,dhk->bshk", h, lp["wk"])
+        v = jnp.einsum("bsd,dhk->bshk", h, lp["wv"])
+        q = _rope(q, positions, cfg.rope_theta)
+        k = _rope(k, positions, cfg.rope_theta)
+        o = _attention_core(q, k, v, mesh, cfg)
+        x = x + jnp.einsum("bshk,hkd->bsd", o, lp["wo"])
     aux = jnp.zeros((), jnp.float32)
-    if cfg.moe_experts > 0:
-        from ray_tpu.models.moe import aux_load_balance_loss, moe_ffn
-        x = x + moe_ffn(h, lp["moe"], cfg.moe_experts,
-                        cfg.moe_capacity_factor, mesh)
-        aux = aux_load_balance_loss(h, lp["moe"]["wr"],
-                                    cfg.moe_experts)
-    else:
-        gate = jax.nn.silu(jnp.einsum("bsd,df->bsf", h, lp["w1"]))
-        up = jnp.einsum("bsd,df->bsf", h, lp["w3"])
-        x = x + jnp.einsum("bsf,fd->bsd", gate * up, lp["w2"])
+    with jax.named_scope("ffn"):
+        h = _rms_norm(x, lp["ln2"])
+        if cfg.moe_experts > 0:
+            from ray_tpu.models.moe import aux_load_balance_loss, moe_ffn
+            x = x + moe_ffn(h, lp["moe"], cfg.moe_experts,
+                            cfg.moe_capacity_factor, mesh)
+            aux = aux_load_balance_loss(h, lp["moe"]["wr"],
+                                        cfg.moe_experts)
+        else:
+            gate = jax.nn.silu(jnp.einsum("bsd,df->bsf", h, lp["w1"]))
+            up = jnp.einsum("bsd,df->bsf", h, lp["w3"])
+            x = x + jnp.einsum("bsf,fd->bsd", gate * up, lp["w2"])
     if mesh is not None:
         x = jax.lax.with_sharding_constraint(
             x, NamedSharding(mesh, P("dp", "sp", None)))
@@ -216,8 +221,9 @@ def forward_with_aux(params: Dict, tokens: jax.Array,
     (x, aux), _ = jax.lax.scan(lambda c, lp: layer_fn(c, lp),
                                (x, jnp.zeros((), jnp.float32)),
                                params["layers"])
-    x = _rms_norm(x, params["ln_f"])
-    logits = jnp.einsum("bsd,dv->bsv", x, params["lm_head"])
+    with jax.named_scope("head_loss"):
+        x = _rms_norm(x, params["ln_f"])
+        logits = jnp.einsum("bsd,dv->bsv", x, params["lm_head"])
     return logits, aux / max(1, cfg.n_layers)
 
 
@@ -230,11 +236,12 @@ def loss_fn(params: Dict, batch: Dict, cfg: TransformerConfig,
     tokens = batch["tokens"]
     inputs, targets = tokens[:, :-1], tokens[:, 1:]
     logits, aux = forward_with_aux(params, inputs, cfg, mesh)
-    logits = logits.astype(jnp.float32)
-    logz = jax.nn.logsumexp(logits, axis=-1)
-    gold = jnp.take_along_axis(logits, targets[..., None],
-                               axis=-1).squeeze(-1)
-    loss = jnp.mean(logz - gold)
+    with jax.named_scope("head_loss"):
+        logits = logits.astype(jnp.float32)
+        logz = jax.nn.logsumexp(logits, axis=-1)
+        gold = jnp.take_along_axis(logits, targets[..., None],
+                                   axis=-1).squeeze(-1)
+        loss = jnp.mean(logz - gold)
     if cfg.moe_experts > 0 and cfg.moe_aux_coeff > 0:
         loss = loss + cfg.moe_aux_coeff * aux
     return loss
@@ -290,10 +297,12 @@ def make_train_step(cfg: TransformerConfig, tx, mesh=None,
             lambda p, b: loss_fn(p, b, cfg, mesh))
         loss, grads = jax.value_and_grad(
             lambda p: compute(p, batch))(state["params"])
-        updates, new_opt = tx.update(grads, state["opt"], state["params"])
-        new_params = jax.tree.map(
-            lambda p, u: (p.astype(jnp.float32) + u).astype(p.dtype),
-            state["params"], updates)
+        with jax.named_scope("optimizer"):
+            updates, new_opt = tx.update(grads, state["opt"],
+                                         state["params"])
+            new_params = jax.tree.map(
+                lambda p, u: (p.astype(jnp.float32) + u).astype(p.dtype),
+                state["params"], updates)
         new_state = {"params": new_params, "opt": new_opt,
                      "step": state["step"] + 1}
         metrics = {"loss": loss,
@@ -301,7 +310,25 @@ def make_train_step(cfg: TransformerConfig, tx, mesh=None,
         return new_state, metrics
 
     donate = (0,)
-    return jax.jit(train_step, donate_argnums=donate)
+    return _TracedStep(jax.jit(train_step, donate_argnums=donate))
+
+
+class _TracedStep:
+    """The jitted ``train_step``, each call under a ``train.model_step``
+    span: the host's dispatch of the step (argument flattening, cache
+    lookup, donation, launch — it returns before the device finishes),
+    i.e. the train worker's own cost per step.  Everything else of the
+    jitted function (``.lower``, ``.trace``, ...) is reached through."""
+
+    def __init__(self, jitted):
+        self._jitted = jitted
+
+    def __call__(self, state, batch):
+        with tracing.span("train.model_step", category="train"):
+            return self._jitted(state, batch)
+
+    def __getattr__(self, name):
+        return getattr(self._jitted, name)
 
 
 def optax_global_norm(tree) -> jax.Array:

@@ -99,6 +99,7 @@ def _flash_forward(qh, kh, vh, causal, block_q, block_k, interpret):
     return out, lse[:, :, 0]
 
 
+@jax.named_scope("flash_attention_bwd")
 def _flash_backward(qh, kh, vh, out, lse, dout, causal, block_k):
     """Blockwise jnp backward over K/V blocks (scan): per block, the
     probabilities are recomputed from the saved log-sum-exp, so peak

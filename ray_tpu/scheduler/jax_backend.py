@@ -381,6 +381,9 @@ def _pallas_class_fill(c_pad: int, n_pad: int, r_pad: int,
             jax.ShapeDtypeStruct((c_pad, 1, n_pad), jnp.float32),
         ],
         interpret=interpret,
+        # The kernel's event in a device trace (else the enclosing
+        # function's name, ``solve``, which every program here shares).
+        name="scheduler_class_fill",
     )
 
     def fill(av_t, total_t, demand, counts, accel_class, accel_node,
@@ -436,8 +439,10 @@ def _class_fill(av_t, total_t, demand, counts, accel_class, accel_node,
                                  cost_row, invert, accel_node, empty,
                                  spread_threshold)
 
-    av_after, allocs = jax.lax.scan(
-        body, av_t, (demand, counts, accel_class, shifts, cost), unroll=8)
+    with jax.named_scope("scheduler_class_fill"):
+        av_after, allocs = jax.lax.scan(
+            body, av_t, (demand, counts, accel_class, shifts, cost),
+            unroll=8)
     return av_after, allocs
 
 
@@ -450,27 +455,29 @@ def _pack_tick(allocs, counts_k, av_pre, demand, nnz_max):
     which replaced the earlier rank-cumsum + searchsorted formulation
     (21 binary-search steps of 32k gathers each dominated the tick).
     """
+    import jax
     import jax.numpy as jnp
 
-    flat_n = allocs.shape[0] * allocs.shape[1]
-    usage = jnp.einsum("cn,cr->rn", allocs, demand)
-    ok_cap = jnp.all(usage <= av_pre + 1e-2)
-    placed_c = jnp.sum(allocs, axis=1)                     # [C]
-    ok_cnt = jnp.all(placed_c <= counts_k + 0.5)
-    placed = jnp.sum(placed_c)
-    flat = allocs.reshape(flat_n)
-    nz = flat > 0
-    nnz = jnp.sum(nz.astype(jnp.int32))
-    (pos,) = jnp.nonzero(nz, size=nnz_max, fill_value=flat_n)
-    live = jnp.arange(nnz_max) < nnz
-    posc = jnp.minimum(pos, flat_n - 1)
-    idx = jnp.where(live, posc, flat_n)
-    vals = jnp.where(live, flat[posc], 0.0)
-    ok = ok_cap & ok_cnt & (nnz <= nnz_max)
-    packed = jnp.concatenate([
-        idx.astype(jnp.float32), vals,
-        jnp.stack([placed, ok.astype(jnp.float32),
-                   nnz.astype(jnp.float32)])])
+    with jax.named_scope("scheduler_pack_tick"):
+        flat_n = allocs.shape[0] * allocs.shape[1]
+        usage = jnp.einsum("cn,cr->rn", allocs, demand)
+        ok_cap = jnp.all(usage <= av_pre + 1e-2)
+        placed_c = jnp.sum(allocs, axis=1)                     # [C]
+        ok_cnt = jnp.all(placed_c <= counts_k + 0.5)
+        placed = jnp.sum(placed_c)
+        flat = allocs.reshape(flat_n)
+        nz = flat > 0
+        nnz = jnp.sum(nz.astype(jnp.int32))
+        (pos,) = jnp.nonzero(nz, size=nnz_max, fill_value=flat_n)
+        live = jnp.arange(nnz_max) < nnz
+        posc = jnp.minimum(pos, flat_n - 1)
+        idx = jnp.where(live, posc, flat_n)
+        vals = jnp.where(live, flat[posc], 0.0)
+        ok = ok_cap & ok_cnt & (nnz <= nnz_max)
+        packed = jnp.concatenate([
+            idx.astype(jnp.float32), vals,
+            jnp.stack([placed, ok.astype(jnp.float32),
+                       nnz.astype(jnp.float32)])])
     return packed, placed_c
 
 
@@ -1215,7 +1222,8 @@ class DeviceRuntimeSolver:
         self.stats = {"ticks": 0, "full_syncs": 0, "row_deltas": 0,
                       "fallbacks": 0, "class_evictions": 0,
                       "cost_ticks": 0, "sharded_ticks": 0,
-                      "device_errors": 0}
+                      "device_errors": 0, "solve_programs": 0}
+        self._programs: set = set()   # (c_cap, n_pad, r_pad, nnz, path)
         from ray_tpu._private.metrics_agent import (get_metrics_registry,
                                                     record_internal)
         # Label by owning node: one solver per raylet, and unlabeled
@@ -1287,106 +1295,134 @@ class DeviceRuntimeSolver:
 
     # -- internals -------------------------------------------------------
     def _solve_groups(self, view, specs, groups, targets) -> bool:
+        # The spans below split the tick from inside (children of the
+        # raylet's ``scheduler.solve``): a traced window attributes the
+        # device's idle gaps to them.  Sharded, ``dispatch`` also holds
+        # the wait and the download (``solve_tick_sharded`` returns host
+        # arrays) and ``fetch`` is empty.
+        from ray_tpu.util import tracing
         self.stats["ticks"] += 1
-        ver, dirty_idx, dirty_rows = view.drain_dirty()
-        st = self._state
-        if (st is None or ver != st["version"]
-                or view.num_nodes() > st["n_pad"]
-                or view.num_columns() > st["r_pad"]):
-            self._full_sync(view)
+        with tracing.span("scheduler.solve.sync", category="sched"):
+            ver, dirty_idx, dirty_rows = view.drain_dirty()
             st = self._state
-        elif dirty_idx:
-            self._apply_deltas(dirty_idx, dirty_rows)
+            if (st is None or ver != st["version"]
+                    or view.num_nodes() > st["n_pad"]
+                    or view.num_columns() > st["r_pad"]):
+                self._full_sync(view)
+                st = self._state
+            elif dirty_idx:
+                self._apply_deltas(dirty_idx, dirty_rows)
         if st is None or not st["node_ids"]:
             return False
-        # Register any new scheduling classes (rare: classes are interned
-        # resource shapes).  A class demanding an unknown resource column
-        # forces the column into the view (version bump -> full resync).
-        tick = self.stats["ticks"]
-        for cls in groups:
-            self._class_last_used[cls] = tick
-        new_classes = [c for c in groups if c not in self._class_rows]
-        if new_classes and (len(self._class_reqs) + len(new_classes)
-                            > self._demand_host.shape[0]):
-            # Growth would widen c_cap (a recompile): first try to
-            # reclaim rows from classes that have gone idle.
-            self._evict_stale_classes(set(groups), st)
-            if (len(self._class_reqs) + len(new_classes)
-                    > self._MAX_CLASS_ROWS):
-                # Over the hard cap even after stale eviction: churn
-                # interned >4096 classes inside the idle window.  Evict
-                # LRU rows regardless of idleness — only the classes
-                # live THIS tick are protected — before giving up.
-                self._evict_stale_classes(set(groups), st, force_lru=True)
-            if (len(self._class_reqs) + len(new_classes)
-                    > self._MAX_CLASS_ROWS):
+        with tracing.span("scheduler.solve.classes", category="sched"):
+            # Register any new scheduling classes (rare: classes are
+            # interned resource shapes).  A class demanding an unknown
+            # resource column forces the column into the view (version
+            # bump -> full resync).
+            tick = self.stats["ticks"]
+            for cls in groups:
+                self._class_last_used[cls] = tick
+            new_classes = [c for c in groups if c not in self._class_rows]
+            if new_classes and (len(self._class_reqs) + len(new_classes)
+                                > self._demand_host.shape[0]):
+                # Growth would widen c_cap (a recompile): first try to
+                # reclaim rows from classes that have gone idle.
+                self._evict_stale_classes(set(groups), st)
+                if (len(self._class_reqs) + len(new_classes)
+                        > self._MAX_CLASS_ROWS):
+                    # Over the hard cap even after stale eviction: churn
+                    # interned >4096 classes inside the idle window.
+                    # Evict LRU rows regardless of idleness — only the
+                    # classes live THIS tick are protected — before
+                    # giving up.
+                    self._evict_stale_classes(set(groups), st,
+                                              force_lru=True)
+                if (len(self._class_reqs) + len(new_classes)
+                        > self._MAX_CLASS_ROWS):
+                    return False
+            for cls, members in groups.items():
+                if cls not in self._class_rows:
+                    req = specs[members[0]].resources
+                    if any(name not in st["columns"]
+                           for name in req.names()):
+                        view.demand_matrix([req])   # creates columns
+                        self._full_sync(view)
+                        st = self._state
+                    self._register_class(cls, req, st)
+            c_cap = self._demand_host.shape[0]
+            counts = np.zeros(c_cap, dtype=np.float32)
+            for cls, members in groups.items():
+                counts[self._class_rows[cls]] = len(members)
+            total_q = int(counts.sum())
+            nnz_bound = min(total_q, len(groups) * len(st["node_ids"]))
+            nnz_max = next(
+                (b for b in self._NNZ_BUCKETS if b >= nnz_bound), None)
+            if nnz_max is None:
                 return False
-        for cls, members in groups.items():
-            if cls not in self._class_rows:
-                req = specs[members[0]].resources
-                if any(name not in st["columns"] for name in req.names()):
-                    view.demand_matrix([req])   # creates columns
-                    self._full_sync(view)
-                    st = self._state
-                self._register_class(cls, req, st)
-        c_cap = self._demand_host.shape[0]
-        counts = np.zeros(c_cap, dtype=np.float32)
-        for cls, members in groups.items():
-            counts[self._class_rows[cls]] = len(members)
-        total_q = int(counts.sum())
-        nnz_bound = min(total_q, len(groups) * len(st["node_ids"]))
-        nnz_max = next((b for b in self._NNZ_BUCKETS if b >= nnz_bound),
-                       None)
-        if nnz_max is None:
-            return False
-        cfg = get_config()
-        cost = self._build_cost(specs, groups, st, c_cap, cfg)
+            cfg = get_config()
+            cost = self._build_cost(specs, groups, st, c_cap, cfg)
         n_pad = st["n_pad"]
         if st.get("n_shards", 1) > 1:
             # Pod-sharded tick: every shard solves its node block
             # against the resident sharded world state.
             from ray_tpu.scheduler import sharded_solve
             self.last_path = f"sharded[{st['n_shards']}]/jnp"
-            merged = sharded_solve.solve_tick_sharded(
-                st["avail_t"], st["total_t"], self._demand_dev,
-                counts, st["accel_node"], self._accel_dev,
-                cfg.scheduler_spread_threshold, cost, c_cap, n_pad,
-                st["r_pad"], nnz_max, st["n_shards"])
+            self._note_program(c_cap, n_pad, st["r_pad"], nnz_max,
+                               self.last_path)
+            with tracing.span("scheduler.solve.dispatch", category="sched"):
+                merged = sharded_solve.solve_tick_sharded(
+                    st["avail_t"], st["total_t"], self._demand_dev,
+                    counts, st["accel_node"], self._accel_dev,
+                    cfg.scheduler_spread_threshold, cost, c_cap, n_pad,
+                    st["r_pad"], nnz_max, st["n_shards"])
             self.stats["sharded_ticks"] += 1
             if not merged["ok"]:
                 return False
             idx, vals = merged["idx"], merged["vals"]
-            live = idx < c_cap * n_pad
-            idx, vals = idx[live], vals[live]
         else:
             use_pallas = _pallas_enabled()
             self.last_path = f"single/{_fill_name(use_pallas)}"
-            packed = np.asarray(_jit_solve_tick(
-                c_cap, st["n_pad"], st["r_pad"], nnz_max, use_pallas)(
-                    st["avail_t"], st["total_t"], self._demand_dev,
-                    counts, st["accel_node"], self._accel_dev,
-                    np.float32(cfg.scheduler_spread_threshold), cost))
+            self._note_program(c_cap, n_pad, st["r_pad"], nnz_max,
+                               self.last_path)
+            with tracing.span("scheduler.solve.dispatch", category="sched"):
+                # Upload of counts/cost, lookup or compile of the
+                # program, launch: returns before the device finishes.
+                packed = _jit_solve_tick(
+                    c_cap, n_pad, st["r_pad"], nnz_max, use_pallas)(
+                        st["avail_t"], st["total_t"], self._demand_dev,
+                        counts, st["accel_node"], self._accel_dev,
+                        np.float32(cfg.scheduler_spread_threshold), cost)
+            with tracing.span("scheduler.solve.fetch", category="sched"):
+                packed = np.asarray(packed)
             ok = packed[2 * nnz_max + 1] > 0.5
             if not ok:
                 return False
-            # Decode the sparse assignment and expand per-spec targets.
             idx = np.rint(packed[:nnz_max]).astype(np.int64)
             vals = packed[nnz_max:2 * nnz_max]
+        with tracing.span("scheduler.solve.expand", category="sched"):
+            # Decode the sparse assignment and expand per-spec targets.
             live = idx < c_cap * n_pad
             idx, vals = idx[live], vals[live]
-        alloc = np.zeros((c_cap, n_pad), dtype=np.int64)
-        alloc.reshape(-1)[idx] = np.rint(vals).astype(np.int64)
-        node_ids = st["node_ids"]
-        n_real = len(node_ids)
-        for cls, members in groups.items():
-            row = alloc[self._class_rows[cls]]
-            k = 0
-            for n in range(n_real):
-                for _ in range(int(row[n])):
-                    if k < len(members):
-                        targets[members[k]] = node_ids[n]
-                        k += 1
+            alloc = np.zeros((c_cap, n_pad), dtype=np.int64)
+            alloc.reshape(-1)[idx] = np.rint(vals).astype(np.int64)
+            node_ids = st["node_ids"]
+            n_real = len(node_ids)
+            for cls, members in groups.items():
+                row = alloc[self._class_rows[cls]]
+                k = 0
+                for n in range(n_real):
+                    for _ in range(int(row[n])):
+                        if k < len(members):
+                            targets[members[k]] = node_ids[n]
+                            k += 1
         return True
+
+    def _note_program(self, *key) -> None:
+        """Count the distinct solve programs this session has asked for
+        (``stats["solve_programs"]``): a step in it inside a measured
+        window is a compile, or a cache load, on the raylet's loop."""
+        self._programs.add(key)
+        self.stats["solve_programs"] = len(self._programs)
 
     def _build_cost(self, specs, groups, st, c_cap: int, cfg):
         """Per-(class, node) cost matrix for this tick, or the cached

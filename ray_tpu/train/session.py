@@ -15,6 +15,8 @@ import queue
 import threading
 from typing import Any, Dict, Optional
 
+from ray_tpu.util import tracing
+
 
 class TrainingResult:
     __slots__ = ("type", "data")
@@ -62,11 +64,16 @@ class Session:
                                         name=f"train-{self.world_rank}")
         self._thread.start()
 
+    # ``train.report`` spans: the worker's side of the hand-off to the
+    # Trainer, on the training thread between two steps.
     def report(self, **metrics):
-        self._queue.put(TrainingResult("report", dict(metrics)))
+        with tracing.span("train.report", category="train"):
+            self._queue.put(TrainingResult("report", dict(metrics)))
 
     def save_checkpoint(self, **checkpoint):
-        self._queue.put(TrainingResult("checkpoint", dict(checkpoint)))
+        with tracing.span("train.report", category="train",
+                          kind="checkpoint"):
+            self._queue.put(TrainingResult("checkpoint", dict(checkpoint)))
 
     # ---- driver side (via actor RPC) ------------------------------------
     def get_next(self, timeout: float = 300.0) -> TrainingResult:

@@ -11,11 +11,26 @@ dumped as chrome://tracing JSON via ``ray.timeline()``
 Workers in other OS processes record spans locally and piggyback them on
 task replies (``drain``/``ingest``), the in-process analogue of the
 reference's ProfileEvent batching to GCS.
+
+One span API, two sinks, two clocks:
+
+* the ring (``enable()`` / ``force``): ``time.time()`` stamps, trace and
+  parent ids, metadata — what ``ray_tpu.timeline()`` and the
+  cross-process ``drain``/``ingest`` read;
+* the XLA profiler's host plane: in a process where ``jax`` is already
+  imported every span also opens a ``jax.profiler.TraceAnnotation``
+  under its constant name, so a ``jax.profiler.start_trace`` anywhere in
+  the process finds the program's spans on the clock of the device
+  events.  The profiler drops the annotation unless a session is live,
+  so this sink has no switch and is independent of ``enable()``.  This
+  module never imports ``jax`` itself: worker children, the GCS and
+  CPU-only drivers do not pay for it.
 """
 
 from __future__ import annotations
 
 import os
+import sys
 import threading
 import time
 import uuid
@@ -83,6 +98,20 @@ def _append_locked(event: dict) -> None:
     _events.append(event)
 
 
+_trace_annotation = None    # jax.profiler.TraceAnnotation, once found
+
+
+def _profiler_annotation():
+    """``jax.profiler.TraceAnnotation`` if some other module has imported
+    ``jax`` by now, else None (a half-imported ``jax`` reads as None
+    too and is looked up again by the next span)."""
+    global _trace_annotation
+    if _trace_annotation is None:
+        profiler = getattr(sys.modules.get("jax"), "profiler", None)
+        _trace_annotation = getattr(profiler, "TraceAnnotation", None)
+    return _trace_annotation
+
+
 def current_context() -> Optional[Dict]:
     """The innermost active span's propagatable context, if any."""
     stack = getattr(_tls, "stack", None)
@@ -97,7 +126,9 @@ class span:
     innermost active span is the parent.  ``force`` records the span
     even when process-wide capture is off — executors use it so a
     traced task from a remote driver is captured in a worker process
-    that never called :func:`enable`.
+    that never called :func:`enable`.  Neither gates the profiler sink
+    (module docstring), which gets the name alone: the trace's
+    reduction keys on it, so ``meta`` goes to the ring only.
     """
 
     def __init__(self, name: str, category: str = "task",
@@ -110,6 +141,7 @@ class span:
         self._force = force
         self._parent = parent
         self._ctx: Optional[Dict] = None
+        self._annotation = None
 
     @property
     def active(self) -> bool:
@@ -120,6 +152,10 @@ class span:
         return dict(self._ctx) if self._ctx else None
 
     def __enter__(self):
+        annotation = _profiler_annotation()
+        if annotation is not None:
+            self._annotation = annotation(self.name)
+            self._annotation.__enter__()
         if not self.active:
             return self
         self.t0 = time.time()
@@ -136,6 +172,9 @@ class span:
         return self
 
     def __exit__(self, *exc):
+        if self._annotation is not None:
+            self._annotation.__exit__(None, None, None)
+            self._annotation = None
         if self._ctx is None:
             return
         stack = getattr(_tls, "stack", None)

@@ -3,6 +3,8 @@ invariants, end-to-end scheduler_backend=jax (runs on the virtual CPU
 mesh in CI; the same code path runs on the real chip in
 chip_smoke.py)."""
 
+import time
+
 import numpy as np
 import pytest
 
@@ -325,6 +327,12 @@ class TestJaxBackendEndToEnd:
 
             @ray_tpu.remote
             def f(i):
+                # Hold the worker a moment: with a no-op body the first
+                # leased workers can drain the whole burst by reuse, the
+                # submitter then never asks for a lease BATCH, no tick
+                # sees two queued entries, and the session legitimately
+                # never engages (a third of the runs on a quiet box).
+                time.sleep(0.02)
                 return i + 1
 
             for _ in range(3):
