@@ -34,6 +34,7 @@ Wall-clock figures printed before it are set-up facts, not benchmark
 results.
 """
 
+import functools
 import json
 import os
 import sys
@@ -309,8 +310,10 @@ def _train_func(config: dict) -> dict:
         losses.append(float(metrics["loss"]))
         train.report(step=i, loss=losses[-1])
     leaf = jax.tree.leaves(state["params"])[0]
+    text = compiled.as_text()
     return {"losses": losses,
-            "mosaic_in_step": "tpu_custom_call" in compiled.as_text(),
+            "mosaic_in_step": "tpu_custom_call" in text,
+            "mosaic_bwd_in_step": "flash_attention_bwd" in text,
             "param_platforms": sorted({d.platform for d in leaf.devices()}),
             "n_params": sum(int(np.prod(x.shape))
                             for x in jax.tree.leaves(state["params"]))}
@@ -321,9 +324,10 @@ def leg_trainer(platform: str = "tpu", model: dict = None, batch: int = None,
                 flash_tol: float = 4e-2) -> dict:
     """Trainer(backend="jax", num_workers=1, use_tpu=True) on a head that
     advertises the chip; a repeated batch, so the loss must fall.  On the
-    chip the compiled step must contain the Mosaic flash kernel, and the
-    kernel must agree with ``full_attention`` (off the chip the kernel is
-    checked in interpret mode and ``attention()`` takes the reference)."""
+    chip the compiled step must contain the Mosaic flash kernels, forward
+    and backward, and both must agree with ``full_attention`` and its
+    ``jax.grad`` (off the chip the kernels are checked in interpret mode
+    and ``attention()`` takes the reference)."""
     import jax
     import jax.numpy as jnp
 
@@ -340,16 +344,36 @@ def leg_trainer(platform: str = "tpu", model: dict = None, batch: int = None,
 
     # Kernel vs reference at the model's attention shape.
     heads, head_dim = model["n_heads"], model["d_model"] // model["n_heads"]
-    q, k, v = (jax.random.normal(key, (batch, seq, heads, head_dim),
-                                 jnp.float32).astype(jnp.dtype(dtype))
-               for key in jax.random.split(jax.random.PRNGKey(7), 3))
+    q, k, v, dout = (jax.random.normal(key, (batch, seq, heads, head_dim),
+                                       jnp.float32).astype(jnp.dtype(dtype))
+                     for key in jax.random.split(jax.random.PRNGKey(7), 4))
+
+    def max_err(a, b):
+        return float(jnp.max(jnp.abs(a.astype(jnp.float32)
+                                     - b.astype(jnp.float32))))
+
     flash = flash_attention(q, k, v, interpret=not on_chip)
     ref = full_attention(q, k, v)
-    flash_err = float(jnp.max(jnp.abs(flash.astype(jnp.float32)
-                                      - ref.astype(jnp.float32))))
+    flash_err = max_err(flash, ref)
     check(flash_err <= flash_tol,
           f"flash forward vs full_attention: max abs err {flash_err} "
           f"> {flash_tol} ({dtype})")
+
+    # The Pallas backward against jax.grad of the reference, each
+    # gradient's error over the reference gradient's largest entry.
+    def grads(fn):
+        return jax.grad(lambda q, k, v: jnp.sum(
+            fn(q, k, v).astype(jnp.float32) * dout.astype(jnp.float32)),
+            (0, 1, 2))(q, k, v)
+
+    flash_grads = grads(functools.partial(flash_attention,
+                                          interpret=not on_chip))
+    flash_bwd_err = max(
+        max_err(g, w) / float(jnp.max(jnp.abs(w.astype(jnp.float32))))
+        for g, w in zip(flash_grads, grads(full_attention)))
+    check(flash_bwd_err <= flash_tol,
+          f"flash backward vs grad of full_attention: max err over the "
+          f"gradient's max {flash_bwd_err} > {flash_tol} ({dtype})")
 
     # num_tpus is passed: init() never initialises a backend to count.
     ray_tpu.init(num_cpus=4, num_tpus=len(jax.devices()))
@@ -372,11 +396,17 @@ def leg_trainer(platform: str = "tpu", model: dict = None, batch: int = None,
     check(result["mosaic_in_step"] == on_chip,
           f"Mosaic flash kernel in the compiled step: "
           f"{result['mosaic_in_step']} (expected {on_chip})")
+    check(result["mosaic_bwd_in_step"] == on_chip,
+          f"Mosaic flash backward kernel in the compiled step: "
+          f"{result['mosaic_bwd_in_step']} (expected {on_chip})")
     return {"model": model, "batch": batch, "seq": seq, "dtype": dtype,
             "params_m": round(result["n_params"] / 1e6, 1),
             "losses": [round(x, 4) for x in losses],
             "flash_in_step": result["mosaic_in_step"],
-            "flash_vs_full_max_abs_err": flash_err, "flash_tol": flash_tol}
+            "flash_bwd_in_step": result["mosaic_bwd_in_step"],
+            "flash_vs_full_max_abs_err": flash_err,
+            "flash_bwd_vs_grad_of_full_max_rel_err": flash_bwd_err,
+            "flash_tol": flash_tol}
 
 
 # ---------------------------------------------------------------------------

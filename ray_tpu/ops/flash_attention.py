@@ -6,10 +6,17 @@ blocks, f32 accumulation, bf16-friendly inputs.  (Pallas guide: grid +
 BlockSpec pattern; preferred_element_type for MXU dots.)
 
 Differentiable through a ``custom_vjp``: the forward kernel also emits
-the per-row log-sum-exp, and the backward recomputes the probabilities
-blockwise in ``jax.numpy`` from (q, k, v, out, lse) — the [L, L] matrix
-is never materialized in either direction.  (A Pallas backward, bf16
-dots and K/V tiling are ROADMAP S3.)
+the per-row log-sum-exp, and the backward is one Pallas kernel too
+(``flash_attention_bwd``) that recomputes the probabilities tile by
+tile from (q, k, v, out, lse): a grid over K/V blocks, a loop over the
+Q blocks from the diagonal down (blocks above it are never visited,
+only blocks the diagonal crosses are masked), ``dk``/``dv`` carried by
+the loop and ``dq`` accumulated in a float32 VMEM scratch, so no
+``[L, block]`` tile goes to HBM in either direction.  The MXU gets its
+operands in the input dtype with float32 accumulation (``p`` cast to
+``dout``'s dtype, ``ds`` to ``q``'s); ``lse``, ``delta``, ``exp`` and
+the three accumulators are float32, cast once at the end.  (bf16 dots
+in the forward and K/V tiling for L = 32k are ROADMAP S3.)
 
 ``attention()`` picks the kernel on TPU and the jnp reference
 (ops.ring_attention.full_attention) elsewhere; tests run the kernel in
@@ -23,9 +30,22 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 _NEG_INF = -1e30
 _LANES = 128
+# dot_general dimension numbers: contract the minor dims (a @ b.T) and
+# the major dims (a.T @ b).
+_NT = (((1,), (1,)), ((), ()))
+_TN = (((0,), (0,)), ((), ()))
+# The backward's block (both ways), the largest that divides L.  On the
+# v5e at [64, 4096, 128] bf16 (PERF.md, PR 27): 512 -> 5.4 ms a call,
+# 256 -> 7.1, 128 -> 15.8; 1024 or unequal blocks are no faster.
+_BWD_BLOCKS = (512, 256, 128)
+# q, dout and dq whole per head (double-buffered), the float32 dq
+# scratch and a few [block, block] float32 tiles: 13 MB at L = 4096,
+# D = 128 bf16, over the 16 MB a kernel gets unasked (the chip has 128).
+_BWD_VMEM_BYTES = 64 * 2 ** 20
 
 
 def _flash_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, block_k: int,
@@ -99,48 +119,107 @@ def _flash_forward(qh, kh, vh, causal, block_q, block_k, interpret):
     return out, lse[:, :, 0]
 
 
+def _causal_q_blocks(k_blk, block_q, block_k, num_q):
+    """The q blocks that see k block ``k_blk`` under the causal mask:
+    ``(first, unmasked)`` -- blocks ``[first, num_q)`` have a row at or
+    past the block's first key, and from ``unmasked`` on every row is
+    past its last key, so no mask is needed.  Python ints or traced."""
+    first = (k_blk * block_k) // block_q
+    unmasked = jnp.minimum(pl.cdiv((k_blk + 1) * block_k - 1, block_q),
+                           num_q)
+    return first, unmasked
+
+
+def _flash_bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
+                      dq_ref, dk_ref, dv_ref, dq_acc, *, block_q: int,
+                      causal: bool, scale: float):
+    # Grid (BH, k block).  k_ref/v_ref/dk_ref/dv_ref: [block_k, D];
+    # q_ref/do_ref/dq_ref: [L, D], resident across a head's k blocks;
+    # lse_ref/delta_ref: [L // block_q, block_q], one row a q block;
+    # dq_acc: [L, D] f32.  Tiles are held transposed, [block_k, block_q],
+    # so the row statistics broadcast along sublanes and dk/dv need no
+    # transpose.
+    block_k, d = k_ref.shape
+    num_q = q_ref.shape[0] // block_q
+    k_blk = pl.program_id(1)
+    f32 = jnp.float32
+
+    @pl.when(k_blk == 0)
+    def _():
+        dq_acc[...] = jnp.zeros_like(dq_acc)
+
+    k = k_ref[...]
+    v = v_ref[...]
+
+    def body(masked, i, carry):
+        dk, dv = carry
+        rows = pl.ds(pl.multiple_of(i * block_q, block_q), block_q)
+        q = q_ref[rows, :]
+        do = do_ref[rows, :]
+        s = jax.lax.dot_general(k, q, _NT, preferred_element_type=f32) * scale
+        if masked:
+            q_pos = i * block_q + jax.lax.broadcasted_iota(
+                jnp.int32, (block_k, block_q), 1)
+            k_pos = k_blk * block_k + jax.lax.broadcasted_iota(
+                jnp.int32, (block_k, block_q), 0)
+            s = jnp.where(q_pos >= k_pos, s, _NEG_INF)
+        p = jnp.exp(s - lse_ref[pl.ds(i, 1), :])
+        dv = dv + jnp.dot(p.astype(do.dtype), do, preferred_element_type=f32)
+        dp = jax.lax.dot_general(v, do, _NT, preferred_element_type=f32)
+        ds = (p * (dp - delta_ref[pl.ds(i, 1), :]) * scale).astype(q.dtype)
+        dk = dk + jnp.dot(ds, q, preferred_element_type=f32)
+        dq_acc[rows, :] += jax.lax.dot_general(ds, k, _TN,
+                                               preferred_element_type=f32)
+        return dk, dv
+
+    first, unmasked = (_causal_q_blocks(k_blk, block_q, block_k, num_q)
+                       if causal else (0, 0))
+    zeros = jnp.zeros((block_k, d), f32)
+    carry = jax.lax.fori_loop(first, unmasked,
+                              functools.partial(body, True), (zeros, zeros))
+    dk, dv = jax.lax.fori_loop(unmasked, num_q,
+                               functools.partial(body, False), carry)
+    dk_ref[...] = dk.astype(dk_ref.dtype)
+    dv_ref[...] = dv.astype(dv_ref.dtype)
+
+    @pl.when(k_blk == pl.num_programs(1) - 1)
+    def _():
+        dq_ref[...] = dq_acc[...].astype(dq_ref.dtype)
+
+
 @jax.named_scope("flash_attention_bwd")
-def _flash_backward(qh, kh, vh, out, lse, dout, causal, block_k):
-    """Blockwise jnp backward over K/V blocks (scan): per block, the
-    probabilities are recomputed from the saved log-sum-exp, so peak
-    extra memory is one [BH, L, block_k] f32 tile, not [BH, L, L]."""
+def _flash_backward(qh, kh, vh, out, lse, dout, causal, interpret):
+    """(dq, dk, dv) from the forward's residuals, each [BH, L, D]."""
     BH, L, D = qh.shape
-    scale = D ** -0.5
-    nk = L // block_k
+    block = next((b for b in _BWD_BLOCKS if L % b == 0), None)
+    if block is None:
+        raise ValueError(
+            f"sequence length {L} must be a multiple of "
+            f"{_BWD_BLOCKS[-1]} for the backward kernel; pad upstream")
+    nq = L // block
     f32 = jnp.float32
     delta = jnp.sum(dout.astype(f32) * out.astype(f32), axis=-1)  # [BH, L]
-    q_pos = jax.lax.broadcasted_iota(jnp.int32, (L, block_k), 0)
-    k_off = jax.lax.broadcasted_iota(jnp.int32, (L, block_k), 1)
-
-    def per_block(dq, xs):
-        j, k_j, v_j = xs                                   # [BH, bk, D]
-        s = jnp.einsum("bqd,bkd->bqk", qh, k_j,
-                       preferred_element_type=f32) * scale
-        if causal:
-            s = jnp.where((q_pos >= j * block_k + k_off)[None], s, _NEG_INF)
-        p = jnp.exp(s - lse[:, :, None])
-        dv_j = jnp.einsum("bqk,bqd->bkd", p.astype(dout.dtype), dout,
-                          preferred_element_type=f32)
-        dp = jnp.einsum("bqd,bkd->bqk", dout, v_j,
-                        preferred_element_type=f32)
-        ds = (p * (dp - delta[:, :, None]) * scale).astype(qh.dtype)
-        dq = dq + jnp.einsum("bqk,bkd->bqd", ds, k_j,
-                             preferred_element_type=f32)
-        dk_j = jnp.einsum("bqk,bqd->bkd", ds, qh,
-                          preferred_element_type=f32)
-        return dq, (dk_j, dv_j)
-
-    def blocks(x):                                         # [nk, BH, bk, D]
-        return x.reshape(BH, nk, block_k, D).transpose(1, 0, 2, 3)
-
-    def unblocks(x):
-        return x.transpose(1, 0, 2, 3).reshape(BH, L, D)
-
-    dq, (dk, dv) = jax.lax.scan(
-        per_block, jnp.zeros((BH, L, D), f32),
-        (jnp.arange(nk, dtype=jnp.int32), blocks(kh), blocks(vh)))
-    return (dq.astype(qh.dtype), unblocks(dk).astype(kh.dtype),
-            unblocks(dv).astype(vh.dtype))
+    whole = pl.BlockSpec((None, L, D), lambda b, j: (b, 0, 0))
+    blocked = pl.BlockSpec((None, block, D), lambda b, j: (b, j, 0))
+    stats = pl.BlockSpec((None, nq, block), lambda b, j: (b, 0, 0))
+    kernel = functools.partial(_flash_bwd_kernel, block_q=block,
+                               causal=causal, scale=D ** -0.5)
+    return tuple(pl.pallas_call(
+        kernel,
+        grid=(BH, nq),
+        in_specs=[whole, blocked, blocked, whole, stats, stats],
+        out_specs=[whole, blocked, blocked],
+        out_shape=[jax.ShapeDtypeStruct(x.shape, x.dtype)
+                   for x in (qh, kh, vh)],
+        scratch_shapes=[pltpu.VMEM((L, D), f32)],
+        # dq accumulates over a head's k blocks: that axis runs in order.
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary"),
+            vmem_limit_bytes=_BWD_VMEM_BYTES),
+        interpret=interpret,
+        name="flash_attention_bwd",
+    )(qh, kh, vh, dout, lse.reshape(BH, nq, block),
+      delta.reshape(BH, nq, block)))
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
@@ -156,8 +235,8 @@ def _flash_vjp_fwd(qh, kh, vh, causal, block_q, block_k, interpret):
 
 
 def _flash_vjp_bwd(causal, block_q, block_k, interpret, res, dout):
-    del block_q, interpret
-    return _flash_backward(*res, dout, causal, block_k)
+    del block_q, block_k     # the forward's; the backward picks its own
+    return _flash_backward(*res, dout, causal, interpret)
 
 
 _flash.defvjp(_flash_vjp_fwd, _flash_vjp_bwd)

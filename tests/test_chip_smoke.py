@@ -57,6 +57,8 @@ def test_leg_trainer(smoke):
                    d_ff=256, max_seq_len=128, remat=True))
     assert facts["losses"][-1] < facts["losses"][0]
     assert not facts["flash_in_step"]             # reference off the chip
+    assert not facts["flash_bwd_in_step"]
+    assert facts["flash_bwd_vs_grad_of_full_max_rel_err"] <= 1e-4
 
 
 def test_leg_sharded_solve(smoke):

@@ -8,7 +8,8 @@ import jax
 import jax.numpy as jnp
 import pytest
 
-from ray_tpu.ops.flash_attention import attention, flash_attention
+from ray_tpu.ops.flash_attention import (_causal_q_blocks, attention,
+                                         flash_attention)
 from ray_tpu.ops.ring_attention import full_attention
 
 # Max abs error allowed on outputs and gradients of O(1) magnitude:
@@ -38,22 +39,104 @@ def test_forward_matches_full_attention(dtype, causal):
     assert _max_err(got, want) <= _TOL[dtype]
 
 
-@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
-@pytest.mark.parametrize("causal", [True, False])
-def test_backward_matches_grad_of_full_attention(dtype, causal):
-    q, k, v, dout = _qkvd(dtype)
+def _assert_grads_match(dtype, causal, shape=None, **blocks):
+    """jax.grad through the kernel's custom_vjp (the Pallas backward in
+    interpret mode) against jax.grad of ``full_attention``."""
+    q, k, v, dout = _qkvd(dtype, **(shape or {}))
 
     def scalar(fn):
         return lambda q, k, v: jnp.sum(
             fn(q, k, v).astype(jnp.float32) * dout.astype(jnp.float32))
 
     got = jax.grad(scalar(lambda q, k, v: flash_attention(
-        q, k, v, causal=causal, interpret=True)), (0, 1, 2))(q, k, v)
+        q, k, v, causal=causal, interpret=True, **blocks)),
+        (0, 1, 2))(q, k, v)
     want = jax.grad(scalar(lambda q, k, v: full_attention(
         q, k, v, causal=causal)), (0, 1, 2))(q, k, v)
     for name, g, w in zip("qkv", got, want):
         assert g.dtype == w.dtype
         assert _max_err(g, w) <= _TOL[dtype], name
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+@pytest.mark.parametrize("causal", [True, False])
+def test_backward_matches_grad_of_full_attention(dtype, causal):
+    _assert_grads_match(dtype, causal)
+
+
+# L = 640 is five backward blocks of 128 and L = 2048 four of 512 (the
+# cell's block), so whole blocks lie above the diagonal and the masked
+# and the unmasked loop both run; D = 128 is the cell's head size.
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+@pytest.mark.parametrize("causal,shape", [
+    (True, dict(B=1, L=640, H=2)),
+    (False, dict(B=1, L=640, H=2)),
+    (True, dict(B=1, L=2048, H=1)),
+    (True, dict(B=1, L=256, H=2, D=128)),
+    (False, dict(B=1, L=256, H=1, D=128)),
+], ids=["causal-5x128", "full-5x128", "causal-4x512", "causal-d128",
+        "full-d128"])
+def test_backward_over_several_blocks_and_head_sizes(dtype, causal, shape):
+    _assert_grads_match(dtype, causal, shape)
+
+
+@pytest.mark.parametrize("block_q,block_k", [(256, 128), (128, 256)])
+def test_backward_with_unequal_forward_blocks(block_q, block_k):
+    """The forward's blocks shape the residuals' producer only: the
+    backward picks its own and must agree whatever they were."""
+    _assert_grads_match(jnp.float32, True, dict(B=1, L=512, H=1),
+                        block_q=block_q, block_k=block_k)
+
+
+def _equations(jaxpr, inside_kernel=False):
+    """(primitive name, params, inside a pallas_call?) of every
+    equation, sub-jaxprs included."""
+    for eqn in jaxpr.eqns:
+        yield eqn.primitive.name, eqn.params, inside_kernel
+        inner = inside_kernel or eqn.primitive.name == "pallas_call"
+        for value in eqn.params.values():
+            for sub in value if isinstance(value, (list, tuple)) else [value]:
+                sub = getattr(sub, "jaxpr", sub)
+                if hasattr(sub, "eqns"):
+                    yield from _equations(sub, inner)
+
+
+def test_gradient_is_pallas_kernels_and_no_loop_outside_them():
+    q, k, v, _ = _qkvd(jnp.float32, B=1, L=512, H=1)
+    jaxpr = jax.make_jaxpr(jax.grad(lambda q, k, v: jnp.sum(
+        flash_attention(q, k, v, interpret=True)), (0, 1, 2)))(q, k, v)
+    eqns = list(_equations(jaxpr.jaxpr))
+    kernels = [params["name"] for name, params, inside in eqns
+               if name == "pallas_call" and not inside]
+    assert kernels[0] == "flash_attention_fwd" and len(kernels) >= 2
+    assert all(n.startswith("flash_attention_bwd") for n in kernels[1:])
+    loops = [name for name, _, inside in eqns
+             if name in ("scan", "while") and not inside]
+    assert loops == []
+
+
+@pytest.mark.parametrize("block_q,block_k,seq_len", [
+    (128, 128, 640), (512, 512, 4096), (128, 256, 1024), (256, 128, 1024),
+    (128, 384, 768)])
+def test_causal_bounds_visit_the_blocks_at_or_below_the_diagonal(
+        block_q, block_k, seq_len):
+    """The helper the kernel's loop bounds come from, on Python ints:
+    every pair with a visible entry is visited, none without one, and
+    only pairs the diagonal crosses are masked."""
+    num_q, num_k = seq_len // block_q, seq_len // block_k
+    visited = 0
+    for j in range(num_k):
+        first, unmasked = map(int, _causal_q_blocks(
+            j, block_q, block_k, num_q))
+        assert 0 <= first <= unmasked <= num_q
+        for i in range(num_q):
+            sees_any = (i + 1) * block_q - 1 >= j * block_k
+            sees_all = i * block_q >= (j + 1) * block_k - 1
+            assert (i >= first) == sees_any, (i, j)
+            assert (i >= unmasked) == sees_all, (i, j)
+        visited += num_q - first
+    if block_q == block_k:
+        assert visited == num_q * (num_q + 1) // 2
 
 
 def test_unequal_blocks_and_train_step_shape():
@@ -75,5 +158,39 @@ def test_rejects_ragged_length_and_dispatch_off_chip():
     q, k, v, _ = _qkvd(jnp.float32, L=192)
     with pytest.raises(ValueError, match="multiple of the block sizes"):
         flash_attention(q, k, v, interpret=True)
+    # The backward's blocks are its own (128 at least): a length only
+    # the forward's blocks divide fails as loudly, not by falling back.
+    with pytest.raises(ValueError, match="for the backward kernel"):
+        jax.grad(lambda q: jnp.sum(flash_attention(
+            q, k, v, block_q=64, block_k=64, interpret=True)))(q)
     # Off the chip attention() is the reference, whatever the shape.
     assert _max_err(attention(q, k, v), full_attention(q, k, v)) == 0.0
+
+
+@pytest.fixture(scope="module")
+def one_v5e_chip():
+    """A described (not attached) v5e for the chip's own compiler; made
+    inside the fixture so that importing this file loads no libtpu."""
+    from jax.experimental import topologies
+    from jax.sharding import SingleDeviceSharding
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    return SingleDeviceSharding(topo.devices[0])
+
+
+def test_gradient_compiles_for_the_chip_at_the_cell_width(one_v5e_chip):
+    """Mosaic takes both kernels at the benchmark cell's attention shape
+    (4 x 4,096 tokens, 16 heads of 128, bfloat16): what interpret mode
+    cannot say -- tiling, VMEM, the transposed-operand product."""
+    x = jax.ShapeDtypeStruct((4, 4096, 16, 128), jnp.bfloat16,
+                             sharding=one_v5e_chip)
+    compiled = jax.jit(jax.grad(lambda q, k, v: jnp.sum(
+        flash_attention(q, k, v).astype(jnp.float32)), (0, 1, 2))).lower(
+            x, x, x).compile()
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") == 2
+    assert "flash_attention_fwd" in text and "flash_attention_bwd" in text
+    assert " while(" not in text
