@@ -326,13 +326,15 @@ def leg_trainer(platform: str = "tpu", model: dict = None, batch: int = None,
     advertises the chip; a repeated batch, so the loss must fall.  On the
     chip the compiled step must contain the Mosaic flash kernels, forward
     and backward, and both must agree with ``full_attention`` and its
-    ``jax.grad`` (off the chip the kernels are checked in interpret mode
-    and ``attention()`` takes the reference)."""
+    ``jax.grad``, under the causal mask and under the block-diffusion
+    mask with grouped K/V heads (off the chip the kernels are checked in
+    interpret mode and ``attention()`` takes the reference)."""
     import jax
     import jax.numpy as jnp
 
     import bench_model
     import ray_tpu
+    from ray_tpu.ops.attention_mask import BlockDiffusion
     from ray_tpu.ops.flash_attention import flash_attention
     from ray_tpu.ops.ring_attention import full_attention
     from ray_tpu.train import Trainer
@@ -366,14 +368,39 @@ def leg_trainer(platform: str = "tpu", model: dict = None, batch: int = None,
             fn(q, k, v).astype(jnp.float32) * dout.astype(jnp.float32)),
             (0, 1, 2))(q, k, v)
 
-    flash_grads = grads(functools.partial(flash_attention,
-                                          interpret=not on_chip))
-    flash_bwd_err = max(
-        max_err(g, w) / float(jnp.max(jnp.abs(w.astype(jnp.float32))))
-        for g, w in zip(flash_grads, grads(full_attention)))
+    def grads_err(got, want):
+        return max(
+            max_err(g, w) / float(jnp.max(jnp.abs(w.astype(jnp.float32))))
+            for g, w in zip(got, want))
+
+    flash_bwd_err = grads_err(
+        grads(functools.partial(flash_attention, interpret=not on_chip)),
+        grads(full_attention))
     check(flash_bwd_err <= flash_tol,
           f"flash backward vs grad of full_attention: max err over the "
           f"gradient's max {flash_bwd_err} > {flash_tol} ({dtype})")
+
+    # The same two questions under the block-diffusion mask, each row
+    # run as [noised ; clean] (2 x seq positions, blocks of 4), query
+    # heads grouped four to a K/V head where the head count allows.
+    mask = BlockDiffusion(seq, 4)
+    kv_heads = heads // 4 if heads % 4 == 0 else heads
+    q, k, v, dout = (
+        jax.random.normal(key, (batch, 2 * seq, h, head_dim),
+                          jnp.float32).astype(jnp.dtype(dtype))
+        for key, h in zip(jax.random.split(jax.random.PRNGKey(8), 4),
+                          (heads, kv_heads, kv_heads, heads)))
+    bd_flash = functools.partial(flash_attention, mask=mask,
+                                 interpret=not on_chip)
+    bd_full = functools.partial(full_attention, mask=mask)
+    bd_err = max_err(bd_flash(q, k, v), bd_full(q, k, v))
+    check(bd_err <= flash_tol,
+          f"block-diffusion grouped flash forward vs full_attention: max "
+          f"abs err {bd_err} > {flash_tol} ({dtype})")
+    bd_bwd_err = grads_err(grads(bd_flash), grads(bd_full))
+    check(bd_bwd_err <= flash_tol,
+          f"block-diffusion grouped flash backward vs grad of "
+          f"full_attention: {bd_bwd_err} > {flash_tol} ({dtype})")
 
     # num_tpus is passed: init() never initialises a backend to count.
     ray_tpu.init(num_cpus=4, num_tpus=len(jax.devices()))
@@ -406,6 +433,9 @@ def leg_trainer(platform: str = "tpu", model: dict = None, batch: int = None,
             "flash_bwd_in_step": result["mosaic_bwd_in_step"],
             "flash_vs_full_max_abs_err": flash_err,
             "flash_bwd_vs_grad_of_full_max_rel_err": flash_bwd_err,
+            "block_diffusion_gqa_flash_vs_full_max_abs_err": bd_err,
+            "block_diffusion_gqa_flash_bwd_max_rel_err": bd_bwd_err,
+            "block_diffusion_kv_heads": kv_heads,
             "flash_tol": flash_tol}
 
 

@@ -1,37 +1,62 @@
-"""Mixture-of-experts FFN with expert parallelism over an ``ep`` mesh
-axis.
+"""Mixture-of-experts FFN: top-k routing without drops over the experts
+held here.
 
-TPU-first design (GShard/Switch recipe, the scaling-book EP chapter's
-shape): top-1 router, capacity-bounded dense dispatch/combine einsums —
-everything is static-shaped matmuls and one-hots, so XLA lays the
-dispatch as all-to-all over the ``ep`` axis when the expert dimension
-is sharded there.  The reference framework has no MoE at all (SURVEY
-§5.7 — parallelism beyond DP is an extension our substrate makes
-natural).
+One expert layer for every deployment shape.  The router scores all
+``E`` experts in float32, each token takes its ``top_k`` largest
+(renormalised over the chosen ones when ``norm_topk``), and this
+program computes the part of the result that the experts it *holds* --
+a contiguous range ``held = (first, count)`` -- give for the
+token-choices routed to them.  What absent experts would add is left
+out: on one chip of an expert-parallel deployment that partial result
+is what goes on; under an ``ep`` mesh axis every shard runs this same
+layer on its own range and the partial results are summed with ``psum``
+(``moe_ffn_sharded``).
 
-Per layer, with T = B*S tokens, E experts, capacity C:
-    probs   = softmax(x @ wr)                        [T, E]
-    choice  = argmax_E                               (switch top-1)
-    pos     = rank of each token within its expert   (cumsum one-hot)
-    disp    = onehot(choice) & (pos < C)             [T, E, C]
-    ex_in   = einsum('tec,td->ecd', disp, x)         (all-to-all in)
-    ex_out  = silu(ex_in @ w1_e) * (ex_in @ w3_e) @ w2_e   per expert
-    y       = einsum('tec,ecd->td', disp * gate, ex_out)   (back)
-Tokens beyond capacity are dropped (residual passes them through) —
-standard Switch behavior.
+Per layer, with T tokens, N = T * top_k token-choices:
+    probs    = softmax(x @ wr)                      [T, E]  float32
+    gate, e  = top_k(probs), renormalised           [T, k]
+    order    = token-choices sorted by held expert; those routed to
+               absent experts last
+    chunks   = the sorted list cut into chunks of a fixed number of
+               rows; the loop ends with the last held choice (the
+               backward walks the same chunks: ``_held_experts``)
+    h        = silu(ragged_dot(xs, w1)) * ragged_dot(xs, w3)
+    y[tok]  += gate * ragged_dot(h, w2)             per chunk
+Nothing is dropped whatever the imbalance: the chunks cover all N
+choices, so memory is bounded by the chunk and work follows the number
+of chunks the held choices fill.  A chunk is sized for tokens that
+route alike (``chunk_rows``): ``m`` choices of every token, with ``m``
+the most of a token's ``top_k`` experts that fall among the ``count``
+held in all but one case in a hundred.  That is the share's worst
+common case, not its average: a block of identical tokens (a mask
+token, a separator, a model whose features have collapsed) puts ``j``
+choices of every one of them here at once, for a ``j`` drawn once for
+the block, and a chunk that held the balanced load only would make a
+layer's cost swing with that draw.  A layer computes one such chunk
+whatever its load, and what a heavier load leaves over in chunks of one
+choice of every token, as many as it fills.
+
+``balance_loss`` is the load-balance auxiliary of top-k routing.
 """
 
 from __future__ import annotations
 
-from typing import Dict
+import functools
+import math
+from typing import Dict, Tuple
 
 import jax
 import jax.numpy as jnp
-from jax.sharding import NamedSharding, PartitionSpec as P
+from jax.sharding import PartitionSpec as P
+
+_CHUNK_ALIGN = 256
+#: The share of blocks of alike tokens a chunk may fall short of.
+_ALIKE_TAIL = 0.01
 
 
 def init_moe_params(rng: jax.Array, n_layers: int, d_model: int,
-                    d_ff: int, n_experts: int, dtype) -> Dict:
+                    d_ff: int, n_experts: int, n_held: int, dtype) -> Dict:
+    """Router over all ``n_experts``; weights of the ``n_held`` held."""
     init = jax.nn.initializers.normal(0.02)
     keys = jax.random.split(rng, 4)
 
@@ -40,9 +65,9 @@ def init_moe_params(rng: jax.Array, n_layers: int, d_model: int,
 
     return {
         "wr": stacked(keys[0], (d_model, n_experts)),
-        "w1": stacked(keys[1], (n_experts, d_model, d_ff)),
-        "w3": stacked(keys[2], (n_experts, d_model, d_ff)),
-        "w2": stacked(keys[3], (n_experts, d_ff, d_model)),
+        "w1": stacked(keys[1], (n_held, d_model, d_ff)),
+        "w3": stacked(keys[2], (n_held, d_model, d_ff)),
+        "w2": stacked(keys[3], (n_held, d_ff, d_model)),
     }
 
 
@@ -56,61 +81,259 @@ def moe_param_specs() -> Dict:
     }
 
 
-def moe_ffn(x: jax.Array, lp: Dict, n_experts: int,
-            capacity_factor: float, mesh=None) -> jax.Array:
-    """One MoE FFN block: x [B, S, D] -> [B, S, D] (residual NOT
-    included).  ``lp`` holds this layer's wr/w1/w3/w2."""
-    B, S, D = x.shape
-    T = B * S
-    capacity = max(1, int(capacity_factor * T / n_experts))
-    xt = x.reshape(T, D)
-    logits = jnp.einsum("td,de->te", xt.astype(jnp.float32),
-                        lp["wr"].astype(jnp.float32))
-    probs = jax.nn.softmax(logits, axis=-1)
-    choice = jnp.argmax(probs, axis=-1)                   # [T]
-    gate = jnp.max(probs, axis=-1)                        # [T]
-    onehot = jax.nn.one_hot(choice, n_experts,
-                            dtype=jnp.float32)            # [T, E]
-    # Position of each token within its chosen expert's queue.
-    pos = jnp.cumsum(onehot, axis=0) * onehot - onehot    # excl. [T, E]
-    within = pos < capacity
-    disp = onehot * within                                # [T, E]
-    slot = jax.nn.one_hot(pos.sum(axis=-1).astype(jnp.int32),
-                          capacity, dtype=jnp.float32)    # [T, C]
-    dispatch = jnp.einsum("te,tc->tec", disp, slot)       # [T, E, C]
-    combine = dispatch * gate[:, None, None]
-    ex_in = jnp.einsum("tec,td->ecd", dispatch,
-                       xt.astype(jnp.float32))            # [E, C, D]
-    if mesh is not None and "ep" in mesh.axis_names:
-        # Experts over ep AND capacity rows over dp: capacity slots are
-        # independent, so dp shards each run 1/dp of every expert's
-        # matmuls instead of replicating the full global-capacity
-        # compute per replica.
-        ex_in = jax.lax.with_sharding_constraint(
-            ex_in, NamedSharding(mesh, P("ep", "dp", None)))
-    ex_in = ex_in.astype(x.dtype)
-    h = jax.nn.silu(jnp.einsum("ecd,edf->ecf", ex_in, lp["w1"])) * \
-        jnp.einsum("ecd,edf->ecf", ex_in, lp["w3"])
-    ex_out = jnp.einsum("ecf,efd->ecd", h, lp["w2"])      # [E, C, D]
-    if mesh is not None and "ep" in mesh.axis_names:
-        ex_out = jax.lax.with_sharding_constraint(
-            ex_out, NamedSharding(mesh, P("ep", "dp", None)))
-    y = jnp.einsum("tec,ecd->td", combine,
-                   ex_out.astype(jnp.float32))            # [T, D]
-    return y.astype(x.dtype).reshape(B, S, D)
+def alike_choices(n_experts: int, n_held: int, top_k: int) -> int:
+    """The least ``m`` such that a token's ``top_k`` distinct experts,
+    wherever they lie among the ``n_experts``, include more than ``m``
+    of the ``n_held`` held ones in at most one case in a hundred
+    (hypergeometric): 3 for 8 of 128 with 16 held."""
+    total = math.comb(n_experts, top_k)
+    tail = 1.0
+    for m in range(min(top_k, n_held) + 1):
+        tail -= (math.comb(n_held, m)
+                 * math.comb(n_experts - n_held, top_k - m)) / total
+        if tail <= _ALIKE_TAIL:
+            return m
+    return min(top_k, n_held)
 
 
-def aux_load_balance_loss(x: jax.Array, wr: jax.Array,
-                          n_experts: int) -> jax.Array:
-    """Switch load-balance auxiliary loss: E * sum_e f_e * p_e, where
-    f_e = fraction of tokens routed to e, p_e = mean router prob."""
-    T = x.shape[0] * x.shape[1]
-    xt = x.reshape(T, -1)
-    probs = jax.nn.softmax(
-        jnp.einsum("td,de->te", xt.astype(jnp.float32),
-                   wr.astype(jnp.float32)), axis=-1)
-    choice = jax.nn.one_hot(jnp.argmax(probs, axis=-1), n_experts,
-                            dtype=jnp.float32)
-    f = jnp.mean(choice, axis=0)
-    p = jnp.mean(probs, axis=0)
-    return n_experts * jnp.sum(f * p)
+def chunk_rows(n_tokens: int, n_experts: int, n_held: int,
+               top_k: int) -> Tuple[int, int]:
+    """Rows of the first dispatch chunk, which is always computed --
+    ``alike_choices`` choices of every token (at least one, and no more
+    than all the choices) -- and of each further one: one choice of
+    every token."""
+    def aligned(rows):
+        return -(-rows // _CHUNK_ALIGN) * _CHUNK_ALIGN
+    first = n_tokens * max(1, alike_choices(n_experts, n_held, top_k))
+    return aligned(min(first, n_tokens * top_k)), aligned(n_tokens)
+
+
+def _chunk_inputs(rows, top_k, n_tokens, order, ends, sizes, start):
+    """Sorted rows ``[start, start + rows)``: their choices, tokens,
+    which of them are held choices, and the experts' groups among them.
+    The rows past the last held choice ride in the last group with a
+    token of their own each (their output is masked out): every row of
+    a chunk is computed, gathered and scattered alike, so a chunk's cost
+    does not vary with the router's balance -- the number of chunks
+    does."""
+    idx = jax.lax.dynamic_slice(order, (start,), (rows,))
+    row = start + jnp.arange(rows, dtype=jnp.int32)
+    valid = row < ends[-1]
+    tok = jnp.where(valid, idx // top_k, row % n_tokens)
+    group = (jnp.clip(ends - start, 0, rows)
+             - jnp.clip(ends - sizes - start, 0, rows))
+    group = group.at[-1].add(rows - jnp.sum(group))
+    return idx, tok, valid, group
+
+
+def _chunk_experts(xs, gate, w1, w3, w2, group, valid):
+    """[rows, D] sorted token rows -> their experts' weighted output,
+    float32; rows past the last held choice give nought."""
+    with jax.named_scope("moe_experts"):
+        h = jax.nn.silu(jax.lax.ragged_dot(xs, w1, group)) * \
+            jax.lax.ragged_dot(xs, w3, group)
+        out = jax.lax.ragged_dot(h, w2, group)
+    with jax.named_scope("moe_combine"):
+        out = jnp.where(valid[:, None], out.astype(jnp.float32), 0.0)
+        return out * gate[:, None]
+
+
+def _over_chunks(chunks, n_held, run, carry):
+    """``carry = run(rows, start, carry)`` over the first chunk, always,
+    and over as many further ones as hold a held choice (a loop of as
+    many turns as that takes: not differentiated, ``_held_experts``
+    brings its own backward)."""
+    first, rest = chunks
+    carry = run(first, 0, carry)
+    return jax.lax.fori_loop(
+        0, jnp.maximum(0, (n_held - first + rest - 1) // rest),
+        lambda c, carry: run(rest, first + c * rest, carry), carry)
+
+
+# Differentiated by hand, so that the loop's length can follow the
+# number of held choices and no chunk's tokens or weights are kept.
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0, 1))
+def _held_experts(chunks, top_k, xt, gate, w1, w3, w2, order, ends, sizes):
+    """-> (y [T, D] float32, rows processed).  ``chunks``: the rows of
+    the first chunk and of each further one; ``order``: the sorted
+    token-choices padded to whole chunks; ``ends``/``sizes``: the held
+    experts' groups in it."""
+    def run(rows, start, carry):
+        y, done = carry
+        with jax.named_scope("moe_dispatch"):
+            idx, tok, valid, group = _chunk_inputs(
+                rows, top_k, xt.shape[0], order, ends, sizes, start)
+            xs = jnp.take(xt, tok, axis=0)
+        out = _chunk_experts(xs, jnp.take(gate, idx), w1, w3, w2, group,
+                             valid)
+        with jax.named_scope("moe_combine"):
+            return y.at[tok].add(out), done + jnp.sum(valid.astype(jnp.int32))
+
+    zero = (jnp.zeros(xt.shape, jnp.float32), jnp.zeros((), jnp.int32))
+    return _over_chunks(chunks, ends[-1], run, zero)
+
+
+def _held_experts_fwd(chunks, top_k, xt, gate, w1, w3, w2, order, ends,
+                      sizes):
+    return (_held_experts(chunks, top_k, xt, gate, w1, w3, w2, order, ends,
+                          sizes),
+            (xt, gate, w1, w3, w2, order, ends, sizes))
+
+
+def _held_experts_bwd(chunks, top_k, res, cotangent):
+    xt, gate, w1, w3, w2, order, ends, sizes = res
+    dy, _ = cotangent
+    f32 = jnp.float32
+
+    def run(rows, start, carry):
+        dxt, dgate, dws = carry
+        with jax.named_scope("moe_dispatch"):
+            idx, tok, valid, group = _chunk_inputs(
+                rows, top_k, xt.shape[0], order, ends, sizes, start)
+            xs = jnp.take(xt, tok, axis=0)
+        _, vjp = jax.vjp(
+            lambda xs, g, w1, w3, w2: _chunk_experts(xs, g, w1, w3, w2,
+                                                     group, valid),
+            xs, jnp.take(gate, idx), w1, w3, w2)
+        dxs, dg, *dw = vjp(jnp.take(dy, tok, axis=0))
+        with jax.named_scope("moe_combine"):
+            # rows past the last held choice carry no gradient (a padded
+            # row repeats choice 0)
+            dxs = jnp.where(valid[:, None], dxs.astype(f32), 0.0)
+            return (dxt.at[tok].add(dxs), dgate.at[idx].add(dg),
+                    [a + d.astype(f32) for a, d in zip(dws, dw)])
+
+    zero = (jnp.zeros(xt.shape, f32), jnp.zeros(gate.shape, f32),
+            [jnp.zeros(w.shape, f32) for w in (w1, w3, w2)])
+    dxt, dgate, dws = _over_chunks(chunks, ends[-1], run, zero)
+    return (dxt.astype(xt.dtype), dgate.astype(gate.dtype),
+            *(d.astype(w.dtype) for d, w in zip(dws, (w1, w3, w2))),
+            None, None, None)
+
+
+_held_experts.defvjp(_held_experts_fwd, _held_experts_bwd)
+
+
+def moe_ffn(x: jax.Array, lp: Dict, top_k: int, norm_topk: bool = True,
+            held: Tuple = None):
+    """x [..., D] -> (y [..., D], stats); the residual is NOT included.
+    ``lp`` holds this layer's ``wr`` [D, E] and ``w1``/``w3``/``w2`` of
+    the ``count`` experts ``held = (first, count)`` (all by default;
+    ``first`` may be traced).  ``stats``: ``held_choices``,
+    ``expert_load`` [count] (each held expert's number of choices),
+    ``dropped_choices`` (held choices less the rows the chunks
+    processed: 0 by construction), ``choices`` [..., top_k] (every
+    token's experts, int32), and what ``balance_loss`` reads:
+    ``router_load`` [E] (choices of every expert, held or not),
+    ``router_prob`` [E] (float32 sum of the tokens' probabilities, the
+    differentiable part) and ``tokens``."""
+    lead, D = x.shape[:-1], x.shape[-1]
+    xt = x.reshape(-1, D)
+    T = xt.shape[0]
+    n_experts = lp["wr"].shape[-1]
+    count = lp["w1"].shape[0]
+    first = 0 if held is None else held[0]
+    if held is not None and held[1] != count:
+        raise ValueError(f"{count} experts' weights for held={held}")
+    N = T * top_k
+
+    with jax.named_scope("moe_router"):
+        # float32 in earnest: the chip's default for a float32 product
+        # is one bfloat16 pass, which would move choices near a tie
+        logits = jnp.einsum("td,de->te", xt.astype(jnp.float32),
+                            lp["wr"].astype(jnp.float32),
+                            precision=jax.lax.Precision.HIGHEST)
+        probs = jax.nn.softmax(logits, axis=-1)
+        gate, expert = jax.lax.top_k(probs, top_k)             # [T, k]
+        if norm_topk:
+            gate = gate / jnp.sum(gate, axis=-1, keepdims=True)
+        router_load = jnp.sum(jax.nn.one_hot(
+            expert.reshape(N), n_experts, dtype=jnp.int32), axis=0)
+
+    with jax.named_scope("moe_dispatch"):
+        # Choice c = token * k + slot.  Held choices sort by their local
+        # expert; the others get the key ``count`` and come last.
+        local = expert.reshape(N) - first
+        is_held = (local >= 0) & (local < count)
+        key = jnp.where(is_held, local, count).astype(jnp.int32)
+        order = jnp.argsort(key, stable=True).astype(jnp.int32)
+        sizes = jnp.sum(jax.nn.one_hot(key, count, dtype=jnp.int32), axis=0)
+        ends = jnp.cumsum(sizes)                               # [count]
+        first, rest = chunks = chunk_rows(T, n_experts, count, top_k)
+        whole = first + -(-max(0, N - first) // rest) * rest
+        order = jnp.pad(order, (0, whole - N))
+        gate_flat = gate.reshape(N)
+
+    y, done = _held_experts(chunks, top_k, xt, gate_flat, lp["w1"], lp["w3"],
+                            lp["w2"], order, ends, sizes)
+    stats = {"held_choices": ends[-1], "expert_load": sizes,
+             "dropped_choices": jnp.sum(is_held.astype(jnp.int32)) - done,
+             "choices": expert.astype(jnp.int32).reshape(*lead, top_k),
+             "router_load": router_load,
+             "router_prob": jnp.sum(probs, axis=0),
+             "tokens": jnp.asarray(T, jnp.int32)}
+    return y.astype(x.dtype).reshape(*lead, D), stats
+
+
+def balance_loss(stats: Dict) -> jax.Array:
+    """The load-balance auxiliary of top-k routing (Switch's, over
+    token-choices): ``E * sum_e f_e p_e`` with ``f_e`` the share of the
+    token-choices routed to expert ``e`` and ``p_e`` the mean router
+    probability of ``e``; 1 under a uniform router, larger the more the
+    choices and the probabilities pile on the same experts.  Its
+    gradient reaches the router through ``p``."""
+    load = stats["router_load"].astype(jnp.float32)
+    f = load / jnp.maximum(jnp.sum(load), 1.0)
+    p = stats["router_prob"] / jnp.maximum(
+        stats["tokens"].astype(jnp.float32), 1.0)
+    return load.shape[0] * jnp.sum(f * p)
+
+
+def counters(stats: Dict) -> Dict:
+    """A layer's ``stats`` as the float32 scalars a step reports:
+    ``moe_held_choices``, ``moe_expert_load_max`` (the largest held
+    expert's share of them), ``moe_dropped_choices`` and
+    ``moe_balance_loss``."""
+    held = stats["held_choices"].astype(jnp.float32)
+    return {
+        "moe_held_choices": held,
+        "moe_expert_load_max": jnp.max(stats["expert_load"]).astype(
+            jnp.float32) / jnp.maximum(held, 1.0),
+        "moe_dropped_choices": stats["dropped_choices"].astype(jnp.float32),
+        "moe_balance_loss": balance_loss(stats),
+    }
+
+
+def moe_ffn_sharded(x: jax.Array, lp: Dict, top_k: int, norm_topk: bool,
+                    mesh):
+    """The layer under an ``ep`` mesh axis: every shard holds
+    ``E / ep`` experts, runs ``moe_ffn`` on its range for its own
+    tokens (``dp`` x ``sp``), and the partial results are summed over
+    ``ep``.  x [B, S, D]."""
+    count = lp["w1"].shape[0] // mesh.shape["ep"]
+    tokens = ("dp", "sp")
+
+    def shard(x, wr, w1, w3, w2):
+        first = jax.lax.axis_index("ep") * count
+        y, stats = moe_ffn(x, {"wr": wr, "w1": w1, "w3": w3, "w2": w2},
+                           top_k, norm_topk, held=(first, count))
+        everywhere = tokens + ("ep",)
+        over = {"held_choices": everywhere, "dropped_choices": everywhere,
+                # an expert's load is summed over the token shards; the
+                # router is the same on every ep shard
+                "expert_load": tokens, "router_load": tokens,
+                "router_prob": tokens, "tokens": tokens}
+        return jax.lax.psum(y, "ep"), dict(
+            {k: jax.lax.psum(stats[k], axes) for k, axes in over.items()},
+            choices=stats["choices"])
+
+    experts = P("ep", None, None)
+    placed = P("dp", "sp", None)
+    return jax.shard_map(
+        shard, mesh=mesh,
+        in_specs=(placed, P(None, None), experts, experts, experts),
+        out_specs=(placed,
+                   {"held_choices": P(), "dropped_choices": P(),
+                    "expert_load": P("ep"), "router_load": P(),
+                    "router_prob": P(), "tokens": P(), "choices": placed}),
+        check_vma=False)(x, lp["wr"], lp["w1"], lp["w3"], lp["w2"])

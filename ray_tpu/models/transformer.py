@@ -25,6 +25,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P
 
+from ray_tpu.ops.attention_mask import CAUSAL
 from ray_tpu.ops.flash_attention import attention as flash_or_ref_attention
 from ray_tpu.ops.ring_attention import ring_attention
 from ray_tpu.util import tracing
@@ -43,23 +44,50 @@ class TransformerConfig:
     remat: bool = True
     #: Use ring attention over the "sp" mesh axis when its size > 1.
     context_parallel: bool = True
-    #: >0 replaces the dense FFN with a switch-MoE of this many experts
-    #: (expert weights shard over the "ep" mesh axis — models/moe.py).
+    #: K/V heads (query head h reads K/V head h // group); 0: n_heads.
+    n_kv_heads: int = 0
+    #: 0: d_model // n_heads.
+    head_dim: int = 0
+    #: RMSNorm over each head of q and k before RoPE (weights
+    #: ``q_norm``/``k_norm`` [head_dim], shared by the heads).
+    qk_norm: bool = False
+    norm_eps: float = 1e-5
+    #: >0 replaces the dense FFN with a mixture of this many experts of
+    #: width ``d_ff`` (models/moe.py): ``moe_top_k`` per token,
+    #: renormalised over the chosen ones if ``moe_norm_topk``.  Expert
+    #: weights shard over the "ep" mesh axis.
     moe_experts: int = 0
-    moe_capacity_factor: float = 1.25
-    #: Switch load-balance auxiliary loss weight (prevents router
-    #: collapse onto one expert under top-1 routing).
+    moe_top_k: int = 2
+    moe_norm_topk: bool = True
+    #: Weight of the router's load-balance auxiliary in the loss
+    #: (``moe.balance_loss``, mean over the layers); 0 leaves it a
+    #: counter.
     moe_aux_coeff: float = 0.01
+    #: The step's metrics also carry every token's experts,
+    #: ``moe_choices`` [n_layers, B, S, moe_top_k] int32, so that a
+    #: reference can follow the routing it checks.
+    moe_report_choices: bool = False
+    #: ``(first, count)``: the experts this program holds of a layer
+    #: shared with other chips (None: all).  The router stays
+    #: ``moe_experts`` wide; what absent experts would add is left out.
+    moe_experts_held: Optional[Tuple[int, int]] = None
 
-    @property
-    def head_dim(self) -> int:
-        return self.d_model // self.n_heads
+    def __post_init__(self):
+        if not self.n_kv_heads:
+            object.__setattr__(self, "n_kv_heads", self.n_heads)
+        if not self.head_dim:
+            object.__setattr__(self, "head_dim",
+                               self.d_model // self.n_heads)
+        if self.n_heads % self.n_kv_heads:
+            raise ValueError(f"{self.n_heads} query heads over "
+                             f"{self.n_kv_heads} K/V heads")
 
 
 def init_params(rng: jax.Array, cfg: TransformerConfig) -> Dict:
     k_embed, k_layers, k_head = jax.random.split(rng, 3)
     d, h, dh, f, nl = (cfg.d_model, cfg.n_heads, cfg.head_dim, cfg.d_ff,
                        cfg.n_layers)
+    kv = cfg.n_kv_heads
     init = jax.nn.initializers.normal(0.02)
     lkeys = jax.random.split(k_layers, 6)
 
@@ -70,15 +98,19 @@ def init_params(rng: jax.Array, cfg: TransformerConfig) -> Dict:
         "ln1": jnp.ones((nl, d), jnp.float32),
         "ln2": jnp.ones((nl, d), jnp.float32),
         "wq": stacked(lkeys[0], (d, h, dh)),
-        "wk": stacked(lkeys[1], (d, h, dh)),
-        "wv": stacked(lkeys[2], (d, h, dh)),
+        "wk": stacked(lkeys[1], (d, kv, dh)),
+        "wv": stacked(lkeys[2], (d, kv, dh)),
         "wo": stacked(lkeys[3], (h, dh, d)),
     }
+    if cfg.qk_norm:
+        layers["q_norm"] = jnp.ones((nl, dh), jnp.float32)
+        layers["k_norm"] = jnp.ones((nl, dh), jnp.float32)
     if cfg.moe_experts > 0:
         from ray_tpu.models.moe import init_moe_params
+        held = cfg.moe_experts_held or (0, cfg.moe_experts)
         layers["moe"] = init_moe_params(
             jax.random.fold_in(k_layers, 8), nl, d, f,
-            cfg.moe_experts, cfg.dtype)
+            cfg.moe_experts, held[1], cfg.dtype)
     else:
         layers.update({
             "w1": stacked(lkeys[4], (d, f)),
@@ -106,6 +138,9 @@ def param_specs(cfg: TransformerConfig) -> Dict:
         "wv": P(None, None, "tp", None),
         "wo": P(None, "tp", None, None),
     }
+    if cfg.qk_norm:
+        layers["q_norm"] = P(None, None)
+        layers["k_norm"] = P(None, None)
     if cfg.moe_experts > 0:
         from ray_tpu.models.moe import moe_param_specs
         layers["moe"] = moe_param_specs()
@@ -127,7 +162,7 @@ def batch_spec() -> P:
     return P("dp", "sp")
 
 
-def _rms_norm(x, w, eps=1e-5):
+def _rms_norm(x, w, eps):
     xf = x.astype(jnp.float32)
     norm = xf * jax.lax.rsqrt(jnp.mean(xf * xf, axis=-1, keepdims=True) + eps)
     return (norm * w).astype(x.dtype)
@@ -147,9 +182,11 @@ def _rope(x, positions, theta):
         [x1 * cos - x2 * sin, x1 * sin + x2 * cos], axis=-1).astype(x.dtype)
 
 
-def _attention_core(q, k, v, mesh, cfg: TransformerConfig):
+def _attention_core(q, k, v, mesh, cfg: TransformerConfig, mask=CAUSAL):
     if (cfg.context_parallel and mesh is not None and
             mesh.shape.get("sp", 1) > 1):
+        if mask != CAUSAL or cfg.n_kv_heads != cfg.n_heads:
+            raise ValueError("ring attention is causal and multi-head only")
         fn = jax.shard_map(
             functools.partial(ring_attention, axis_name="sp", causal=True),
             mesh=mesh,
@@ -157,33 +194,54 @@ def _attention_core(q, k, v, mesh, cfg: TransformerConfig):
             out_specs=P("dp", "sp", "tp", None),
             check_vma=False)
         return fn(q, k, v)
-    return flash_or_ref_attention(q, k, v, causal=True)
+    return flash_or_ref_attention(q, k, v, mask=mask)
 
 
-def apply_layer(x, lp, positions, cfg: TransformerConfig, mesh=None):
+def _moe_block(h, lp, cfg: TransformerConfig, mesh):
+    """The expert layer on [B, S, D] -> (y, what the layer counted)."""
+    from ray_tpu.models import moe
+    if mesh is not None and mesh.shape.get("ep", 1) > 1:
+        if cfg.moe_experts_held is not None:
+            raise ValueError("moe_experts_held is one chip's share; an "
+                             "ep mesh shares the experts itself")
+        y, stats = moe.moe_ffn_sharded(h, lp, cfg.moe_top_k,
+                                       cfg.moe_norm_topk, mesh)
+    else:
+        y, stats = moe.moe_ffn(h, lp, cfg.moe_top_k, cfg.moe_norm_topk,
+                               held=cfg.moe_experts_held)
+    counted = moe.counters(stats)
+    if cfg.moe_report_choices:
+        counted["moe_choices"] = stats["choices"]
+    return y, counted
+
+
+def apply_layer(x, lp, positions, cfg: TransformerConfig, mesh=None,
+                mask=CAUSAL):
     """One transformer block on [B, S, D] activations with this
-    layer's params ``lp``; returns (x, moe_aux).  Shared by the scan
-    forward and the pipeline-parallel stage executor."""
+    layer's params ``lp``; returns (x, what the layer counted: nothing
+    for a dense one).  Shared by the scan forward, the block-diffusion
+    objective and the pipeline-parallel stage executor."""
     # The named scopes here and in loss_fn / train_step are metadata
     # only: stable names for a device trace to group time by.
+    eps = cfg.norm_eps
     with jax.named_scope("attention"):
-        h = _rms_norm(x, lp["ln1"])
+        h = _rms_norm(x, lp["ln1"], eps)
         q = jnp.einsum("bsd,dhk->bshk", h, lp["wq"])
         k = jnp.einsum("bsd,dhk->bshk", h, lp["wk"])
         v = jnp.einsum("bsd,dhk->bshk", h, lp["wv"])
+        if cfg.qk_norm:
+            q = _rms_norm(q, lp["q_norm"], eps)
+            k = _rms_norm(k, lp["k_norm"], eps)
         q = _rope(q, positions, cfg.rope_theta)
         k = _rope(k, positions, cfg.rope_theta)
-        o = _attention_core(q, k, v, mesh, cfg)
+        o = _attention_core(q, k, v, mesh, cfg, mask)
         x = x + jnp.einsum("bshk,hkd->bsd", o, lp["wo"])
-    aux = jnp.zeros((), jnp.float32)
+    counted = {}
     with jax.named_scope("ffn"):
-        h = _rms_norm(x, lp["ln2"])
+        h = _rms_norm(x, lp["ln2"], eps)
         if cfg.moe_experts > 0:
-            from ray_tpu.models.moe import aux_load_balance_loss, moe_ffn
-            x = x + moe_ffn(h, lp["moe"], cfg.moe_experts,
-                            cfg.moe_capacity_factor, mesh)
-            aux = aux_load_balance_loss(h, lp["moe"]["wr"],
-                                        cfg.moe_experts)
+            y, counted = _moe_block(h, lp["moe"], cfg, mesh)
+            x = x + y
         else:
             gate = jax.nn.silu(jnp.einsum("bsd,df->bsf", h, lp["w1"]))
             up = jnp.einsum("bsd,df->bsf", h, lp["w3"])
@@ -191,60 +249,76 @@ def apply_layer(x, lp, positions, cfg: TransformerConfig, mesh=None):
     if mesh is not None:
         x = jax.lax.with_sharding_constraint(
             x, NamedSharding(mesh, P("dp", "sp", None)))
-    return x, aux
+    return x, counted
+
+
+def run_layers(params: Dict, tokens: jax.Array, positions: jax.Array,
+               cfg: TransformerConfig, mesh=None, mask=CAUSAL):
+    """Embedding and the scan over the stacked layers: tokens [B, S]
+    -> (x [B, S, D] before the final norm, what the layers counted:
+    scalars as means over the layers, anything else stacked by layer)."""
+    x = jnp.take(params["embed"], tokens, axis=0)     # [B, S, D]
+    if mesh is not None:
+        x = jax.lax.with_sharding_constraint(
+            x, NamedSharding(mesh, P("dp", "sp", None)))
+
+    def layer(x, lp):
+        return apply_layer(x, lp, positions, cfg, mesh, mask)
+
+    layer_fn = jax.checkpoint(layer) if cfg.remat else layer
+    x, counted = jax.lax.scan(lambda x, lp: layer_fn(x, lp), x,
+                              params["layers"])
+    return x, {k: jnp.mean(v) if v.ndim == 1 else v
+               for k, v in counted.items()}
+
+
+def with_balance_loss(loss, counters: Dict, cfg: TransformerConfig):
+    """The objective's loss plus the router's load-balance auxiliary,
+    where the model has one."""
+    if cfg.moe_experts > 0 and cfg.moe_aux_coeff > 0:
+        loss = loss + cfg.moe_aux_coeff * counters["moe_balance_loss"]
+    return loss
 
 
 def forward(params: Dict, tokens: jax.Array, cfg: TransformerConfig,
             mesh=None) -> jax.Array:
     """tokens [B, S] int32 -> logits [B, S, V]."""
-    logits, _aux = forward_with_aux(params, tokens, cfg, mesh)
-    return logits
+    return forward_with_counters(params, tokens, cfg, mesh)[0]
 
 
-def forward_with_aux(params: Dict, tokens: jax.Array,
-                     cfg: TransformerConfig, mesh=None):
-    """Like :func:`forward` but also returns the mean per-layer MoE
-    load-balance auxiliary (0 for dense models)."""
+def forward_with_counters(params: Dict, tokens: jax.Array,
+                          cfg: TransformerConfig, mesh=None):
+    """Like :func:`forward` but also returns the expert layers'
+    counters (nothing for dense models)."""
     B, S = tokens.shape
-    x = jnp.take(params["embed"], tokens, axis=0)     # [B, S, D]
-    if mesh is not None:
-        x = jax.lax.with_sharding_constraint(
-            x, NamedSharding(mesh, P("dp", "sp", None)))
     positions = jnp.broadcast_to(jnp.arange(S, dtype=jnp.int32)[None], (B, S))
-
-    def layer(carry, lp):
-        x, aux = carry
-        x, layer_aux = apply_layer(x, lp, positions, cfg, mesh)
-        return (x, aux + layer_aux), None
-
-    layer_fn = jax.checkpoint(layer) if cfg.remat else layer
-    (x, aux), _ = jax.lax.scan(lambda c, lp: layer_fn(c, lp),
-                               (x, jnp.zeros((), jnp.float32)),
-                               params["layers"])
+    x, counters = run_layers(params, tokens, positions, cfg, mesh)
     with jax.named_scope("head_loss"):
-        x = _rms_norm(x, params["ln_f"])
+        x = _rms_norm(x, params["ln_f"], cfg.norm_eps)
         logits = jnp.einsum("bsd,dv->bsv", x, params["lm_head"])
-    return logits, aux / max(1, cfg.n_layers)
+    return logits, counters
 
 
-def loss_fn(params: Dict, batch: Dict, cfg: TransformerConfig,
-            mesh=None) -> jax.Array:
-    """Next-token cross entropy (+ MoE load-balance auxiliary when
-    experts are on: without it, top-1 routing collapses onto one
-    expert and over-capacity tokens get dropped en masse).
+def loss_and_counters(params: Dict, batch: Dict, cfg: TransformerConfig,
+                      mesh=None):
+    """Next-token cross entropy (plus the router's load-balance
+    auxiliary) and the expert layers' counters.
     batch = {"tokens": [B, S+1] int32}."""
     tokens = batch["tokens"]
     inputs, targets = tokens[:, :-1], tokens[:, 1:]
-    logits, aux = forward_with_aux(params, inputs, cfg, mesh)
+    logits, counters = forward_with_counters(params, inputs, cfg, mesh)
     with jax.named_scope("head_loss"):
         logits = logits.astype(jnp.float32)
         logz = jax.nn.logsumexp(logits, axis=-1)
         gold = jnp.take_along_axis(logits, targets[..., None],
                                    axis=-1).squeeze(-1)
         loss = jnp.mean(logz - gold)
-    if cfg.moe_experts > 0 and cfg.moe_aux_coeff > 0:
-        loss = loss + cfg.moe_aux_coeff * aux
-    return loss
+    return with_balance_loss(loss, counters, cfg), counters
+
+
+def loss_fn(params: Dict, batch: Dict, cfg: TransformerConfig,
+            mesh=None) -> jax.Array:
+    return loss_and_counters(params, batch, cfg, mesh)[0]
 
 
 # ---------------------------------------------------------------------------
@@ -290,13 +364,20 @@ def _opt_specs(opt_state, param_spec_tree):
 
 def make_train_step(cfg: TransformerConfig, tx, mesh=None,
                     loss_override=None):
-    """``loss_override(params, batch)`` substitutes the plain loss
-    (used by the pipeline-parallel schedule)."""
+    """``loss_override(params, batch)`` substitutes the next-token loss
+    (the pipeline-parallel schedule; the block-diffusion objective,
+    ``models/block_diffusion.py``).  It returns the loss, or the loss
+    and a dict of counters that join the step's ``metrics``."""
     def train_step(state, batch):
         compute = loss_override or (
-            lambda p, b: loss_fn(p, b, cfg, mesh))
-        loss, grads = jax.value_and_grad(
-            lambda p: compute(p, batch))(state["params"])
+            lambda p, b: loss_and_counters(p, b, cfg, mesh))
+
+        def objective(p):
+            out = compute(p, batch)
+            return out if isinstance(out, tuple) else (out, {})
+
+        (loss, counters), grads = jax.value_and_grad(
+            objective, has_aux=True)(state["params"])
         with jax.named_scope("optimizer"):
             updates, new_opt = tx.update(grads, state["opt"],
                                          state["params"])
@@ -305,8 +386,8 @@ def make_train_step(cfg: TransformerConfig, tx, mesh=None,
                 state["params"], updates)
         new_state = {"params": new_params, "opt": new_opt,
                      "step": state["step"] + 1}
-        metrics = {"loss": loss,
-                   "grad_norm": optax_global_norm(grads)}
+        metrics = {"loss": loss, "grad_norm": optax_global_norm(grads),
+                   **counters}
         return new_state, metrics
 
     donate = (0,)

@@ -9,14 +9,27 @@ Differentiable through a ``custom_vjp``: the forward kernel also emits
 the per-row log-sum-exp, and the backward is one Pallas kernel too
 (``flash_attention_bwd``) that recomputes the probabilities tile by
 tile from (q, k, v, out, lse): a grid over K/V blocks, a loop over the
-Q blocks from the diagonal down (blocks above it are never visited,
-only blocks the diagonal crosses are masked), ``dk``/``dv`` carried by
-the loop and ``dq`` accumulated in a float32 VMEM scratch, so no
-``[L, block]`` tile goes to HBM in either direction.  The MXU gets its
+Q blocks that see each (under the causal mask from the diagonal down:
+blocks above it are never visited, only blocks the diagonal crosses are
+masked), ``dk``/``dv`` carried by the loop and ``dq`` accumulated in a
+float32 VMEM scratch, so no ``[L, block]`` tile goes to HBM in either
+direction.  The MXU gets its
 operands in the input dtype with float32 accumulation (``p`` cast to
 ``dout``'s dtype, ``ds`` to ``q``'s); ``lse``, ``delta``, ``exp`` and
 the three accumulators are float32, cast once at the end.  (bf16 dots
 in the forward and K/V tiling for L = 32k are ROADMAP S3.)
+
+What is attended to is a static description (``ops/attention_mask.py``:
+``CAUSAL``, ``FULL``, ``BlockDiffusion(seq_len, block)``): the kernels
+take from it the elementwise predicate and, per Q tile (forward) or K
+tile (backward), the ranges of opposite tiles to visit, each range
+masked or not.  Tiles the mask empties are never visited.
+
+``k`` and ``v`` may have fewer heads than ``q`` (grouped K/V): query
+head ``h`` reads K/V head ``h // group`` through the block index, so
+K/V are never repeated in HBM; the backward's grid runs the group's
+query heads one after another over each K/V head and sums ``dk``/``dv``
+in a float32 VMEM scratch.
 
 ``attention()`` picks the kernel on TPU and the jnp reference
 (ops.ring_attention.full_attention) elsewhere; tests run the kernel in
@@ -31,6 +44,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+from ray_tpu.ops.attention_mask import CAUSAL
 
 _NEG_INF = -1e30
 _LANES = 128
@@ -49,7 +64,7 @@ _BWD_VMEM_BYTES = 64 * 2 ** 20
 
 
 def _flash_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, block_k: int,
-                  causal: bool, scale: float, seq_len: int):
+                  mask, scale: float, seq_len: int):
     # q_ref/o_ref: [block_q, D]; k_ref/v_ref: [L, D];
     # lse_ref: [block_q, _LANES] (row value broadcast along lanes).
     block_q = q_ref.shape[0]
@@ -57,17 +72,17 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, block_k: int,
     q_blk = pl.program_id(1)
     q = q_ref[:].astype(jnp.float32) * scale
 
-    def body(i, carry):
+    def body(masked, i, carry):
         acc, m_i, l_i = carry
         k = k_ref[pl.ds(i * block_k, block_k), :].astype(jnp.float32)
         v = v_ref[pl.ds(i * block_k, block_k), :].astype(jnp.float32)
         s = jnp.dot(q, k.T, preferred_element_type=jnp.float32)
-        if causal:
+        if masked:
             q_pos = q_blk * block_q + jax.lax.broadcasted_iota(
                 jnp.int32, (block_q, block_k), 0)
             k_pos = i * block_k + jax.lax.broadcasted_iota(
                 jnp.int32, (block_q, block_k), 1)
-            s = jnp.where(q_pos >= k_pos, s, _NEG_INF)
+            s = jnp.where(mask.allowed(q_pos, k_pos), s, _NEG_INF)
         m_new = jnp.maximum(m_i, jnp.max(s, axis=-1, keepdims=True))
         p = jnp.exp(s - m_new)
         corr = jnp.exp(m_i - m_new)
@@ -75,35 +90,41 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, block_k: int,
         acc = acc * corr + jnp.dot(p, v, preferred_element_type=jnp.float32)
         return acc, m_new, l_new
 
-    num_k = seq_len // block_k
-    if causal:
-        # Only blocks at or before this q block contribute.
-        num_k = jnp.minimum(num_k, pl.cdiv((q_blk + 1) * block_q, block_k))
-    acc0 = jnp.zeros((block_q, d), jnp.float32)
-    m0 = jnp.full((block_q, 1), _NEG_INF, jnp.float32)
-    l0 = jnp.zeros((block_q, 1), jnp.float32)
-    acc, m, l = jax.lax.fori_loop(0, num_k, body, (acc0, m0, l0))
+    carry = (jnp.zeros((block_q, d), jnp.float32),
+             jnp.full((block_q, 1), _NEG_INF, jnp.float32),
+             jnp.zeros((block_q, 1), jnp.float32))
+    # Only the K tiles the mask admits for this Q tile.
+    for first, stop, masked in mask.k_ranges(q_blk, block_q, block_k,
+                                             seq_len // block_k):
+        carry = jax.lax.fori_loop(first, stop,
+                                  functools.partial(body, masked), carry)
+    acc, m, l = carry
     l = jnp.maximum(l, 1e-20)
     o_ref[:] = (acc / l).astype(o_ref.dtype)
     lse_ref[:] = jnp.broadcast_to(m + jnp.log(l), (block_q, _LANES))
 
 
-def _flash_forward(qh, kh, vh, causal, block_q, block_k, interpret):
-    """[BH, L, D] x3 -> (out [BH, L, D], lse [BH, L] f32)."""
+def _flash_forward(qh, kh, vh, mask, block_q, block_k, interpret):
+    """q [BH, L, D], k/v [BH // group, L, D] -> (out [BH, L, D],
+    lse [BH, L] f32)."""
     BH, L, D = qh.shape
-    if L % block_q or L % block_k:
+    group = BH // kh.shape[0]
+    span = mask.tile_span(L)
+    if span % block_q or span % block_k:
         raise ValueError(
-            f"sequence length {L} must be a multiple of the block sizes "
+            f"sequence length {span} must be a multiple of the block sizes "
             f"({block_q}, {block_k}); pad upstream")
     kernel = functools.partial(_flash_kernel, block_k=block_k,
-                               causal=causal, scale=D ** -0.5, seq_len=L)
+                               mask=mask, scale=D ** -0.5, seq_len=L)
     out, lse = pl.pallas_call(
         kernel,
         grid=(BH, L // block_q),
         in_specs=[
             pl.BlockSpec((None, block_q, D), lambda b, i: (b, i, 0)),
-            pl.BlockSpec((None, L, D), lambda b, i: (b, 0, 0)),
-            pl.BlockSpec((None, L, D), lambda b, i: (b, 0, 0)),
+            # a group's query heads follow one another, so a K/V head
+            # is fetched once for all of them
+            pl.BlockSpec((None, L, D), lambda b, i: (b // group, 0, 0)),
+            pl.BlockSpec((None, L, D), lambda b, i: (b // group, 0, 0)),
         ],
         out_specs=[
             pl.BlockSpec((None, block_q, D), lambda b, i: (b, i, 0)),
@@ -119,29 +140,20 @@ def _flash_forward(qh, kh, vh, causal, block_q, block_k, interpret):
     return out, lse[:, :, 0]
 
 
-def _causal_q_blocks(k_blk, block_q, block_k, num_q):
-    """The q blocks that see k block ``k_blk`` under the causal mask:
-    ``(first, unmasked)`` -- blocks ``[first, num_q)`` have a row at or
-    past the block's first key, and from ``unmasked`` on every row is
-    past its last key, so no mask is needed.  Python ints or traced."""
-    first = (k_blk * block_k) // block_q
-    unmasked = jnp.minimum(pl.cdiv((k_blk + 1) * block_k - 1, block_q),
-                           num_q)
-    return first, unmasked
-
-
 def _flash_bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-                      dq_ref, dk_ref, dv_ref, dq_acc, *, block_q: int,
-                      causal: bool, scale: float):
-    # Grid (BH, k block).  k_ref/v_ref/dk_ref/dv_ref: [block_k, D];
-    # q_ref/do_ref/dq_ref: [L, D], resident across a head's k blocks;
-    # lse_ref/delta_ref: [L // block_q, block_q], one row a q block;
-    # dq_acc: [L, D] f32.  Tiles are held transposed, [block_k, block_q],
+                      dq_ref, dk_ref, dv_ref, dq_acc, *kv_acc, block_q: int,
+                      mask, scale: float):
+    # Grid (K/V head, query head of its group, k block).
+    # k_ref/v_ref/dk_ref/dv_ref: [block_k, D]; q_ref/do_ref/dq_ref:
+    # [L, D], resident across a query head's k blocks; lse_ref/delta_ref:
+    # [L // block_q, block_q], one row a q block; dq_acc: [L, D] f32;
+    # kv_acc (grouped K/V only): dk and dv of the K/V head so far,
+    # [L, D] f32 each.  Tiles are held transposed, [block_k, block_q],
     # so the row statistics broadcast along sublanes and dk/dv need no
     # transpose.
     block_k, d = k_ref.shape
     num_q = q_ref.shape[0] // block_q
-    k_blk = pl.program_id(1)
+    head, k_blk = pl.program_id(1), pl.program_id(2)
     f32 = jnp.float32
 
     @pl.when(k_blk == 0)
@@ -162,7 +174,7 @@ def _flash_bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                 jnp.int32, (block_k, block_q), 1)
             k_pos = k_blk * block_k + jax.lax.broadcasted_iota(
                 jnp.int32, (block_k, block_q), 0)
-            s = jnp.where(q_pos >= k_pos, s, _NEG_INF)
+            s = jnp.where(mask.allowed(q_pos, k_pos), s, _NEG_INF)
         p = jnp.exp(s - lse_ref[pl.ds(i, 1), :])
         dv = dv + jnp.dot(p.astype(do.dtype), do, preferred_element_type=f32)
         dp = jax.lax.dot_general(v, do, _NT, preferred_element_type=f32)
@@ -172,49 +184,75 @@ def _flash_bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                                                preferred_element_type=f32)
         return dk, dv
 
-    first, unmasked = (_causal_q_blocks(k_blk, block_q, block_k, num_q)
-                       if causal else (0, 0))
     zeros = jnp.zeros((block_k, d), f32)
-    carry = jax.lax.fori_loop(first, unmasked,
-                              functools.partial(body, True), (zeros, zeros))
-    dk, dv = jax.lax.fori_loop(unmasked, num_q,
-                               functools.partial(body, False), carry)
+    carry = (zeros, zeros)
+    # Only the Q tiles that see this K tile.
+    for first, stop, masked in mask.q_ranges(k_blk, block_q, block_k, num_q):
+        carry = jax.lax.fori_loop(first, stop,
+                                  functools.partial(body, masked), carry)
+    dk, dv = carry
+    if kv_acc:
+        # The K/V head's block comes round once a query head; every
+        # visit writes the sum so far, the last one the whole of it.
+        dk_acc, dv_acc = kv_acc
+        keys = pl.ds(pl.multiple_of(k_blk * block_k, block_k), block_k)
+
+        @pl.when(head == 0)
+        def _():
+            dk_acc[keys, :] = dk
+            dv_acc[keys, :] = dv
+
+        @pl.when(head > 0)
+        def _():
+            dk_acc[keys, :] += dk
+            dv_acc[keys, :] += dv
+
+        dk, dv = dk_acc[keys, :], dv_acc[keys, :]
     dk_ref[...] = dk.astype(dk_ref.dtype)
     dv_ref[...] = dv.astype(dv_ref.dtype)
 
-    @pl.when(k_blk == pl.num_programs(1) - 1)
+    @pl.when(k_blk == pl.num_programs(2) - 1)
     def _():
         dq_ref[...] = dq_acc[...].astype(dq_ref.dtype)
 
 
 @jax.named_scope("flash_attention_bwd")
-def _flash_backward(qh, kh, vh, out, lse, dout, causal, interpret):
-    """(dq, dk, dv) from the forward's residuals, each [BH, L, D]."""
+def _flash_backward(qh, kh, vh, out, lse, dout, mask, interpret):
+    """(dq [BH, L, D], dk, dv [BH // group, L, D]) from the forward's
+    residuals."""
     BH, L, D = qh.shape
-    block = next((b for b in _BWD_BLOCKS if L % b == 0), None)
+    heads_kv = kh.shape[0]
+    group = BH // heads_kv
+    span = mask.tile_span(L)
+    block = next((b for b in _BWD_BLOCKS if span % b == 0), None)
     if block is None:
         raise ValueError(
-            f"sequence length {L} must be a multiple of "
+            f"sequence length {span} must be a multiple of "
             f"{_BWD_BLOCKS[-1]} for the backward kernel; pad upstream")
     nq = L // block
     f32 = jnp.float32
     delta = jnp.sum(dout.astype(f32) * out.astype(f32), axis=-1)  # [BH, L]
-    whole = pl.BlockSpec((None, L, D), lambda b, j: (b, 0, 0))
-    blocked = pl.BlockSpec((None, block, D), lambda b, j: (b, j, 0))
-    stats = pl.BlockSpec((None, nq, block), lambda b, j: (b, 0, 0))
+    whole = pl.BlockSpec((None, L, D), lambda b, g, j: (b * group + g, 0, 0))
+    blocked = pl.BlockSpec((None, block, D), lambda b, g, j: (b, j, 0))
+    stats = pl.BlockSpec((None, nq, block),
+                         lambda b, g, j: (b * group + g, 0, 0))
     kernel = functools.partial(_flash_bwd_kernel, block_q=block,
-                               causal=causal, scale=D ** -0.5)
+                               mask=mask, scale=D ** -0.5)
+    scratch = [pltpu.VMEM((L, D), f32)]
+    if group > 1:
+        scratch += [pltpu.VMEM((L, D), f32)] * 2
     return tuple(pl.pallas_call(
         kernel,
-        grid=(BH, nq),
+        grid=(heads_kv, group, nq),
         in_specs=[whole, blocked, blocked, whole, stats, stats],
         out_specs=[whole, blocked, blocked],
         out_shape=[jax.ShapeDtypeStruct(x.shape, x.dtype)
                    for x in (qh, kh, vh)],
-        scratch_shapes=[pltpu.VMEM((L, D), f32)],
-        # dq accumulates over a head's k blocks: that axis runs in order.
+        scratch_shapes=scratch,
+        # dq accumulates over a query head's k blocks and dk/dv over a
+        # K/V head's query heads: those axes run in order.
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "arbitrary"),
+            dimension_semantics=("parallel", "arbitrary", "arbitrary"),
             vmem_limit_bytes=_BWD_VMEM_BYTES),
         interpret=interpret,
         name="flash_attention_bwd",
@@ -223,49 +261,55 @@ def _flash_backward(qh, kh, vh, out, lse, dout, causal, interpret):
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
-def _flash(qh, kh, vh, causal, block_q, block_k, interpret):
-    return _flash_forward(qh, kh, vh, causal, block_q, block_k,
+def _flash(qh, kh, vh, mask, block_q, block_k, interpret):
+    return _flash_forward(qh, kh, vh, mask, block_q, block_k,
                           interpret)[0]
 
 
-def _flash_vjp_fwd(qh, kh, vh, causal, block_q, block_k, interpret):
-    out, lse = _flash_forward(qh, kh, vh, causal, block_q, block_k,
+def _flash_vjp_fwd(qh, kh, vh, mask, block_q, block_k, interpret):
+    out, lse = _flash_forward(qh, kh, vh, mask, block_q, block_k,
                               interpret)
     return out, (qh, kh, vh, out, lse)
 
 
-def _flash_vjp_bwd(causal, block_q, block_k, interpret, res, dout):
+def _flash_vjp_bwd(mask, block_q, block_k, interpret, res, dout):
     del block_q, block_k     # the forward's; the backward picks its own
-    return _flash_backward(*res, dout, causal, interpret)
+    return _flash_backward(*res, dout, mask, interpret)
 
 
 _flash.defvjp(_flash_vjp_fwd, _flash_vjp_bwd)
 
 
-@functools.partial(jax.jit, static_argnames=("causal", "block_q", "block_k",
+@functools.partial(jax.jit, static_argnames=("mask", "block_q", "block_k",
                                              "interpret"))
 def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array,
-                    causal: bool = True, block_q: int = 128,
+                    mask=CAUSAL, block_q: int = 128,
                     block_k: int = 128, interpret: bool = False
                     ) -> jax.Array:
-    """q, k, v: [B, L, H, D] -> [B, L, H, D].  L must be a multiple of
-    the block sizes (pad upstream).  ``interpret`` runs the kernel in
-    the Pallas interpreter (CPU tests)."""
+    """q: [B, L, H, D]; k, v: [B, L, H // group, D] -> [B, L, H, D].
+    ``mask`` is one of ``ops.attention_mask``'s descriptions.  L must be
+    a multiple of the block sizes (pad upstream).  ``interpret`` runs
+    the kernel in the Pallas interpreter (CPU tests)."""
     B, L, H, D = q.shape
-    # Collapse batch x heads into the leading grid dimension.
+    heads_kv = k.shape[2]
+    if H % heads_kv or v.shape != k.shape:
+        raise ValueError(f"{H} query heads over K/V of shapes "
+                         f"{k.shape}, {v.shape}")
+    # Collapse batch x heads into the leading grid dimension: query
+    # head b * H + h reads K/V head (b * H + h) // group.
     qh = q.transpose(0, 2, 1, 3).reshape(B * H, L, D)
-    kh = k.transpose(0, 2, 1, 3).reshape(B * H, L, D)
-    vh = v.transpose(0, 2, 1, 3).reshape(B * H, L, D)
-    out = _flash(qh, kh, vh, causal, block_q, block_k, interpret)
+    kh = k.transpose(0, 2, 1, 3).reshape(B * heads_kv, L, D)
+    vh = v.transpose(0, 2, 1, 3).reshape(B * heads_kv, L, D)
+    out = _flash(qh, kh, vh, mask, block_q, block_k, interpret)
     return out.reshape(B, H, L, D).transpose(0, 2, 1, 3)
 
 
 def attention(q: jax.Array, k: jax.Array, v: jax.Array,
-              causal: bool = True) -> jax.Array:
+              mask=CAUSAL) -> jax.Array:
     """Backend dispatch: pallas kernel on TPU, jnp reference elsewhere."""
     from ray_tpu.ops.ring_attention import full_attention
     # Trace-time decision: the backend is fixed per process.
     if (jax.default_backend() == "tpu" and q.shape[1] % 128 == 0
             and q.shape[-1] >= 64):
-        return flash_attention(q, k, v, causal=causal)
-    return full_attention(q, k, v, causal=causal)
+        return flash_attention(q, k, v, mask=mask)
+    return full_attention(q, k, v, mask=mask)

@@ -22,6 +22,8 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 
+from ray_tpu.ops.attention_mask import CAUSAL, FULL
+
 
 def _block_attn(q, k, v, q_idx, kv_idx, chunk, causal, scale):
     """One q-chunk x kv-chunk block: returns (out_unnorm, row_max, row_sum).
@@ -98,18 +100,35 @@ def ring_attention(q: jax.Array, k: jax.Array, v: jax.Array,
 
 
 def full_attention(q: jax.Array, k: jax.Array, v: jax.Array,
-                   causal: bool = True,
+                   mask=CAUSAL,
                    scale: Optional[float] = None) -> jax.Array:
-    """Single-device reference attention ([B, L, H, D])."""
+    """Single-device reference attention: q [B, L, H, D], k/v
+    [B, L, H // group, D] (query head ``h`` reads K/V head
+    ``h // group``); ``mask`` is one of ``ops.attention_mask``'s
+    descriptions."""
     if scale is None:
         scale = q.shape[-1] ** -0.5
-    logits = jnp.einsum("bqhd,bkhd->bhqk", q, k,
+    B, lq, H, D = q.shape
+    lk, heads_kv = k.shape[1], k.shape[2]
+    allowed = None
+    if mask != FULL:
+        allowed = mask.allowed(
+            jax.lax.broadcasted_iota(jnp.int32, (lq, lk), 0),
+            jax.lax.broadcasted_iota(jnp.int32, (lq, lk), 1))
+    if heads_kv == H:
+        logits = jnp.einsum("bqhd,bkhd->bhqk", q, k,
+                            preferred_element_type=jnp.float32) * scale
+        if allowed is not None:
+            logits = jnp.where(allowed[None, None], logits, -jnp.inf)
+        p = jax.nn.softmax(logits, axis=-1)
+        return jnp.einsum("bhqk,bkhd->bqhd", p.astype(v.dtype), v,
+                          preferred_element_type=jnp.float32).astype(q.dtype)
+    qg = q.reshape(B, lq, heads_kv, H // heads_kv, D)
+    logits = jnp.einsum("bqhgd,bkhd->bhgqk", qg, k,
                         preferred_element_type=jnp.float32) * scale
-    if causal:
-        li, lj = logits.shape[-2], logits.shape[-1]
-        mask = jax.lax.broadcasted_iota(jnp.int32, (li, lj), 0) >= \
-            jax.lax.broadcasted_iota(jnp.int32, (li, lj), 1)
-        logits = jnp.where(mask[None, None], logits, -jnp.inf)
+    if allowed is not None:
+        logits = jnp.where(allowed[None, None, None], logits, -jnp.inf)
     p = jax.nn.softmax(logits, axis=-1)
-    return jnp.einsum("bhqk,bkhd->bqhd", p.astype(v.dtype), v,
-                      preferred_element_type=jnp.float32).astype(q.dtype)
+    out = jnp.einsum("bhgqk,bkhd->bqhgd", p.astype(v.dtype), v,
+                     preferred_element_type=jnp.float32)
+    return out.reshape(B, lq, H, D).astype(q.dtype)

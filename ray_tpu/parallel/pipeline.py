@@ -57,7 +57,7 @@ def make_pp_loss_fn(cfg: TransformerConfig, mesh, n_micro: int):
     assert mesh.shape.get("tp", 1) == 1, "pp does not compose with tp"
     assert mesh.shape.get("ep", 1) == 1, "pp does not compose with ep"
     assert cfg.moe_experts == 0, \
-        "MoE composes with ep, not pp (aux loss is not plumbed here)"
+        "MoE composes with ep, not pp (its counters are not plumbed here)"
     rot = [(i, (i + 1) % pp) for i in range(pp)]
 
     def stage_loss(layers, embed, lnf, head, tokens):
@@ -74,19 +74,15 @@ def make_pp_loss_fn(cfg: TransformerConfig, mesh, n_micro: int):
             jnp.arange(S, dtype=jnp.int32)[None], (mb, S))
 
         def run_stage(x):
-            def body(carry, lp):
-                h, aux = carry
-                h, a = apply_layer(h, lp, positions, cfg, mesh=None)
-                return (h, aux + a), None
+            def body(h, lp):
+                return apply_layer(h, lp, positions, cfg, mesh=None)[0], None
 
             body_fn = jax.checkpoint(body) if cfg.remat else body
-            (x, aux), _ = jax.lax.scan(
-                body_fn, (x, jnp.zeros((), jnp.float32)), layers)
-            return x, aux
+            return jax.lax.scan(body_fn, x, layers)[0]
 
         def ce(h, tgt):
             logits = jnp.einsum(
-                "bsd,dv->bsv", _rms_norm(h, lnf),
+                "bsd,dv->bsv", _rms_norm(h, lnf, cfg.norm_eps),
                 head).astype(jnp.float32)
             logz = jax.nn.logsumexp(logits, axis=-1)
             gold = jnp.take_along_axis(
@@ -101,7 +97,7 @@ def make_pp_loss_fn(cfg: TransformerConfig, mesh, n_micro: int):
             inject = jnp.take(embed, micro_in[min(t, n_micro - 1)],
                               axis=0).astype(cfg.dtype)
             x = jnp.where((p == 0) & (t < n_micro), inject, state)
-            y, _aux = run_stage(x)
+            y = run_stage(x)
             # The LAST stage finishes microbatch t - (pp - 1).
             m = t - (pp - 1)
             if 0 <= m < n_micro:

@@ -11,10 +11,12 @@ result queue consumed by ``get_next``).
 
 from __future__ import annotations
 
+import numbers
 import queue
 import threading
 from typing import Any, Dict, Optional
 
+from ray_tpu._private.metrics_agent import record_internal
 from ray_tpu.util import tracing
 
 
@@ -69,6 +71,18 @@ class Session:
     def report(self, **metrics):
         with tracing.span("train.report", category="train"):
             self._queue.put(TrainingResult("report", dict(metrics)))
+            self._mirror(metrics)
+
+    def _mirror(self, metrics) -> None:
+        """Every number a worker reports is also a gauge on /metrics,
+        ``ray_tpu.train.<name>`` with the worker's rank, beside the
+        scheduler tick's."""
+        rank = str(self.world_rank)
+        for name, value in metrics.items():
+            if isinstance(value, numbers.Real) and not isinstance(value,
+                                                                  bool):
+                record_internal(f"ray_tpu.train.{name}", float(value),
+                                rank=rank)
 
     def save_checkpoint(self, **checkpoint):
         with tracing.span("train.report", category="train",

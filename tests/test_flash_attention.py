@@ -8,8 +8,8 @@ import jax
 import jax.numpy as jnp
 import pytest
 
-from ray_tpu.ops.flash_attention import (_causal_q_blocks, attention,
-                                         flash_attention)
+from ray_tpu.ops.attention_mask import CAUSAL, FULL, BlockDiffusion
+from ray_tpu.ops.flash_attention import attention, flash_attention
 from ray_tpu.ops.ring_attention import full_attention
 
 # Max abs error allowed on outputs and gradients of O(1) magnitude:
@@ -18,10 +18,16 @@ from ray_tpu.ops.ring_attention import full_attention
 _TOL = {jnp.float32: 2e-5, jnp.bfloat16: 5e-2}
 
 
-def _qkvd(dtype, B=2, L=256, H=2, D=64):
+def _qkvd(dtype, B=2, L=256, H=2, D=64, kv_heads=None):
+    """q, k, v, dout; k and v with ``kv_heads`` heads (default: H)."""
     keys = jax.random.split(jax.random.PRNGKey(0), 4)
-    return [jax.random.normal(k, (B, L, H, D), jnp.float32).astype(dtype)
-            for k in keys]
+    heads = (H, kv_heads or H, kv_heads or H, H)
+    return [jax.random.normal(k, (B, L, h, D), jnp.float32).astype(dtype)
+            for k, h in zip(keys, heads)]
+
+
+def _mask(causal):
+    return CAUSAL if causal else FULL
 
 
 def _max_err(a, b):
@@ -33,28 +39,30 @@ def _max_err(a, b):
 @pytest.mark.parametrize("causal", [True, False])
 def test_forward_matches_full_attention(dtype, causal):
     q, k, v, _ = _qkvd(dtype)
-    got = flash_attention(q, k, v, causal=causal, interpret=True)
-    want = full_attention(q, k, v, causal=causal)
+    got = flash_attention(q, k, v, mask=_mask(causal), interpret=True)
+    want = full_attention(q, k, v, mask=_mask(causal))
     assert got.dtype == want.dtype and got.shape == want.shape
     assert _max_err(got, want) <= _TOL[dtype]
 
 
 def _assert_grads_match(dtype, causal, shape=None, **blocks):
     """jax.grad through the kernel's custom_vjp (the Pallas backward in
-    interpret mode) against jax.grad of ``full_attention``."""
+    interpret mode) against jax.grad of ``full_attention``.  ``causal``
+    is a flag or a mask description."""
     q, k, v, dout = _qkvd(dtype, **(shape or {}))
+    mask = causal if not isinstance(causal, bool) else _mask(causal)
 
     def scalar(fn):
         return lambda q, k, v: jnp.sum(
             fn(q, k, v).astype(jnp.float32) * dout.astype(jnp.float32))
 
     got = jax.grad(scalar(lambda q, k, v: flash_attention(
-        q, k, v, causal=causal, interpret=True, **blocks)),
+        q, k, v, mask=mask, interpret=True, **blocks)),
         (0, 1, 2))(q, k, v)
     want = jax.grad(scalar(lambda q, k, v: full_attention(
-        q, k, v, causal=causal)), (0, 1, 2))(q, k, v)
+        q, k, v, mask=mask)), (0, 1, 2))(q, k, v)
     for name, g, w in zip("qkv", got, want):
-        assert g.dtype == w.dtype
+        assert g.dtype == w.dtype and g.shape == w.shape
         assert _max_err(g, w) <= _TOL[dtype], name
 
 
@@ -126,8 +134,10 @@ def test_causal_bounds_visit_the_blocks_at_or_below_the_diagonal(
     num_q, num_k = seq_len // block_q, seq_len // block_k
     visited = 0
     for j in range(num_k):
-        first, unmasked = map(int, _causal_q_blocks(
-            j, block_q, block_k, num_q))
+        (first, unmasked, masked), (also, stop, plain) = CAUSAL.q_ranges(
+            j, block_q, block_k, num_q)
+        first, unmasked, also, stop = map(int, (first, unmasked, also, stop))
+        assert masked and not plain and also == unmasked and stop == num_q
         assert 0 <= first <= unmasked <= num_q
         for i in range(num_q):
             sees_any = (i + 1) * block_q - 1 >= j * block_k
@@ -144,9 +154,9 @@ def test_unequal_blocks_and_train_step_shape():
     value_and_grad straight through the kernel, as make_train_step
     takes it."""
     q, k, v, _ = _qkvd(jnp.float32, B=1, L=512, H=1)
-    want = full_attention(q, k, v, causal=True)
+    want = full_attention(q, k, v)
     for bq, bk in ((256, 128), (128, 256)):
-        got = flash_attention(q, k, v, causal=True, block_q=bq,
+        got = flash_attention(q, k, v, mask=CAUSAL, block_q=bq,
                               block_k=bk, interpret=True)
         assert _max_err(got, want) <= _TOL[jnp.float32], (bq, bk)
     loss, grads = jax.value_and_grad(
@@ -165,6 +175,122 @@ def test_rejects_ragged_length_and_dispatch_off_chip():
             q, k, v, block_q=64, block_k=64, interpret=True)))(q)
     # Off the chip attention() is the reference, whatever the shape.
     assert _max_err(attention(q, k, v), full_attention(q, k, v)) == 0.0
+
+
+# --- masks by description and grouped K/V heads -------------------------
+
+def _tile_has(mask, q_tile, k_tile, block_q, block_k):
+    """(some pair allowed, every pair allowed) of one tile pair, by the
+    elementwise predicate."""
+    import numpy as np
+    q_pos = q_tile * block_q + np.arange(block_q)[:, None]
+    k_pos = k_tile * block_k + np.arange(block_k)[None, :]
+    allowed = np.asarray(mask.allowed(jnp.asarray(q_pos), jnp.asarray(k_pos)))
+    return bool(allowed.any()), bool(allowed.all())
+
+
+def _ranges_cover(ranges, n):
+    """tile -> masked flag, for the tiles the ranges visit; no tile is
+    visited twice."""
+    out = {}
+    for first, stop, masked in ranges:
+        for t in range(int(first), int(stop)):
+            assert 0 <= t < n and t not in out, (t, ranges)
+            out[t] = masked
+    return out
+
+
+@pytest.mark.parametrize("seq_len,block,block_q,block_k", [
+    (32, 4, 8, 8), (32, 4, 16, 8), (32, 4, 8, 16), (64, 32, 16, 16),
+    (64, 32, 8, 32), (64, 16, 16, 16), (32, 8, 4, 4), (32, 2, 8, 4)])
+def test_block_diffusion_ranges_admit_exactly_the_tiles_with_an_allowed_pair(
+        seq_len, block, block_q, block_k):
+    """(b) Enumerated at small sizes: a tile pair is visited iff it holds
+    an allowed pair, and flagged unmasked iff it holds no disallowed
+    one -- from the Q side (forward) and from the K side (backward)."""
+    mask = BlockDiffusion(seq_len, block)
+    num_q, num_k = 2 * seq_len // block_q, 2 * seq_len // block_k
+    pairs = 0
+    for i in range(num_q):
+        visit = _ranges_cover(mask.k_ranges(i, block_q, block_k, num_k),
+                              num_k)
+        for j in range(num_k):
+            some, every = _tile_has(mask, i, j, block_q, block_k)
+            assert (j in visit) == some, (i, j)
+            if some:
+                assert visit[j] == (not every), (i, j)
+        pairs += len(visit)
+    back = 0
+    for j in range(num_k):
+        visit = _ranges_cover(mask.q_ranges(j, block_q, block_k, num_q),
+                              num_q)
+        for i in range(num_q):
+            some, every = _tile_has(mask, i, j, block_q, block_k)
+            assert (i in visit) == some, (i, j)
+            if some:
+                assert visit[i] == (not every), (i, j)
+        back += len(visit)
+    assert pairs == back
+
+
+def test_block_diffusion_visits_80_of_256_tiles_at_the_cell_size():
+    mask = BlockDiffusion(4096, 4)
+    n = 2 * 4096 // 512
+    visited = [sum(int(stop) - int(first) for first, stop, _ in
+                   ranges(t, 512, 512, n))
+               for ranges in (mask.k_ranges, mask.q_ranges) for t in range(n)]
+    assert sum(visited[:n]) == sum(visited[n:]) == 80 and n * n == 256
+    # and of the 4 L^2 pairs the predicate admits L^2 + L B
+    import numpy as np
+    small = BlockDiffusion(64, 4)
+    pos = jnp.arange(128)
+    assert int(np.sum(np.asarray(small.allowed(pos[:, None], pos[None, :])))
+               ) == 64 * 64 + 64 * 4
+    with pytest.raises(ValueError, match="multiple or a divisor"):
+        BlockDiffusion(96, 6).k_ranges(0, 16, 16, 12)
+    with pytest.raises(ValueError, match="positions"):
+        BlockDiffusion(64, 4).tile_span(64)
+
+
+# (a) Forward and backward under the block-diffusion mask, query heads
+# grouped 8 to a K/V head and ungrouped, block lengths 4 and 32, two
+# tile sizes: 2L = 256 runs the backward in 128-tiles, 2L = 1024 in
+# 512-tiles (the cell's), and the forward in 128- or 256-tiles.
+@pytest.mark.parametrize("block", [4, 32])
+@pytest.mark.parametrize("shape,blocks", [
+    (dict(B=1, L=256, H=8, kv_heads=1), {}),
+    (dict(B=2, L=256, H=2, kv_heads=2), {}),
+    (dict(B=1, L=1024, H=8, kv_heads=1, D=128),
+     dict(block_q=256, block_k=256)),
+], ids=["grouped8-128", "ungrouped-128", "grouped8-512"])
+def test_block_diffusion_forward_and_backward_match_the_dense_softmax(
+        block, shape, blocks):
+    mask = BlockDiffusion(shape["L"] // 2, block)
+    q, k, v, _ = _qkvd(jnp.float32, **shape)
+    got = flash_attention(q, k, v, mask=mask, interpret=True, **blocks)
+    # the dense masked softmax, written out: K/V repeated per query head
+    group = q.shape[2] // k.shape[2]
+    pos = jnp.arange(q.shape[1])
+    logits = jnp.einsum("bqhd,bkhd->bhqk", q, jnp.repeat(k, group, 2)
+                        ) * q.shape[-1] ** -0.5
+    logits = jnp.where(mask.allowed(pos[:, None], pos[None, :]), logits,
+                       -jnp.inf)
+    want = jnp.einsum("bhqk,bkhd->bqhd", jax.nn.softmax(logits, -1),
+                      jnp.repeat(v, group, 2))
+    assert _max_err(got, want) <= _TOL[jnp.float32]
+    assert _max_err(full_attention(q, k, v, mask=mask), want) \
+        <= _TOL[jnp.float32]
+    _assert_grads_match(jnp.float32, mask, shape, **blocks)
+
+
+@pytest.mark.parametrize("causal", [True, False])
+def test_grouped_kv_heads_under_the_older_masks(causal):
+    """dk and dv are summed over the group's query heads (bfloat16 as
+    the cell runs it: the sum is kept in float32)."""
+    _assert_grads_match(jnp.bfloat16, causal,
+                        dict(B=2, L=256, H=4, kv_heads=2))
+    _assert_grads_match(jnp.float32, causal,
+                        dict(B=1, L=640, H=4, kv_heads=1))
 
 
 @pytest.fixture(scope="module")
@@ -190,6 +316,23 @@ def test_gradient_compiles_for_the_chip_at_the_cell_width(one_v5e_chip):
     compiled = jax.jit(jax.grad(lambda q, k, v: jnp.sum(
         flash_attention(q, k, v).astype(jnp.float32)), (0, 1, 2))).lower(
             x, x, x).compile()
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") == 2
+    assert "flash_attention_fwd" in text and "flash_attention_bwd" in text
+    assert " while(" not in text
+
+
+def test_block_diffusion_gradient_compiles_for_the_chip_at_the_cell_width(
+        one_v5e_chip):
+    """Both kernels at the block-diffusion cell's attention shape: 4 rows
+    x 8,192 positions, 32 query heads on 4 K/V heads of 128, bfloat16."""
+    q = jax.ShapeDtypeStruct((4, 8192, 32, 128), jnp.bfloat16,
+                             sharding=one_v5e_chip)
+    kv = jax.ShapeDtypeStruct((4, 8192, 4, 128), jnp.bfloat16,
+                              sharding=one_v5e_chip)
+    compiled = jax.jit(jax.grad(lambda q, k, v: jnp.sum(flash_attention(
+        q, k, v, mask=BlockDiffusion(4096, 4)).astype(jnp.float32)),
+        (0, 1, 2))).lower(q, kv, kv).compile()
     text = compiled.as_text()
     assert text.count("tpu_custom_call") == 2
     assert "flash_attention_fwd" in text and "flash_attention_bwd" in text

@@ -1,6 +1,7 @@
 """Model + parallelism tests on the virtual 8-device CPU mesh:
 ring attention vs full attention, sharded train step, graft entry."""
 
+import dataclasses
 import os
 
 import jax
@@ -25,7 +26,7 @@ def test_ring_attention_matches_full():
     k = jnp.asarray(rng.standard_normal((B, S, H, D)), jnp.float32)
     v = jnp.asarray(rng.standard_normal((B, S, H, D)), jnp.float32)
 
-    want = full_attention(q, k, v, causal=True)
+    want = full_attention(q, k, v)
     fn = jax.shard_map(
         lambda q, k, v: ring_attention(q, k, v, axis_name="sp", causal=True),
         mesh=mesh,
@@ -49,7 +50,8 @@ def test_ring_attention_non_causal():
     q = jnp.asarray(rng.standard_normal((B, S, H, D)), jnp.float32)
     k = jnp.asarray(rng.standard_normal((B, S, H, D)), jnp.float32)
     v = jnp.asarray(rng.standard_normal((B, S, H, D)), jnp.float32)
-    want = full_attention(q, k, v, causal=False)
+    from ray_tpu.ops.attention_mask import FULL
+    want = full_attention(q, k, v, mask=FULL)
     fn = jax.shard_map(
         lambda q, k, v: ring_attention(q, k, v, axis_name="sp",
                                        causal=False),
@@ -136,24 +138,42 @@ def test_graft_entry_dryrun_multichip():
 
 
 def test_moe_expert_parallel_train_step():
-    """Switch-MoE FFN with experts sharded over the ep axis: sharded
-    loss matches the unsharded MoE loss, a train step is finite, and
-    routing actually uses multiple experts."""
+    """The one expert layer with its experts sharded over the ep axis
+    (each shard runs the layer on its own range, the partial results
+    are summed with psum): the sharded loss matches the unsharded one,
+    a train step is finite and reports the layer's counters, nothing is
+    dropped, routing uses several experts, and the router's
+    load-balance auxiliary is in the loss with the same gradient
+    sharded as not."""
     import numpy as np
 
     from ray_tpu.models.transformer import (
-        TransformerConfig, loss_fn, make_train_state, make_train_step)
+        TransformerConfig, loss_and_counters, loss_fn, make_train_state,
+        make_train_step)
     from ray_tpu.parallel.mesh import MeshConfig, build_mesh
     cfg = TransformerConfig(vocab_size=64, d_model=32, n_layers=2,
                             n_heads=4, d_ff=64, dtype=jnp.float32,
                             remat=False, context_parallel=False,
-                            moe_experts=4, moe_capacity_factor=2.0)
+                            moe_experts=4, moe_top_k=2)
     tokens = jax.random.randint(jax.random.PRNGKey(1), (4, 33), 0, 64,
                                 dtype=jnp.int32)
     state_plain, _ = make_train_state(jax.random.PRNGKey(0), cfg)
-    want = float(jax.jit(
-        lambda p: loss_fn(p, {"tokens": tokens}, cfg))(
-            state_plain["params"]))
+    want, plain = jax.jit(
+        lambda p: loss_and_counters(p, {"tokens": tokens}, cfg))(
+            state_plain["params"])
+    # 4 rows x 32 positions x 2 choices, all four experts held
+    assert float(plain["moe_held_choices"]) == 4 * 32 * 2
+    assert float(plain["moe_dropped_choices"]) == 0.0
+    assert 0.25 <= float(plain["moe_expert_load_max"]) < 1.0  # > 1 expert
+    # the auxiliary (1 at balance) is in the loss at its default weight
+    assert float(plain["moe_balance_loss"]) >= 1.0
+    without = dataclasses.replace(cfg, moe_aux_coeff=0.0)
+    assert float(want) - float(loss_fn(
+        state_plain["params"], {"tokens": tokens}, without)) == \
+        pytest.approx(0.01 * float(plain["moe_balance_loss"]), rel=1e-3)
+    want_grad = jax.jit(jax.grad(
+        lambda p: loss_fn(p, {"tokens": tokens}, cfg)))(
+            state_plain["params"])
     mesh = build_mesh(MeshConfig(dp=2, ep=4), devices=jax.devices()[:8])
     with mesh:
         state, tx = make_train_state(jax.random.PRNGKey(0), cfg,
@@ -161,24 +181,24 @@ def test_moe_expert_parallel_train_step():
         got = float(jax.jit(
             lambda p: loss_fn(p, {"tokens": tokens}, cfg, mesh))(
                 state["params"]))
-        assert abs(got - want) < 1e-3, (got, want)
+        assert abs(got - float(want)) < 1e-3, (got, want)
+        got_grad = jax.jit(jax.grad(
+            lambda p: loss_fn(p, {"tokens": tokens}, cfg, mesh)))(
+                state["params"])
+        for name in ("wr", "w1", "w2"):
+            g, w = (np.asarray(t["layers"]["moe"][name])
+                    for t in (got_grad, want_grad))
+            assert np.abs(g - w).max() <= 1e-4 * np.abs(w).max() + 1e-7, name
         step = make_train_step(cfg, tx, mesh=mesh)
         state, metrics = step(state, {"tokens": tokens})
         assert np.isfinite(float(metrics["loss"]))
         assert float(metrics["grad_norm"]) > 0
-
-    # Routing spreads across experts (router init is random but the
-    # distribution over 132 tokens should hit >1 expert).
-    from ray_tpu.models.moe import aux_load_balance_loss
-    x = jax.random.normal(jax.random.PRNGKey(2), (4, 33, 32))
-    wr = state_plain["params"]["layers"]["moe"]["wr"][0]
-    import jax.numpy as jnp_mod
-    probs = jax.nn.softmax(jnp_mod.einsum(
-        "bsd,de->bse", x, wr.astype(jnp_mod.float32)), axis=-1)
-    used = len(np.unique(np.argmax(np.asarray(probs), axis=-1)))
-    assert used >= 2
-    aux = float(aux_load_balance_loss(x, wr, 4))
-    assert np.isfinite(aux) and aux > 0
+        assert float(metrics["moe_held_choices"]) == 4 * 32 * 2
+        assert float(metrics["moe_dropped_choices"]) == 0.0
+        assert float(metrics["moe_expert_load_max"]) == pytest.approx(
+            float(plain["moe_expert_load_max"]))
+        assert float(metrics["moe_balance_loss"]) == pytest.approx(
+            float(plain["moe_balance_loss"]), rel=1e-5)
 
 
 def test_pipeline_parallel_matches_single_device():
