@@ -68,11 +68,11 @@ class Causal:
         return seq_len
 
     def k_ranges(self, q_tile, block_q, block_k, num_k):
-        # One range, every tile of it masked: the forward kernel's loop
-        # as it has been since the first chip run (the benchmark's dense
-        # cell is held to it).
-        return [(0, jnp.minimum(num_k, _cdiv((q_tile + 1) * block_q,
-                                             block_k)), True)]
+        # K tiles [0, stop) have a key at or before the tile's last row;
+        # below ``unmasked`` every key is at or before its first row.
+        unmasked = (q_tile * block_q + 1) // block_k
+        stop = jnp.minimum(_cdiv((q_tile + 1) * block_q, block_k), num_k)
+        return [(0, unmasked, False), (unmasked, stop, True)]
 
     def q_ranges(self, k_tile, block_q, block_k, num_q):
         # Q tiles [first, num_q) have a row at or past the tile's first

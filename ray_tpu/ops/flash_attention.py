@@ -1,23 +1,34 @@
-"""Pallas flash-attention kernel for a single TPU chip.
+"""Pallas flash-attention kernels for a single TPU chip.
 
 The hot op of the model stack: blockwise attention with running-max
-softmax so the [L, L] score matrix never leaves VMEM.  MXU-aligned 128
-blocks, f32 accumulation, bf16-friendly inputs.  (Pallas guide: grid +
-BlockSpec pattern; preferred_element_type for MXU dots.)
+softmax so the [L, L] score matrix never leaves VMEM.  (Pallas guide:
+grid + BlockSpec pattern; preferred_element_type for MXU dots.)
 
-Differentiable through a ``custom_vjp``: the forward kernel also emits
-the per-row log-sum-exp, and the backward is one Pallas kernel too
-(``flash_attention_bwd``) that recomputes the probabilities tile by
+Two kernels behind a ``custom_vjp``, made the same way.  The forward
+(``flash_attention_fwd``) runs a grid over Q blocks and a loop over the
+K/V blocks each sees, and also emits the per-row log-sum-exp; the
+backward (``flash_attention_bwd``) recomputes the probabilities tile by
 tile from (q, k, v, out, lse): a grid over K/V blocks, a loop over the
-Q blocks that see each (under the causal mask from the diagonal down:
-blocks above it are never visited, only blocks the diagonal crosses are
-masked), ``dk``/``dv`` carried by the loop and ``dq`` accumulated in a
-float32 VMEM scratch, so no ``[L, block]`` tile goes to HBM in either
-direction.  The MXU gets its
-operands in the input dtype with float32 accumulation (``p`` cast to
-``dout``'s dtype, ``ds`` to ``q``'s); ``lse``, ``delta``, ``exp`` and
-the three accumulators are float32, cast once at the end.  (bf16 dots
-in the forward and K/V tiling for L = 32k are ROADMAP S3.)
+Q blocks that see each, ``dk``/``dv`` carried by the loop and ``dq``
+accumulated in a float32 VMEM scratch, so no ``[L, block]`` tile goes
+to HBM in either direction.  In both:
+
+  * the MXU gets its operands in the input dtype with float32
+    accumulation (``p`` cast to ``v``'s dtype or ``dout``'s, ``ds`` to
+    ``q``'s); the scale is applied to the float32 scores; the running
+    max and sum, ``lse``, ``delta``, ``exp`` and the accumulators are
+    float32, cast once at the end;
+  * a tile is held transposed, ``[block_k, block_q]``, so a row's
+    statistics lie along lanes and broadcast along sublanes, and
+    ``lse`` passes between the kernels as ``[L // block, block]`` rows;
+  * the blocks are the largest of a short ladder that divides the
+    length (512 at the benchmark's shapes: the loop's fixed cost is
+    paid per tile);
+  * under the causal mask tiles above the diagonal are never visited
+    and only the tiles the diagonal crosses are masked.
+
+(K/V blocked through the grid for L = 32k is ROADMAP R-M4: the forward
+holds K and V whole per head, the backward q, dout and dq.)
 
 What is attended to is a static description (``ops/attention_mask.py``:
 ``CAUSAL``, ``FULL``, ``BlockDiffusion(seq_len, block)``): the kernels
@@ -48,11 +59,19 @@ from jax.experimental.pallas import tpu as pltpu
 from ray_tpu.ops.attention_mask import CAUSAL
 
 _NEG_INF = -1e30
-_LANES = 128
 # dot_general dimension numbers: contract the minor dims (a @ b.T) and
 # the major dims (a.T @ b).
 _NT = (((1,), (1,)), ((), ()))
 _TN = (((0,), (0,)), ((), ()))
+# The forward's block (both ways), the largest that divides the span a
+# tile may not straddle.  On the v5e in bf16, ms a call, causal at
+# [64, 4096, 128] / block diffusion at [128 over 16, 8192, 128] (PERF.md,
+# PR 29): 512 -> 2.95 / 15.7, 256 -> 5.77 / 25.1, 128 -> 12.7 / 52.9 (the
+# loop's fixed cost is paid per tile); 1024 x 512 2.85 / 19.5 and
+# 512 x 1024 3.01 / 20.0: a larger tile visits more of what the block
+# mask empties.  K and V whole at 8,192 positions fit the 16 MB a
+# kernel gets unasked.
+_FWD_BLOCKS = (512, 256, 128)
 # The backward's block (both ways), the largest that divides L.  On the
 # v5e at [64, 4096, 128] bf16 (PERF.md, PR 27): 512 -> 5.4 ms a call,
 # 256 -> 7.1, 128 -> 15.8; 1024 or unequal blocks are no faster.
@@ -64,61 +83,74 @@ _BWD_VMEM_BYTES = 64 * 2 ** 20
 
 
 def _flash_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, block_k: int,
-                  mask, scale: float, seq_len: int):
-    # q_ref/o_ref: [block_q, D]; k_ref/v_ref: [L, D];
-    # lse_ref: [block_q, _LANES] (row value broadcast along lanes).
-    block_q = q_ref.shape[0]
-    d = q_ref.shape[1]
+                  mask, scale: float):
+    # Grid (query head, q block).  q_ref/o_ref: [block_q, D]; k_ref/v_ref:
+    # [L, D], resident across a K/V head's query heads and q blocks;
+    # lse_ref: [L // block_q, block_q], resident across a head's q
+    # blocks, one row each.  Tiles are held transposed, [block_k,
+    # block_q], as the backward holds them: the row statistics are
+    # [1, block_q], dense along lanes, they broadcast along sublanes, and
+    # lse leaves as the row the backward reads.  acc is out transposed,
+    # [D, block_q].
+    block_q, d = q_ref.shape
     q_blk = pl.program_id(1)
-    q = q_ref[:].astype(jnp.float32) * scale
+    f32 = jnp.float32
+    q = q_ref[...]
 
     def body(masked, i, carry):
         acc, m_i, l_i = carry
-        k = k_ref[pl.ds(i * block_k, block_k), :].astype(jnp.float32)
-        v = v_ref[pl.ds(i * block_k, block_k), :].astype(jnp.float32)
-        s = jnp.dot(q, k.T, preferred_element_type=jnp.float32)
+        keys = pl.ds(pl.multiple_of(i * block_k, block_k), block_k)
+        k = k_ref[keys, :]
+        v = v_ref[keys, :]
+        # Scaled after the product: the operands go to the MXU as they
+        # arrived, and bfloat16 products are exact in float32.
+        s = jax.lax.dot_general(k, q, _NT, preferred_element_type=f32) * scale
         if masked:
             q_pos = q_blk * block_q + jax.lax.broadcasted_iota(
-                jnp.int32, (block_q, block_k), 0)
+                jnp.int32, (block_k, block_q), 1)
             k_pos = i * block_k + jax.lax.broadcasted_iota(
-                jnp.int32, (block_q, block_k), 1)
+                jnp.int32, (block_k, block_q), 0)
             s = jnp.where(mask.allowed(q_pos, k_pos), s, _NEG_INF)
-        m_new = jnp.maximum(m_i, jnp.max(s, axis=-1, keepdims=True))
+        m_new = jnp.maximum(m_i, jnp.max(s, axis=0, keepdims=True))
         p = jnp.exp(s - m_new)
         corr = jnp.exp(m_i - m_new)
-        l_new = l_i * corr + jnp.sum(p, axis=-1, keepdims=True)
-        acc = acc * corr + jnp.dot(p, v, preferred_element_type=jnp.float32)
+        l_new = l_i * corr + jnp.sum(p, axis=0, keepdims=True)
+        acc = acc * corr + jax.lax.dot_general(
+            v, p.astype(v.dtype), _TN, preferred_element_type=f32)
         return acc, m_new, l_new
 
-    carry = (jnp.zeros((block_q, d), jnp.float32),
-             jnp.full((block_q, 1), _NEG_INF, jnp.float32),
-             jnp.zeros((block_q, 1), jnp.float32))
+    carry = (jnp.zeros((d, block_q), f32),
+             jnp.full((1, block_q), _NEG_INF, f32),
+             jnp.zeros((1, block_q), f32))
     # Only the K tiles the mask admits for this Q tile.
     for first, stop, masked in mask.k_ranges(q_blk, block_q, block_k,
-                                             seq_len // block_k):
+                                             k_ref.shape[0] // block_k):
         carry = jax.lax.fori_loop(first, stop,
                                   functools.partial(body, masked), carry)
     acc, m, l = carry
     l = jnp.maximum(l, 1e-20)
-    o_ref[:] = (acc / l).astype(o_ref.dtype)
-    lse_ref[:] = jnp.broadcast_to(m + jnp.log(l), (block_q, _LANES))
+    o_ref[...] = (acc / l).T.astype(o_ref.dtype)
+    lse_ref[pl.ds(q_blk, 1), :] = m + jnp.log(l)
 
 
 def _flash_forward(qh, kh, vh, mask, block_q, block_k, interpret):
     """q [BH, L, D], k/v [BH // group, L, D] -> (out [BH, L, D],
-    lse [BH, L] f32)."""
+    lse [BH, L] f32).  A block that is None is chosen from the span."""
     BH, L, D = qh.shape
     group = BH // kh.shape[0]
     span = mask.tile_span(L)
+    chosen = next((b for b in _FWD_BLOCKS if span % b == 0), _FWD_BLOCKS[-1])
+    block_q, block_k = block_q or chosen, block_k or chosen
     if span % block_q or span % block_k:
         raise ValueError(
             f"sequence length {span} must be a multiple of the block sizes "
             f"({block_q}, {block_k}); pad upstream")
+    nq = L // block_q
     kernel = functools.partial(_flash_kernel, block_k=block_k,
-                               mask=mask, scale=D ** -0.5, seq_len=L)
+                               mask=mask, scale=D ** -0.5)
     out, lse = pl.pallas_call(
         kernel,
-        grid=(BH, L // block_q),
+        grid=(BH, nq),
         in_specs=[
             pl.BlockSpec((None, block_q, D), lambda b, i: (b, i, 0)),
             # a group's query heads follow one another, so a K/V head
@@ -128,16 +160,20 @@ def _flash_forward(qh, kh, vh, mask, block_q, block_k, interpret):
         ],
         out_specs=[
             pl.BlockSpec((None, block_q, D), lambda b, i: (b, i, 0)),
-            pl.BlockSpec((None, block_q, _LANES), lambda b, i: (b, i, 0)),
+            pl.BlockSpec((None, nq, block_q), lambda b, i: (b, 0, 0)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((BH, L, D), qh.dtype),
-            jax.ShapeDtypeStruct((BH, L, _LANES), jnp.float32),
+            jax.ShapeDtypeStruct((BH, nq, block_q), jnp.float32),
         ],
+        # a head's lse block is written a row a q block: that axis runs
+        # in order
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
         name="flash_attention_fwd",
     )(qh, kh, vh)
-    return out, lse[:, :, 0]
+    return out, lse.reshape(BH, L)
 
 
 def _flash_bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
@@ -283,13 +319,14 @@ _flash.defvjp(_flash_vjp_fwd, _flash_vjp_bwd)
 @functools.partial(jax.jit, static_argnames=("mask", "block_q", "block_k",
                                              "interpret"))
 def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array,
-                    mask=CAUSAL, block_q: int = 128,
-                    block_k: int = 128, interpret: bool = False
+                    mask=CAUSAL, block_q: int | None = None,
+                    block_k: int | None = None, interpret: bool = False
                     ) -> jax.Array:
     """q: [B, L, H, D]; k, v: [B, L, H // group, D] -> [B, L, H, D].
-    ``mask`` is one of ``ops.attention_mask``'s descriptions.  L must be
-    a multiple of the block sizes (pad upstream).  ``interpret`` runs
-    the kernel in the Pallas interpreter (CPU tests)."""
+    ``mask`` is one of ``ops.attention_mask``'s descriptions.  The
+    forward's blocks are chosen from L where they are not given; L must
+    be a multiple of them (pad upstream).  ``interpret`` runs the kernel
+    in the Pallas interpreter (CPU tests)."""
     B, L, H, D = q.shape
     heads_kv = k.shape[2]
     if H % heads_kv or v.shape != k.shape:

@@ -9,7 +9,8 @@ import jax.numpy as jnp
 import pytest
 
 from ray_tpu.ops.attention_mask import CAUSAL, FULL, BlockDiffusion
-from ray_tpu.ops.flash_attention import attention, flash_attention
+from ray_tpu.ops.flash_attention import (_FWD_BLOCKS, _flash_forward,
+                                         attention, flash_attention)
 from ray_tpu.ops.ring_attention import full_attention
 
 # Max abs error allowed on outputs and gradients of O(1) magnitude:
@@ -43,6 +44,44 @@ def test_forward_matches_full_attention(dtype, causal):
     want = full_attention(q, k, v, mask=_mask(causal))
     assert got.dtype == want.dtype and got.shape == want.shape
     assert _max_err(got, want) <= _TOL[dtype]
+
+
+# (a) The forward at every tile of its ladder and at the one it chooses
+# (512 here: L = 1,024, or two halves of 512 under block diffusion, so a
+# row of tiles has unmasked tiles, masked ones and ones never visited).
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["f32", "bf16"])
+@pytest.mark.parametrize("kv_heads", [2, 1], ids=["mha", "grouped"])
+@pytest.mark.parametrize("mask", [CAUSAL, FULL, BlockDiffusion(512, 4)],
+                         ids=["causal", "full", "blockdiff"])
+@pytest.mark.parametrize("block", [None, *_FWD_BLOCKS])
+def test_forward_over_the_ladder_of_tiles(block, mask, kv_heads, dtype):
+    q, k, v, _ = _qkvd(dtype, B=1, L=1024, H=2, kv_heads=kv_heads)
+    got = flash_attention(q, k, v, mask=mask, block_q=block, block_k=block,
+                          interpret=True)
+    want = full_attention(q, k, v, mask=mask)
+    assert got.dtype == want.dtype and got.shape == want.shape
+    assert _max_err(got, want) <= _TOL[dtype]
+
+
+@pytest.mark.parametrize("mask", [CAUSAL, FULL, BlockDiffusion(256, 4)],
+                         ids=["causal", "full", "blockdiff"])
+def test_lse_of_bfloat16_inputs_is_the_float32_log_sum_exp_of_them(mask):
+    """(c) bfloat16 products are exact in float32 and the scale (128 **
+    -0.5, no power of two) is applied to the float32 scores, so lse is
+    the reference's up to summation order: rounding ``q * scale`` to
+    bfloat16 would move it by thousandths."""
+    q, k, _, _ = _qkvd(jnp.bfloat16, B=1, L=512, H=2, D=128, kv_heads=1)
+    qh, kh = (x.transpose(0, 2, 1, 3).reshape(-1, 512, 128) for x in (q, k))
+    _, lse = _flash_forward(qh, kh, kh, mask, None, None, True)
+    assert lse.shape == (2, 512) and lse.dtype == jnp.float32
+    pos = jnp.arange(512)
+    scores = jnp.einsum("hqd,kd->hqk", qh.astype(jnp.float32),
+                        kh[0].astype(jnp.float32),
+                        precision="highest") * 128 ** -0.5
+    want = jax.nn.logsumexp(jnp.where(
+        mask.allowed(pos[:, None], pos[None, :]), scores, -jnp.inf), -1)
+    assert _max_err(lse, want) <= _TOL[jnp.float32]
 
 
 def _assert_grads_match(dtype, causal, shape=None, **blocks):
@@ -96,12 +135,13 @@ def test_backward_with_unequal_forward_blocks(block_q, block_k):
                         block_q=block_q, block_k=block_k)
 
 
-def _equations(jaxpr, inside_kernel=False):
-    """(primitive name, params, inside a pallas_call?) of every
+def _equations(jaxpr, kernel=None):
+    """(equation, name of the pallas_call it is inside or None) of every
     equation, sub-jaxprs included."""
     for eqn in jaxpr.eqns:
-        yield eqn.primitive.name, eqn.params, inside_kernel
-        inner = inside_kernel or eqn.primitive.name == "pallas_call"
+        yield eqn, kernel
+        inner = kernel or (eqn.params["name"]
+                           if eqn.primitive.name == "pallas_call" else None)
         for value in eqn.params.values():
             for sub in value if isinstance(value, (list, tuple)) else [value]:
                 sub = getattr(sub, "jaxpr", sub)
@@ -114,18 +154,44 @@ def test_gradient_is_pallas_kernels_and_no_loop_outside_them():
     jaxpr = jax.make_jaxpr(jax.grad(lambda q, k, v: jnp.sum(
         flash_attention(q, k, v, interpret=True)), (0, 1, 2)))(q, k, v)
     eqns = list(_equations(jaxpr.jaxpr))
-    kernels = [params["name"] for name, params, inside in eqns
-               if name == "pallas_call" and not inside]
+    kernels = [eqn.params["name"] for eqn, kernel in eqns
+               if eqn.primitive.name == "pallas_call" and not kernel]
     assert kernels[0] == "flash_attention_fwd" and len(kernels) >= 2
     assert all(n.startswith("flash_attention_bwd") for n in kernels[1:])
-    loops = [name for name, _, inside in eqns
-             if name in ("scan", "while") and not inside]
+    loops = [eqn.primitive.name for eqn, kernel in eqns
+             if eqn.primitive.name in ("scan", "while") and not kernel]
     assert loops == []
 
 
-@pytest.mark.parametrize("block_q,block_k,seq_len", [
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+@pytest.mark.parametrize("mask", [CAUSAL, FULL, BlockDiffusion(256, 4)],
+                         ids=["causal", "full", "blockdiff"])
+def test_forward_products_take_the_input_dtype_and_accumulate_in_float32(
+        dtype, mask):
+    """(d) The MXU gets q, k, v (and p, cast to v's dtype) as they
+    arrive; only the accumulation is float32."""
+    q, k, v, _ = _qkvd(dtype, B=1, L=512, H=1)
+    jaxpr = jax.make_jaxpr(lambda q, k, v: flash_attention(
+        q, k, v, mask=mask, interpret=True))(q, k, v)
+    dots = [eqn for eqn, kernel in _equations(jaxpr.jaxpr)
+            if eqn.primitive.name == "dot_general"
+            and kernel == "flash_attention_fwd"]
+    # two products a tile, once for each range of tiles the mask gives
+    assert len(dots) >= 2 and len(dots) % 2 == 0
+    for eqn in dots:
+        assert [x.aval.dtype for x in eqn.invars] == [dtype, dtype]
+        assert eqn.params["preferred_element_type"] == jnp.float32
+        assert eqn.outvars[0].aval.dtype == jnp.float32
+
+
+# (block_q, block_k, L): equal and unequal tiles, the dense cell's
+# (512 x 512 over 4,096) among them.
+_CAUSAL_TILINGS = [
     (128, 128, 640), (512, 512, 4096), (128, 256, 1024), (256, 128, 1024),
-    (128, 384, 768)])
+    (128, 384, 768), (1024, 512, 4096), (512, 1024, 4096)]
+
+
+@pytest.mark.parametrize("block_q,block_k,seq_len", _CAUSAL_TILINGS)
 def test_causal_bounds_visit_the_blocks_at_or_below_the_diagonal(
         block_q, block_k, seq_len):
     """The helper the kernel's loop bounds come from, on Python ints:
@@ -147,6 +213,31 @@ def test_causal_bounds_visit_the_blocks_at_or_below_the_diagonal(
         visited += num_q - first
     if block_q == block_k:
         assert visited == num_q * (num_q + 1) // 2
+
+
+@pytest.mark.parametrize("block_q,block_k,seq_len", _CAUSAL_TILINGS)
+def test_causal_forward_ranges_visit_and_mask_exactly_the_tiles_they_must(
+        block_q, block_k, seq_len):
+    """(b) The forward's twin of the test above, from the Q side: a K
+    tile is visited iff some row sees some key of it, and masked iff
+    some row does not see every key of it."""
+    num_q, num_k = seq_len // block_q, seq_len // block_k
+    visited = masked = 0
+    for i in range(num_q):
+        visit = _ranges_cover(CAUSAL.k_ranges(i, block_q, block_k, num_k),
+                              num_k)
+        for j in range(num_k):
+            sees_any = (i + 1) * block_q - 1 >= j * block_k
+            sees_all = i * block_q >= (j + 1) * block_k - 1
+            assert (j in visit) == sees_any, (i, j)
+            if sees_any:
+                assert visit[j] == (not sees_all), (i, j)
+        visited += len(visit)
+        masked += sum(visit.values())
+    if block_q == block_k:
+        assert visited == num_q * (num_q + 1) // 2 and masked == num_q
+    if (block_q, block_k, seq_len) == (512, 512, 4096):
+        assert (visited, masked) == (36, 8)      # the dense cell's
 
 
 def test_unequal_blocks_and_train_step_shape():
