@@ -7,12 +7,10 @@ Reference: ``benchmarks/single_node/test_single_node.py`` (MAX_ARGS
 ``python/ray/_private/ray_perf.py`` (task/actor throughput).
 
 Each row prints one JSON line; the final line is the whole envelope.
-``--quick`` shrinks the counts ~10x for smoke runs.  The companion
-``bench.py`` (scheduler kernel on real TPU) is separate — this file
-measures the RUNTIME's envelope on CPU.  Importing it pins nothing:
-``main()`` pins its own process to the CPU, and ``bench.py`` imports
-the kernel rows (``bench_pg_packing``, ``bench_autoscaler_solve``)
-into a process that holds the chip.
+``--quick`` shrinks the counts ~10x for smoke runs.  This file measures
+the RUNTIME's host-side envelope on the CPU (``main()`` pins its own
+process there); what runs on the chip is measured by
+``benchmarks/run.py`` through the chip tool.
 """
 
 import argparse
@@ -181,9 +179,9 @@ def introspection_summary():
 def bench_introspection_overhead(n=500):
     """Overhead bound for the introspection plane (ISSUE 13): the
     dispatch-latency row with flight recorder + lock-contention
-    profiling armed.  bench.py compares this against the unarmed
-    --dispatch-only row from the same invocation; the acceptance
-    target is p99 within 10% of the BENCH_r07 configuration."""
+    profiling armed, to hold against an unarmed --dispatch-only row
+    from the same machine; the acceptance target is p99 within 10% of
+    it (``--introspection-gate`` is the enforced form)."""
     row = bench_dispatch_latency(n, warm=True, reset_window=True)
     return emit("dispatch_latency_introspection_armed",
                 row["value"], "ms", n=n, p50_ms=row.get("p50_ms"),
@@ -286,87 +284,6 @@ def bench_introspection_gate(n=500, max_ratio=1.10, retries=1,
                     "introspection", {}).get("striped_locks"))
 
 
-def bench_solve_scale(arms=None, ticks=3, n_classes=64):
-    """--solve-scale row (ISSUE 17): the pod-sharded waterfill solve vs
-    the single-device kernel on synthetic (classes x nodes) ticks.  On
-    a chipless box the "pod" is XLA's forced 8-host-device CPU backend
-    — per-tick latency is then dominated by host FLOPS shared across
-    the very shards that would each own a real chip, so rows are
-    ``cpu_throttled``-marked and the honest claim is the CAPACITY one
-    (the sharded arm solves a 10x node count through the identical
-    code path that parity tests pin to the single-device kernel), not
-    the speedup one.  Not measured on real devices yet (ROADMAP S7)."""
-    import numpy as np
-
-    import jax
-
-    from ray_tpu._private.config import get_config
-    from ray_tpu.scheduler import sharded_solve
-    from ray_tpu.scheduler.jax_backend import BatchSolver
-
-    cfg = get_config()
-    n_dev = len(jax.devices())
-    cpu_throttled = jax.default_backend() != "tpu"
-    if arms is None:
-        arms = (("single", 10_000, 100_000),
-                ("sharded", 10_000, 100_000),
-                ("sharded", 100_000, 10_000_000))
-    prev_mode, prev_gate = (cfg.solver_shard_backend,
-                            cfg.solver_shard_min_nodes)
-    rows = []
-    try:
-        for mode, n_nodes, n_tasks in arms:
-            # Seeded per (shape) so the single and sharded arms at the
-            # same scale solve the IDENTICAL problem — the placed/
-            # feasible_frac columns are then directly comparable
-            # (parity, not just throughput).
-            rng = np.random.default_rng(17 + n_nodes % 1_000_003)
-            C, R = n_classes, 3
-            total = rng.integers(4, 64, size=(n_nodes, R)).astype(
-                np.float64)
-            avail = np.floor(total * rng.uniform(
-                0.2, 1.0, size=(n_nodes, R)))
-            demand = rng.integers(1, 4, size=(C, R)).astype(np.float64)
-            counts = rng.multinomial(
-                n_tasks, np.full(C, 1.0 / C)).astype(np.float64)
-            accel_node = rng.random(n_nodes) < 0.1
-            accel_class = rng.random(C) < 0.1
-            cfg.solver_shard_backend = (
-                "force" if mode == "sharded" else "off")
-            solver = BatchSolver()
-            solve = lambda: solver.solve_matrices(
-                avail, total, demand, counts, accel_node, accel_class,
-                0.5, None, False, False)
-            alloc = solve()                       # warm: jit compile
-            t0 = time.monotonic()
-            for _ in range(ticks):
-                alloc = solve()
-            per_tick_ms = (time.monotonic() - t0) / ticks * 1000.0
-            rows.append({
-                "arm": mode, "n_nodes": n_nodes,
-                "pending_tasks": n_tasks,
-                "n_shards": (sharded_solve.plan_shards(n_nodes)
-                             if mode == "sharded" else 1),
-                "per_tick_ms": round(per_tick_ms, 2),
-                "placed": int(alloc.sum()),
-                "feasible_frac": round(
-                    float(alloc.sum()) / n_tasks, 4),
-            })
-            emit("solve_scale_arm", per_tick_ms, "ms/tick", **rows[-1])
-    finally:
-        cfg.solver_shard_backend = prev_mode
-        cfg.solver_shard_min_nodes = prev_gate
-    single = next((r for r in rows if r["arm"] == "single"), None)
-    big = max((r for r in rows if r["arm"] == "sharded"),
-              key=lambda r: r["n_nodes"], default=None)
-    scale_x = (round(big["n_nodes"] / single["n_nodes"], 1)
-               if single and big else None)
-    return emit("solve_scale", len(rows), "arms", backend=jax.default_backend(),
-                devices=n_dev, cpu_throttled=cpu_throttled,
-                cores=os.cpu_count(),
-                sharded_node_scale_x=scale_x, sweep=rows)
-
-
 def bench_profile_overhead(n=500):
     """Overhead bound for the causal job profiler (ISSUE 15): the
     dispatch-latency row with provenance capture armed (parent/arg ids
@@ -402,7 +319,7 @@ def bench_profile_overhead(n=500):
                 armed["value"], "ms", n=n,
                 off_p99_ms=off["value"],
                 ratio=ratio,
-                # 1-core runners' p99 is noisy run-to-run (BENCH_r07):
+                # 1-core runners' p99 is noisy run-to-run:
                 # the honest record is both numbers, not just the bit.
                 within_10pct=(ratio is not None and ratio <= 1.10),
                 p50_ms=armed.get("p50_ms"),
@@ -506,8 +423,8 @@ def bench_object_gb(gib):
     pages, reported separately as cold_put_gbps).  get_gbps streams the
     returned array once (a full reduction) — the store's zero-copy get
     returns a view in ~constant time, and timing only the view creation
-    is what produced the absurd 6805 "GB/s" of ENVELOPE_r05; the
-    view-latency signal is kept as get_view_ms."""
+    once produced an absurd 6805 "GB/s"; the view-latency signal is
+    kept as get_view_ms."""
     import gc
 
     import numpy as np
@@ -848,150 +765,6 @@ def bench_broadcast_relay(sweep=((64, 8), (64, 16), (64, 32),
                      "origin_fair_ratio":
                          acceptance["origin_fair_ratio"]}),
                 sweep=results, **best)
-
-
-def _synthetic_view(n_nodes, rng):
-    """A heterogeneous ClusterResourceView without a live cluster —
-    the PG/autoscaler solves are pure functions of the view."""
-    import numpy as np
-
-    from ray_tpu.scheduler.resources import (ClusterResourceView,
-                                             NodeResources)
-    view = ClusterResourceView()
-    kinds = rng.choice(3, size=n_nodes, p=[0.6, 0.3, 0.1])
-    for i in range(n_nodes):
-        k = int(kinds[i])
-        total = {"CPU": [4, 64, 8][k], "memory": [16, 256, 64][k]}
-        if k == 2:
-            total["TPU"] = 4
-        view.add_node(f"node{i}", NodeResources(total))
-    return view
-
-
-def bench_pg_packing(n_pgs, n_nodes, kernel=True):
-    """pg_bundle_packing row: mixed-strategy placement groups solved at
-    the ``pack_bundles`` surface against one synthetic N-node view —
-    the newly-kernelized GCS solve, timed kernel arm vs greedy arm.
-    Solve-level (no 2PC) so the number is the scheduler, not RPC."""
-    import numpy as np
-
-    from ray_tpu._private.config import get_config
-    from ray_tpu.scheduler import bundle_packing
-    from ray_tpu.scheduler.resources import ResourceRequest
-
-    rng = np.random.default_rng(7)
-    view = _synthetic_view(n_nodes, rng)
-    strategies = ["PACK", "SPREAD", "STRICT_PACK", "STRICT_SPREAD"]
-    groups = []
-    for i in range(n_pgs):
-        nb = int(rng.integers(1, 5))
-        bundles = [ResourceRequest(
-            {"CPU": float(rng.choice([0.5, 1, 2])),
-             "memory": float(rng.choice([1, 2, 4]))})
-            for _ in range(nb)]
-        groups.append((bundles, strategies[i % len(strategies)]))
-
-    prev_mode = get_config().pg_kernel_backend
-
-    def run_arm(mode):
-        get_config().pg_kernel_backend = mode
-        try:
-            placed = 0
-            t0 = time.monotonic()
-            for bundles, strategy in groups:
-                if bundle_packing.pack_bundles(view, bundles,
-                                               strategy) is not None:
-                    placed += 1
-            return time.monotonic() - t0, placed
-        finally:
-            get_config().pg_kernel_backend = prev_mode
-
-    # Warm the jit caches outside the timed region.
-    if kernel:
-        run_arm("force")
-    kernel_dt, kernel_placed = run_arm("force") if kernel else (None, None)
-    greedy_dt, greedy_placed = run_arm("off")
-    import jax
-    row = dict(n_nodes=n_nodes,
-               greedy_pgs_per_s=round(n_pgs / greedy_dt, 2),
-               greedy_placed=greedy_placed,
-               backend=jax.default_backend())
-    if kernel:
-        row.update(kernel_pgs_per_s=round(n_pgs / kernel_dt, 2),
-                   kernel_placed=kernel_placed,
-                   kernel_vs_greedy=round(greedy_dt / kernel_dt, 2))
-    return emit("pg_bundle_packing", n_pgs, "pgs", **row)
-
-
-def bench_autoscaler_solve(n_demands, n_nodes, kernel=True):
-    """autoscaler_solve row: ``get_nodes_to_launch`` over a big demand
-    vector + pending placement groups, kernel arm vs exact-numpy arm —
-    the newly-kernelized ResourceDemandScheduler solve."""
-    import numpy as np
-
-    from ray_tpu._private.config import get_config
-    from ray_tpu.autoscaler import resource_demand_scheduler as rds
-
-    rng = np.random.default_rng(11)
-    node_types = {
-        "head": {"resources": {"CPU": 8}, "max_workers": 1},
-        "cpu_small": {"resources": {"CPU": 4, "memory": 16},
-                      "max_workers": max(n_nodes, 64)},
-        "cpu_big": {"resources": {"CPU": 64, "memory": 256},
-                    "max_workers": max(n_nodes // 4, 16)},
-        "tpu_host": {"resources": {"CPU": 8, "TPU": 4, "memory": 64},
-                     "max_workers": max(n_nodes // 8, 8)},
-    }
-    sched = rds.ResourceDemandScheduler(node_types,
-                                        max_workers=2 * n_nodes,
-                                        head_node_type="head")
-    demands = []
-    for _ in range(n_demands):
-        d = {"CPU": float(rng.choice([0.5, 1, 2, 4]))}
-        if rng.random() < 0.3:
-            d["memory"] = float(rng.choice([1, 2, 16]))
-        if rng.random() < 0.08:
-            d["TPU"] = float(rng.choice([1, 4]))
-        demands.append(d)
-    unused = {f"n{i}": {"CPU": float(rng.integers(0, 4)),
-                        "memory": float(rng.integers(0, 16))}
-              for i in range(n_nodes)}
-    pgs = [{"strategy": ["PACK", "STRICT_SPREAD"][i % 2],
-            "bundles": [{"CPU": 2}] * 3} for i in range(16)]
-    args = dict(node_type_counts={"head": 1, "cpu_small": n_nodes},
-                launching_nodes={},
-                resource_demands=demands,
-                unused_resources_by_node=unused,
-                pending_placement_groups=pgs)
-
-    prev_mode = get_config().autoscaler_kernel_backend
-
-    def run_arm(mode):
-        get_config().autoscaler_kernel_backend = mode
-        try:
-            t0 = time.monotonic()
-            to_launch, unfulfilled = sched.get_nodes_to_launch(**args)
-            return (time.monotonic() - t0, sum(to_launch.values()),
-                    len(unfulfilled))
-        finally:
-            get_config().autoscaler_kernel_backend = prev_mode
-
-    if kernel:
-        run_arm("force")               # warm jit caches
-    import jax
-    row = {"backend": jax.default_backend(), "n_nodes": n_nodes}
-    numpy_dt, numpy_launch, numpy_unf = run_arm("off")
-    row.update(numpy_ms=round(numpy_dt * 1000.0, 2),
-               numpy_nodes_launched=numpy_launch,
-               numpy_unfulfilled=numpy_unf)
-    if kernel:
-        kernel_dt, kernel_launch, kernel_unf = run_arm("force")
-        row.update(kernel_ms=round(kernel_dt * 1000.0, 2),
-                   kernel_nodes_launched=kernel_launch,
-                   kernel_unfulfilled=kernel_unf,
-                   kernel_vs_numpy=round(numpy_dt / max(kernel_dt, 1e-9),
-                                         2))
-    return emit("autoscaler_solve", n_demands, "demands", **row)
 
 
 def bench_process_mode_objects(mb, rounds):
@@ -1443,22 +1216,19 @@ def main():
     parser.add_argument("--queued", type=int, default=None,
                         help="queued-task count (default 1M; quick 20k)")
     parser.add_argument("--dispatch-only", action="store_true",
-                        help="run only the dispatch-latency row "
-                             "(bench.py folds this into its JSON)")
+                        help="run only the dispatch-latency row")
     parser.add_argument("--broadcast-only", action="store_true",
                         help="run only the relay-vs-naive broadcast "
-                             "sweep (bench.py folds this into its "
-                             "JSON)")
+                             "sweep")
     parser.add_argument("--introspection-bench", action="store_true",
                         help="run the dispatch-latency row with the "
                              "flight recorder + lock-contention "
                              "profiling armed (the ISSUE-13 overhead "
-                             "bound; bench.py folds this in)")
+                             "bound)")
     parser.add_argument("--profile-bench", action="store_true",
                         help="run the dispatch-latency row with "
                              "provenance capture armed vs off (the "
-                             "ISSUE-15 job-profiler overhead bound; "
-                             "bench.py folds this in)")
+                             "ISSUE-15 job-profiler overhead bound)")
     parser.add_argument("--introspection-gate", action="store_true",
                         help="CI regression gate (ISSUE 17): armed vs "
                              "unarmed dispatch p99 ratio must be "
@@ -1479,24 +1249,17 @@ def main():
                         help="run the cluster envelope driver at smoke "
                              "scale (4 node-host OS processes, chaos "
                              "armed) in a fresh subprocess; exits "
-                             "non-zero on silent loss (bench.py folds "
-                             "this in)")
+                             "non-zero on silent loss")
     parser.add_argument("--envelope-hosts", type=int, default=4,
                         help="fleet size for --envelope-smoke")
     parser.add_argument("--serve-bench", action="store_true",
                         help="closed-loop serve sweep: autoscaled + "
                              "adaptively-batched deployment, p50/p99 "
                              "vs offered load with the knee, stage "
-                             "trace, relay-vs-naive cold start "
-                             "(bench.py folds this in)")
-    parser.add_argument("--solve-scale", action="store_true",
-                        help="pod-sharded vs single-device scheduler "
-                             "solve sweep (ISSUE 17); forces 8 host "
-                             "devices when chipless")
+                             "trace, relay-vs-naive cold start")
     args = parser.parse_args()
     # Host-side envelope: this process and its children stay off the
-    # chip unless the caller names another platform explicitly (the
-    # --solve-scale arm on real devices: JAX_PLATFORMS=tpu).
+    # chip unless the caller names another platform explicitly.
     os.environ.setdefault("JAX_PLATFORMS", "cpu")
 
     if args.introspection_bench:
@@ -1504,19 +1267,6 @@ def main():
         # at lock CREATION time (module-level locks are created at
         # import).  The flight recorder is on by default.
         os.environ["RAY_TPU_LOCK_CONTENTION"] = "1"
-    if args.solve_scale:
-        # The sharded arm needs >1 device; on a chipless box force the
-        # 8-way host-platform split BEFORE the jax backend initializes
-        # (XLA_FLAGS is read at backend init).  A real-TPU run sets
-        # JAX_PLATFORMS=tpu explicitly and skips the forcing.
-        if os.environ["JAX_PLATFORMS"] == "cpu" and \
-                "host_platform_device_count" not in \
-                os.environ.get("XLA_FLAGS", ""):
-            os.environ["XLA_FLAGS"] = (
-                os.environ.get("XLA_FLAGS", "") +
-                " --xla_force_host_platform_device_count=8")
-        bench_solve_scale()
-        return 0
     if args.envelope_smoke:
         # The driver owns its own cluster in a fresh subprocess — no
         # ray_tpu.init in THIS process.  rc mirrors the zero-silent-
@@ -1573,8 +1323,8 @@ def main():
         row = bench_broadcast_relay()
         ray_tpu.shutdown()
         # The fair-share property is the acceptance gate here: the row
-        # is already printed (bench.py parses stdout regardless of rc),
-        # so a violation surfaces as rc=1 WITHOUT losing the data.
+        # is already printed, so a violation surfaces as rc=1 WITHOUT
+        # losing the data.
         return 0 if row.get("fair_share_ok", True) else 1
     rows = []
     rows.append(bench_tasks(1_000 if quick else 10_000))
@@ -1584,10 +1334,6 @@ def main():
     rows.append(bench_args(1_000 if quick else 10_000))
     rows.append(bench_returns(300 if quick else 3_000))
     rows.append(bench_get_many(1_000 if quick else 10_000))
-    rows.append(bench_pg_packing(40 if quick else 200,
-                                 128 if quick else 512))
-    rows.append(bench_autoscaler_solve(200 if quick else 2_000,
-                                       64 if quick else 256))
     rows.append(bench_object_gb(0.25 if quick else 1.0))
     rows.append(bench_broadcast(64 if quick else 256,
                                 4 if quick else 8))

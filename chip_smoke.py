@@ -8,14 +8,15 @@ CPU), through the entry points a user calls:
                     thread-mode workers), an in-process ``Cluster`` plus
                     one ``node_host`` child, a few thousand
                     ``@ray_tpu.remote`` tasks of several resource shapes;
-  2. scheduler      the BASELINE config-5 problem as ``bench.py`` builds it
-     kernel         (1M tasks x 256 classes x 10k nodes x 8 resources): one
-                    ``solve_stream`` program and a few single live ticks,
-                    fused Pallas fill on, checked on the host and compared
-                    EQUAL to the jnp scan on the same device;
+  2. scheduler      the BASELINE config-5 problem (1M tasks x 256 classes x
+     kernel         10k nodes x 8 resources): ``BatchSolver.solve_matrices``
+                    picks the fused Pallas fill and its answer is checked on
+                    the host; then single live ticks through the program a
+                    raylet runs, the fused fill compared EQUAL to the jnp scan
+                    on the same device and to the entry point's answer;
   3. trainer        ``ray_tpu.train.Trainer(backend="jax", use_tpu=True)``
-                    taking steps on ``bench_model.py``'s full-width model,
-                    the flash kernel in the compiled step.
+                    taking steps on a 200M-parameter model (``TPU_MODEL``),
+                    the flash kernels in the compiled step.
 
 With more than one chip visible it also runs the sharded solve and the
 dp/sp/tp + ep + pp programs on the real devices; with one it says those
@@ -48,37 +49,6 @@ def check(cond, what: str) -> None:
     under ``python -O``)."""
     if not cond:
         raise SystemExit(f"chip_smoke: FAILED: {what}")
-
-
-class CompileClock:
-    """Seconds JAX spent tracing, lowering and compiling (or fetching
-    from the persistent cache), and the cache's hit/miss counts — from
-    ``jax.monitoring``, so work on raylet and worker threads counts."""
-
-    _DURATIONS = ("/jax/core/compile/jaxpr_trace_duration",
-                  "/jax/core/compile/jaxpr_to_mlir_module_duration",
-                  "/jax/core/compile/backend_compile_duration")
-
-    def __init__(self):
-        from jax import monitoring
-        self.seconds = 0.0
-        self.hits = 0
-        self.misses = 0
-        monitoring.register_event_duration_secs_listener(self._on_duration)
-        monitoring.register_event_listener(self._on_event)
-
-    def _on_duration(self, name, secs, **_):
-        if name in self._DURATIONS:
-            self.seconds += secs
-
-    def _on_event(self, name, **_):
-        if name == "/jax/compilation_cache/cache_hits":
-            self.hits += 1
-        elif name == "/jax/compilation_cache/cache_misses":
-            self.misses += 1
-
-    def snapshot(self):
-        return self.seconds, self.hits, self.misses
 
 
 def _version(package: str) -> str:
@@ -191,100 +161,130 @@ def leg_live_runtime(platform: str = "tpu", num_nodes: int = 4,
 # Leg 2 — the scheduler kernel at full width.
 # ---------------------------------------------------------------------------
 
+def build_problem(rng, num_tasks=1_000_000, C=256, N=10_000, R=8):
+    """Config 5 of BASELINE.json, Google-cluster-trace shaped.  (The
+    benchmark's ``raylet_rounds`` driver keeps its own copy of this
+    draw: the yardstick never imports this tool.)"""
+    # Heterogeneous fleet: small CPU nodes, big CPU nodes, TPU hosts.
+    total = np.zeros((N, R), dtype=np.float32)
+    kinds = rng.choice(3, size=N, p=[0.6, 0.3, 0.1])
+    total[:, 0] = np.where(kinds == 0, 4, np.where(kinds == 1, 64, 8))  # CPU
+    total[:, 1] = np.where(kinds == 0, 16, np.where(kinds == 1, 256, 64))  # mem GB
+    total[:, 2] = np.where(kinds == 2, 4, 0)   # TPU chips
+    total[:, 3] = rng.integers(0, 2, N)        # GPU-ish custom accel
+    for r in range(4, R):
+        total[:, r] = rng.integers(0, 8, N)    # custom resources
+    used = rng.uniform(0.0, 0.6, size=(N, R)).astype(np.float32)
+    avail = np.floor(total * (1.0 - used))
+
+    # Trace-shaped demand: most classes small CPU tasks, a tail of
+    # memory-heavy and accelerator classes; counts follow a power law.
+    demand = np.zeros((C, R), dtype=np.float32)
+    demand[:, 0] = rng.choice([0.5, 1, 2, 4], size=C, p=[0.4, 0.4, 0.15, 0.05])
+    demand[:, 1] = rng.choice([1, 2, 4, 16], size=C, p=[0.5, 0.3, 0.15, 0.05])
+    accel_classes = rng.random(C) < 0.08
+    demand[accel_classes, 2] = rng.choice([1, 4], size=accel_classes.sum())
+    raw = rng.pareto(1.5, size=C) + 1.0
+    counts = np.floor(raw / raw.sum() * num_tasks).astype(np.int64)
+    counts[-1] += num_tasks - counts.sum()
+    accel_node = total[:, 2] > 0
+    return avail, total, demand, counts, accel_node, accel_classes
+
+
 def leg_scheduler_kernel(platform: str = "tpu", num_tasks: int = 1_000_000,
                          classes: int = 256, nodes: int = 10_000,
-                         resources: int = 8, ticks: int = 40,
-                         live_ticks: int = 3) -> dict:
-    """``solve_stream`` (closed loop) and single live ticks through
-    ``_jit_solve_tick``; host-side validity as ``bench.py`` checks it;
-    fused fill compared EQUAL to the jnp scan on the same inputs and
-    device.  Off the chip the fused kernel runs in interpret mode."""
+                         resources: int = 8, live_ticks: int = 3) -> dict:
+    """The single-device solve (leg 4 is the sharded one): the entry
+    point ``BatchSolver.solve_matrices`` with the fill it picks for this
+    platform, its answer checked on the host; then single live ticks
+    through ``_jit_solve_tick``, the fused fill compared EQUAL to the
+    jnp scan on the same inputs and device, the ``ok`` bit, and the
+    packed tick decoded EQUAL to the entry point's answer.  Off the chip
+    the fused kernel runs in interpret mode."""
     import jax
 
-    from bench import arrival_stream, build_problem
+    from ray_tpu._private.config import get_config
     from ray_tpu.scheduler import jax_backend as jb
 
     rng = np.random.default_rng(42)
     avail, total, demand, counts, accel_node, accel_class = build_problem(
         rng, num_tasks=num_tasks, C=classes, N=nodes, R=resources)
-    per_tick = max(1, int(0.13 * num_tasks))
-    stream = arrival_stream(rng, counts, ticks, per_tick=per_tick)
-    rho = rng.integers(2, 9, size=classes) / 16.0
+    # Tick 0 is the whole backlog; later ticks the same volume with the
+    # per-class mix rotated onto other demand shapes.
+    queues = [np.roll(counts, k) for k in range(max(live_ticks, 1))]
 
-    def host_valid(alloc, queue, what):
+    cfg = get_config()
+    prev = cfg.solver_shard_backend
+    cfg.solver_shard_backend = "off"
+    try:
+        solver = jb.BatchSolver()
+        dense = [solver.solve_matrices(avail, total, demand, queue,
+                                       accel_node, accel_class,
+                                       spread_threshold=0.5)
+                 for queue in queues]
+    finally:
+        cfg.solver_shard_backend = prev
+    entry_path = solver.last_path
+    check(entry_path == ("single/pallas" if platform == "tpu"
+                         else "single/jnp"),
+          f"solve_matrices took {entry_path}")
+    for k, (alloc, queue) in enumerate(zip(dense, queues)):
         usage = alloc.T.astype(np.float64) @ demand.astype(np.float64)
         check((usage <= avail.astype(np.float64) + 1e-2).all(),
-              f"{what}: capacity")
-        check((alloc.sum(axis=1) <= queue).all(), f"{what}: counts")
+              f"solve_matrices tick {k}: capacity")
+        check((alloc.sum(axis=1) <= queue).all(),
+              f"solve_matrices tick {k}: counts")
 
-    # The entry point, whatever fill it picks for this platform.
-    solver = jb.BatchSolver()
-    solver.prepare_device(avail, total, demand, accel_node=accel_node,
-                          accel_class=accel_class, spread_threshold=0.5)
-    out = solver.solve_stream(stream, rho=rho)
-    stream_path = solver.last_path
-    check(stream_path == ("single/pallas" if platform == "tpu"
-                          else "single/jnp"),
-          f"solve_stream took {stream_path}")
-    check(out["ok"].all(), "solve_stream on-device validation bits")
-    host_valid(solver.expand_sparse(out["idx"][0], out["vals"][0]),
-               stream[0], "solve_stream tick 0")
-
-    # Both fills, explicitly, on the same device-resident inputs.
-    dev = solver._device_state
-    c_pad, n_pad, r_pad = dev["pads"]
-    check({d.platform for d in dev["avail"].devices()} == {platform},
-          f"world state on {platform}")
-    stream_args = (
-        dev["avail"], dev["total"], dev["demand"],
-        np.zeros(c_pad, np.float32),
-        jb._pad_to(stream.astype(np.float32), (ticks, c_pad)),
-        jb._pad_to(rho.astype(np.float32), (c_pad,)),
-        dev["accel_node"], dev["accel_class"], dev["thr"], dev["cost"])
-    nnz_stream = 32768
-    packed = {
-        use: np.asarray(jb._jit_waterfill_stream(
-            c_pad, n_pad, r_pad, ticks, nnz_stream, use)(*stream_args))
-        for use in (True, False)}
-    check(np.array_equal(packed[True], packed[False]),
-          "solve_stream: fused Pallas fill == jnp scan")
-
-    # Single live ticks: what a raylet runs (one tick per program,
-    # nnz_max from the solver's own buckets).
-    nnz_bound = int(out["nnz"].max())
+    # Single live ticks: what a raylet runs (resident [R, N] world, one
+    # tick per program, nnz_max from the solver's own buckets), both
+    # fills explicitly on the same device-resident inputs.
+    c_pad, n_pad, r_pad = jb.BatchSolver._pads(classes, nodes, resources)
+    nnz_seen = max(int((alloc > 0).sum()) for alloc in dense)
     nnz_max = next(b for b in jb.DeviceRuntimeSolver._NNZ_BUCKETS
-                   if b >= nnz_bound)
-    avail_t = jax.device_put(
-        jb._pad_to(avail.astype(np.float32), (n_pad, r_pad)).T.copy())
-    total_t = jax.device_put(
-        jb._pad_to(total.astype(np.float32), (n_pad, r_pad)).T.copy())
+                   if b >= nnz_seen)
+    avail_t, total_t, demand_d, accel_node_d, accel_class_d, cost_d = (
+        jax.device_put(x) for x in (
+            jb._pad_to(avail.astype(np.float32), (n_pad, r_pad)).T.copy(),
+            jb._pad_to(total.astype(np.float32), (n_pad, r_pad)).T.copy(),
+            jb._pad_to(demand.astype(np.float32), (c_pad, r_pad)),
+            jb._pad_to(accel_node.astype(bool), (n_pad,)),
+            jb._pad_to(accel_class.astype(bool), (c_pad,)),
+            np.zeros((c_pad, n_pad), np.float32)))
+    check({d.platform for d in avail_t.devices()} == {platform},
+          f"world state on {platform}")
     for k in range(live_ticks):
-        queue = stream[k % ticks]
-        tick_args = (avail_t, total_t, dev["demand"],
-                     jb._pad_to(queue.astype(np.float32), (c_pad,)),
-                     dev["accel_node"], dev["accel_class"], dev["thr"],
-                     dev["cost"])
+        tick_args = (avail_t, total_t, demand_d,
+                     jb._pad_to(queues[k].astype(np.float32), (c_pad,)),
+                     accel_node_d, accel_class_d, np.float32(0.5), cost_d)
         fused = np.asarray(jb._jit_solve_tick(
             c_pad, n_pad, r_pad, nnz_max, True)(*tick_args))
         scan = np.asarray(jb._jit_solve_tick(
             c_pad, n_pad, r_pad, nnz_max, False)(*tick_args))
         check(np.array_equal(fused, scan),
               f"live tick {k}: fused Pallas fill == jnp scan")
-        check(fused[2 * nnz_max + 1] > 0.5, f"live tick {k}: ok bit")
-        idx = np.rint(fused[:nnz_max]).astype(np.int64)
-        host_valid(solver.expand_sparse(idx, fused[nnz_max:2 * nnz_max]),
-                   queue, f"live tick {k}")
+        idx, vals, _, ok, _ = jb._unpack_tick(fused, nnz_max)
+        check(ok, f"live tick {k}: ok bit")
+        check(np.array_equal(
+            jb._dense_alloc(idx, vals, c_pad, n_pad)[:classes, :nodes],
+            dense[k]), f"live tick {k}: packed tick == solve_matrices")
     return {"shape": [num_tasks, classes, nodes, resources],
-            "padded": [c_pad, n_pad, r_pad], "stream_path": stream_path,
-            "ticks_per_program": ticks,
-            "placed_tick0": int(out["placed"][0]),
-            "nnz_max_seen": nnz_bound, "live_tick_nnz_bucket": nnz_max,
-            "live_ticks": live_ticks, "fused_equals_scan": True}
+            "padded": [c_pad, n_pad, r_pad], "entry_path": entry_path,
+            "placed_tick0": int(dense[0].sum()),
+            "nnz_max_seen": nnz_seen, "live_tick_nnz_bucket": nnz_max,
+            "live_ticks": live_ticks, "fused_equals_scan": True,
+            "tick_equals_entry_point": True}
 
 
 # ---------------------------------------------------------------------------
-# Leg 3 — Trainer steps at bench_model.py's width.
+# Leg 3 — Trainer steps on a 200M-parameter model.
 # ---------------------------------------------------------------------------
+
+#: kwargs of TransformerConfig minus the dtype, and the batch: the
+#: 199.8M-parameter model this leg has trained since the chip arrived.
+TPU_MODEL = dict(vocab_size=32_000, d_model=1024, n_layers=8, n_heads=16,
+                 d_ff=4096, max_seq_len=1024, remat=True)
+TPU_BATCH, TPU_SEQ = 8, 1024
+
 
 def _train_func(config: dict) -> dict:
     """Runs inside the Train worker (a thread of this process)."""
@@ -332,16 +332,15 @@ def leg_trainer(platform: str = "tpu", model: dict = None, batch: int = None,
     import jax
     import jax.numpy as jnp
 
-    import bench_model
     import ray_tpu
     from ray_tpu.ops.attention_mask import BlockDiffusion
     from ray_tpu.ops.flash_attention import flash_attention
     from ray_tpu.ops.ring_attention import full_attention
     from ray_tpu.train import Trainer
 
-    model = dict(model or bench_model.TPU_MODEL)
-    batch = batch or bench_model.TPU_BATCH
-    seq = seq or bench_model.TPU_SEQ
+    model = dict(model or TPU_MODEL)
+    batch = batch or TPU_BATCH
+    seq = seq or TPU_SEQ
     on_chip = platform == "tpu"
 
     # Kernel vs reference at the model's attention shape.
@@ -452,7 +451,6 @@ def leg_sharded_solve(platform: str = "tpu", nodes: int = 10_240,
     devices so both pad to the same ring and must agree bit for bit."""
     import jax
 
-    from bench import build_problem
     from ray_tpu._private.config import get_config
     from ray_tpu.scheduler import jax_backend as jb
     from ray_tpu.scheduler.policy import SchedulingOptions
@@ -558,16 +556,18 @@ def leg_model_parallel(platform: str = "tpu", devices: int = None) -> dict:
 # ---------------------------------------------------------------------------
 
 def run_leg(name, clock, fn, **kwargs):
-    c0, h0, m0 = clock.snapshot()
+    before = clock.snapshot()
     t0 = time.perf_counter()
     facts = fn(**kwargs)
     wall = time.perf_counter() - t0
-    c1, h1, m1 = clock.snapshot()
+    after = clock.snapshot()
+    compile_s, hits, misses = (after[key] - before[key] for key in (
+        "compile_s", "cache_hits", "cache_misses"))
     # Compile seconds are summed over threads (raylets compile side by
     # side), so "run" is what is left of the wall clock at least.
-    print(f"leg {name}: PASSED  wall {wall:.1f}s; compile {c1 - c0:.1f}s "
-          f"(persistent cache: {h1 - h0} hits, {m1 - m0} misses); run "
-          f"{max(wall - (c1 - c0), 0.0):.1f}s  "
+    print(f"leg {name}: PASSED  wall {wall:.1f}s; compile {compile_s:.1f}s "
+          f"(persistent cache: {hits} hits, {misses} misses); run "
+          f"{max(wall - compile_s, 0.0):.1f}s  "
           f"[set-up facts, not benchmark numbers]")
     print(f"  {json.dumps(facts)}", flush=True)
     return facts
@@ -576,6 +576,7 @@ def run_leg(name, clock, fn, **kwargs):
 def main() -> int:
     import jax
 
+    from benchmarks.harness.compile_clock import clock as compile_clock
     from ray_tpu._private.device_policy import enable_compile_cache
     cache_dir = enable_compile_cache()
     device = device_facts()
@@ -593,10 +594,10 @@ def main() -> int:
     print(f"compile cache: {cache_dir} ({entries_before} entries at start)",
           flush=True)
 
-    clock = CompileClock()
+    clock = compile_clock()
     run_leg("1 live runtime", clock, leg_live_runtime)
     run_leg("2 scheduler kernel 1M x 256 x 10k", clock, leg_scheduler_kernel)
-    run_leg("3 trainer (bench_model.py width)", clock, leg_trainer)
+    run_leg("3 trainer 200M x 8 x 1024", clock, leg_trainer)
     if device["count"] > 1:
         run_leg("4 sharded solve", clock, leg_sharded_solve)
         run_leg("5 model parallel dp/sp/tp + ep + pp", clock,
@@ -604,10 +605,11 @@ def main() -> int:
     else:
         print("leg 4 sharded solve: NOT RUN (one device visible)")
         print("leg 5 model parallel: NOT RUN (one device visible)")
-    seconds, hits, misses = clock.snapshot()
+    total = clock.snapshot()
     print(f"compile cache: {cache_dir} ({len(os.listdir(cache_dir))} entries "
-          f"at end, {entries_before} at start); this run: {hits} hits, "
-          f"{misses} misses, {seconds:.1f}s tracing+lowering+compiling")
+          f"at end, {entries_before} at start); this run: "
+          f"{total['cache_hits']} hits, {total['cache_misses']} misses, "
+          f"{total['compile_s']:.1f}s tracing+lowering+compiling")
     print(json.dumps({"ok": True, "device": device}), flush=True)
     return 0
 

@@ -38,10 +38,6 @@ class Config:
     #:            are pinned to the CPU (_private/device_policy.py).
     scheduler_backend: str = "jax"
 
-    #: Fuse the per-class waterfill into one Mosaic (Pallas) kernel on
-    #: TPU.  False selects the jnp scan there too (chip_smoke.py compares
-    #: the two on the chip); a kernel failure is never a silent switch.
-    scheduler_pallas_fill: bool = True
     #: Heterogeneity cost weight (Gavel-style effective-rate scaling):
     #: slower nodes (per the ray_tpu.throughput / accel_throughput node
     #: labels) cost this much extra utilization at full rate spread.

@@ -24,7 +24,7 @@ Dispatch fast path (three levers on the submit->running hot path):
 * Tasks pushed onto a reused/parked lease never traverse the raylet
   scheduler, so the transport emits their SCHEDULED transition itself at
   push time — the queue_wait stage covers every task, not just the
-  slow path (the BENCH_r06 118-of-700 coverage gap).
+  slow path (which once sampled 118 tasks of 700).
 """
 
 from __future__ import annotations

@@ -23,7 +23,7 @@ Entry points: :func:`run_envelope` (importable — tests and
 ``bench_runtime.py --envelope-smoke`` call it in-process),
 :func:`main` (``python -m ray_tpu._private.envelope`` /
 ``tools/envelope.py`` / ``ray-tpu envelope``).  Results land as a JSON
-document (``ENVELOPE_r06.json`` for the recorded run).
+document (``--out``).
 """
 
 from __future__ import annotations
@@ -558,7 +558,7 @@ def main(argv=None) -> int:
                    help="system-config override on top of the "
                         "fleet-size calibration (repeatable; values "
                         "parsed as JSON, falling back to string)")
-    p.add_argument("--out", default="ENVELOPE_r06.json")
+    p.add_argument("--out", default="envelope.json")
     p.add_argument("--quiet", action="store_true")
     args = p.parse_args(argv)
 

@@ -373,7 +373,8 @@ def _maybe_native_store(cfg, capacity_bytes: int = 0):
     The segment is sized to the node store's capacity (clamped to the
     free space actually available on /dev/shm): a segment smaller than
     the store forced every large put onto the python-held fallback path
-    — and through its extra flatten copy (ENVELOPE_r05's 1.44 GB/s put).
+    — and through its extra flatten copy (a 1.44 GB/s put on the
+    50-host soak's 1-core CPU box).
     tmpfs pages are allocated on first touch, so an over-provisioned
     segment costs nothing until objects actually land in it."""
     global _native_store_failed

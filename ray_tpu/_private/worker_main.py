@@ -277,8 +277,7 @@ class _WorkerRuntime:
             # args died with the frame (zero-copy views included), and
             # actor creation/call args were copied out of the mapping
             # above.  Holding pins for an actor's lifetime permanently
-            # pinned every large shm arg a long-lived actor ever took
-            # (ADVICE.md).
+            # pinned every large shm arg a long-lived actor ever took.
             if pinned:
                 self._release_pins(pinned)
         if trace_ctx:

@@ -359,7 +359,7 @@ class WorkerHostService:
         any mid-write exception, including a timeout on a seal reply
         that actually LANDED host-side — by then the object is sealed,
         registered in the node store and locatable by other readers, so
-        deleting it here would corrupt a live object (ADVICE.md)."""
+        deleting it here would corrupt a live object."""
         _store, native = self._native_store()
         if native is None:
             return False

@@ -41,8 +41,7 @@ logger = logging.getLogger(__name__)
 
 _JAX_OK = importlib.util.find_spec("jax") is not None
 
-# Kernel-vs-numpy routing telemetry (folded into the autoscaler_solve
-# bench row).
+# Kernel-vs-numpy routing telemetry.
 kernel_stats = {"kernel_solves": 0, "kernel_errors": 0, "numpy_solves": 0}
 
 

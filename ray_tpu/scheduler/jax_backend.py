@@ -41,28 +41,19 @@ sort-free: prefix capacities come from a two-level blocked cumsum
 VPU.  The fill is still exact water-filling — capacity-consistent within
 the tick because the scan over classes carries the availability matrix.
 
-Three levels of TPU-residency:
-  * ``prepare_device`` uploads avail/total/masks once; per-tick calls ship
-    only the [C] counts vector (the queue snapshot), not the [N, R] world.
-  * ``solve_stream`` runs K ticks in ONE device program (scan over ticks)
-    with FULLY closed-loop world state: the pending queue, the evolving
-    availability matrix AND the inflight-work matrix are all scan carries
-    — placements subtract capacity, a geometric completion process
-    (per-class rate ``rho``) releases it back.  Returns a fixed-size
-    sparse encoding of each tick's assignment plus on-device validation
-    flags — amortizing the per-program dispatch latency.
+Two levels of TPU-residency, one solver (the deterministic bucketized
+fill, golden-tested against a numpy oracle with identical semantics):
+  * ``BatchSolver.solve_matrices`` uploads the caller's [N, R] world
+    with every call and fetches the dense alloc[C, N] — the autoscaler's
+    pack-mode solve (Serve's kernel placement goes through it too), and
+    the single-device reference of ``sharded_solve``.
+    ``BatchSolver.solve_bundles`` is the same shape of call for one
+    placement group's bundles.
   * ``DeviceRuntimeSolver`` is the **runtime dispatch path**: a raylet's
     ``ClusterTaskManager`` keeps the cluster world state device-resident
     between scheduling ticks, shipping only dirty-row deltas (nodes whose
-    availability changed) down and one sparse assignment back per tick.
-
-Two solvers behind one contract:
-  * ``waterfill`` (default, exact): deterministic bucketized fill —
-    golden-tested against a numpy oracle with identical semantics.
-  * ``sinkhorn``: cost = utilization score masked by feasibility; a
-    masked-softmax transport plan iterated to respect capacities, then
-    rounded with a capacity-aware fill using the plan as node ordering.
-    Load-balances like SPREAD while respecting capacities.
+    availability changed) and the [C] counts vector down, and one packed
+    sparse assignment with on-device validation bits back per tick.
 
 The raylet stays authoritative: kernel output is validated against the
 exact fixed-point vectors before commit and falls back to the native
@@ -237,14 +228,13 @@ def _class_shifts(c_pad: int, n_pad: int):
 
 
 def _pallas_enabled() -> bool:
-    """Fuse the per-class fill into one Mosaic kernel?  TPU-only (tests
-    run the jnp path on CPU; equivalence is covered by an interpret-mode
-    test and, on the chip, by chip_smoke.py), opt-out via config.  A
-    Mosaic compile or run error propagates to the caller — there is no
+    """Fuse the per-class fill into one Mosaic kernel?  On the TPU, yes
+    (tests run the jnp path on CPU; equivalence is covered by an
+    interpret-mode test and, on the chip, by chip_smoke.py).  A Mosaic
+    compile or run error propagates to the caller — there is no
     run-time switch to the jnp scan."""
     import jax
-    return (get_config().scheduler_pallas_fill
-            and jax.default_backend() == "tpu")
+    return jax.default_backend() == "tpu"
 
 
 def _fill_name(use_pallas: bool) -> str:
@@ -258,9 +248,9 @@ def _pallas_class_fill(c_pad: int, n_pad: int, r_pad: int,
     availability carried in VMEM scratch across grid steps.
 
     The jnp path lowers each class step to ~10 fused XLA kernels; at
-    256 classes x 40 ticks that is ~10^5 sequential kernel launches
-    whose fixed overheads dominate the tick (the arrays are far too
-    small to be bandwidth-bound).  Here one kernel invocation per class
+    256 classes that is ~2,500 sequential kernel launches a tick whose
+    fixed overheads dominate it (the arrays are far too small to be
+    bandwidth-bound).  Here one kernel invocation per class
     does everything in VMEM — the [B, N] bucket tensors never touch
     HBM, and per-class HBM traffic is one [1, N] allocs row out.
 
@@ -481,6 +471,26 @@ def _pack_tick(allocs, counts_k, av_pre, demand, nnz_max):
     return packed, placed_c
 
 
+def _unpack_tick(packed: np.ndarray, nnz_max: int):
+    """Host side of ``_pack_tick``: (idx [nnz_max] int64, vals [nnz_max],
+    placed, ok, nnz) of one fetched tick."""
+    return (np.rint(packed[:nnz_max]).astype(np.int64),
+            packed[nnz_max:2 * nnz_max],
+            int(np.rint(packed[2 * nnz_max])),
+            bool(packed[2 * nnz_max + 1] > 0.5),
+            int(np.rint(packed[2 * nnz_max + 2])))
+
+
+def _dense_alloc(idx: np.ndarray, vals: np.ndarray, c_pad: int,
+                 n_pad: int) -> np.ndarray:
+    """Dense alloc[c_pad, n_pad] int64 from a tick's sparse pairs (flat
+    index class * n_pad + node; unused slots carry c_pad * n_pad)."""
+    live = idx < c_pad * n_pad
+    alloc = np.zeros((c_pad, n_pad), dtype=np.int64)
+    alloc.reshape(-1)[idx[live]] = np.rint(vals[live]).astype(np.int64)
+    return alloc
+
+
 # ---------------------------------------------------------------------------
 # Device kernels (jit-compiled once per padded shape).
 # ---------------------------------------------------------------------------
@@ -504,62 +514,6 @@ def _jit_waterfill(c_pad: int, n_pad: int, r_pad: int,
     return jax.jit(solve)
 
 
-@functools.lru_cache(maxsize=8)
-def _jit_waterfill_stream(c_pad: int, n_pad: int, r_pad: int,
-                          ticks: int, nnz_max: int,
-                          use_pallas: bool = False):
-    """K scheduler ticks in one device program, closed-loop in STATE.
-
-    All world state is device-resident scan carry:
-      * ``pending`` [C] — each tick's queue is ``pending + arrivals_k``;
-        the solve places what fits and the remainder carries forward;
-      * ``avail`` [R, N] — placements subtract capacity *across* ticks;
-      * ``inflight`` [C, N] — placed-but-unfinished work; a geometric
-        completion process with per-class rate ``rho`` releases
-        ``ceil(inflight * rho)`` tasks per (class, node) each tick,
-        returning their resources to ``avail`` (ceil guarantees drains
-        finish: any nonzero inflight releases at least one task).
-
-    Output is ONE packed f32 array [K, 2*nnz_max + 3] — per tick: sparse
-    indices (exact in f32 while C_pad*N_pad < 2^24), sparse values, then
-    (placed, ok, nnz) — so the host needs a single fetch per program.
-    """
-    import jax
-    import jax.numpy as jnp
-
-    assert c_pad * n_pad < (1 << 24), "sparse idx must stay exact in f32"
-
-    def solve(avail0, total, demand, pending0, arrivals, rho, accel_node,
-              accel_class, spread_threshold, cost):
-        av0_t, total_t = avail0.T, total.T                 # [R, N]
-        inflight0 = jnp.zeros((c_pad, n_pad), jnp.float32)
-
-        def one_tick(carry, arrivals_k):
-            pending, av, inflight = carry
-            # Completions first: release resources held by finished work.
-            release = jnp.minimum(jnp.ceil(inflight * rho[:, None]),
-                                  inflight)                # [C, N]
-            av = jnp.minimum(
-                av + jnp.einsum("cn,cr->rn", release, demand), total_t)
-            inflight = inflight - release
-            counts_k = pending + arrivals_k
-            av_after, allocs = _class_fill(
-                av, total_t, demand, counts_k, accel_class, accel_node,
-                spread_threshold, c_pad=c_pad, n_pad=n_pad, r_pad=r_pad,
-                use_pallas=use_pallas, cost=cost)
-            packed, placed_c = _pack_tick(allocs, counts_k, av, demand,
-                                          nnz_max)
-            pending_next = jnp.maximum(counts_k - placed_c, 0.0)
-            inflight = inflight + allocs
-            return (pending_next, av_after, inflight), packed
-
-        _, out = jax.lax.scan(one_tick, (pending0, av0_t, inflight0),
-                              arrivals)
-        return out
-
-    return jax.jit(solve)
-
-
 @functools.lru_cache(maxsize=16)
 def _jit_solve_tick(c_pad: int, n_pad: int, r_pad: int, nnz_max: int,
                     use_pallas: bool = False):
@@ -568,7 +522,7 @@ def _jit_solve_tick(c_pad: int, n_pad: int, r_pad: int, nnz_max: int,
     Unlike ``_jit_waterfill`` this takes the transposed [R, N] matrices a
     ``DeviceRuntimeSolver`` keeps on device between ticks — only the [C]
     counts vector crosses host->device, only the packed sparse assignment
-    comes back (solve_stream-style validation bits included).
+    comes back (``_pack_tick``'s validation bits included).
     """
     import jax
     import jax.numpy as jnp
@@ -656,82 +610,6 @@ def _jit_pack_bundles(b_pad: int, n_pad: int, r_pad: int):
 
         (_, _), (idx, ok) = jax.lax.scan(body, (avail, used0), demand)
         return idx, ok
-
-    return jax.jit(solve)
-
-
-@functools.lru_cache(maxsize=16)
-def _jit_sinkhorn(c_pad: int, n_pad: int, r_pad: int, iters: int):
-    import jax
-    import jax.numpy as jnp
-
-    def solve(avail, total, demand, counts, accel_node, accel_class,
-              spread_threshold, tau):
-        eps = 1e-6
-        # Feasibility + initial per-(class,node) capacity in task units.
-        demanded = demand > 0                              # [C, R]
-        ratios = jnp.where(demanded[:, None, :],
-                           avail[None, :, :] /
-                           jnp.maximum(demand[:, None, :], eps), _BIG)
-        cap = jnp.floor(jnp.min(ratios, axis=2) + eps)     # [C, N]
-        cap = jnp.minimum(cap, counts[:, None])
-        feasible = cap > 0
-        # Cost: utilization + accel penalty (same shape as waterfill).
-        util = jnp.where(total > 0, (total - avail) /
-                         jnp.maximum(total, eps), 0.0)     # [N, R]
-        score = jnp.einsum("nr,cr->cn",
-                           util, demanded.astype(jnp.float32))
-        score = score / jnp.maximum(
-            jnp.sum(demanded, axis=1, dtype=jnp.float32)[:, None], 1.0)
-        score = jnp.where(score < spread_threshold, 0.0, score)
-        score = score + (accel_node[None, :] &
-                         ~accel_class[:, None]) * 1.0
-        logits = jnp.where(feasible, -score / tau, -_BIG)
-        # Masked-softmax transport plan, row-targets = counts.
-        plan = jax.nn.softmax(logits, axis=1) * counts[:, None]  # [C, N]
-        # Column capacity in "task slots" is class-dependent; approximate
-        # the shared multi-resource constraint per resource: scale columns
-        # so per-resource usage fits availability.
-        def sinkhorn_iter(plan, _):
-            usage = jnp.einsum("cn,cr->nr", plan, demand)      # [N, R]
-            factor = jnp.min(
-                jnp.where(usage > eps,
-                          jnp.clip(avail / jnp.maximum(usage, eps), 0.0, 1.0),
-                          1.0),
-                axis=1)                                        # [N]
-            plan = plan * factor[None, :]
-            # Re-normalize rows back toward counts (never exceeding them).
-            row = jnp.sum(plan, axis=1, keepdims=True)
-            plan = plan * jnp.where(row > eps,
-                                    jnp.minimum(counts[:, None] /
-                                                jnp.maximum(row, eps),
-                                                _BIG),
-                                    0.0)
-            plan = jnp.minimum(plan, cap)
-            return plan, None
-
-        plan, _ = jax.lax.scan(sinkhorn_iter, plan, None, length=iters)
-
-        # Round: fill nodes per class in plan-descending order, re-checking
-        # capacity against the running availability (exactness restored).
-        def body(av, inputs):
-            d, cnt, p = inputs
-            demanded_r = d > 0
-            ratios = jnp.where(demanded_r[None, :],
-                               av / jnp.maximum(d[None, :], eps), _BIG)
-            capn = jnp.floor(jnp.min(ratios, axis=1) + eps)
-            capn = jnp.clip(capn, 0.0, cnt)
-            order = jnp.argsort(-p, stable=True)
-            cap_sorted = capn[order]
-            prefix = jnp.cumsum(cap_sorted) - cap_sorted
-            take_sorted = jnp.clip(cnt - prefix, 0.0, cap_sorted)
-            alloc = jnp.zeros((n_pad,), jnp.float32).at[order].set(take_sorted)
-            av = av - alloc[:, None] * d[None, :]
-            return av, alloc
-
-        final_avail, allocs = jax.lax.scan(body, avail,
-                                           (demand, counts, plan))
-        return allocs, final_avail
 
     return jax.jit(solve)
 
@@ -837,62 +715,23 @@ def waterfill_oracle(avail: np.ndarray, total: np.ndarray,
     return alloc
 
 
-def stream_oracle(avail: np.ndarray, total: np.ndarray, demand: np.ndarray,
-                  arrivals: np.ndarray, rho: np.ndarray,
-                  accel_node: np.ndarray, accel_class: np.ndarray,
-                  spread_threshold: float,
-                  pending0: Optional[np.ndarray] = None,
-                  cost: Optional[np.ndarray] = None) -> List[np.ndarray]:
-    """Numpy replay of the closed-loop tick stream (same release model as
-    ``_jit_waterfill_stream``): returns each tick's dense alloc[C, N].
-
-    Exact vs the device when all quantities are dyadic rationals (integer
-    demands/counts, rho a multiple of 2^-k) under f32."""
-    C, R = demand.shape
-    N = avail.shape[0]
-    avail = avail.astype(np.float32).copy()
-    total = total.astype(np.float32)
-    demand = demand.astype(np.float32)
-    rho = np.broadcast_to(np.asarray(rho, dtype=np.float32), (C,))
-    pending = (np.zeros(C, dtype=np.float32) if pending0 is None
-               else pending0.astype(np.float32))
-    inflight = np.zeros((C, N), dtype=np.float32)
-    out = []
-    for k in range(arrivals.shape[0]):
-        release = np.minimum(np.ceil(inflight * rho[:, None]), inflight)
-        avail = np.minimum(
-            avail + np.einsum("cn,cr->nr", release, demand), total)
-        inflight = inflight - release
-        queue_k = pending + arrivals[k]
-        alloc = waterfill_oracle(avail, total, demand, queue_k,
-                                 accel_node, accel_class, spread_threshold,
-                                 cost=cost)
-        af = alloc.astype(np.float32)
-        avail = avail - np.einsum("cn,cr->nr", af, demand)
-        inflight = inflight + af
-        pending = np.maximum(queue_k - af.sum(axis=1), 0.0)
-        out.append(alloc)
-    return out
-
-
 # ---------------------------------------------------------------------------
 # Host-side driver.
 # ---------------------------------------------------------------------------
 
 class BatchSolver:
-    """Groups pending specs by scheduling class, runs the device solve,
-    expands the allocation back to per-task node targets."""
+    """One dense solve per call over matrices the caller already holds
+    (the autoscaler's bin-packing, placement-group bundles): pads,
+    picks the single-device or the node-sharded program, returns host
+    arrays."""
 
-    def __init__(self, mode: Optional[str] = None, sinkhorn_iters: int = 8):
-        self.mode = mode or "waterfill"
-        self.sinkhorn_iters = sinkhorn_iters
+    def __init__(self):
         enable_compile_cache()
-        self._device_state = None  # set by prepare_device
         #: Which program the LAST solve ran: "single/pallas",
         #: "single/jnp" or "sharded[n]/jnp" (chip_smoke.py prints it).
         self.last_path: Optional[str] = None
 
-    # -- raw matrix interface (used by bench + autoscaler) ---------------
+    # -- raw matrix interface (the autoscaler's pack-mode solve) ---------
     def solve_matrices(self, avail: np.ndarray, total: np.ndarray,
                        demand: np.ndarray, counts: np.ndarray,
                        accel_node: Optional[np.ndarray] = None,
@@ -915,48 +754,37 @@ class BatchSolver:
         import jax
         C, R = demand.shape
         N = avail.shape[0]
-        accel_node, accel_class, spread_threshold = self._defaults(
-            N, C, accel_node, accel_class, spread_threshold)
-        if self.mode != "sinkhorn":
-            from ray_tpu.scheduler import sharded_solve
-            n_shards = sharded_solve.plan_shards(N)
-            if n_shards > 1:
-                self.last_path = f"sharded[{n_shards}]/jnp"
-                return sharded_solve.solve_matrices_sharded(
-                    avail, total, demand, counts, accel_node,
-                    accel_class, spread_threshold, cost, invert_util,
-                    zero_shifts, n_shards)
+        if accel_node is None:
+            accel_node = np.zeros(N, dtype=bool)
+        if accel_class is None:
+            accel_class = np.zeros(C, dtype=bool)
+        if spread_threshold is None:
+            spread_threshold = get_config().scheduler_spread_threshold
+        from ray_tpu.scheduler import sharded_solve
+        n_shards = sharded_solve.plan_shards(N)
+        if n_shards > 1:
+            self.last_path = f"sharded[{n_shards}]/jnp"
+            return sharded_solve.solve_matrices_sharded(
+                avail, total, demand, counts, accel_node,
+                accel_class, spread_threshold, cost, invert_util,
+                zero_shifts, n_shards)
         c_pad, n_pad, r_pad = self._pads(C, N, R)
-        args = (
+        cost_p = np.zeros((c_pad, n_pad), np.float32) if cost is None \
+            else _pad_to(cost.astype(np.float32), (c_pad, n_pad))
+        shifts = np.zeros(c_pad, np.int32) if zero_shifts else \
+            np.asarray((np.arange(c_pad) * _ROT_STRIDE) % n_pad,
+                       np.int32)
+        use_pallas = _pallas_enabled()
+        self.last_path = f"single/{_fill_name(use_pallas)}"
+        allocs, _ = _jit_waterfill(c_pad, n_pad, r_pad, use_pallas)(
             _pad_to(avail.astype(np.float32), (n_pad, r_pad)),
             _pad_to(total.astype(np.float32), (n_pad, r_pad)),
             _pad_to(demand.astype(np.float32), (c_pad, r_pad)),
             _pad_to(counts.astype(np.float32), (c_pad,)),
             _pad_to(accel_node.astype(bool), (n_pad,)),
             _pad_to(accel_class.astype(bool), (c_pad,)),
-        )
-        if self.mode == "sinkhorn":
-            if cost is not None or invert_util or zero_shifts:
-                raise ValueError(
-                    "cost/invert_util/zero_shifts are waterfill-only; "
-                    "the sinkhorn solver does not implement the cost "
-                    "matrix and silently dropping them would return a "
-                    "wrong-ordering solve")
-            fn = _jit_sinkhorn(c_pad, n_pad, r_pad, self.sinkhorn_iters)
-            self.last_path = "single/sinkhorn"
-            allocs, _ = fn(*args, np.float32(spread_threshold),
-                           np.float32(0.1))
-        else:
-            cost_p = np.zeros((c_pad, n_pad), np.float32) if cost is None \
-                else _pad_to(cost.astype(np.float32), (c_pad, n_pad))
-            shifts = np.zeros(c_pad, np.int32) if zero_shifts else \
-                np.asarray((np.arange(c_pad) * _ROT_STRIDE) % n_pad,
-                           np.int32)
-            use_pallas = _pallas_enabled()
-            self.last_path = f"single/{_fill_name(use_pallas)}"
-            allocs, _ = _jit_waterfill(c_pad, n_pad, r_pad, use_pallas)(
-                *args, np.float32(spread_threshold), cost_p,
-                np.float32(1.0 if invert_util else 0.0), shifts)
+            np.float32(spread_threshold), cost_p,
+            np.float32(1.0 if invert_util else 0.0), shifts)
         allocs = np.asarray(jax.device_get(allocs))[:C, :N]
         return np.rint(allocs).astype(np.int64)
 
@@ -1001,156 +829,10 @@ class BatchSolver:
         ok = np.asarray(jax.device_get(ok))[:B].astype(bool)
         return idx, ok
 
-    # -- device-resident tick-stream interface (used by bench) -----------
-    def prepare_device(self, avail: np.ndarray, total: np.ndarray,
-                       demand: np.ndarray,
-                       accel_node: Optional[np.ndarray] = None,
-                       accel_class: Optional[np.ndarray] = None,
-                       spread_threshold: Optional[float] = None,
-                       cost: Optional[np.ndarray] = None) -> None:
-        """Upload the cluster world-state once (including the static
-        per-(class, node) cost matrix); subsequent solve_stream calls
-        ship only per-tick queue counts."""
-        import jax
-        C, R = demand.shape
-        N = avail.shape[0]
-        c_pad, n_pad, r_pad = self._pads(C, N, R)
-        accel_node, accel_class, spread_threshold = self._defaults(
-            N, C, accel_node, accel_class, spread_threshold)
-        cost_p = np.zeros((c_pad, n_pad), np.float32) if cost is None \
-            else _pad_to(cost.astype(np.float32), (c_pad, n_pad))
-        dev = {
-            "cost": jax.device_put(cost_p),
-            "avail": jax.device_put(
-                _pad_to(avail.astype(np.float32), (n_pad, r_pad))),
-            "total": jax.device_put(
-                _pad_to(total.astype(np.float32), (n_pad, r_pad))),
-            "demand": jax.device_put(
-                _pad_to(demand.astype(np.float32), (c_pad, r_pad))),
-            "accel_node": jax.device_put(
-                _pad_to(accel_node.astype(bool), (n_pad,))),
-            "accel_class": jax.device_put(
-                _pad_to(accel_class.astype(bool), (c_pad,))),
-            "thr": np.float32(spread_threshold),
-            "shape": (C, N, R), "pads": (c_pad, n_pad, r_pad),
-        }
-        jax.block_until_ready([dev["avail"], dev["total"], dev["demand"]])
-        self._device_state = dev
-
-    def solve_stream(self, arrivals: np.ndarray,
-                     pending0: Optional[np.ndarray] = None,
-                     nnz_max: int = 32768,
-                     rho: float | np.ndarray = 0.0) -> Dict[str, np.ndarray]:
-        """Run K closed-loop ticks on device.
-
-        arrivals is [K, C]: the exogenous per-tick task arrivals per
-        scheduling class.  The pending queue, the availability matrix and
-        the inflight-work matrix are all device-resident scan state: each
-        tick releases completed work (per-class geometric rate ``rho``),
-        solves ``pending + arrivals_k`` against the EVOLVING availability
-        and carries the unplaced remainder forward.  ``rho=0`` disables
-        completions (pure capacity drain).  Returns sparse assignments +
-        validation per tick: ``idx`` [K, nnz_max] in the PADDED flat
-        space (class*N_pad + node; decode with ``expand_sparse``, which
-        knows this solver's padding), ``vals`` [K, nnz_max],
-        ``placed`` [K], ``ok`` [K], ``nnz`` [K]."""
-        assert self._device_state is not None, "call prepare_device first"
-        dev = self._device_state
-        C, N, R = dev["shape"]
-        c_pad, n_pad, r_pad = dev["pads"]
-        K = arrivals.shape[0]
-        if pending0 is None:
-            pending0 = np.zeros(C, dtype=np.float32)
-        arr = _pad_to(arrivals.astype(np.float32), (K, c_pad))
-        pen = _pad_to(pending0.astype(np.float32), (c_pad,))
-        rho_vec = _pad_to(
-            np.broadcast_to(np.asarray(rho, dtype=np.float32), (C,)).copy(),
-            (c_pad,))
-        use_pallas = _pallas_enabled()
-        self.last_path = f"single/{_fill_name(use_pallas)}"
-        packed = np.asarray(_jit_waterfill_stream(
-            c_pad, n_pad, r_pad, K, nnz_max, use_pallas)(
-                dev["avail"], dev["total"], dev["demand"], pen, arr,
-                rho_vec, dev["accel_node"], dev["accel_class"],
-                dev["thr"], dev["cost"]))
-        return {
-            "idx": np.rint(packed[:, :nnz_max]).astype(np.int64),
-            "vals": packed[:, nnz_max:2 * nnz_max],
-            "placed": packed[:, 2 * nnz_max],
-            "ok": packed[:, 2 * nnz_max + 1] > 0.5,
-            "nnz": np.rint(packed[:, 2 * nnz_max + 2]).astype(np.int64),
-        }
-
-    def expand_sparse(self, idx: np.ndarray, vals: np.ndarray
-                      ) -> np.ndarray:
-        """Decode one tick's sparse assignment to dense alloc[C, N]."""
-        assert self._device_state is not None
-        C, N, R = self._device_state["shape"]
-        c_pad, n_pad, _ = self._device_state["pads"]
-        alloc = np.zeros((c_pad, n_pad), dtype=np.int64)
-        live = idx < c_pad * n_pad
-        alloc.reshape(-1)[idx[live]] = np.rint(vals[live]).astype(np.int64)
-        return alloc[:C, :N]
-
     @staticmethod
     def _pads(C: int, N: int, R: int) -> Tuple[int, int, int]:
         return (_round_up(max(C, 1), 8), _round_up(max(N, 8), _GROUP),
                 _round_up(max(R, 1), 8))
-
-    @staticmethod
-    def _defaults(N, C, accel_node, accel_class, spread_threshold):
-        if accel_node is None:
-            accel_node = np.zeros(N, dtype=bool)
-        if accel_class is None:
-            accel_class = np.zeros(C, dtype=bool)
-        if spread_threshold is None:
-            spread_threshold = get_config().scheduler_spread_threshold
-        return accel_node, accel_class, spread_threshold
-
-    # -- spec interface (kept for the autoscaler + as a dense fallback) ---
-    def assign(self, view, specs: Sequence) -> List:
-        """Per-spec node targets (None = infeasible/unassigned)."""
-        from ray_tpu.scheduler.policy import SchedulingType
-        node_ids, total, avail, columns = view.snapshot()
-        if not node_ids:
-            return [None] * len(specs)
-        # Group hybrid-class specs; everything else single-task fallback.
-        groups: Dict[int, List[int]] = {}
-        fallback: List[int] = []
-        for i, spec in enumerate(specs):
-            if spec.scheduling_options.scheduling_type is SchedulingType.HYBRID:
-                groups.setdefault(spec.scheduling_class, []).append(i)
-            else:
-                fallback.append(i)
-        targets: List = [None] * len(specs)
-        if groups:
-            classes = list(groups.keys())
-            reqs = [specs[groups[c][0]].resources for c in classes]
-            demand = view.demand_matrix(reqs)
-            # demand_matrix may have added columns; re-snapshot widths.
-            node_ids, total, avail, columns = view.snapshot()
-            if demand.shape[1] < total.shape[1]:
-                demand = _pad_to(demand, (demand.shape[0], total.shape[1]))
-            counts = np.array([len(groups[c]) for c in classes])
-            accel_node = accelerator_node_mask(total)
-            accel_class = np.array([r.uses_accelerator() for r in reqs])
-            alloc = self.solve_matrices(avail, total, demand, counts,
-                                        accel_node, accel_class)
-            for ci, cls in enumerate(classes):
-                members = groups[cls]
-                k = 0
-                for n in range(len(node_ids)):
-                    for _ in range(int(alloc[ci, n])):
-                        if k < len(members):
-                            targets[members[k]] = node_ids[n]
-                            k += 1
-        if fallback:
-            from ray_tpu.scheduler import policy as policy_mod
-            for i in fallback:
-                targets[i] = policy_mod.schedule(
-                    view, specs[i].resources, specs[i].scheduling_options,
-                    local_node_id=None)
-        return targets
 
 
 class DeviceRuntimeSolver:
@@ -1167,7 +849,7 @@ class DeviceRuntimeSolver:
         grants/releases or usage broadcasts since the last tick) are
         scattered in via ``_jit_apply_rows``;
       * per tick, only the [C] counts vector goes down and one packed
-        sparse assignment (with solve_stream-style on-device validation
+        sparse assignment (with ``_pack_tick``'s on-device validation
         bits) comes back.
 
     The solver never mutates the device availability with its own
@@ -1394,17 +1076,12 @@ class DeviceRuntimeSolver:
                         np.float32(cfg.scheduler_spread_threshold), cost)
             with tracing.span("scheduler.solve.fetch", category="sched"):
                 packed = np.asarray(packed)
-            ok = packed[2 * nnz_max + 1] > 0.5
+            idx, vals, _, ok, _ = _unpack_tick(packed, nnz_max)
             if not ok:
                 return False
-            idx = np.rint(packed[:nnz_max]).astype(np.int64)
-            vals = packed[nnz_max:2 * nnz_max]
         with tracing.span("scheduler.solve.expand", category="sched"):
             # Decode the sparse assignment and expand per-spec targets.
-            live = idx < c_cap * n_pad
-            idx, vals = idx[live], vals[live]
-            alloc = np.zeros((c_cap, n_pad), dtype=np.int64)
-            alloc.reshape(-1)[idx] = np.rint(vals).astype(np.int64)
+            alloc = _dense_alloc(idx, vals, c_cap, n_pad)
             node_ids = st["node_ids"]
             n_real = len(node_ids)
             for cls, members in groups.items():

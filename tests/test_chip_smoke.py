@@ -44,9 +44,10 @@ def test_leg_live_runtime(smoke):
 def test_leg_scheduler_kernel(smoke):
     facts = smoke.leg_scheduler_kernel(
         platform="cpu", num_tasks=2000, classes=16, nodes=100,
-        resources=8, ticks=3, live_ticks=2)
+        resources=8, live_ticks=2)
     assert facts["fused_equals_scan"] and facts["placed_tick0"] > 0
-    assert facts["stream_path"] == "single/jnp"   # the CPU's choice
+    assert facts["tick_equals_entry_point"]
+    assert facts["entry_path"] == "single/jnp"    # the CPU's choice
 
 
 def test_leg_trainer(smoke):
@@ -76,7 +77,7 @@ def test_leg_model_parallel(smoke):
 def test_a_failed_check_fails_the_process(smoke):
     # Told to find a TPU, the leg must not accept what the CPU picked.
     with pytest.raises(SystemExit,
-                       match="FAILED: solve_stream took single/jnp"):
+                       match="FAILED: solve_matrices took single/jnp"):
         smoke.leg_scheduler_kernel(
             platform="tpu", num_tasks=200, classes=8, nodes=16,
-            resources=8, ticks=1, live_ticks=0)
+            resources=8, live_ticks=0)

@@ -311,7 +311,7 @@ class TestPrestartAndKeepalive:
 
 class TestQueueWaitCoverage:
     def test_every_task_gets_a_queue_wait_sample(self):
-        """The BENCH_r06 coverage gap: lease-reuse pushes skipped the
+        """The queue_wait coverage gap: lease-reuse pushes skipped the
         scheduler and produced NO queue_wait sample, so the histogram
         covered only the slow path.  The transport now emits SCHEDULED
         at push time: every stage's sample count must match."""

@@ -11,8 +11,8 @@ Three layers:
   races);
 * mini-envelope — the REAL driver end-to-end at tier-1 scale (6 hosts,
   200 actors, 20 PGs, 16 MiB broadcast, 2 scheduled faults) asserting
-  the zero-silent-loss contract the 50-host soak records in
-  ENVELOPE_r06.json, plus a ``slow``-marked 32-host variant.
+  the zero-silent-loss contract the 50-host soak holds to, plus a
+  ``slow``-marked 32-host variant.
 """
 
 import dataclasses

@@ -10,7 +10,7 @@ import pytest
 
 import ray_tpu
 from ray_tpu.scheduler.jax_backend import (BatchSolver, DeviceRuntimeSolver,
-                                           stream_oracle, waterfill_oracle)
+                                           waterfill_oracle)
 
 
 @pytest.fixture(autouse=True)
@@ -51,7 +51,7 @@ def random_problem(rng, C=12, N=40, R=4):
 class TestWaterfillKernel:
     def test_matches_numpy_oracle(self):
         rng = np.random.default_rng(0)
-        solver = BatchSolver(mode="waterfill")
+        solver = BatchSolver()
         for trial in range(5):
             avail, total, demand, counts, an, ac = random_problem(rng)
             got = solver.solve_matrices(avail, total, demand, counts, an, ac,
@@ -63,7 +63,7 @@ class TestWaterfillKernel:
 
     def test_capacity_never_violated(self):
         rng = np.random.default_rng(1)
-        solver = BatchSolver(mode="waterfill")
+        solver = BatchSolver()
         for _ in range(5):
             avail, total, demand, counts, an, ac = random_problem(
                 rng, C=20, N=64, R=5)
@@ -74,7 +74,7 @@ class TestWaterfillKernel:
             assert (alloc.sum(axis=1) <= counts).all()
 
     def test_all_assigned_when_plenty(self):
-        solver = BatchSolver(mode="waterfill")
+        solver = BatchSolver()
         avail = total = np.full((8, 2), 100.0, dtype=np.float32)
         demand = np.array([[1.0, 0.0], [0.0, 2.0]], dtype=np.float32)
         counts = np.array([100, 50])
@@ -82,93 +82,82 @@ class TestWaterfillKernel:
         assert alloc.sum(axis=1).tolist() == [100, 50]
 
     def test_infeasible_left_unassigned(self):
-        solver = BatchSolver(mode="waterfill")
+        solver = BatchSolver()
         avail = total = np.full((4, 1), 2.0, dtype=np.float32)
         demand = np.array([[5.0]], dtype=np.float32)  # never fits
         alloc = solver.solve_matrices(avail, total, demand, np.array([10]))
         assert alloc.sum() == 0
 
 
-class TestTickStream:
-    def test_stream_matches_evolving_state_oracle(self):
-        """The closed loop carries availability + inflight across ticks:
-        placements occupy capacity until the completion process (rate
-        rho) releases it.  Replay the whole loop in numpy and demand
-        exact per-tick equality (all quantities dyadic -> f32-exact)."""
-        rng = np.random.default_rng(3)
-        solver = BatchSolver(mode="waterfill")
+class TestSolveTickProgram:
+    """``_jit_solve_tick`` — the program a raylet runs each tick — called
+    as ``DeviceRuntimeSolver._solve_groups`` calls it: resident [R, N]
+    matrices in, one packed sparse assignment with ``_pack_tick``'s
+    validation bits out."""
+
+    @staticmethod
+    def _tick(avail, total, demand, counts, accel_node, accel_class,
+              nnz_max, cost=None, fused=False):
+        """Returns (dense alloc[C, N], placed, ok, nnz) of one tick;
+        ``fused`` takes the Pallas fill (interpret mode off the chip)."""
+        from ray_tpu.scheduler import jax_backend as jb
+        C, R = demand.shape
+        N = avail.shape[0]
+        c_pad, n_pad, r_pad = BatchSolver._pads(C, N, R)
+        cost_p = np.zeros((c_pad, n_pad), np.float32) if cost is None \
+            else jb._pad_to(cost, (c_pad, n_pad))
+        packed = np.asarray(jb._jit_solve_tick(
+            c_pad, n_pad, r_pad, nnz_max, fused)(
+                jb._pad_to(avail, (n_pad, r_pad)).T.copy(),
+                jb._pad_to(total, (n_pad, r_pad)).T.copy(),
+                jb._pad_to(demand, (c_pad, r_pad)),
+                jb._pad_to(counts.astype(np.float32), (c_pad,)),
+                jb._pad_to(accel_node, (n_pad,)),
+                jb._pad_to(accel_class, (c_pad,)),
+                np.float32(0.5), cost_p))
+        assert packed.shape == (2 * nnz_max + 3,)
+        idx, vals, placed, ok, nnz = jb._unpack_tick(packed, nnz_max)
+        return (jb._dense_alloc(idx, vals, c_pad, n_pad)[:C, :N],
+                placed, ok, nnz)
+
+    @pytest.mark.parametrize("seed", [0, 1, 2, 3])
+    @pytest.mark.parametrize("fused", [False, True])
+    def test_packed_tick_decodes_to_the_oracle(self, seed, fused):
+        rng = np.random.default_rng(seed)
         avail, total, demand, counts, an, ac = random_problem(rng)
-        solver.prepare_device(avail, total, demand, accel_node=an,
-                              accel_class=ac, spread_threshold=0.5)
-        K = 6
-        arrivals = np.stack([np.roll(counts, k) for k in range(K)])
-        rho = rng.integers(1, 9, size=demand.shape[0]) / 16.0  # dyadic
-        out = solver.solve_stream(arrivals, nnz_max=512, rho=rho)
-        assert out["ok"].all()
-        want_ticks = stream_oracle(avail, total, demand, arrivals, rho,
-                                   an, ac, spread_threshold=0.5)
-        for k in range(K):
-            alloc = solver.expand_sparse(out["idx"][k], out["vals"][k])
-            np.testing.assert_array_equal(alloc, want_ticks[k],
-                                          err_msg=f"tick {k}")
-            assert int(out["nnz"][k]) == int((want_ticks[k] > 0).sum())
-            assert int(out["placed"][k]) == int(want_ticks[k].sum())
+        cost = None
+        if seed % 2:
+            shape = (demand.shape[0], avail.shape[0])
+            cost = np.where(rng.random(shape) < 0.15,
+                            rng.uniform(-0.7, 0.5, shape),
+                            0.0).astype(np.float32)
+        want = waterfill_oracle(avail, total, demand, counts, an, ac,
+                                spread_threshold=0.5, cost=cost)
+        assert (want > 0).sum() > 16          # several cells per class
+        alloc, placed, ok, nnz = self._tick(avail, total, demand, counts,
+                                            an, ac, nnz_max=512, cost=cost,
+                                            fused=fused)
+        assert ok
+        np.testing.assert_array_equal(alloc, want)
+        assert nnz == int((want > 0).sum())
+        assert placed == int(want.sum())
 
-    def test_stream_availability_actually_evolves(self):
-        """With rho=0 (no completions) capacity drains monotonically: a
-        saturating arrival stream places less and less until nothing
-        fits — impossible under the old reset-each-tick semantics."""
-        solver = BatchSolver(mode="waterfill")
-        avail = total = np.full((8, 1), 4.0, dtype=np.float32)  # 32 slots
-        demand = np.ones((1, 1), dtype=np.float32)
-        solver.prepare_device(avail, total, demand)
-        arrivals = np.full((4, 1), 20, dtype=np.int64)
-        out = solver.solve_stream(arrivals, nnz_max=64, rho=0.0)
-        assert out["ok"].all()
-        placed = out["placed"].astype(int).tolist()
-        assert placed[0] == 20 and placed[1] == 12  # 32-slot drain
-        assert placed[2] == 0 and placed[3] == 0
-        # And with completions the steady state keeps placing.
-        out2 = solver.solve_stream(np.full((6, 1), 8, dtype=np.int64),
-                                   nnz_max=64, rho=0.5)
-        assert out2["ok"].all()
-        assert out2["placed"][-1] > 0
-
-    def test_stream_overflow_flagged(self):
-        # nnz_max smaller than the true nonzero count must trip ok=False.
-        solver = BatchSolver(mode="waterfill")
+    def test_overflow_clears_the_ok_bit(self):
+        """``nnz_max`` below the true number of nonzeros must come back
+        ``ok`` false with the true count — the bit ``_solve_groups``
+        falls back to the greedy path on — and the next bucket up holds
+        the same tick whole."""
         avail = total = np.full((16, 2), 100.0, dtype=np.float32)
         demand = np.ones((8, 2), dtype=np.float32)
-        solver.prepare_device(avail, total, demand)
-        stream = np.full((1, 8), 16, dtype=np.int64)  # fills many cells
-        out = solver.solve_stream(stream, nnz_max=4)
-        assert not out["ok"].all()
-
-
-class TestSinkhornKernel:
-    def test_capacity_respected_and_spreads(self):
-        solver = BatchSolver(mode="sinkhorn")
-        N = 16
-        avail = total = np.full((N, 2), 8.0, dtype=np.float32)
-        demand = np.array([[1.0, 0.0]], dtype=np.float32)
-        counts = np.array([64])
-        alloc = solver.solve_matrices(avail, total, demand, counts)
-        usage = alloc.T.astype(np.float64) @ demand.astype(np.float64)
-        assert (usage <= avail + 1e-3).all()
-        assert alloc.sum() == 64
-        # Sinkhorn balances: several nodes should share the load.
-        assert (alloc[0] > 0).sum() >= 4
-
-    def test_feasibility_random(self):
-        rng = np.random.default_rng(7)
-        solver = BatchSolver(mode="sinkhorn")
-        for _ in range(3):
-            avail, total, demand, counts, an, ac = random_problem(rng)
-            alloc = solver.solve_matrices(avail, total, demand, counts,
-                                          an, ac)
-            usage = alloc.T.astype(np.float64) @ demand.astype(np.float64)
-            assert (usage <= avail + 1e-3).all()
-            assert (alloc.sum(axis=1) <= counts).all()
+        counts = np.full(8, 16)                # a cell per class: 8 > 4
+        none = np.zeros(16, dtype=bool), np.zeros(8, dtype=bool)
+        _, placed, ok, nnz = self._tick(avail, total, demand, counts,
+                                        *none, nnz_max=4)
+        assert not ok and nnz > 4 and placed == 8 * 16
+        alloc, placed, ok, nnz_fit = self._tick(avail, total, demand,
+                                                counts, *none, nnz_max=256)
+        assert ok and nnz_fit == nnz == int((alloc > 0).sum())
+        assert alloc.sum(axis=1).tolist() == [16] * 8
 
 
 class TestDeviceRuntimeSolver:

@@ -90,7 +90,7 @@ class TestShmAbort:
     create-reservations: a worker fires abort on any mid-write failure,
     including a timed-out seal reply that actually landed — deleting
     the now-sealed (registered, locatable) object would corrupt it for
-    every other reader (ADVICE.md)."""
+    every other reader."""
 
     def _host_stub(self, native):
         import threading

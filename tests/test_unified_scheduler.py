@@ -38,7 +38,7 @@ class TestCostMatrixKernel:
     @pytest.mark.parametrize("seed", [0, 1, 2, 3])
     def test_cost_matches_numpy_oracle(self, seed):
         rng = np.random.default_rng(seed)
-        solver = BatchSolver(mode="waterfill")
+        solver = BatchSolver()
         avail, total, demand, counts, an, ac = _random_problem(rng)
         cost = np.where(rng.random((demand.shape[0], avail.shape[0])) < 0.15,
                         rng.uniform(-0.7, 0.5,
@@ -53,7 +53,7 @@ class TestCostMatrixKernel:
     @pytest.mark.parametrize("seed", [0, 1])
     def test_pack_mode_matches_numpy_oracle(self, seed):
         rng = np.random.default_rng(seed)
-        solver = BatchSolver(mode="waterfill")
+        solver = BatchSolver()
         avail, total, demand, counts, an, ac = _random_problem(rng)
         got = solver.solve_matrices(avail, total, demand, counts, an, ac,
                                     spread_threshold=0.0, invert_util=True,
@@ -66,7 +66,7 @@ class TestCostMatrixKernel:
     def test_locality_cost_steers_placement(self):
         """A strong negative cost on one node pulls the whole class
         there (capacity permitting) — the arg-locality shape."""
-        solver = BatchSolver(mode="waterfill")
+        solver = BatchSolver()
         N = 8
         avail = total = np.full((N, 1), 10.0, dtype=np.float32)
         demand = np.ones((1, 1), dtype=np.float32)
@@ -81,7 +81,7 @@ class TestCostMatrixKernel:
     def test_pack_mode_minimizes_nodes_used(self):
         """Inverted-utilization + zero shifts = bin-packing order: the
         solve fills one node before touching the next."""
-        solver = BatchSolver(mode="waterfill")
+        solver = BatchSolver()
         N = 8
         avail = total = np.full((N, 1), 10.0, dtype=np.float32)
         demand = np.ones((2, 1), dtype=np.float32)
@@ -95,7 +95,7 @@ class TestCostMatrixKernel:
     def test_accel_class_lands_on_accel_nodes_cpu_avoids(self):
         """Heterogeneity baseline: accelerator demand can only land on
         accelerator nodes; CPU-only classes avoid them (bucket 17)."""
-        solver = BatchSolver(mode="waterfill")
+        solver = BatchSolver()
         N = 8
         total = np.zeros((N, 3), dtype=np.float32)
         total[:, 0] = 8.0                     # CPU everywhere

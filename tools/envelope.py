@@ -5,7 +5,7 @@ Thin runnable wrapper over :mod:`ray_tpu._private.envelope` — the same
 driver backs ``ray-tpu envelope`` and ``bench_runtime.py
 --envelope-smoke``.  Typical runs:
 
-    # The recorded 50-host soak (writes ENVELOPE_r06.json):
+    # The 50-host soak (writes envelope.json unless --out names a file):
     python tools/envelope.py --hosts 50 --actors 10000 --pgs 1000
 
     # Quick smoke (4 hosts, small everything, one fault):
