@@ -38,6 +38,7 @@ results.
 import functools
 import json
 import os
+import re
 import sys
 import time
 
@@ -314,6 +315,12 @@ def _train_func(config: dict) -> dict:
     return {"losses": losses,
             "mosaic_in_step": "tpu_custom_call" in text,
             "mosaic_bwd_in_step": "flash_attention_bwd" in text,
+            # Mosaic calls named for the forward kernel: one, in the
+            # forward scan's body, where remat keeps its out and lse;
+            # the backward scan's body would hold a second.
+            "flash_fwd_calls_in_step": len(re.findall(
+                r'%flash_attention_fwd[.\d]* = [^\n]*"tpu_custom_call"',
+                text)),
             "param_platforms": sorted({d.platform for d in leaf.devices()}),
             "n_params": sum(int(np.prod(x.shape))
                             for x in jax.tree.leaves(state["params"]))}
@@ -325,10 +332,12 @@ def leg_trainer(platform: str = "tpu", model: dict = None, batch: int = None,
     """Trainer(backend="jax", num_workers=1, use_tpu=True) on a head that
     advertises the chip; a repeated batch, so the loss must fall.  On the
     chip the compiled step must contain the Mosaic flash kernels, forward
-    and backward, and both must agree with ``full_attention`` and its
-    ``jax.grad``, under the causal mask and under the block-diffusion
-    mask with grouped K/V heads (off the chip the kernels are checked in
-    interpret mode and ``attention()`` takes the reference)."""
+    and backward, the forward once (remat keeps what it wrote and does
+    not run it again in the backward scan), and both must agree with
+    ``full_attention`` and its ``jax.grad``, under the causal mask and
+    under the block-diffusion mask with grouped K/V heads (off the chip
+    the kernels are checked in interpret mode and ``attention()`` takes
+    the reference)."""
     import jax
     import jax.numpy as jnp
 
@@ -425,11 +434,16 @@ def leg_trainer(platform: str = "tpu", model: dict = None, batch: int = None,
     check(result["mosaic_bwd_in_step"] == on_chip,
           f"Mosaic flash backward kernel in the compiled step: "
           f"{result['mosaic_bwd_in_step']} (expected {on_chip})")
+    check(result["flash_fwd_calls_in_step"] == int(on_chip),
+          f"the flash forward kernel once a layer (remat keeps out and "
+          f"lse): {result['flash_fwd_calls_in_step']} calls in the "
+          f"compiled step (expected {int(on_chip)})")
     return {"model": model, "batch": batch, "seq": seq, "dtype": dtype,
             "params_m": round(result["n_params"] / 1e6, 1),
             "losses": [round(x, 4) for x in losses],
             "flash_in_step": result["mosaic_in_step"],
             "flash_bwd_in_step": result["mosaic_bwd_in_step"],
+            "flash_fwd_calls_in_step": result["flash_fwd_calls_in_step"],
             "flash_vs_full_max_abs_err": flash_err,
             "flash_bwd_vs_grad_of_full_max_rel_err": flash_bwd_err,
             "block_diffusion_gqa_flash_vs_full_max_abs_err": bd_err,

@@ -11,7 +11,9 @@ graft entry exercise):
     via ring attention (ops/ring_attention.py), sequence-parallel
     activation sharding between blocks.
   * ``lax.scan`` over stacked layer params — one compilation regardless
-    of depth; optional ``jax.checkpoint`` rematerialisation.
+    of depth; optional ``jax.checkpoint`` rematerialisation that keeps
+    each layer's input and the flash kernel's ``out`` and ``lse``
+    (``remat_layer``) and recomputes the rest in the backward pass.
   * bf16 activations/params with f32 RMSNorm + softmax + Adam moments.
 """
 
@@ -26,7 +28,9 @@ import jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from ray_tpu.ops.attention_mask import CAUSAL
-from ray_tpu.ops.flash_attention import attention as flash_or_ref_attention
+from ray_tpu.ops.flash_attention import (
+    RESIDUAL_NAMES as FLASH_RESIDUAL_NAMES,
+    attention as flash_or_ref_attention)
 from ray_tpu.ops.ring_attention import ring_attention
 from ray_tpu.util import tracing
 
@@ -41,6 +45,8 @@ class TransformerConfig:
     max_seq_len: int = 2048
     rope_theta: float = 10_000.0
     dtype: Any = jnp.bfloat16
+    #: Recompute each layer in the backward pass from its input and the
+    #: flash kernel's ``out`` and ``lse`` (``remat_layer``).
     remat: bool = True
     #: Use ring attention over the "sp" mesh axis when its size > 1.
     context_parallel: bool = True
@@ -252,6 +258,20 @@ def apply_layer(x, lp, positions, cfg: TransformerConfig, mesh=None,
     return x, counted
 
 
+def remat_layer(layer, cfg: TransformerConfig):
+    """``layer`` as a scan over the stacked layers runs it: under
+    ``cfg.remat`` its backward pass recomputes the layer from its input,
+    all but the flash kernel's ``out`` and ``lse``, which are kept (the
+    kernel's call is the dearest thing in a layer per byte it leaves,
+    and its backward kernel reads just these two).  The jnp and ring
+    attention paths make no such names and are recomputed whole."""
+    if not cfg.remat:
+        return layer
+    return jax.checkpoint(
+        layer, policy=jax.checkpoint_policies.save_only_these_names(
+            *FLASH_RESIDUAL_NAMES))
+
+
 def run_layers(params: Dict, tokens: jax.Array, positions: jax.Array,
                cfg: TransformerConfig, mesh=None, mask=CAUSAL):
     """Embedding and the scan over the stacked layers: tokens [B, S]
@@ -265,7 +285,7 @@ def run_layers(params: Dict, tokens: jax.Array, positions: jax.Array,
     def layer(x, lp):
         return apply_layer(x, lp, positions, cfg, mesh, mask)
 
-    layer_fn = jax.checkpoint(layer) if cfg.remat else layer
+    layer_fn = remat_layer(layer, cfg)
     x, counted = jax.lax.scan(lambda x, lp: layer_fn(x, lp), x,
                               params["layers"])
     return x, {k: jnp.mean(v) if v.ndim == 1 else v

@@ -42,6 +42,14 @@ K/V are never repeated in HBM; the backward's grid runs the group's
 query heads one after another over each K/V head and sums ``dk``/``dv``
 in a float32 VMEM scratch.
 
+The ``custom_vjp``'s residuals are (q, k, v, out, lse).  ``out`` and
+``lse`` carry the names ``RESIDUAL_NAMES``: a layer rematerialised under
+``models.transformer.remat_layer`` keeps those two (as much again as
+the layer's input where H * D is ``d_model``, and 4 bytes a row) and so
+runs this forward kernel once a layer, not again in the backward pass;
+q, k and v -- three times the bytes for less time -- are recomputed from
+the layer's input like everything else.
+
 ``attention()`` picks the kernel on TPU and the jnp reference
 (ops.ring_attention.full_attention) elsewhere; tests run the kernel in
 Pallas interpret mode.
@@ -53,12 +61,19 @@ import functools
 
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from ray_tpu.ops.attention_mask import CAUSAL
 
 _NEG_INF = -1e30
+# What the forward kernel writes and the backward kernel reads, named
+# where the custom_vjp makes them its residuals (out [BH, L, D] in the
+# input dtype, lse [BH, L] float32): a ``jax.checkpoint`` whose policy
+# saves these names (``models.transformer.remat_layer``) does not run the
+# forward kernel again in the backward pass.  q, k, v carry no name.
+RESIDUAL_NAMES = ("flash_out", "flash_lse")
 # dot_general dimension numbers: contract the minor dims (a @ b.T) and
 # the major dims (a.T @ b).
 _NT = (((1,), (1,)), ((), ()))
@@ -305,6 +320,8 @@ def _flash(qh, kh, vh, mask, block_q, block_k, interpret):
 def _flash_vjp_fwd(qh, kh, vh, mask, block_q, block_k, interpret):
     out, lse = _flash_forward(qh, kh, vh, mask, block_q, block_k,
                               interpret)
+    out = checkpoint_name(out, RESIDUAL_NAMES[0])
+    lse = checkpoint_name(lse, RESIDUAL_NAMES[1])
     return out, (qh, kh, vh, out, lse)
 
 

@@ -25,7 +25,8 @@ import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
 from ray_tpu.models.transformer import (TransformerConfig, _rms_norm,
-                                        apply_layer, param_specs)
+                                        apply_layer, param_specs,
+                                        remat_layer)
 
 
 def pp_param_specs(cfg: TransformerConfig) -> Dict:
@@ -77,8 +78,7 @@ def make_pp_loss_fn(cfg: TransformerConfig, mesh, n_micro: int):
             def body(h, lp):
                 return apply_layer(h, lp, positions, cfg, mesh=None)[0], None
 
-            body_fn = jax.checkpoint(body) if cfg.remat else body
-            return jax.lax.scan(body_fn, x, layers)[0]
+            return jax.lax.scan(remat_layer(body, cfg), x, layers)[0]
 
         def ce(h, tgt):
             logits = jnp.einsum(

@@ -293,6 +293,65 @@ def test_defaults_leave_the_dense_model_bitwise_as_it_was(dtype, remat):
     assert set(metrics) == {"loss", "grad_norm"}
 
 
+def _dense_objective(remat):
+    cfg = TransformerConfig(vocab_size=256, d_model=64, n_layers=2,
+                            n_heads=4, d_ff=96, max_seq_len=128,
+                            dtype=jnp.float32, remat=remat)
+    batch = {"tokens": jax.random.randint(jax.random.PRNGKey(4), (2, 129),
+                                          0, 256, dtype=jnp.int32)}
+    return cfg, lambda p: loss_fn(p, batch, cfg)
+
+
+def _block_diffusion_objective(remat):
+    """The expert stack (8 experts, 4 held, top 2; 4 query heads on 2
+    K/V heads) under the block-diffusion objective: rows of 128 tokens
+    run as 256 positions."""
+    cfg = TransformerConfig(vocab_size=128, d_model=64, n_layers=2,
+                            n_heads=4, n_kv_heads=2, head_dim=16,
+                            qk_norm=True, d_ff=32, max_seq_len=256,
+                            dtype=jnp.float32, remat=remat, moe_experts=8,
+                            moe_top_k=2, moe_experts_held=(2, 4))
+    x0 = jax.random.randint(jax.random.PRNGKey(4), (2, 128), 0, 127,
+                            dtype=jnp.int32)
+    xt, weight = block_diffusion.noise(jax.random.PRNGKey(5), x0, 4, 127)
+    batch = {"tokens": x0, "noisy": xt, "weight": weight}
+    return cfg, lambda p: block_diffusion.loss_fn(p, batch, cfg, 4)[0]
+
+
+@pytest.mark.parametrize("attention", ["reference", "kernel"])
+@pytest.mark.parametrize("objective", [_dense_objective,
+                                       _block_diffusion_objective],
+                         ids=["dense", "experts-blockdiff"])
+def test_remat_that_keeps_the_flash_residuals_changes_no_number(
+        objective, attention, monkeypatch):
+    """``remat=True`` (the layer's input and, where the kernel runs, its
+    ``out`` and ``lse`` kept; the rest recomputed) against
+    ``remat=False``: the same loss and gradients.  With the kernel in the
+    model (interpreted here; the chip's dispatch) the gradient holds the
+    forward kernel once: the policy reaches it through ``run_layers``."""
+    if attention == "kernel":
+        from ray_tpu.models import transformer
+        from ray_tpu.ops.flash_attention import flash_attention
+        monkeypatch.setattr(transformer, "flash_or_ref_attention",
+                            functools.partial(flash_attention,
+                                              interpret=True))
+    got = {}
+    for remat in (True, False):
+        cfg, loss = objective(remat)
+        params = init_params(jax.random.PRNGKey(3), cfg)
+        fn = jax.value_and_grad(loss)
+        text = str(jax.make_jaxpr(fn)(params))
+        assert text.count("name=flash_attention_fwd") == text.count(
+            "name=flash_attention_bwd") == (attention == "kernel")
+        got[remat] = jax.jit(fn)(params)
+    assert float(got[True][0]) == float(got[False][0])
+    for a, b in zip(jax.tree.leaves(got[True][1]),
+                    jax.tree.leaves(got[False][1])):
+        assert bool(jnp.all(jnp.isfinite(a)))
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                   rtol=1e-5, atol=1e-7)
+
+
 def test_the_spans_and_counters_of_the_objective_have_readers():
     """``train.noise`` around the collator's draw; the step's counters,
     where a worker reports them, as gauges on /metrics; the two

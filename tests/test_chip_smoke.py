@@ -59,6 +59,7 @@ def test_leg_trainer(smoke):
     assert facts["losses"][-1] < facts["losses"][0]
     assert not facts["flash_in_step"]             # reference off the chip
     assert not facts["flash_bwd_in_step"]
+    assert facts["flash_fwd_calls_in_step"] == 0  # no Mosaic call to count
     assert facts["flash_bwd_vs_grad_of_full_max_rel_err"] <= 1e-4
 
 

@@ -4,13 +4,17 @@ mode on the CPU, against ``full_attention`` and its ``jax.grad``.
 tier-1 reaches it; chip_smoke.py asks the chip the same question at
 full width."""
 
+import re
+
 import jax
 import jax.numpy as jnp
 import pytest
 
+from ray_tpu.models.transformer import TransformerConfig, remat_layer
 from ray_tpu.ops.attention_mask import CAUSAL, FULL, BlockDiffusion
-from ray_tpu.ops.flash_attention import (_FWD_BLOCKS, _flash_forward,
-                                         attention, flash_attention)
+from ray_tpu.ops.flash_attention import (_FWD_BLOCKS, RESIDUAL_NAMES,
+                                         _flash_forward, attention,
+                                         flash_attention)
 from ray_tpu.ops.ring_attention import full_attention
 
 # Max abs error allowed on outputs and gradients of O(1) magnitude:
@@ -161,6 +165,77 @@ def test_gradient_is_pallas_kernels_and_no_loop_outside_them():
     loops = [eqn.primitive.name for eqn, kernel in eqns
              if eqn.primitive.name in ("scan", "while") and not kernel]
     assert loops == []
+
+
+def _two_layer_gradient(mask, kv_heads, wrap, interpret=True):
+    """jax.grad, by the carry and the stacked weights, of a two-layer
+    scan of ``wrap(layer)``: each layer projects its input, calls the
+    kernel and adds; with the arguments' shapes [B, L, H, D], [2, D, D]."""
+    def layer(x, w):
+        q = jnp.tanh(jnp.einsum("blhd,de->blhe", x, w))
+        k = q[:, :, :kv_heads] + x[:, :, :kv_heads]
+        return x + flash_attention(q, k, x[:, :, :kv_heads], mask=mask,
+                                   interpret=interpret), None
+
+    def loss(x, ws):
+        return jnp.sum(jax.lax.scan(wrap(layer), x, ws)[0].astype(
+            jnp.float32) ** 2)
+
+    return jax.grad(loss, (0, 1))
+
+
+def _keeping_the_residuals(layer):
+    return remat_layer(layer, TransformerConfig(remat=True))
+
+
+# The two shapes of the cells' attention at a small size: every head its
+# own K/V under the causal mask, four query heads to a K/V head under
+# block diffusion (2L = 256 positions).
+_REMAT_CASES = [(CAUSAL, 2, 2), (BlockDiffusion(128, 4), 4, 1)]
+
+
+@pytest.mark.parametrize("mask,heads,kv_heads", _REMAT_CASES,
+                         ids=["causal", "blockdiff-grouped"])
+def test_remat_keeps_the_residuals_so_the_forward_kernel_runs_once_a_layer(
+        mask, heads, kv_heads):
+    """The policy of ``remat_layer`` reaches the two names inside the
+    custom_vjp's forward rule, inside ``flash_attention``'s own jit,
+    under the scan: the backward scan's body has the backward kernel
+    only.  A plain ``jax.checkpoint`` runs the forward kernel there
+    again, to the same gradients bit for bit."""
+    x = _qkvd(jnp.float32, B=1, L=256, H=heads)[0]
+    ws = jax.random.normal(jax.random.PRNGKey(1), (2, 64, 64)) * 0.1
+    calls, grads = {}, {}
+    for how, wrap in (("kept", _keeping_the_residuals),
+                      ("plain", jax.checkpoint)):
+        fn = _two_layer_gradient(mask, kv_heads, wrap)
+        kernels = [eqn.params["name"] for eqn, kernel in _equations(
+            jax.make_jaxpr(fn)(x, ws).jaxpr)
+            if eqn.primitive.name == "pallas_call" and not kernel]
+        calls[how] = (kernels.count("flash_attention_fwd"),
+                      kernels.count("flash_attention_bwd"))
+        assert sum(calls[how]) == len(kernels)
+        grads[how] = fn(x, ws)
+    # one scan forward, one backward: a call in a body is a call a layer
+    assert calls == {"kept": (1, 1), "plain": (2, 1)}
+    for kept, plain in zip(grads["kept"], grads["plain"]):
+        assert bool(jnp.all(jnp.isfinite(kept)))
+        assert bool(jnp.array_equal(kept, plain))
+
+
+def test_the_names_are_made_by_the_kernel_path_only():
+    """``remat_layer`` without ``remat`` is the layer itself, and the
+    jnp reference makes no names for the policy to keep."""
+    def layer(x, w):
+        return x, None
+    assert remat_layer(layer, TransformerConfig(remat=False)) is layer
+    q, k, v, _ = _qkvd(jnp.float32, B=1, L=128, H=1)
+    named = {how: [eqn.params["name"] for eqn, _ in _equations(
+        jax.make_jaxpr(jax.grad(lambda q: jnp.sum(fn(q, k, v))))(q).jaxpr)
+        if eqn.primitive.name == "name"]
+        for how, fn in (("kernel", lambda q, k, v: flash_attention(
+            q, k, v, interpret=True)), ("reference", full_attention))}
+    assert named == {"kernel": list(RESIDUAL_NAMES), "reference": []}
 
 
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
@@ -384,6 +459,14 @@ def test_grouped_kv_heads_under_the_older_masks(causal):
                         dict(B=1, L=640, H=4, kv_heads=1))
 
 
+def _kernel_calls(text):
+    """(forward, backward) Mosaic calls in a compiled program's text, by
+    the kernels' names: the names a trace's events carry."""
+    return tuple(len(re.findall(
+        rf"%{name}[.\d]* = [^\n]*custom_call_target=\"tpu_custom_call\"",
+        text)) for name in ("flash_attention_fwd", "flash_attention_bwd"))
+
+
 @pytest.fixture(scope="module")
 def one_v5e_chip():
     """A described (not attached) v5e for the chip's own compiler; made
@@ -409,7 +492,7 @@ def test_gradient_compiles_for_the_chip_at_the_cell_width(one_v5e_chip):
             x, x, x).compile()
     text = compiled.as_text()
     assert text.count("tpu_custom_call") == 2
-    assert "flash_attention_fwd" in text and "flash_attention_bwd" in text
+    assert _kernel_calls(text) == (1, 1)
     assert " while(" not in text
 
 
@@ -426,5 +509,30 @@ def test_block_diffusion_gradient_compiles_for_the_chip_at_the_cell_width(
         (0, 1, 2))).lower(q, kv, kv).compile()
     text = compiled.as_text()
     assert text.count("tpu_custom_call") == 2
-    assert "flash_attention_fwd" in text and "flash_attention_bwd" in text
+    assert _kernel_calls(text) == (1, 1)
     assert " while(" not in text
+
+
+@pytest.mark.parametrize("how,wrap,want", [
+    ("kept", _keeping_the_residuals, (1, 1)),
+    ("plain", jax.checkpoint, (2, 1))])
+@pytest.mark.parametrize("mask,q_shape,kv_heads", [
+    (CAUSAL, (4, 4096, 16, 128), 16),
+    (BlockDiffusion(4096, 4), (4, 8192, 32, 128), 4)],
+    ids=["dense-cell", "blockdiff-cell"])
+def test_remat_scan_compiles_for_the_chip_with_one_forward_kernel_a_layer(
+        one_v5e_chip, mask, q_shape, kv_heads, how, wrap, want):
+    """What the chip's compiler is handed at both cells' attention
+    shapes: the gradient of a two-layer scan holds the forward kernel
+    once (in the forward loop's body) where remat keeps ``out`` and
+    ``lse``, and a second time in the backward loop's where it does
+    not."""
+    x = jax.ShapeDtypeStruct(q_shape, jnp.bfloat16, sharding=one_v5e_chip)
+    ws = jax.ShapeDtypeStruct((2, 128, 128), jnp.bfloat16,
+                              sharding=one_v5e_chip)
+    text = jax.jit(_two_layer_gradient(mask, kv_heads, wrap,
+                                       interpret=False)).lower(
+        x, ws).compile().as_text()
+    assert _kernel_calls(text) == want
+    assert text.count("tpu_custom_call") == sum(want)
+    assert text.count(" while(") == 2

@@ -236,3 +236,47 @@ def test_pipeline_parallel_matches_single_device():
         assert np.isfinite(float(metrics["loss"]))
         state, metrics2 = step(state, {"tokens": tokens})
         assert float(metrics2["loss"]) < float(metrics["loss"]) + 1.0
+
+
+@pytest.mark.parametrize("attention", ["reference", "kernel"])
+def test_pipeline_stage_remat_matches_no_remat(attention, monkeypatch):
+    """The stage's scan shares ``remat_layer`` with ``run_layers``:
+    ``remat=True`` (now keeping the flash kernel's residuals, where the
+    kernel runs: interpreted here) gives the loss and the gradients of
+    ``remat=False`` over pp=2 x dp=2."""
+    import functools
+
+    from ray_tpu.models import transformer
+    from ray_tpu.ops.flash_attention import flash_attention
+    from ray_tpu.parallel.mesh import MeshConfig, build_mesh
+    from ray_tpu.parallel.pipeline import (make_pp_loss_fn,
+                                           make_pp_train_state)
+    if attention == "kernel":
+        monkeypatch.setattr(transformer, "flash_or_ref_attention",
+                            functools.partial(flash_attention,
+                                              interpret=True))
+    base = transformer.TransformerConfig(
+        vocab_size=64, d_model=32, n_layers=4, n_heads=2, d_ff=64,
+        max_seq_len=128, dtype=jnp.float32, context_parallel=False)
+    tokens = jax.random.randint(jax.random.PRNGKey(1), (4, 129), 0, 64,
+                                dtype=jnp.int32)
+    mesh = build_mesh(MeshConfig(dp=2, pp=2), devices=jax.devices()[:4])
+    got = {}
+    with mesh:
+        for remat in (True, False):
+            cfg = dataclasses.replace(base, remat=remat)
+            state, _ = make_pp_train_state(jax.random.PRNGKey(0), cfg, mesh)
+            pp_loss = make_pp_loss_fn(cfg, mesh, n_micro=2)
+            fn = jax.value_and_grad(
+                lambda p: pp_loss(p, {"tokens": tokens}))
+            text = str(jax.make_jaxpr(fn)(state["params"]))
+            assert ("name=flash_attention_fwd" in text) == (
+                attention == "kernel")
+            got[remat] = jax.jit(fn)(state["params"])
+    assert float(got[True][0]) == pytest.approx(float(got[False][0]),
+                                                rel=1e-6)
+    for a, b in zip(jax.tree.leaves(got[True][1]),
+                    jax.tree.leaves(got[False][1])):
+        assert bool(jnp.all(jnp.isfinite(a)))
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                   rtol=1e-5, atol=1e-7)
