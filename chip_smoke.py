@@ -287,6 +287,12 @@ TPU_MODEL = dict(vocab_size=32_000, d_model=1024, n_layers=8, n_heads=16,
 TPU_BATCH, TPU_SEQ = 8, 1024
 
 
+def _max_err(a, b) -> float:
+    import jax.numpy as jnp
+    return float(jnp.max(jnp.abs(a.astype(jnp.float32)
+                                 - b.astype(jnp.float32))))
+
+
 def _train_func(config: dict) -> dict:
     """Runs inside the Train worker (a thread of this process)."""
     import jax
@@ -297,10 +303,17 @@ def _train_func(config: dict) -> dict:
                                             make_train_state,
                                             make_train_step)
 
-    cfg = TransformerConfig(dtype=jnp.dtype(config["dtype"]),
-                            **config["model"])
+    model = dict(config["model"])
+    if "mla" in model:
+        from ray_tpu.models.mla import MLAConfig
+        model["mla"] = MLAConfig(**model["mla"])
+    cfg = TransformerConfig(dtype=jnp.dtype(config["dtype"]), **model)
     state, tx = make_train_state(jax.random.PRNGKey(0), cfg)
-    step = make_train_step(cfg, tx)
+    objective = None
+    if cfg.mtp_depth:
+        from ray_tpu.models import mtp
+        objective = functools.partial(mtp.loss_fn, cfg=cfg, coeff=0.3)
+    step = make_train_step(cfg, tx, loss_override=objective)
     tokens = np.random.default_rng(0).integers(
         0, cfg.vocab_size, (config["batch"], config["seq"] + 1))
     batch = {"tokens": jnp.asarray(tokens, jnp.int32)}
@@ -313,6 +326,10 @@ def _train_func(config: dict) -> dict:
     leaf = jax.tree.leaves(state["params"])[0]
     text = compiled.as_text()
     return {"losses": losses,
+            # the last step's scalars (the expert layers' counters, the
+            # two losses and the bias of a latent-attention model)
+            "counters": {k: float(v) for k, v in metrics.items()
+                         if v.ndim == 0},
             "mosaic_in_step": "tpu_custom_call" in text,
             "mosaic_bwd_in_step": "flash_attention_bwd" in text,
             # Mosaic calls named for the forward kernel: one, in the
@@ -358,13 +375,9 @@ def leg_trainer(platform: str = "tpu", model: dict = None, batch: int = None,
                                        jnp.float32).astype(jnp.dtype(dtype))
                      for key in jax.random.split(jax.random.PRNGKey(7), 4))
 
-    def max_err(a, b):
-        return float(jnp.max(jnp.abs(a.astype(jnp.float32)
-                                     - b.astype(jnp.float32))))
-
     flash = flash_attention(q, k, v, interpret=not on_chip)
     ref = full_attention(q, k, v)
-    flash_err = max_err(flash, ref)
+    flash_err = _max_err(flash, ref)
     check(flash_err <= flash_tol,
           f"flash forward vs full_attention: max abs err {flash_err} "
           f"> {flash_tol} ({dtype})")
@@ -378,7 +391,7 @@ def leg_trainer(platform: str = "tpu", model: dict = None, batch: int = None,
 
     def grads_err(got, want):
         return max(
-            max_err(g, w) / float(jnp.max(jnp.abs(w.astype(jnp.float32))))
+            _max_err(g, w) / float(jnp.max(jnp.abs(w.astype(jnp.float32))))
             for g, w in zip(got, want))
 
     flash_bwd_err = grads_err(
@@ -401,7 +414,7 @@ def leg_trainer(platform: str = "tpu", model: dict = None, batch: int = None,
     bd_flash = functools.partial(flash_attention, mask=mask,
                                  interpret=not on_chip)
     bd_full = functools.partial(full_attention, mask=mask)
-    bd_err = max_err(bd_flash(q, k, v), bd_full(q, k, v))
+    bd_err = _max_err(bd_flash(q, k, v), bd_full(q, k, v))
     check(bd_err <= flash_tol,
           f"block-diffusion grouped flash forward vs full_attention: max "
           f"abs err {bd_err} > {flash_tol} ({dtype})")
@@ -449,6 +462,121 @@ def leg_trainer(platform: str = "tpu", model: dict = None, batch: int = None,
             "block_diffusion_gqa_flash_vs_full_max_abs_err": bd_err,
             "block_diffusion_gqa_flash_bwd_max_rel_err": bd_bwd_err,
             "block_diffusion_kv_heads": kv_heads,
+            "flash_tol": flash_tol}
+
+
+#: The mixed stack at a size the chip takes in seconds: latent
+#: attention at the published head sizes (128 + 64 score columns, 128
+#: value columns), one dense-FFN layer, two expert layers (8 of 32
+#: experts held, 4 a token, a shared expert, the sigmoid router's
+#: correction bias) and the multi-token-prediction module: 181.9M
+#: parameters.
+LATENT_MODEL = dict(
+    vocab_size=32_000, d_model=1024, n_heads=16, d_ff=4096, max_seq_len=1024,
+    remat=True, norm_eps=1e-6, rope_theta=32e6,
+    mla=dict(q_lora_rank=768, kv_lora_rank=256, qk_nope_head_dim=128,
+             qk_rope_head_dim=64, v_head_dim=128),
+    layer_pattern=(("mla", "dense", 1), ("mla", "moe", 2)), mtp_depth=1,
+    moe_experts=32, moe_top_k=4, moe_experts_held=(0, 8), moe_d_ff=512,
+    moe_scoring="sigmoid", moe_route_scale=2.5, moe_shared_width=512,
+    moe_bias_rate=1e-3, moe_aux_coeff=0.0)
+
+
+def leg_latent_trainer(platform: str = "tpu", model: dict = None,
+                       batch: int = 4, seq: int = 1024, steps: int = 2,
+                       dtype: str = "bfloat16",
+                       flash_tol: float = 4e-2) -> dict:
+    """The mixed layer stack through the same Trainer path, ``steps``
+    steps: both flash kernels with the shared rotary key first compared
+    with ``full_attention`` and its ``jax.grad`` (all five gradients) at
+    the model's attention shape; then the compiled step must hold the
+    forward kernel once a run of the layer pattern and once in the
+    module, nothing may be dropped, and the routers' correction bias
+    must have moved by its rate a step."""
+    import jax
+    import jax.numpy as jnp
+
+    import ray_tpu
+    from ray_tpu.ops.flash_attention import flash_attention
+    from ray_tpu.ops.ring_attention import full_attention
+    from ray_tpu.train import Trainer
+
+    model = dict(model or LATENT_MODEL)
+    on_chip = platform == "tpu"
+    heads, m = model["n_heads"], model["mla"]
+    shapes = [(batch, seq, heads, m["qk_nope_head_dim"]),
+              (batch, seq, heads, m["qk_nope_head_dim"]),
+              (batch, seq, heads, m["v_head_dim"]),
+              (batch, seq, heads, m["qk_rope_head_dim"]),
+              (batch, seq, m["qk_rope_head_dim"]),
+              (batch, seq, heads, m["v_head_dim"])]
+    *operands, dout = (
+        jax.random.normal(key, shape, jnp.float32).astype(jnp.dtype(dtype))
+        for key, shape in zip(jax.random.split(jax.random.PRNGKey(9), 6),
+                              shapes))
+
+    def kernel(q, k, v, q_rope, k_rope):
+        return flash_attention(q, k, v, interpret=not on_chip,
+                               q_rope=q_rope, k_rope=k_rope)
+
+    def reference(q, k, v, q_rope, k_rope):
+        return full_attention(q, k, v, q_rope=q_rope, k_rope=k_rope)
+
+    def grads(fn):
+        return jax.grad(lambda *xs: jnp.sum(
+            fn(*xs).astype(jnp.float32) * dout.astype(jnp.float32)),
+            (0, 1, 2, 3, 4))(*operands)
+
+    fwd_err = _max_err(kernel(*operands), reference(*operands))
+    check(fwd_err <= flash_tol,
+          f"latent flash forward vs full_attention: max abs err {fwd_err} "
+          f"> {flash_tol} ({dtype})")
+    bwd_err = max(
+        _max_err(g, w) / float(jnp.max(jnp.abs(w.astype(jnp.float32))))
+        for g, w in zip(grads(kernel), grads(reference)))
+    check(bwd_err <= flash_tol,
+          f"latent flash backward (dq, dk, dv, dq_rope, dk_rope) vs grad of "
+          f"full_attention: {bwd_err} > {flash_tol} ({dtype})")
+
+    ray_tpu.init(num_cpus=4, num_tpus=len(jax.devices()))
+    try:
+        trainer = Trainer(backend="jax", num_workers=1, use_tpu=True)
+        try:
+            (result,) = trainer.run(_train_func, config=dict(
+                model=model, batch=batch, seq=seq, steps=steps,
+                dtype=dtype))
+        finally:
+            trainer.shutdown()
+    finally:
+        ray_tpu.shutdown()
+    losses, counters = result["losses"], result["counters"]
+    check(len(losses) == steps and np.isfinite(losses).all(),
+          f"finite losses: {losses}")
+    check(losses[-1] < losses[0], f"loss falls on a repeated batch: {losses}")
+    check(result["param_platforms"] == [platform],
+          f"params on {platform}: {result['param_platforms']}")
+    # a forward kernel a scanned run and one in the module
+    runs = len(model["layer_pattern"]) + model["mtp_depth"]
+    check(result["flash_fwd_calls_in_step"] == runs * int(on_chip),
+          f"the flash forward kernel once a layer in each of the {runs} "
+          f"scans: {result['flash_fwd_calls_in_step']} calls in the "
+          f"compiled step (expected {runs * int(on_chip)})")
+    check(result["mosaic_bwd_in_step"] == on_chip,
+          f"Mosaic flash backward kernel in the compiled step: "
+          f"{result['mosaic_bwd_in_step']} (expected {on_chip})")
+    check(counters["moe_dropped_choices"] == 0.0,
+          f"no choice dropped: {counters}")
+    check(abs(counters["moe_bias_abs_max"]
+              - steps * model["moe_bias_rate"]) < 1e-6,
+          f"the correction bias moves by its rate a step: {counters}")
+    return {"batch": batch, "seq": seq, "dtype": dtype,
+            "params_m": round(result["n_params"] / 1e6, 1),
+            "losses": [round(x, 4) for x in losses],
+            "counters": {k: round(v, 5) for k, v in counters.items()},
+            "flash_fwd_calls_in_step": result["flash_fwd_calls_in_step"],
+            "flash_bwd_in_step": result["mosaic_bwd_in_step"],
+            "latent_flash_vs_full_max_abs_err": fwd_err,
+            "latent_flash_bwd_max_rel_err": bwd_err,
             "flash_tol": flash_tol}
 
 
@@ -612,6 +740,8 @@ def main() -> int:
     run_leg("1 live runtime", clock, leg_live_runtime)
     run_leg("2 scheduler kernel 1M x 256 x 10k", clock, leg_scheduler_kernel)
     run_leg("3 trainer 200M x 8 x 1024", clock, leg_trainer)
+    run_leg("3b latent attention + experts + MTP 182M x 4 x 1024", clock,
+            leg_latent_trainer)
     if device["count"] > 1:
         run_leg("4 sharded solve", clock, leg_sharded_solve)
         run_leg("5 model parallel dp/sp/tp + ep + pp", clock,

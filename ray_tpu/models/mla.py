@@ -1,0 +1,122 @@
+"""Latent attention (MLA, as DeepSeek-V2/V3 publish it): queries and
+keys/values pass through low-rank latents, and position enters through
+a separate rotary part of the score whose key is ONE head a position,
+shared by all query heads.
+
+A layer-pattern kind (``"mla"`` in ``TransformerConfig.layer_pattern``)
+with its own parameters under ``lp["mla"]``.  For ``h [B, S, d]`` (the
+layer's normed input), ``H`` heads, ranks ``rq``/``rkv``, head sizes
+``dn`` (score, without position), ``dr`` (score, rotary), ``dv``:
+
+    c_q        = rmsnorm(h wq_a; q_norm)                 [rq]
+    q_nope|q_r = c_q wq_b                                H x (dn | dr)
+    c_kv|k_r   = h wkv_a                                 [rkv | dr]
+    k_nope|v   = rmsnorm(c_kv; kv_norm) wkv_b            H x (dn | dv)
+    s          = (q_nope . k_nope + rope(q_r) . rope(k_r)) (dn + dr)^-1/2
+    out        = softmax_mask(s) v, heads joined, times wo  [H dv, d]
+
+This is the training path: K and V are expanded from the latent and
+nothing is absorbed into ``wq_b``/``wo`` (the decode path's trick, with
+the latent as the cache).  Both flash kernels take the rotary part as
+operands of their own (``ops/flash_attention.py``): the shared key is
+never repeated per head, in HBM or in the kernel's reads.
+
+RoPE is over interleaved pairs ``(x[2i], x[2i+1])`` when
+``rope_interleave`` (the published layout), else over the two halves.
+The rotated pairs leave de-interleaved, ``[all first members ; all
+second members]``: a permutation of the rotary columns that q and k
+share, which the score does not see.
+"""
+
+from __future__ import annotations
+
+import dataclasses
+from typing import Dict
+
+import jax
+import jax.numpy as jnp
+from jax.sharding import PartitionSpec as P
+
+
+@dataclasses.dataclass(frozen=True)
+class MLAConfig:
+    q_lora_rank: int
+    kv_lora_rank: int
+    qk_nope_head_dim: int
+    qk_rope_head_dim: int
+    v_head_dim: int
+    rope_interleave: bool = True
+
+
+def init_mla_params(rng: jax.Array, n_layers: int, d_model: int,
+                    n_heads: int, m: MLAConfig, dtype) -> Dict:
+    init = jax.nn.initializers.normal(0.02)
+    keys = jax.random.split(rng, 5)
+    dn, dr, dv = m.qk_nope_head_dim, m.qk_rope_head_dim, m.v_head_dim
+
+    def stacked(key, shape):
+        return init(key, (n_layers, *shape), jnp.float32).astype(dtype)
+
+    return {
+        "wq_a": stacked(keys[0], (d_model, m.q_lora_rank)),
+        "q_norm": jnp.ones((n_layers, m.q_lora_rank), jnp.float32),
+        "wq_b": stacked(keys[1], (m.q_lora_rank, n_heads, dn + dr)),
+        "wkv_a": stacked(keys[2], (d_model, m.kv_lora_rank + dr)),
+        "kv_norm": jnp.ones((n_layers, m.kv_lora_rank), jnp.float32),
+        "wkv_b": stacked(keys[3], (m.kv_lora_rank, n_heads, dn + dv)),
+        "wo": stacked(keys[4], (n_heads, dv, d_model)),
+    }
+
+
+def mla_param_specs() -> Dict:
+    """The up-projections and the output projection by heads over
+    ``tp``; the two down-projections and their norms replicated (a
+    latent is whole on every shard)."""
+    return {
+        "wq_a": P(None, None, None),
+        "q_norm": P(None, None),
+        "wq_b": P(None, None, "tp", None),
+        "wkv_a": P(None, None, None),
+        "kv_norm": P(None, None),
+        "wkv_b": P(None, None, "tp", None),
+        "wo": P(None, "tp", None, None),
+    }
+
+
+def rope(x, positions, theta: float, interleave: bool):
+    """x [B, S, H, R] -> the rotated pairs, de-interleaved."""
+    from ray_tpu.models.transformer import _rope
+    if interleave:
+        pairs = x.reshape(*x.shape[:-1], x.shape[-1] // 2, 2)
+        x = jnp.concatenate([pairs[..., 0], pairs[..., 1]], axis=-1)
+    return _rope(x, positions, theta)
+
+
+def mla_attention(h, lp: Dict, positions, cfg, mesh=None, mask=None):
+    """The layer's normed input ``h [B, S, d]`` -> what attention adds
+    to the residual.  ``lp``: this layer's ``mla`` parameters."""
+    from ray_tpu.models.transformer import _rms_norm
+    from ray_tpu.ops.flash_attention import attention
+    m, eps = cfg.mla, cfg.norm_eps
+    if (cfg.context_parallel and mesh is not None
+            and mesh.shape.get("sp", 1) > 1):
+        raise ValueError("ring attention has no rotary part: latent "
+                         "attention does not run over an sp axis")
+    dn, rkv = m.qk_nope_head_dim, m.kv_lora_rank
+    with jax.named_scope("mla_q"):
+        c_q = _rms_norm(jnp.einsum("bsd,dr->bsr", h, lp["wq_a"]),
+                        lp["q_norm"], eps)
+        q = jnp.einsum("bsr,rhk->bshk", c_q, lp["wq_b"])
+        q_nope = q[..., :dn]
+        q_rope = rope(q[..., dn:], positions, cfg.rope_theta,
+                      m.rope_interleave)
+    with jax.named_scope("mla_kv"):
+        latent = jnp.einsum("bsd,dr->bsr", h, lp["wkv_a"])
+        c_kv = _rms_norm(latent[..., :rkv], lp["kv_norm"], eps)
+        k_rope = rope(latent[:, :, None, rkv:], positions, cfg.rope_theta,
+                      m.rope_interleave)[:, :, 0]
+        kv = jnp.einsum("bsr,rhk->bshk", c_kv, lp["wkv_b"])
+        k_nope, v = kv[..., :dn], kv[..., dn:]
+    o = attention(q_nope, k_nope, v, mask=mask, q_rope=q_rope, k_rope=k_rope)
+    with jax.named_scope("mla_out"):
+        return jnp.einsum("bshk,hkd->bsd", o, lp["wo"])

@@ -37,6 +37,20 @@ whatever its load, and what a heavier load leaves over in chunks of one
 choice of every token, as many as it fills.
 
 ``balance_loss`` is the load-balance auxiliary of top-k routing.
+
+Two routers.  ``scoring="softmax"`` is the one above.  ``"sigmoid"`` is
+the auxiliary-loss-free one (DeepSeek-V3's ``noaux_tc`` with one group):
+    score    = sigmoid(x @ wr)                      [T, E]  float32
+    e        = top_k(score + bias)      the bias only chooses
+    gate     = score[e] / (sum over e + 1e-20) * route_scale
+``bias`` [E] is no parameter: it is state beside the parameters, handed
+to the layer as ``lp["bias"]`` (no gradient reaches it), and after each
+step ``update_bias`` moves it by ``rate`` towards the experts that step
+loaded least.  A layer that has one also reports every expert's load.
+
+A shared expert (``ws1``/``ws3``/``ws2``, one SwiGLU every token
+passes) is added by ``shared_expert`` OUTSIDE the share's partial sum:
+on one chip's share and under an ``ep`` axis alike it counts once.
 """
 
 from __future__ import annotations
@@ -55,56 +69,73 @@ _ALIKE_TAIL = 0.01
 
 
 def init_moe_params(rng: jax.Array, n_layers: int, d_model: int,
-                    d_ff: int, n_experts: int, n_held: int, dtype) -> Dict:
-    """Router over all ``n_experts``; weights of the ``n_held`` held."""
+                    d_ff: int, n_experts: int, n_held: int, dtype,
+                    shared_width: int = 0) -> Dict:
+    """Router over all ``n_experts``; weights of the ``n_held`` held;
+    a shared expert of ``shared_width`` where that is not 0."""
     init = jax.nn.initializers.normal(0.02)
     keys = jax.random.split(rng, 4)
 
     def stacked(key, shape):
         return init(key, (n_layers, *shape), jnp.float32).astype(dtype)
 
-    return {
+    params = {
         "wr": stacked(keys[0], (d_model, n_experts)),
         "w1": stacked(keys[1], (n_held, d_model, d_ff)),
         "w3": stacked(keys[2], (n_held, d_model, d_ff)),
         "w2": stacked(keys[3], (n_held, d_ff, d_model)),
     }
+    if shared_width:
+        shared = jax.random.split(jax.random.fold_in(rng, 4), 3)
+        params.update({
+            "ws1": stacked(shared[0], (d_model, shared_width)),
+            "ws3": stacked(shared[1], (d_model, shared_width)),
+            "ws2": stacked(shared[2], (shared_width, d_model)),
+        })
+    return params
 
 
-def moe_param_specs() -> Dict:
-    """Experts sharded over ``ep``; router replicated."""
-    return {
+def moe_param_specs(shared: bool = False) -> Dict:
+    """Experts sharded over ``ep``; router replicated; the shared
+    expert's width over ``tp``, replicated over ``ep``."""
+    specs = {
         "wr": P(None, None),
         "w1": P(None, "ep", None, None),
         "w3": P(None, "ep", None, None),
         "w2": P(None, "ep", None, None),
     }
+    if shared:
+        specs.update({"ws1": P(None, None, "tp"), "ws3": P(None, None, "tp"),
+                      "ws2": P(None, "tp", None)})
+    return specs
 
 
-def alike_choices(n_experts: int, n_held: int, top_k: int) -> int:
+def alike_choices(n_experts: int, n_held: int, top_k: int,
+                  tail: float = _ALIKE_TAIL) -> int:
     """The least ``m`` such that a token's ``top_k`` distinct experts,
     wherever they lie among the ``n_experts``, include more than ``m``
-    of the ``n_held`` held ones in at most one case in a hundred
-    (hypergeometric): 3 for 8 of 128 with 16 held."""
+    of the ``n_held`` held ones in at most the share ``tail`` of cases
+    (hypergeometric; one in a hundred by default): 3 for 8 of 128 with
+    16 held; 2 for 8 of 256 with 16 held, 3 at one in a thousand."""
     total = math.comb(n_experts, top_k)
-    tail = 1.0
+    beyond = 1.0
     for m in range(min(top_k, n_held) + 1):
-        tail -= (math.comb(n_held, m)
-                 * math.comb(n_experts - n_held, top_k - m)) / total
-        if tail <= _ALIKE_TAIL:
+        beyond -= (math.comb(n_held, m)
+                   * math.comb(n_experts - n_held, top_k - m)) / total
+        if beyond <= tail:
             return m
     return min(top_k, n_held)
 
 
 def chunk_rows(n_tokens: int, n_experts: int, n_held: int,
-               top_k: int) -> Tuple[int, int]:
+               top_k: int, tail: float = _ALIKE_TAIL) -> Tuple[int, int]:
     """Rows of the first dispatch chunk, which is always computed --
     ``alike_choices`` choices of every token (at least one, and no more
     than all the choices) -- and of each further one: one choice of
     every token."""
     def aligned(rows):
         return -(-rows // _CHUNK_ALIGN) * _CHUNK_ALIGN
-    first = n_tokens * max(1, alike_choices(n_experts, n_held, top_k))
+    first = n_tokens * max(1, alike_choices(n_experts, n_held, top_k, tail))
     return aligned(min(first, n_tokens * top_k)), aligned(n_tokens)
 
 
@@ -215,8 +246,10 @@ _held_experts.defvjp(_held_experts_fwd, _held_experts_bwd)
 
 
 def moe_ffn(x: jax.Array, lp: Dict, top_k: int, norm_topk: bool = True,
-            held: Tuple = None):
-    """x [..., D] -> (y [..., D], stats); the residual is NOT included.
+            held: Tuple = None, scoring: str = "softmax",
+            route_scale: float = 1.0, alike_tail: float = _ALIKE_TAIL):
+    """x [..., D] -> (y [..., D], stats); the residual is NOT included
+    (nor the shared expert: ``shared_expert``).
     ``lp`` holds this layer's ``wr`` [D, E] and ``w1``/``w3``/``w2`` of
     the ``count`` experts ``held = (first, count)`` (all by default;
     ``first`` may be traced).  ``stats``: ``held_choices``,
@@ -226,7 +259,12 @@ def moe_ffn(x: jax.Array, lp: Dict, top_k: int, norm_topk: bool = True,
     token's experts, int32), and what ``balance_loss`` reads:
     ``router_load`` [E] (choices of every expert, held or not),
     ``router_prob`` [E] (float32 sum of the tokens' probabilities, the
-    differentiable part) and ``tokens``."""
+    differentiable part) and ``tokens``.  ``scoring="sigmoid"``: the
+    scores are sigmoids, the choice is by score plus ``lp["bias"]`` [E]
+    (where the layer has one), the gates are the chosen scores
+    (renormalised if ``norm_topk``) times ``route_scale``.
+    ``alike_tail``: the share of blocks of alike tokens the first chunk
+    may fall short of (``chunk_rows``)."""
     lead, D = x.shape[:-1], x.shape[-1]
     xt = x.reshape(-1, D)
     T = xt.shape[0]
@@ -243,10 +281,22 @@ def moe_ffn(x: jax.Array, lp: Dict, top_k: int, norm_topk: bool = True,
         logits = jnp.einsum("td,de->te", xt.astype(jnp.float32),
                             lp["wr"].astype(jnp.float32),
                             precision=jax.lax.Precision.HIGHEST)
-        probs = jax.nn.softmax(logits, axis=-1)
-        gate, expert = jax.lax.top_k(probs, top_k)             # [T, k]
-        if norm_topk:
-            gate = gate / jnp.sum(gate, axis=-1, keepdims=True)
+        if scoring == "softmax":
+            probs = jax.nn.softmax(logits, axis=-1)
+            gate, expert = jax.lax.top_k(probs, top_k)         # [T, k]
+            if norm_topk:
+                gate = gate / jnp.sum(gate, axis=-1, keepdims=True)
+        elif scoring == "sigmoid":
+            probs = jax.nn.sigmoid(logits)
+            select = probs if "bias" not in lp else \
+                probs + jax.lax.stop_gradient(lp["bias"])
+            _, expert = jax.lax.top_k(select, top_k)
+            gate = jnp.take_along_axis(probs, expert, axis=-1)
+            if norm_topk:
+                gate = gate / (jnp.sum(gate, axis=-1, keepdims=True) + 1e-20)
+            gate = gate * route_scale
+        else:
+            raise ValueError(f"scoring {scoring!r}")
         router_load = jnp.sum(jax.nn.one_hot(
             expert.reshape(N), n_experts, dtype=jnp.int32), axis=0)
 
@@ -259,7 +309,8 @@ def moe_ffn(x: jax.Array, lp: Dict, top_k: int, norm_topk: bool = True,
         order = jnp.argsort(key, stable=True).astype(jnp.int32)
         sizes = jnp.sum(jax.nn.one_hot(key, count, dtype=jnp.int32), axis=0)
         ends = jnp.cumsum(sizes)                               # [count]
-        first, rest = chunks = chunk_rows(T, n_experts, count, top_k)
+        first, rest = chunks = chunk_rows(T, n_experts, count, top_k,
+                                          alike_tail)
         whole = first + -(-max(0, N - first) // rest) * rest
         order = jnp.pad(order, (0, whole - N))
         gate_flat = gate.reshape(N)
@@ -289,13 +340,42 @@ def balance_loss(stats: Dict) -> jax.Array:
     return load.shape[0] * jnp.sum(f * p)
 
 
-def counters(stats: Dict) -> Dict:
+def shared_expert(x: jax.Array, lp: Dict) -> jax.Array:
+    """The SwiGLU every token passes, on x [B, S, D]."""
+    with jax.named_scope("moe_shared"):
+        gate = jax.nn.silu(jnp.einsum("bsd,df->bsf", x, lp["ws1"]))
+        up = jnp.einsum("bsd,df->bsf", x, lp["ws3"])
+        return jnp.einsum("bsf,fd->bsd", gate * up, lp["ws2"])
+
+
+def update_bias(bias: jax.Array, load: jax.Array, rate: float) -> jax.Array:
+    """The correction bias after a step: ``bias`` [layers, E] float32,
+    ``load`` [layers, E] the step's choices of every expert (over all
+    tokens: on a mesh, summed over the data axes).  An expert under the
+    layer's mean load gains ``rate``, one over it loses ``rate``: signs
+    of whole numbers (``sum - E * load``), so no rounding decides."""
+    load = load.astype(jnp.int32)
+    under = jnp.sum(load, axis=-1, keepdims=True) - load.shape[-1] * load
+    return bias + rate * jnp.sign(under).astype(bias.dtype)
+
+
+def counters(stats: Dict, with_load: bool = False) -> Dict:
     """A layer's ``stats`` as the float32 scalars a step reports:
     ``moe_held_choices``, ``moe_expert_load_max`` (the largest held
     expert's share of them), ``moe_dropped_choices`` and
-    ``moe_balance_loss``."""
+    ``moe_balance_loss``.  ``with_load`` (a layer with a correction
+    bias): also ``moe_load_cv``, the coefficient of variation of all
+    experts' loads, and the loads themselves, ``moe_router_load`` [E],
+    for ``update_bias``."""
     held = stats["held_choices"].astype(jnp.float32)
+    more = {}
+    if with_load:
+        load = stats["router_load"].astype(jnp.float32)
+        more = {"moe_load_cv": jnp.std(load) / jnp.maximum(
+                    jnp.mean(load), 1e-20),
+                "moe_router_load": stats["router_load"]}
     return {
+        **more,
         "moe_held_choices": held,
         "moe_expert_load_max": jnp.max(stats["expert_load"]).astype(
             jnp.float32) / jnp.maximum(held, 1.0),
@@ -305,18 +385,24 @@ def counters(stats: Dict) -> Dict:
 
 
 def moe_ffn_sharded(x: jax.Array, lp: Dict, top_k: int, norm_topk: bool,
-                    mesh):
+                    mesh, **router):
     """The layer under an ``ep`` mesh axis: every shard holds
     ``E / ep`` experts, runs ``moe_ffn`` on its range for its own
     tokens (``dp`` x ``sp``), and the partial results are summed over
-    ``ep``.  x [B, S, D]."""
+    ``ep``.  x [B, S, D].  The shared expert is not in here: it would
+    be summed once a shard."""
     count = lp["w1"].shape[0] // mesh.shape["ep"]
     tokens = ("dp", "sp")
+    # the router's correction bias, where the layer has one: replicated
+    bias = (lp["bias"],) if "bias" in lp else ()
 
-    def shard(x, wr, w1, w3, w2):
+    def shard(x, wr, w1, w3, w2, *bias):
         first = jax.lax.axis_index("ep") * count
-        y, stats = moe_ffn(x, {"wr": wr, "w1": w1, "w3": w3, "w2": w2},
-                           top_k, norm_topk, held=(first, count))
+        layer = {"wr": wr, "w1": w1, "w3": w3, "w2": w2}
+        if bias:
+            layer["bias"] = bias[0]
+        y, stats = moe_ffn(x, layer, top_k, norm_topk, held=(first, count),
+                           **router)
         everywhere = tokens + ("ep",)
         over = {"held_choices": everywhere, "dropped_choices": everywhere,
                 # an expert's load is summed over the token shards; the
@@ -331,9 +417,10 @@ def moe_ffn_sharded(x: jax.Array, lp: Dict, top_k: int, norm_topk: bool,
     placed = P("dp", "sp", None)
     return jax.shard_map(
         shard, mesh=mesh,
-        in_specs=(placed, P(None, None), experts, experts, experts),
+        in_specs=(placed, P(None, None), experts, experts, experts)
+        + (P(None),) * len(bias),
         out_specs=(placed,
                    {"held_choices": P(), "dropped_choices": P(),
                     "expert_load": P("ep"), "router_load": P(),
                     "router_prob": P(), "tokens": P(), "choices": placed}),
-        check_vma=False)(x, lp["wr"], lp["w1"], lp["w3"], lp["w2"])
+        check_vma=False)(x, lp["wr"], lp["w1"], lp["w3"], lp["w2"], *bias)

@@ -15,6 +15,15 @@ graft entry exercise):
     each layer's input and the flash kernel's ``out`` and ``lse``
     (``remat_layer``) and recomputes the rest in the backward pass.
   * bf16 activations/params with f32 RMSNorm + softmax + Adam moments.
+  * A model is a LAYER PATTERN (``TransformerConfig.layer_pattern``):
+    runs of layers of one kind, each run one scanned stack with its own
+    ``remat_layer``.  A kind is an attention module (``"mha"``: rotary
+    multi-head / grouped attention, below; ``"mla"``: latent attention,
+    ``models/mla.py``) and an FFN module (``"dense"``: SwiGLU, below;
+    ``"moe"``: the expert layer, ``models/moe.py``), each with its own
+    parameters and its own code: a model pays for the kinds it names.
+    With one run ``params["layers"]`` is that stack's tree (as it always
+    was); with several it is a tuple of them, in order.
 """
 
 from __future__ import annotations
@@ -33,6 +42,11 @@ from ray_tpu.ops.flash_attention import (
     attention as flash_or_ref_attention)
 from ray_tpu.ops.ring_attention import ring_attention
 from ray_tpu.util import tracing
+
+
+#: The kinds a run of the layer pattern is made of.
+_ATTENTION = ("mha", "mla")
+_FFN = ("dense", "moe")
 
 
 @dataclasses.dataclass(frozen=True)
@@ -77,6 +91,38 @@ class TransformerConfig:
     #: shared with other chips (None: all).  The router stays
     #: ``moe_experts`` wide; what absent experts would add is left out.
     moe_experts_held: Optional[Tuple[int, int]] = None
+    #: The experts' width; 0: ``d_ff`` (a mixed stack's dense layers
+    #: and experts differ).
+    moe_d_ff: int = 0
+    #: ``"softmax"``, or ``"sigmoid"``: sigmoid scores, the gates the
+    #: chosen scores (renormalised if ``moe_norm_topk``) times
+    #: ``moe_route_scale``.
+    moe_scoring: str = "softmax"
+    moe_route_scale: float = 1.0
+    #: The share of blocks of tokens that route alike which the expert
+    #: layer's first, always computed chunk may fall short of
+    #: (``moe.chunk_rows``): the smaller, the rarer a step that runs
+    #: further chunks, and the more rows every step computes.
+    moe_alike_tail: float = 0.01
+    #: >0: every expert layer adds a shared SwiGLU of this width.
+    moe_shared_width: int = 0
+    #: >0: the sigmoid router chooses by score plus a correction bias
+    #: that the step moves by this much towards the experts it loaded
+    #: least (``moe.update_bias``).  The bias is state beside the
+    #: parameters, ``state["moe_bias"]`` [expert layers, moe_experts]
+    #: float32: no gradient, no weight decay, no Adam moments.
+    moe_bias_rate: float = 0.0
+    #: Latent attention's sizes (``models.mla.MLAConfig``) for the
+    #: ``"mla"`` kind.
+    mla: Any = None
+    #: Runs of layers of one kind, ``((attention, ffn, count), ...)``
+    #: with attention ``"mha"`` | ``"mla"`` and ffn ``"dense"`` |
+    #: ``"moe"``; ``n_layers`` is then their sum.  None: ``n_layers``
+    #: of the one kind the other fields describe.
+    layer_pattern: Optional[Tuple[Tuple[str, str, int], ...]] = None
+    #: Multi-token-prediction modules after the stack (0 or 1):
+    #: ``models/mtp.py``, whose loss hooks in by ``loss_override``.
+    mtp_depth: int = 0
 
     def __post_init__(self):
         if not self.n_kv_heads:
@@ -87,15 +133,50 @@ class TransformerConfig:
         if self.n_heads % self.n_kv_heads:
             raise ValueError(f"{self.n_heads} query heads over "
                              f"{self.n_kv_heads} K/V heads")
+        if self.layer_pattern is None:
+            object.__setattr__(self, "layer_pattern", ((
+                "mha" if self.mla is None else "mla",
+                "moe" if self.moe_experts > 0 else "dense",
+                self.n_layers),))
+        else:
+            pattern = tuple(tuple(run) for run in self.layer_pattern)
+            for attention, ffn, count in pattern:
+                if attention not in _ATTENTION or ffn not in _FFN \
+                        or count < 1:
+                    raise ValueError(f"layer pattern run {attention!r}, "
+                                     f"{ffn!r}, {count}")
+            object.__setattr__(self, "layer_pattern", pattern)
+            object.__setattr__(self, "n_layers",
+                               sum(count for _, _, count in pattern))
+        kinds = {kind for run in self.layer_pattern for kind in run[:2]}
+        if "mla" in kinds and self.mla is None:
+            raise ValueError("an \"mla\" layer needs the mla sizes")
+        if "moe" in kinds and self.moe_experts < 1:
+            raise ValueError("a \"moe\" layer needs moe_experts")
+        if self.mtp_depth not in (0, 1):
+            raise ValueError("one multi-token-prediction module at most")
+
+    @property
+    def moe_layers(self) -> int:
+        """Expert layers, the multi-token-prediction module's included:
+        the rows of the correction bias."""
+        return sum(count for _, ffn, count in self.layer_pattern
+                   if ffn == "moe") + self.mtp_depth
 
 
-def init_params(rng: jax.Array, cfg: TransformerConfig) -> Dict:
-    k_embed, k_layers, k_head = jax.random.split(rng, 3)
-    d, h, dh, f, nl = (cfg.d_model, cfg.n_heads, cfg.head_dim, cfg.d_ff,
-                       cfg.n_layers)
+def stacks_of(layers) -> Tuple[Dict, ...]:
+    """``params["layers"]`` as a tuple of stacks, one a run of the
+    layer pattern."""
+    return (layers,) if isinstance(layers, dict) else tuple(layers)
+
+
+def init_stack(key: jax.Array, cfg: TransformerConfig, attention: str,
+               ffn: str, nl: int) -> Dict:
+    """The stacked parameters of ``nl`` layers of one kind."""
+    d, h, dh, f = cfg.d_model, cfg.n_heads, cfg.head_dim, cfg.d_ff
     kv = cfg.n_kv_heads
     init = jax.nn.initializers.normal(0.02)
-    lkeys = jax.random.split(k_layers, 6)
+    lkeys = jax.random.split(key, 6)
 
     def stacked(key, shape):
         return init(key, (nl,) + shape, jnp.float32).astype(cfg.dtype)
@@ -103,27 +184,47 @@ def init_params(rng: jax.Array, cfg: TransformerConfig) -> Dict:
     layers: Dict = {
         "ln1": jnp.ones((nl, d), jnp.float32),
         "ln2": jnp.ones((nl, d), jnp.float32),
-        "wq": stacked(lkeys[0], (d, h, dh)),
-        "wk": stacked(lkeys[1], (d, kv, dh)),
-        "wv": stacked(lkeys[2], (d, kv, dh)),
-        "wo": stacked(lkeys[3], (h, dh, d)),
     }
-    if cfg.qk_norm:
-        layers["q_norm"] = jnp.ones((nl, dh), jnp.float32)
-        layers["k_norm"] = jnp.ones((nl, dh), jnp.float32)
-    if cfg.moe_experts > 0:
+    if attention == "mla":
+        from ray_tpu.models.mla import init_mla_params
+        layers["mla"] = init_mla_params(jax.random.fold_in(key, 9), nl, d,
+                                        h, cfg.mla, cfg.dtype)
+    else:
+        layers.update({
+            "wq": stacked(lkeys[0], (d, h, dh)),
+            "wk": stacked(lkeys[1], (d, kv, dh)),
+            "wv": stacked(lkeys[2], (d, kv, dh)),
+            "wo": stacked(lkeys[3], (h, dh, d)),
+        })
+        if cfg.qk_norm:
+            layers["q_norm"] = jnp.ones((nl, dh), jnp.float32)
+            layers["k_norm"] = jnp.ones((nl, dh), jnp.float32)
+    if ffn == "moe":
         from ray_tpu.models.moe import init_moe_params
         held = cfg.moe_experts_held or (0, cfg.moe_experts)
         layers["moe"] = init_moe_params(
-            jax.random.fold_in(k_layers, 8), nl, d, f,
-            cfg.moe_experts, held[1], cfg.dtype)
+            jax.random.fold_in(key, 8), nl, d, cfg.moe_d_ff or f,
+            cfg.moe_experts, held[1], cfg.dtype, cfg.moe_shared_width)
     else:
         layers.update({
             "w1": stacked(lkeys[4], (d, f)),
             "w3": stacked(lkeys[5], (d, f)),
-            "w2": stacked(jax.random.fold_in(k_layers, 7), (f, d)),
+            "w2": stacked(jax.random.fold_in(key, 7), (f, d)),
         })
-    return {
+    return layers
+
+
+def init_params(rng: jax.Array, cfg: TransformerConfig) -> Dict:
+    k_embed, k_layers, k_head = jax.random.split(rng, 3)
+    d = cfg.d_model
+    init = jax.nn.initializers.normal(0.02)
+    pattern = cfg.layer_pattern
+    if len(pattern) == 1:
+        layers = init_stack(k_layers, cfg, *pattern[0])
+    else:
+        layers = tuple(init_stack(jax.random.fold_in(k_layers, 16 + i), cfg,
+                                  *run) for i, run in enumerate(pattern))
+    params = {
         "embed": init(k_embed, (cfg.vocab_size, d), jnp.float32
                       ).astype(cfg.dtype),
         "layers": layers,
@@ -131,37 +232,58 @@ def init_params(rng: jax.Array, cfg: TransformerConfig) -> Dict:
         "lm_head": init(k_head, (d, cfg.vocab_size), jnp.float32
                         ).astype(cfg.dtype),
     }
+    if cfg.mtp_depth:
+        from ray_tpu.models.mtp import init_mtp_params
+        params["mtp"] = init_mtp_params(jax.random.fold_in(rng, 3), cfg)
+    return params
 
 
-def param_specs(cfg: TransformerConfig) -> Dict:
-    """PartitionSpecs: Megatron TP on heads/FFN-hidden, vocab on
-    lm_head; MoE expert weights shard over "ep"."""
+def stack_specs(cfg: TransformerConfig, attention: str, ffn: str) -> Dict:
+    """PartitionSpecs of one kind's stack: Megatron TP on heads and
+    FFN-hidden; MoE expert weights shard over "ep"."""
     layers: Dict = {
         "ln1": P(None, None),
         "ln2": P(None, None),
-        "wq": P(None, None, "tp", None),
-        "wk": P(None, None, "tp", None),
-        "wv": P(None, None, "tp", None),
-        "wo": P(None, "tp", None, None),
     }
-    if cfg.qk_norm:
-        layers["q_norm"] = P(None, None)
-        layers["k_norm"] = P(None, None)
-    if cfg.moe_experts > 0:
+    if attention == "mla":
+        from ray_tpu.models.mla import mla_param_specs
+        layers["mla"] = mla_param_specs()
+    else:
+        layers.update({
+            "wq": P(None, None, "tp", None),
+            "wk": P(None, None, "tp", None),
+            "wv": P(None, None, "tp", None),
+            "wo": P(None, "tp", None, None),
+        })
+        if cfg.qk_norm:
+            layers["q_norm"] = P(None, None)
+            layers["k_norm"] = P(None, None)
+    if ffn == "moe":
         from ray_tpu.models.moe import moe_param_specs
-        layers["moe"] = moe_param_specs()
+        layers["moe"] = moe_param_specs(shared=cfg.moe_shared_width > 0)
     else:
         layers.update({
             "w1": P(None, None, "tp"),
             "w3": P(None, None, "tp"),
             "w2": P(None, "tp", None),
         })
-    return {
+    return layers
+
+
+def param_specs(cfg: TransformerConfig) -> Dict:
+    """PartitionSpecs following the layer pattern; vocab on lm_head."""
+    stacks = tuple(stack_specs(cfg, attention, ffn)
+                   for attention, ffn, _ in cfg.layer_pattern)
+    specs = {
         "embed": P(None, "tp"),
-        "layers": layers,
+        "layers": stacks[0] if len(stacks) == 1 else stacks,
         "ln_f": P(None),
         "lm_head": P(None, "tp"),
     }
+    if cfg.mtp_depth:
+        from ray_tpu.models.mtp import mtp_param_specs
+        specs["mtp"] = mtp_param_specs(cfg)
+    return specs
 
 
 def batch_spec() -> P:
@@ -206,52 +328,75 @@ def _attention_core(q, k, v, mesh, cfg: TransformerConfig, mask=CAUSAL):
 def _moe_block(h, lp, cfg: TransformerConfig, mesh):
     """The expert layer on [B, S, D] -> (y, what the layer counted)."""
     from ray_tpu.models import moe
+    router = dict(scoring=cfg.moe_scoring, route_scale=cfg.moe_route_scale,
+                  alike_tail=cfg.moe_alike_tail)
     if mesh is not None and mesh.shape.get("ep", 1) > 1:
         if cfg.moe_experts_held is not None:
             raise ValueError("moe_experts_held is one chip's share; an "
                              "ep mesh shares the experts itself")
         y, stats = moe.moe_ffn_sharded(h, lp, cfg.moe_top_k,
-                                       cfg.moe_norm_topk, mesh)
+                                       cfg.moe_norm_topk, mesh, **router)
     else:
         y, stats = moe.moe_ffn(h, lp, cfg.moe_top_k, cfg.moe_norm_topk,
-                               held=cfg.moe_experts_held)
-    counted = moe.counters(stats)
+                               held=cfg.moe_experts_held, **router)
+    if cfg.moe_shared_width:
+        # once, whoever holds which experts
+        y = y + moe.shared_expert(h, lp)
+    counted = moe.counters(stats, with_load="bias" in lp)
     if cfg.moe_report_choices:
         counted["moe_choices"] = stats["choices"]
     return y, counted
 
 
+def _mha(h, lp, positions, cfg: TransformerConfig, mesh, mask):
+    """Rotary multi-head / grouped attention on the layer's normed
+    input -> what it adds to the residual."""
+    eps = cfg.norm_eps
+    q = jnp.einsum("bsd,dhk->bshk", h, lp["wq"])
+    k = jnp.einsum("bsd,dhk->bshk", h, lp["wk"])
+    v = jnp.einsum("bsd,dhk->bshk", h, lp["wv"])
+    if cfg.qk_norm:
+        q = _rms_norm(q, lp["q_norm"], eps)
+        k = _rms_norm(k, lp["k_norm"], eps)
+    q = _rope(q, positions, cfg.rope_theta)
+    k = _rope(k, positions, cfg.rope_theta)
+    o = _attention_core(q, k, v, mesh, cfg, mask)
+    return jnp.einsum("bshk,hkd->bsd", o, lp["wo"])
+
+
+def _dense_ffn(h, lp):
+    gate = jax.nn.silu(jnp.einsum("bsd,df->bsf", h, lp["w1"]))
+    up = jnp.einsum("bsd,df->bsf", h, lp["w3"])
+    return jnp.einsum("bsf,fd->bsd", gate * up, lp["w2"])
+
+
 def apply_layer(x, lp, positions, cfg: TransformerConfig, mesh=None,
-                mask=CAUSAL):
+                mask=CAUSAL, kind: Optional[Tuple[str, str]] = None):
     """One transformer block on [B, S, D] activations with this
     layer's params ``lp``; returns (x, what the layer counted: nothing
-    for a dense one).  Shared by the scan forward, the block-diffusion
-    objective and the pipeline-parallel stage executor."""
+    for a dense one).  ``kind``: the layer's (attention, ffn) modules;
+    by default the pattern's first run's.  Shared by the scan forward,
+    the block-diffusion and multi-token objectives and the
+    pipeline-parallel stage executor."""
     # The named scopes here and in loss_fn / train_step are metadata
     # only: stable names for a device trace to group time by.
+    attention, ffn = kind or cfg.layer_pattern[0][:2]
     eps = cfg.norm_eps
     with jax.named_scope("attention"):
         h = _rms_norm(x, lp["ln1"], eps)
-        q = jnp.einsum("bsd,dhk->bshk", h, lp["wq"])
-        k = jnp.einsum("bsd,dhk->bshk", h, lp["wk"])
-        v = jnp.einsum("bsd,dhk->bshk", h, lp["wv"])
-        if cfg.qk_norm:
-            q = _rms_norm(q, lp["q_norm"], eps)
-            k = _rms_norm(k, lp["k_norm"], eps)
-        q = _rope(q, positions, cfg.rope_theta)
-        k = _rope(k, positions, cfg.rope_theta)
-        o = _attention_core(q, k, v, mesh, cfg, mask)
-        x = x + jnp.einsum("bshk,hkd->bsd", o, lp["wo"])
+        if attention == "mla":
+            from ray_tpu.models.mla import mla_attention
+            x = x + mla_attention(h, lp["mla"], positions, cfg, mesh, mask)
+        else:
+            x = x + _mha(h, lp, positions, cfg, mesh, mask)
     counted = {}
     with jax.named_scope("ffn"):
         h = _rms_norm(x, lp["ln2"], eps)
-        if cfg.moe_experts > 0:
+        if ffn == "moe":
             y, counted = _moe_block(h, lp["moe"], cfg, mesh)
             x = x + y
         else:
-            gate = jax.nn.silu(jnp.einsum("bsd,df->bsf", h, lp["w1"]))
-            up = jnp.einsum("bsd,df->bsf", h, lp["w3"])
-            x = x + jnp.einsum("bsf,fd->bsd", gate * up, lp["w2"])
+            x = x + _dense_ffn(h, lp)
     if mesh is not None:
         x = jax.lax.with_sharding_constraint(
             x, NamedSharding(mesh, P("dp", "sp", None)))
@@ -272,24 +417,66 @@ def remat_layer(layer, cfg: TransformerConfig):
             *FLASH_RESIDUAL_NAMES))
 
 
-def run_layers(params: Dict, tokens: jax.Array, positions: jax.Array,
-               cfg: TransformerConfig, mesh=None, mask=CAUSAL):
-    """Embedding and the scan over the stacked layers: tokens [B, S]
-    -> (x [B, S, D] before the final norm, what the layers counted:
-    scalars as means over the layers, anything else stacked by layer)."""
+def run_stack(x, stack: Dict, kind, positions, cfg: TransformerConfig,
+              mesh=None, mask=CAUSAL, moe_bias=None):
+    """The scan over one run's stacked layers -> (x, what each layer
+    counted, stacked by layer).  ``moe_bias`` [layers of the run, E]:
+    the expert layers' correction bias, a row a layer."""
+    def layer(x, scanned):
+        lp, bias = scanned
+        if bias is not None:
+            lp = dict(lp, moe=dict(lp["moe"], bias=bias))
+        return apply_layer(x, lp, positions, cfg, mesh, mask, kind)
+
+    return jax.lax.scan(remat_layer(layer, cfg), x, (stack, moe_bias))
+
+
+def run_stacks(x, layers, positions, cfg: TransformerConfig, mesh=None,
+               mask=CAUSAL, moe_bias=None):
+    """Every run of the layer pattern in turn -> (x, [what the layers of
+    each run counted, stacked by layer])."""
+    counted, row = [], 0
+    for stack, (attention, ffn, count) in zip(stacks_of(layers),
+                                              cfg.layer_pattern):
+        bias = None
+        if moe_bias is not None and ffn == "moe":
+            bias, row = moe_bias[row:row + count], row + count
+        x, c = run_stack(x, stack, (attention, ffn), positions, cfg, mesh,
+                         mask, bias)
+        counted.append(c)
+    return x, counted
+
+
+def reduce_counters(counted) -> Dict:
+    """The runs' per-layer counters as a step reports them: scalars as
+    means over the layers that count them, anything else stacked by
+    layer (the runs in order)."""
+    out = {}
+    for name in sorted({name for c in counted for name in c}):
+        runs = [c[name] for c in counted if name in c]
+        v = runs[0] if len(runs) == 1 else jnp.concatenate(runs)
+        out[name] = jnp.mean(v) if v.ndim == 1 else v
+    return out
+
+
+def embed_tokens(params: Dict, tokens: jax.Array, mesh=None):
     x = jnp.take(params["embed"], tokens, axis=0)     # [B, S, D]
     if mesh is not None:
         x = jax.lax.with_sharding_constraint(
             x, NamedSharding(mesh, P("dp", "sp", None)))
+    return x
 
-    def layer(x, lp):
-        return apply_layer(x, lp, positions, cfg, mesh, mask)
 
-    layer_fn = remat_layer(layer, cfg)
-    x, counted = jax.lax.scan(lambda x, lp: layer_fn(x, lp), x,
-                              params["layers"])
-    return x, {k: jnp.mean(v) if v.ndim == 1 else v
-               for k, v in counted.items()}
+def run_layers(params: Dict, tokens: jax.Array, positions: jax.Array,
+               cfg: TransformerConfig, mesh=None, mask=CAUSAL,
+               moe_bias=None):
+    """Embedding and the scans over the layer pattern's runs: tokens
+    [B, S] -> (x [B, S, D] before the final norm, what the layers
+    counted: ``reduce_counters``)."""
+    x, counted = run_stacks(embed_tokens(params, tokens, mesh),
+                            params["layers"], positions, cfg, mesh, mask,
+                            moe_bias)
+    return x, reduce_counters(counted)
 
 
 def with_balance_loss(loss, counters: Dict, cfg: TransformerConfig):
@@ -307,12 +494,13 @@ def forward(params: Dict, tokens: jax.Array, cfg: TransformerConfig,
 
 
 def forward_with_counters(params: Dict, tokens: jax.Array,
-                          cfg: TransformerConfig, mesh=None):
+                          cfg: TransformerConfig, mesh=None, moe_bias=None):
     """Like :func:`forward` but also returns the expert layers'
     counters (nothing for dense models)."""
     B, S = tokens.shape
     positions = jnp.broadcast_to(jnp.arange(S, dtype=jnp.int32)[None], (B, S))
-    x, counters = run_layers(params, tokens, positions, cfg, mesh)
+    x, counters = run_layers(params, tokens, positions, cfg, mesh,
+                             moe_bias=moe_bias)
     with jax.named_scope("head_loss"):
         x = _rms_norm(x, params["ln_f"], cfg.norm_eps)
         logits = jnp.einsum("bsd,dv->bsv", x, params["lm_head"])
@@ -320,13 +508,14 @@ def forward_with_counters(params: Dict, tokens: jax.Array,
 
 
 def loss_and_counters(params: Dict, batch: Dict, cfg: TransformerConfig,
-                      mesh=None):
+                      mesh=None, moe_bias=None):
     """Next-token cross entropy (plus the router's load-balance
     auxiliary) and the expert layers' counters.
     batch = {"tokens": [B, S+1] int32}."""
     tokens = batch["tokens"]
     inputs, targets = tokens[:, :-1], tokens[:, 1:]
-    logits, counters = forward_with_counters(params, inputs, cfg, mesh)
+    logits, counters = forward_with_counters(params, inputs, cfg, mesh,
+                                             moe_bias)
     with jax.named_scope("head_loss"):
         logits = logits.astype(jnp.float32)
         logz = jax.nn.logsumexp(logits, axis=-1)
@@ -354,6 +543,11 @@ def make_train_state(rng, cfg: TransformerConfig, mesh=None,
     opt_state = tx.init(params)
     state = {"params": params, "opt": opt_state,
              "step": jnp.zeros((), jnp.int32)}
+    if cfg.moe_bias_rate > 0:
+        # beside the parameters and outside ``tx``: the step moves it by
+        # rule (``moe.update_bias``)
+        state["moe_bias"] = jnp.zeros((cfg.moe_layers, cfg.moe_experts),
+                                      jnp.float32)
     if mesh is not None:
         specs = specs_override or param_specs(cfg)
         state_specs = {
@@ -365,6 +559,8 @@ def make_train_state(rng, cfg: TransformerConfig, mesh=None,
         }
         # Adam moments mirror the param tree's specs.
         state_specs["opt"] = _opt_specs(opt_state, specs)
+        if "moe_bias" in state:
+            state_specs["moe_bias"] = P()
         state = jax.device_put(
             state, jax.tree.map(
                 lambda s: NamedSharding(mesh, s), state_specs,
@@ -386,14 +582,20 @@ def make_train_step(cfg: TransformerConfig, tx, mesh=None,
                     loss_override=None):
     """``loss_override(params, batch)`` substitutes the next-token loss
     (the pipeline-parallel schedule; the block-diffusion objective,
-    ``models/block_diffusion.py``).  It returns the loss, or the loss
-    and a dict of counters that join the step's ``metrics``."""
+    ``models/block_diffusion.py``; the multi-token one,
+    ``models/mtp.py``).  It returns the loss, or the loss and a dict of
+    counters that join the step's ``metrics``.  Where the state holds
+    the routers' correction bias (``moe_bias_rate``), the loss is
+    called with it as a third argument, its counters carry every expert
+    layer's loads (``moe_router_load``), and the step moves the bias by
+    them."""
     def train_step(state, batch):
         compute = loss_override or (
-            lambda p, b: loss_and_counters(p, b, cfg, mesh))
+            lambda p, b, *bias: loss_and_counters(p, b, cfg, mesh, *bias))
+        bias = (state["moe_bias"],) if "moe_bias" in state else ()
 
         def objective(p):
-            out = compute(p, batch)
+            out = compute(p, batch, *bias)
             return out if isinstance(out, tuple) else (out, {})
 
         (loss, counters), grads = jax.value_and_grad(
@@ -406,6 +608,15 @@ def make_train_step(cfg: TransformerConfig, tx, mesh=None,
                 state["params"], updates)
         new_state = {"params": new_params, "opt": new_opt,
                      "step": state["step"] + 1}
+        if bias:
+            from ray_tpu.models.moe import update_bias
+            with jax.named_scope("moe_bias"):
+                counters = dict(counters)
+                new_state["moe_bias"] = update_bias(
+                    bias[0], counters.pop("moe_router_load"),
+                    cfg.moe_bias_rate)
+                counters["moe_bias_abs_max"] = jnp.max(
+                    jnp.abs(new_state["moe_bias"]))
         metrics = {"loss": loss, "grad_norm": optax_global_norm(grads),
                    **counters}
         return new_state, metrics

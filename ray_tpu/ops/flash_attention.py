@@ -42,7 +42,22 @@ K/V are never repeated in HBM; the backward's grid runs the group's
 query heads one after another over each K/V head and sums ``dk``/``dv``
 in a float32 VMEM scratch.
 
-The ``custom_vjp``'s residuals are (q, k, v, out, lse).  ``out`` and
+Latent attention (``models/mla.py``) scores over more columns than it
+sums: ``v`` may be narrower or wider than ``k``, and a second, rotary
+part of the score may come with its own operands, ``q_rope [.., H, R]``
+and ``k_rope [.., R]`` -- ONE rotary key a position, shared by all the
+heads of a row:
+
+    s = (q . k + q_rope . k_rope) * (D + R) ** -0.5
+
+Both kernels take the two extra operands as further refs of the same
+grid (no third and fourth kernel; without them what is traced is the
+kernel as it was): the shared key's block index is the row's, so it is
+fetched once a row and not once a head, and the backward returns
+``dq_rope`` per head and ``dk_rope`` summed over the row's heads in a
+float32 VMEM scratch, as grouped K/V sums ``dk``/``dv``.
+
+The ``custom_vjp``'s residuals are (q, k, v, the rotary pair, out, lse).  ``out`` and
 ``lse`` carry the names ``RESIDUAL_NAMES``: a layer rematerialised under
 ``models.transformer.remat_layer`` keeps those two (as much again as
 the layer's input where H * D is ``d_model``, and 4 bytes a row) and so
@@ -95,10 +110,17 @@ _BWD_BLOCKS = (512, 256, 128)
 # scratch and a few [block, block] float32 tiles: 13 MB at L = 4096,
 # D = 128 bf16, over the 16 MB a kernel gets unasked (the chip has 128).
 _BWD_VMEM_BYTES = 64 * 2 ** 20
+# With a rotary part the forward holds three operands whole per head
+# (double-buffered): k, v and the shared rotary key, whose 64 columns
+# take a whole 128-lane tile: 12 MB at L = 8192, D = 128 bf16, beside
+# the q and out blocks and a few [block, block] float32 tiles, over the
+# 16 MB a kernel gets unasked.  Asked for with the rotary part only: the
+# kernel without it is compiled as it was.
+_FWD_VMEM_BYTES = 64 * 2 ** 20
 
 
-def _flash_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, block_k: int,
-                  mask, scale: float):
+def _flash_kernel(q_ref, k_ref, v_ref, *refs, block_k: int, mask,
+                  scale: float):
     # Grid (query head, q block).  q_ref/o_ref: [block_q, D]; k_ref/v_ref:
     # [L, D], resident across a K/V head's query heads and q blocks;
     # lse_ref: [L // block_q, block_q], resident across a head's q
@@ -106,11 +128,16 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, block_k: int,
     # block_q], as the backward holds them: the row statistics are
     # [1, block_q], dense along lanes, they broadcast along sublanes, and
     # lse leaves as the row the backward reads.  acc is out transposed,
-    # [D, block_q].
-    block_q, d = q_ref.shape
+    # [Dv, block_q].  With a rotary part, ``refs`` starts with qr_ref
+    # [block_q, R] and kr_ref [L, R], resident across a row's heads.
+    *rope, o_ref, lse_ref = refs
+    block_q, d = q_ref.shape[0], v_ref.shape[1]
     q_blk = pl.program_id(1)
     f32 = jnp.float32
     q = q_ref[...]
+    if rope:
+        qr_ref, kr_ref = rope
+        qr = qr_ref[...]
 
     def body(masked, i, carry):
         acc, m_i, l_i = carry
@@ -119,7 +146,11 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, block_k: int,
         v = v_ref[keys, :]
         # Scaled after the product: the operands go to the MXU as they
         # arrived, and bfloat16 products are exact in float32.
-        s = jax.lax.dot_general(k, q, _NT, preferred_element_type=f32) * scale
+        s = jax.lax.dot_general(k, q, _NT, preferred_element_type=f32)
+        if rope:
+            s = s + jax.lax.dot_general(kr_ref[keys, :], qr, _NT,
+                                        preferred_element_type=f32)
+        s = s * scale
         if masked:
             q_pos = q_blk * block_q + jax.lax.broadcasted_iota(
                 jnp.int32, (block_k, block_q), 1)
@@ -148,10 +179,13 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, block_k: int,
     lse_ref[pl.ds(q_blk, 1), :] = m + jnp.log(l)
 
 
-def _flash_forward(qh, kh, vh, mask, block_q, block_k, interpret):
-    """q [BH, L, D], k/v [BH // group, L, D] -> (out [BH, L, D],
-    lse [BH, L] f32).  A block that is None is chosen from the span."""
+def _flash_forward(qh, kh, vh, rope, mask, block_q, block_k, interpret):
+    """q [BH, L, D], k [BH // group, L, D], v [BH // group, L, Dv],
+    ``rope`` None or (q_rope [BH, L, R], k_rope [B, L, R]) -> (out
+    [BH, L, Dv], lse [BH, L] f32).  A block that is None is chosen from
+    the span."""
     BH, L, D = qh.shape
+    Dv = vh.shape[-1]
     group = BH // kh.shape[0]
     span = mask.tile_span(L)
     chosen = next((b for b in _FWD_BLOCKS if span % b == 0), _FWD_BLOCKS[-1])
@@ -161,48 +195,70 @@ def _flash_forward(qh, kh, vh, mask, block_q, block_k, interpret):
             f"sequence length {span} must be a multiple of the block sizes "
             f"({block_q}, {block_k}); pad upstream")
     nq = L // block_q
-    kernel = functools.partial(_flash_kernel, block_k=block_k,
-                               mask=mask, scale=D ** -0.5)
+    in_specs = [
+        pl.BlockSpec((None, block_q, D), lambda b, i: (b, i, 0)),
+        # a group's query heads follow one another, so a K/V head
+        # is fetched once for all of them
+        pl.BlockSpec((None, L, D), lambda b, i: (b // group, 0, 0)),
+        pl.BlockSpec((None, L, Dv), lambda b, i: (b // group, 0, 0)),
+    ]
+    operands, rotary, limit = (qh, kh, vh), 0, None
+    if rope is not None:
+        rotary = rope[0].shape[-1]
+        heads = BH // rope[1].shape[0]           # query heads a row
+        in_specs += [
+            pl.BlockSpec((None, block_q, rotary), lambda b, i: (b, i, 0)),
+            # a row's heads follow one another: its one rotary key is
+            # fetched once for all of them
+            pl.BlockSpec((None, L, rotary), lambda b, i: (b // heads, 0, 0)),
+        ]
+        operands, limit = operands + tuple(rope), _FWD_VMEM_BYTES
+    kernel = functools.partial(_flash_kernel, block_k=block_k, mask=mask,
+                               scale=(D + rotary) ** -0.5)
     out, lse = pl.pallas_call(
         kernel,
         grid=(BH, nq),
-        in_specs=[
-            pl.BlockSpec((None, block_q, D), lambda b, i: (b, i, 0)),
-            # a group's query heads follow one another, so a K/V head
-            # is fetched once for all of them
-            pl.BlockSpec((None, L, D), lambda b, i: (b // group, 0, 0)),
-            pl.BlockSpec((None, L, D), lambda b, i: (b // group, 0, 0)),
-        ],
+        in_specs=in_specs,
         out_specs=[
-            pl.BlockSpec((None, block_q, D), lambda b, i: (b, i, 0)),
+            pl.BlockSpec((None, block_q, Dv), lambda b, i: (b, i, 0)),
             pl.BlockSpec((None, nq, block_q), lambda b, i: (b, 0, 0)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((BH, L, D), qh.dtype),
+            jax.ShapeDtypeStruct((BH, L, Dv), qh.dtype),
             jax.ShapeDtypeStruct((BH, nq, block_q), jnp.float32),
         ],
         # a head's lse block is written a row a q block: that axis runs
         # in order
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "arbitrary")),
+            dimension_semantics=("parallel", "arbitrary"),
+            vmem_limit_bytes=limit),
         interpret=interpret,
         name="flash_attention_fwd",
-    )(qh, kh, vh)
+    )(*operands)
     return out, lse.reshape(BH, L)
 
 
-def _flash_bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-                      dq_ref, dk_ref, dv_ref, dq_acc, *kv_acc, block_q: int,
-                      mask, scale: float):
+def _flash_bwd_kernel(q_ref, k_ref, v_ref, *refs, block_q: int, mask,
+                      scale: float, grouped: bool, heads: int):
     # Grid (K/V head, query head of its group, k block).
-    # k_ref/v_ref/dk_ref/dv_ref: [block_k, D]; q_ref/do_ref/dq_ref:
-    # [L, D], resident across a query head's k blocks; lse_ref/delta_ref:
-    # [L // block_q, block_q], one row a q block; dq_acc: [L, D] f32;
-    # kv_acc (grouped K/V only): dk and dv of the K/V head so far,
-    # [L, D] f32 each.  Tiles are held transposed, [block_k, block_q],
-    # so the row statistics broadcast along sublanes and dk/dv need no
-    # transpose.
-    block_k, d = k_ref.shape
+    # k_ref/dk_ref: [block_k, D], v_ref/dv_ref: [block_k, Dv];
+    # q_ref/dq_ref: [L, D], do_ref: [L, Dv], resident across a query
+    # head's k blocks; lse_ref/delta_ref: [L // block_q, block_q], one
+    # row a q block; dq_acc: [L, D] f32; kv_acc (grouped K/V only): dk
+    # and dv of the K/V head so far, [L, D] / [L, Dv] f32.  Tiles are
+    # held transposed, [block_k, block_q], so the row statistics
+    # broadcast along sublanes and dk/dv need no transpose.  With a
+    # rotary part (``heads`` query heads a row, else 0) ``refs`` starts
+    # with qr_ref [L, R] and kr_ref [block_k, R] and the outputs end
+    # with dqr_ref [L, R] and dkr_ref [L, R], the row's, resident across
+    # its heads; the scratch ends with dqr_acc and dkr_acc, [L, R] f32.
+    if heads:
+        qr_ref, kr_ref, *refs = refs
+    (do_ref, lse_ref, delta_ref, dq_ref, dk_ref, dv_ref, *refs) = refs
+    if heads:
+        dqr_ref, dkr_ref, *refs, dqr_acc, dkr_acc = refs
+    dq_acc, *kv_acc = refs
+    block_k = k_ref.shape[0]
     num_q = q_ref.shape[0] // block_q
     head, k_blk = pl.program_id(1), pl.program_id(2)
     f32 = jnp.float32
@@ -210,16 +266,25 @@ def _flash_bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
     @pl.when(k_blk == 0)
     def _():
         dq_acc[...] = jnp.zeros_like(dq_acc)
+        if heads:
+            dqr_acc[...] = jnp.zeros_like(dqr_acc)
 
     k = k_ref[...]
     v = v_ref[...]
+    if heads:
+        kr = kr_ref[...]
 
     def body(masked, i, carry):
-        dk, dv = carry
+        dk, dv, *dkr = carry
         rows = pl.ds(pl.multiple_of(i * block_q, block_q), block_q)
         q = q_ref[rows, :]
         do = do_ref[rows, :]
-        s = jax.lax.dot_general(k, q, _NT, preferred_element_type=f32) * scale
+        s = jax.lax.dot_general(k, q, _NT, preferred_element_type=f32)
+        if heads:
+            qr = qr_ref[rows, :]
+            s = s + jax.lax.dot_general(kr, qr, _NT,
+                                        preferred_element_type=f32)
+        s = s * scale
         if masked:
             q_pos = i * block_q + jax.lax.broadcasted_iota(
                 jnp.int32, (block_k, block_q), 1)
@@ -233,16 +298,23 @@ def _flash_bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         dk = dk + jnp.dot(ds, q, preferred_element_type=f32)
         dq_acc[rows, :] += jax.lax.dot_general(ds, k, _TN,
                                                preferred_element_type=f32)
-        return dk, dv
+        if not heads:
+            return dk, dv
+        dqr_acc[rows, :] += jax.lax.dot_general(ds, kr, _TN,
+                                                preferred_element_type=f32)
+        return dk, dv, dkr[0] + jnp.dot(ds, qr, preferred_element_type=f32)
 
-    zeros = jnp.zeros((block_k, d), f32)
-    carry = (zeros, zeros)
+    zeros = jnp.zeros(k_ref.shape, f32)
+    carry = (zeros, zeros if v_ref.shape == k_ref.shape
+             else jnp.zeros(v_ref.shape, f32))
+    if heads:
+        carry += (jnp.zeros(kr_ref.shape, f32),)
     # Only the Q tiles that see this K tile.
     for first, stop, masked in mask.q_ranges(k_blk, block_q, block_k, num_q):
         carry = jax.lax.fori_loop(first, stop,
                                   functools.partial(body, masked), carry)
-    dk, dv = carry
-    if kv_acc:
+    dk, dv, *dkr = carry
+    if grouped:
         # The K/V head's block comes round once a query head; every
         # visit writes the sum so far, the last one the whole of it.
         dk_acc, dv_acc = kv_acc
@@ -261,17 +333,36 @@ def _flash_bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         dk, dv = dk_acc[keys, :], dv_acc[keys, :]
     dk_ref[...] = dk.astype(dk_ref.dtype)
     dv_ref[...] = dv.astype(dv_ref.dtype)
+    if heads:
+        # The row's one rotary key comes round once a query head of the
+        # row, likewise.
+        keys = pl.ds(pl.multiple_of(k_blk * block_k, block_k), block_k)
+        in_row = (pl.program_id(0) * pl.num_programs(1) + head) % heads
+
+        @pl.when(in_row == 0)
+        def _():
+            dkr_acc[keys, :] = dkr[0]
+
+        @pl.when(in_row > 0)
+        def _():
+            dkr_acc[keys, :] += dkr[0]
+
+        dkr_ref[keys, :] = dkr_acc[keys, :].astype(dkr_ref.dtype)
 
     @pl.when(k_blk == pl.num_programs(2) - 1)
     def _():
         dq_ref[...] = dq_acc[...].astype(dq_ref.dtype)
+        if heads:
+            dqr_ref[...] = dqr_acc[...].astype(dqr_ref.dtype)
 
 
 @jax.named_scope("flash_attention_bwd")
-def _flash_backward(qh, kh, vh, out, lse, dout, mask, interpret):
-    """(dq [BH, L, D], dk, dv [BH // group, L, D]) from the forward's
-    residuals."""
+def _flash_backward(qh, kh, vh, rope, out, lse, dout, mask, interpret):
+    """(dq [BH, L, D], dk [BH // group, L, D], dv [BH // group, L, Dv],
+    and None or (dq_rope [BH, L, R], dk_rope [B, L, R])) from the
+    forward's residuals."""
     BH, L, D = qh.shape
+    Dv = vh.shape[-1]
     heads_kv = kh.shape[0]
     group = BH // heads_kv
     span = mask.tile_span(L)
@@ -283,46 +374,76 @@ def _flash_backward(qh, kh, vh, out, lse, dout, mask, interpret):
     nq = L // block
     f32 = jnp.float32
     delta = jnp.sum(dout.astype(f32) * out.astype(f32), axis=-1)  # [BH, L]
-    whole = pl.BlockSpec((None, L, D), lambda b, g, j: (b * group + g, 0, 0))
-    blocked = pl.BlockSpec((None, block, D), lambda b, g, j: (b, j, 0))
+
+    def whole(width):
+        return pl.BlockSpec((None, L, width),
+                            lambda b, g, j: (b * group + g, 0, 0))
+
+    def blocked(width):
+        return pl.BlockSpec((None, block, width), lambda b, g, j: (b, j, 0))
+
     stats = pl.BlockSpec((None, nq, block),
                          lambda b, g, j: (b * group + g, 0, 0))
-    kernel = functools.partial(_flash_bwd_kernel, block_q=block,
-                               mask=mask, scale=D ** -0.5)
+    in_specs = [whole(D), blocked(D), blocked(Dv)]
+    operands = (qh, kh, vh)
+    out_specs = [whole(D), blocked(D), blocked(Dv)]
+    results = [qh, kh, vh]
     scratch = [pltpu.VMEM((L, D), f32)]
     if group > 1:
-        scratch += [pltpu.VMEM((L, D), f32)] * 2
-    return tuple(pl.pallas_call(
+        scratch += [pltpu.VMEM((L, D), f32), pltpu.VMEM((L, Dv), f32)]
+    rotary = heads = 0
+    # dq accumulates over a query head's k blocks and dk/dv over a
+    # K/V head's query heads: those axes run in order.
+    order = ("parallel", "arbitrary", "arbitrary")
+    if rope is not None:
+        rotary = rope[0].shape[-1]
+        heads = BH // rope[1].shape[0]           # query heads a row
+        # a row's heads follow one another: its rotary key's gradient
+        # stays in VMEM while they add to it
+        row = pl.BlockSpec(
+            (None, L, rotary), lambda b, g, j: ((b * group + g) // heads,
+                                                0, 0))
+        in_specs += [whole(rotary), pl.BlockSpec(
+            (None, block, rotary),
+            lambda b, g, j: ((b * group + g) // heads, j, 0))]
+        operands += tuple(rope)
+        out_specs += [whole(rotary), row]
+        results += list(rope)
+        scratch += [pltpu.VMEM((L, rotary), f32)] * 2
+        # ... so the heads run in order too
+        order = ("arbitrary",) * 3
+    kernel = functools.partial(_flash_bwd_kernel, block_q=block, mask=mask,
+                               scale=(D + rotary) ** -0.5,
+                               grouped=group > 1, heads=heads)
+    grads = pl.pallas_call(
         kernel,
         grid=(heads_kv, group, nq),
-        in_specs=[whole, blocked, blocked, whole, stats, stats],
-        out_specs=[whole, blocked, blocked],
-        out_shape=[jax.ShapeDtypeStruct(x.shape, x.dtype)
-                   for x in (qh, kh, vh)],
+        in_specs=in_specs + [whole(Dv), stats, stats],
+        out_specs=out_specs,
+        out_shape=[jax.ShapeDtypeStruct(x.shape, x.dtype) for x in results],
         scratch_shapes=scratch,
-        # dq accumulates over a query head's k blocks and dk/dv over a
-        # K/V head's query heads: those axes run in order.
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "arbitrary", "arbitrary"),
+            dimension_semantics=order,
             vmem_limit_bytes=_BWD_VMEM_BYTES),
         interpret=interpret,
         name="flash_attention_bwd",
-    )(qh, kh, vh, dout, lse.reshape(BH, nq, block),
-      delta.reshape(BH, nq, block)))
+    )(*operands, dout, lse.reshape(BH, nq, block),
+      delta.reshape(BH, nq, block))
+    return (*grads[:3], None if rope is None else tuple(grads[3:]))
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
-def _flash(qh, kh, vh, mask, block_q, block_k, interpret):
-    return _flash_forward(qh, kh, vh, mask, block_q, block_k,
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7))
+def _flash(qh, kh, vh, rope, mask, block_q, block_k, interpret):
+    return _flash_forward(qh, kh, vh, rope, mask, block_q, block_k,
                           interpret)[0]
 
 
-def _flash_vjp_fwd(qh, kh, vh, mask, block_q, block_k, interpret):
-    out, lse = _flash_forward(qh, kh, vh, mask, block_q, block_k,
+def _flash_vjp_fwd(qh, kh, vh, rope, mask, block_q, block_k, interpret):
+    out, lse = _flash_forward(qh, kh, vh, rope, mask, block_q, block_k,
                               interpret)
     out = checkpoint_name(out, RESIDUAL_NAMES[0])
     lse = checkpoint_name(lse, RESIDUAL_NAMES[1])
-    return out, (qh, kh, vh, out, lse)
+    return out, (qh, kh, vh, rope, out, lse)
 
 
 def _flash_vjp_bwd(mask, block_q, block_k, interpret, res, dout):
@@ -337,33 +458,48 @@ _flash.defvjp(_flash_vjp_fwd, _flash_vjp_bwd)
                                              "interpret"))
 def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array,
                     mask=CAUSAL, block_q: int | None = None,
-                    block_k: int | None = None, interpret: bool = False
-                    ) -> jax.Array:
-    """q: [B, L, H, D]; k, v: [B, L, H // group, D] -> [B, L, H, D].
+                    block_k: int | None = None, interpret: bool = False,
+                    q_rope: jax.Array | None = None,
+                    k_rope: jax.Array | None = None) -> jax.Array:
+    """q: [B, L, H, D]; k: [B, L, H // group, D]; v: [B, L, H // group,
+    Dv] -> [B, L, H, Dv].  ``q_rope`` [B, L, H, R] with ``k_rope``
+    [B, L, R] (one rotary key a position, shared by the heads) add
+    ``q_rope . k_rope`` to the score, scaled by ``(D + R) ** -0.5``.
     ``mask`` is one of ``ops.attention_mask``'s descriptions.  The
     forward's blocks are chosen from L where they are not given; L must
     be a multiple of them (pad upstream).  ``interpret`` runs the kernel
     in the Pallas interpreter (CPU tests)."""
     B, L, H, D = q.shape
     heads_kv = k.shape[2]
-    if H % heads_kv or v.shape != k.shape:
-        raise ValueError(f"{H} query heads over K/V of shapes "
+    if H % heads_kv or v.shape[:3] != k.shape[:3] or k.shape[3] != D:
+        raise ValueError(f"{H} query heads of {D} over K/V of shapes "
                          f"{k.shape}, {v.shape}")
+    if (q_rope is None) != (k_rope is None):
+        raise ValueError("q_rope and k_rope come together")
     # Collapse batch x heads into the leading grid dimension: query
     # head b * H + h reads K/V head (b * H + h) // group.
     qh = q.transpose(0, 2, 1, 3).reshape(B * H, L, D)
     kh = k.transpose(0, 2, 1, 3).reshape(B * heads_kv, L, D)
-    vh = v.transpose(0, 2, 1, 3).reshape(B * heads_kv, L, D)
-    out = _flash(qh, kh, vh, mask, block_q, block_k, interpret)
-    return out.reshape(B, H, L, D).transpose(0, 2, 1, 3)
+    vh = v.transpose(0, 2, 1, 3).reshape(B * heads_kv, L, v.shape[3])
+    rope = None
+    if q_rope is not None:
+        if q_rope.shape[:3] != (B, L, H) or \
+                k_rope.shape != (B, L, q_rope.shape[3]):
+            raise ValueError(f"rotary parts of shapes {q_rope.shape}, "
+                             f"{k_rope.shape} beside q {q.shape}")
+        rope = (q_rope.transpose(0, 2, 1, 3).reshape(B * H, L, -1), k_rope)
+    out = _flash(qh, kh, vh, rope, mask, block_q, block_k, interpret)
+    return out.reshape(B, H, L, -1).transpose(0, 2, 1, 3)
 
 
-def attention(q: jax.Array, k: jax.Array, v: jax.Array,
-              mask=CAUSAL) -> jax.Array:
+def attention(q: jax.Array, k: jax.Array, v: jax.Array, mask=CAUSAL,
+              q_rope: jax.Array | None = None,
+              k_rope: jax.Array | None = None) -> jax.Array:
     """Backend dispatch: pallas kernel on TPU, jnp reference elsewhere."""
     from ray_tpu.ops.ring_attention import full_attention
     # Trace-time decision: the backend is fixed per process.
     if (jax.default_backend() == "tpu" and q.shape[1] % 128 == 0
             and q.shape[-1] >= 64):
-        return flash_attention(q, k, v, mask=mask)
-    return full_attention(q, k, v, mask=mask)
+        return flash_attention(q, k, v, mask=mask, q_rope=q_rope,
+                               k_rope=k_rope)
+    return full_attention(q, k, v, mask=mask, q_rope=q_rope, k_rope=k_rope)

@@ -101,11 +101,21 @@ def ring_attention(q: jax.Array, k: jax.Array, v: jax.Array,
 
 def full_attention(q: jax.Array, k: jax.Array, v: jax.Array,
                    mask=CAUSAL,
-                   scale: Optional[float] = None) -> jax.Array:
-    """Single-device reference attention: q [B, L, H, D], k/v
-    [B, L, H // group, D] (query head ``h`` reads K/V head
-    ``h // group``); ``mask`` is one of ``ops.attention_mask``'s
-    descriptions."""
+                   scale: Optional[float] = None,
+                   q_rope: Optional[jax.Array] = None,
+                   k_rope: Optional[jax.Array] = None) -> jax.Array:
+    """Single-device reference attention: q [B, L, H, D], k
+    [B, L, H // group, D], v [B, L, H // group, Dv] (query head ``h``
+    reads K/V head ``h // group``); ``mask`` is one of
+    ``ops.attention_mask``'s descriptions.  ``q_rope`` [B, L, H, R] and
+    ``k_rope`` [B, L, R] (one rotary key a position, shared by the
+    heads) are further columns of the score, as ``flash_attention``
+    takes them."""
+    if q_rope is not None:
+        q = jnp.concatenate([q, q_rope], axis=-1)
+        k = jnp.concatenate([k, jnp.broadcast_to(
+            k_rope[:, :, None, :], k.shape[:3] + k_rope.shape[-1:])],
+            axis=-1)
     if scale is None:
         scale = q.shape[-1] ** -0.5
     B, lq, H, D = q.shape
@@ -131,4 +141,4 @@ def full_attention(q: jax.Array, k: jax.Array, v: jax.Array,
     p = jax.nn.softmax(logits, axis=-1)
     out = jnp.einsum("bhgqk,bkhd->bqhgd", p.astype(v.dtype), v,
                      preferred_element_type=jnp.float32)
-    return out.reshape(B, lq, H, D).astype(q.dtype)
+    return out.reshape(B, lq, H, v.shape[-1]).astype(q.dtype)

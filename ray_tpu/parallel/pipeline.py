@@ -57,6 +57,8 @@ def make_pp_loss_fn(cfg: TransformerConfig, mesh, n_micro: int):
     # contractions would need in-body psums / ep constraints).
     assert mesh.shape.get("tp", 1) == 1, "pp does not compose with tp"
     assert mesh.shape.get("ep", 1) == 1, "pp does not compose with ep"
+    assert len(cfg.layer_pattern) == 1, \
+        "a stage scans one kind of layer: no mixed layer pattern"
     assert cfg.moe_experts == 0, \
         "MoE composes with ep, not pp (its counters are not plumbed here)"
     rot = [(i, (i + 1) % pp) for i in range(pp)]

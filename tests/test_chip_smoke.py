@@ -63,6 +63,23 @@ def test_leg_trainer(smoke):
     assert facts["flash_bwd_vs_grad_of_full_max_rel_err"] <= 1e-4
 
 
+def test_leg_latent_trainer(smoke):
+    facts = smoke.leg_latent_trainer(
+        platform="cpu", batch=2, seq=128, steps=2, dtype="float32",
+        flash_tol=1e-4,
+        model=dict(smoke.LATENT_MODEL, vocab_size=256, d_model=64, n_heads=2,
+                   d_ff=96, max_seq_len=128, moe_experts=8, moe_top_k=2,
+                   moe_experts_held=(2, 4), moe_d_ff=32, moe_shared_width=32,
+                   mla=dict(q_lora_rank=48, kv_lora_rank=32,
+                            qk_nope_head_dim=32, qk_rope_head_dim=16,
+                            v_head_dim=32)))
+    assert facts["losses"][-1] < facts["losses"][0]
+    assert facts["flash_fwd_calls_in_step"] == 0  # no Mosaic call to count
+    assert facts["counters"]["moe_dropped_choices"] == 0.0
+    assert facts["counters"]["mtp_loss"] > 0
+    assert facts["latent_flash_bwd_max_rel_err"] <= 1e-4
+
+
 def test_leg_sharded_solve(smoke):
     facts = smoke.leg_sharded_solve(platform="cpu", nodes=4096, classes=8,
                                     num_tasks=5000, tick_specs=256)

@@ -77,7 +77,7 @@ def test_lse_of_bfloat16_inputs_is_the_float32_log_sum_exp_of_them(mask):
     bfloat16 would move it by thousandths."""
     q, k, _, _ = _qkvd(jnp.bfloat16, B=1, L=512, H=2, D=128, kv_heads=1)
     qh, kh = (x.transpose(0, 2, 1, 3).reshape(-1, 512, 128) for x in (q, k))
-    _, lse = _flash_forward(qh, kh, kh, mask, None, None, True)
+    _, lse = _flash_forward(qh, kh, kh, None, mask, None, None, True)
     assert lse.shape == (2, 512) and lse.dtype == jnp.float32
     pos = jnp.arange(512)
     scores = jnp.einsum("hqd,kd->hqk", qh.astype(jnp.float32),
@@ -459,6 +459,150 @@ def test_grouped_kv_heads_under_the_older_masks(causal):
                         dict(B=1, L=640, H=4, kv_heads=1))
 
 
+# --- latent attention: unequal widths and one shared rotary key ---------
+
+def _latent_operands(dtype, B=2, L=256, H=4, kv_heads=None, D=32, R=16,
+                     Dv=48):
+    """q [.., H, D], k [.., KV, D], v [.., KV, Dv], q_rope [.., H, R],
+    k_rope [B, L, R] (one head a position), dout [.., H, Dv]."""
+    kv = kv_heads or H
+    shapes = [(B, L, H, D), (B, L, kv, D), (B, L, kv, Dv), (B, L, H, R),
+              (B, L, R), (B, L, H, Dv)]
+    keys = jax.random.split(jax.random.PRNGKey(2), len(shapes))
+    return [jax.random.normal(k, shape, jnp.float32).astype(dtype)
+            for k, shape in zip(keys, shapes)]
+
+
+def _written_out(q, k, v, q_rope, k_rope, mask):
+    """The masked softmax over both parts of the score, written out."""
+    group = q.shape[2] // k.shape[2]
+    pos = jnp.arange(q.shape[1])
+    f32 = jnp.float32
+    logits = (jnp.einsum("bqhd,bkhd->bhqk", q.astype(f32),
+                         jnp.repeat(k, group, 2).astype(f32))
+              + jnp.einsum("bqhr,bkr->bhqk", q_rope.astype(f32),
+                           k_rope.astype(f32))
+              ) * (q.shape[-1] + q_rope.shape[-1]) ** -0.5
+    logits = jnp.where(mask.allowed(pos[:, None], pos[None, :]), logits,
+                       -jnp.inf)
+    return jnp.einsum("bhqk,bkhd->bqhd", jax.nn.softmax(logits, -1),
+                      jnp.repeat(v, group, 2).astype(f32))
+
+
+# (a) Both kernels at unequal score and value widths with the shared
+# rotary key: L = 256 runs 128-tiles (two heads' rows in the backward's
+# scratch), L = 1024 the cells' 512-tiles; grouped K/V beside the
+# latent model's one K/V head a query head.
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["f32", "bf16"])
+@pytest.mark.parametrize("mask,shape", [
+    (CAUSAL, {}), (FULL, {}), (BlockDiffusion(128, 4), {}),
+    (CAUSAL, dict(kv_heads=2)), (BlockDiffusion(128, 4), dict(kv_heads=1)),
+    (CAUSAL, dict(B=1, L=1024, H=2, D=128, R=64, Dv=128)),
+    (BlockDiffusion(512, 4), dict(B=1, L=1024, H=2, D=128, R=64, Dv=128)),
+], ids=["causal", "full", "blockdiff", "causal-grouped", "blockdiff-grouped",
+        "causal-512-cell-widths", "blockdiff-512-cell-widths"])
+def test_unequal_widths_and_the_shared_rotary_key_match_jnp(mask, shape,
+                                                            dtype):
+    """The output and all five gradients, against the softmax written
+    out (float32) and against ``full_attention`` with the same operands
+    (what ``attention()`` falls back to)."""
+    q, k, v, q_rope, k_rope, dout = _latent_operands(dtype, **shape)
+
+    def kernel(q, k, v, q_rope, k_rope):
+        return flash_attention(q, k, v, mask=mask, interpret=True,
+                               q_rope=q_rope, k_rope=k_rope)
+
+    def fallback(q, k, v, q_rope, k_rope):
+        return full_attention(q, k, v, mask=mask, q_rope=q_rope,
+                              k_rope=k_rope)
+
+    got = kernel(q, k, v, q_rope, k_rope)
+    assert got.dtype == dtype and got.shape == dout.shape
+    want = fallback(q, k, v, q_rope, k_rope)
+    assert _max_err(got, want) <= _TOL[dtype]
+    if dtype == jnp.float32:
+        assert _max_err(got, _written_out(q, k, v, q_rope, k_rope, mask)) \
+            <= _TOL[dtype]
+
+    def scalar(fn):
+        return lambda *xs: jnp.sum(fn(*xs).astype(jnp.float32)
+                                   * dout.astype(jnp.float32))
+
+    grads = jax.grad(scalar(kernel), (0, 1, 2, 3, 4))(q, k, v, q_rope, k_rope)
+    wants = jax.grad(scalar(fallback), (0, 1, 2, 3, 4))(q, k, v, q_rope,
+                                                        k_rope)
+    # the rotary key's gradient is a sum over the row's heads
+    for name, g, w in zip(("q", "k", "v", "q_rope", "k_rope"), grads, wants):
+        assert g.dtype == w.dtype and g.shape == w.shape, name
+        assert _max_err(g, w) <= _TOL[dtype] * (
+            q.shape[2] if name == "k_rope" and dtype == jnp.bfloat16
+            else 1), name
+
+
+def test_without_a_rotary_part_the_kernels_are_traced_as_they_were():
+    """The rotary operands are refs of their own: a call without them
+    traces no product, no scratch and no output for them, in either
+    kernel, and the forward asks for no memory limit."""
+    q, k, v, q_rope, k_rope, _ = _latent_operands(jnp.float32, B=1, H=2,
+                                                  D=32, Dv=32)
+
+    def products(**rope):
+        jaxpr = jax.make_jaxpr(jax.grad(lambda q: jnp.sum(flash_attention(
+            q, k, v, interpret=True, **rope))))(q)
+        eqns = list(_equations(jaxpr.jaxpr))
+        calls = {eqn.params["name"]: eqn for eqn, kernel in eqns
+                 if eqn.primitive.name == "pallas_call" and not kernel}
+        dots = {name: sum(1 for eqn, kernel in eqns if kernel == name
+                          and eqn.primitive.name == "dot_general")
+                for name in calls}
+        return dots, {name: (len(eqn.invars), len(eqn.outvars))
+                      for name, eqn in calls.items()}
+
+    plain, plain_io = products()
+    latent, latent_io = products(q_rope=q_rope, k_rope=k_rope)
+    # two tile ranges under the causal mask: 2 products a forward tile
+    # (3 with the rotary part), 5 a backward tile (8)
+    assert plain == {"flash_attention_fwd": 4, "flash_attention_bwd": 10}
+    assert latent == {"flash_attention_fwd": 6, "flash_attention_bwd": 16}
+    assert plain_io == {"flash_attention_fwd": (3, 2),
+                        "flash_attention_bwd": (6, 3)}
+    assert latent_io == {"flash_attention_fwd": (5, 2),
+                         "flash_attention_bwd": (8, 5)}
+    with pytest.raises(ValueError, match="come together"):
+        flash_attention(q, k, v, interpret=True, q_rope=q_rope)
+
+
+def test_latent_attention_keeps_its_residuals_under_remat():
+    """``RESIDUAL_NAMES`` under ``remat_layer`` with the rotary operands:
+    the backward scan's body holds the backward kernel only."""
+    q, k, v, q_rope, k_rope, _ = _latent_operands(jnp.float32, B=1, H=2,
+                                                  D=32, Dv=32)
+    ws = jax.random.normal(jax.random.PRNGKey(1), (2, 32, 32)) * 0.1
+
+    def layer(x, w):
+        return x + flash_attention(
+            jnp.tanh(jnp.einsum("blhd,de->blhe", x, w)), k, v,
+            interpret=True, q_rope=q_rope, k_rope=k_rope), None
+
+    def gradient(wrap):
+        return jax.grad(lambda x, ws: jnp.sum(jax.lax.scan(
+            wrap(layer), x, ws)[0] ** 2), (0, 1))
+
+    calls, grads = {}, {}
+    for how, wrap in (("kept", _keeping_the_residuals),
+                      ("plain", jax.checkpoint)):
+        kernels = [eqn.params["name"] for eqn, kernel in _equations(
+            jax.make_jaxpr(gradient(wrap))(q, ws).jaxpr)
+            if eqn.primitive.name == "pallas_call" and not kernel]
+        calls[how] = (kernels.count("flash_attention_fwd"),
+                      kernels.count("flash_attention_bwd"))
+        grads[how] = gradient(wrap)(q, ws)
+    assert calls == {"kept": (1, 1), "plain": (2, 1)}
+    for kept, plain in zip(grads["kept"], grads["plain"]):
+        assert bool(jnp.array_equal(kept, plain))
+
+
 def _kernel_calls(text):
     """(forward, backward) Mosaic calls in a compiled program's text, by
     the kernels' names: the names a trace's events carry."""
@@ -507,6 +651,29 @@ def test_block_diffusion_gradient_compiles_for_the_chip_at_the_cell_width(
     compiled = jax.jit(jax.grad(lambda q, k, v: jnp.sum(flash_attention(
         q, k, v, mask=BlockDiffusion(4096, 4)).astype(jnp.float32)),
         (0, 1, 2))).lower(q, kv, kv).compile()
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") == 2
+    assert _kernel_calls(text) == (1, 1)
+    assert " while(" not in text
+
+
+def test_latent_attention_gradient_compiles_for_the_chip_at_the_cell_width(
+        one_v5e_chip):
+    """Both kernels at the latent-attention cell's shape: 2 rows x 8,192
+    positions, 32 heads scoring over 128 + 64 columns against one
+    rotary key a position, values over 128, bfloat16: K, V and the
+    rotary key whole in the forward pass the 16 MB a kernel gets
+    unasked (``_FWD_VMEM_BYTES``)."""
+    def spec(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.bfloat16,
+                                    sharding=one_v5e_chip)
+    wide = spec(2, 8192, 32, 128)
+    compiled = jax.jit(jax.grad(
+        lambda q, k, v, q_rope, k_rope: jnp.sum(flash_attention(
+            q, k, v, q_rope=q_rope, k_rope=k_rope).astype(jnp.float32)),
+        (0, 1, 2, 3, 4))).lower(
+            wide, wide, wide, spec(2, 8192, 32, 64),
+            spec(2, 8192, 64)).compile()
     text = compiled.as_text()
     assert text.count("tpu_custom_call") == 2
     assert _kernel_calls(text) == (1, 1)

@@ -201,6 +201,76 @@ def test_moe_expert_parallel_train_step():
             float(plain["moe_balance_loss"]), rel=1e-5)
 
 
+def test_mixed_stack_latent_attention_and_shared_expert_on_the_mesh():
+    """The layer pattern (a dense-FFN layer, expert layers, the
+    multi-token-prediction module) with latent attention's heads over
+    ``tp`` and the experts over ``ep``: loss, gradients, the step's
+    counters and the routers' correction bias equal the unsharded
+    step's.  The shared expert is outside the shards' ``psum``: counted
+    once, not once a shard (leaving it inside would double it here)."""
+    import functools
+
+    import numpy as np
+
+    from ray_tpu.models import mtp
+    from ray_tpu.models.mla import MLAConfig
+    from ray_tpu.models.transformer import (TransformerConfig,
+                                            make_train_state,
+                                            make_train_step)
+    from ray_tpu.parallel.mesh import MeshConfig, build_mesh
+    cfg = TransformerConfig(
+        vocab_size=64, d_model=32, n_heads=4, d_ff=48, dtype=jnp.float32,
+        remat=True, context_parallel=False, norm_eps=1e-6,
+        mla=MLAConfig(24, 16, 8, 4, 8),
+        layer_pattern=(("mla", "dense", 1), ("mla", "moe", 2)), mtp_depth=1,
+        moe_experts=8, moe_top_k=2, moe_d_ff=16, moe_scoring="sigmoid",
+        moe_route_scale=2.5, moe_shared_width=16, moe_bias_rate=1e-3,
+        moe_aux_coeff=0.0)
+    tokens = jax.random.randint(jax.random.PRNGKey(1), (4, 33), 0, 64,
+                                dtype=jnp.int32)
+    objective = functools.partial(mtp.loss_fn, cfg=cfg, coeff=0.3)
+    plain_state, plain_tx = make_train_state(jax.random.PRNGKey(0), cfg)
+    bias = plain_state["moe_bias"] + 0.01 * jnp.arange(8)
+    want, want_grad = jax.jit(jax.value_and_grad(
+        lambda p: objective(p, {"tokens": tokens}, bias)[0]))(
+            plain_state["params"])
+    after, plain = make_train_step(cfg, plain_tx, loss_override=objective)(
+        dict(plain_state, moe_bias=bias + 0.0), {"tokens": tokens})
+    mesh = build_mesh(MeshConfig(dp=2, tp=2, ep=2), devices=jax.devices()[:8])
+    with mesh:
+        state, tx = make_train_state(jax.random.PRNGKey(0), cfg, mesh=mesh)
+        shards = {name: state["params"]["layers"][1][group][name].sharding.spec
+                  for group, name in (("mla", "wq_b"), ("mla", "wq_a"),
+                                      ("moe", "ws1"), ("moe", "w1"))}
+        assert shards["wq_b"] == jax.sharding.PartitionSpec(
+            None, None, "tp", None)
+        assert "tp" not in shards["wq_a"] and "ep" in shards["w1"]
+        assert "tp" in shards["ws1"] and "ep" not in shards["ws1"]
+        sharded = functools.partial(objective, mesh=mesh)
+        got, got_grad = jax.jit(jax.value_and_grad(
+            lambda p: sharded(p, {"tokens": tokens}, bias)[0]))(
+                state["params"])
+        assert abs(float(got) - float(want)) < 1e-4, (got, want)
+        for (path, g), w in zip(
+                jax.tree_util.tree_flatten_with_path(got_grad)[0],
+                jax.tree.leaves(want_grad)):
+            g, w = np.asarray(g), np.asarray(w)
+            assert np.abs(g - w).max() <= 1e-4 * np.abs(w).max() + 1e-7, path
+        step = make_train_step(cfg, tx, mesh=mesh, loss_override=sharded)
+        state, metrics = step(dict(state, moe_bias=bias + 0.0),
+                              {"tokens": tokens})
+        for name in ("loss", "main_loss", "mtp_loss", "moe_held_choices",
+                     "moe_load_cv", "moe_bias_abs_max"):
+            assert float(metrics[name]) == pytest.approx(
+                float(plain[name]), rel=1e-4), name
+        # the loads are summed over the data axes: the same bias
+        assert np.array_equal(np.asarray(state["moe_bias"]),
+                              np.asarray(after["moe_bias"]))
+        # all 8 experts are held between the shards: every choice counts
+        assert float(metrics["moe_held_choices"]) == 4 * 32 * 2
+        assert float(metrics["moe_dropped_choices"]) == 0.0
+
+
 def test_pipeline_parallel_matches_single_device():
     """GPipe over pp=2 (x dp=2): the pipelined loss equals the plain
     sequential loss exactly, and a full pp train step (AD through
