@@ -16,15 +16,26 @@ Per layer, with T tokens, N = T * top_k token-choices:
     probs    = softmax(x @ wr)                      [T, E]  float32
     gate, e  = top_k(probs), renormalised           [T, k]
     order    = token-choices sorted by held expert; those routed to
-               absent experts last
+               absent experts last; the sort carries the gates along
     chunks   = the sorted list cut into chunks of a fixed number of
                rows; the loop ends with the last held choice (the
                backward walks the same chunks: ``_held_experts``)
     h        = silu(ragged_dot(xs, w1)) * ragged_dot(xs, w3)
-    y[tok]  += gate * ragged_dot(h, w2)             per chunk
+    out      = ragged_dot(h, w2)        [rows, D], the products' dtype
+    y[tok]  += gate * float32(out)      per chunk: a scatter-add
 Nothing is dropped whatever the imbalance: the chunks cover all N
 choices, so memory is bounded by the chunk and work follows the number
-of chunks the held choices fill.  A chunk is sized for tokens that
+of chunks the held choices fill.  The rows return to their tokens by
+one float32 scatter-add a chunk and direction, and that add is the only
+place where a chunk's rows are widened: the backward (written by hand,
+``_held_experts_bwd``) gathers the tokens' cotangent in the tokens'
+dtype, and makes the rows' cotangent and the gates' gradient in one
+pass over the rows.  The other way round -- every token gathering the
+rows of its ``top_k`` slots through the inverse of the sort and summing
+them -- is no faster on the chip: a gathered row of 2,048 bfloat16 costs
+35 ns and a scattered float32 one 83 ns, and the slots are ``top_k``
+a token where a first chunk holds ``m`` (PERF.md section 6, PR 34).  A
+chunk is sized for tokens that
 route alike (``chunk_rows``): ``m`` choices of every token, with ``m``
 the most of a token's ``top_k`` experts that fall among the ``count``
 held in all but one case in a hundred.  That is the share's worst
@@ -157,16 +168,21 @@ def _chunk_inputs(rows, top_k, n_tokens, order, ends, sizes, start):
     return idx, tok, valid, group
 
 
-def _chunk_experts(xs, gate, w1, w3, w2, group, valid):
-    """[rows, D] sorted token rows -> their experts' weighted output,
-    float32; rows past the last held choice give nought."""
+def _chunk_experts(xs, w1, w3, w2, group):
+    """[rows, D] sorted token rows -> their experts' output, unweighted,
+    in the products' dtype."""
     with jax.named_scope("moe_experts"):
         h = jax.nn.silu(jax.lax.ragged_dot(xs, w1, group)) * \
             jax.lax.ragged_dot(xs, w3, group)
-        out = jax.lax.ragged_dot(h, w2, group)
-    with jax.named_scope("moe_combine"):
-        out = jnp.where(valid[:, None], out.astype(jnp.float32), 0.0)
-        return out * gate[:, None]
+        return jax.lax.ragged_dot(h, w2, group)
+
+
+def _token_rows(a, tok):
+    """``a[tok]``, rows of [T, D] in ``a``'s dtype.  ``tok`` lies in
+    range by construction (``_chunk_inputs``), and the gather says so:
+    ``jnp.take``'s default would follow it with a pass over all the
+    rows that fills those out of range with NaN."""
+    return jnp.take(a, tok, axis=0, mode="clip")
 
 
 def _over_chunks(chunks, n_held, run, carry):
@@ -181,38 +197,69 @@ def _over_chunks(chunks, n_held, run, carry):
         lambda c, carry: run(rest, first + c * rest, carry), carry)
 
 
-# Differentiated by hand, so that the loop's length can follow the
-# number of held choices and no chunk's tokens or weights are kept.
-@functools.partial(jax.custom_vjp, nondiff_argnums=(0, 1))
-def _held_experts(chunks, top_k, xt, gate, w1, w3, w2, order, ends, sizes):
-    """-> (y [T, D] float32, rows processed).  ``chunks``: the rows of
-    the first chunk and of each further one; ``order``: the sorted
-    token-choices padded to whole chunks; ``ends``/``sizes``: the held
-    experts' groups in it."""
+def _sorted_choices(chunks, key, gate):
+    """The token-choices (``token * top_k + slot``) sorted by ``key``
+    [N] -- a held choice's local expert, ``count`` for the others, which
+    so come last -- and their gates in that order (the sort carries them
+    along: fetched a row at a time they cost a gather of scalars a
+    chunk, 0.7 ms at 98,304 rows), both padded to whole chunks."""
+    n = key.shape[0]
+    first, rest = chunks
+    _, order, gates = jax.lax.sort(
+        (key, jnp.arange(n, dtype=jnp.int32), gate), num_keys=1,
+        is_stable=True)
+    pad = (0, first + -(-max(0, n - first) // rest) * rest - n)
+    return jnp.pad(order, pad), jnp.pad(gates, pad)
+
+
+def _forward(chunks, top_k, xt, gate, w1, w3, w2, key, ends, sizes):
+    """``_held_experts`` and the sorted list it walked."""
+    with jax.named_scope("moe_dispatch"):
+        order, gates = _sorted_choices(chunks, key, gate)
+
     def run(rows, start, carry):
         y, done = carry
         with jax.named_scope("moe_dispatch"):
-            idx, tok, valid, group = _chunk_inputs(
+            _, tok, valid, group = _chunk_inputs(
                 rows, top_k, xt.shape[0], order, ends, sizes, start)
-            xs = jnp.take(xt, tok, axis=0)
-        out = _chunk_experts(xs, jnp.take(gate, idx), w1, w3, w2, group,
-                             valid)
+            xs = _token_rows(xt, tok)
+        out = _chunk_experts(xs, w1, w3, w2, group)
         with jax.named_scope("moe_combine"):
-            return y.at[tok].add(out), done + jnp.sum(valid.astype(jnp.int32))
+            # rows past the last held choice give nought (their output
+            # may be undefined)
+            out = jnp.where(valid[:, None], out.astype(jnp.float32), 0.0)
+            g = jax.lax.dynamic_slice(gates, (start,), (rows,))
+            return (y.at[tok].add(out * g[:, None]),
+                    done + jnp.sum(valid.astype(jnp.int32)))
 
     zero = (jnp.zeros(xt.shape, jnp.float32), jnp.zeros((), jnp.int32))
-    return _over_chunks(chunks, ends[-1], run, zero)
+    y, done = _over_chunks(chunks, ends[-1], run, zero)
+    return (y.astype(xt.dtype), done), (order, gates)
 
 
-def _held_experts_fwd(chunks, top_k, xt, gate, w1, w3, w2, order, ends,
-                      sizes):
-    return (_held_experts(chunks, top_k, xt, gate, w1, w3, w2, order, ends,
-                          sizes),
-            (xt, gate, w1, w3, w2, order, ends, sizes))
+# Differentiated by hand, so that the loop's length can follow the
+# number of held choices, no chunk's tokens or weights are kept, and a
+# chunk's rows are widened to float32 once in each direction, where they
+# are added to their tokens.
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0, 1))
+def _held_experts(chunks, top_k, xt, gate, w1, w3, w2, key, ends, sizes):
+    """-> (y [T, D], summed in float32 and returned in ``xt``'s dtype,
+    so that its cotangent arrives in it and is gathered in it; rows
+    processed).  ``chunks``: the rows of the first chunk and of each
+    further one; ``gate`` [N] float32 and ``key`` [N], every choice's
+    sort key (``_sorted_choices``); ``ends``/``sizes``: the held
+    experts' groups in the sorted list."""
+    return _forward(chunks, top_k, xt, gate, w1, w3, w2, key, ends, sizes)[0]
+
+
+def _held_experts_fwd(chunks, top_k, xt, gate, w1, w3, w2, key, ends, sizes):
+    out, (order, gates) = _forward(chunks, top_k, xt, gate, w1, w3, w2, key,
+                                   ends, sizes)
+    return out, (xt, w1, w3, w2, order, gates, ends, sizes)
 
 
 def _held_experts_bwd(chunks, top_k, res, cotangent):
-    xt, gate, w1, w3, w2, order, ends, sizes = res
+    xt, w1, w3, w2, order, gates, ends, sizes = res
     dy, _ = cotangent
     f32 = jnp.float32
 
@@ -221,28 +268,68 @@ def _held_experts_bwd(chunks, top_k, res, cotangent):
         with jax.named_scope("moe_dispatch"):
             idx, tok, valid, group = _chunk_inputs(
                 rows, top_k, xt.shape[0], order, ends, sizes, start)
-            xs = jnp.take(xt, tok, axis=0)
-        _, vjp = jax.vjp(
-            lambda xs, g, w1, w3, w2: _chunk_experts(xs, g, w1, w3, w2,
-                                                     group, valid),
-            xs, jnp.take(gate, idx), w1, w3, w2)
-        dxs, dg, *dw = vjp(jnp.take(dy, tok, axis=0))
+            xs = _token_rows(xt, tok)
+            dys = _token_rows(dy, tok).astype(f32)
+        out, vjp = jax.vjp(
+            lambda xs, w1, w3, w2: _chunk_experts(xs, w1, w3, w2, group),
+            xs, w1, w3, w2)
         with jax.named_scope("moe_combine"):
-            # rows past the last held choice carry no gradient (a padded
-            # row repeats choice 0)
+            # a row's cotangent is its token's times the gate, the
+            # gate's the row's output along its token's cotangent: one
+            # pass over the rows; those past the last held choice carry
+            # no gradient (a padded row repeats choice 0)
+            g = jax.lax.dynamic_slice(gates, (start,), (rows,))
+            dout = jnp.where(valid[:, None], dys * g[:, None], 0.0)
+            dg = jnp.where(valid, jnp.sum(out.astype(f32) * dys, axis=-1),
+                           0.0)
+        dxs, *dw = vjp(dout.astype(out.dtype))
+        with jax.named_scope("moe_combine"):
             dxs = jnp.where(valid[:, None], dxs.astype(f32), 0.0)
             return (dxt.at[tok].add(dxs), dgate.at[idx].add(dg),
                     [a + d.astype(f32) for a, d in zip(dws, dw)])
 
-    zero = (jnp.zeros(xt.shape, f32), jnp.zeros(gate.shape, f32),
+    zero = (jnp.zeros(xt.shape, f32), jnp.zeros((xt.shape[0] * top_k,), f32),
             [jnp.zeros(w.shape, f32) for w in (w1, w3, w2)])
     dxt, dgate, dws = _over_chunks(chunks, ends[-1], run, zero)
-    return (dxt.astype(xt.dtype), dgate.astype(gate.dtype),
+    return (dxt.astype(xt.dtype), dgate.astype(gates.dtype),
             *(d.astype(w.dtype) for d, w in zip(dws, (w1, w3, w2))),
             None, None, None)
 
 
 _held_experts.defvjp(_held_experts_fwd, _held_experts_bwd)
+
+
+# Differentiated by hand: ``top_k``'s own transpose (and
+# ``take_along_axis``'s) is a scatter of ``T * top_k`` scalars into
+# [T, E], and the chip scatters or gathers a scalar in about 8 ns (2.3
+# ms a layer at 32,768 tokens).  No (token, expert) pair is chosen
+# twice, so a select and a sum over the slots give the same numbers.
+@functools.partial(jax.custom_vjp, nondiff_argnums=(1,))
+def _choose(probs, k, offset=None):
+    """The ``k`` experts every token takes -- those with the largest
+    ``probs`` [T, E], plus ``offset`` [E] where given (it only chooses:
+    no gradient reaches it) -- and their ``probs``: ([T, k] float32,
+    [T, k] int32)."""
+    if offset is None:
+        return tuple(jax.lax.top_k(probs, k))
+    _, expert = jax.lax.top_k(probs + offset, k)
+    hot = expert[..., None] == jnp.arange(probs.shape[-1])
+    return jnp.sum(jnp.where(hot, probs[:, None, :], 0.0), axis=-1), expert
+
+
+def _choose_fwd(probs, k, offset):
+    chosen, expert = _choose(probs, k, offset)
+    return (chosen, expert), (expert, jnp.arange(probs.shape[-1]), offset)
+
+
+def _choose_bwd(k, res, cotangent):
+    expert, experts, offset = res
+    hot = expert[..., None] == experts
+    d_probs = jnp.sum(jnp.where(hot, cotangent[0][..., None], 0.0), axis=-2)
+    return d_probs, None if offset is None else jnp.zeros_like(offset)
+
+
+_choose.defvjp(_choose_fwd, _choose_bwd)
 
 
 def moe_ffn(x: jax.Array, lp: Dict, top_k: int, norm_topk: bool = True,
@@ -283,15 +370,12 @@ def moe_ffn(x: jax.Array, lp: Dict, top_k: int, norm_topk: bool = True,
                             precision=jax.lax.Precision.HIGHEST)
         if scoring == "softmax":
             probs = jax.nn.softmax(logits, axis=-1)
-            gate, expert = jax.lax.top_k(probs, top_k)         # [T, k]
+            gate, expert = _choose(probs, top_k)               # [T, k]
             if norm_topk:
                 gate = gate / jnp.sum(gate, axis=-1, keepdims=True)
         elif scoring == "sigmoid":
             probs = jax.nn.sigmoid(logits)
-            select = probs if "bias" not in lp else \
-                probs + jax.lax.stop_gradient(lp["bias"])
-            _, expert = jax.lax.top_k(select, top_k)
-            gate = jnp.take_along_axis(probs, expert, axis=-1)
+            gate, expert = _choose(probs, top_k, lp.get("bias"))
             if norm_topk:
                 gate = gate / (jnp.sum(gate, axis=-1, keepdims=True) + 1e-20)
             gate = gate * route_scale
@@ -306,24 +390,19 @@ def moe_ffn(x: jax.Array, lp: Dict, top_k: int, norm_topk: bool = True,
         local = expert.reshape(N) - first
         is_held = (local >= 0) & (local < count)
         key = jnp.where(is_held, local, count).astype(jnp.int32)
-        order = jnp.argsort(key, stable=True).astype(jnp.int32)
         sizes = jnp.sum(jax.nn.one_hot(key, count, dtype=jnp.int32), axis=0)
         ends = jnp.cumsum(sizes)                               # [count]
-        first, rest = chunks = chunk_rows(T, n_experts, count, top_k,
-                                          alike_tail)
-        whole = first + -(-max(0, N - first) // rest) * rest
-        order = jnp.pad(order, (0, whole - N))
-        gate_flat = gate.reshape(N)
+        chunks = chunk_rows(T, n_experts, count, top_k, alike_tail)
 
-    y, done = _held_experts(chunks, top_k, xt, gate_flat, lp["w1"], lp["w3"],
-                            lp["w2"], order, ends, sizes)
+    y, done = _held_experts(chunks, top_k, xt, gate.reshape(N), lp["w1"],
+                            lp["w3"], lp["w2"], key, ends, sizes)
     stats = {"held_choices": ends[-1], "expert_load": sizes,
              "dropped_choices": jnp.sum(is_held.astype(jnp.int32)) - done,
              "choices": expert.astype(jnp.int32).reshape(*lead, top_k),
              "router_load": router_load,
              "router_prob": jnp.sum(probs, axis=0),
              "tokens": jnp.asarray(T, jnp.int32)}
-    return y.astype(x.dtype).reshape(*lead, D), stats
+    return y.reshape(*lead, D), stats
 
 
 def balance_loss(stats: Dict) -> jax.Array:

@@ -8,6 +8,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from ray_tpu.models import moe
 from ray_tpu.models.moe import (alike_choices, balance_loss, chunk_rows,
                                 counters, init_moe_params, moe_ffn)
 
@@ -30,15 +31,22 @@ def _tokens(rows=2, length=64, key=1):
     return x.at[..., 0].set(1.0)
 
 
-def _loop(x, lp, first=0, count=E, top_k=K, norm=True):
+def _loop(x, lp, first=0, count=E, top_k=K, norm=True, scoring="softmax",
+          route_scale=1.0):
     """The layer as a loop over the held experts ``[first, first +
     count)`` of the whole layer's ``lp``, every token through every one
     of them with its gate (nought where it was not chosen)."""
     xt = x.reshape(-1, D)
-    probs = jax.nn.softmax(xt @ lp["wr"], -1)
-    gate, chosen = jax.lax.top_k(probs, top_k)
+    if scoring == "softmax":
+        probs = jax.nn.softmax(xt @ lp["wr"], -1)
+        gate, chosen = jax.lax.top_k(probs, top_k)
+    else:
+        probs = jax.nn.sigmoid(xt @ lp["wr"])
+        _, chosen = jax.lax.top_k(probs + lp["bias"], top_k)
+        gate = jnp.take_along_axis(probs, chosen, axis=-1)
     if norm:
         gate = gate / gate.sum(-1, keepdims=True)
+    gate = gate * route_scale
     y = jnp.zeros_like(xt)
     for j in range(count):
         g = jnp.sum(jnp.where(chosen == first + j, gate, 0.0), -1)
@@ -49,8 +57,10 @@ def _loop(x, lp, first=0, count=E, top_k=K, norm=True):
 
 
 def _held(lp, first, count):
-    return dict(wr=lp["wr"], **{k: lp[k][first:first + count]
-                                for k in ("w1", "w3", "w2")})
+    """The share ``[first, first + count)`` of the layer's ``lp``: the
+    whole router (and its bias, where it has one), those experts."""
+    return dict({k: lp[k] for k in ("wr", "bias") if k in lp},
+                **{k: lp[k][first:first + count] for k in ("w1", "w3", "w2")})
 
 
 @pytest.mark.parametrize("norm", [True, False])
@@ -174,3 +184,186 @@ def test_the_balance_loss_is_one_at_balance_and_pushes_towards_it():
     g = jax.grad(lambda wr: balance_loss(moe_ffn(
         x, dict(mild, wr=wr), K)[1]))(mild["wr"])
     assert float(g[0, 3]) > 0 > float(g[0, 5])
+
+
+# -- PR 34: how a chunk's rows return to their tokens, and what the
+# backward reads on the way --------------------------------------------
+
+def _equations(jaxpr):
+    """Every equation of a jaxpr and of the jaxprs inside it (loops,
+    conditionals, hand-written derivatives)."""
+    for eqn in jaxpr.eqns:
+        yield eqn
+        for value in eqn.params.values():
+            for inner in (value if isinstance(value, (list, tuple))
+                          else [value]):
+                inner = getattr(inner, "jaxpr", inner)
+                if hasattr(inner, "eqns"):
+                    yield from _equations(inner)
+
+
+@pytest.mark.parametrize("scoring", ["softmax", "sigmoid"])
+@pytest.mark.parametrize("direction", ["forward", "gradient"])
+def test_what_the_layer_gathers_and_scatters(direction, scoring):
+    """On bfloat16 tokens, forward and backward: (a) the rows are added
+    to their tokens by ONE float32 scatter-add a chunk and direction,
+    and nothing else with ``D`` columns is scattered; (b) every gather
+    of rows says its indices are in range (no pass that fills rows with
+    NaN follows it) and reads the tokens' dtype -- the cotangent too,
+    never float32 rows; (c) no gate is gathered a row at a time (the
+    sort carries them); (d) the router's choice scatters nothing into
+    [T, E] and gathers nothing out of it."""
+    bf = jnp.bfloat16
+    lp = {k: v.astype(bf) for k, v in _layer().items()}
+    kwargs = {"scoring": scoring}
+    if scoring == "sigmoid":
+        lp["bias"] = jnp.linspace(-0.2, 0.2, E)
+    x = _tokens().astype(bf)
+    tokens = x.shape[0] * x.shape[1]
+
+    def layer(x, lp):
+        return moe_ffn(x, lp, K, True, held=(8, 4), **kwargs)[0]
+
+    fn = layer if direction == "forward" else jax.grad(
+        lambda x, lp: jnp.sum(layer(x, lp).astype(jnp.float32) ** 2), (0, 1))
+    eqns = list(_equations(jax.make_jaxpr(fn)(x, _held(lp, 8, 4)).jaxpr))
+    wide_adds = [e for e in eqns if e.primitive.name == "scatter-add"
+                 and e.invars[0].aval.shape[-1:] == (D,)]
+    # the first chunk and the loop's body, in each direction traced
+    assert len(wide_adds) == (2 if direction == "forward" else 4)
+    assert all(e.invars[0].aval.dtype == jnp.float32
+               and e.invars[0].aval.shape == (tokens, D) for e in wide_adds)
+    assert not [e for e in eqns if e.primitive.name == "scatter"
+                and e.invars[0].aval.shape[-1:] == (D,)]
+    row_gathers = [e for e in eqns if e.primitive.name == "gather"
+                   and e.invars[0].aval.shape == (tokens, D)]
+    # the tokens' rows, and in the backward their cotangent's as well
+    assert len(row_gathers) == (2 if direction == "forward" else 6)
+    for e in row_gathers:
+        assert e.params["mode"] in (jax.lax.GatherScatterMode.CLIP,
+                                    jax.lax.GatherScatterMode.PROMISE_IN_BOUNDS)
+        assert e.invars[0].aval.dtype == bf
+    others = [e for e in eqns
+              if e.primitive.name in ("gather", "scatter", "scatter-add")
+              and e not in wide_adds and e not in row_gathers]
+    for e in others:
+        shape = e.invars[0].aval.shape
+        assert shape != (tokens * K,) or e.primitive.name == "scatter-add", e
+        assert shape != (tokens, E), e
+
+
+@pytest.mark.parametrize("traced", [False, True])
+def test_the_sort_carries_every_choices_gate(traced):
+    """``_sorted_choices``: held choices first, by their local expert,
+    in token order within one (stable); the gates in the same order
+    (``gates[r] == gate[order[r]]``), padded to whole chunks -- with a
+    held range whose first index is traced as well."""
+    x = _tokens()
+    tokens = x.shape[0] * x.shape[1]
+    expert = jax.random.randint(jax.random.PRNGKey(5), (tokens * K,), 0, E)
+    gate = jax.random.uniform(jax.random.PRNGKey(6), (tokens * K,))
+    chunks = chunk_rows(tokens, E, 4, K)
+
+    def sort(first):
+        local = expert - first
+        key = jnp.where((local >= 0) & (local < 4), local, 4)
+        return key, moe._sorted_choices(chunks, key.astype(jnp.int32), gate)
+
+    key, (order, gates) = (jax.jit(sort) if traced else sort)(
+        jnp.asarray(8) if traced else 8)
+    n = tokens * K
+    whole = chunks[0] + -(-(n - chunks[0]) // chunks[1]) * chunks[1]
+    assert order.shape == gates.shape == (whole,)
+    order, gates, key = (np.asarray(a) for a in (order, gates, key))
+    assert sorted(order[:n]) == list(range(n))
+    assert np.array_equal(gates[:n], np.asarray(gate)[order[:n]])
+    sorted_key = key[order[:n]]
+    assert np.all(np.diff(sorted_key) >= 0)
+    # stable: within one key the choices keep their order
+    assert np.all((np.diff(order[:n]) > 0) | (np.diff(sorted_key) > 0))
+    held = int((key < 4).sum())
+    assert np.all(sorted_key[:held] < 4) and np.all(sorted_key[held:] == 4)
+    assert not order[n:].any() and not gates[n:].any()
+
+
+def _case(name):
+    """-> (x, whole layer's lp, held range, keywords of ``moe_ffn`` and
+    of ``_loop``, relative tolerance)."""
+    x, held, kwargs, tol = _tokens(), (8, 4), {}, 1e-4
+    lp = _layer()
+    if name == "all_of_a_tokens_choices_in_one_chunk":
+        held = (0, E)                       # everything held: one chunk
+    elif name == "a_tokens_choices_split_across_two_chunks":
+        # every token takes all four held experts: three of its choices
+        # lie in the first chunk, the fourth in a further one
+        lp = _layer(skew={8 + j: 30.0 - j for j in range(4)})
+        x = _tokens(4, 128, key=2)
+    elif name == "tokens_with_no_held_choice":
+        lp = _layer(skew={8: -30.0, 9: -30.0, 10: -30.0})
+    elif name == "sigmoid_router_with_a_bias":
+        kwargs = {"scoring": "sigmoid", "route_scale": 2.5}
+        lp = dict(_layer(scale=4.0),
+                  bias=jnp.linspace(-0.3, 0.3, E)[::-1])
+    elif name == "bfloat16_inputs":
+        lp = _layer(skew={8: 30.0, 9: 29.0})
+        tol = 2e-2
+    return x, lp, held, kwargs, tol
+
+
+@pytest.mark.parametrize("name", [
+    "all_of_a_tokens_choices_in_one_chunk",
+    "a_tokens_choices_split_across_two_chunks",
+    "tokens_with_no_held_choice",
+    "sigmoid_router_with_a_bias",
+    "bfloat16_inputs"])
+def test_the_rows_return_to_their_tokens_as_in_the_plain_loop(name):
+    """Value and every gradient against ``_loop`` where adding a chunk's
+    rows to their tokens can go wrong (the file's 1e-4; bfloat16 inputs
+    against the float32 loop over the same rounded numbers, within
+    bfloat16's rounding: the sums are float32)."""
+    x, lp, (first, count), kwargs, tol = _case(name)
+    held_lp = _held(lp, first, count)
+    dtype = jnp.bfloat16 if name == "bfloat16_inputs" else jnp.float32
+    if dtype == jnp.bfloat16:
+        x, lp, held_lp = jax.tree.map(
+            lambda a: a.astype(dtype).astype(jnp.float32), (x, lp, held_lp))
+
+    def layer(x, lp):
+        x, lp = jax.tree.map(lambda a: a.astype(dtype), (x, lp))
+        if "bias" in lp:
+            lp["bias"] = lp["bias"].astype(jnp.float32)
+        y, stats = moe_ffn(x, lp, K, True, held=(first, count), **kwargs)
+        return y.astype(jnp.float32), stats
+
+    def loop(x, lp):
+        return _loop(x, lp, first=first, count=count, **kwargs)
+
+    got, stats = jax.jit(layer)(x, held_lp)
+    want, chosen = loop(x, lp)
+    chosen = np.asarray(chosen)
+    in_range = (chosen >= first) & (chosen < first + count)
+    assert int(stats["held_choices"]) == in_range.sum()
+    assert int(stats["dropped_choices"]) == 0
+    tokens = chosen.shape[0]
+    first_chunk = chunk_rows(tokens, E, count, K)[0]
+    if name == "all_of_a_tokens_choices_in_one_chunk":
+        assert in_range.all() and first_chunk == tokens * K
+    elif name == "a_tokens_choices_split_across_two_chunks":
+        assert (in_range.sum(-1) == 4).all() and first_chunk == 3 * tokens
+    elif name == "tokens_with_no_held_choice":
+        assert (in_range.sum(-1) == 0).sum() > tokens // 2 \
+            and in_range.any()
+    assert float(jnp.max(jnp.abs(got - want))) <= tol * float(
+        jnp.max(jnp.abs(want)))
+    got_g = jax.grad(lambda x, lp: jnp.sum(layer(x, lp)[0] ** 2),
+                     (0, 1))(x, held_lp)
+    want_x, want_lp = jax.grad(lambda x, lp: jnp.sum(loop(x, lp)[0] ** 2),
+                               (0, 1))(x, lp)
+    want_g = (want_x, _held(want_lp, first, count))
+    assert jax.tree.structure(got_g) == jax.tree.structure(want_g)
+    for path, g in jax.tree_util.tree_flatten_with_path(got_g)[0]:
+        w = want_g
+        for k in path:
+            w = w[getattr(k, "key", getattr(k, "idx", None))]
+        assert float(jnp.max(jnp.abs(g - w))) <= tol * max(
+            float(jnp.max(jnp.abs(w))), 1e-30), (path, name)
