@@ -16,7 +16,9 @@ CPU), through the entry points a user calls:
                     on the same device and to the entry point's answer;
   3. trainer        ``ray_tpu.train.Trainer(backend="jax", use_tpu=True)``
                     taking steps on a 200M-parameter model (``TPU_MODEL``),
-                    the flash kernels in the compiled step.
+                    the flash kernels in the compiled step; 3b the mixed
+                    latent-attention stack, 3c the hybrid delta-rule one,
+                    each kernel first compared with its jnp form.
 
 With more than one chip visible it also runs the sharded solve and the
 dp/sp/tp + ep + pp programs on the real devices; with one it says those
@@ -307,6 +309,9 @@ def _train_func(config: dict) -> dict:
     if "mla" in model:
         from ray_tpu.models.mla import MLAConfig
         model["mla"] = MLAConfig(**model["mla"])
+    if "gdn" in model:
+        from ray_tpu.models.gdn import GDNConfig
+        model["gdn"] = GDNConfig(**model["gdn"])
     cfg = TransformerConfig(dtype=jnp.dtype(config["dtype"]), **model)
     state, tx = make_train_state(jax.random.PRNGKey(0), cfg)
     objective = None
@@ -338,6 +343,12 @@ def _train_func(config: dict) -> dict:
             "flash_fwd_calls_in_step": len(re.findall(
                 r'%flash_attention_fwd[.\d]* = [^\n]*"tpu_custom_call"',
                 text)),
+            # the delta layers' state passes: the forward in the forward
+            # scan's body and again in the backward's (nothing of it is
+            # kept), the backward once
+            "gated_delta_calls_in_step": [len(re.findall(
+                rf'%{name}[.\d]* = [^\n]*"tpu_custom_call"', text))
+                for name in ("gated_delta_fwd", "gated_delta_bwd")],
             "param_platforms": sorted({d.platform for d in leaf.devices()}),
             "n_params": sum(int(np.prod(x.shape))
                             for x in jax.tree.leaves(state["params"]))}
@@ -580,6 +591,132 @@ def leg_latent_trainer(platform: str = "tpu", model: dict = None,
             "flash_tol": flash_tol}
 
 
+#: The hybrid stack at a size the chip takes in seconds: one period of
+#: two Gated DeltaNet layers at the published head sizes (8 key heads
+#: serving 16 value heads of 128, 4 taps, chunks of 64) and one gated
+#: attention layer at 256-wide heads (8 query heads on 1 K/V head,
+#: rotary on 64 columns, ``1 + w`` norms), every layer with 8 of 32
+#: experts held, 4 a token, and a gated shared expert: 131.8M parameters.
+HYBRID_MODEL = dict(
+    vocab_size=32_000, d_model=1024, n_heads=8, n_kv_heads=1, head_dim=256,
+    d_ff=512, max_seq_len=1024, remat=True, norm_eps=1e-6, rope_theta=1e7,
+    qk_norm=True, norm_plus_one=True, attn_out_gate=True, rotary_dim=64,
+    gdn=dict(num_key_heads=8, num_value_heads=16, key_head_dim=128,
+             value_head_dim=128, conv_kernel=4, chunk=64),
+    layer_pattern=(((("gdn", "moe", 2), ("mha", "moe", 1)), 1),),
+    moe_experts=32, moe_top_k=4, moe_experts_held=(0, 8),
+    moe_shared_width=512, moe_shared_gate=True, moe_aux_coeff=0.001)
+
+
+def leg_hybrid_trainer(platform: str = "tpu", model: dict = None,
+                       batch: int = 4, seq: int = 1024, steps: int = 2,
+                       dtype: str = "bfloat16",
+                       rule_tol: float = 4e-2) -> dict:
+    """The hybrid stack through the same Trainer path, ``steps`` steps:
+    the delta rule's two kernels first compared with the chunked scan
+    and its ``jax.grad`` (all five gradients) at the model's shape; then
+    the compiled step must hold both state-pass kernels and both flash
+    kernels, nothing may be dropped, and the new counters must read what
+    an untrained model's gates read."""
+    import jax
+    import jax.numpy as jnp
+
+    import ray_tpu
+    from ray_tpu.ops.gated_delta import gated_delta_rule
+    from ray_tpu.train import Trainer
+
+    model = dict(model or HYBRID_MODEL)
+    on_chip = platform == "tpu"
+    g_ = model["gdn"]
+    heads, dk, dv = (g_["num_value_heads"], g_["key_head_dim"],
+                     g_["value_head_dim"])
+    keys = jax.random.split(jax.random.PRNGKey(11), 6)
+    low = jnp.dtype(dtype)
+
+    def unit(x):
+        return x / jnp.linalg.norm(x, axis=-1, keepdims=True)
+
+    q = (unit(jax.random.normal(keys[0], (batch, seq, heads, dk)))
+         * dk ** -0.5).astype(low)
+    k = unit(jax.random.normal(keys[1], (batch, seq, heads, dk))).astype(low)
+    v = jax.random.normal(keys[2], (batch, seq, heads, dv)).astype(low)
+    # heads that forget within a position and heads that never do
+    rate = jnp.exp(jnp.linspace(jnp.log(1e-3), jnp.log(16.0), heads))
+    g = -rate * jax.nn.softplus(jax.random.normal(keys[3],
+                                                  (batch, seq, heads)))
+    beta = jax.nn.sigmoid(jax.random.normal(keys[4], (batch, seq, heads)))
+    dout = jax.random.normal(keys[5], (batch, seq, heads, dv))
+    operands = (q, k, v, g, beta)
+    chunk = min(g_["chunk"], seq)
+
+    def kernels(*xs):
+        return gated_delta_rule(*xs, chunk=chunk, use_pallas=True,
+                                interpret=not on_chip)
+
+    def scan(*xs):
+        return gated_delta_rule(*xs, chunk=chunk, use_pallas=False)
+
+    def grads(fn):
+        return jax.grad(lambda *xs: jnp.sum(
+            fn(*xs).astype(jnp.float32) * dout), (0, 1, 2, 3, 4))(*operands)
+
+    def rel_err(got, want):
+        return _max_err(got, want) / float(
+            jnp.max(jnp.abs(want.astype(jnp.float32))))
+
+    fwd_err = rel_err(kernels(*operands), scan(*operands))
+    check(fwd_err <= rule_tol,
+          f"gated delta kernels vs the chunked scan: max rel err {fwd_err} "
+          f"> {rule_tol} ({dtype})")
+    bwd_err = max(rel_err(a, b)
+                  for a, b in zip(grads(kernels), grads(scan)))
+    check(bwd_err <= rule_tol,
+          f"gated delta backward (dq, dk, dv, dg, dbeta) vs grad of the "
+          f"chunked scan: {bwd_err} > {rule_tol} ({dtype})")
+
+    ray_tpu.init(num_cpus=4, num_tpus=len(jax.devices()))
+    try:
+        trainer = Trainer(backend="jax", num_workers=1, use_tpu=True)
+        try:
+            (result,) = trainer.run(_train_func, config=dict(
+                model=model, batch=batch, seq=seq, steps=steps,
+                dtype=dtype))
+        finally:
+            trainer.shutdown()
+    finally:
+        ray_tpu.shutdown()
+    losses, counters = result["losses"], result["counters"]
+    check(len(losses) == steps and np.isfinite(losses).all(),
+          f"finite losses: {losses}")
+    check(losses[-1] < losses[0], f"loss falls on a repeated batch: {losses}")
+    check(result["param_platforms"] == [platform],
+          f"params on {platform}: {result['param_platforms']}")
+    check(result["gated_delta_calls_in_step"]
+          == [2 * int(on_chip), int(on_chip)],
+          f"the state pass forward in both scans' bodies and backward in "
+          f"one: {result['gated_delta_calls_in_step']} Mosaic calls")
+    check(result["flash_fwd_calls_in_step"] == int(on_chip)
+          and result["mosaic_bwd_in_step"] == on_chip,
+          f"both flash kernels once in the compiled step: "
+          f"{result['flash_fwd_calls_in_step']}, "
+          f"{result['mosaic_bwd_in_step']}")
+    check(counters["moe_dropped_choices"] == 0.0,
+          f"no choice dropped: {counters}")
+    for name in ("attn_gate_mean", "moe_shared_gate_mean", "gdn_beta_mean"):
+        check(0.3 < counters[name] < 0.7, f"{name} near a half: {counters}")
+    check(0.0 < counters["gdn_decay_mean"] < 1.0
+          and counters["gdn_state_norm"] > 0.0,
+          f"the delta layers' state decays and is written: {counters}")
+    return {"batch": batch, "seq": seq, "dtype": dtype,
+            "params_m": round(result["n_params"] / 1e6, 1),
+            "losses": [round(x, 4) for x in losses],
+            "counters": {k: round(v, 5) for k, v in counters.items()},
+            "gated_delta_calls_in_step": result["gated_delta_calls_in_step"],
+            "flash_fwd_calls_in_step": result["flash_fwd_calls_in_step"],
+            "gated_delta_vs_scan_max_rel_err": fwd_err,
+            "gated_delta_bwd_max_rel_err": bwd_err, "rule_tol": rule_tol}
+
+
 # ---------------------------------------------------------------------------
 # Legs 4 and 5 — more than one device.
 # ---------------------------------------------------------------------------
@@ -742,6 +879,8 @@ def main() -> int:
     run_leg("3 trainer 200M x 8 x 1024", clock, leg_trainer)
     run_leg("3b latent attention + experts + MTP 182M x 4 x 1024", clock,
             leg_latent_trainer)
+    run_leg("3c delta rule + gated attention + experts 132M x 4 x 1024",
+            clock, leg_hybrid_trainer)
     if device["count"] > 1:
         run_leg("4 sharded solve", clock, leg_sharded_solve)
         run_leg("5 model parallel dp/sp/tp + ep + pp", clock,

@@ -32,7 +32,8 @@ import jax
 import jax.numpy as jnp
 
 from ray_tpu.models.transformer import (TransformerConfig, _rms_norm,
-                                        run_layers, with_balance_loss)
+                                        norm_weight, run_layers,
+                                        with_balance_loss)
 from ray_tpu.ops.attention_mask import BlockDiffusion
 from ray_tpu.util import tracing
 
@@ -76,7 +77,9 @@ def loss_fn(params: Dict, batch: Dict, cfg: TransformerConfig, block: int,
     with jax.named_scope("block_diffusion_loss"):
         # only the noisy half is scored
         z = jnp.einsum("bsd,dv->bsv",
-                       _rms_norm(x[:, :length], params["ln_f"], cfg.norm_eps),
+                       _rms_norm(x[:, :length],
+                                 norm_weight(params["ln_f"], cfg),
+                                 cfg.norm_eps),
                        params["lm_head"]).astype(jnp.float32)
         logz = jax.nn.logsumexp(z, axis=-1)
         gold = jnp.take_along_axis(z, x0[..., None], axis=-1).squeeze(-1)

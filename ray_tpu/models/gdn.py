@@ -1,0 +1,158 @@
+"""Gated DeltaNet linear attention (as Qwen3-Next publishes it): a
+recurrent state a head in place of keys and values, written by the
+delta rule and forgotten by a gate, both functions of the input.
+
+A layer-pattern kind (``"gdn"`` in ``TransformerConfig.layer_pattern``)
+with its own parameters under ``lp["gdn"]``.  For ``h [B, S, d]`` (the
+layer's normed input), ``Hk`` key heads of ``Dk``, ``Hv = r Hk`` value
+heads of ``Dv`` (key head ``j`` serves value heads ``r j .. r j + r - 1``):
+
+    q | k | v | z = h w_qkvz       a key head's columns together:
+                                   [Dk | Dk | r Dv | r Dv]
+    b | a         = h w_ba         [r | r] a key head
+    q | k | v     = silu(conv(q | k | v))   causal, depthwise, ``taps``
+                    positions, zeros before the row's start, no bias
+    q, k          = l2norm(q) Dk^-1/2, l2norm(k)      (eps 1e-6)
+    beta          = sigmoid(b)                         float32
+    g             = -exp(A_log) softplus(a + dt_bias)  float32
+    o             = gated_delta_rule(q, k, v, g, beta) (ops/gated_delta.py)
+    out           = (rmsnorm(o; norm) silu(z), heads joined) wo
+
+The fused projections are laid out a key head at a time so that ``tp``
+shards value heads with the key head they read and their q/k/z/b/a
+columns, conv taps, ``A_log`` and ``dt_bias`` with them, and ``wo`` by
+rows.  ``norm`` [Dv] is a plain RMSNorm weight (1 at the start), shared
+by the heads.  A row is one causal sequence: the state and the
+convolution cross whatever separators it holds, as attention does.  Over
+an ``sp`` axis the layer raises (the state would have to pass from shard
+to shard).
+"""
+
+from __future__ import annotations
+
+import dataclasses
+from typing import Dict
+
+import jax
+import jax.numpy as jnp
+from jax.sharding import PartitionSpec as P
+
+_L2_EPS = 1e-6
+
+
+@dataclasses.dataclass(frozen=True)
+class GDNConfig:
+    num_key_heads: int
+    num_value_heads: int
+    key_head_dim: int
+    value_head_dim: int
+    conv_kernel: int = 4
+    chunk: int = 64
+
+    @property
+    def ratio(self) -> int:
+        return self.num_value_heads // self.num_key_heads
+
+
+def init_gdn_params(rng: jax.Array, n_layers: int, d_model: int,
+                    g: GDNConfig, dtype) -> Dict:
+    """Matrices and taps N(0, 0.02); ``A_log = log U(0, 16)``,
+    ``dt_bias`` and ``norm`` 1, as the published modelling code."""
+    init = jax.nn.initializers.normal(0.02)
+    keys = jax.random.split(rng, 5)
+    hk, r, dk, dv = g.num_key_heads, g.ratio, g.key_head_dim, g.value_head_dim
+
+    def stacked(key, shape):
+        return init(key, (n_layers, *shape), jnp.float32).astype(dtype)
+
+    return {
+        "w_qkvz": stacked(keys[0], (d_model, hk, 2 * dk + 2 * r * dv)),
+        "w_ba": stacked(keys[1], (d_model, hk, 2 * r)),
+        "conv": stacked(keys[2], (hk, 2 * dk + r * dv, g.conv_kernel)),
+        "A_log": jnp.log(jax.random.uniform(
+            keys[3], (n_layers, hk, r), jnp.float32, 1e-6, 16.0)),
+        "dt_bias": jnp.ones((n_layers, hk, r), jnp.float32),
+        "norm": jnp.ones((n_layers, dv), jnp.float32),
+        "wo": stacked(keys[4], (hk, r * dv, d_model)),
+    }
+
+
+def gdn_param_specs() -> Dict:
+    """Everything that has a key head axis over ``tp`` by it; the output
+    norm replicated."""
+    return {
+        "w_qkvz": P(None, None, "tp", None),
+        "w_ba": P(None, None, "tp", None),
+        "conv": P(None, "tp", None, None),
+        "A_log": P(None, "tp", None),
+        "dt_bias": P(None, "tp", None),
+        "norm": P(None, None),
+        "wo": P(None, "tp", None, None),
+    }
+
+
+def causal_conv(x, taps):
+    """x [B, S, ..., C], taps [..., C, K]: each channel over its own last
+    K positions (tap K - 1 on the position itself), zeros before the
+    row's start; summed in float32."""
+    k = taps.shape[-1]
+    seq = x.shape[1]
+    padded = jnp.pad(x.astype(jnp.float32),
+                     [(0, 0), (k - 1, 0)] + [(0, 0)] * (x.ndim - 2))
+    taps = taps.astype(jnp.float32)
+    return sum(padded[:, j:j + seq] * taps[..., j] for j in range(k))
+
+
+def _l2norm(x):
+    xf = x.astype(jnp.float32)
+    return xf * jax.lax.rsqrt(jnp.sum(xf * xf, axis=-1, keepdims=True)
+                              + _L2_EPS)
+
+
+def gdn_attention(h, lp: Dict, cfg, mesh=None):
+    """The layer's normed input ``h [B, S, d]`` -> (what the delta layer
+    adds to the residual, what it counted: ``gdn_state_norm`` -- the
+    root mean square of the state entering a row's last chunk --
+    ``gdn_decay_mean``, the mean of ``exp(g)``, and ``gdn_beta_mean``).
+    ``lp``: this layer's ``gdn`` parameters."""
+    from ray_tpu.ops.gated_delta import gated_delta_rule
+    g_ = cfg.gdn
+    if (cfg.context_parallel and mesh is not None
+            and mesh.shape.get("sp", 1) > 1):
+        raise ValueError("a delta layer's state passes along the row: it "
+                         "does not run over an sp axis")
+    hk, r, dk, dv = (g_.num_key_heads, g_.ratio, g_.key_head_dim,
+                     g_.value_head_dim)
+    b, s, _ = h.shape
+    f32 = jnp.float32
+    with jax.named_scope("gdn_proj"):
+        qkvz = jnp.einsum("bsd,dhc->bshc", h, lp["w_qkvz"])
+        ba = jnp.einsum("bsd,dhc->bshc", h, lp["w_ba"]).astype(f32)
+        z = qkvz[..., 2 * dk + r * dv:].reshape(b, s, hk * r, dv)
+    with jax.named_scope("gdn_conv"):
+        mixed = jax.nn.silu(causal_conv(qkvz[..., :2 * dk + r * dv],
+                                        lp["conv"]))
+    with jax.named_scope("gdn_core"):
+        q = (_l2norm(mixed[..., :dk]) * dk ** -0.5).astype(h.dtype)
+        k = _l2norm(mixed[..., dk:2 * dk]).astype(h.dtype)
+        v = mixed[..., 2 * dk:].astype(h.dtype).reshape(b, s, hk * r, dv)
+        beta = jax.nn.sigmoid(ba[..., :r]).reshape(b, s, hk * r)
+        g = (-jnp.exp(lp["A_log"]) * jax.nn.softplus(
+            ba[..., r:] + lp["dt_bias"])).reshape(b, s, hk * r)
+        # a key head's q and k, once a value head that reads them
+        o, state = gated_delta_rule(
+            jnp.repeat(q, r, axis=2), jnp.repeat(k, r, axis=2), v, g, beta,
+            chunk=min(g_.chunk, s), with_state=True)
+        counted = {
+            "gdn_state_norm": jnp.sqrt(jnp.mean(jnp.square(state))),
+            "gdn_decay_mean": jax.lax.stop_gradient(jnp.mean(jnp.exp(g))),
+            "gdn_beta_mean": jax.lax.stop_gradient(jnp.mean(beta)),
+        }
+    with jax.named_scope("gdn_out"):
+        o = o.astype(f32)
+        o = o * jax.lax.rsqrt(jnp.mean(o * o, axis=-1, keepdims=True)
+                              + cfg.norm_eps) * lp["norm"]
+        o = (o * jax.nn.silu(z.astype(f32))).astype(h.dtype)
+        out = jnp.einsum("bshk,hkd->bsd", o.reshape(b, s, hk, r * dv),
+                         lp["wo"])
+    return out, counted

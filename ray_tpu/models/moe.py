@@ -61,7 +61,9 @@ loaded least.  A layer that has one also reports every expert's load.
 
 A shared expert (``ws1``/``ws3``/``ws2``, one SwiGLU every token
 passes) is added by ``shared_expert`` OUTSIDE the share's partial sum:
-on one chip's share and under an ``ep`` axis alike it counts once.
+on one chip's share and under an ``ep`` axis alike it counts once.  It
+may have a gate of its own (``wsg`` [D, 1], ``shared_gate``): the token's
+``sigmoid(x . wsg)`` multiplies its output.
 """
 
 from __future__ import annotations
@@ -81,9 +83,10 @@ _ALIKE_TAIL = 0.01
 
 def init_moe_params(rng: jax.Array, n_layers: int, d_model: int,
                     d_ff: int, n_experts: int, n_held: int, dtype,
-                    shared_width: int = 0) -> Dict:
+                    shared_width: int = 0, shared_gate: bool = False) -> Dict:
     """Router over all ``n_experts``; weights of the ``n_held`` held;
-    a shared expert of ``shared_width`` where that is not 0."""
+    a shared expert of ``shared_width`` where that is not 0, with its
+    gate ``wsg`` [d_model, 1] under ``shared_gate``."""
     init = jax.nn.initializers.normal(0.02)
     keys = jax.random.split(rng, 4)
 
@@ -103,12 +106,15 @@ def init_moe_params(rng: jax.Array, n_layers: int, d_model: int,
             "ws3": stacked(shared[1], (d_model, shared_width)),
             "ws2": stacked(shared[2], (shared_width, d_model)),
         })
+        if shared_gate:
+            params["wsg"] = stacked(jax.random.fold_in(rng, 5), (d_model, 1))
     return params
 
 
-def moe_param_specs(shared: bool = False) -> Dict:
+def moe_param_specs(shared: bool = False, shared_gate: bool = False) -> Dict:
     """Experts sharded over ``ep``; router replicated; the shared
-    expert's width over ``tp``, replicated over ``ep``."""
+    expert's width over ``tp``, replicated over ``ep``; its gate
+    replicated."""
     specs = {
         "wr": P(None, None),
         "w1": P(None, "ep", None, None),
@@ -118,6 +124,8 @@ def moe_param_specs(shared: bool = False) -> Dict:
     if shared:
         specs.update({"ws1": P(None, None, "tp"), "ws3": P(None, None, "tp"),
                       "ws2": P(None, "tp", None)})
+        if shared_gate:
+            specs["wsg"] = P(None, None, None)
     return specs
 
 
@@ -425,6 +433,15 @@ def shared_expert(x: jax.Array, lp: Dict) -> jax.Array:
         gate = jax.nn.silu(jnp.einsum("bsd,df->bsf", x, lp["ws1"]))
         up = jnp.einsum("bsd,df->bsf", x, lp["ws3"])
         return jnp.einsum("bsf,fd->bsd", gate * up, lp["ws2"])
+
+
+def shared_gate(x: jax.Array, lp: Dict) -> jax.Array:
+    """``sigmoid(x . wsg)`` [B, S, 1] in ``x``'s dtype: what a gated
+    shared expert's output is multiplied by, a token at a time."""
+    with jax.named_scope("moe_shared"):
+        return jax.nn.sigmoid(jnp.einsum(
+            "bsd,do->bso", x, lp["wsg"],
+            preferred_element_type=jnp.float32)).astype(x.dtype)
 
 
 def update_bias(bias: jax.Array, load: jax.Array, rate: float) -> jax.Array:

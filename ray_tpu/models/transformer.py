@@ -23,7 +23,14 @@ graft entry exercise):
     ``"moe"``: the expert layer, ``models/moe.py``), each with its own
     parameters and its own code: a model pays for the kinds it names.
     With one run ``params["layers"]`` is that stack's tree (as it always
-    was); with several it is a tuple of them, in order.
+    was); with several it is a tuple of them, in order.  An entry of the
+    pattern may also be a PERIOD, ``((run, run, ...), repeats)``: the
+    runs in turn, that many times over, as one scan over periods whose
+    body is the runs' own scans (a 3 : 1 pattern at 48 layers is one
+    compiled body, not 24).  Its tree is a tuple of its runs' stacks,
+    each with the periods as a further leading axis.  ``"gdn"`` is the
+    third attention kind: Gated DeltaNet's linear attention,
+    ``models/gdn.py``.
 """
 
 from __future__ import annotations
@@ -45,7 +52,7 @@ from ray_tpu.util import tracing
 
 
 #: The kinds a run of the layer pattern is made of.
-_ATTENTION = ("mha", "mla")
+_ATTENTION = ("mha", "mla", "gdn")
 _FFN = ("dense", "moe")
 
 
@@ -112,14 +119,31 @@ class TransformerConfig:
     #: parameters, ``state["moe_bias"]`` [expert layers, moe_experts]
     #: float32: no gradient, no weight decay, no Adam moments.
     moe_bias_rate: float = 0.0
+    #: The shared expert's output is multiplied by ``sigmoid(x . wsg)``
+    #: (``wsg`` [d_model, 1]).
+    moe_shared_gate: bool = False
     #: Latent attention's sizes (``models.mla.MLAConfig``) for the
     #: ``"mla"`` kind.
     mla: Any = None
-    #: Runs of layers of one kind, ``((attention, ffn, count), ...)``
-    #: with attention ``"mha"`` | ``"mla"`` and ffn ``"dense"`` |
-    #: ``"moe"``; ``n_layers`` is then their sum.  None: ``n_layers``
-    #: of the one kind the other fields describe.
-    layer_pattern: Optional[Tuple[Tuple[str, str, int], ...]] = None
+    #: The delta layers' sizes (``models.gdn.GDNConfig``) for the
+    #: ``"gdn"`` kind.
+    gdn: Any = None
+    #: Gated attention (the ``"mha"`` kind): ``wq`` is twice as wide,
+    #: query | gate a head, and the heads' output is multiplied by
+    #: ``sigmoid(gate)`` before ``wo``.
+    attn_out_gate: bool = False
+    #: >0: RoPE turns the first ``rotary_dim`` columns of a head (the
+    #: halves of that slice) and leaves the others as they are.
+    rotary_dim: int = 0
+    #: The model's RMSNorms (``ln1``, ``ln2``, ``ln_f``, ``q_norm``,
+    #: ``k_norm``) scale by ``1 + w`` with ``w`` nought at the start.
+    norm_plus_one: bool = False
+    #: Entries ``(attention, ffn, count)``, a run of layers of one kind
+    #: (attention ``"mha"`` | ``"mla"`` | ``"gdn"``, ffn ``"dense"`` |
+    #: ``"moe"``), or ``((run, ...), repeats)``, a period of runs;
+    #: ``n_layers`` is then their sum.  None: ``n_layers`` of the one
+    #: kind the other fields describe.
+    layer_pattern: Optional[Tuple[Any, ...]] = None
     #: Multi-token-prediction modules after the stack (0 or 1):
     #: ``models/mtp.py``, whose loss hooks in by ``loss_override``.
     mtp_depth: int = 0
@@ -139,34 +163,68 @@ class TransformerConfig:
                 "moe" if self.moe_experts > 0 else "dense",
                 self.n_layers),))
         else:
-            pattern = tuple(tuple(run) for run in self.layer_pattern)
-            for attention, ffn, count in pattern:
-                if attention not in _ATTENTION or ffn not in _FFN \
-                        or count < 1:
-                    raise ValueError(f"layer pattern run {attention!r}, "
-                                     f"{ffn!r}, {count}")
+            pattern = tuple(_checked_entry(e) for e in self.layer_pattern)
             object.__setattr__(self, "layer_pattern", pattern)
             object.__setattr__(self, "n_layers",
-                               sum(count for _, _, count in pattern))
-        kinds = {kind for run in self.layer_pattern for kind in run[:2]}
+                               sum(count for _, _, count in runs_of(pattern)))
+        kinds = {kind for run in runs_of(self.layer_pattern)
+                 for kind in run[:2]}
         if "mla" in kinds and self.mla is None:
             raise ValueError("an \"mla\" layer needs the mla sizes")
+        if "gdn" in kinds and self.gdn is None:
+            raise ValueError("a \"gdn\" layer needs the gdn sizes")
         if "moe" in kinds and self.moe_experts < 1:
             raise ValueError("a \"moe\" layer needs moe_experts")
         if self.mtp_depth not in (0, 1):
             raise ValueError("one multi-token-prediction module at most")
+        if self.mtp_depth and (is_period(self.layer_pattern[-1])
+                               or self.norm_plus_one):
+            raise ValueError("the multi-token-prediction module takes its "
+                             "kind from a last entry that is a run, and "
+                             "its norms scale by w")
 
     @property
     def moe_layers(self) -> int:
         """Expert layers, the multi-token-prediction module's included:
         the rows of the correction bias."""
-        return sum(count for _, ffn, count in self.layer_pattern
+        return sum(count for _, ffn, count in runs_of(self.layer_pattern)
                    if ffn == "moe") + self.mtp_depth
 
 
+def is_period(entry) -> bool:
+    """An entry of the layer pattern is a run ``(attention, ffn, count)``
+    or a period ``((run, ...), repeats)``."""
+    return len(entry) == 2
+
+
+def _checked_entry(entry):
+    if is_period(entry):
+        runs, repeats = entry
+        runs = tuple(_checked_entry(run) for run in runs)
+        if repeats < 1 or not runs or any(is_period(run) for run in runs):
+            raise ValueError(f"layer pattern period {entry!r}")
+        return runs, repeats
+    attention, ffn, count = entry
+    if attention not in _ATTENTION or ffn not in _FFN or count < 1:
+        raise ValueError(f"layer pattern run {attention!r}, {ffn!r}, {count}")
+    return attention, ffn, count
+
+
+def runs_of(pattern):
+    """Every run of the pattern in the order the layers come, a period's
+    runs once a repeat: ``(attention, ffn, count)``."""
+    for entry in pattern:
+        if is_period(entry):
+            runs, repeats = entry
+            for _ in range(repeats):
+                yield from runs
+        else:
+            yield entry
+
+
 def stacks_of(layers) -> Tuple[Dict, ...]:
-    """``params["layers"]`` as a tuple of stacks, one a run of the
-    layer pattern."""
+    """``params["layers"]`` as a tuple, one element an entry of the
+    layer pattern: a run's stack, or a period's tuple of stacks."""
     return (layers,) if isinstance(layers, dict) else tuple(layers)
 
 
@@ -181,30 +239,38 @@ def init_stack(key: jax.Array, cfg: TransformerConfig, attention: str,
     def stacked(key, shape):
         return init(key, (nl,) + shape, jnp.float32).astype(cfg.dtype)
 
+    # a norm's weight at the start: 1, or 0 where it scales by 1 + w
+    unit = jnp.zeros if cfg.norm_plus_one else jnp.ones
     layers: Dict = {
-        "ln1": jnp.ones((nl, d), jnp.float32),
-        "ln2": jnp.ones((nl, d), jnp.float32),
+        "ln1": unit((nl, d), jnp.float32),
+        "ln2": unit((nl, d), jnp.float32),
     }
     if attention == "mla":
         from ray_tpu.models.mla import init_mla_params
         layers["mla"] = init_mla_params(jax.random.fold_in(key, 9), nl, d,
                                         h, cfg.mla, cfg.dtype)
+    elif attention == "gdn":
+        from ray_tpu.models.gdn import init_gdn_params
+        layers["gdn"] = init_gdn_params(jax.random.fold_in(key, 10), nl, d,
+                                        cfg.gdn, cfg.dtype)
     else:
         layers.update({
-            "wq": stacked(lkeys[0], (d, h, dh)),
+            "wq": stacked(lkeys[0], (d, h, (2 if cfg.attn_out_gate else 1)
+                                     * dh)),
             "wk": stacked(lkeys[1], (d, kv, dh)),
             "wv": stacked(lkeys[2], (d, kv, dh)),
             "wo": stacked(lkeys[3], (h, dh, d)),
         })
         if cfg.qk_norm:
-            layers["q_norm"] = jnp.ones((nl, dh), jnp.float32)
-            layers["k_norm"] = jnp.ones((nl, dh), jnp.float32)
+            layers["q_norm"] = unit((nl, dh), jnp.float32)
+            layers["k_norm"] = unit((nl, dh), jnp.float32)
     if ffn == "moe":
         from ray_tpu.models.moe import init_moe_params
         held = cfg.moe_experts_held or (0, cfg.moe_experts)
         layers["moe"] = init_moe_params(
             jax.random.fold_in(key, 8), nl, d, cfg.moe_d_ff or f,
-            cfg.moe_experts, held[1], cfg.dtype, cfg.moe_shared_width)
+            cfg.moe_experts, held[1], cfg.dtype, cfg.moe_shared_width,
+            cfg.moe_shared_gate)
     else:
         layers.update({
             "w1": stacked(lkeys[4], (d, f)),
@@ -219,16 +285,17 @@ def init_params(rng: jax.Array, cfg: TransformerConfig) -> Dict:
     d = cfg.d_model
     init = jax.nn.initializers.normal(0.02)
     pattern = cfg.layer_pattern
-    if len(pattern) == 1:
+    if len(pattern) == 1 and not is_period(pattern[0]):
         layers = init_stack(k_layers, cfg, *pattern[0])
     else:
-        layers = tuple(init_stack(jax.random.fold_in(k_layers, 16 + i), cfg,
-                                  *run) for i, run in enumerate(pattern))
+        layers = tuple(_init_entry(jax.random.fold_in(k_layers, 16 + i), cfg,
+                                   entry) for i, entry in enumerate(pattern))
     params = {
         "embed": init(k_embed, (cfg.vocab_size, d), jnp.float32
                       ).astype(cfg.dtype),
         "layers": layers,
-        "ln_f": jnp.ones((d,), jnp.float32),
+        "ln_f": (jnp.zeros if cfg.norm_plus_one else jnp.ones)(
+            (d,), jnp.float32),
         "lm_head": init(k_head, (d, cfg.vocab_size), jnp.float32
                         ).astype(cfg.dtype),
     }
@@ -236,6 +303,19 @@ def init_params(rng: jax.Array, cfg: TransformerConfig) -> Dict:
         from ray_tpu.models.mtp import init_mtp_params
         params["mtp"] = init_mtp_params(jax.random.fold_in(rng, 3), cfg)
     return params
+
+
+def _init_entry(key, cfg: TransformerConfig, entry):
+    """A run's stack, or a period's: its runs' stacks with the periods
+    as a further leading axis."""
+    if not is_period(entry):
+        return init_stack(key, cfg, *entry)
+    runs, repeats = entry
+    return tuple(jax.tree.map(
+        lambda a, count=count: a.reshape(repeats, count, *a.shape[1:]),
+        init_stack(jax.random.fold_in(key, i), cfg, attention, ffn,
+                   repeats * count))
+        for i, (attention, ffn, count) in enumerate(runs))
 
 
 def stack_specs(cfg: TransformerConfig, attention: str, ffn: str) -> Dict:
@@ -248,6 +328,9 @@ def stack_specs(cfg: TransformerConfig, attention: str, ffn: str) -> Dict:
     if attention == "mla":
         from ray_tpu.models.mla import mla_param_specs
         layers["mla"] = mla_param_specs()
+    elif attention == "gdn":
+        from ray_tpu.models.gdn import gdn_param_specs
+        layers["gdn"] = gdn_param_specs()
     else:
         layers.update({
             "wq": P(None, None, "tp", None),
@@ -260,7 +343,8 @@ def stack_specs(cfg: TransformerConfig, attention: str, ffn: str) -> Dict:
             layers["k_norm"] = P(None, None)
     if ffn == "moe":
         from ray_tpu.models.moe import moe_param_specs
-        layers["moe"] = moe_param_specs(shared=cfg.moe_shared_width > 0)
+        layers["moe"] = moe_param_specs(shared=cfg.moe_shared_width > 0,
+                                        shared_gate=cfg.moe_shared_gate)
     else:
         layers.update({
             "w1": P(None, None, "tp"),
@@ -272,11 +356,20 @@ def stack_specs(cfg: TransformerConfig, attention: str, ffn: str) -> Dict:
 
 def param_specs(cfg: TransformerConfig) -> Dict:
     """PartitionSpecs following the layer pattern; vocab on lm_head."""
-    stacks = tuple(stack_specs(cfg, attention, ffn)
-                   for attention, ffn, _ in cfg.layer_pattern)
+    def entry_specs(entry):
+        if not is_period(entry):
+            return stack_specs(cfg, *entry[:2])
+        # the periods are one more leading axis, not sharded
+        return tuple(jax.tree.map(
+            lambda spec: P(None, *spec), stack_specs(cfg, attention, ffn),
+            is_leaf=lambda x: isinstance(x, P))
+            for attention, ffn, _ in entry[0])
+
+    stacks = tuple(entry_specs(entry) for entry in cfg.layer_pattern)
     specs = {
         "embed": P(None, "tp"),
-        "layers": stacks[0] if len(stacks) == 1 else stacks,
+        "layers": (stacks[0] if len(stacks) == 1
+                   and not is_period(cfg.layer_pattern[0]) else stacks),
         "ln_f": P(None),
         "lm_head": P(None, "tp"),
     }
@@ -296,8 +389,19 @@ def _rms_norm(x, w, eps):
     return (norm * w).astype(x.dtype)
 
 
-def _rope(x, positions, theta):
-    # x: [B, S, H, D]; rotate pairs.
+def norm_weight(w, cfg: TransformerConfig):
+    """A model norm's weight as it scales: ``1 + w`` under
+    ``norm_plus_one``."""
+    return w + 1.0 if cfg.norm_plus_one else w
+
+
+def _rope(x, positions, theta, rotary_dim: int = 0):
+    # x: [B, S, H, D]; rotate pairs: of all D columns, or of the first
+    # ``rotary_dim`` with the others passed through.
+    if rotary_dim and rotary_dim < x.shape[-1]:
+        return jnp.concatenate(
+            [_rope(x[..., :rotary_dim], positions, theta),
+             x[..., rotary_dim:]], axis=-1)
     d = x.shape[-1]
     half = d // 2
     freqs = jnp.exp(-jnp.log(theta) *
@@ -339,10 +443,16 @@ def _moe_block(h, lp, cfg: TransformerConfig, mesh):
     else:
         y, stats = moe.moe_ffn(h, lp, cfg.moe_top_k, cfg.moe_norm_topk,
                                held=cfg.moe_experts_held, **router)
+    gated = {}
     if cfg.moe_shared_width:
         # once, whoever holds which experts
-        y = y + moe.shared_expert(h, lp)
-    counted = moe.counters(stats, with_load="bias" in lp)
+        shared = moe.shared_expert(h, lp)
+        if cfg.moe_shared_gate:
+            gate = moe.shared_gate(h, lp)
+            shared = shared * gate
+            gated["moe_shared_gate_mean"] = jnp.mean(gate.astype(jnp.float32))
+        y = y + shared
+    counted = {**moe.counters(stats, with_load="bias" in lp), **gated}
     if cfg.moe_report_choices:
         counted["moe_choices"] = stats["choices"]
     return y, counted
@@ -350,18 +460,27 @@ def _moe_block(h, lp, cfg: TransformerConfig, mesh):
 
 def _mha(h, lp, positions, cfg: TransformerConfig, mesh, mask):
     """Rotary multi-head / grouped attention on the layer's normed
-    input -> what it adds to the residual."""
+    input -> (what it adds to the residual, what it counted: the mean
+    output gate where it has one)."""
     eps = cfg.norm_eps
     q = jnp.einsum("bsd,dhk->bshk", h, lp["wq"])
     k = jnp.einsum("bsd,dhk->bshk", h, lp["wk"])
     v = jnp.einsum("bsd,dhk->bshk", h, lp["wv"])
+    if cfg.attn_out_gate:
+        q, gate = q[..., :cfg.head_dim], q[..., cfg.head_dim:]
     if cfg.qk_norm:
-        q = _rms_norm(q, lp["q_norm"], eps)
-        k = _rms_norm(k, lp["k_norm"], eps)
-    q = _rope(q, positions, cfg.rope_theta)
-    k = _rope(k, positions, cfg.rope_theta)
+        q = _rms_norm(q, norm_weight(lp["q_norm"], cfg), eps)
+        k = _rms_norm(k, norm_weight(lp["k_norm"], cfg), eps)
+    q = _rope(q, positions, cfg.rope_theta, cfg.rotary_dim)
+    k = _rope(k, positions, cfg.rope_theta, cfg.rotary_dim)
     o = _attention_core(q, k, v, mesh, cfg, mask)
-    return jnp.einsum("bshk,hkd->bsd", o, lp["wo"])
+    counted = {}
+    if cfg.attn_out_gate:
+        with jax.named_scope("attn_gate"):
+            gate = jax.nn.sigmoid(gate.astype(jnp.float32))
+            o = (o.astype(jnp.float32) * gate).astype(o.dtype)
+            counted["attn_gate_mean"] = jnp.mean(gate)
+    return jnp.einsum("bshk,hkd->bsd", o, lp["wo"]), counted
 
 
 def _dense_ffn(h, lp):
@@ -380,20 +499,28 @@ def apply_layer(x, lp, positions, cfg: TransformerConfig, mesh=None,
     pipeline-parallel stage executor."""
     # The named scopes here and in loss_fn / train_step are metadata
     # only: stable names for a device trace to group time by.
-    attention, ffn = kind or cfg.layer_pattern[0][:2]
+    attention, ffn = kind or next(runs_of(cfg.layer_pattern))[:2]
     eps = cfg.norm_eps
+    counted = {}
     with jax.named_scope("attention"):
-        h = _rms_norm(x, lp["ln1"], eps)
+        h = _rms_norm(x, norm_weight(lp["ln1"], cfg), eps)
         if attention == "mla":
             from ray_tpu.models.mla import mla_attention
             x = x + mla_attention(h, lp["mla"], positions, cfg, mesh, mask)
+        elif attention == "gdn":
+            from ray_tpu.models.gdn import gdn_attention
+            if mask != CAUSAL:
+                raise ValueError("a delta layer is causal")
+            y, counted = gdn_attention(h, lp["gdn"], cfg, mesh)
+            x = x + y
         else:
-            x = x + _mha(h, lp, positions, cfg, mesh, mask)
-    counted = {}
+            y, counted = _mha(h, lp, positions, cfg, mesh, mask)
+            x = x + y
     with jax.named_scope("ffn"):
-        h = _rms_norm(x, lp["ln2"], eps)
+        h = _rms_norm(x, norm_weight(lp["ln2"], cfg), eps)
         if ffn == "moe":
-            y, counted = _moe_block(h, lp["moe"], cfg, mesh)
+            y, moe_counted = _moe_block(h, lp["moe"], cfg, mesh)
+            counted = {**counted, **moe_counted}
             x = x + y
         else:
             x = x + _dense_ffn(h, lp)
@@ -431,18 +558,49 @@ def run_stack(x, stack: Dict, kind, positions, cfg: TransformerConfig,
     return jax.lax.scan(remat_layer(layer, cfg), x, (stack, moe_bias))
 
 
-def run_stacks(x, layers, positions, cfg: TransformerConfig, mesh=None,
+def run_period(x, stacks, runs, positions, cfg: TransformerConfig, mesh=None,
                mask=CAUSAL, moe_bias=None):
-    """Every run of the layer pattern in turn -> (x, [what the layers of
-    each run counted, stacked by layer])."""
+    """The scan over a period's repeats, its body the runs' own scans ->
+    (x, what the layers counted, stacked by layer in the order they
+    come).  ``stacks``: a stack a run, ``[repeats, count, ...]``;
+    ``moe_bias`` [expert layers of the entry, E]."""
+    repeats = jax.tree.leaves(stacks)[0].shape[0]
+    if moe_bias is not None:
+        moe_bias = moe_bias.reshape(repeats, -1, moe_bias.shape[-1])
+
+    def period(x, scanned):
+        return run_stacks(x, scanned[0], positions, cfg, mesh, mask,
+                          scanned[1], pattern=runs)
+
+    x, counted = jax.lax.scan(period, x, (tuple(stacks), moe_bias))
+    # [repeats, layers of a run that count it, ...] a run -> by layer
+    by_layer = {}
+    for name in sorted({name for c in counted for name in c}):
+        v = jnp.concatenate([c[name] for c in counted if name in c], axis=1)
+        by_layer[name] = v.reshape(-1, *v.shape[2:])
+    return x, by_layer
+
+
+def run_stacks(x, layers, positions, cfg: TransformerConfig, mesh=None,
+               mask=CAUSAL, moe_bias=None, pattern=None):
+    """Every entry of the layer pattern in turn (``pattern``: of a
+    period's runs, with ``layers`` their stacks) -> (x, [what the layers
+    of each entry counted, stacked by layer])."""
+    if pattern is None:
+        pattern, layers = cfg.layer_pattern, stacks_of(layers)
     counted, row = [], 0
-    for stack, (attention, ffn, count) in zip(stacks_of(layers),
-                                              cfg.layer_pattern):
+    for stack, entry in zip(layers, pattern):
         bias = None
-        if moe_bias is not None and ffn == "moe":
-            bias, row = moe_bias[row:row + count], row + count
-        x, c = run_stack(x, stack, (attention, ffn), positions, cfg, mesh,
-                         mask, bias)
+        rows = sum(count for _, ffn, count in runs_of((entry,))
+                   if ffn == "moe")
+        if moe_bias is not None and rows:
+            bias, row = moe_bias[row:row + rows], row + rows
+        if is_period(entry):
+            x, c = run_period(x, stack, entry[0], positions, cfg, mesh, mask,
+                              bias)
+        else:
+            x, c = run_stack(x, stack, entry[:2], positions, cfg, mesh, mask,
+                             bias)
         counted.append(c)
     return x, counted
 
@@ -502,7 +660,7 @@ def forward_with_counters(params: Dict, tokens: jax.Array,
     x, counters = run_layers(params, tokens, positions, cfg, mesh,
                              moe_bias=moe_bias)
     with jax.named_scope("head_loss"):
-        x = _rms_norm(x, params["ln_f"], cfg.norm_eps)
+        x = _rms_norm(x, norm_weight(params["ln_f"], cfg), cfg.norm_eps)
         logits = jnp.einsum("bsd,dv->bsv", x, params["lm_head"])
     return logits, counters
 

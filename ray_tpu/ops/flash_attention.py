@@ -28,7 +28,9 @@ to HBM in either direction.  In both:
     and only the tiles the diagonal crosses are masked.
 
 (K/V blocked through the grid for L = 32k is ROADMAP R-M4: the forward
-holds K and V whole per head, the backward q, dout and dq.)
+holds K and V whole per head, the backward q, dout and dq; at 256-wide
+heads L = 8,192 is the longest row both hold: 16.8 MB of K and V in the
+forward, some 50 MB of the backward's 64.)
 
 What is attended to is a static description (``ops/attention_mask.py``:
 ``CAUSAL``, ``FULL``, ``BlockDiffusion(seq_len, block)``): the kernels
@@ -110,12 +112,14 @@ _BWD_BLOCKS = (512, 256, 128)
 # scratch and a few [block, block] float32 tiles: 13 MB at L = 4096,
 # D = 128 bf16, over the 16 MB a kernel gets unasked (the chip has 128).
 _BWD_VMEM_BYTES = 64 * 2 ** 20
-# With a rotary part the forward holds three operands whole per head
-# (double-buffered): k, v and the shared rotary key, whose 64 columns
-# take a whole 128-lane tile: 12 MB at L = 8192, D = 128 bf16, beside
-# the q and out blocks and a few [block, block] float32 tiles, over the
-# 16 MB a kernel gets unasked.  Asked for with the rotary part only: the
-# kernel without it is compiled as it was.
+# The forward holds K, V and, with a rotary part, the shared rotary key
+# whole per head, double-buffered (a 64-column key takes a whole
+# 128-lane tile), beside the q and out blocks and a few [block, block]
+# float32 tiles.  What they come to decides the limit: 8 MB (K and V at
+# L = 8192, D = 128 bf16) fits the 16 MB a kernel gets unasked and is
+# compiled as it was; 12 MB (latent attention's three at L = 8192) and
+# 16.8 MB (K and V at L = 8192, D = 256) get this limit instead.
+_FWD_UNASKED_BYTES = 10 * 2 ** 20
 _FWD_VMEM_BYTES = 64 * 2 ** 20
 
 
@@ -202,7 +206,7 @@ def _flash_forward(qh, kh, vh, rope, mask, block_q, block_k, interpret):
         pl.BlockSpec((None, L, D), lambda b, i: (b // group, 0, 0)),
         pl.BlockSpec((None, L, Dv), lambda b, i: (b // group, 0, 0)),
     ]
-    operands, rotary, limit = (qh, kh, vh), 0, None
+    operands, rotary = (qh, kh, vh), 0
     if rope is not None:
         rotary = rope[0].shape[-1]
         heads = BH // rope[1].shape[0]           # query heads a row
@@ -212,7 +216,11 @@ def _flash_forward(qh, kh, vh, rope, mask, block_q, block_k, interpret):
             # fetched once for all of them
             pl.BlockSpec((None, L, rotary), lambda b, i: (b // heads, 0, 0)),
         ]
-        operands, limit = operands + tuple(rope), _FWD_VMEM_BYTES
+        operands = operands + tuple(rope)
+    # the whole operands, double-buffered, a column group a lane tile
+    held = 2 * L * qh.dtype.itemsize * sum(
+        -(-width // 128) * 128 for width in (D, Dv, rotary) if width)
+    limit = _FWD_VMEM_BYTES if held > _FWD_UNASKED_BYTES else None
     kernel = functools.partial(_flash_kernel, block_k=block_k, mask=mask,
                                scale=(D + rotary) ** -0.5)
     out, lse = pl.pallas_call(
@@ -495,7 +503,10 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array,
 def attention(q: jax.Array, k: jax.Array, v: jax.Array, mask=CAUSAL,
               q_rope: jax.Array | None = None,
               k_rope: jax.Array | None = None) -> jax.Array:
-    """Backend dispatch: pallas kernel on TPU, jnp reference elsewhere."""
+    """Backend dispatch: pallas kernel on TPU, jnp reference elsewhere.
+    The forward kernel asks for a VMEM limit by the bytes it holds whole
+    (K, V and the rotary key where there is one: ``_FWD_UNASKED_BYTES``),
+    not by which operands came."""
     from ray_tpu.ops.ring_attention import full_attention
     # Trace-time decision: the backend is fixed per process.
     if (jax.default_backend() == "tpu" and q.shape[1] % 128 == 0

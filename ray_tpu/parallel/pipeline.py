@@ -25,8 +25,8 @@ import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
 from ray_tpu.models.transformer import (TransformerConfig, _rms_norm,
-                                        apply_layer, param_specs,
-                                        remat_layer)
+                                        apply_layer, is_period, norm_weight,
+                                        param_specs, remat_layer)
 
 
 def pp_param_specs(cfg: TransformerConfig) -> Dict:
@@ -57,7 +57,8 @@ def make_pp_loss_fn(cfg: TransformerConfig, mesh, n_micro: int):
     # contractions would need in-body psums / ep constraints).
     assert mesh.shape.get("tp", 1) == 1, "pp does not compose with tp"
     assert mesh.shape.get("ep", 1) == 1, "pp does not compose with ep"
-    assert len(cfg.layer_pattern) == 1, \
+    assert len(cfg.layer_pattern) == 1 \
+        and not is_period(cfg.layer_pattern[0]), \
         "a stage scans one kind of layer: no mixed layer pattern"
     assert cfg.moe_experts == 0, \
         "MoE composes with ep, not pp (its counters are not plumbed here)"
@@ -84,7 +85,8 @@ def make_pp_loss_fn(cfg: TransformerConfig, mesh, n_micro: int):
 
         def ce(h, tgt):
             logits = jnp.einsum(
-                "bsd,dv->bsv", _rms_norm(h, lnf, cfg.norm_eps),
+                "bsd,dv->bsv",
+                _rms_norm(h, norm_weight(lnf, cfg), cfg.norm_eps),
                 head).astype(jnp.float32)
             logz = jax.nn.logsumexp(logits, axis=-1)
             gold = jnp.take_along_axis(

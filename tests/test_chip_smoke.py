@@ -80,6 +80,23 @@ def test_leg_latent_trainer(smoke):
     assert facts["latent_flash_bwd_max_rel_err"] <= 1e-4
 
 
+def test_leg_hybrid_trainer(smoke):
+    facts = smoke.leg_hybrid_trainer(
+        platform="cpu", batch=2, seq=128, steps=2, dtype="float32",
+        rule_tol=1e-4,
+        model=dict(smoke.HYBRID_MODEL, vocab_size=256, d_model=64, n_heads=2,
+                   head_dim=32, d_ff=32, max_seq_len=128, rotary_dim=8,
+                   moe_experts=8, moe_top_k=2, moe_experts_held=(2, 4),
+                   moe_shared_width=32,
+                   gdn=dict(num_key_heads=2, num_value_heads=4,
+                            key_head_dim=16, value_head_dim=16,
+                            conv_kernel=4, chunk=32)))
+    assert facts["losses"][-1] < facts["losses"][0]
+    assert facts["gated_delta_calls_in_step"] == [0, 0]   # no Mosaic call
+    assert facts["counters"]["moe_dropped_choices"] == 0.0
+    assert facts["gated_delta_bwd_max_rel_err"] <= 1e-4
+
+
 def test_leg_sharded_solve(smoke):
     facts = smoke.leg_sharded_solve(platform="cpu", nodes=4096, classes=8,
                                     num_tasks=5000, tick_specs=256)

@@ -680,6 +680,78 @@ def test_latent_attention_gradient_compiles_for_the_chip_at_the_cell_width(
     assert " while(" not in text
 
 
+def test_gated_attention_gradient_compiles_for_the_chip_at_the_cell_width(
+        one_v5e_chip):
+    """Both kernels at the hybrid cell's attention shape: 2 rows x 8,192
+    positions, 16 query heads on 2 K/V heads of 256, bfloat16.  K and V
+    whole are 16.8 MB double-buffered, over what a kernel gets unasked:
+    the forward asks by the bytes it holds (``_FWD_UNASKED_BYTES``), no
+    rotary pair in sight."""
+    q = jax.ShapeDtypeStruct((2, 8192, 16, 256), jnp.bfloat16,
+                             sharding=one_v5e_chip)
+    kv = jax.ShapeDtypeStruct((2, 8192, 2, 256), jnp.bfloat16,
+                              sharding=one_v5e_chip)
+    gradient = jax.jit(jax.grad(lambda q, k, v: jnp.sum(flash_attention(
+        q, k, v).astype(jnp.float32)), (0, 1, 2)))
+    jaxpr = jax.make_jaxpr(gradient)(q, kv, kv).jaxpr
+    limits = [eqn.params["compiler_params"]["mosaic_tpu"].vmem_limit_bytes
+              for eqn, _ in _equations(jaxpr)
+              if eqn.primitive.name == "pallas_call"]
+    assert limits == [64 * 2 ** 20, 64 * 2 ** 20]
+    text = gradient.lower(q, kv, kv).compile().as_text()
+    assert text.count("tpu_custom_call") == 2
+    assert _kernel_calls(text) == (1, 1)
+
+
+def test_the_forward_asks_for_vmem_by_the_bytes_it_holds():
+    """8 MB of K and V (the block-diffusion cell) is compiled as it was,
+    12 MB with the rotary key (the latent cell) and 16.8 MB of 256-wide
+    K and V ask."""
+    def limit(q, kv, v=None, rope=None):
+        shapes = [jax.ShapeDtypeStruct(s, jnp.bfloat16)
+                  for s in (q, kv, v or kv) + (rope or ())]
+
+        def call(q, k, v, *rope):
+            return flash_attention(q, k, v, **dict(zip(("q_rope", "k_rope"),
+                                                       rope)))
+        (eqn,) = [e for e, _ in _equations(jax.make_jaxpr(call)(*shapes).jaxpr)
+                  if e.primitive.name == "pallas_call"]
+        return eqn.params["compiler_params"]["mosaic_tpu"].vmem_limit_bytes
+
+    assert limit((4, 4096, 16, 128), (4, 4096, 16, 128)) is None
+    assert limit((4, 8192, 32, 128), (4, 8192, 4, 128)) is None
+    assert limit((2, 8192, 32, 128), (2, 8192, 32, 128),
+                 rope=((2, 8192, 32, 64), (2, 8192, 64))) == 64 * 2 ** 20
+    assert limit((2, 8192, 16, 256), (2, 8192, 2, 256)) == 64 * 2 ** 20
+
+
+def _delta_calls(text):
+    return tuple(len(re.findall(
+        rf"%{name}[.\d]* = [^\n]*custom_call_target=\"tpu_custom_call\"",
+        text)) for name in ("gated_delta_fwd", "gated_delta_bwd"))
+
+
+def test_gated_delta_gradient_compiles_for_the_chip_at_the_cell_width(
+        one_v5e_chip):
+    """Mosaic takes both state-pass kernels at the hybrid cell's delta
+    layers' shape (2 rows x 8,192 positions, 32 value heads of 128 x 128,
+    chunks of 64, bfloat16): kept in this file because one process may
+    describe the chip (``one_v5e_chip``)."""
+    from ray_tpu.ops.gated_delta import gated_delta_rule
+
+    def spec(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_v5e_chip)
+
+    wide, gate = spec((2, 8192, 32, 128)), spec((2, 8192, 32), jnp.float32)
+    text = jax.jit(jax.grad(lambda q, k, v, g, beta: jnp.sum(
+        gated_delta_rule(q, k, v, g, beta, use_pallas=True).astype(
+            jnp.float32)), (0, 1, 2, 3, 4))).lower(
+                wide, wide, wide, gate, gate).compile().as_text()
+    assert _delta_calls(text) == (1, 1)
+    assert text.count("tpu_custom_call") == 2
+    assert " while(" not in text
+
+
 @pytest.mark.parametrize("how,wrap,want", [
     ("kept", _keeping_the_residuals, (1, 1)),
     ("plain", jax.checkpoint, (2, 1))])

@@ -1,0 +1,150 @@
+"""The gated delta rule: the chunked form against the recurrence it
+stands for, the two kernels (interpret mode) against the chunked scan,
+and a row that holds two documents.
+
+Tolerances.  Everything here is float32 on the CPU: the chunked form and
+the recurrence differ by summation order and by the inverse's products,
+1e-5 of the largest value at these sizes (5e-5 asked); the kernels and
+the scan compute the same products in the same order (2e-6 asked).
+"""
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from ray_tpu.ops import gated_delta
+from ray_tpu.ops.gated_delta import gated_delta_rule
+
+NAMES = ("q", "k", "v", "g", "beta")
+
+
+def recurrence(q, k, v, g, beta):
+    """Token by token, as the rule is written: [B, L, H, D] in float32."""
+    def head(q, k, v, g, beta):                     # [L, D], [L]
+        def step(s, x):
+            q, k, v, g, beta = x
+            s = jnp.exp(g) * s
+            s = s + jnp.outer(k, beta * (v - s.T @ k))
+            return s, s.T @ q
+        zero = jnp.zeros((q.shape[-1], v.shape[-1]), jnp.float32)
+        return jax.lax.scan(step, zero, (q, k, v, g, beta))[1]
+
+    over_heads = jax.vmap(head, in_axes=(1, 1, 1, 1, 1), out_axes=1)
+    with jax.default_matmul_precision("highest"):
+        return jax.vmap(over_heads)(q, k, v, g, beta)
+
+
+def inputs(seed, length, decay, b=2, h=3, dk=16, dv=8):
+    """q and k of unit length (q scaled as the layer scales it), ``g``
+    from a per-head rate: ``decay`` "fast" forgets within a position or
+    two, "slow" hardly within the row, "mixed" has heads of each."""
+    keys = jax.random.split(jax.random.PRNGKey(seed), 6)
+    unit = lambda x: x / jnp.linalg.norm(x, axis=-1, keepdims=True)
+    q = unit(jax.random.normal(keys[0], (b, length, h, dk))) * dk ** -0.5
+    k = unit(jax.random.normal(keys[1], (b, length, h, dk)))
+    v = jax.random.normal(keys[2], (b, length, h, dv))
+    rate = {"fast": jnp.full((h,), 12.0), "slow": jnp.full((h,), 1e-3),
+            "mixed": jnp.array([1e-3, 0.3, 12.0])[:h]}[decay]
+    g = -rate * jax.nn.softplus(jax.random.normal(keys[3], (b, length, h)))
+    beta = jax.nn.sigmoid(2.0 * jax.random.normal(keys[4], (b, length, h)))
+    return (q, k, v, g, beta), jax.random.normal(keys[5], (b, length, h, dv))
+
+
+def close(a, b, tol):
+    scale = float(jnp.max(jnp.abs(b))) + 1e-30
+    np.testing.assert_allclose(np.asarray(a) / scale, np.asarray(b) / scale,
+                               atol=tol, rtol=0)
+
+
+@pytest.mark.parametrize("length", [64, 128, 320])
+@pytest.mark.parametrize("decay", ["fast", "slow", "mixed"])
+def test_chunked_is_the_recurrence_values_and_all_five_gradients(length,
+                                                                 decay):
+    args, dout = inputs(length, length, decay)
+    chunked = lambda *a: gated_delta_rule(*a, use_pallas=False)
+    close(chunked(*args), recurrence(*args), 5e-5)
+    want = jax.grad(lambda *a: jnp.sum(recurrence(*a) * dout),
+                    argnums=range(5))(*args)
+    got = jax.grad(lambda *a: jnp.sum(chunked(*a) * dout),
+                   argnums=range(5))(*args)
+    for name, a, b in zip(NAMES, got, want):
+        assert bool(jnp.all(jnp.isfinite(a))), name
+        close(a, b, 5e-5)
+
+
+@pytest.mark.parametrize("length,chunk", [(64, 64), (320, 64), (512, 16)])
+def test_both_kernels_interpreted_are_the_chunked_scan(length, chunk):
+    """Values, all five gradients and the counter's state: one chunk,
+    five chunks a block of one, 32 chunks in blocks of 8."""
+    args, dout = inputs(7 + length, length, "mixed")
+    scan = lambda *a: gated_delta_rule(*a, chunk=chunk, use_pallas=False,
+                                       with_state=True)
+    kernels = lambda *a: gated_delta_rule(*a, chunk=chunk, use_pallas=True,
+                                          interpret=True, with_state=True)
+    for a, b in zip(kernels(*args), scan(*args)):
+        close(a, b, 2e-6)
+    want = jax.grad(lambda *a: jnp.sum(scan(*a)[0] * dout),
+                    argnums=range(5))(*args)
+    got = jax.grad(lambda *a: jnp.sum(kernels(*a)[0] * dout),
+                   argnums=range(5))(*args)
+    for name, a, b in zip(NAMES, got, want):
+        close(a, b, 2e-6)
+
+
+def test_the_kernels_carry_their_names_into_the_traced_program():
+    args, dout = inputs(3, 128, "mixed")
+    text = str(jax.make_jaxpr(jax.grad(lambda *a: jnp.sum(gated_delta_rule(
+        *a, use_pallas=True) * dout), argnums=range(5)))(*args))
+    assert "gated_delta_fwd" in text and "gated_delta_bwd" in text
+
+
+def test_bfloat16_operands_stay_near_the_float32_rule():
+    """The operands' rounding only: the state and the inverse stay
+    float32 (a state kept in bfloat16 is 10 times further off)."""
+    args, _ = inputs(11, 256, "mixed")
+    want = recurrence(*args)
+    low = [a.astype(jnp.bfloat16) for a in args[:3]] + list(args[3:])
+    got = gated_delta_rule(*low, use_pallas=True, interpret=True)
+    assert got.dtype == jnp.bfloat16
+    close(got.astype(jnp.float32), want, 2e-2)
+
+
+def test_the_state_crosses_a_separator():
+    """A row of two documents is neither document alone: the second
+    half's outputs see the state the first half left (as attention sees
+    its keys); the first half is what it is alone."""
+    (q, k, v, g, beta), _ = inputs(5, 128, "slow")
+    run = lambda s: gated_delta_rule(q[:, s], k[:, s], v[:, s], g[:, s],
+                                     beta[:, s], use_pallas=False)
+    whole = run(slice(0, 128))
+    first, second = run(slice(0, 64)), run(slice(64, 128))
+    close(whole[:, :64], first, 5e-6)
+    assert float(jnp.max(jnp.abs(whole[:, 64:] - second))) > 1e-2 * float(
+        jnp.max(jnp.abs(second)))
+
+
+def test_the_inverse_is_the_inverse_where_rows_are_alike():
+    """Identical keys and strong writes: the strictly lower block is all
+    ones, whose powers reach 1e17 at 64 rows; the halved inverse is the
+    bidiagonal one to rounding, and its hand-written gradient is
+    autodiff's."""
+    n = 64
+    a = jnp.tril(jnp.ones((n, n), jnp.float32), -1)
+    t = gated_delta.unit_lower_inverse(a)
+    want = jnp.eye(n) - jnp.eye(n, k=-1)
+    np.testing.assert_allclose(np.asarray(t), np.asarray(want), atol=1e-3)
+    a = 0.3 * jnp.tril(jax.random.normal(jax.random.PRNGKey(0), (2, n, n)),
+                       -1)
+    dt = jax.random.normal(jax.random.PRNGKey(1), (2, n, n))
+    by_hand = jax.grad(lambda a: jnp.sum(
+        gated_delta.unit_lower_inverse(a) * dt))(a)
+    by_jax = jax.grad(lambda a: jnp.sum(jnp.linalg.inv(
+        jnp.eye(n) + a) * dt))(a)
+    close(by_hand, by_jax, 1e-4)
+
+
+def test_a_row_that_is_no_whole_number_of_chunks_is_refused():
+    args, _ = inputs(1, 96, "slow")
+    with pytest.raises(ValueError, match="chunks of 64"):
+        gated_delta_rule(*args, use_pallas=False)

@@ -271,6 +271,77 @@ def test_mixed_stack_latent_attention_and_shared_expert_on_the_mesh():
         assert float(metrics["moe_dropped_choices"]) == 0.0
 
 
+def test_hybrid_stack_delta_layers_and_gated_shared_expert_on_the_mesh():
+    """Two periods of (delta layer, gated-attention layer) with the
+    delta layers' key heads -- and the value heads, convolution taps,
+    decays and output rows that belong to them -- over ``tp`` and the
+    experts over ``ep``: loss, gradients and the step's counters equal
+    the unsharded step's.  The gated shared expert is outside the
+    shards' ``psum``: counted once.  Over ``sp`` a delta layer raises."""
+    import dataclasses
+
+    import numpy as np
+
+    from ray_tpu.models.gdn import GDNConfig
+    from ray_tpu.models.transformer import (TransformerConfig,
+                                            loss_and_counters,
+                                            make_train_state,
+                                            make_train_step)
+    from ray_tpu.parallel.mesh import MeshConfig, build_mesh
+    cfg = TransformerConfig(
+        vocab_size=64, d_model=32, n_heads=4, n_kv_heads=2, head_dim=16,
+        d_ff=16, dtype=jnp.float32, remat=True, context_parallel=False,
+        norm_eps=1e-6, qk_norm=True, norm_plus_one=True, attn_out_gate=True,
+        rotary_dim=4, gdn=GDNConfig(2, 4, 8, 8, 4, 16),
+        layer_pattern=(((("gdn", "moe", 1), ("mha", "moe", 1)), 2),),
+        moe_experts=8, moe_top_k=2, moe_shared_width=16,
+        moe_shared_gate=True, moe_aux_coeff=0.001)
+    tokens = jax.random.randint(jax.random.PRNGKey(1), (4, 33), 0, 64,
+                                dtype=jnp.int32)
+    batch = {"tokens": tokens}
+    plain_state, plain_tx = make_train_state(jax.random.PRNGKey(0), cfg)
+    want, want_grad = jax.jit(jax.value_and_grad(
+        lambda p: loss_and_counters(p, batch, cfg)[0]))(plain_state["params"])
+    over_sp = dataclasses.replace(cfg, context_parallel=True)
+    ring = build_mesh(MeshConfig(dp=2, sp=2), devices=jax.devices()[:4])
+    with ring, pytest.raises(ValueError, match="sp axis"):
+        loss_and_counters(plain_state["params"], batch, over_sp, ring)
+    _, plain = make_train_step(cfg, plain_tx)(plain_state, batch)
+    mesh = build_mesh(MeshConfig(dp=2, tp=2, ep=2), devices=jax.devices()[:8])
+    with mesh:
+        state, tx = make_train_state(jax.random.PRNGKey(0), cfg, mesh=mesh)
+        delta, attention = state["params"]["layers"][0]
+        spec = jax.sharding.PartitionSpec
+        assert delta["gdn"]["w_qkvz"].sharding.spec == spec(
+            None, None, None, "tp", None)
+        assert delta["gdn"]["A_log"].sharding.spec == spec(
+            None, None, "tp", None)
+        assert delta["gdn"]["wo"].sharding.spec == spec(
+            None, None, "tp", None, None)
+        assert "tp" not in delta["gdn"]["norm"].sharding.spec
+        assert "ep" in delta["moe"]["w1"].sharding.spec
+        assert not any(delta["moe"]["wsg"].sharding.spec)
+        assert "tp" in attention["wq"].sharding.spec
+        got, got_grad = jax.jit(jax.value_and_grad(
+            lambda p: loss_and_counters(p, batch, cfg, mesh)[0]))(
+                state["params"])
+        assert abs(float(got) - float(want)) < 1e-4, (got, want)
+        for (path, g), w in zip(
+                jax.tree_util.tree_flatten_with_path(got_grad)[0],
+                jax.tree.leaves(want_grad)):
+            g, w = np.asarray(g), np.asarray(w)
+            assert np.abs(g - w).max() <= 1e-4 * np.abs(w).max() + 1e-7, path
+        state, metrics = make_train_step(cfg, tx, mesh=mesh)(state, batch)
+        for name in ("loss", "moe_held_choices", "moe_balance_loss",
+                     "moe_shared_gate_mean", "attn_gate_mean",
+                     "gdn_state_norm", "gdn_decay_mean", "gdn_beta_mean"):
+            assert float(metrics[name]) == pytest.approx(
+                float(plain[name]), rel=1e-4), name
+        # all 8 experts are held between the shards: every choice counts
+        assert float(metrics["moe_held_choices"]) == 4 * 32 * 2
+        assert float(metrics["moe_dropped_choices"]) == 0.0
+
+
 def test_pipeline_parallel_matches_single_device():
     """GPipe over pp=2 (x dp=2): the pipelined loss equals the plain
     sequential loss exactly, and a full pp train step (AD through
