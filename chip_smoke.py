@@ -343,9 +343,10 @@ def _train_func(config: dict) -> dict:
             "flash_fwd_calls_in_step": len(re.findall(
                 r'%flash_attention_fwd[.\d]* = [^\n]*"tpu_custom_call"',
                 text)),
-            # the delta layers' state passes: the forward in the forward
-            # scan's body and again in the backward's (nothing of it is
-            # kept), the backward once
+            # the delta rule's fused kernels: the forward in the forward
+            # scan's body (writing o alone) and again in the backward's
+            # (nothing of it is kept: there it also writes the entering
+            # states), the backward once
             "gated_delta_calls_in_step": [len(re.findall(
                 rf'%{name}[.\d]* = [^\n]*"tpu_custom_call"', text))
                 for name in ("gated_delta_fwd", "gated_delta_bwd")],
@@ -613,11 +614,13 @@ def leg_hybrid_trainer(platform: str = "tpu", model: dict = None,
                        dtype: str = "bfloat16",
                        rule_tol: float = 4e-2) -> dict:
     """The hybrid stack through the same Trainer path, ``steps`` steps:
-    the delta rule's two kernels first compared with the chunked scan
-    and its ``jax.grad`` (all five gradients) at the model's shape; then
-    the compiled step must hold both state-pass kernels and both flash
-    kernels, nothing may be dropped, and the new counters must read what
-    an untrained model's gates read."""
+    the delta rule's two fused kernels (the whole rule in VMEM, the
+    gradient by hand) first compared with the chunked ``jnp`` form and its
+    ``jax.grad`` (all five gradients) at the model's shape, q and k at
+    the value heads and at the key heads; then the compiled step must
+    hold both delta kernels and both flash kernels, nothing may be
+    dropped, and the new counters must read what an untrained model's
+    gates read."""
     import jax
     import jax.numpy as jnp
 
@@ -646,7 +649,6 @@ def leg_hybrid_trainer(platform: str = "tpu", model: dict = None,
                                                   (batch, seq, heads)))
     beta = jax.nn.sigmoid(jax.random.normal(keys[4], (batch, seq, heads)))
     dout = jax.random.normal(keys[5], (batch, seq, heads, dv))
-    operands = (q, k, v, g, beta)
     chunk = min(g_["chunk"], seq)
 
     def kernels(*xs):
@@ -664,12 +666,17 @@ def leg_hybrid_trainer(platform: str = "tpu", model: dict = None,
         return _max_err(got, want) / float(
             jnp.max(jnp.abs(want.astype(jnp.float32))))
 
-    fwd_err = rel_err(kernels(*operands), scan(*operands))
+    fwd_err = bwd_err = 0.0
+    # q and k once a value head, then once a key head, as the layer
+    # hands them over
+    for every in (1, heads // g_["num_key_heads"]):
+        operands = (q[:, :, ::every], k[:, :, ::every], v, g, beta)
+        fwd_err = max(fwd_err, rel_err(kernels(*operands), scan(*operands)))
+        bwd_err = max([bwd_err] + [rel_err(a, b) for a, b in zip(
+            grads(kernels), grads(scan))])
     check(fwd_err <= rule_tol,
-          f"gated delta kernels vs the chunked scan: max rel err {fwd_err} "
+          f"gated delta kernels vs the chunked form: max rel err {fwd_err} "
           f"> {rule_tol} ({dtype})")
-    bwd_err = max(rel_err(a, b)
-                  for a, b in zip(grads(kernels), grads(scan)))
     check(bwd_err <= rule_tol,
           f"gated delta backward (dq, dk, dv, dg, dbeta) vs grad of the "
           f"chunked scan: {bwd_err} > {rule_tol} ({dtype})")
@@ -693,8 +700,9 @@ def leg_hybrid_trainer(platform: str = "tpu", model: dict = None,
           f"params on {platform}: {result['param_platforms']}")
     check(result["gated_delta_calls_in_step"]
           == [2 * int(on_chip), int(on_chip)],
-          f"the state pass forward in both scans' bodies and backward in "
-          f"one: {result['gated_delta_calls_in_step']} Mosaic calls")
+          f"the delta rule's forward kernel in both scans' bodies and its "
+          f"backward in one: {result['gated_delta_calls_in_step']} Mosaic "
+          f"calls")
     check(result["flash_fwd_calls_in_step"] == int(on_chip)
           and result["mosaic_bwd_in_step"] == on_chip,
           f"both flash kernels once in the compiled step: "

@@ -15,7 +15,8 @@ heads of ``Dv`` (key head ``j`` serves value heads ``r j .. r j + r - 1``):
     q, k          = l2norm(q) Dk^-1/2, l2norm(k)      (eps 1e-6)
     beta          = sigmoid(b)                         float32
     g             = -exp(A_log) softplus(a + dt_bias)  float32
-    o             = gated_delta_rule(q, k, v, g, beta) (ops/gated_delta.py)
+    o             = gated_delta_rule(q, k, v, g, beta) (ops/gated_delta.py;
+                    q, k with their Hk heads, v, g, beta with Hv)
     out           = (rmsnorm(o; norm) silu(z), heads joined) wo
 
 The fused projections are laid out a key head at a time so that ``tp``
@@ -26,6 +27,12 @@ by the heads.  A row is one causal sequence: the state and the
 convolution cross whatever separators it holds, as attention does.  Over
 an ``sp`` axis the layer raises (the state would have to pass from shard
 to shard).
+
+What the rule leaves in HBM on a TPU: its operands as this layer makes
+them (q, k at ``Hk`` heads, v, g, beta), ``o`` and, between the forward
+rule and the backward one, each chunk's entering state in float32; no
+copy of q and k a value head, no ``[C, C]`` tensor, no ``W``, ``U`` or
+``V'`` (``ops/gated_delta.py``).
 """
 
 from __future__ import annotations
@@ -139,10 +146,11 @@ def gdn_attention(h, lp: Dict, cfg, mesh=None):
         beta = jax.nn.sigmoid(ba[..., :r]).reshape(b, s, hk * r)
         g = (-jnp.exp(lp["A_log"]) * jax.nn.softplus(
             ba[..., r:] + lp["dt_bias"])).reshape(b, s, hk * r)
-        # a key head's q and k, once a value head that reads them
-        o, state = gated_delta_rule(
-            jnp.repeat(q, r, axis=2), jnp.repeat(k, r, axis=2), v, g, beta,
-            chunk=min(g_.chunk, s), with_state=True)
+        # q and k go in with their key heads: the kernels fetch a key
+        # head's block for each value head that reads it, nothing is
+        # repeated in HBM and dq, dk return summed a key head
+        o, state = gated_delta_rule(q, k, v, g, beta,
+                                    chunk=min(g_.chunk, s), with_state=True)
         counted = {
             "gdn_state_norm": jnp.sqrt(jnp.mean(jnp.square(state))),
             "gdn_decay_mean": jax.lax.stop_gradient(jnp.mean(jnp.exp(g))),

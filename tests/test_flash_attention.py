@@ -731,25 +731,61 @@ def _delta_calls(text):
         text)) for name in ("gated_delta_fwd", "gated_delta_bwd"))
 
 
+@pytest.mark.parametrize("wrap,calls", [(lambda f: f, (1, 1)),
+                                        (jax.checkpoint, (2, 1))],
+                         ids=["kept", "remat"])
+@pytest.mark.parametrize("key_heads", [32, 16])
 def test_gated_delta_gradient_compiles_for_the_chip_at_the_cell_width(
-        one_v5e_chip):
-    """Mosaic takes both state-pass kernels at the hybrid cell's delta
-    layers' shape (2 rows x 8,192 positions, 32 value heads of 128 x 128,
-    chunks of 64, bfloat16): kept in this file because one process may
-    describe the chip (``one_v5e_chip``)."""
+        one_v5e_chip, key_heads, wrap, calls):
+    """Mosaic takes both fused kernels at the hybrid cell's delta layers'
+    shape (2 rows x 8,192 positions, 32 value heads of 128 x 128, chunks
+    of 64, bfloat16) with q and k a value head and a key head: kept in
+    this file because one process may describe the chip
+    (``one_v5e_chip``).  What the compiled programs hold in HBM: no
+    float32 array that ends in a chunk's ``[64, 64]`` (nor a tile's
+    ``[128, 128]`` a pair of chunks), forward no array of the states'
+    shape, under the gradient the entering states from the ``fwd`` rule's
+    kernel to ``gated_delta_bwd`` and nothing else a chunk; under remat
+    the forward pass runs the kernel that writes ``o`` alone."""
     from ray_tpu.ops.gated_delta import gated_delta_rule
 
     def spec(shape, dtype=jnp.bfloat16):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one_v5e_chip)
 
-    wide, gate = spec((2, 8192, 32, 128)), spec((2, 8192, 32), jnp.float32)
-    text = jax.jit(jax.grad(lambda q, k, v, g, beta: jnp.sum(
-        gated_delta_rule(q, k, v, g, beta, use_pallas=True).astype(
-            jnp.float32)), (0, 1, 2, 3, 4))).lower(
-                wide, wide, wide, gate, gate).compile().as_text()
-    assert _delta_calls(text) == (1, 1)
-    assert text.count("tpu_custom_call") == 2
+    keyed, wide = spec((2, 8192, key_heads, 128)), spec((2, 8192, 32, 128))
+    gate = spec((2, 8192, 32), jnp.float32)
+    operands = (keyed, keyed, wide, gate, gate)
+    states = "f32[64,128,128,128]"
+    # a row's last entering state, as the kernel writes it and as the
+    # rule returns it: the one float32 array the forward may hold that
+    # ends in a square (Dk x Dv is a tile's size)
+    last = {"f32[64,128,128]", "f32[2,32,128,128]"}
+
+    def squares(text):
+        return {m.group(1) for m in re.finditer(
+            r"(f32\[(?:\d+,)*(?:64,64|128,128)\])\{", text)}
+
+    def rule(*xs):
+        return gated_delta_rule(*xs, use_pallas=True)
+
+    forward = jax.jit(rule).lower(*operands).compile().as_text()
+    assert _delta_calls(forward) == (1, 0)
+    assert forward.count("tpu_custom_call") == 1
+    assert squares(forward) <= last
+
+    # the value too, so that remat's forward pass has a reader
+    text = jax.jit(jax.value_and_grad(lambda *xs: jnp.sum(wrap(rule)(
+        *xs).astype(jnp.float32)), (0, 1, 2, 3, 4))).lower(
+            *operands).compile().as_text()
+    assert _delta_calls(text) == calls
+    assert text.count("tpu_custom_call") == sum(calls)
     assert " while(" not in text
+    # the entering states, written by one kernel: the fwd rule's
+    assert states in squares(text) <= last | {states}
+    writers = [line for line in text.splitlines()
+               if "tpu_custom_call" in line
+               and states in line.split(" custom-call(")[0]]
+    assert len(writers) == 1 and "%gated_delta_fwd" in writers[0]
 
 
 @pytest.mark.parametrize("how,wrap,want", [

@@ -307,6 +307,47 @@ def test_the_pattern_says_periods_and_the_kinds_need_their_sizes():
     assert TransformerConfig().layer_pattern == (("mha", "dense", 4),)
 
 
+def test_the_delta_layer_hands_the_rule_its_key_heads(monkeypatch):
+    """``gdn_attention`` calls the rule with q and k at their key heads
+    (2 here, for 4 value heads) and gets what the parent got by writing
+    each key head out once a value head that reads it: values, counters
+    and the gradient of every ``gdn`` leaf (``jnp.repeat``'s transpose
+    summed a key head's value heads; the rule now does)."""
+    from ray_tpu.models import gdn
+    from ray_tpu.ops import gated_delta
+    cfg = _cfg()
+    lp = jax.tree.map(lambda x: x[0], gdn.init_gdn_params(
+        jax.random.PRNGKey(3), 1, cfg.d_model, cfg.gdn, jnp.float32))
+    h, dout = jax.random.normal(jax.random.PRNGKey(4), (2, 2, 32, cfg.d_model))
+    heads, rule = [], gated_delta.gated_delta_rule
+
+    def run(lp):
+        out, counted = gdn.gdn_attention(h, lp, cfg)
+        return jnp.sum(out * dout), (out, counted)
+
+    def repeated(q, k, v, *rest, **how):
+        heads.append(q.shape[2])
+        r = v.shape[2] // q.shape[2]
+        return rule(jnp.repeat(q, r, axis=2), jnp.repeat(k, r, axis=2), v,
+                    *rest, **how)
+
+    (_, (out, counted)), grads = jax.value_and_grad(run, has_aux=True)(lp)
+    monkeypatch.setattr(gated_delta, "gated_delta_rule", repeated)
+    (_, (want, counted_), ), want_grads = jax.value_and_grad(
+        run, has_aux=True)(lp)
+    assert heads == [cfg.gdn.num_key_heads] and cfg.gdn.ratio == 2
+    np.testing.assert_allclose(out, want, rtol=1e-5, atol=1e-7)
+    for name in counted:
+        np.testing.assert_allclose(counted[name], counted_[name], rtol=1e-5)
+    assert set(grads) == set(lp)
+    for name in lp:
+        scale = float(jnp.max(jnp.abs(want_grads[name])))
+        assert scale > 0, name
+        np.testing.assert_allclose(grads[name] / scale,
+                                   want_grads[name] / scale, atol=1e-5,
+                                   err_msg=name)
+
+
 def test_the_scopes_and_counters_have_readers():
     """The new scopes are in the step as it is lowered; the new counters,
     where a worker reports them, are gauges on /metrics."""
