@@ -788,17 +788,44 @@ class _TracedStep:
     span: the host's dispatch of the step (argument flattening, cache
     lookup, donation, launch — it returns before the device finishes),
     i.e. the train worker's own cost per step.  Everything else of the
-    jitted function (``.lower``, ``.trace``, ...) is reached through."""
+    jitted function (``.lower``, ``.trace``, ...) is reached through.
+
+    The first call also offers the program to ``tracing.programs()`` as
+    ``"train_step"``: the jitted function with the arguments' shapes,
+    dtypes and shardings (the state is donated, so nothing concrete is
+    kept).  Whoever reads that entry pays for its manifest, afterwards;
+    the step pays one ``tree.map``."""
 
     def __init__(self, jitted):
         self._jitted = jitted
+        self._offered = False
 
     def __call__(self, state, batch):
+        # (a call under a trace, ``jax.eval_shape(step, ...)``, has no
+        # shardings to offer: the first concrete call registers)
+        if not self._offered and not any(
+                isinstance(x, jax.core.Tracer)
+                for x in jax.tree.leaves((state, batch))):
+            self._offered = True
+            tracing.register_program(
+                "train_step", self._jitted,
+                *jax.tree.map(_abstract_argument, (state, batch)))
         with tracing.span("train.model_step", category="train"):
             return self._jitted(state, batch)
 
     def __getattr__(self, name):
         return getattr(self._jitted, name)
+
+
+def _abstract_argument(x):
+    """An argument's leaf as ``lower`` takes it in the leaf's place: an
+    uncommitted array (a ``jit``'s output) names no device, as in the
+    call itself."""
+    if not isinstance(x, jax.Array):
+        return x
+    return jax.ShapeDtypeStruct(
+        x.shape, x.dtype, weak_type=x.weak_type,
+        sharding=x.sharding if x.committed else None)
 
 
 def optax_global_norm(tree) -> jax.Array:

@@ -12,7 +12,8 @@ Workers in other OS processes record spans locally and piggyback them on
 task replies (``drain``/``ingest``), the in-process analogue of the
 reference's ProfileEvent batching to GCS.
 
-One span API, two sinks, two clocks:
+The module holds three things.  One span API with two sinks and two
+clocks:
 
 * the ring (``enable()`` / ``force``): ``time.time()`` stamps, trace and
   parent ids, metadata — what ``ray_tpu.timeline()`` and the
@@ -22,19 +23,32 @@ One span API, two sinks, two clocks:
   under its constant name, so a ``jax.profiler.start_trace`` anywhere in
   the process finds the program's spans on the clock of the device
   events.  The profiler drops the annotation unless a session is live,
-  so this sink has no switch and is independent of ``enable()``.  This
-  module never imports ``jax`` itself: worker children, the GCS and
-  CPU-only drivers do not pay for it.
+  so this sink has no switch and is independent of ``enable()``.
+
+And, third, the registry of compiled programs (``register_program`` /
+``programs`` / ``device_time_by_scope``): for each program the process
+registers (the train step, by ``models/transformer.py::_TracedStep``)
+a manifest of the executable that runs -- which ``jax.named_scope`` of
+``STEP_SCOPES`` and which pass (forward, backward, remat's second
+forward) owns each instruction, which instructions only enclose others,
+what memory the executable asks for -- so that a device trace's rows,
+named ``fusion.586`` by the compiler, can be summed by the program's own
+layers.  A manifest is made when it is first read, never when it is
+registered: a run that reads none pays for none.
+
+This module never imports ``jax`` itself: worker children, the GCS and
+CPU-only drivers do not pay for it.
 """
 
 from __future__ import annotations
 
 import os
+import re
 import sys
 import threading
 import time
 import uuid
-from typing import Dict, List, Optional
+from typing import Dict, Iterable, List, Optional, Tuple
 
 from ray_tpu._private.debug.lock_order import diag_lock
 
@@ -264,6 +278,178 @@ def clear():
         _events.clear()
         _dropped = 0
         _dropped_reported = 0
+        _programs.clear()
+
+
+# ---- the registry of compiled programs ---------------------------------
+
+#: Every name the train path passes to ``jax.named_scope``
+#: (``ray_tpu/models``, ``ray_tpu/ops``; the raylet's solve program has
+#: its own two and is not a step).  The models keep their literals;
+#: ``tests/test_program_spans.py`` holds the literals to this list.
+STEP_SCOPES = (
+    "attention", "ffn", "head_loss", "optimizer",
+    "moe_router", "moe_dispatch", "moe_experts", "moe_combine",
+    "moe_shared", "moe_bias",
+    "mla_q", "mla_kv", "mla_out", "mtp_module", "mtp_loss",
+    "gdn_proj", "gdn_conv", "gdn_core", "gdn_out", "attn_gate",
+    "block_diffusion_loss", "flash_attention_bwd", "gated_delta_bwd")
+#: The names the train path's ``pallas_call``s are given: a device
+#: trace's events of these kernels start with them.
+KERNEL_EVENTS = ("flash_attention_fwd", "flash_attention_bwd",
+                 "gated_delta_fwd", "gated_delta_bwd")
+#: The passes of a step an instruction can belong to: the forward pass,
+#: the backward pass, and the forward that ``jax.checkpoint`` runs again
+#: inside the backward pass.
+PHASES = ("fwd", "bwd", "recompute")
+_SCOPES = frozenset(STEP_SCOPES)
+_ENCLOSING_OPCODES = ("while", "conditional", "call")
+_MEMORY_KEYS = ("temp_size_in_bytes", "argument_size_in_bytes",
+                "output_size_in_bytes")
+
+_INSTRUCTION = re.compile(r"^\s*(?:ROOT\s+)?%?([\w.\-]+) = ")
+# The opcode is the first lower-case word before a "(" that follows
+# white space: shapes and tiled layouts (``{1,0:T(8,128)}``) have none.
+_OPCODE = re.compile(r"\s([a-z][a-z0-9\-]*)\(")
+_OP_NAME = re.compile(r'op_name="([^"]*)"')
+_TRANSFORMED = re.compile(r"^(?:jvp|transpose)\((.*)\)$")
+
+
+def _scope_and_phase(path: str) -> Tuple[Optional[str], str]:
+    """``op_name`` -> (innermost component that is in ``STEP_SCOPES``,
+    phase).  A component arrives bare or inside its transforms:
+    ``attention``, ``jvp(ffn)``, ``transpose(jvp(head_loss))``."""
+    parts = path.split("/")
+    if "rematted_computation" in parts:
+        phase = "recompute"
+    elif any(part.startswith("transpose(") for part in parts):
+        phase = "bwd"
+    else:
+        phase = "fwd"
+    for part in reversed(parts):
+        inner = _TRANSFORMED.match(part)
+        while inner is not None:
+            part = inner.group(1)
+            inner = _TRANSFORMED.match(part)
+        if part in _SCOPES:
+            return part, phase
+    return None, phase
+
+
+def manifest_of_text(text: str) -> dict:
+    """The text of a compiled HLO module -> ``{"scopes": {instruction:
+    (scope or None, phase)}, "enclosing": {instruction, ...}}``: the one
+    place where the rules are applied.  A fusion has the ``op_name`` XLA
+    gave the fusion instruction; a Pallas kernel's custom call is its
+    ``KERNEL_EVENTS`` name whatever scope encloses it; the expert
+    layer's grouped product loses its scope in XLA (a ``ragged-dot``
+    without metadata, on the TPU the custom calls ``ragged-dot-none.<n>``
+    and ``ragged-dot-metadata.<n>`` under an ``op_name`` of their own, so
+    also its pass: it is counted as ``fwd``) and is ``moe_experts``."""
+    scopes, enclosing = {}, set()
+    for line in text.splitlines():
+        found = _INSTRUCTION.match(line)
+        if found is None:
+            continue
+        name = found.group(1)
+        call = _OPCODE.search(line, found.end() - 1)
+        opcode = call.group(1) if call else ""
+        if opcode in _ENCLOSING_OPCODES:
+            enclosing.add(name)
+        path = _OP_NAME.search(line, found.end())
+        scope, phase = _scope_and_phase(path.group(1) if path else "")
+        if opcode == "custom-call":
+            scope = next((k for k in KERNEL_EVENTS if name.startswith(k)),
+                         scope)
+        if scope is None and name.startswith("ragged-dot"):
+            scope = "moe_experts"
+        scopes.setdefault(name, (scope, phase))
+    return {"scopes": scopes, "enclosing": enclosing}
+
+
+class _Program:
+    """One registered program.  Subscripted (``["scopes"]``,
+    ``["enclosing"]``, ``["memory"]``, ``["resolve_s"]``,
+    ``["text_bytes"]``) it resolves first: ``lower(*abstract).compile()``
+    of the jitted function and one pass over the executable's text.
+    After a call of the function with arguments the abstract ones
+    describe, jit's own caches answer both (JAX 0.9.0: no lowering, no
+    compile, and the executable is the one that ran); otherwise it is a
+    load from the persistent compile cache or a second compile.  Never
+    inside a timed window: the jitted function and the abstract
+    arguments are all it holds until then, and it lets go of them
+    afterwards."""
+
+    def __init__(self, jitted, abstract_args):
+        self._unresolved = (jitted, abstract_args)
+        self._manifest = None
+        self._resolving = diag_lock("tracing._Program._resolving")
+
+    def __getitem__(self, key):
+        with self._resolving:
+            if self._manifest is None:
+                self._manifest = self._resolve(*self._unresolved)
+                self._unresolved = None
+        return self._manifest[key]
+
+    @staticmethod
+    def _resolve(jitted, abstract_args) -> dict:
+        t0 = time.perf_counter()
+        compiled = jitted.lower(*abstract_args).compile()
+        text = compiled.as_text()
+        manifest = manifest_of_text(text)
+        memory = compiled.memory_analysis()
+        manifest["memory"] = {} if memory is None else {
+            key: int(getattr(memory, key)) for key in _MEMORY_KEYS}
+        manifest["text_bytes"] = len(text)
+        manifest["resolve_s"] = time.perf_counter() - t0
+        return manifest
+
+
+_programs: Dict[str, _Program] = {}
+
+
+def register_program(name: str, jitted, *abstract_args) -> None:
+    """Offer the program that ``jitted(*args)`` runs under ``name``;
+    ``abstract_args`` are the arguments as ``jax.ShapeDtypeStruct``
+    leaves (a donated state cannot be kept).  Costs nothing until
+    ``programs()[name]`` is subscripted.  One entry a name: the last
+    registration replaces, and frees, the one before."""
+    program = _Program(jitted, abstract_args)
+    with _lock:
+        _programs[name] = program
+
+
+def programs() -> Dict[str, _Program]:
+    """name -> the registered program, resolved when subscripted."""
+    with _lock:
+        return dict(_programs)
+
+
+def device_time_by_scope(rows: Iterable[Tuple[str, float]],
+                         program: str = "train_step") -> dict:
+    """Device seconds by the program's own layers.  ``rows`` are
+    ``(instruction name, seconds)`` of a device trace (any xplane's
+    ``XLA Ops`` events, the name as the HLO has it, with or without
+    ``%``) -> ``{scope or None: {"fwd": s, "bwd": s, "recompute": s},
+    ..., "unknown": s}``: ``None`` holds what no scope of ``STEP_SCOPES``
+    owns, ``"unknown"`` the rows of instructions the program does not
+    have (another program's events in the window).  Instructions that
+    only enclose others are left out, so time is counted once.  Raises
+    ``KeyError`` where no such program is registered."""
+    entry = programs()[program]
+    scopes, enclosing = entry["scopes"], entry["enclosing"]
+    out = {"unknown": 0.0}
+    for name, seconds in rows:
+        name = name.lstrip("%")
+        if name in enclosing:
+            continue
+        if name not in scopes:
+            out["unknown"] += seconds
+            continue
+        scope, phase = scopes[name]
+        out.setdefault(scope, dict.fromkeys(PHASES, 0.0))[phase] += seconds
+    return out
 
 
 # /metrics surface for the ring's loss accounting — a scrape-time

@@ -307,22 +307,28 @@ def test_the_layer_pattern_names_what_a_model_is_made_of():
 
 
 def test_the_spans_and_counters_have_readers():
-    """The new scopes are in the step as it is lowered; the new counters,
-    where a worker reports them, are gauges on /metrics; the two
+    """The new scopes own instructions in the manifest the program
+    publishes of the step it ran (what the benchmark's scope readers
+    read: ``tests/test_program_spans.py``); the new counters, where a
+    worker reports them, are gauges on /metrics; the two
     latent-attention roofline readers find nothing in a trace without
     the kernels and a share where they are."""
     from benchmarks import run as bench_run
     from ray_tpu._private.metrics_agent import get_metrics_registry
     from ray_tpu.train.session import Session
+    from ray_tpu.util import tracing
     cfg = _cfg()
     state, tx = make_train_state(jax.random.PRNGKey(1), cfg)
     step = make_train_step(cfg, tx, loss_override=functools.partial(
         mtp.loss_fn, cfg=cfg, coeff=0.3))
-    text = step.lower(state, {"tokens": jnp.asarray(_batches(3)[0])}
-                      ).as_text(debug_info=True)
+    tracing.clear()
+    step(state, {"tokens": jnp.asarray(_batches(3)[0])})
+    owners = {scope for scope, _ in
+              tracing.programs()["train_step"]["scopes"].values()}
+    tracing.clear()
     for scope in ("mla_q", "mla_kv", "mla_out", "moe_shared", "mtp_module",
                   "mtp_loss", "moe_router", "attention", "moe_bias"):
-        assert scope in text, scope
+        assert scope in owners, scope
 
     session = Session(lambda: None, 2, 0, 4)
     session.report(loss=1.0, main_loss=0.8, mtp_loss=0.7,

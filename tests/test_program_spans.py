@@ -5,9 +5,18 @@ stamps, parent ids — what ``ray_tpu.timeline()`` reads) and, where
 ``jax`` is already imported, the XLA profiler's host plane.  These tests
 pin both, the spans and counters that split the raylet tick and the
 train worker from inside, and the two benchmark readers that read them.
+
+Since PR 37 the module also holds the registry of compiled programs: the
+manifest of the train step that ran (which ``named_scope`` and which pass
+owns each instruction), ``device_time_by_scope`` and the eight benchmark
+readers that sum a traced window's device time by it.
 """
 
+import ast
 import contextlib
+import functools
+import gc
+import glob
 import json
 import os
 import signal
@@ -15,6 +24,7 @@ import subprocess
 import sys
 import threading
 import time
+import weakref
 
 import pytest
 
@@ -336,7 +346,389 @@ def test_tracing_does_not_import_jax():
             "with tracing.span('scheduler.probe'):\n"
             "    pass\n"
             "assert tracing.num_buffered() == 1\n"
+            "assert tracing.programs() == {}\n"
+            "assert tracing.manifest_of_text('')['scopes'] == {}\n"
             "assert 'jax' not in sys.modules, 'tracing imported jax'\n")
     out = subprocess.run([sys.executable, "-c", code], cwd=ROOT,
                          capture_output=True, text=True, timeout=120)
     assert out.returncode == 0, out.stderr
+
+
+# ---- the registry of compiled programs (PR 37) -------------------------
+
+def _train_path_calls():
+    """(file, called name, call node) for every call in the train path's
+    source, ``ray_tpu/models`` and ``ray_tpu/ops``."""
+    for folder in ("models", "ops"):
+        for path in sorted(glob.glob(os.path.join(
+                ROOT, "ray_tpu", folder, "*.py"))):
+            with open(path) as f:
+                tree = ast.parse(f.read())
+            for node in ast.walk(tree):
+                if isinstance(node, ast.Call):
+                    called = getattr(node.func, "attr",
+                                     getattr(node.func, "id", None))
+                    yield os.path.relpath(path, ROOT), called, node
+
+
+def test_every_scope_literal_is_declared_and_every_declared_scope_is_used():
+    """A ``named_scope`` cannot ship on the train path without being in
+    ``STEP_SCOPES`` (so without a reader, below), nor a ``pallas_call``
+    under a name that is not in ``KERNEL_EVENTS``; and the lists hold no
+    name the source has lost.  Read from the syntax tree."""
+    scopes, kernels = set(), set()
+    for path, called, node in _train_path_calls():
+        if called == "named_scope":
+            (arg,) = node.args
+            assert isinstance(arg, ast.Constant), (path, node.lineno)
+            assert arg.value in tracing.STEP_SCOPES, (path, arg.value)
+            scopes.add(arg.value)
+        elif called == "pallas_call":
+            names = [k.value for k in node.keywords if k.arg == "name"]
+            assert names and isinstance(names[0], ast.Constant), \
+                (path, node.lineno)
+            assert names[0].value in tracing.KERNEL_EVENTS, \
+                (path, names[0].value)
+            kernels.add(names[0].value)
+    assert scopes == set(tracing.STEP_SCOPES)
+    assert kernels == set(tracing.KERNEL_EVENTS)
+    assert len(set(tracing.STEP_SCOPES)) == len(tracing.STEP_SCOPES) == 23
+
+
+def test_every_declared_scope_feeds_one_metric():
+    """Each scope and kernel event is summed by exactly one of the
+    benchmark's group metrics; ``optimizer`` alone is left to the rest
+    (``train_step_device_ms`` less the groups)."""
+    from benchmarks.harness import step_scopes
+    grouped = [s for group in step_scopes.GROUPS.values() for s in group]
+    assert len(grouped) == len(set(grouped))
+    assert set(grouped) | {"optimizer"} == \
+        set(tracing.STEP_SCOPES) | set(tracing.KERNEL_EVENTS)
+    for metric in step_scopes.GROUPS:
+        assert os.path.exists(os.path.join(
+            ROOT, "benchmarks", "layer_metrics", metric + ".py")), metric
+
+
+def _tiny_step(kind):
+    """-> (step, state, batch) of one of the four tiny configurations the
+    tests of the models build, as the benchmark's drivers build them."""
+    import jax
+    import jax.numpy as jnp
+
+    from ray_tpu.models.transformer import (TransformerConfig,
+                                            make_train_state,
+                                            make_train_step)
+    over = None
+    if kind == "dense":
+        cfg = TransformerConfig(vocab_size=128, d_model=64, n_layers=2,
+                                n_heads=4, d_ff=96, max_seq_len=32,
+                                dtype=jnp.float32, remat=True)
+        batch = {"tokens": jnp.zeros((2, 33), jnp.int32)}
+    elif kind == "block_diffusion":
+        import test_block_diffusion as tiny
+        from benchmarks.drivers import trainer_blockdiff_steps as driver
+        from ray_tpu.models import block_diffusion
+        cfg = TransformerConfig(dtype=jnp.float32, **driver._model_kwargs(
+            tiny.CONFIG, tiny.TRAFFIC["seq_len"]))
+        over = functools.partial(block_diffusion.loss_fn, cfg=cfg, block=4)
+        batch = {k: jnp.asarray(v) for k, v in driver.make_batches(
+            tiny.CONFIG, tiny.TRAFFIC, 7)[0].items()}
+    elif kind == "latent":
+        import test_mla_moe_mtp as tiny
+        from ray_tpu.models import mtp
+        cfg = tiny._cfg()
+        over = functools.partial(mtp.loss_fn, cfg=cfg, coeff=0.3)
+        batch = {"tokens": jnp.asarray(tiny._batches(3)[0])}
+    else:
+        import test_qwen3_next as tiny
+        cfg = tiny._cfg()
+        batch = {"tokens": jnp.asarray(tiny._batches(3)[0])}
+    state, tx = make_train_state(jax.random.PRNGKey(1), cfg)
+    return make_train_step(cfg, tx, loss_override=over), state, batch
+
+
+ALL, ONCE, OUTSIDE = {"fwd", "bwd", "recompute"}, {"fwd", "bwd"}, {"fwd"}
+# A layer's scopes run forward, backward and again under remat; the
+# experts' products and the rows' return have a backward by hand that
+# keeps what it needs (nothing of them is rematted); heads and losses
+# lie outside the rematted scan; the optimizer and the router bias'
+# update have no backward.  Off the TPU no flash or delta-rule kernel
+# runs, so their two scopes are the chip's to show (PERF.md section 5).
+MOE = {"moe_router": ALL, "moe_dispatch": ALL, "moe_experts": ONCE,
+       "moe_combine": ONCE}
+MANIFESTS = {
+    "dense": {"attention": ALL, "ffn": ALL, "head_loss": ONCE,
+              "optimizer": OUTSIDE},
+    "block_diffusion": dict(MOE, attention=ALL, ffn=ALL, optimizer=OUTSIDE,
+                            block_diffusion_loss=ONCE),
+    "latent": dict(MOE, attention=ALL, ffn=ALL, mla_q=ALL, mla_kv=ALL,
+                   mla_out=ALL, moe_shared=ALL, moe_bias=OUTSIDE,
+                   head_loss=ONCE, mtp_loss=ONCE, mtp_module=ONCE,
+                   optimizer=OUTSIDE),
+    "hybrid": dict(MOE, attention=ALL, ffn=ALL, attn_gate=ALL, gdn_proj=ALL,
+                   gdn_conv=ALL, gdn_core=ALL, gdn_out=ALL, moe_shared=ALL,
+                   head_loss=ONCE, optimizer=OUTSIDE),
+}
+
+
+@pytest.mark.parametrize("kind", sorted(MANIFESTS))
+def test_the_manifest_has_each_scope_of_the_configuration(kind):
+    """One call of the tiny step registers it; the manifest, resolved
+    when read, has every scope the configuration uses in the passes it
+    runs in, every ``while`` among the enclosing instructions, and the
+    executable's memory."""
+    tracing.clear()
+    step, state, batch = _tiny_step(kind)
+    assert tracing.programs() == {}              # registered by the call
+    step(state, batch)
+    entry = tracing.programs()["train_step"]
+    phases = {}
+    for scope, phase in entry["scopes"].values():
+        phases.setdefault(scope, set()).add(phase)
+    assert {k: v for k, v in phases.items() if k is not None} == \
+        MANIFESTS[kind]
+    assert phases[None] == ONCE
+    assert entry["enclosing"] and all(
+        name.startswith("while") for name in entry["enclosing"])
+    assert set(entry["memory"]) == {
+        "temp_size_in_bytes", "argument_size_in_bytes",
+        "output_size_in_bytes"}
+    assert entry["memory"]["argument_size_in_bytes"] > 0
+    assert entry["text_bytes"] > 0 and entry["resolve_s"] > 0
+    tracing.clear()
+
+
+def test_the_registry_costs_no_compile_unread_and_no_step_compiles_after():
+    """Five calls of the step and nobody reading: one lowering and one
+    backend compile, the step's own.  Reading the manifest afterwards
+    compiles at most once more (on this JAX not at all: jit's own caches
+    answer), and a later call of the step fires nothing."""
+    from jax import monitoring
+    fired = []
+    monitoring.register_event_duration_secs_listener(
+        lambda name, secs, **_: fired.append(name.rsplit("/", 1)[-1]))
+    counted = ("jaxpr_to_mlir_module_duration", "backend_compile_duration")
+
+    def count():
+        got = [fired.count(name) for name in counted]
+        del fired[:]
+        return got
+
+    import jax
+    tracing.clear()
+    step, state, batch = _tiny_step("dense")
+    # a call under a trace has nothing to offer, and breaks nothing
+    assert jax.eval_shape(step, state, batch)[1]["loss"].shape == ()
+    assert tracing.programs() == {}
+    count()
+    for _ in range(5):
+        state, metrics = step(state, batch)
+    assert count() == [1, 1]
+    entry = tracing.programs()["train_step"]
+    assert entry["scopes"] and entry["scopes"] is entry["scopes"]
+    lowerings, compiles = count()
+    assert lowerings <= 1 and compiles <= 1
+    state, metrics = step(state, batch)
+    assert float(metrics["loss"]) > 0
+    assert count() == [0, 0]
+    tracing.clear()
+
+
+class _Executable:
+    """What ``jitted.lower(*args).compile()`` gives, by hand."""
+
+    def __init__(self, text):
+        self.text, self.lowered_with = text, None
+
+    def lower(self, *args):
+        self.lowered_with = args
+        return self
+
+    def compile(self):
+        return self
+
+    def as_text(self):
+        return self.text
+
+    def memory_analysis(self):
+        return None
+
+
+# What the chip's compiler writes, cut to a line an instruction: tiled
+# layouts, names with and without "%", a fused computation's inner
+# instruction, a kernel's custom call inside ``attention``, XLA's
+# ``ragged-dot`` without metadata and as the TPU's custom call, a
+# conditional named ``cond.<n>``.
+STEP_TEXT = """HloModule jit_train_step, is_scheduled=true
+
+%fused_computation (p: f32[2]) -> f32[2] {
+  %p = f32[2]{0} parameter(0)
+  ROOT %add.1 = f32[2]{0:T(256)} add(%p, %p), metadata={op_name="jit(train_step)/jvp()/while/body/closed_call/attention/add"}
+}
+
+ENTRY %main.9 (a: f32[2]) -> f32[2] {
+  %fusion.1 = bf16[4,8]{1,0:T(8,128)(2,1)} fusion(%a), kind=kLoop, calls=%fused_computation, metadata={op_name="jit(train_step)/jvp()/while/body/closed_call/attention/bsd,dhk->bshk/dot_general" stack_frame_id=3}
+  fusion.2 = f32[2]{0} fusion(a), kind=kLoop, calls=fc, metadata={op_name="jit(train_step)/transpose(jvp())/while/body/closed_call/checkpoint/rematted_computation/ffn/jit(silu)/mul"}
+  %flash_attention_fwd.3 = (bf16[2]{0}, f32[2]{0}) custom-call(%q), custom_call_target="tpu_custom_call", metadata={op_name="jit(train_step)/jvp()/while/body/closed_call/attention/pallas_call"}
+  %flash_attention_bwd.4 = (bf16[2]{0}, /*index=1*/bf16[2]{0}) custom-call(%q), custom_call_target="tpu_custom_call", metadata={op_name="jit(train_step)/transpose(jvp())/while/body/closed_call/checkpoint/attention/flash_attention_bwd/pallas_call"}
+  %fusion.5 = f32[2]{0} fusion(%a), kind=kInput, calls=%f5, metadata={op_name="jit(train_step)/transpose(jvp())/while/body/closed_call/checkpoint/attention/flash_attention_bwd/reduce_sum"}
+  %ragged-dot.6 = bf16[8,4]{1,0} ragged-dot(%x, %w, %g), lhs_contracting_dims={1}
+  %ragged-dot-none.19 = bf16[8,4]{1,0:T(8,128)(2,1)} custom-call(%m, %x, %w), custom_call_target="tpu_custom_call", frontend_attributes={mosaic_fusion_entry_point="true",ragged_dot_tiling="512,256,512"}, metadata={op_name="ragged-dot-none"}
+  %cond.7 = (f32[2]{0}) conditional(%pred, %a, %b), true_computation=%t, false_computation=%f, metadata={op_name="jit(train_step)/jvp()/while/body/closed_call/ffn/moe_dispatch/cond"}
+  %while.8 = (s32[], f32[2]{0}) while(%init), condition=%c, body=%b
+  %fusion.10 = f32[2]{0} fusion(%a), kind=kLoop, calls=%f10, metadata={op_name="jit(train_step)/transpose(jvp(head_loss))/mul"}
+  %fusion.11 = f32[2]{0} fusion(%a), kind=kLoop, calls=%f11, metadata={op_name="jit(train_step)/jvp()/while/body/closed_call/ffn/moe_router/jit(_where)/select_n"}
+  %fusion.12 = f32[2]{0} fusion(%a), kind=kLoop, calls=%f12, metadata={op_name="jit(train_step)/mtp_module/jvp()/while/body/closed_call/attention/mla_q/dot_general"}
+  %fusion.13 = f32[2]{0} fusion(%a), kind=kLoop, calls=%f13, metadata={op_name="jit(train_step)/jvp(mtp_module)/concatenate"}
+  %custom-call.14 = f32[2]{0} custom-call(%a), custom_call_target="Sharding", metadata={op_name="jit(train_step)/optimizer/mul"}
+  %gated_delta_fwd.15 = bf16[2]{0} custom-call(%q), custom_call_target="tpu_custom_call", metadata={op_name="jit(train_step)/transpose(jvp())/while/body/closed_call/checkpoint/rematted_computation/attention/gdn_core/pallas_call"}
+  %fusion.16 = f32[2]{0} fusion(%a), kind=kLoop, calls=%f16, metadata={op_name="jit(train_step)/jvp()/while/body/closed_call/attention/gdn_conv/mul"}
+  %fusion.17 = f32[2]{0} fusion(%a), kind=kLoop, calls=%f17, metadata={op_name="jit(train_step)/jvp(block_diffusion_loss)/mul"}
+  ROOT %copy.18 = f32[2]{0} copy(%a)
+}
+"""
+STEP_SCOPES_WANT = {
+    "p": (None, "fwd"), "add.1": ("attention", "fwd"),
+    "fusion.1": ("attention", "fwd"), "fusion.2": ("ffn", "recompute"),
+    "flash_attention_fwd.3": ("flash_attention_fwd", "fwd"),
+    "flash_attention_bwd.4": ("flash_attention_bwd", "bwd"),
+    "fusion.5": ("flash_attention_bwd", "bwd"),
+    "ragged-dot.6": ("moe_experts", "fwd"),
+    "ragged-dot-none.19": ("moe_experts", "fwd"),
+    "cond.7": ("moe_dispatch", "fwd"), "while.8": (None, "fwd"),
+    "fusion.10": ("head_loss", "bwd"), "fusion.11": ("moe_router", "fwd"),
+    "fusion.12": ("mla_q", "fwd"), "fusion.13": ("mtp_module", "fwd"),
+    "custom-call.14": ("optimizer", "fwd"),
+    "gated_delta_fwd.15": ("gated_delta_fwd", "recompute"),
+    "fusion.16": ("gdn_conv", "fwd"),
+    "fusion.17": ("block_diffusion_loss", "fwd"),
+    "copy.18": (None, "fwd"),
+}
+
+
+@pytest.fixture
+def hand_made_step():
+    """``"train_step"`` registered over the text above."""
+    tracing.clear()
+    executable = _Executable(STEP_TEXT)
+    tracing.register_program("train_step", executable, "state", "batch")
+    yield executable
+    tracing.clear()
+
+
+def test_the_rules_of_the_manifest_on_a_hand_made_text(hand_made_step):
+    entry = tracing.programs()["train_step"]
+    assert hand_made_step.lowered_with is None    # not before it is read
+    assert entry["scopes"] == STEP_SCOPES_WANT
+    assert hand_made_step.lowered_with == ("state", "batch")
+    assert entry["enclosing"] == {"cond.7", "while.8"}
+    assert entry["memory"] == {}                  # a backend without one
+    assert entry["text_bytes"] == len(STEP_TEXT)
+
+
+@pytest.mark.parametrize("rows,want", [
+    # a kernel's event inside ``attention`` is the kernel's, not the
+    # projections'; the scope ``flash_attention_bwd`` joins its kernel
+    ([("fusion.1", 2.0), ("flash_attention_fwd.3", 4.0),
+      ("flash_attention_bwd.4", 8.0), ("fusion.5", 0.5)],
+     {"attention": {"fwd": 2.0}, "flash_attention_fwd": {"fwd": 4.0},
+      "flash_attention_bwd": {"bwd": 8.5}}),
+    # XLA's grouped product, which lost its scope: the instruction
+    # without metadata, and the custom call the TPU's compiler makes of it
+    ([("ragged-dot.6", 3.0), ("ragged-dot-none.19", 0.5)],
+     {"moe_experts": {"fwd": 3.5}}),
+    # a trace that kept the HLO's "%", and one that did not; remat
+    ([("%fusion.2", 1.0), ("fusion.2", 0.25)],
+     {"ffn": {"recompute": 1.25}}),
+    # instructions that only enclose others, by opcode: ``cond.<n>`` too
+    ([("cond.7", 100.0), ("while.8", 100.0), ("fusion.11", 0.5)],
+     {"moe_router": {"fwd": 0.5}}),
+    # another program's events in the window, and an unscoped copy
+    ([("fusion.999", 7.0), ("copy.18", 0.25), ("fusion.10", 0.5)],
+     {"unknown": 7.0, None: {"fwd": 0.25}, "head_loss": {"bwd": 0.5}}),
+    ([], {}),
+])
+def test_device_time_by_scope_on_hand_made_rows(hand_made_step, rows, want):
+    got = tracing.device_time_by_scope(iter(rows))
+    zero = dict.fromkeys(tracing.PHASES, 0.0)
+    full = {scope: (value if scope == "unknown" else dict(zero, **value))
+            for scope, value in want.items()}
+    full.setdefault("unknown", 0.0)
+    assert got == full
+
+
+def test_the_registry_keeps_one_entry_a_name_and_clear_empties_it():
+    tracing.clear()
+    with pytest.raises(KeyError):
+        tracing.device_time_by_scope([("fusion.1", 1.0)])
+    first, second = _Executable(STEP_TEXT), _Executable("")
+    gone = weakref.ref(first)
+    tracing.register_program("train_step", first)
+    tracing.register_program("train_step", second)
+    del first
+    gc.collect()
+    assert gone() is None                         # replaced and freed
+    assert list(tracing.programs()) == ["train_step"]
+    assert tracing.programs()["train_step"]["scopes"] == {}
+    gone = weakref.ref(second)
+    del second
+    gc.collect()
+    assert gone() is None                         # resolved: let go of
+    tracing.clear()
+    assert tracing.programs() == {}
+
+
+# Two steps of one chip's trace over the hand-made step: [name, start
+# ns, duration ns]; the conditional's row would count its branch twice.
+STEP_TRACE = {"device_ops": {"/device:TPU:0": [
+    ["fusion.1", 0.0, 4e6], ["fusion.2", 0.0, 2e6],
+    ["flash_attention_fwd.3", 0.0, 6e6], ["flash_attention_bwd.4", 0.0, 8e6],
+    ["fusion.5", 0.0, 1e6], ["ragged-dot.6", 0.0, 10e6],
+    ["cond.7", 0.0, 50e6], ["fusion.10", 0.0, 3e6], ["fusion.11", 0.0, 1e6],
+    ["fusion.12", 0.0, 5e6], ["fusion.13", 0.0, 0.5e6],
+    ["custom-call.14", 0.0, 2e6], ["gated_delta_fwd.15", 0.0, 7e6],
+    ["fusion.16", 0.0, 1e6], ["fusion.17", 0.0, 0.5e6],
+    ["copy.18", 0.0, 1.5e6], ["fusion.999", 0.0, 0.5e6]]},
+    "host_spans": []}
+READERS = {
+    # of 53 ms: all but the copy's 1.5 and the stranger's 0.5
+    "step_attributed_pct": 100.0 * 51.0 / 53.0,
+    "remat_recompute_ms": (2.0 + 7.0) / 2,
+    "attn_proj_ms": (4.0 + 5.0) / 2,
+    "attn_kernels_ms": (6.0 + 8.0 + 1.0) / 2,
+    "delta_layers_ms": (7.0 + 1.0) / 2,
+    "ffn_ms": 2.0 / 2,
+    "experts_ms": (10.0 + 1.0) / 2,
+    "head_loss_ms": (3.0 + 0.5 + 0.5) / 2,
+}
+
+
+@pytest.mark.parametrize("metric", sorted(READERS))
+def test_scope_reader_on_a_hand_made_trace(hand_made_step, metric, capfd):
+    ctx = {"trace": STEP_TRACE, "facts": {"steps": 2}}
+    assert _reader(metric)(ctx) == pytest.approx(READERS[metric])
+    # the table is made once a run, and written out whole
+    assert _reader(metric)(ctx) == pytest.approx(READERS[metric])
+    (line,) = [l for l in capfd.readouterr().err.splitlines()
+               if l.startswith('{"step_scopes"')]
+    table = json.loads(line)["step_scopes"]
+    assert table["ms_a_step"]["optimizer"]["fwd"] == pytest.approx(1.0)
+    assert table["ms_a_step"]["unknown"] == pytest.approx(0.25)
+    assert table["instructions"] == len(STEP_SCOPES_WANT)
+
+
+@pytest.mark.parametrize("metric", sorted(READERS))
+def test_scope_reader_without_a_manifest_returns_nothing(metric,
+                                                         monkeypatch):
+    """A raylet run, or a window none of whose events is the step's;
+    and the parent commit's program, which has no registry at all."""
+    tracing.clear()
+    ctx = {"trace": STEP_TRACE, "facts": {"steps": 2}}
+    assert _reader(metric)(ctx) is None
+    tracing.register_program("train_step", _Executable(""))
+    assert _reader(metric)({"trace": STEP_TRACE,
+                            "facts": {"steps": 2}}) is None
+    tracing.clear()
+    monkeypatch.delattr(tracing, "programs")
+    assert _reader(metric)({"trace": STEP_TRACE,
+                            "facts": {"steps": 2}}) is None

@@ -349,18 +349,24 @@ def test_the_delta_layer_hands_the_rule_its_key_heads(monkeypatch):
 
 
 def test_the_scopes_and_counters_have_readers():
-    """The new scopes are in the step as it is lowered; the new counters,
-    where a worker reports them, are gauges on /metrics."""
+    """The new scopes own instructions in the manifest the program
+    publishes of the step it ran (what the benchmark's scope readers
+    read: ``tests/test_program_spans.py``); the new counters, where a
+    worker reports them, are gauges on /metrics."""
     from ray_tpu._private.metrics_agent import get_metrics_registry
     from ray_tpu.train.session import Session
+    from ray_tpu.util import tracing
     cfg = _cfg()
     state, tx = make_train_state(jax.random.PRNGKey(1), cfg)
     step = make_train_step(cfg, tx)
-    text = step.lower(state, {"tokens": jnp.asarray(_batches(3)[0])}
-                      ).as_text(debug_info=True)
+    tracing.clear()
+    step(state, {"tokens": jnp.asarray(_batches(3)[0])})
+    owners = {scope for scope, _ in
+              tracing.programs()["train_step"]["scopes"].values()}
+    tracing.clear()
     for scope in ("gdn_proj", "gdn_conv", "gdn_core", "gdn_out", "attn_gate",
                   "moe_shared", "moe_router", "attention", "ffn"):
-        assert scope in text, scope
+        assert scope in owners, scope
 
     session = Session(lambda: None, 3, 0, 4)
     session.report(loss=1.0, gdn_state_norm=0.02, gdn_decay_mean=0.05,
@@ -399,6 +405,8 @@ def step_jaxpr_hash(cell: str, root: str = ROOT) -> str:
     if "mla" in kwargs:
         from ray_tpu.models.mla import MLAConfig
         kwargs["mla"] = MLAConfig(**kwargs["mla"])
+    if "gdn" in kwargs:
+        kwargs["gdn"] = GDNConfig(**kwargs["gdn"])
     cfg = TransformerConfig(dtype=jnp.dtype(config["dtype"]), **kwargs)
     if wl["driver"] == "trainer_blockdiff_steps":
         from ray_tpu.models import block_diffusion
@@ -432,7 +440,8 @@ def step_jaxpr_hash(cell: str, root: str = ROOT) -> str:
         re.sub(r"0x[0-9a-f]+", "0x", text).encode()).hexdigest()
 
 
-# The three older cells' steps at the parent commit 50ae53a (PR 34).
+# The three older cells' steps at the commit 50ae53a (PR 34), which they
+# still equal, and the hybrid cell's at the parent commit 32d33aa (PR 36).
 PARENT_STEPS = {
     "train-dscoder-1b3.pack4k":
         "593226c3e798e87962790db2f4055938f8862739301114386319090f98a5021b",
@@ -440,6 +449,8 @@ PARENT_STEPS = {
         "f137e57d6e107e1b1d4548271d9d82c16616f18b7ff8e23ff678874c522a24ba",
     "train-joyai-flash.pack8k":
         "7acc7897f37b96e284f7c4873a1eb0820aeee4b6254a698c29781d9c3a3b91aa",
+    "train-qwen3-next.pack8k":
+        "7627867e8f9f64b5350d5c720dc0b50ada98ce297a253b425396b993aa6cdeda",
 }
 
 
@@ -449,6 +460,9 @@ def test_the_older_cells_steps_are_traced_as_the_parent_traced_them(cell):
     shared expert's gate and the forward kernel's VMEM rule added is
     behind defaults that leave the dense, block-diffusion and
     latent-attention steps' jaxprs equal to the parent's, both flash
-    kernels and their compiler parameters included."""
+    kernels and their compiler parameters included.  Since PR 37 the
+    hybrid cell's too: the registry of compiled programs touches
+    nothing inside ``jax.jit``, so the executables, and their cache
+    entries, are the parent's in all four cells."""
     assert step_jaxpr_hash(cell) == PARENT_STEPS[cell]
 
