@@ -42,6 +42,7 @@ from typing import Dict
 
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 from jax.sharding import PartitionSpec as P
 
 _L2_EPS = 1e-6
@@ -132,13 +133,19 @@ def gdn_attention(h, lp: Dict, cfg, mesh=None):
                      g_.value_head_dim)
     b, s, _ = h.shape
     f32 = jnp.float32
+    # The names: cut points a rematerialised layer may keep
+    # (``models/remat.py``) -- the two projections, the convolution's
+    # sum (which SiLU's gradient reads) and its output.
     with jax.named_scope("gdn_proj"):
-        qkvz = jnp.einsum("bsd,dhc->bshc", h, lp["w_qkvz"])
-        ba = jnp.einsum("bsd,dhc->bshc", h, lp["w_ba"]).astype(f32)
+        qkvz = checkpoint_name(
+            jnp.einsum("bsd,dhc->bshc", h, lp["w_qkvz"]), "gdn_qkvz")
+        ba = checkpoint_name(
+            jnp.einsum("bsd,dhc->bshc", h, lp["w_ba"]), "gdn_ba").astype(f32)
         z = qkvz[..., 2 * dk + r * dv:].reshape(b, s, hk * r, dv)
     with jax.named_scope("gdn_conv"):
-        mixed = jax.nn.silu(causal_conv(qkvz[..., :2 * dk + r * dv],
-                                        lp["conv"]))
+        mixed = checkpoint_name(jax.nn.silu(checkpoint_name(
+            causal_conv(qkvz[..., :2 * dk + r * dv], lp["conv"]),
+            "gdn_conv")), "gdn_mixed")
     with jax.named_scope("gdn_core"):
         q = (_l2norm(mixed[..., :dk]) * dk ** -0.5).astype(h.dtype)
         k = _l2norm(mixed[..., dk:2 * dk]).astype(h.dtype)

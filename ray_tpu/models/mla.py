@@ -35,6 +35,7 @@ from typing import Dict
 
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 from jax.sharding import PartitionSpec as P
 
 
@@ -103,19 +104,31 @@ def mla_attention(h, lp: Dict, positions, cfg, mesh=None, mask=None):
         raise ValueError("ring attention has no rotary part: latent "
                          "attention does not run over an sp axis")
     dn, rkv = m.qk_nope_head_dim, m.kv_lora_rank
+    # The names: cut points a rematerialised layer may keep
+    # (``models/remat.py``) -- the two down-projections, the latents
+    # after their norms, the two up-projections whole (their halves go
+    # to the kernel: neither half alone spares the product) and the
+    # rotary parts as they enter the kernel.
     with jax.named_scope("mla_q"):
-        c_q = _rms_norm(jnp.einsum("bsd,dr->bsr", h, lp["wq_a"]),
-                        lp["q_norm"], eps)
-        q = jnp.einsum("bsr,rhk->bshk", c_q, lp["wq_b"])
+        c_q = checkpoint_name(_rms_norm(
+            checkpoint_name(jnp.einsum("bsd,dr->bsr", h, lp["wq_a"]),
+                            "mla_q_down"), lp["q_norm"], eps), "mla_q_latent")
+        q = checkpoint_name(jnp.einsum("bsr,rhk->bshk", c_q, lp["wq_b"]),
+                            "mla_q")
         q_nope = q[..., :dn]
-        q_rope = rope(q[..., dn:], positions, cfg.rope_theta,
-                      m.rope_interleave)
+        q_rope = checkpoint_name(
+            rope(q[..., dn:], positions, cfg.rope_theta, m.rope_interleave),
+            "mla_q_rope")
     with jax.named_scope("mla_kv"):
-        latent = jnp.einsum("bsd,dr->bsr", h, lp["wkv_a"])
-        c_kv = _rms_norm(latent[..., :rkv], lp["kv_norm"], eps)
-        k_rope = rope(latent[:, :, None, rkv:], positions, cfg.rope_theta,
-                      m.rope_interleave)[:, :, 0]
-        kv = jnp.einsum("bsr,rhk->bshk", c_kv, lp["wkv_b"])
+        latent = checkpoint_name(jnp.einsum("bsd,dr->bsr", h, lp["wkv_a"]),
+                                 "mla_kv_down")
+        c_kv = checkpoint_name(_rms_norm(latent[..., :rkv], lp["kv_norm"],
+                                         eps), "mla_kv_latent")
+        k_rope = checkpoint_name(
+            rope(latent[:, :, None, rkv:], positions, cfg.rope_theta,
+                 m.rope_interleave)[:, :, 0], "mla_k_rope")
+        kv = checkpoint_name(jnp.einsum("bsr,rhk->bshk", c_kv, lp["wkv_b"]),
+                             "mla_kv")
         k_nope, v = kv[..., :dn], kv[..., dn:]
     o = attention(q_nope, k_nope, v, mask=mask, q_rope=q_rope, k_rope=k_rope)
     with jax.named_scope("mla_out"):

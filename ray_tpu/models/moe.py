@@ -74,6 +74,7 @@ from typing import Dict, Tuple
 
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 from jax.sharding import PartitionSpec as P
 
 _CHUNK_ALIGN = 256
@@ -373,22 +374,31 @@ def moe_ffn(x: jax.Array, lp: Dict, top_k: int, norm_topk: bool = True,
     with jax.named_scope("moe_router"):
         # float32 in earnest: the chip's default for a float32 product
         # is one bfloat16 pass, which would move choices near a tie
-        logits = jnp.einsum("td,de->te", xt.astype(jnp.float32),
-                            lp["wr"].astype(jnp.float32),
-                            precision=jax.lax.Precision.HIGHEST)
+        # (``moe_scores``: a cut point a rematerialised layer may keep,
+        # ``models/remat.py``; as the shared expert's two products)
+        logits = checkpoint_name(
+            jnp.einsum("td,de->te", xt.astype(jnp.float32),
+                       lp["wr"].astype(jnp.float32),
+                       precision=jax.lax.Precision.HIGHEST), "moe_scores")
         if scoring == "softmax":
             probs = jax.nn.softmax(logits, axis=-1)
             gate, expert = _choose(probs, top_k)               # [T, k]
+            gate = checkpoint_name(gate, "moe_choice")
             if norm_topk:
                 gate = gate / jnp.sum(gate, axis=-1, keepdims=True)
         elif scoring == "sigmoid":
             probs = jax.nn.sigmoid(logits)
             gate, expert = _choose(probs, top_k, lp.get("bias"))
+            gate = checkpoint_name(gate, "moe_choice")
             if norm_topk:
                 gate = gate / (jnp.sum(gate, axis=-1, keepdims=True) + 1e-20)
             gate = gate * route_scale
         else:
             raise ValueError(f"scoring {scoring!r}")
+        # (one name for the chosen scores and the experts: kept, they
+        # spare the backward pass the choice itself, and one without the
+        # other spares nothing)
+        expert = checkpoint_name(expert, "moe_choice")
         router_load = jnp.sum(jax.nn.one_hot(
             expert.reshape(N), n_experts, dtype=jnp.int32), axis=0)
 
@@ -430,8 +440,10 @@ def balance_loss(stats: Dict) -> jax.Array:
 def shared_expert(x: jax.Array, lp: Dict) -> jax.Array:
     """The SwiGLU every token passes, on x [B, S, D]."""
     with jax.named_scope("moe_shared"):
-        gate = jax.nn.silu(jnp.einsum("bsd,df->bsf", x, lp["ws1"]))
-        up = jnp.einsum("bsd,df->bsf", x, lp["ws3"])
+        gate = jax.nn.silu(checkpoint_name(
+            jnp.einsum("bsd,df->bsf", x, lp["ws1"]), "moe_shared_gate"))
+        up = checkpoint_name(jnp.einsum("bsd,df->bsf", x, lp["ws3"]),
+                             "moe_shared_up")
         return jnp.einsum("bsf,fd->bsd", gate * up, lp["ws2"])
 
 

@@ -12,8 +12,10 @@ graft entry exercise):
     activation sharding between blocks.
   * ``lax.scan`` over stacked layer params — one compilation regardless
     of depth; optional ``jax.checkpoint`` rematerialisation that keeps
-    each layer's input and the flash kernel's ``out`` and ``lse``
-    (``remat_layer``) and recomputes the rest in the backward pass.
+    each layer's input, the flash kernel's ``out`` and ``lse`` and as
+    many of the layer's named products as the step's plan found room
+    for on the device (``remat_layer``, ``models/remat.py``), and
+    recomputes the rest in the backward pass.
   * bf16 activations/params with f32 RMSNorm + softmax + Adam moments.
   * A model is a LAYER PATTERN (``TransformerConfig.layer_pattern``):
     runs of layers of one kind, each run one scanned stack with its own
@@ -41,12 +43,12 @@ from typing import Any, Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 from jax.sharding import NamedSharding, PartitionSpec as P
 
+from ray_tpu.models import remat
 from ray_tpu.ops.attention_mask import CAUSAL
-from ray_tpu.ops.flash_attention import (
-    RESIDUAL_NAMES as FLASH_RESIDUAL_NAMES,
-    attention as flash_or_ref_attention)
+from ray_tpu.ops.flash_attention import attention as flash_or_ref_attention
 from ray_tpu.ops.ring_attention import ring_attention
 from ray_tpu.util import tracing
 
@@ -66,8 +68,11 @@ class TransformerConfig:
     max_seq_len: int = 2048
     rope_theta: float = 10_000.0
     dtype: Any = jnp.bfloat16
-    #: Recompute each layer in the backward pass from its input and the
-    #: flash kernel's ``out`` and ``lse`` (``remat_layer``).
+    #: Recompute each layer in the backward pass from its input, the
+    #: flash kernel's ``out`` and ``lse`` and what else of the layer the
+    #: device has room to keep (``remat_layer``: planned when the step
+    #: is traced, from the device's memory; nothing to set).  False:
+    #: keep everything.
     remat: bool = True
     #: Use ring attention over the "sp" mesh axis when its size > 1.
     context_parallel: bool = True
@@ -465,14 +470,21 @@ def _mha(h, lp, positions, cfg: TransformerConfig, mesh, mask):
     eps = cfg.norm_eps
     q = jnp.einsum("bsd,dhk->bshk", h, lp["wq"])
     k = jnp.einsum("bsd,dhk->bshk", h, lp["wk"])
-    v = jnp.einsum("bsd,dhk->bshk", h, lp["wv"])
+    # The names here and below are cut points a rematerialised layer may
+    # keep (``models/remat.py``): q, k and v as they enter the kernel.
+    # The projections before a norm carry none: kept, q's cost the
+    # block-diffusion step 38 ms of copies between layouts to spare 16
+    # (PERF.md section 6, PR 38).
+    v = checkpoint_name(jnp.einsum("bsd,dhk->bshk", h, lp["wv"]), "attn_v")
     if cfg.attn_out_gate:
         q, gate = q[..., :cfg.head_dim], q[..., cfg.head_dim:]
     if cfg.qk_norm:
         q = _rms_norm(q, norm_weight(lp["q_norm"], cfg), eps)
         k = _rms_norm(k, norm_weight(lp["k_norm"], cfg), eps)
-    q = _rope(q, positions, cfg.rope_theta, cfg.rotary_dim)
-    k = _rope(k, positions, cfg.rope_theta, cfg.rotary_dim)
+    q = checkpoint_name(_rope(q, positions, cfg.rope_theta, cfg.rotary_dim),
+                        "attn_q")
+    k = checkpoint_name(_rope(k, positions, cfg.rope_theta, cfg.rotary_dim),
+                        "attn_k")
     o = _attention_core(q, k, v, mesh, cfg, mask)
     counted = {}
     if cfg.attn_out_gate:
@@ -484,8 +496,9 @@ def _mha(h, lp, positions, cfg: TransformerConfig, mesh, mask):
 
 
 def _dense_ffn(h, lp):
-    gate = jax.nn.silu(jnp.einsum("bsd,df->bsf", h, lp["w1"]))
-    up = jnp.einsum("bsd,df->bsf", h, lp["w3"])
+    gate = jax.nn.silu(checkpoint_name(
+        jnp.einsum("bsd,df->bsf", h, lp["w1"]), "ffn_gate"))
+    up = checkpoint_name(jnp.einsum("bsd,df->bsf", h, lp["w3"]), "ffn_up")
     return jnp.einsum("bsf,fd->bsd", gate * up, lp["w2"])
 
 
@@ -516,6 +529,7 @@ def apply_layer(x, lp, positions, cfg: TransformerConfig, mesh=None,
         else:
             y, counted = _mha(h, lp, positions, cfg, mesh, mask)
             x = x + y
+        x = checkpoint_name(x, "mid_residual")
     with jax.named_scope("ffn"):
         h = _rms_norm(x, norm_weight(lp["ln2"], cfg), eps)
         if ffn == "moe":
@@ -530,18 +544,24 @@ def apply_layer(x, lp, positions, cfg: TransformerConfig, mesh=None,
     return x, counted
 
 
-def remat_layer(layer, cfg: TransformerConfig):
-    """``layer`` as a scan over the stacked layers runs it: under
-    ``cfg.remat`` its backward pass recomputes the layer from its input,
-    all but the flash kernel's ``out`` and ``lse``, which are kept (the
-    kernel's call is the dearest thing in a layer per byte it leaves,
-    and its backward kernel reads just these two).  The jnp and ring
-    attention paths make no such names and are recomputed whole."""
+def remat_layer(layer, cfg: TransformerConfig,
+                kind: Tuple[str, ...] = (), mesh=None):
+    """``layer(carry, scanned)`` as a scan over the stacked layers runs
+    it: under ``cfg.remat`` its backward pass recomputes the layer from
+    its input, all but what its policy keeps.  Always the flash kernel's
+    ``out`` and ``lse`` (the kernel's call is the dearest thing in a
+    layer per byte it leaves, and its backward kernel reads just these
+    two); beyond them, those of the layer's named cut points that the
+    step's plan found room for on the device, in the order of work
+    avoided per byte (``models/remat.py``; ``make_train_step`` plans,
+    from the step's own trace).  Where nothing plans (a CPU, a trace
+    outside a step) it is those two names.  ``kind`` and ``mesh``: what
+    the plan calls the run, and the mesh its token axes are split over.
+    The jnp and ring attention paths make no such two names and are
+    recomputed from q, k and v."""
     if not cfg.remat:
         return layer
-    return jax.checkpoint(
-        layer, policy=jax.checkpoint_policies.save_only_these_names(
-            *FLASH_RESIDUAL_NAMES))
+    return jax.checkpoint(layer, policy=remat.policy(kind, mesh))
 
 
 def run_stack(x, stack: Dict, kind, positions, cfg: TransformerConfig,
@@ -555,7 +575,8 @@ def run_stack(x, stack: Dict, kind, positions, cfg: TransformerConfig,
             lp = dict(lp, moe=dict(lp["moe"], bias=bias))
         return apply_layer(x, lp, positions, cfg, mesh, mask, kind)
 
-    return jax.lax.scan(remat_layer(layer, cfg), x, (stack, moe_bias))
+    return jax.lax.scan(remat_layer(layer, cfg, kind, mesh), x,
+                        (stack, moe_bias))
 
 
 def run_period(x, stacks, runs, positions, cfg: TransformerConfig, mesh=None,
@@ -746,7 +767,17 @@ def make_train_step(cfg: TransformerConfig, tx, mesh=None,
     the routers' correction bias (``moe_bias_rate``), the loss is
     called with it as a third argument, its counters carry every expert
     layer's loads (``moe_router_load``), and the step moves the bias by
-    them."""
+    them.
+
+    Under ``cfg.remat``, what the layer scans keep for the backward pass
+    is planned where the step is traced (``remat.value_and_grad``): on a
+    device that reports a memory limit the objective is traced once,
+    forward only, the plan is read from that jaxpr and the device's free
+    bytes, and the jaxpr is differentiated; on one that reports none the
+    objective is differentiated as it always was."""
+    plans: Dict = {}      # the plans of this step's traces, by shapes
+    kept: Dict = {}       # ... and the last one, as published
+
     def train_step(state, batch):
         compute = loss_override or (
             lambda p, b, *bias: loss_and_counters(p, b, cfg, mesh, *bias))
@@ -756,8 +787,10 @@ def make_train_step(cfg: TransformerConfig, tx, mesh=None,
             out = compute(p, batch, *bias)
             return out if isinstance(out, tuple) else (out, {})
 
-        (loss, counters), grads = jax.value_and_grad(
-            objective, has_aux=True)(state["params"])
+        ((loss, counters), grads), plan = remat.value_and_grad(
+            objective, state["params"], mesh, plans)
+        kept.clear()
+        kept.update(plan)
         with jax.named_scope("optimizer"):
             updates, new_opt = tx.update(grads, state["opt"],
                                          state["params"])
@@ -780,7 +813,7 @@ def make_train_step(cfg: TransformerConfig, tx, mesh=None,
         return new_state, metrics
 
     donate = (0,)
-    return _TracedStep(jax.jit(train_step, donate_argnums=donate))
+    return _TracedStep(jax.jit(train_step, donate_argnums=donate), kept)
 
 
 class _TracedStep:
@@ -794,24 +827,39 @@ class _TracedStep:
     ``"train_step"``: the jitted function with the arguments' shapes,
     dtypes and shardings (the state is donated, so nothing concrete is
     kept).  Whoever reads that entry pays for its manifest, afterwards;
-    the step pays one ``tree.map``."""
+    the step pays one ``tree.map``.  The entry's ``memory`` carries,
+    under ``"remat"``, what the step's layer scans keep for the backward
+    pass (``models/remat.py``: the names a run, their bytes, the budget
+    they were held to, the names refused for room, and ``plan_seconds``,
+    what making the plan cost the trace), which is also on ``/metrics``
+    as ``ray_tpu.train.remat_kept_bytes``, ``remat_budget_bytes`` and
+    ``remat_plan_seconds`` once the step has been traced."""
 
-    def __init__(self, jitted):
+    def __init__(self, jitted, kept=None):
         self._jitted = jitted
+        self._kept = {} if kept is None else kept   # the plan, as published
         self._offered = False
 
     def __call__(self, state, batch):
         # (a call under a trace, ``jax.eval_shape(step, ...)``, has no
         # shardings to offer: the first concrete call registers)
-        if not self._offered and not any(
-                isinstance(x, jax.core.Tracer)
-                for x in jax.tree.leaves((state, batch))):
+        offer = not self._offered and not any(
+            isinstance(x, jax.core.Tracer)
+            for x in jax.tree.leaves((state, batch)))
+        if offer:
             self._offered = True
             tracing.register_program(
                 "train_step", self._jitted,
-                *jax.tree.map(_abstract_argument, (state, batch)))
+                *jax.tree.map(_abstract_argument, (state, batch)),
+                memory=lambda: {"remat": dict(self._kept)})
         with tracing.span("train.model_step", category="train"):
-            return self._jitted(state, batch)
+            out = self._jitted(state, batch)
+        if offer:
+            from ray_tpu._private.metrics_agent import record_internal
+            for name in ("kept_bytes", "budget_bytes", "plan_seconds"):
+                record_internal(f"ray_tpu.train.remat_{name}",
+                                float(self._kept.get(name) or 0))
+        return out
 
     def __getattr__(self, name):
         return getattr(self._jitted, name)

@@ -61,11 +61,15 @@ float32 VMEM scratch, as grouped K/V sums ``dk``/``dv``.
 
 The ``custom_vjp``'s residuals are (q, k, v, the rotary pair, out, lse).  ``out`` and
 ``lse`` carry the names ``RESIDUAL_NAMES``: a layer rematerialised under
-``models.transformer.remat_layer`` keeps those two (as much again as
-the layer's input where H * D is ``d_model``, and 4 bytes a row) and so
-runs this forward kernel once a layer, not again in the backward pass;
-q, k and v -- three times the bytes for less time -- are recomputed from
-the layer's input like everything else.
+``models.transformer.remat_layer`` keeps those two always (as much
+again as the layer's input where H * D is ``d_model``, and 4 bytes a
+row) and so runs this forward kernel once a layer, not again in the
+backward pass; q, k and v -- three times the bytes for less time -- are
+the caller's to name, and are kept where the step's plan finds room for
+them (``models/remat.py``), else recomputed from the layer's input.
+(Named here, heads first as the kernels read them, they came back no
+faster on the v5e than named by the caller: ledger and PERF.md section
+6, PR 38.)
 
 ``attention()`` picks the kernel on TPU and the jnp reference
 (ops.ring_attention.full_attention) elsewhere; tests run the kernel in
@@ -442,8 +446,16 @@ def _flash_backward(qh, kh, vh, rope, out, lse, dout, mask, interpret):
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7))
 def _flash(qh, kh, vh, rope, mask, block_q, block_k, interpret):
-    return _flash_forward(qh, kh, vh, rope, mask, block_q, block_k,
-                          interpret)[0]
+    return _kept_out(_flash_forward(qh, kh, vh, rope, mask, block_q, block_k,
+                                    interpret)[0])
+
+
+def _kept_out(out):
+    """``out`` under its name in the primal function too.  A checkpoint
+    policy reads the ``fwd`` rule's names; whoever reads the forward's
+    jaxpr alone, as ``models/remat.py`` does to plan, sees here that
+    ``out`` is kept and the kernel not run again."""
+    return checkpoint_name(out, RESIDUAL_NAMES[0])
 
 
 def _flash_vjp_fwd(qh, kh, vh, rope, mask, block_q, block_k, interpret):

@@ -68,13 +68,20 @@ Off the TPU, and under ``use_pallas=False``, the same lines are batched
 ``lax.scan`` over chunks (``_chunked_rule``): the oracle the kernels are
 held to in interpret mode.
 
-Under ``models.transformer.remat_layer`` nothing of this is kept: the
-layer's backward runs the forward kernel again, as the ``fwd`` rule
-(7.7 ms a call on the v5e at [2 x 32 heads, 8,192, 128], the backward
-kernel 11.5: 23 + 23 + 34 ms of a 565 ms step over three layers; keeping
-the entering states would hold 0.54 GB a layer to save the second 23:
-PERF.md section 6, PR 35 and PR 36).  ``optimize_remat`` lets the forward
-scan, which needs no residual, run the kernel that writes ``o`` alone.
+Under ``models.transformer.remat_layer`` nothing of the rule is kept,
+whatever room the step's plan finds (``models/remat.py``): the layer's
+backward runs the forward kernel again, as the ``fwd`` rule (7.7 ms a
+call on the v5e at [2 x 32 heads, 8,192, 128], the backward kernel 11.5:
+23 + 23 + 34 ms of a 565 ms step over three layers; keeping ``o`` and the
+entering states would hold 0.6 GB a layer to save the second 23:
+PERF.md section 6, PR 35, PR 36 and PR 39).  ``optimize_remat`` lets the
+forward scan, which needs no residual, run the kernel that writes ``o``
+alone; it also wraps the ``fwd`` rule in one equation that a checkpoint
+policy cannot look into, so ``o`` and the states cannot carry names for
+the plan to keep: the two are one choice, and the lean forward is the
+one made.  What the plan may keep of a delta layer lies around the rule
+(``models/gdn.py``: the fused projections; the convolution's sum and
+output are named and priced, and not worth their float32 bytes).
 """
 
 from __future__ import annotations

@@ -81,7 +81,9 @@ def make_pp_loss_fn(cfg: TransformerConfig, mesh, n_micro: int):
             def body(h, lp):
                 return apply_layer(h, lp, positions, cfg, mesh=None)[0], None
 
-            return jax.lax.scan(remat_layer(body, cfg), x, layers)[0]
+            return jax.lax.scan(
+                remat_layer(body, cfg, cfg.layer_pattern[0][:2]), x,
+                layers)[0]
 
         def ce(h, tgt):
             logits = jnp.einsum(

@@ -305,7 +305,7 @@ PHASES = ("fwd", "bwd", "recompute")
 _SCOPES = frozenset(STEP_SCOPES)
 _ENCLOSING_OPCODES = ("while", "conditional", "call")
 _MEMORY_KEYS = ("temp_size_in_bytes", "argument_size_in_bytes",
-                "output_size_in_bytes")
+                "output_size_in_bytes", "peak_memory_in_bytes")
 
 _INSTRUCTION = re.compile(r"^\s*(?:ROOT\s+)?%?([\w.\-]+) = ")
 # The opcode is the first lower-case word before a "(" that follows
@@ -380,8 +380,8 @@ class _Program:
     arguments are all it holds until then, and it lets go of them
     afterwards."""
 
-    def __init__(self, jitted, abstract_args):
-        self._unresolved = (jitted, abstract_args)
+    def __init__(self, jitted, abstract_args, memory=None):
+        self._unresolved = (jitted, abstract_args, memory)
         self._manifest = None
         self._resolving = diag_lock("tracing._Program._resolving")
 
@@ -393,14 +393,16 @@ class _Program:
         return self._manifest[key]
 
     @staticmethod
-    def _resolve(jitted, abstract_args) -> dict:
+    def _resolve(jitted, abstract_args, more_memory) -> dict:
         t0 = time.perf_counter()
         compiled = jitted.lower(*abstract_args).compile()
         text = compiled.as_text()
         manifest = manifest_of_text(text)
         memory = compiled.memory_analysis()
         manifest["memory"] = {} if memory is None else {
-            key: int(getattr(memory, key)) for key in _MEMORY_KEYS}
+            key: int(getattr(memory, key, 0)) for key in _MEMORY_KEYS}
+        if more_memory is not None:
+            manifest["memory"].update(more_memory())
         manifest["text_bytes"] = len(text)
         manifest["resolve_s"] = time.perf_counter() - t0
         return manifest
@@ -409,13 +411,17 @@ class _Program:
 _programs: Dict[str, _Program] = {}
 
 
-def register_program(name: str, jitted, *abstract_args) -> None:
+def register_program(name: str, jitted, *abstract_args,
+                     memory=None) -> None:
     """Offer the program that ``jitted(*args)`` runs under ``name``;
     ``abstract_args`` are the arguments as ``jax.ShapeDtypeStruct``
-    leaves (a donated state cannot be kept).  Costs nothing until
+    leaves (a donated state cannot be kept).  ``memory()``: what the
+    program itself knows of its memory (the train step: what its layer
+    scans keep for the backward pass), joined to the entry's
+    ``"memory"`` when it resolves.  Costs nothing until
     ``programs()[name]`` is subscripted.  One entry a name: the last
     registration replaces, and frees, the one before."""
-    program = _Program(jitted, abstract_args)
+    program = _Program(jitted, abstract_args, memory)
     with _lock:
         _programs[name] = program
 

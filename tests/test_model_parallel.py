@@ -382,12 +382,16 @@ def test_pipeline_parallel_matches_single_device():
 @pytest.mark.parametrize("attention", ["reference", "kernel"])
 def test_pipeline_stage_remat_matches_no_remat(attention, monkeypatch):
     """The stage's scan shares ``remat_layer`` with ``run_layers``:
-    ``remat=True`` (now keeping the flash kernel's residuals, where the
-    kernel runs: interpreted here) gives the loss and the gradients of
-    ``remat=False`` over pp=2 x dp=2."""
+    ``remat=True`` -- keeping the flash kernel's residuals, where the
+    kernel runs (interpreted here), and under a plan with room for them
+    the layer's named products too (``models/remat.py``) -- gives the
+    loss and the gradients of ``remat=False`` over pp=2 x dp=2.  Every
+    tick of the schedule keeps its stage's stacks: three ticks of two
+    layers are three runs of two, and under the plan the kept products
+    are named once a tick (the forward), under today's twice."""
     import functools
 
-    from ray_tpu.models import transformer
+    from ray_tpu.models import remat as remat_plan, transformer
     from ray_tpu.ops.flash_attention import flash_attention
     from ray_tpu.parallel.mesh import MeshConfig, build_mesh
     from ray_tpu.parallel.pipeline import (make_pp_loss_fn,
@@ -396,28 +400,47 @@ def test_pipeline_stage_remat_matches_no_remat(attention, monkeypatch):
         monkeypatch.setattr(transformer, "flash_or_ref_attention",
                             functools.partial(flash_attention,
                                               interpret=True))
+    # (at this width no product is dearer to make again than to keep)
+    monkeypatch.setattr(remat_plan, "_KEPT_BYTE_MOVES", 0.0)
     base = transformer.TransformerConfig(
         vocab_size=64, d_model=32, n_layers=4, n_heads=2, d_ff=64,
         max_seq_len=128, dtype=jnp.float32, context_parallel=False)
     tokens = jax.random.randint(jax.random.PRNGKey(1), (4, 129), 0, 64,
                                 dtype=jnp.int32)
     mesh = build_mesh(MeshConfig(dp=2, pp=2), devices=jax.devices()[:4])
-    got = {}
+    got, named, plans = {}, {}, []
     with mesh:
-        for remat in (True, False):
-            cfg = dataclasses.replace(base, remat=remat)
+        for how in ("planned", "today", "no remat"):
+            cfg = dataclasses.replace(base, remat=how != "no remat")
             state, _ = make_pp_train_state(jax.random.PRNGKey(0), cfg, mesh)
             pp_loss = make_pp_loss_fn(cfg, mesh, n_micro=2)
-            fn = jax.value_and_grad(
-                lambda p: pp_loss(p, {"tokens": tokens}))
+            monkeypatch.setattr(
+                remat_plan, "device_memory", lambda mesh=None, how=how: (
+                    (1 << 40, 0) if how == "planned" else None))
+
+            def fn(p):
+                ((loss, _), grads), plan = remat_plan.value_and_grad(
+                    lambda p: (pp_loss(p, {"tokens": tokens}), {}), p)
+                plans.append(plan)
+                return loss, grads
+
             text = str(jax.make_jaxpr(fn)(state["params"]))
             assert ("name=flash_attention_fwd" in text) == (
                 attention == "kernel")
-            got[remat] = jax.jit(fn)(state["params"])
-    assert float(got[True][0]) == pytest.approx(float(got[False][0]),
-                                                rel=1e-6)
-    for a, b in zip(jax.tree.leaves(got[True][1]),
-                    jax.tree.leaves(got[False][1])):
-        assert bool(jnp.all(jnp.isfinite(a)))
-        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
-                                   rtol=1e-5, atol=1e-7)
+            got[how] = jax.jit(fn)(state["params"])
+            named[how] = text.count("name=attn_q]"), text.count(
+                "name=mid_residual]")
+    runs = plans[0]["runs"]
+    # (inside the shard_map: a device's shapes as they are)
+    assert [(r["layers"], r["kind"]) for r in runs] == [(2, "mha+dense")] * 3
+    assert all({"attn_q", "attn_k", "attn_v", "mid_residual"}
+               <= set(r["names"]) for r in runs)
+    assert named == {"planned": (3, 3), "today": (6, 6), "no remat": (3, 3)}
+    for other in ("today", "no remat"):
+        assert float(got["planned"][0]) == pytest.approx(
+            float(got[other][0]), rel=1e-6)
+        for a, b in zip(jax.tree.leaves(got["planned"][1]),
+                        jax.tree.leaves(got[other][1])):
+            assert bool(jnp.all(jnp.isfinite(a)))
+            np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                       rtol=1e-5, atol=1e-7)

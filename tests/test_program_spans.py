@@ -492,10 +492,72 @@ def test_the_manifest_has_each_scope_of_the_configuration(kind):
         name.startswith("while") for name in entry["enclosing"])
     assert set(entry["memory"]) == {
         "temp_size_in_bytes", "argument_size_in_bytes",
-        "output_size_in_bytes"}
+        "output_size_in_bytes", "peak_memory_in_bytes", "remat"}
     assert entry["memory"]["argument_size_in_bytes"] > 0
+    # no device to ask here: the layer scans keep what they always kept
+    assert entry["memory"]["remat"] == {
+        "budget_bytes": None, "kept_bytes": 0, "runs": []}
     assert entry["text_bytes"] > 0 and entry["resolve_s"] > 0
     tracing.clear()
+
+
+@pytest.mark.parametrize("kind", sorted(MANIFESTS))
+def test_the_registry_entrys_memory_carries_the_plan(kind, monkeypatch):
+    """Where the device reports room (stood in for here) the tiny step's
+    layer scans keep more than the flash kernel's two names, and what
+    they keep -- names a run, bytes a layer and in all, the budget, the
+    names refused for room, and ``plan_seconds``, what making the plan
+    cost the trace -- is in the registry entry's ``memory`` (the
+    ``step_scopes`` line of a traced run prints it whole) and on
+    /metrics; the step compiles once, the manifest read afterwards
+    neither traces nor compiles, and the step's numbers are the
+    unplanned step's."""
+    import jax
+    import numpy as np
+    from jax import monitoring
+
+    from ray_tpu._private.metrics_agent import get_metrics_registry
+    from ray_tpu.models import remat
+    tracing.clear()
+    step, state, batch = _tiny_step(kind)
+    plain = jax.device_get(step(jax.tree.map(jax.numpy.copy, state),
+                                batch)[1])
+    assert tracing.programs()["train_step"]["memory"]["remat"]["runs"] == []
+    tracing.clear()
+    step, _, _ = _tiny_step(kind)
+    monkeypatch.setattr(remat, "device_memory",
+                        lambda mesh=None: (10 ** 9, 0))     # room for all
+    # (at a tiny width no product is dearer to make again than to keep)
+    monkeypatch.setattr(remat, "_KEPT_BYTE_MOVES", 0.0)
+    fired = []
+    monitoring.register_event_duration_secs_listener(
+        lambda name, secs, **_: fired.append(name.rsplit("/", 1)[-1]))
+    planned = jax.device_get(step(state, batch)[1])
+    assert fired.count("backend_compile_duration") == 1
+    del fired[:]
+    plan = tracing.programs()["train_step"]["memory"]["remat"]
+    # jit's own caches answered the manifest: nothing lowered or compiled
+    assert set(fired) <= {"jaxpr_trace_duration"}
+    tracing.clear()
+    assert plan["bytes_limit"] == 10 ** 9 and plan["bytes_in_use"] == 0
+    assert plan["budget_bytes"] == int(remat.SAFETY * (
+        10 ** 9 - plan["step_bytes"]))
+    assert plan["runs"] and all(
+        "mid_residual" in run["names"] and run["refused"] == []
+        and run["bytes_a_layer"] > 0 for run in plan["runs"])
+    assert plan["kept_bytes"] == sum(
+        run["layers"] * run["bytes_a_layer"] for run in plan["runs"])
+    assert sorted(run["kind"] for run in plan["runs"]) == {
+        "dense": ["mha+dense"], "block_diffusion": ["mha+moe"],
+        "latent": ["mla+dense", "mla+moe", "mla+moe"],
+        "hybrid": ["gdn+moe", "mha+moe"]}[kind]
+    assert 0 < plan["plan_seconds"] < 5 and plan["trace_seconds"] > 0
+    exposed = get_metrics_registry().render_prometheus().splitlines()
+    for name in ("kept_bytes", "budget_bytes", "plan_seconds"):
+        assert f"ray_tpu_train_remat_{name} {float(plan[name])}" in exposed
+    for name, value in plain.items():
+        np.testing.assert_allclose(planned[name], value, rtol=2e-5,
+                                   atol=1e-6, err_msg=name)
 
 
 def test_the_registry_costs_no_compile_unread_and_no_step_compiles_after():

@@ -1,0 +1,516 @@
+"""What a rematerialised layer keeps (``ray_tpu/models/remat.py``): the
+planner as a pure function of surveys and a budget; for each kind of
+layer a two-layer scan, and for each of the four tiny steps the step
+itself, whose numbers under a generous plan equal those under today's
+two names and under ``remat=False``; and what the plan costs as a
+count: a planned trace calls the objective, each layer's function and
+each kernel's forward no more often than an unplanned one."""
+
+import ast
+import collections
+import functools
+import os
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from ray_tpu.models import remat, transformer
+from ray_tpu.models.gdn import GDNConfig
+from ray_tpu.models.mla import MLAConfig
+from ray_tpu.models.transformer import (TransformerConfig, apply_layer,
+                                        init_stack, run_stack)
+from ray_tpu.ops.flash_attention import RESIDUAL_NAMES
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+_BASE = dict(vocab_size=64, d_model=32, n_heads=2, d_ff=48, max_seq_len=32,
+             dtype=jnp.float32, context_parallel=False)
+# kind -> (the run's (attention, ffn), the configuration, names a plan
+# with room for everything has to keep at these sizes).  What it leaves
+# spares nothing once what is upstream of it is kept.
+KINDS = {
+    "mha": (("mha", "dense"), dict(n_layers=2),
+            {"mid_residual", "attn_q", "attn_k", "attn_v", "ffn_gate",
+             "ffn_up"}),
+    "mha-gated": (("mha", "dense"),
+                  dict(n_layers=2, attn_out_gate=True, qk_norm=True,
+                       head_dim=16, rotary_dim=8, norm_plus_one=True),
+                  {"mid_residual", "attn_v", "ffn_gate", "ffn_up"}),
+    "mla": (("mla", "dense"),
+            dict(n_layers=2, mla=MLAConfig(
+                q_lora_rank=24, kv_lora_rank=16, qk_nope_head_dim=16,
+                qk_rope_head_dim=8, v_head_dim=16)),
+            {"mid_residual", "mla_q_down", "mla_kv_down", "mla_k_rope",
+             "ffn_gate", "ffn_up"}),
+    "gdn": (("gdn", "dense"),
+            dict(layer_pattern=(("gdn", "dense", 2),), gdn=GDNConfig(
+                num_key_heads=2, num_value_heads=4, key_head_dim=16,
+                value_head_dim=16, chunk=16)),
+            {"mid_residual", "gdn_ba", "gdn_qkvz", "ffn_gate", "ffn_up"}),
+    "moe-shared": (("mha", "moe"),
+                   dict(n_layers=2, moe_experts=4, moe_top_k=2,
+                        moe_shared_width=16, moe_shared_gate=True),
+                   {"mid_residual", "moe_scores", "moe_shared_gate",
+                    "moe_shared_up", "attn_q", "attn_k", "attn_v"}),
+    "moe-sigmoid": (("mha", "moe"),
+                    dict(n_layers=2, moe_experts=4, moe_top_k=2,
+                         moe_scoring="sigmoid", moe_bias_rate=0.01),
+                    {"mid_residual", "moe_scores", "attn_v"}),
+}
+ROOM = (1 << 44, 0)          # a device with room for everything
+FULL = (1 << 20, 1 << 20)    # ... and one that is full already
+
+
+@pytest.fixture(autouse=True)
+def every_candidate_that_spares_anything(monkeypatch):
+    """At these widths (32 columns) no product is dearer to make again
+    than an array is to keep (``_KEPT_BYTE_MOVES``: that takes some 500
+    columns in bfloat16), so the tests order and keep whatever spares
+    any work at all; ``test_a_name_has_to_spare_more_than_keeping_it_
+    costs`` holds the threshold itself, at a cell's widths."""
+    monkeypatch.setattr(remat, "_KEPT_BYTE_MOVES", 0.0)
+
+
+def _device(monkeypatch, memory):
+    monkeypatch.setattr(remat, "device_memory", lambda mesh=None: memory)
+
+
+def _two_layers(kind, remat_on=True, length=32, **more):
+    """-> (cfg, loss(x, stack), x, stack): the scan over two layers of
+    the kind, as ``run_layers`` runs it."""
+    run, extra, _ = KINDS[kind]
+    cfg = TransformerConfig(**{**_BASE, **extra, **more, "remat": remat_on,
+                               "max_seq_len": length})
+    stack = init_stack(jax.random.PRNGKey(2), cfg, *run, 2)
+    # (norm weights off their start, so that their gradients say something)
+    stack = jax.tree.map(
+        lambda a: a + 0.05 * jax.random.normal(jax.random.PRNGKey(a.size),
+                                               a.shape, a.dtype), stack)
+    x = jax.random.normal(jax.random.PRNGKey(3), (2, length, cfg.d_model),
+                          jnp.float32)
+    positions = jnp.broadcast_to(jnp.arange(length, dtype=jnp.int32)[None],
+                                 (2, length))
+
+    def loss(x, stack):
+        return jnp.sum(run_stack(x, stack, run, positions, cfg)[0] ** 2)
+
+    return cfg, loss, x, stack
+
+
+def _planned(loss, reports=None, mesh=None):
+    """``jax.grad(loss, (0, 1))`` as a step takes it: through
+    ``remat.value_and_grad``; the plans it got land on ``reports``."""
+    def grads(x, stack):
+        (_, got), report = remat.value_and_grad(
+            lambda p: (loss(*p), {}), (x, stack), mesh)
+        if reports is not None:
+            reports.append(report)
+        return got
+
+    return grads
+
+
+def _names_in(jaxpr, found=None):
+    """name -> how many ``checkpoint_name`` equations of it the jaxpr
+    and everything it calls hold."""
+    found = collections.Counter() if found is None else found
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "name":
+            found[eqn.params["name"]] += 1
+        for value in eqn.params.values():
+            for item in (value if isinstance(value, (tuple, list))
+                         else (value,)):
+                inner = getattr(item, "jaxpr", item)
+                if hasattr(inner, "eqns"):
+                    _names_in(inner, found)
+    return found
+
+
+@pytest.mark.parametrize("kind", sorted(KINDS))
+def test_a_generous_plan_changes_no_number_and_spares_the_named_products(
+        kind, monkeypatch):
+    """Gradients of the two-layer scan under a plan with room for every
+    candidate, under today's plan (no device to ask: the flash kernel's
+    two names, which the reference attention here does not even make)
+    and under ``remat=False`` are the same numbers (to a float32
+    rounding: XLA fuses, and so sums, the three programs differently, as
+    PR 31 found on the chip).  In the gradient's jaxpr a product the
+    plan keeps is named once (the forward scan), one it leaves twice
+    (again in the backward scan's recomputation) unless the backward
+    needs it no more; without remat nothing is made twice."""
+    got, named, reports = {}, {}, []
+    for how in ("generous", "today", "no remat"):
+        _, loss, x, stack = _two_layers(kind, remat_on=how != "no remat")
+        _device(monkeypatch, ROOM if how == "generous" else None)
+        fn = _planned(loss, reports)
+        named[how] = _names_in(jax.make_jaxpr(fn)(x, stack).jaxpr)
+        got[how] = jax.jit(fn)(x, stack)
+    (run,) = reports[0]["runs"]
+    wanted = set(run["names"])
+    assert wanted >= KINDS[kind][2] and run["refused"] == []
+    assert run["layers"] == 2
+    assert reports[0]["kept_bytes"] == 2 * run["bytes_a_layer"] > 0
+    assert all(r["kept_bytes"] == 0 and r["runs"] == []
+               for r in reports[2:])
+    left = set(named["today"]) - wanted
+    once = named["no remat"]         # (a name may be on several values)
+    assert all(named["generous"][n] == once[n] for n in wanted)
+    # (what the plan leaves is made again, or hangs on nothing any more)
+    assert all(named["generous"][n] in (once[n], 2 * once[n]) for n in left)
+    assert all(named["today"][n] == 2 * once[n] for n in wanted | left)
+    for other in ("today", "no remat"):
+        for a, b in zip(jax.tree.leaves(got["generous"]),
+                        jax.tree.leaves(got[other])):
+            assert bool(jnp.all(jnp.isfinite(a)))
+            np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                       rtol=1e-5, atol=5e-6)
+
+
+def test_a_generous_plan_keeps_the_interpreted_kernels_residuals_too(
+        monkeypatch):
+    """With the flash kernel in the layer (interpreted here; the chip's
+    dispatch) the plan keeps its ``out`` and ``lse`` as always and q, k,
+    v beside them: the gradient holds each kernel once, the kernel's
+    forward body is traced as often planned as not, and the numbers
+    equal today's within the kernel tests' tolerance."""
+    import importlib
+    fa = importlib.import_module("ray_tpu.ops.flash_attention")
+    monkeypatch.setattr(transformer, "flash_or_ref_attention",
+                        functools.partial(fa.flash_attention,
+                                          interpret=True))
+    forwards = []
+    real = fa._flash_forward
+    monkeypatch.setattr(fa, "_flash_forward", lambda *a, **k: (
+        forwards.append(1), real(*a, **k))[1])
+    _, loss, x, stack = _two_layers("mha", length=128, head_dim=64)
+    got, traced = {}, {}
+    for how, memory in (("generous", ROOM), ("full", FULL), ("today", None)):
+        _device(monkeypatch, memory)
+        reports = []
+        fn = _planned(loss, reports)
+        jax.clear_caches()       # (the kernel's wrapper is a ``jax.jit``)
+        del forwards[:]
+        text = str(jax.make_jaxpr(fn)(x, stack))
+        traced[how] = len(forwards)
+        got[how] = jax.jit(fn)(x, stack)
+        assert text.count("name=flash_attention_fwd") == 1
+        assert text.count("name=flash_attention_bwd") == 1
+        kept = set(reports[0]["runs"][0]["names"]) if reports[0]["runs"] \
+            else set()
+        assert (kept >= {"attn_q", "attn_k", "attn_v", "mid_residual"}) \
+            == (how == "generous")
+    assert traced["generous"] == traced["full"] == traced["today"] > 0
+    for other in ("full", "today"):
+        for a, b in zip(jax.tree.leaves(got["generous"]),
+                        jax.tree.leaves(got[other])):
+            np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                       rtol=2e-5, atol=2e-5)
+
+
+def _survey_of(kind, layers=1, **more):
+    """The survey of one layer of the kind, from its forward jaxpr."""
+    cfg, _, x, stack = _two_layers(kind, **more)
+    run = KINDS[kind][0]
+    positions = jnp.zeros(x.shape[:2], jnp.int32)
+    one = jax.tree.map(lambda a: a[0], stack)
+    jaxpr = jax.make_jaxpr(
+        lambda x, lp: apply_layer(x, lp, positions, cfg, kind=run))(x, one)
+    return remat.survey(jaxpr.jaxpr, layers, run, x.size * 4)
+
+
+def _surveys():
+    """Two runs' surveys at sizes where bytes differ by name: the dense
+    kind over 3 layers and the expert kind over 2."""
+    return [_survey_of("mha", 3), _survey_of("moe-shared", 2)]
+
+
+def test_no_budget_is_exactly_todays_two_names(monkeypatch):
+    """Budget 0, none or negative (a step that fills the device
+    already): every run keeps ``RESIDUAL_NAMES``, nothing else, and no
+    candidate is even ordered; a device that reports no limit is not
+    even planned for: its policy is ``save_only_these_names`` of the
+    two, the parent's."""
+    surveys = _surveys()
+    for budget in (0, None, -5):
+        names, report = remat.make_plan(surveys, budget)
+        assert names == [(), ()]
+        assert report["kept_bytes"] == 0
+        assert all(r["names"] == [] for r in report["runs"])
+    assert remat.no_plan() == {"budget_bytes": None, "kept_bytes": 0,
+                               "runs": []}
+    assert remat.BASE_NAMES == RESIDUAL_NAMES
+    assert remat.device_memory() is None
+    policy = remat.policy(("mha", "dense"))
+    assert not isinstance(policy, remat.Keeps)
+    assert "save_only_these_names" in policy.__qualname__
+    keeps = remat.Keeps(("mha", "dense"), 1)
+    assert keeps.names == RESIDUAL_NAMES
+    keeps.keep(["attn_v"])
+    assert keeps.names == RESIDUAL_NAMES + ("attn_v",)
+
+
+def test_a_growing_budget_keeps_a_growing_prefix_of_the_worth_order():
+    """The order is by work avoided per byte, each candidate given those
+    ahead of it, and never rises; a budget keeps the longest prefix that
+    fits it and not a byte more; what follows the cut is reported as
+    refused, with its bytes."""
+    surveys = _surveys()
+    order = remat.worth_order(surveys)
+    assert len(order) > 8
+    worth = [c.work / c.bytes for c in order]
+    assert all(w > 0 for w in worth)
+    # (a candidate ahead may raise one behind it -- two halves of one
+    # product -- but never above itself)
+    assert all(b <= a * (1 + 1e-9) or order[i].run != order[i + 1].run
+               for i, (a, b) in enumerate(zip(worth, worth[1:])))
+    for c in order:
+        s = surveys[c.run]
+        assert c.bytes == s.names[c.name] * s.layers
+    sums = np.cumsum([c.bytes for c in order])
+    before = -1
+    for budget in [1, int(sums[0]) - 1, int(sums[0]), int(sums[3]),
+                   int(sums[3]) + 1, int(sums[-1]), 1 << 40]:
+        names, report = remat.make_plan(surveys, budget)
+        n = int(np.searchsorted(sums, budget, side="right"))
+        kept = [(r, name) for r, run in enumerate(report["runs"])
+                for name in run["names"]]
+        assert sorted(kept) == sorted((c.run, c.name) for c in order[:n])
+        assert kept == [(r, name) for r, run in enumerate(names)
+                        for name in run]
+        assert report["kept_bytes"] == (sums[n - 1] if n else 0) <= budget
+        refused = [(r, name, b) for r, run in enumerate(report["runs"])
+                   for name, b in run["refused"]]
+        assert sorted(refused) == sorted(
+            (c.run, c.name, c.bytes) for c in order[n:])
+        assert n >= before
+        before = n
+    assert before == len(order)
+
+
+def test_bytes_are_counted_a_device_under_a_dp_sp_mesh(monkeypatch):
+    """A named value's bytes on one device are its bytes over the
+    devices that split the token axes: the survey divides by ``dp x
+    sp`` of the mesh the run was given (``tp`` is left out: it does not
+    split every candidate)."""
+    from ray_tpu.parallel.mesh import MeshConfig, build_mesh
+    whole = _survey_of("mha")
+    cfg, loss, x, stack = _two_layers("mha")
+    mesh = build_mesh(MeshConfig(dp=2, sp=2), devices=jax.devices()[:4])
+    positions = jnp.zeros((2, 32), jnp.int32)
+
+    def under_mesh(x, stack):
+        return jnp.sum(run_stack(x, stack, ("mha", "dense"), positions, cfg,
+                                 mesh)[0] ** 2)
+
+    _device(monkeypatch, ROOM)
+    reports = []
+    with mesh:
+        jax.make_jaxpr(_planned(under_mesh, reports, mesh))(x, stack)
+    jax.make_jaxpr(_planned(loss, reports))(x, stack)
+    (shared,), (alone,) = (r["runs"] for r in reports)
+    assert shared["names"] == alone["names"]
+    assert shared["bytes_a_layer"] * 4 == alone["bytes_a_layer"] == sum(
+        whole.names[n] for n in alone["names"])
+
+
+def test_the_budget_is_counted_from_the_device(monkeypatch):
+    """Where the device reports no limit (this CPU) nothing is planned;
+    else the budget is ``SAFETY`` of the limit less what is resident
+    less what the step needs whatever is kept (``step_bytes``: the
+    stacks, and the larger of the head and of the gradients beside one
+    layer's backward), and the objective is traced once all the same."""
+    _, loss, x, stack = _two_layers("mha")
+    traced = []
+
+    def counting(x, stack):
+        traced.append(1)
+        return loss(x, stack)
+
+    reports = []
+    jax.make_jaxpr(_planned(counting, reports))(x, stack)
+    assert reports.pop() == remat.no_plan() and len(traced) == 1
+    limit, in_use = 1_330_000, 1_000_000
+    _device(monkeypatch, (limit, in_use))
+    jax.make_jaxpr(_planned(counting, reports))(x, stack)
+    assert len(traced) == 2
+    report = reports.pop()
+    assert (report["bytes_limit"], report["bytes_in_use"]) == (limit, in_use)
+    (run,) = report["runs"]
+    # two layers' inputs at the least, and the gradients
+    grads = sum(a.size * 4 for a in jax.tree.leaves((x, stack)))
+    assert report["step_bytes"] > 2 * x.size * 4 + grads
+    assert report["budget_bytes"] == int(remat.SAFETY * (
+        limit - in_use - report["step_bytes"]))
+    assert 0 < report["kept_bytes"] <= report["budget_bytes"]
+    assert run["names"] and run["refused"]
+    assert 0 < report["plan_seconds"] < 5 and report["trace_seconds"] > 0
+    # a device that is full already: today's plan
+    _device(monkeypatch, FULL)
+    jax.make_jaxpr(_planned(counting, reports))(x, stack)
+    report = reports.pop()
+    assert report["budget_bytes"] < 0 and report["kept_bytes"] == 0
+    assert report["runs"][0]["names"] == []
+
+
+def test_a_period_keeps_its_runs_stacks_every_repeat(monkeypatch):
+    """A run inside a period is the body of the scan over the repeats:
+    its stacks are kept ``repeats`` times over, and the plan, which
+    reads the scans' lengths from the step's jaxpr, counts them so."""
+    from ray_tpu.models.transformer import init_params, loss_fn
+    cfg = TransformerConfig(**{**_BASE, "layer_pattern": (
+        ((("mha", "dense", 2), ("mha", "dense", 1)), 3),)})
+    params = init_params(jax.random.PRNGKey(0), cfg)
+    batch = {"tokens": jnp.zeros((2, 33), jnp.int32)}
+    _device(monkeypatch, ROOM)
+    plans = {}
+    jax.make_jaxpr(lambda p: remat.value_and_grad(
+        lambda p: (loss_fn(p, batch, cfg), {}), p, None, plans)[0])(params)
+    (_, report), = plans.values()
+    assert sorted(r["layers"] for r in report["runs"]) == [3, 6]
+    assert report["kept_bytes"] == sum(
+        r["layers"] * r["bytes_a_layer"] for r in report["runs"])
+    # the scan stacks a layer's input whatever is kept
+    carry = 2 * 32 * 32 * 4
+    assert report["step_bytes"] >= 9 * carry
+
+
+def test_a_name_has_to_spare_more_than_keeping_it_costs(monkeypatch):
+    """At a cell's widths (2,048 columns, bfloat16, 16,384 tokens; shapes
+    alone, nothing is computed) the dense layer's products are worth some
+    ten bytes moved a byte kept and every one is ordered; at 32 columns a
+    product alone is no longer worth its bytes (the FFN's two, v), only
+    what has several passes behind it."""
+    monkeypatch.undo()
+    assert remat._KEPT_BYTE_MOVES >= 2.0
+    for width, wanted in ((2048, {"mid_residual", "attn_q", "attn_k",
+                                  "attn_v", "ffn_gate", "ffn_up"}),
+                          (32, {"mid_residual", "attn_q", "attn_k"})):
+        cfg = TransformerConfig(**{**_BASE, "d_model": width, "n_heads": 16,
+                                   "d_ff": 5504 * width // 2048,
+                                   "dtype": jnp.bfloat16})
+        stack = jax.eval_shape(
+            lambda: init_stack(jax.random.PRNGKey(0), cfg, "mha", "dense", 1))
+        x = jax.ShapeDtypeStruct((4, 4096, width), jnp.bfloat16)
+        positions = jnp.zeros((4, 4096), jnp.int32)
+        jaxpr = jax.make_jaxpr(
+            lambda x, lp: apply_layer(x, lp, positions, cfg))(
+                x, jax.tree.map(lambda a: jax.ShapeDtypeStruct(
+                    a.shape[1:], a.dtype), stack))
+        order = remat.worth_order([remat.survey(jaxpr.jaxpr)])
+        assert {c.name for c in order} == wanted
+        assert all(c.work / c.bytes > remat._KEPT_BYTE_MOVES for c in order)
+
+
+def test_every_named_cut_point_is_a_candidate_some_survey_sees():
+    """Every ``checkpoint_name`` literal in ``models/`` and ``ops/`` is
+    a candidate in the survey of some kind of layer here (the flash
+    kernel's two are ``BASE_NAMES``, not literals): a name that no
+    survey sees is never kept."""
+    literals = set()
+    for part in ("models", "ops"):
+        folder = os.path.join(ROOT, "ray_tpu", part)
+        for name in sorted(os.listdir(folder)):
+            if not name.endswith(".py"):
+                continue
+            with open(os.path.join(folder, name)) as f:
+                tree = ast.parse(f.read())
+            for node in ast.walk(tree):
+                if (isinstance(node, ast.Call) and getattr(
+                        node.func, "id", "") == "checkpoint_name"
+                        and isinstance(node.args[1], ast.Constant)):
+                    literals.add(node.args[1].value)
+    assert len(literals) >= 20
+    seen = set()
+    for kind in KINDS:
+        seen |= set(_survey_of(kind).names)
+    assert literals - seen == set()
+    assert not seen & set(RESIDUAL_NAMES)
+
+
+STEPS = ("dense", "block_diffusion", "latent", "hybrid")
+RUNS = {"dense": ["mha+dense"], "block_diffusion": ["mha+moe"],
+        "latent": ["mla+dense", "mla+moe", "mla+moe"],
+        "hybrid": ["gdn+moe", "mha+moe"]}
+
+
+def _counting(monkeypatch, module, name, counts):
+    real = getattr(module, name)
+
+    @functools.wraps(real)
+    def counted(*args, **kwargs):
+        counts[name] += 1
+        return real(*args, **kwargs)
+
+    monkeypatch.setattr(module, name, counted)
+
+
+@pytest.mark.parametrize("kind", STEPS)
+def test_planning_a_step_calls_no_function_more_often(kind, monkeypatch):
+    """The plan's cost as a count, not a clock: tracing a planned step
+    calls each layer's function (``apply_layer``), the loss
+    (``loss_and_counters`` or the override) and the reader of a layer
+    (``survey``: once a run, Python over a jaxpr that exists) as often
+    as the runs are, and the objective once -- what the unplanned trace
+    does.  A second trace of the same shapes reads no layer again and
+    gets the plan of the first."""
+    from test_program_spans import _tiny_step
+    counts = collections.Counter()
+    _counting(monkeypatch, transformer, "apply_layer", counts)
+    _counting(monkeypatch, remat, "survey", counts)
+    real = jax.make_jaxpr
+    monkeypatch.setattr(remat.jax, "make_jaxpr", lambda *a, **k: (
+        counts.update(["make_jaxpr"]), real(*a, **k))[1])
+    calls = {}
+    for how, memory in (("today", None), ("planned", ROOM)):
+        _device(monkeypatch, memory)
+        step, state, batch = _tiny_step(kind)
+        counts.clear()
+        step.lower(state, batch)
+        calls[how] = dict(counts)
+    runs = len(RUNS[kind])
+    assert calls["today"] == {"apply_layer": runs}
+    assert calls["planned"] == {"apply_layer": runs, "survey": runs,
+                                "make_jaxpr": 1}
+    # the same step traced again (other arguments' weak types, say)
+    counts.clear()
+    first = dict(step._kept)
+    jax.clear_caches()
+    step.lower(state, batch)
+    assert counts == {"apply_layer": runs, "make_jaxpr": 1}
+    assert {**step._kept, "trace_seconds": 0} == {**first, "trace_seconds": 0}
+
+
+@pytest.mark.parametrize("kind", STEPS)
+def test_a_planned_step_is_the_step_without_remat(kind, monkeypatch):
+    """The four tiny steps (dense; block-diffusion experts; latent
+    attention, shared expert and the multi-token module; delta layers
+    beside gated attention in a period) under a plan with room for
+    everything, under today's two names and with ``remat_layer`` taken
+    out: the same loss, gradient norm and counters, to the tolerance of
+    ``tests/test_block_diffusion.py``'s remat test, and the same new
+    parameters to a thirtieth of one AdamW update (a gradient near zero
+    moves its update by more than its own rounding)."""
+    from test_program_spans import _tiny_step
+    got = {}
+    for how in ("planned", "today", "no remat"):
+        _device(monkeypatch, ROOM if how == "planned" else None)
+        if how == "no remat":
+            monkeypatch.setattr(transformer, "remat_layer",
+                                lambda layer, cfg, *a, **k: layer)
+        step, state, batch = _tiny_step(kind)
+        new, metrics = step(state, batch)
+        got[how] = jax.device_get((metrics, new["params"]))
+        if how == "planned":
+            plan = step._kept
+            assert sorted(r["kind"] for r in plan["runs"]) == RUNS[kind]
+            assert all("mid_residual" in r["names"] and r["refused"] == []
+                       for r in plan["runs"])
+        else:
+            assert step._kept == remat.no_plan()
+    for other in ("today", "no remat"):
+        for part, atol in ((0, 1e-6), (1, 1e-5)):
+            for a, b in zip(jax.tree.leaves(got["planned"][part]),
+                            jax.tree.leaves(got[other][part])):
+                assert np.all(np.isfinite(a))
+                np.testing.assert_allclose(a, b, rtol=2e-5, atol=atol)
