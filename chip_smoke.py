@@ -312,6 +312,9 @@ def _train_func(config: dict) -> dict:
     if "gdn" in model:
         from ray_tpu.models.gdn import GDNConfig
         model["gdn"] = GDNConfig(**model["gdn"])
+    if "mamba" in model:
+        from ray_tpu.models.mamba import MambaConfig
+        model["mamba"] = MambaConfig(**model["mamba"])
     cfg = TransformerConfig(dtype=jnp.dtype(config["dtype"]), **model)
     state, tx = make_train_state(jax.random.PRNGKey(0), cfg)
     objective = None
@@ -350,6 +353,14 @@ def _train_func(config: dict) -> dict:
             "gated_delta_calls_in_step": [len(re.findall(
                 rf'%{name}[.\d]* = [^\n]*"tpu_custom_call"', text))
                 for name in ("gated_delta_fwd", "gated_delta_bwd")],
+            # the selective scan's: the forward in both scans' bodies
+            # (nothing of it is kept), the backward once, a Mamba layer
+            "selective_scan_calls_in_step": [len(re.findall(
+                rf'%{name}[.\d]* = [^\n]*"tpu_custom_call"', text))
+                for name in ("selective_scan_fwd", "selective_scan_bwd")],
+            "flash_bwd_calls_in_step": len(re.findall(
+                r'%flash_attention_bwd[.\d]* = [^\n]*"tpu_custom_call"',
+                text)),
             "param_platforms": sorted({d.platform for d in leaf.devices()}),
             "n_params": sum(int(np.prod(x.shape))
                             for x in jax.tree.leaves(state["params"]))}
@@ -725,6 +736,156 @@ def leg_hybrid_trainer(platform: str = "tpu", model: dict = None,
             "gated_delta_bwd_max_rel_err": bwd_err, "rule_tol": rule_tol}
 
 
+#: The decoder-hybrid-decoder's four kinds of layer at published widths
+#: (hidden 2,560, 40 query heads of 64 on 20 K/V heads, Mamba with 5,120
+#: channels of 16 states, LayerNorms with a bias, no rotary, the head
+#: tied): a Mamba layer that hands on its scan output, differential
+#: attention under a window of 512, a full layer that hands on its keys
+#: and values, a Gated Memory Unit and a cross layer; a 1,024-wide
+#: SwiGLU in each, 8,192 vocabulary rows: 180.3M parameters.
+SAMBAY_MODEL = dict(
+    vocab_size=8192, d_model=2560, n_heads=40, n_kv_heads=20, d_ff=1024,
+    max_seq_len=2048, remat=True, norm="layernorm", rope="none",
+    tie_embeddings=True, first_layer_index=15,
+    mamba=dict(d_inner=5120, d_state=16, d_conv=4, dt_rank=160, chunk=64),
+    layer_pattern=(("diff:window=512", "dense", 1),
+                   ("mamba:writes=memory", "dense", 1),
+                   ("diff:writes=kv", "dense", 1), ("gmu", "dense", 1),
+                   ("diff:reads=kv", "dense", 1)))
+
+
+def leg_sambay_trainer(platform: str = "tpu", model: dict = None,
+                       batch: int = 1, seq: int = 2048, steps: int = 2,
+                       dtype: str = "bfloat16", scan_tol: float = 4e-2,
+                       flash_tol: float = 4e-2) -> dict:
+    """The state-space and differential-attention kinds through the same
+    Trainer path, ``steps`` steps: the selective scan's two kernels
+    first compared with the chunked ``jnp`` path and its ``jax.grad``
+    (all six gradients) at the model's shape, and both flash kernels
+    under the window (20 query heads of 64 over 10 key heads of 64 and
+    value heads of 128) with ``full_attention`` and its ``jax.grad``;
+    then the compiled step must hold the scan's kernels (the forward in
+    both passes, the backward once) and six flash calls each way (two
+    maps a differential layer), and the counters must read what an
+    untrained model's read."""
+    import jax
+    import jax.numpy as jnp
+
+    import ray_tpu
+    from ray_tpu.ops.attention_mask import SlidingWindow
+    from ray_tpu.ops.flash_attention import flash_attention
+    from ray_tpu.ops.ring_attention import full_attention
+    from ray_tpu.ops.selective_scan import selective_scan
+    from ray_tpu.train import Trainer
+
+    model = dict(model or SAMBAY_MODEL)
+    on_chip = platform == "tpu"
+    m = model["mamba"]
+    e, n = m["d_inner"], m["d_state"]
+    low = jnp.dtype(dtype)
+    keys = jax.random.split(jax.random.PRNGKey(13), 10)
+
+    def rel_err(got, want):
+        return _max_err(got, want) / float(
+            jnp.max(jnp.abs(want.astype(jnp.float32))))
+
+    # channels that forget within a position and channels that never do
+    rate = jnp.exp(jnp.linspace(jnp.log(1e-4), 0.0, e))
+    operands = (
+        jax.random.normal(keys[0], (batch, seq, e)).astype(low),
+        rate * jax.nn.softplus(jax.random.normal(keys[1], (batch, seq, e))),
+        -jnp.broadcast_to(jnp.arange(1.0, n + 1), (e, n)),
+        jax.random.normal(keys[2], (batch, seq, n)),
+        jax.random.normal(keys[3], (batch, seq, n)), jnp.ones((e,)))
+    dy = jax.random.normal(keys[4], (batch, seq, e))
+    chunk = min(m["chunk"], seq)
+
+    def scan_grads(**how):
+        def loss(*xs):
+            y = selective_scan(*xs, chunk=chunk, **how)
+            return jnp.sum(y.astype(jnp.float32) * dy), y
+        grads, y = jax.grad(loss, (0, 1, 2, 3, 4, 5), has_aux=True)(
+            *operands)
+        return (y, *grads)
+
+    got = scan_grads(use_pallas=True, interpret=not on_chip)
+    want = scan_grads(use_pallas=False)
+    scan_fwd_err = rel_err(got[0], want[0])
+    scan_bwd_err = max(rel_err(a, b) for a, b in zip(got[1:], want[1:]))
+    check(scan_fwd_err <= scan_tol,
+          f"selective scan kernels vs the jnp scans: max rel err "
+          f"{scan_fwd_err} > {scan_tol} ({dtype})")
+    check(scan_bwd_err <= scan_tol,
+          f"selective scan backward (dc, ddelta, dA, dB, dC, dD) vs grad of "
+          f"the jnp scans: {scan_bwd_err} > {scan_tol} ({dtype})")
+
+    h, kv, dh = model["n_heads"] // 2, model["n_kv_heads"] // 2, \
+        model["d_model"] // model["n_heads"]
+    window = SlidingWindow(min(512, seq // 4))
+    q = jax.random.normal(keys[5], (batch, seq, h, dh)).astype(low)
+    k = jax.random.normal(keys[6], (batch, seq, kv, dh)).astype(low)
+    v = jax.random.normal(keys[7], (batch, seq, kv, 2 * dh)).astype(low)
+    dout = jax.random.normal(keys[8], (batch, seq, h, 2 * dh))
+
+    def flash_grads(fn):
+        def loss(q, k, v):
+            o = fn(q, k, v)
+            return jnp.sum(o.astype(jnp.float32) * dout), o
+        grads, o = jax.grad(loss, (0, 1, 2), has_aux=True)(q, k, v)
+        return (o, *grads)
+
+    got = flash_grads(lambda q, k, v: flash_attention(
+        q, k, v, mask=window, interpret=not on_chip))
+    want = flash_grads(lambda q, k, v: full_attention(q, k, v, mask=window))
+    flash_err = max(rel_err(a, b) for a, b in zip(got, want))
+    check(flash_err <= flash_tol,
+          f"flash kernels under the window at 64 | 128 columns vs "
+          f"full_attention and its grad: {flash_err} > {flash_tol}")
+
+    ray_tpu.init(num_cpus=4, num_tpus=len(jax.devices()))
+    try:
+        trainer = Trainer(backend="jax", num_workers=1, use_tpu=True)
+        try:
+            (result,) = trainer.run(_train_func, config=dict(
+                model=model, batch=batch, seq=seq, steps=steps,
+                dtype=dtype))
+        finally:
+            trainer.shutdown()
+    finally:
+        ray_tpu.shutdown()
+    losses, counters = result["losses"], result["counters"]
+    check(len(losses) == steps and np.isfinite(losses).all(),
+          f"finite losses: {losses}")
+    check(losses[-1] < losses[0], f"loss falls on a repeated batch: {losses}")
+    check(result["param_platforms"] == [platform],
+          f"params on {platform}: {result['param_platforms']}")
+    check(result["selective_scan_calls_in_step"]
+          == [2 * int(on_chip), int(on_chip)],
+          f"the scan's forward kernel in both scans' bodies and its "
+          f"backward in one: {result['selective_scan_calls_in_step']}")
+    check(result["flash_fwd_calls_in_step"] == 6 * int(on_chip)
+          and result["flash_bwd_calls_in_step"] == 6 * int(on_chip),
+          f"two flash calls a differential layer each way: "
+          f"{result['flash_fwd_calls_in_step']}, "
+          f"{result['flash_bwd_calls_in_step']}")
+    check(counters["ssm_scan_fallback_passes"] == float(not on_chip),
+          f"the scan ran as the kernels on the chip: {counters}")
+    check(0.5 < counters["diff_lambda"] < 1.0
+          and 0.0 < counters["ssm_delta_mean"] < 0.2,
+          f"lambda near lambda_init, step sizes in their range: {counters}")
+    return {"batch": batch, "seq": seq, "dtype": dtype,
+            "params_m": round(result["n_params"] / 1e6, 1),
+            "losses": [round(x, 4) for x in losses],
+            "counters": {k: round(v, 5) for k, v in counters.items()},
+            "selective_scan_calls_in_step":
+                result["selective_scan_calls_in_step"],
+            "flash_fwd_calls_in_step": result["flash_fwd_calls_in_step"],
+            "selective_scan_vs_jnp_max_rel_err": scan_fwd_err,
+            "selective_scan_bwd_max_rel_err": scan_bwd_err,
+            "window_flash_max_rel_err": flash_err,
+            "scan_tol": scan_tol, "flash_tol": flash_tol}
+
+
 # ---------------------------------------------------------------------------
 # Legs 4 and 5 — more than one device.
 # ---------------------------------------------------------------------------
@@ -889,6 +1050,8 @@ def main() -> int:
             leg_latent_trainer)
     run_leg("3c delta rule + gated attention + experts 132M x 4 x 1024",
             clock, leg_hybrid_trainer)
+    run_leg("3d selective scan + differential attention 180M x 1 x 2048",
+            clock, leg_sambay_trainer)
     if device["count"] > 1:
         run_leg("4 sharded solve", clock, leg_sharded_solve)
         run_leg("5 model parallel dp/sp/tp + ep + pp", clock,

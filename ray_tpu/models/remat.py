@@ -7,7 +7,9 @@ The flash kernel's ``out`` and ``lse`` (``BASE_NAMES``) are kept always.
 Beyond them the layers name their cut points (``checkpoint_name``: the
 middle residual, q, k and v as they enter the kernel, the FFN's
 products, the latents, the delta layer's projections and convolution,
-the router's scores ...), and this module decides, where the step is
+the state-space layer's projections, convolution and scan output, the
+memory unit's gate, differential attention's q and keys and values, the
+router's scores ...), and this module decides, where the step is
 traced, which of those names the device has room for.
 
 **What is traced when** (``value_and_grad``, which ``make_train_step``
@@ -65,7 +67,12 @@ an observation of shapes and of the device, not a setting: there is
 nothing to configure.  What it cannot see: under a mesh the working
 sets are counted whole, not a device's share (less is kept than would
 fit); the delta rule's states, which cannot carry a name
-(``ops/gated_delta.py``).
+(``ops/gated_delta.py``), and the selective scan's entering states,
+which carry none (``ops/selective_scan.py``: a layer's backward runs the
+forward kernel again).  What one run hands to later runs (the shared
+slot of ``models.transformer.run_stacks``) is no candidate: it is an
+output of its scan and a constant of its readers', kept once whatever
+the plan.
 """
 
 from __future__ import annotations

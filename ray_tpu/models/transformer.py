@@ -32,7 +32,21 @@ graft entry exercise):
     compiled body, not 24).  Its tree is a tuple of its runs' stacks,
     each with the periods as a further leading axis.  ``"gdn"`` is the
     third attention kind: Gated DeltaNet's linear attention,
-    ``models/gdn.py``.
+    ``models/gdn.py``.  ``"mamba"`` and ``"gmu"`` (``models/mamba.py``:
+    a selective state-space mixer, and the unit that gates an earlier
+    one's scan output) and ``"diff"`` (``models/diff_attention.py``:
+    differential attention) are the others.
+  * A run may say more than its kind, ``"kind:option,option"``
+    (``run_options``): ``window=512`` (a ``diff`` layer's mask),
+    ``writes=memory`` (a ``mamba`` run hands its last layer's scan
+    output to the runs after it), ``writes=kv`` / ``reads=kv`` (a
+    ``diff`` run hands on its keys and values / attends over those it
+    is handed and projects none); a ``gmu`` run reads the memory.  What
+    is handed on lives in a SHARED SLOT that ``run_stacks`` threads
+    beside ``x``: computed once in the forward pass, kept for its
+    readers, their cotangents summed into the writer.  A run that reads
+    a slot no earlier run wrote is refused when the configuration is
+    made.
 """
 
 from __future__ import annotations
@@ -54,8 +68,17 @@ from ray_tpu.util import tracing
 
 
 #: The kinds a run of the layer pattern is made of.
-_ATTENTION = ("mha", "mla", "gdn")
+_ATTENTION = ("mha", "mla", "gdn", "mamba", "gmu", "diff")
 _FFN = ("dense", "moe")
+#: What a run of each kind may say beside its kind (``run_options``),
+#: and the slot each word is about.
+_OPTIONS = {"mamba": {"writes": ("memory",)},
+            "diff": {"window": None, "writes": ("kv",), "reads": ("kv",)}}
+#: The kinds that run on one device's rows whole: no ``tp``, ``sp`` or
+#: pipeline layout yet.
+SINGLE_DEVICE_KINDS = ("mamba", "gmu", "diff")
+#: The kinds whose layers read their index in the model.
+_INDEXED_KINDS = ("diff",)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -152,6 +175,23 @@ class TransformerConfig:
     #: Multi-token-prediction modules after the stack (0 or 1):
     #: ``models/mtp.py``, whose loss hooks in by ``loss_override``.
     mtp_depth: int = 0
+    #: The state-space layers' sizes (``models.mamba.MambaConfig``) for
+    #: the ``"mamba"`` and ``"gmu"`` kinds.
+    mamba: Any = None
+    #: ``"rms"``, or ``"layernorm"``: the model's norms (``ln1``,
+    #: ``ln2``, ``ln_f``) subtract the mean and have a bias beside the
+    #: weight (``ln1_b``, ...); ``norm_eps`` is theirs.
+    norm: str = "rms"
+    #: ``"rotary"``, or ``"none"``: the ``"mha"`` kind turns nothing
+    #: (``"diff"`` never does).
+    rope: str = "rotary"
+    #: The head is the embedding transposed: no ``lm_head`` leaf, and
+    #: the embedding's gradient is the sum of both uses.
+    tie_embeddings: bool = False
+    #: The index of the pattern's first layer in the model it is a slice
+    #: of (differential attention's ``lambda_init`` reads a layer's
+    #: index).
+    first_layer_index: int = 0
 
     def __post_init__(self):
         if not self.n_kv_heads:
@@ -172,14 +212,26 @@ class TransformerConfig:
             object.__setattr__(self, "layer_pattern", pattern)
             object.__setattr__(self, "n_layers",
                                sum(count for _, _, count in runs_of(pattern)))
-        kinds = {kind for run in runs_of(self.layer_pattern)
-                 for kind in run[:2]}
+        kinds = {kind for attention, ffn, _ in runs_of(self.layer_pattern)
+                 for kind in (run_options(attention)[0], ffn)}
         if "mla" in kinds and self.mla is None:
             raise ValueError("an \"mla\" layer needs the mla sizes")
         if "gdn" in kinds and self.gdn is None:
             raise ValueError("a \"gdn\" layer needs the gdn sizes")
         if "moe" in kinds and self.moe_experts < 1:
             raise ValueError("a \"moe\" layer needs moe_experts")
+        if kinds & {"mamba", "gmu"} and self.mamba is None:
+            raise ValueError("a \"mamba\" or \"gmu\" layer needs the "
+                             "mamba sizes")
+        if self.norm not in ("rms", "layernorm") or \
+                self.rope not in ("rotary", "none"):
+            raise ValueError(f"norm {self.norm!r}, rope {self.rope!r}")
+        if self.norm == "layernorm" and self.norm_plus_one:
+            raise ValueError("a LayerNorm's weight scales as it is")
+        _check_slots(self.layer_pattern)
+        if (self.tie_embeddings or self.norm != "rms") and self.mtp_depth:
+            raise ValueError("the multi-token-prediction module has its "
+                             "own head and RMSNorms")
         if self.mtp_depth not in (0, 1):
             raise ValueError("one multi-token-prediction module at most")
         if self.mtp_depth and (is_period(self.layer_pattern[-1])
@@ -210,9 +262,52 @@ def _checked_entry(entry):
             raise ValueError(f"layer pattern period {entry!r}")
         return runs, repeats
     attention, ffn, count = entry
-    if attention not in _ATTENTION or ffn not in _FFN or count < 1:
+    if ffn not in _FFN or count < 1:
         raise ValueError(f"layer pattern run {attention!r}, {ffn!r}, {count}")
+    run_options(attention)
     return attention, ffn, count
+
+
+def run_options(attention: str) -> Tuple[str, Dict[str, Any]]:
+    """A run's first word, ``"kind"`` or ``"kind:option,option"`` ->
+    (kind, its options): ``window`` an int, ``writes`` / ``reads`` the
+    slot's name.  Anything a kind does not take is refused."""
+    kind, _, rest = str(attention).partition(":")
+    if kind not in _ATTENTION:
+        raise ValueError(f"layer pattern kind {attention!r}: one of "
+                         f"{_ATTENTION}")
+    options: Dict[str, Any] = {}
+    for word in filter(None, rest.split(",")):
+        name, _, value = word.partition("=")
+        takes = _OPTIONS.get(kind, {})
+        if name not in takes or name in options or (
+                takes[name] is not None and value not in takes[name]):
+            raise ValueError(f"a {kind!r} run does not take {word!r}")
+        options[name] = value if takes[name] is not None else int(value)
+    if "reads" in options and "writes" in options:
+        raise ValueError(f"{attention!r} reads the slot it writes")
+    if kind == "gmu":
+        options["reads"] = "memory"
+    return kind, options
+
+
+def _check_slots(pattern) -> None:
+    """Every slot is written by a run before the runs that read it; a
+    period's runs neither write nor read (the slot would have to pass
+    through its scan)."""
+    written = set()
+    for entry in pattern:
+        for attention, _, _ in runs_of((entry,)):
+            options = run_options(attention)[1]
+            slot = options.get("reads") or options.get("writes")
+            if slot and is_period(entry):
+                raise ValueError(f"{attention!r} in a period: the shared "
+                                 f"slot does not pass through its scan")
+            if "reads" in options and slot not in written:
+                raise ValueError(f"{attention!r} reads the slot {slot!r} "
+                                 f"that no earlier run writes")
+            if "writes" in options:
+                written.add(slot)
 
 
 def runs_of(pattern):
@@ -250,7 +345,23 @@ def init_stack(key: jax.Array, cfg: TransformerConfig, attention: str,
         "ln1": unit((nl, d), jnp.float32),
         "ln2": unit((nl, d), jnp.float32),
     }
-    if attention == "mla":
+    if cfg.norm == "layernorm":
+        layers["ln1_b"] = jnp.zeros((nl, d), jnp.float32)
+        layers["ln2_b"] = jnp.zeros((nl, d), jnp.float32)
+    attention, options = run_options(attention)
+    if attention == "mamba":
+        from ray_tpu.models.mamba import init_mamba_params
+        layers["mamba"] = init_mamba_params(jax.random.fold_in(key, 11), nl,
+                                            d, cfg.mamba, cfg.dtype)
+    elif attention == "gmu":
+        from ray_tpu.models.mamba import init_gmu_params
+        layers["gmu"] = init_gmu_params(jax.random.fold_in(key, 12), nl, d,
+                                        cfg.mamba, cfg.dtype)
+    elif attention == "diff":
+        from ray_tpu.models.diff_attention import init_diff_params
+        layers["diff"] = init_diff_params(jax.random.fold_in(key, 13), nl,
+                                          cfg, cross="reads" in options)
+    elif attention == "mla":
         from ray_tpu.models.mla import init_mla_params
         layers["mla"] = init_mla_params(jax.random.fold_in(key, 9), nl, d,
                                         h, cfg.mla, cfg.dtype)
@@ -301,9 +412,12 @@ def init_params(rng: jax.Array, cfg: TransformerConfig) -> Dict:
         "layers": layers,
         "ln_f": (jnp.zeros if cfg.norm_plus_one else jnp.ones)(
             (d,), jnp.float32),
-        "lm_head": init(k_head, (d, cfg.vocab_size), jnp.float32
-                        ).astype(cfg.dtype),
     }
+    if cfg.norm == "layernorm":
+        params["ln_f_b"] = jnp.zeros((d,), jnp.float32)
+    if not cfg.tie_embeddings:
+        params["lm_head"] = init(k_head, (d, cfg.vocab_size), jnp.float32
+                                 ).astype(cfg.dtype)
     if cfg.mtp_depth:
         from ray_tpu.models.mtp import init_mtp_params
         params["mtp"] = init_mtp_params(jax.random.fold_in(rng, 3), cfg)
@@ -330,7 +444,19 @@ def stack_specs(cfg: TransformerConfig, attention: str, ffn: str) -> Dict:
         "ln1": P(None, None),
         "ln2": P(None, None),
     }
-    if attention == "mla":
+    if cfg.norm == "layernorm":
+        layers["ln1_b"] = layers["ln2_b"] = P(None, None)
+    attention, options = run_options(attention)
+    if attention == "mamba":
+        from ray_tpu.models.mamba import mamba_param_specs
+        layers["mamba"] = mamba_param_specs()
+    elif attention == "gmu":
+        from ray_tpu.models.mamba import gmu_param_specs
+        layers["gmu"] = gmu_param_specs()
+    elif attention == "diff":
+        from ray_tpu.models.diff_attention import diff_param_specs
+        layers["diff"] = diff_param_specs(cross="reads" in options)
+    elif attention == "mla":
         from ray_tpu.models.mla import mla_param_specs
         layers["mla"] = mla_param_specs()
     elif attention == "gdn":
@@ -376,8 +502,11 @@ def param_specs(cfg: TransformerConfig) -> Dict:
         "layers": (stacks[0] if len(stacks) == 1
                    and not is_period(cfg.layer_pattern[0]) else stacks),
         "ln_f": P(None),
-        "lm_head": P(None, "tp"),
     }
+    if cfg.norm == "layernorm":
+        specs["ln_f_b"] = P(None)
+    if not cfg.tie_embeddings:
+        specs["lm_head"] = P(None, "tp")
     if cfg.mtp_depth:
         from ray_tpu.models.mtp import mtp_param_specs
         specs["mtp"] = mtp_param_specs(cfg)
@@ -392,6 +521,21 @@ def _rms_norm(x, w, eps):
     xf = x.astype(jnp.float32)
     norm = xf * jax.lax.rsqrt(jnp.mean(xf * xf, axis=-1, keepdims=True) + eps)
     return (norm * w).astype(x.dtype)
+
+
+def _layer_norm(x, w, b, eps):
+    xf = x.astype(jnp.float32)
+    xf = xf - jnp.mean(xf, axis=-1, keepdims=True)
+    norm = xf * jax.lax.rsqrt(jnp.mean(xf * xf, axis=-1, keepdims=True) + eps)
+    return (norm * w + b).astype(x.dtype)
+
+
+def model_norm(x, tree: Dict, name: str, cfg: TransformerConfig):
+    """The model's norm ``name`` (``ln1``, ``ln2``, ``ln_f``) of
+    ``tree``: RMSNorm, or LayerNorm with the bias ``<name>_b``."""
+    if cfg.norm == "layernorm":
+        return _layer_norm(x, tree[name], tree[name + "_b"], cfg.norm_eps)
+    return _rms_norm(x, norm_weight(tree[name], cfg), cfg.norm_eps)
 
 
 def norm_weight(w, cfg: TransformerConfig):
@@ -481,10 +625,11 @@ def _mha(h, lp, positions, cfg: TransformerConfig, mesh, mask):
     if cfg.qk_norm:
         q = _rms_norm(q, norm_weight(lp["q_norm"], cfg), eps)
         k = _rms_norm(k, norm_weight(lp["k_norm"], cfg), eps)
-    q = checkpoint_name(_rope(q, positions, cfg.rope_theta, cfg.rotary_dim),
-                        "attn_q")
-    k = checkpoint_name(_rope(k, positions, cfg.rope_theta, cfg.rotary_dim),
-                        "attn_k")
+    if cfg.rope == "rotary":
+        q = _rope(q, positions, cfg.rope_theta, cfg.rotary_dim)
+        k = _rope(k, positions, cfg.rope_theta, cfg.rotary_dim)
+    q = checkpoint_name(q, "attn_q")
+    k = checkpoint_name(k, "attn_k")
     o = _attention_core(q, k, v, mesh, cfg, mask)
     counted = {}
     if cfg.attn_out_gate:
@@ -509,15 +654,53 @@ def apply_layer(x, lp, positions, cfg: TransformerConfig, mesh=None,
     for a dense one).  ``kind``: the layer's (attention, ffn) modules;
     by default the pattern's first run's.  Shared by the scan forward,
     the block-diffusion and multi-token objectives and the
-    pipeline-parallel stage executor."""
+    pipeline-parallel stage executor.  (A layer that reads or writes the
+    shared slot runs through ``run_stack``.)"""
+    return _apply_layer(x, lp, positions, cfg, mesh, mask, kind)[:2]
+
+
+def _single_device_mixer(kind, options, h, lp, index, shared, cfg, mesh,
+                         mask):
+    """A ``SINGLE_DEVICE_KINDS`` mixer on the layer's normed input ->
+    (what it adds to the residual, what it counted, what it hands on:
+    None, or the value of the slot its run writes)."""
+    if mask != CAUSAL:
+        raise ValueError(f"a {kind!r} layer brings its own mask")
+    if mesh is not None and max(mesh.shape.get("tp", 1),
+                                mesh.shape.get("sp", 1)) > 1:
+        raise ValueError(f"a {kind!r} layer has no tp or sp layout")
+    if kind == "mamba":
+        from ray_tpu.models.mamba import mamba_mixer
+        y, counted, memory = mamba_mixer(h, lp["mamba"], cfg)
+        return y, counted, memory if "writes" in options else None
+    if kind == "gmu":
+        from ray_tpu.models.mamba import gmu_mixer
+        return gmu_mixer(h, lp["gmu"], shared["memory"], cfg), {}, None
+    from ray_tpu.models.diff_attention import diff_attention
+    y, counted, kv = diff_attention(
+        h, lp["diff"], index, window=options.get("window"),
+        kv=shared["kv"] if "reads" in options else None)
+    return y, counted, kv if "writes" in options else None
+
+
+def _apply_layer(x, lp, positions, cfg: TransformerConfig, mesh=None,
+                 mask=CAUSAL, kind: Optional[Tuple[str, str]] = None,
+                 index=None, shared: Optional[Dict] = None):
+    """``apply_layer`` -> (x, what the layer counted, what it hands to
+    later layers or None).  ``index``: the layer's index in the model;
+    ``shared``: the slots written so far."""
     # The named scopes here and in loss_fn / train_step are metadata
     # only: stable names for a device trace to group time by.
     attention, ffn = kind or next(runs_of(cfg.layer_pattern))[:2]
-    eps = cfg.norm_eps
-    counted = {}
+    attention, options = run_options(attention)
+    counted, handed_on = {}, None
     with jax.named_scope("attention"):
-        h = _rms_norm(x, norm_weight(lp["ln1"], cfg), eps)
-        if attention == "mla":
+        h = model_norm(x, lp, "ln1", cfg)
+        if attention in SINGLE_DEVICE_KINDS:
+            y, counted, handed_on = _single_device_mixer(
+                attention, options, h, lp, index, shared, cfg, mesh, mask)
+            x = x + y
+        elif attention == "mla":
             from ray_tpu.models.mla import mla_attention
             x = x + mla_attention(h, lp["mla"], positions, cfg, mesh, mask)
         elif attention == "gdn":
@@ -531,7 +714,7 @@ def apply_layer(x, lp, positions, cfg: TransformerConfig, mesh=None,
             x = x + y
         x = checkpoint_name(x, "mid_residual")
     with jax.named_scope("ffn"):
-        h = _rms_norm(x, norm_weight(lp["ln2"], cfg), eps)
+        h = model_norm(x, lp, "ln2", cfg)
         if ffn == "moe":
             y, moe_counted = _moe_block(h, lp["moe"], cfg, mesh)
             counted = {**counted, **moe_counted}
@@ -541,7 +724,7 @@ def apply_layer(x, lp, positions, cfg: TransformerConfig, mesh=None,
     if mesh is not None:
         x = jax.lax.with_sharding_constraint(
             x, NamedSharding(mesh, P("dp", "sp", None)))
-    return x, counted
+    return x, counted, handed_on
 
 
 def remat_layer(layer, cfg: TransformerConfig,
@@ -564,23 +747,48 @@ def remat_layer(layer, cfg: TransformerConfig,
     return jax.checkpoint(layer, policy=remat.policy(kind, mesh))
 
 
+def _reads_index(runs) -> bool:
+    """Whether a layer of ``runs`` reads its index in the model."""
+    return any(run_options(run[0])[0] in _INDEXED_KINDS for run in runs)
+
+
 def run_stack(x, stack: Dict, kind, positions, cfg: TransformerConfig,
-              mesh=None, mask=CAUSAL, moe_bias=None):
+              mesh=None, mask=CAUSAL, moe_bias=None, first_index: int = 0,
+              shared: Optional[Dict] = None):
     """The scan over one run's stacked layers -> (x, what each layer
     counted, stacked by layer).  ``moe_bias`` [layers of the run, E]:
-    the expert layers' correction bias, a row a layer."""
+    the expert layers' correction bias, a row a layer.  ``first_index``:
+    the index of the run's first layer; ``shared``: the slots written so
+    far, by name -- a run that reads one takes it from there (the scan
+    closes over it: kept once, its cotangent summed over the run's
+    layers), a run that writes one puts its LAST layer's there."""
+    options = run_options(kind[0])[1]
+    shared = {} if shared is None else shared
+    reads = {slot: shared[slot] for slot in (options.get("reads"),) if slot}
+
     def layer(x, scanned):
-        lp, bias = scanned
+        lp, bias, index = scanned
         if bias is not None:
             lp = dict(lp, moe=dict(lp["moe"], bias=bias))
-        return apply_layer(x, lp, positions, cfg, mesh, mask, kind)
+        x, counted, handed_on = _apply_layer(x, lp, positions, cfg, mesh,
+                                             mask, kind, index, reads)
+        return x, (counted, handed_on)
 
-    return jax.lax.scan(remat_layer(layer, cfg, kind, mesh), x,
-                        (stack, moe_bias))
+    # (only a kind that reads its index is given one: the others' scans
+    # are traced as they always were)
+    indices = None
+    if _reads_index((kind,)):
+        indices = first_index + jnp.arange(
+            jax.tree.leaves(stack)[0].shape[0], dtype=jnp.int32)
+    x, (counted, handed_on) = jax.lax.scan(
+        remat_layer(layer, cfg, kind, mesh), x, (stack, moe_bias, indices))
+    if "writes" in options:
+        shared[options["writes"]] = jax.tree.map(lambda a: a[-1], handed_on)
+    return x, counted
 
 
 def run_period(x, stacks, runs, positions, cfg: TransformerConfig, mesh=None,
-               mask=CAUSAL, moe_bias=None):
+               mask=CAUSAL, moe_bias=None, first_index=0):
     """The scan over a period's repeats, its body the runs' own scans ->
     (x, what the layers counted, stacked by layer in the order they
     come).  ``stacks``: a stack a run, ``[repeats, count, ...]``;
@@ -589,11 +797,16 @@ def run_period(x, stacks, runs, positions, cfg: TransformerConfig, mesh=None,
     if moe_bias is not None:
         moe_bias = moe_bias.reshape(repeats, -1, moe_bias.shape[-1])
 
+    starts = None
+    if _reads_index(runs):
+        length = sum(count for _, _, count in runs)
+        starts = first_index + length * jnp.arange(repeats, dtype=jnp.int32)
+
     def period(x, scanned):
         return run_stacks(x, scanned[0], positions, cfg, mesh, mask,
-                          scanned[1], pattern=runs)
+                          scanned[1], pattern=runs, first_index=scanned[2])
 
-    x, counted = jax.lax.scan(period, x, (tuple(stacks), moe_bias))
+    x, counted = jax.lax.scan(period, x, (tuple(stacks), moe_bias, starts))
     # [repeats, layers of a run that count it, ...] a run -> by layer
     by_layer = {}
     for name in sorted({name for c in counted for name in c}):
@@ -603,13 +816,16 @@ def run_period(x, stacks, runs, positions, cfg: TransformerConfig, mesh=None,
 
 
 def run_stacks(x, layers, positions, cfg: TransformerConfig, mesh=None,
-               mask=CAUSAL, moe_bias=None, pattern=None):
+               mask=CAUSAL, moe_bias=None, pattern=None, first_index=None):
     """Every entry of the layer pattern in turn (``pattern``: of a
     period's runs, with ``layers`` their stacks) -> (x, [what the layers
-    of each entry counted, stacked by layer])."""
+    of each entry counted, stacked by layer]).  The shared slot starts
+    empty here and passes from run to run beside ``x``."""
     if pattern is None:
         pattern, layers = cfg.layer_pattern, stacks_of(layers)
-    counted, row = [], 0
+    if first_index is None:
+        first_index = cfg.first_layer_index
+    counted, row, shared = [], 0, {}
     for stack, entry in zip(layers, pattern):
         bias = None
         rows = sum(count for _, ffn, count in runs_of((entry,))
@@ -618,10 +834,12 @@ def run_stacks(x, layers, positions, cfg: TransformerConfig, mesh=None,
             bias, row = moe_bias[row:row + rows], row + rows
         if is_period(entry):
             x, c = run_period(x, stack, entry[0], positions, cfg, mesh, mask,
-                              bias)
+                              bias, first_index)
         else:
             x, c = run_stack(x, stack, entry[:2], positions, cfg, mesh, mask,
-                             bias)
+                             bias, first_index, shared)
+        first_index = first_index + sum(
+            count for _, _, count in runs_of((entry,)))
         counted.append(c)
     return x, counted
 
@@ -681,8 +899,11 @@ def forward_with_counters(params: Dict, tokens: jax.Array,
     x, counters = run_layers(params, tokens, positions, cfg, mesh,
                              moe_bias=moe_bias)
     with jax.named_scope("head_loss"):
-        x = _rms_norm(x, norm_weight(params["ln_f"], cfg), cfg.norm_eps)
-        logits = jnp.einsum("bsd,dv->bsv", x, params["lm_head"])
+        x = model_norm(x, params, "ln_f", cfg)
+        if cfg.tie_embeddings:
+            logits = jnp.einsum("bsd,vd->bsv", x, params["embed"])
+        else:
+            logits = jnp.einsum("bsd,dv->bsv", x, params["lm_head"])
     return logits, counters
 
 

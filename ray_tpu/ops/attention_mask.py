@@ -185,5 +185,49 @@ class BlockDiffusion:
         ]
 
 
+@dataclasses.dataclass(frozen=True)
+class SlidingWindow:
+    """Query ``i`` sees keys ``i - window < j <= i``: causal within its
+    last ``window`` positions, its own among them.  A band of tiles a
+    Q tile (or a K tile): those the causal diagonal crosses and those
+    the window's far edge crosses are masked, what lies between is not
+    (at tiles as long as the window nothing lies between)."""
+    window: int
+
+    def __post_init__(self):
+        if self.window < 1:
+            raise ValueError(f"a window of {self.window} positions")
+
+    def allowed(self, q_pos, k_pos):
+        return (q_pos >= k_pos) & (q_pos - k_pos < self.window)
+
+    def tile_span(self, seq_len: int) -> int:
+        return seq_len
+
+    def k_ranges(self, q_tile, block_q, block_k, num_k):
+        q0, q1 = q_tile * block_q, (q_tile + 1) * block_q
+        # K tiles [first, stop) hold a key some row sees; in [inner,
+        # unmasked) every row sees every key: the tile's last key is at
+        # or before the first row, its first within the last row's window
+        first = jnp.maximum(q0 - self.window + 1, 0) // block_k
+        stop = jnp.minimum(_cdiv(q1, block_k), num_k)
+        inner = jnp.clip(_cdiv(jnp.maximum(q1 - self.window, 0), block_k),
+                         first, stop)
+        unmasked = jnp.clip((q0 + 1) // block_k, inner, stop)
+        return [(first, inner, True), (inner, unmasked, False),
+                (unmasked, stop, True)]
+
+    def q_ranges(self, k_tile, block_q, block_k, num_q):
+        k0, k1 = k_tile * block_k, (k_tile + 1) * block_k
+        # Q tiles [first, stop) hold a row that sees some key of the
+        # tile; in [inner, unmasked) every row sees every key
+        first = k0 // block_q
+        stop = jnp.minimum(_cdiv(k1 + self.window - 1, block_q), num_q)
+        inner = jnp.clip(_cdiv(k1 - 1, block_q), first, stop)
+        unmasked = jnp.clip((k0 + self.window) // block_q, inner, stop)
+        return [(first, inner, True), (inner, unmasked, False),
+                (unmasked, stop, True)]
+
+
 CAUSAL = Causal()
 FULL = Full()

@@ -30,10 +30,12 @@ to HBM in either direction.  In both:
 (K/V blocked through the grid for L = 32k is ROADMAP R-M4: the forward
 holds K and V whole per head, the backward q, dout and dq; at 256-wide
 heads L = 8,192 is the longest row both hold: 16.8 MB of K and V in the
-forward, some 50 MB of the backward's 64.)
+forward, some 50 MB of the backward's 64.  At 64 score and 128 value
+columns both hold L = 16,384.)
 
 What is attended to is a static description (``ops/attention_mask.py``:
-``CAUSAL``, ``FULL``, ``BlockDiffusion(seq_len, block)``): the kernels
+``CAUSAL``, ``FULL``, ``BlockDiffusion(seq_len, block)``,
+``SlidingWindow(window)``): the kernels
 take from it the elementwise predicate and, per Q tile (forward) or K
 tile (backward), the ranges of opposite tiles to visit, each range
 masked or not.  Tiles the mask empties are never visited.

@@ -24,9 +24,11 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from ray_tpu.models.transformer import (TransformerConfig, _rms_norm,
+from ray_tpu.models.transformer import (SINGLE_DEVICE_KINDS,
+                                        TransformerConfig, _rms_norm,
                                         apply_layer, is_period, norm_weight,
-                                        param_specs, remat_layer)
+                                        param_specs, remat_layer,
+                                        run_options, runs_of)
 
 
 def pp_param_specs(cfg: TransformerConfig) -> Dict:
@@ -50,6 +52,14 @@ def make_pp_loss_fn(cfg: TransformerConfig, mesh, n_micro: int):
     """
     pp = mesh.shape["pp"]
     dp = mesh.shape.get("dp", 1)
+    kinds = {run_options(run[0])[0] for run in runs_of(cfg.layer_pattern)}
+    if kinds & set(SINGLE_DEVICE_KINDS) or cfg.tie_embeddings \
+            or cfg.norm != "rms":
+        raise ValueError(
+            f"the pipeline schedule runs rotary attention stages with "
+            f"RMSNorms and a head of their own: not {sorted(kinds)}, a tied "
+            f"head or a LayerNorm (what a layer hands to later layers "
+            f"would have to cross stages)")
     assert cfg.n_layers % pp == 0, "n_layers must divide over pp stages"
     # Composition limits of this schedule: the stage body runs
     # unsharded layer math, so head/FFN tensor parallelism and MoE

@@ -293,11 +293,14 @@ STEP_SCOPES = (
     "moe_shared", "moe_bias",
     "mla_q", "mla_kv", "mla_out", "mtp_module", "mtp_loss",
     "gdn_proj", "gdn_conv", "gdn_core", "gdn_out", "attn_gate",
-    "block_diffusion_loss", "flash_attention_bwd", "gated_delta_bwd")
+    "block_diffusion_loss", "flash_attention_bwd", "gated_delta_bwd",
+    "ssm_proj", "ssm_conv", "ssm_scan", "ssm_out", "gmu", "diff_attn",
+    "selective_scan_bwd")
 #: The names the train path's ``pallas_call``s are given: a device
 #: trace's events of these kernels start with them.
 KERNEL_EVENTS = ("flash_attention_fwd", "flash_attention_bwd",
-                 "gated_delta_fwd", "gated_delta_bwd")
+                 "gated_delta_fwd", "gated_delta_bwd",
+                 "selective_scan_fwd", "selective_scan_bwd")
 #: The passes of a step an instruction can belong to: the forward pass,
 #: the backward pass, and the forward that ``jax.checkpoint`` runs again
 #: inside the backward pass.

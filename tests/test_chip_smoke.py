@@ -97,6 +97,21 @@ def test_leg_hybrid_trainer(smoke):
     assert facts["gated_delta_bwd_max_rel_err"] <= 1e-4
 
 
+def test_leg_sambay_trainer(smoke):
+    facts = smoke.leg_sambay_trainer(
+        platform="cpu", batch=1, seq=128, steps=2, dtype="float32",
+        scan_tol=1e-4, flash_tol=1e-4,
+        model=dict(smoke.SAMBAY_MODEL, vocab_size=256, d_model=64, n_heads=8,
+                   n_kv_heads=4, d_ff=96, max_seq_len=128,
+                   mamba=dict(d_inner=1024, d_state=4, d_conv=4, dt_rank=4,
+                              chunk=32)))
+    assert facts["losses"][-1] < facts["losses"][0]
+    assert facts["selective_scan_calls_in_step"] == [0, 0]  # no Mosaic call
+    assert facts["counters"]["ssm_scan_fallback_passes"] == 1.0
+    assert facts["selective_scan_bwd_max_rel_err"] <= 1e-4
+    assert facts["window_flash_max_rel_err"] <= 1e-4
+
+
 def test_leg_sharded_solve(smoke):
     facts = smoke.leg_sharded_solve(platform="cpu", nodes=4096, classes=8,
                                     num_tasks=5000, tick_specs=256)

@@ -11,7 +11,8 @@ import jax.numpy as jnp
 import pytest
 
 from ray_tpu.models.transformer import TransformerConfig, remat_layer
-from ray_tpu.ops.attention_mask import CAUSAL, FULL, BlockDiffusion
+from ray_tpu.ops.attention_mask import (CAUSAL, FULL, BlockDiffusion,
+                                        SlidingWindow)
 from ray_tpu.ops.flash_attention import (_FWD_BLOCKS, RESIDUAL_NAMES,
                                          _flash_forward, attention,
                                          flash_attention)
@@ -811,3 +812,145 @@ def test_remat_scan_compiles_for_the_chip_with_one_forward_kernel_a_layer(
     assert _kernel_calls(text) == want
     assert text.count("tpu_custom_call") == sum(want)
     assert text.count(" while(") == 2
+
+
+# --- the sliding window ---------------------------------------------------
+
+def test_the_window_counts_the_querys_own_position():
+    """Query ``i`` sees keys ``i - w + 1 .. i``: ``w`` of them once the
+    row is that long, and ``L w - w (w - 1) / 2`` pairs in all."""
+    import numpy as np
+    mask = SlidingWindow(4)
+    pos = jnp.arange(16)
+    allowed = np.asarray(mask.allowed(pos[:, None], pos[None, :]))
+    assert allowed[7].nonzero()[0].tolist() == [4, 5, 6, 7]
+    assert allowed[2].nonzero()[0].tolist() == [0, 1, 2]
+    assert int(allowed.sum()) == 16 * 4 - 4 * 3 // 2
+    assert mask.tile_span(16) == 16 and mask != CAUSAL
+    assert SlidingWindow(4) == SlidingWindow(4)
+    with pytest.raises(ValueError, match="window"):
+        SlidingWindow(0)
+
+
+@pytest.mark.parametrize("window,block_q,block_k", [
+    (4, 8, 8), (8, 8, 8), (12, 8, 8), (24, 8, 8), (8, 16, 8), (8, 8, 16),
+    (5, 4, 8), (64, 8, 8), (1, 8, 8), (9, 8, 4)])
+def test_window_ranges_admit_exactly_the_tiles_with_an_allowed_pair(
+        window, block_q, block_k):
+    """Against the brute-force table, for windows below, at and above a
+    tile and past the row: a tile pair is visited iff it holds an
+    allowed pair and flagged unmasked iff it holds no disallowed one,
+    from the Q side (forward) and from the K side (backward)."""
+    mask = SlidingWindow(window)
+    num_q, num_k = 32 // block_q, 32 // block_k
+    pairs = back = 0
+    for i in range(num_q):
+        visit = _ranges_cover(mask.k_ranges(i, block_q, block_k, num_k),
+                              num_k)
+        for j in range(num_k):
+            some, every = _tile_has(mask, i, j, block_q, block_k)
+            assert (j in visit) == some, (i, j)
+            if some:
+                assert visit[j] == (not every), (i, j)
+        pairs += len(visit)
+    for j in range(num_k):
+        visit = _ranges_cover(mask.q_ranges(j, block_q, block_k, num_q),
+                              num_q)
+        for i in range(num_q):
+            some, every = _tile_has(mask, i, j, block_q, block_k)
+            assert (i in visit) == some, (i, j)
+            if some:
+                assert visit[i] == (not every), (i, j)
+        back += len(visit)
+    assert pairs == back
+
+
+def test_a_window_of_a_tile_visits_two_masked_tiles_a_tile():
+    """At 512-tiles and a window of 512 (the cell's) a Q tile visits the
+    tile before it and its own, both masked: 63 of the 528 tiles the
+    causal mask visits at 16,384 positions."""
+    mask, n = SlidingWindow(512), 16384 // 512
+    for ranges in (mask.k_ranges, mask.q_ranges):
+        for t in range(n):
+            visit = _ranges_cover(ranges(t, 512, 512, n), n)
+            near = {t - 1, t} if ranges == mask.k_ranges else {t, t + 1}
+            assert set(visit) == {x for x in near if 0 <= x < n}
+            assert all(visit.values())
+    assert sum(len(_ranges_cover(mask.k_ranges(t, 512, 512, n), n))
+               for t in range(n)) == 63
+
+
+@pytest.mark.parametrize("window", [40, 128, 200])
+@pytest.mark.parametrize("shape", [
+    dict(B=2, L=256, H=2), dict(B=1, L=512, H=4, kv_heads=2)],
+    ids=["mha", "grouped"])
+def test_window_forward_and_backward_match_full_attention(window, shape):
+    """Both kernels under windows below, at and above their 128-tiles,
+    grouped K/V too, against ``full_attention`` and its ``jax.grad``."""
+    mask = SlidingWindow(window)
+    q, k, v, _ = _qkvd(jnp.float32, **shape)
+    got = flash_attention(q, k, v, mask=mask, interpret=True,
+                          block_q=128, block_k=128)
+    assert _max_err(got, full_attention(q, k, v, mask=mask)) \
+        <= _TOL[jnp.float32]
+    # and it is not the causal answer
+    assert _max_err(got, full_attention(q, k, v)) > 1e-3
+    _assert_grads_match(jnp.float32, mask, shape)
+
+
+def test_differential_attention_gradient_compiles_for_the_chip_at_the_cell_width(
+        one_v5e_chip):
+    """Both kernels at the decoder-hybrid-decoder cell's attention shape
+    (1 row x 16,384 positions, 20 query heads of 64 over 10 key heads of
+    64 and value heads of 128, bfloat16) under the window of 512 and
+    under the causal mask: K and V whole a head fit at these widths."""
+    def spec(heads, width):
+        return jax.ShapeDtypeStruct((1, 16384, heads, width), jnp.bfloat16,
+                                    sharding=one_v5e_chip)
+
+    for mask in (SlidingWindow(512), CAUSAL):
+        text = jax.jit(jax.grad(lambda q, k, v: jnp.sum(flash_attention(
+            q, k, v, mask=mask).astype(jnp.float32)), (0, 1, 2))).lower(
+                spec(20, 64), spec(10, 64), spec(10, 128)).compile().as_text()
+        assert _kernel_calls(text) == (1, 1)
+        assert text.count("tpu_custom_call") == 2
+
+
+def test_selective_scan_gradient_compiles_for_the_chip_at_the_cell_width(
+        one_v5e_chip):
+    """Mosaic takes both scan kernels at the cell's Mamba layers' shape
+    (1 row x 16,384 positions, 5,120 channels x 16 states, chunks of
+    64): kept in this file because one process may describe the chip.
+    What the compiled programs hold in HBM: no array of ``[positions,
+    channels, states]`` in either direction; under the gradient the
+    entering states a chunk, written by the ``fwd`` rule's kernel."""
+    from ray_tpu.ops.selective_scan import selective_scan
+
+    def spec(shape, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_v5e_chip)
+
+    operands = (spec((1, 16384, 5120), jnp.bfloat16), spec((1, 16384, 5120)),
+                spec((5120, 16)), spec((1, 16384, 16)), spec((1, 16384, 16)),
+                spec((5120,)))
+
+    def calls(text):
+        return tuple(len(re.findall(
+            rf"%{name}[.\d]* = [^\n]*custom_call_target=\"tpu_custom_call\"",
+            text)) for name in ("selective_scan_fwd", "selective_scan_bwd"))
+
+    def rule(*xs):
+        return selective_scan(*xs, use_pallas=True)
+
+    def every_state(text):
+        # [.., 16384, .., 16, ..] or [.., 16384, 5120 or 40 x 128, .., 16]
+        return re.findall(r"f32\[(?:\d+,)*16384,(?:5120,16|16,5120|"
+                          r"16,40,128|40,128,16)\]", text)
+
+    forward = jax.jit(rule).lower(*operands).compile().as_text()
+    assert calls(forward) == (1, 0) and not every_state(forward)
+    text = jax.jit(jax.value_and_grad(lambda *xs: jnp.sum(rule(*xs).astype(
+        jnp.float32)), (0, 1, 2, 3, 4, 5))).lower(
+            *operands).compile().as_text()
+    assert calls(text) == (1, 1) and not every_state(text)
+    # the entering states: 256 chunks of [16, 40, 128]
+    assert "f32[1,256,16,40,128]" in text

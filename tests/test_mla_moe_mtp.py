@@ -295,8 +295,10 @@ def test_the_layer_pattern_names_what_a_model_is_made_of():
     assert specs["layers"][1]["mla"]["kv_norm"] == P(None, None)
     assert specs["layers"][1]["moe"]["ws1"] == P(None, None, "tp")
     assert specs["layers"][1]["moe"]["w1"] == P(None, "ep", None, None)
-    with pytest.raises(ValueError, match="layer pattern run"):
+    with pytest.raises(ValueError, match="layer pattern kind"):
         TransformerConfig(layer_pattern=(("window", "dense", 1),))
+    with pytest.raises(ValueError, match="layer pattern run"):
+        TransformerConfig(layer_pattern=(("mha", "sparse", 1),))
     with pytest.raises(ValueError, match="mla sizes"):
         TransformerConfig(layer_pattern=(("mla", "dense", 1),))
     # the next-token loss runs the pattern too (no module asked for)

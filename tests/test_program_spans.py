@@ -392,17 +392,24 @@ def test_every_scope_literal_is_declared_and_every_declared_scope_is_used():
             kernels.add(names[0].value)
     assert scopes == set(tracing.STEP_SCOPES)
     assert kernels == set(tracing.KERNEL_EVENTS)
-    assert len(set(tracing.STEP_SCOPES)) == len(tracing.STEP_SCOPES) == 23
+    assert len(set(tracing.STEP_SCOPES)) == len(tracing.STEP_SCOPES) == 30
 
 
 def test_every_declared_scope_feeds_one_metric():
     """Each scope and kernel event is summed by exactly one of the
-    benchmark's group metrics; ``optimizer`` alone is left to the rest
-    (``train_step_device_ms`` less the groups)."""
+    benchmark's group metrics -- ``step_scopes.GROUPS``, or the list in
+    the file of a metric that came after it (``ssm_layers_ms``);
+    ``optimizer`` is left to the rest (``train_step_device_ms`` less the
+    groups), and ``diff_attn`` to ``step_attributed_pct`` alone."""
+    from benchmarks import run as bench_run
     from benchmarks.harness import step_scopes
+    ssm = bench_run._reader("ssm_layers_ms").__globals__["SCOPES"]
+    assert {"ssm_proj", "ssm_conv", "ssm_scan", "ssm_out", "gmu",
+            "selective_scan_fwd", "selective_scan_bwd"} == set(ssm)
     grouped = [s for group in step_scopes.GROUPS.values() for s in group]
+    grouped += list(ssm)
     assert len(grouped) == len(set(grouped))
-    assert set(grouped) | {"optimizer"} == \
+    assert set(grouped) | {"optimizer", "diff_attn"} == \
         set(tracing.STEP_SCOPES) | set(tracing.KERNEL_EVENTS)
     for metric in step_scopes.GROUPS:
         assert os.path.exists(os.path.join(
@@ -410,7 +417,7 @@ def test_every_declared_scope_feeds_one_metric():
 
 
 def _tiny_step(kind):
-    """-> (step, state, batch) of one of the four tiny configurations the
+    """-> (step, state, batch) of one of the five tiny configurations the
     tests of the models build, as the benchmark's drivers build them."""
     import jax
     import jax.numpy as jnp
@@ -438,6 +445,10 @@ def _tiny_step(kind):
         from ray_tpu.models import mtp
         cfg = tiny._cfg()
         over = functools.partial(mtp.loss_fn, cfg=cfg, coeff=0.3)
+        batch = {"tokens": jnp.asarray(tiny._batches(3)[0])}
+    elif kind == "sambay":
+        import test_phi4_flash as tiny
+        cfg = tiny._cfg()
         batch = {"tokens": jnp.asarray(tiny._batches(3)[0])}
     else:
         import test_qwen3_next as tiny
@@ -467,6 +478,9 @@ MANIFESTS = {
                    optimizer=OUTSIDE),
     "hybrid": dict(MOE, attention=ALL, ffn=ALL, attn_gate=ALL, gdn_proj=ALL,
                    gdn_conv=ALL, gdn_core=ALL, gdn_out=ALL, moe_shared=ALL,
+                   head_loss=ONCE, optimizer=OUTSIDE),
+    "sambay": dict(attention=ALL, ffn=ALL, ssm_proj=ALL, ssm_conv=ALL,
+                   ssm_scan=ALL, ssm_out=ALL, gmu=ALL, diff_attn=ALL,
                    head_loss=ONCE, optimizer=OUTSIDE),
 }
 
@@ -550,7 +564,10 @@ def test_the_registry_entrys_memory_carries_the_plan(kind, monkeypatch):
     assert sorted(run["kind"] for run in plan["runs"]) == {
         "dense": ["mha+dense"], "block_diffusion": ["mha+moe"],
         "latent": ["mla+dense", "mla+moe", "mla+moe"],
-        "hybrid": ["gdn+moe", "mha+moe"]}[kind]
+        "hybrid": ["gdn+moe", "mha+moe"],
+        "sambay": ["diff:reads=kv+dense", "diff:window=8+dense",
+                   "diff:writes=kv+dense", "gmu+dense", "mamba+dense",
+                   "mamba:writes=memory+dense"]}[kind]
     assert 0 < plan["plan_seconds"] < 5 and plan["trace_seconds"] > 0
     exposed = get_metrics_registry().render_prometheus().splitlines()
     for name in ("kept_bytes", "budget_bytes", "plan_seconds"):

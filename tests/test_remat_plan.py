@@ -18,6 +18,7 @@ import pytest
 
 from ray_tpu.models import remat, transformer
 from ray_tpu.models.gdn import GDNConfig
+from ray_tpu.models.mamba import MambaConfig
 from ray_tpu.models.mla import MLAConfig
 from ray_tpu.models.transformer import (TransformerConfig, apply_layer,
                                         init_stack, run_stack)
@@ -26,6 +27,7 @@ from ray_tpu.ops.flash_attention import RESIDUAL_NAMES
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 _BASE = dict(vocab_size=64, d_model=32, n_heads=2, d_ff=48, max_seq_len=32,
              dtype=jnp.float32, context_parallel=False)
+_MAMBA = MambaConfig(d_inner=64, d_state=4, d_conv=4, dt_rank=4, chunk=8)
 # kind -> (the run's (attention, ffn), the configuration, names a plan
 # with room for everything has to keep at these sizes).  What it leaves
 # spares nothing once what is upstream of it is kept.
@@ -57,6 +59,34 @@ KINDS = {
                     dict(n_layers=2, moe_experts=4, moe_top_k=2,
                          moe_scoring="sigmoid", moe_bias_rate=0.01),
                     {"mid_residual", "moe_scores", "attn_v"}),
+    "mamba": (("mamba:writes=memory", "dense"),
+              dict(layer_pattern=(("mamba:writes=memory", "dense", 2),),
+                   mamba=_MAMBA, norm="layernorm"),
+              {"mid_residual", "ssm_in", "ssm_conv", "ssm_dbc", "ffn_gate",
+               "ffn_up"}),
+    "gmu": (("gmu", "dense"),
+            dict(layer_pattern=(("mamba:writes=memory", "dense", 1),
+                                ("gmu", "dense", 2)), mamba=_MAMBA),
+            {"mid_residual", "gmu_gate", "ffn_gate", "ffn_up"}),
+    "diff-window": (("diff:window=8,writes=kv", "dense"),
+                    dict(layer_pattern=(("diff:window=8,writes=kv", "dense",
+                                         2),), n_heads=4, n_kv_heads=2,
+                         rope="none"),
+                    {"mid_residual", "diff_q", "diff_kv", "ffn_gate",
+                     "ffn_up"}),
+    "diff-cross": (("diff:reads=kv", "dense"),
+                   dict(layer_pattern=(("diff:writes=kv", "dense", 1),
+                                       ("diff:reads=kv", "dense", 2)),
+                        n_heads=4, n_kv_heads=2, tie_embeddings=True),
+                   {"mid_residual", "diff_q", "ffn_gate", "ffn_up"}),
+}
+#: What the kinds that read the shared slot are handed (rows 2, the
+#: tests' length 32): a scan output; keys and values.
+SLOTS = {
+    "gmu": lambda: {"memory": jnp.full((2, 32, 64), 0.5, jnp.float32)},
+    "diff-cross": lambda: {"kv": tuple(
+        jax.random.normal(jax.random.PRNGKey(7 + i), (2, 32, 1, w))
+        for i, w in enumerate((8, 8, 16)))},
 }
 ROOM = (1 << 44, 0)          # a device with room for everything
 FULL = (1 << 20, 1 << 20)    # ... and one that is full already
@@ -93,7 +123,9 @@ def _two_layers(kind, remat_on=True, length=32, **more):
                                  (2, length))
 
     def loss(x, stack):
-        return jnp.sum(run_stack(x, stack, run, positions, cfg)[0] ** 2)
+        return jnp.sum(run_stack(
+            x, stack, run, positions, cfg, first_index=3,
+            shared=SLOTS.get(kind, dict)())[0] ** 2)
 
     return cfg, loss, x, stack
 
@@ -214,8 +246,9 @@ def _survey_of(kind, layers=1, **more):
     run = KINDS[kind][0]
     positions = jnp.zeros(x.shape[:2], jnp.int32)
     one = jax.tree.map(lambda a: a[0], stack)
-    jaxpr = jax.make_jaxpr(
-        lambda x, lp: apply_layer(x, lp, positions, cfg, kind=run))(x, one)
+    jaxpr = jax.make_jaxpr(lambda x, lp: transformer._apply_layer(
+        x, lp, positions, cfg, kind=run, index=3,
+        shared=SLOTS.get(kind, dict)())[:2])(x, one)
     return remat.survey(jaxpr.jaxpr, layers, run, x.size * 4)
 
 
@@ -428,10 +461,13 @@ def test_every_named_cut_point_is_a_candidate_some_survey_sees():
     assert not seen & set(RESIDUAL_NAMES)
 
 
-STEPS = ("dense", "block_diffusion", "latent", "hybrid")
+STEPS = ("dense", "block_diffusion", "latent", "hybrid", "sambay")
 RUNS = {"dense": ["mha+dense"], "block_diffusion": ["mha+moe"],
         "latent": ["mla+dense", "mla+moe", "mla+moe"],
-        "hybrid": ["gdn+moe", "mha+moe"]}
+        "hybrid": ["gdn+moe", "mha+moe"],
+        "sambay": ["diff:reads=kv+dense", "diff:window=8+dense",
+                   "diff:writes=kv+dense", "gmu+dense", "mamba+dense",
+                   "mamba:writes=memory+dense"]}
 
 
 def _counting(monkeypatch, module, name, counts):
@@ -448,7 +484,7 @@ def _counting(monkeypatch, module, name, counts):
 @pytest.mark.parametrize("kind", STEPS)
 def test_planning_a_step_calls_no_function_more_often(kind, monkeypatch):
     """The plan's cost as a count, not a clock: tracing a planned step
-    calls each layer's function (``apply_layer``), the loss
+    calls each layer's function (``_apply_layer``), the loss
     (``loss_and_counters`` or the override) and the reader of a layer
     (``survey``: once a run, Python over a jaxpr that exists) as often
     as the runs are, and the objective once -- what the unplanned trace
@@ -456,7 +492,7 @@ def test_planning_a_step_calls_no_function_more_often(kind, monkeypatch):
     gets the plan of the first."""
     from test_program_spans import _tiny_step
     counts = collections.Counter()
-    _counting(monkeypatch, transformer, "apply_layer", counts)
+    _counting(monkeypatch, transformer, "_apply_layer", counts)
     _counting(monkeypatch, remat, "survey", counts)
     real = jax.make_jaxpr
     monkeypatch.setattr(remat.jax, "make_jaxpr", lambda *a, **k: (
@@ -469,15 +505,15 @@ def test_planning_a_step_calls_no_function_more_often(kind, monkeypatch):
         step.lower(state, batch)
         calls[how] = dict(counts)
     runs = len(RUNS[kind])
-    assert calls["today"] == {"apply_layer": runs}
-    assert calls["planned"] == {"apply_layer": runs, "survey": runs,
+    assert calls["today"] == {"_apply_layer": runs}
+    assert calls["planned"] == {"_apply_layer": runs, "survey": runs,
                                 "make_jaxpr": 1}
     # the same step traced again (other arguments' weak types, say)
     counts.clear()
     first = dict(step._kept)
     jax.clear_caches()
     step.lower(state, batch)
-    assert counts == {"apply_layer": runs, "make_jaxpr": 1}
+    assert counts == {"_apply_layer": runs, "make_jaxpr": 1}
     assert {**step._kept, "trace_seconds": 0} == {**first, "trace_seconds": 0}
 
 
