@@ -333,6 +333,12 @@ def _train_func(config: dict) -> dict:
         train.report(step=i, loss=losses[-1])
     leaf = jax.tree.leaves(state["params"])[0]
     text = compiled.as_text()
+
+    def mosaic_calls(*names):
+        return [len(re.findall(
+            rf'%{name}[.\d]* = [^\n]*"tpu_custom_call"', text))
+            for name in names]
+
     return {"losses": losses,
             # the last step's scalars (the expert layers' counters, the
             # two losses and the bias of a latent-attention model)
@@ -350,14 +356,15 @@ def _train_func(config: dict) -> dict:
             # scan's body (writing o alone) and again in the backward's
             # (nothing of it is kept: there it also writes the entering
             # states), the backward once
-            "gated_delta_calls_in_step": [len(re.findall(
-                rf'%{name}[.\d]* = [^\n]*"tpu_custom_call"', text))
-                for name in ("gated_delta_fwd", "gated_delta_bwd")],
+            "gated_delta_calls_in_step": mosaic_calls(
+                "gated_delta_fwd", "gated_delta_bwd"),
+            # the delta layers' convolution: as the rule's kernels
+            "causal_conv_calls_in_step": mosaic_calls(
+                "causal_conv_fwd", "causal_conv_bwd"),
             # the selective scan's: the forward in both scans' bodies
             # (nothing of it is kept), the backward once, a Mamba layer
-            "selective_scan_calls_in_step": [len(re.findall(
-                rf'%{name}[.\d]* = [^\n]*"tpu_custom_call"', text))
-                for name in ("selective_scan_fwd", "selective_scan_bwd")],
+            "selective_scan_calls_in_step": mosaic_calls(
+                "selective_scan_fwd", "selective_scan_bwd"),
             "flash_bwd_calls_in_step": len(re.findall(
                 r'%flash_attention_bwd[.\d]* = [^\n]*"tpu_custom_call"',
                 text)),
@@ -628,14 +635,18 @@ def leg_hybrid_trainer(platform: str = "tpu", model: dict = None,
     the delta rule's two fused kernels (the whole rule in VMEM, the
     gradient by hand) first compared with the chunked ``jnp`` form and its
     ``jax.grad`` (all five gradients) at the model's shape, q and k at
-    the value heads and at the key heads; then the compiled step must
-    hold both delta kernels and both flash kernels, nothing may be
-    dropped, and the new counters must read what an untrained model's
+    the value heads and at the key heads, and the convolution's two
+    kernels with ``silu(causal_conv(.))`` and its ``jax.grad`` at the
+    fused projection's shape (off the chip only where the tiny shape is
+    one the kernels take); then the compiled step must hold both delta
+    kernels, both convolution kernels and both flash kernels, nothing may
+    be dropped, and the new counters must read what an untrained model's
     gates read."""
     import jax
     import jax.numpy as jnp
 
     import ray_tpu
+    from ray_tpu.ops import causal_conv as conv_op
     from ray_tpu.ops.gated_delta import gated_delta_rule
     from ray_tpu.train import Trainer
 
@@ -692,6 +703,32 @@ def leg_hybrid_trainer(platform: str = "tpu", model: dict = None,
           f"gated delta backward (dq, dk, dv, dg, dbeta) vs grad of the "
           f"chunked scan: {bwd_err} > {rule_tol} ({dtype})")
 
+    # the convolution over q | k | v of every key head of the fused
+    # projection [q | k | v | z], as the layer calls it
+    hk, ratio = g_["num_key_heads"], heads // g_["num_key_heads"]
+    width = 2 * dk + ratio * dv
+    qkvz = jax.random.normal(
+        keys[0], (batch, seq, hk, width + ratio * dv)).astype(low)
+    taps = (0.5 * jax.random.normal(
+        keys[1], (hk, width, g_["conv_kernel"]))).astype(low)
+    dmixed = jax.random.normal(keys[2], (batch, seq, hk, width))
+
+    def conv_kernels(x, t):
+        return conv_op.in_kernels(x, t, interpret=not on_chip)
+
+    def conv_plain(x, t):
+        return jax.nn.silu(conv_op.causal_conv(x[..., :width], t))
+
+    conv_err = None
+    if conv_op.fits(qkvz.shape, taps.shape):
+        got, got_vjp = jax.vjp(conv_kernels, qkvz, taps)
+        want, want_vjp = jax.vjp(conv_plain, qkvz, taps)
+        conv_err = max([rel_err(got, want)] + [rel_err(a, b) for a, b in zip(
+            got_vjp(dmixed), want_vjp(dmixed))])
+        check(conv_err <= rule_tol,
+              f"convolution kernels (mixed, dqkvz, dtaps) vs silu(causal_conv)"
+              f" and its grad: {conv_err} > {rule_tol} ({dtype})")
+
     ray_tpu.init(num_cpus=4, num_tpus=len(jax.devices()))
     try:
         trainer = Trainer(backend="jax", num_workers=1, use_tpu=True)
@@ -714,6 +751,14 @@ def leg_hybrid_trainer(platform: str = "tpu", model: dict = None,
           f"the delta rule's forward kernel in both scans' bodies and its "
           f"backward in one: {result['gated_delta_calls_in_step']} Mosaic "
           f"calls")
+    check(result["causal_conv_calls_in_step"]
+          == [2 * int(on_chip), int(on_chip)]
+          and counters["gdn_conv_fallback_passes"] == float(not on_chip),
+          f"the convolution's forward kernel in both scans' bodies and its "
+          f"backward in one, no pass by the jnp form: "
+          f"{result['causal_conv_calls_in_step']} Mosaic calls, "
+          f"gdn_conv_fallback_passes "
+          f"{counters['gdn_conv_fallback_passes']}")
     check(result["flash_fwd_calls_in_step"] == int(on_chip)
           and result["mosaic_bwd_in_step"] == on_chip,
           f"both flash kernels once in the compiled step: "
@@ -731,7 +776,9 @@ def leg_hybrid_trainer(platform: str = "tpu", model: dict = None,
             "losses": [round(x, 4) for x in losses],
             "counters": {k: round(v, 5) for k, v in counters.items()},
             "gated_delta_calls_in_step": result["gated_delta_calls_in_step"],
+            "causal_conv_calls_in_step": result["causal_conv_calls_in_step"],
             "flash_fwd_calls_in_step": result["flash_fwd_calls_in_step"],
+            "causal_conv_max_rel_err": conv_err,
             "gated_delta_vs_scan_max_rel_err": fwd_err,
             "gated_delta_bwd_max_rel_err": bwd_err, "rule_tol": rule_tol}
 

@@ -12,6 +12,7 @@ heads of ``Dv`` (key head ``j`` serves value heads ``r j .. r j + r - 1``):
     b | a         = h w_ba         [r | r] a key head
     q | k | v     = silu(conv(q | k | v))   causal, depthwise, ``taps``
                     positions, zeros before the row's start, no bias
+                    (ops/causal_conv.py)
     q, k          = l2norm(q) Dk^-1/2, l2norm(k)      (eps 1e-6)
     beta          = sigmoid(b)                         float32
     g             = -exp(A_log) softplus(a + dt_bias)  float32
@@ -32,7 +33,15 @@ What the rule leaves in HBM on a TPU: its operands as this layer makes
 them (q, k at ``Hk`` heads, v, g, beta), ``o`` and, between the forward
 rule and the backward one, each chunk's entering state in float32; no
 copy of q and k a value head, no ``[C, C]`` tensor, no ``W``, ``U`` or
-``V'`` (``ops/gated_delta.py``).
+``V'`` (``ops/gated_delta.py``).  What the convolution reads and writes
+there: ``qkvz`` as the projection left it (the ``q | k | v`` columns of
+every key head through the kernels' block index: no sliced copy, no
+padded float32 copy, no sum before SiLU as an array) and ``mixed`` in
+float32, once each; backward ``qkvz``, ``mixed``'s cotangent and
+``qkvz``'s (zeros in ``z``'s columns), once each, and the taps' gradient
+as a sum a tile (``ops/causal_conv.py``).  Off the TPU, or at channels
+that are not whole 128-lane blocks, the plain ``causal_conv`` and XLA's
+SiLU run instead and ``gdn_conv_fallback_passes`` counts 1.
 """
 
 from __future__ import annotations
@@ -44,6 +53,10 @@ import jax
 import jax.numpy as jnp
 from jax.ad_checkpoint import checkpoint_name
 from jax.sharding import PartitionSpec as P
+
+from ray_tpu.ops import causal_conv as conv_op
+# ``models/mamba.py`` and the tests take the plain filter from here
+from ray_tpu.ops.causal_conv import causal_conv  # noqa: F401
 
 _L2_EPS = 1e-6
 
@@ -99,18 +112,6 @@ def gdn_param_specs() -> Dict:
     }
 
 
-def causal_conv(x, taps):
-    """x [B, S, ..., C], taps [..., C, K]: each channel over its own last
-    K positions (tap K - 1 on the position itself), zeros before the
-    row's start; summed in float32."""
-    k = taps.shape[-1]
-    seq = x.shape[1]
-    padded = jnp.pad(x.astype(jnp.float32),
-                     [(0, 0), (k - 1, 0)] + [(0, 0)] * (x.ndim - 2))
-    taps = taps.astype(jnp.float32)
-    return sum(padded[:, j:j + seq] * taps[..., j] for j in range(k))
-
-
 def _l2norm(x):
     xf = x.astype(jnp.float32)
     return xf * jax.lax.rsqrt(jnp.sum(xf * xf, axis=-1, keepdims=True)
@@ -121,7 +122,9 @@ def gdn_attention(h, lp: Dict, cfg, mesh=None):
     """The layer's normed input ``h [B, S, d]`` -> (what the delta layer
     adds to the residual, what it counted: ``gdn_state_norm`` -- the
     root mean square of the state entering a row's last chunk --
-    ``gdn_decay_mean``, the mean of ``exp(g)``, and ``gdn_beta_mean``).
+    ``gdn_decay_mean``, the mean of ``exp(g)``, ``gdn_beta_mean`` and
+    ``gdn_conv_fallback_passes``: 1 where the convolution ran as the
+    ``jnp`` form, 0 where as the kernels).
     ``lp``: this layer's ``gdn`` parameters."""
     from ray_tpu.ops.gated_delta import gated_delta_rule
     g_ = cfg.gdn
@@ -134,8 +137,8 @@ def gdn_attention(h, lp: Dict, cfg, mesh=None):
     b, s, _ = h.shape
     f32 = jnp.float32
     # The names: cut points a rematerialised layer may keep
-    # (``models/remat.py``) -- the two projections, the convolution's
-    # sum (which SiLU's gradient reads) and its output.
+    # (``models/remat.py``) -- the two projections and the convolution's
+    # output after SiLU (the kernels leave no sum before it in HBM).
     with jax.named_scope("gdn_proj"):
         qkvz = checkpoint_name(
             jnp.einsum("bsd,dhc->bshc", h, lp["w_qkvz"]), "gdn_qkvz")
@@ -143,9 +146,10 @@ def gdn_attention(h, lp: Dict, cfg, mesh=None):
             jnp.einsum("bsd,dhc->bshc", h, lp["w_ba"]), "gdn_ba").astype(f32)
         z = qkvz[..., 2 * dk + r * dv:].reshape(b, s, hk * r, dv)
     with jax.named_scope("gdn_conv"):
-        mixed = checkpoint_name(jax.nn.silu(checkpoint_name(
-            causal_conv(qkvz[..., :2 * dk + r * dv], lp["conv"]),
-            "gdn_conv")), "gdn_mixed")
+        # q | k | v of every key head, read out of qkvz by the kernels'
+        # block index: z's columns are never sliced away in HBM
+        mixed = checkpoint_name(
+            conv_op.causal_conv_silu(qkvz, lp["conv"]), "gdn_mixed")
     with jax.named_scope("gdn_core"):
         q = (_l2norm(mixed[..., :dk]) * dk ** -0.5).astype(h.dtype)
         k = _l2norm(mixed[..., dk:2 * dk]).astype(h.dtype)
@@ -162,6 +166,8 @@ def gdn_attention(h, lp: Dict, cfg, mesh=None):
             "gdn_state_norm": jnp.sqrt(jnp.mean(jnp.square(state))),
             "gdn_decay_mean": jax.lax.stop_gradient(jnp.mean(jnp.exp(g))),
             "gdn_beta_mean": jax.lax.stop_gradient(jnp.mean(beta)),
+            "gdn_conv_fallback_passes": jnp.asarray(
+                conv_op.fallback_passes(qkvz.shape, lp["conv"].shape), f32),
         }
     with jax.named_scope("gdn_out"):
         o = o.astype(f32)

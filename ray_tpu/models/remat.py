@@ -238,8 +238,8 @@ def _flatten(jaxpr, env: Dict[Any, _Val], out: List[_Eqn]) -> None:
         env.update(zip(eqn.outvars, outs))
         work, inner_bytes = 0.0, 0
         if prim in _HEAVY:
-            work = (sum(v.bytes for v in ins) + sum(v.bytes for v in outs)
-                    + _eqn_flops(eqn) / _FLOPS_A_BYTE)
+            work = (sum(v.bytes for v in {id(v): v for v in (*ins, *outs)}
+                        .values()) + _eqn_flops(eqn) / _FLOPS_A_BYTE)
             if prim in _BODIES:
                 inner_bytes = max((_held(*_flat_of(j))
                                    for j, _ in _sub_jaxprs(eqn)), default=0)

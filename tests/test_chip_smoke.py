@@ -93,6 +93,10 @@ def test_leg_hybrid_trainer(smoke):
                             conv_kernel=4, chunk=32)))
     assert facts["losses"][-1] < facts["losses"][0]
     assert facts["gated_delta_calls_in_step"] == [0, 0]   # no Mosaic call
+    assert facts["causal_conv_calls_in_step"] == [0, 0]
+    assert facts["counters"]["gdn_conv_fallback_passes"] == 1.0
+    # 64 of every 96 columns a key head: no shape of the kernels'
+    assert facts["causal_conv_max_rel_err"] is None
     assert facts["counters"]["moe_dropped_choices"] == 0.0
     assert facts["gated_delta_bwd_max_rel_err"] <= 1e-4
 

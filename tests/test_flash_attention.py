@@ -954,3 +954,37 @@ def test_selective_scan_gradient_compiles_for_the_chip_at_the_cell_width(
     assert calls(text) == (1, 1) and not every_state(text)
     # the entering states: 256 chunks of [16, 40, 128]
     assert "f32[1,256,16,40,128]" in text
+
+
+def test_causal_conv_gradient_compiles_for_the_chip_at_the_cell_width(
+        one_v5e_chip):
+    """Mosaic takes both kernels of the delta layers' convolution at the
+    hybrid cell's shape (2 rows x 8,192 positions, 16 key heads of 768
+    columns of which 512 are filtered, 4 taps, bfloat16): the packed
+    rotation, the block index that skips ``z``, the lane-sparse store of
+    the taps' sums.  Kept in this file because one process may describe
+    the chip.  What the compiled program no longer holds: the padded
+    float32 copy (8,195 positions) and any float32 array of the
+    projection's whole width."""
+    from ray_tpu.ops import causal_conv as cc
+
+    def spec(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_v5e_chip)
+
+    def calls(text):
+        return tuple(len(re.findall(
+            rf"%\w*{name}[\w.]* = [^\n]*custom_call_target=\"tpu_custom_call\"",
+            text)) for name in ("causal_conv_fwd", "causal_conv_bwd"))
+
+    op = cc.in_kernels
+
+    operands = (spec((2, 8192, 16, 768)), spec((16, 512, 4)))
+    forward = jax.jit(op).lower(*operands).compile().as_text()
+    assert calls(forward) == (1, 0)
+    text = jax.jit(lambda x, taps, dy: jax.vjp(op, x, taps)[1](dy)).lower(
+        *operands, spec((2, 8192, 16, 512), jnp.float32)).compile().as_text()
+    assert calls(text) == (0, 1)
+    for compiled in (forward, text):
+        assert "8195" not in compiled
+        assert not re.findall(r"f32\[2,(?:8192,12288|12288,8192|"
+                              r"8192,16,768)\]", compiled)

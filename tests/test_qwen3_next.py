@@ -407,6 +407,9 @@ def step_jaxpr_hash(cell: str, root: str = ROOT) -> str:
         kwargs["mla"] = MLAConfig(**kwargs["mla"])
     if "gdn" in kwargs:
         kwargs["gdn"] = GDNConfig(**kwargs["gdn"])
+    if "mamba" in kwargs:
+        from ray_tpu.models.mamba import MambaConfig
+        kwargs["mamba"] = MambaConfig(**kwargs["mamba"])
     cfg = TransformerConfig(dtype=jnp.dtype(config["dtype"]), **kwargs)
     if wl["driver"] == "trainer_blockdiff_steps":
         from ray_tpu.models import block_diffusion
@@ -441,11 +444,15 @@ def step_jaxpr_hash(cell: str, root: str = ROOT) -> str:
 
 
 # The three older cells' steps at the commit 50ae53a (PR 34), which they
-# still equal, and the hybrid cell's at the commit 32d33aa (PR 36).  Since
+# still equal, and the state-space cell's at 7f1202d (PR 40: its
+# convolution still calls the plain ``causal_conv``).  Since
 # PR 39 the layers' cut points carry names (``checkpoint_name``: metadata
 # that lowers to nothing), which are equations of the jaxpr: the test
 # below takes the names out and finds these hashes, so the names are all
-# that differs where no device reports a limit.
+# that differs where no device reports a limit.  The hybrid cell's step
+# is PR 41's own: its delta layers' convolution is now the kernel pair of
+# ``ops/causal_conv.py`` (traced as on a TPU here) and the layer counts
+# one more thing (7627867e... at PR 36 to PR 40).
 PARENT_STEPS = {
     "train-dscoder-1b3.pack4k":
         "593226c3e798e87962790db2f4055938f8862739301114386319090f98a5021b",
@@ -454,7 +461,9 @@ PARENT_STEPS = {
     "train-joyai-flash.pack8k":
         "7acc7897f37b96e284f7c4873a1eb0820aeee4b6254a698c29781d9c3a3b91aa",
     "train-qwen3-next.pack8k":
-        "7627867e8f9f64b5350d5c720dc0b50ada98ce297a253b425396b993aa6cdeda",
+        "e839d08e1a72a3af1560c39f74eb027a1d48694f6df0cac4797cb272512a9636",
+    "train-phi4-mini-flash.pack16k":
+        "ef676578e0020595e3ae2f5d9b63594d720dd9670c02ef45bb2f7c366b65b715",
 }
 
 

@@ -435,6 +435,35 @@ def test_a_name_has_to_spare_more_than_keeping_it_costs(monkeypatch):
         assert all(c.work / c.bytes > remat._KEPT_BYTE_MOVES for c in order)
 
 
+def test_the_convolutions_output_is_not_worth_its_bytes_at_the_cell(
+        monkeypatch):
+    """At the hybrid cell's widths, traced as on a TPU (the convolution
+    is its kernel pair: shapes alone, nothing is compiled), the delta
+    layer's ``gdn_mixed`` -- 537 MB of float32 a layer -- stays out of
+    the order: keeping it would spare one pass of the forward kernel,
+    which takes ``qkvz`` twice (a tile and the positions before it) and
+    reads it once, so it is priced once; ``gdn_qkvz``, a product, is in."""
+    monkeypatch.undo()
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    cfg = TransformerConfig(**{
+        **_BASE, "d_model": 2048, "n_heads": 16, "d_ff": 512,
+        "dtype": jnp.bfloat16, "layer_pattern": (("gdn", "dense", 1),),
+        "gdn": GDNConfig(16, 32, 128, 128)})
+    stack = jax.eval_shape(
+        lambda: init_stack(jax.random.PRNGKey(0), cfg, "gdn", "dense", 1))
+    x = jax.ShapeDtypeStruct((2, 8192, 2048), jnp.bfloat16)
+    positions = jnp.zeros((2, 8192), jnp.int32)
+    jaxpr = jax.make_jaxpr(
+        lambda x, lp: apply_layer(x, lp, positions, cfg))(
+            x, jax.tree.map(lambda a: jax.ShapeDtypeStruct(
+                a.shape[1:], a.dtype), stack))
+    assert "causal_conv_fwd" in str(jaxpr)
+    survey = remat.survey(jaxpr.jaxpr)
+    assert survey.names["gdn_mixed"] == 2 * 8192 * 16 * 512 * 4
+    ordered = {c.name for c in remat.worth_order([survey])}
+    assert "gdn_qkvz" in ordered and "gdn_mixed" not in ordered
+
+
 def test_every_named_cut_point_is_a_candidate_some_survey_sees():
     """Every ``checkpoint_name`` literal in ``models/`` and ``ops/`` is
     a candidate in the survey of some kind of layer here (the flash
