@@ -86,11 +86,11 @@ def mla_param_specs() -> Dict:
 
 def rope(x, positions, theta: float, interleave: bool):
     """x [B, S, H, R] -> the rotated pairs, de-interleaved."""
-    from ray_tpu.models.transformer import _rope
+    from ray_tpu.models.transformer import RopeTable, _rope
     if interleave:
         pairs = x.reshape(*x.shape[:-1], x.shape[-1] // 2, 2)
         x = jnp.concatenate([pairs[..., 0], pairs[..., 1]], axis=-1)
-    return _rope(x, positions, theta)
+    return _rope(x, positions, RopeTable(theta))
 
 
 def mla_attention(h, lp: Dict, positions, cfg, mesh=None, mask=None):

@@ -355,10 +355,10 @@ def moe_ffn(x: jax.Array, lp: Dict, top_k: int, norm_topk: bool = True,
     token's experts, int32), and what ``balance_loss`` reads:
     ``router_load`` [E] (choices of every expert, held or not),
     ``router_prob`` [E] (float32 sum of the tokens' probabilities, the
-    differentiable part) and ``tokens``.  ``scoring="sigmoid"``: the
-    scores are sigmoids, the choice is by score plus ``lp["bias"]`` [E]
-    (where the layer has one), the gates are the chosen scores
-    (renormalised if ``norm_topk``) times ``route_scale``.
+    differentiable part) and ``tokens``.  The gates are the chosen
+    scores (renormalised if ``norm_topk``) times ``route_scale``.
+    ``scoring="sigmoid"``: the scores are sigmoids, the choice is by
+    score plus ``lp["bias"]`` [E] (where the layer has one).
     ``alike_tail``: the share of blocks of alike tokens the first chunk
     may fall short of (``chunk_rows``)."""
     lead, D = x.shape[:-1], x.shape[-1]
@@ -386,6 +386,8 @@ def moe_ffn(x: jax.Array, lp: Dict, top_k: int, norm_topk: bool = True,
             gate = checkpoint_name(gate, "moe_choice")
             if norm_topk:
                 gate = gate / jnp.sum(gate, axis=-1, keepdims=True)
+            if route_scale != 1.0:
+                gate = gate * route_scale
         elif scoring == "sigmoid":
             probs = jax.nn.sigmoid(logits)
             gate, expert = _choose(probs, top_k, lp.get("bias"))

@@ -51,17 +51,20 @@ graft entry exercise):
 
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import functools
+import math
 from typing import Any, Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.ad_checkpoint import checkpoint_name
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from ray_tpu.models import remat
-from ray_tpu.ops.attention_mask import CAUSAL
+from ray_tpu.ops.attention_mask import CAUSAL, SlidingWindow
 from ray_tpu.ops.flash_attention import attention as flash_or_ref_attention
 from ray_tpu.ops.ring_attention import ring_attention
 from ray_tpu.util import tracing
@@ -70,15 +73,63 @@ from ray_tpu.util import tracing
 #: The kinds a run of the layer pattern is made of.
 _ATTENTION = ("mha", "mla", "gdn", "mamba", "gmu", "diff")
 _FFN = ("dense", "moe")
-#: What a run of each kind may say beside its kind (``run_options``),
-#: and the slot each word is about.
+#: What a run of each kind may say beside its kind (``run_options``):
+#: a whole number, a name, or one of the slots the word is about.
 _OPTIONS = {"mamba": {"writes": ("memory",)},
-            "diff": {"window": None, "writes": ("kv",), "reads": ("kv",)}}
+            "diff": {"window": int, "writes": ("kv",), "reads": ("kv",)},
+            "mha": {"heads": int, "window": int, "rope": str}}
 #: The kinds that run on one device's rows whole: no ``tp``, ``sp`` or
 #: pipeline layout yet.
 SINGLE_DEVICE_KINDS = ("mamba", "gmu", "diff")
 #: The kinds whose layers read their index in the model.
 _INDEXED_KINDS = ("diff",)
+
+
+@dataclasses.dataclass(frozen=True)
+class RopeTable:
+    """One rotary table: what a run of the ``mha`` kind turns q and k by
+    (``TransformerConfig.rope_tables``, named by the run's ``rope=``).
+    ``factor`` > 1 is YaRN (arXiv:2309.00071): with ``d`` the rotated
+    columns and ``f_i = theta ** (-2 i / d)``, the frequencies whose
+    wavelength the ``original_max_position`` positions hold fewer than
+    ``beta_slow`` times are divided by ``factor``, those they hold more
+    than ``beta_fast`` times stay, a linear ramp between; cos and sin
+    are multiplied by ``attention_factor``."""
+    theta: float = 10_000.0
+    #: >0: the first ``rotary_dim`` columns of a head turn (the halves
+    #: of that slice), the others pass.
+    rotary_dim: int = 0
+    factor: float = 1.0
+    original_max_position: int = 0
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    attention_factor: float = 1.0
+
+    def __post_init__(self):
+        if self.factor != 1.0 and self.original_max_position < 1:
+            raise ValueError("a scaled rotary table says which positions "
+                             "it was first trained for")
+
+    def frequencies(self, width: int):
+        """-> the ``width // 2`` angles a position, float32."""
+        half = width // 2
+        if self.factor == 1.0:
+            return jnp.exp(-jnp.log(self.theta) *
+                           jnp.arange(0, half, dtype=jnp.float32) / half)
+        i = np.arange(half, dtype=np.float64)
+        plain = self.theta ** (-i / half)
+
+        def turns_at(turns):
+            # the index whose wavelength the first positions hold
+            # ``turns`` times
+            return width * math.log(self.original_max_position / (
+                2 * math.pi * turns)) / (2 * math.log(self.theta))
+
+        low = max(math.floor(turns_at(self.beta_fast)), 0)
+        high = min(math.ceil(turns_at(self.beta_slow)), width - 1)
+        ramp = np.clip((i - low) / max(high - low, 1e-3), 0.0, 1.0)
+        return jnp.asarray(plain / self.factor * ramp + plain * (1.0 - ramp),
+                           jnp.float32)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -129,9 +180,9 @@ class TransformerConfig:
     #: The experts' width; 0: ``d_ff`` (a mixed stack's dense layers
     #: and experts differ).
     moe_d_ff: int = 0
-    #: ``"softmax"``, or ``"sigmoid"``: sigmoid scores, the gates the
-    #: chosen scores (renormalised if ``moe_norm_topk``) times
-    #: ``moe_route_scale``.
+    #: ``"softmax"``, or ``"sigmoid"``: sigmoid scores.  Either way the
+    #: gates are the chosen scores (renormalised if ``moe_norm_topk``)
+    #: times ``moe_route_scale``.
     moe_scoring: str = "softmax"
     moe_route_scale: float = 1.0
     #: The share of blocks of tokens that route alike which the expert
@@ -156,10 +207,13 @@ class TransformerConfig:
     #: The delta layers' sizes (``models.gdn.GDNConfig``) for the
     #: ``"gdn"`` kind.
     gdn: Any = None
-    #: Gated attention (the ``"mha"`` kind): ``wq`` is twice as wide,
-    #: query | gate a head, and the heads' output is multiplied by
-    #: ``sigmoid(gate)`` before ``wo``.
-    attn_out_gate: bool = False
+    #: Gated attention (the ``"mha"`` kind).  True: ``wq`` is twice as
+    #: wide, query | gate a head, and the heads' output is multiplied
+    #: elementwise by ``sigmoid(gate)`` before ``wo``.  ``"head"``: one
+    #: scalar a head from a projection of its own (``wg [d_model,
+    #: heads]``, arXiv:2505.06708's head-wise form), read from the
+    #: layer's normed input as the elementwise one is.
+    attn_out_gate: Any = False
     #: >0: RoPE turns the first ``rotary_dim`` columns of a head (the
     #: halves of that slice) and leaves the others as they are.
     rotary_dim: int = 0
@@ -192,6 +246,10 @@ class TransformerConfig:
     #: of (differential attention's ``lambda_init`` reads a layer's
     #: index).
     first_layer_index: int = 0
+    #: The rotary tables an ``mha`` run may name (``rope=<name>``):
+    #: ``((name, RopeTable), ...)`` or a dict.  A run that names none
+    #: turns by ``rope_theta`` over ``rotary_dim``.
+    rope_tables: Any = ()
 
     def __post_init__(self):
         if not self.n_kv_heads:
@@ -202,6 +260,14 @@ class TransformerConfig:
         if self.n_heads % self.n_kv_heads:
             raise ValueError(f"{self.n_heads} query heads over "
                              f"{self.n_kv_heads} K/V heads")
+        tables = self.rope_tables
+        object.__setattr__(self, "rope_tables", tuple(
+            sorted(tables.items()) if isinstance(tables, dict) else tables))
+        if self.attn_out_gate not in (False, True, "head"):
+            raise ValueError(f"attn_out_gate {self.attn_out_gate!r}")
+        if self.attn_out_gate != "head":
+            object.__setattr__(self, "attn_out_gate",
+                               bool(self.attn_out_gate))
         if self.layer_pattern is None:
             object.__setattr__(self, "layer_pattern", ((
                 "mha" if self.mla is None else "mla",
@@ -229,6 +295,9 @@ class TransformerConfig:
         if self.norm == "layernorm" and self.norm_plus_one:
             raise ValueError("a LayerNorm's weight scales as it is")
         _check_slots(self.layer_pattern)
+        for attention, _, _ in runs_of(self.layer_pattern):
+            if run_options(attention)[0] == "mha":
+                mha_run(self, attention)
         if (self.tie_embeddings or self.norm != "rms") and self.mtp_depth:
             raise ValueError("the multi-token-prediction module has its "
                              "own head and RMSNorms")
@@ -280,15 +349,56 @@ def run_options(attention: str) -> Tuple[str, Dict[str, Any]]:
     for word in filter(None, rest.split(",")):
         name, _, value = word.partition("=")
         takes = _OPTIONS.get(kind, {})
-        if name not in takes or name in options or (
-                takes[name] is not None and value not in takes[name]):
+        if name not in takes or name in options or not value or (
+                isinstance(takes[name], tuple) and value not in takes[name]):
             raise ValueError(f"a {kind!r} run does not take {word!r}")
-        options[name] = value if takes[name] is not None else int(value)
+        options[name] = int(value) if takes[name] is int else value
     if "reads" in options and "writes" in options:
         raise ValueError(f"{attention!r} reads the slot it writes")
     if kind == "gmu":
         options["reads"] = "memory"
     return kind, options
+
+
+def mha_run(cfg: TransformerConfig, attention: str):
+    """What an ``mha`` run's layers are -> (query heads, the mask they
+    bring or None for the caller's, the rotary table).  A run whose
+    heads the K/V heads do not divide, or that names a table the
+    configuration lacks, is refused by its name."""
+    options = run_options(attention)[1]
+    heads = options.get("heads", cfg.n_heads)
+    if heads < 1 or heads % cfg.n_kv_heads:
+        raise ValueError(f"{attention!r}: {heads} query heads over "
+                         f"{cfg.n_kv_heads} K/V heads")
+    tables = dict(cfg.rope_tables)
+    if "rope" not in options:
+        table = RopeTable(cfg.rope_theta, cfg.rotary_dim)
+    elif options["rope"] in tables:
+        table = tables[options["rope"]]
+    else:
+        raise ValueError(f"{attention!r}: the configuration's rotary "
+                         f"tables are {sorted(tables)}")
+    mask = SlidingWindow(options["window"]) if "window" in options else None
+    return heads, mask, table
+
+
+def _check_mesh(cfg: TransformerConfig, mesh) -> None:
+    """An ``mha`` run's heads split over ``tp`` by its K/V heads (which
+    divide every run's query heads, so a ``tp`` that takes them takes
+    the run); the ring over ``sp`` is causal and multi-head only, and a
+    run it cannot take is refused by its name where the state is laid
+    out for the mesh."""
+    tp, sp = mesh.shape.get("tp", 1), mesh.shape.get("sp", 1)
+    runs = [attention for attention, _, _ in runs_of(cfg.layer_pattern)
+            if run_options(attention)[0] == "mha"]
+    if runs and cfg.n_kv_heads % tp:
+        raise ValueError(f"{cfg.n_kv_heads} K/V heads on tp={tp}")
+    for attention in runs:
+        heads, mask, _ = mha_run(cfg, attention)
+        if sp > 1 and cfg.context_parallel and (
+                mask is not None or heads != cfg.n_kv_heads):
+            raise ValueError(f"{attention!r} on sp={sp}: ring attention "
+                             f"is causal and multi-head only")
 
 
 def _check_slots(pattern) -> None:
@@ -331,7 +441,7 @@ def stacks_of(layers) -> Tuple[Dict, ...]:
 def init_stack(key: jax.Array, cfg: TransformerConfig, attention: str,
                ffn: str, nl: int) -> Dict:
     """The stacked parameters of ``nl`` layers of one kind."""
-    d, h, dh, f = cfg.d_model, cfg.n_heads, cfg.head_dim, cfg.d_ff
+    d, dh, f = cfg.d_model, cfg.head_dim, cfg.d_ff
     kv = cfg.n_kv_heads
     init = jax.nn.initializers.normal(0.02)
     lkeys = jax.random.split(key, 6)
@@ -348,6 +458,7 @@ def init_stack(key: jax.Array, cfg: TransformerConfig, attention: str,
     if cfg.norm == "layernorm":
         layers["ln1_b"] = jnp.zeros((nl, d), jnp.float32)
         layers["ln2_b"] = jnp.zeros((nl, d), jnp.float32)
+    run = attention
     attention, options = run_options(attention)
     if attention == "mamba":
         from ray_tpu.models.mamba import init_mamba_params
@@ -364,19 +475,22 @@ def init_stack(key: jax.Array, cfg: TransformerConfig, attention: str,
     elif attention == "mla":
         from ray_tpu.models.mla import init_mla_params
         layers["mla"] = init_mla_params(jax.random.fold_in(key, 9), nl, d,
-                                        h, cfg.mla, cfg.dtype)
+                                        cfg.n_heads, cfg.mla, cfg.dtype)
     elif attention == "gdn":
         from ray_tpu.models.gdn import init_gdn_params
         layers["gdn"] = init_gdn_params(jax.random.fold_in(key, 10), nl, d,
                                         cfg.gdn, cfg.dtype)
     else:
+        h = mha_run(cfg, run)[0]
         layers.update({
-            "wq": stacked(lkeys[0], (d, h, (2 if cfg.attn_out_gate else 1)
-                                     * dh)),
+            "wq": stacked(lkeys[0], (d, h, (2 if cfg.attn_out_gate is True
+                                            else 1) * dh)),
             "wk": stacked(lkeys[1], (d, kv, dh)),
             "wv": stacked(lkeys[2], (d, kv, dh)),
             "wo": stacked(lkeys[3], (h, dh, d)),
         })
+        if cfg.attn_out_gate == "head":
+            layers["wg"] = stacked(jax.random.fold_in(key, 14), (d, h))
         if cfg.qk_norm:
             layers["q_norm"] = unit((nl, dh), jnp.float32)
             layers["k_norm"] = unit((nl, dh), jnp.float32)
@@ -469,6 +583,8 @@ def stack_specs(cfg: TransformerConfig, attention: str, ffn: str) -> Dict:
             "wv": P(None, None, "tp", None),
             "wo": P(None, "tp", None, None),
         })
+        if cfg.attn_out_gate == "head":
+            layers["wg"] = P(None, None, "tp")
         if cfg.qk_norm:
             layers["q_norm"] = P(None, None)
             layers["k_norm"] = P(None, None)
@@ -544,20 +660,23 @@ def norm_weight(w, cfg: TransformerConfig):
     return w + 1.0 if cfg.norm_plus_one else w
 
 
-def _rope(x, positions, theta, rotary_dim: int = 0):
+def _rope(x, positions, table: RopeTable):
     # x: [B, S, H, D]; rotate pairs: of all D columns, or of the first
-    # ``rotary_dim`` with the others passed through.
+    # ``table.rotary_dim`` with the others passed through.
+    rotary_dim = table.rotary_dim
     if rotary_dim and rotary_dim < x.shape[-1]:
         return jnp.concatenate(
-            [_rope(x[..., :rotary_dim], positions, theta),
+            [_rope(x[..., :rotary_dim], positions,
+                   dataclasses.replace(table, rotary_dim=0)),
              x[..., rotary_dim:]], axis=-1)
     d = x.shape[-1]
     half = d // 2
-    freqs = jnp.exp(-jnp.log(theta) *
-                    jnp.arange(0, half, dtype=jnp.float32) / half)
+    freqs = table.frequencies(d)
     angles = positions[..., None].astype(jnp.float32) * freqs  # [B, S, half]
     cos = jnp.cos(angles)[:, :, None, :]
     sin = jnp.sin(angles)[:, :, None, :]
+    if table.attention_factor != 1.0:
+        cos, sin = cos * table.attention_factor, sin * table.attention_factor
     x1, x2 = x[..., :half], x[..., half:]
     return jnp.concatenate(
         [x1 * cos - x2 * sin, x1 * sin + x2 * cos], axis=-1).astype(x.dtype)
@@ -566,7 +685,7 @@ def _rope(x, positions, theta, rotary_dim: int = 0):
 def _attention_core(q, k, v, mesh, cfg: TransformerConfig, mask=CAUSAL):
     if (cfg.context_parallel and mesh is not None and
             mesh.shape.get("sp", 1) > 1):
-        if mask != CAUSAL or cfg.n_kv_heads != cfg.n_heads:
+        if mask != CAUSAL or k.shape[2] != q.shape[2]:
             raise ValueError("ring attention is causal and multi-head only")
         fn = jax.shard_map(
             functools.partial(ring_attention, axis_name="sp", causal=True),
@@ -607,11 +726,18 @@ def _moe_block(h, lp, cfg: TransformerConfig, mesh):
     return y, counted
 
 
-def _mha(h, lp, positions, cfg: TransformerConfig, mesh, mask):
+def _mha(h, lp, positions, cfg: TransformerConfig, mesh, mask,
+         run: str = "mha"):
     """Rotary multi-head / grouped attention on the layer's normed
     input -> (what it adds to the residual, what it counted: the mean
-    output gate where it has one)."""
+    output gate where it has one).  ``run``: the run's first word; its
+    heads are ``wq``'s, its window and rotary table ``mha_run``'s."""
     eps = cfg.norm_eps
+    _, window, table = mha_run(cfg, run)
+    if window is not None:
+        if mask != CAUSAL:
+            raise ValueError(f"{run!r} brings its own mask")
+        mask = window
     q = jnp.einsum("bsd,dhk->bshk", h, lp["wq"])
     k = jnp.einsum("bsd,dhk->bshk", h, lp["wk"])
     # The names here and below are cut points a rematerialised layer may
@@ -620,23 +746,31 @@ def _mha(h, lp, positions, cfg: TransformerConfig, mesh, mask):
     # block-diffusion step 38 ms of copies between layouts to spare 16
     # (PERF.md section 6, PR 38).
     v = checkpoint_name(jnp.einsum("bsd,dhk->bshk", h, lp["wv"]), "attn_v")
-    if cfg.attn_out_gate:
+    if cfg.attn_out_gate is True:
         q, gate = q[..., :cfg.head_dim], q[..., cfg.head_dim:]
     if cfg.qk_norm:
         q = _rms_norm(q, norm_weight(lp["q_norm"], cfg), eps)
         k = _rms_norm(k, norm_weight(lp["k_norm"], cfg), eps)
     if cfg.rope == "rotary":
-        q = _rope(q, positions, cfg.rope_theta, cfg.rotary_dim)
-        k = _rope(k, positions, cfg.rope_theta, cfg.rotary_dim)
+        q = _rope(q, positions, table)
+        k = _rope(k, positions, table)
     q = checkpoint_name(q, "attn_q")
     k = checkpoint_name(k, "attn_k")
     o = _attention_core(q, k, v, mesh, cfg, mask)
     counted = {}
     if cfg.attn_out_gate:
+        # (a window run's gates are counted apart from the others')
+        name = "attn_gate_mean" if window is None else "attn_window_gate_mean"
         with jax.named_scope("attn_gate"):
-            gate = jax.nn.sigmoid(gate.astype(jnp.float32))
+            if cfg.attn_out_gate == "head":
+                gate = checkpoint_name(jnp.einsum(
+                    "bsd,dh->bsh", h, lp["wg"],
+                    preferred_element_type=jnp.float32), "attn_head_gate")
+                gate = jax.nn.sigmoid(gate)[..., None]
+            else:
+                gate = jax.nn.sigmoid(gate.astype(jnp.float32))
             o = (o.astype(jnp.float32) * gate).astype(o.dtype)
-            counted["attn_gate_mean"] = jnp.mean(gate)
+            counted[name] = jnp.mean(gate)
     return jnp.einsum("bshk,hkd->bsd", o, lp["wo"]), counted
 
 
@@ -691,10 +825,15 @@ def _apply_layer(x, lp, positions, cfg: TransformerConfig, mesh=None,
     ``shared``: the slots written so far."""
     # The named scopes here and in loss_fn / train_step are metadata
     # only: stable names for a device trace to group time by.
-    attention, ffn = kind or next(runs_of(cfg.layer_pattern))[:2]
-    attention, options = run_options(attention)
+    run, ffn = kind or next(runs_of(cfg.layer_pattern))[:2]
+    attention, options = run_options(run)
     counted, handed_on = {}, None
-    with jax.named_scope("attention"):
+    # (a run under a window says so to the device trace: the step's
+    # manifest tells its layers' time from the other ``mha`` runs')
+    windowed = jax.named_scope("mha_window") \
+        if attention == "mha" and "window" in options \
+        else contextlib.nullcontext()
+    with jax.named_scope("attention"), windowed:
         h = model_norm(x, lp, "ln1", cfg)
         if attention in SINGLE_DEVICE_KINDS:
             y, counted, handed_on = _single_device_mixer(
@@ -710,7 +849,7 @@ def _apply_layer(x, lp, positions, cfg: TransformerConfig, mesh=None,
             y, counted = gdn_attention(h, lp["gdn"], cfg, mesh)
             x = x + y
         else:
-            y, counted = _mha(h, lp, positions, cfg, mesh, mask)
+            y, counted = _mha(h, lp, positions, cfg, mesh, mask, run)
             x = x + y
         x = checkpoint_name(x, "mid_residual")
     with jax.named_scope("ffn"):
@@ -949,6 +1088,7 @@ def make_train_state(rng, cfg: TransformerConfig, mesh=None,
         state["moe_bias"] = jnp.zeros((cfg.moe_layers, cfg.moe_experts),
                                       jnp.float32)
     if mesh is not None:
+        _check_mesh(cfg, mesh)
         specs = specs_override or param_specs(cfg)
         state_specs = {
             "params": specs,

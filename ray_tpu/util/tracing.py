@@ -295,7 +295,13 @@ STEP_SCOPES = (
     "gdn_proj", "gdn_conv", "gdn_core", "gdn_out", "attn_gate",
     "block_diffusion_loss", "flash_attention_bwd", "gated_delta_bwd",
     "ssm_proj", "ssm_conv", "ssm_scan", "ssm_out", "gmu", "diff_attn",
-    "selective_scan_bwd")
+    "selective_scan_bwd", "mha_window")
+#: Of those, the ones that say which RUN of layers an instruction
+#: belongs to, not which part of a layer: they own no instruction (the
+#: part's scope does), and ``device_time_by_scope(..., within=)`` keeps
+#: the rows that lie under one.  ``mha_window``: an ``mha`` run under a
+#: window, beside the runs that see everything before.
+RUN_SCOPES = ("mha_window",)
 #: The names the train path's ``pallas_call``s are given: a device
 #: trace's events of these kernels start with them.
 KERNEL_EVENTS = ("flash_attention_fwd", "flash_attention_bwd",
@@ -305,7 +311,7 @@ KERNEL_EVENTS = ("flash_attention_fwd", "flash_attention_bwd",
 #: the backward pass, and the forward that ``jax.checkpoint`` runs again
 #: inside the backward pass.
 PHASES = ("fwd", "bwd", "recompute")
-_SCOPES = frozenset(STEP_SCOPES)
+_SCOPES = frozenset(STEP_SCOPES) - frozenset(RUN_SCOPES)
 _ENCLOSING_OPCODES = ("while", "conditional", "call")
 _MEMORY_KEYS = ("temp_size_in_bytes", "argument_size_in_bytes",
                 "output_size_in_bytes", "peak_memory_in_bytes")
@@ -318,10 +324,11 @@ _OP_NAME = re.compile(r'op_name="([^"]*)"')
 _TRANSFORMED = re.compile(r"^(?:jvp|transpose)\((.*)\)$")
 
 
-def _scope_and_phase(path: str) -> Tuple[Optional[str], str]:
-    """``op_name`` -> (innermost component that is in ``STEP_SCOPES``,
-    phase).  A component arrives bare or inside its transforms:
-    ``attention``, ``jvp(ffn)``, ``transpose(jvp(head_loss))``."""
+def _scope_and_phase(path: str) -> Tuple[Optional[str], str, Optional[str]]:
+    """``op_name`` -> (innermost component that is in ``STEP_SCOPES``
+    and no run's, phase, the ``RUN_SCOPES`` component or None).  A
+    component arrives bare or inside its transforms: ``attention``,
+    ``jvp(ffn)``, ``transpose(jvp(head_loss))``."""
     parts = path.split("/")
     if "rematted_computation" in parts:
         phase = "recompute"
@@ -329,27 +336,32 @@ def _scope_and_phase(path: str) -> Tuple[Optional[str], str]:
         phase = "bwd"
     else:
         phase = "fwd"
+    scope = run = None
     for part in reversed(parts):
         inner = _TRANSFORMED.match(part)
         while inner is not None:
             part = inner.group(1)
             inner = _TRANSFORMED.match(part)
-        if part in _SCOPES:
-            return part, phase
-    return None, phase
+        if scope is None and part in _SCOPES:
+            scope = part
+        if run is None and part in RUN_SCOPES:
+            run = part
+    return scope, phase, run
 
 
 def manifest_of_text(text: str) -> dict:
     """The text of a compiled HLO module -> ``{"scopes": {instruction:
-    (scope or None, phase)}, "enclosing": {instruction, ...}}``: the one
-    place where the rules are applied.  A fusion has the ``op_name`` XLA
+    (scope or None, phase)}, "enclosing": {instruction, ...}, "runs":
+    {instruction: its ``RUN_SCOPES`` scope}}`` (``runs``: of the
+    instructions under one alone): the one place where the rules are
+    applied.  A fusion has the ``op_name`` XLA
     gave the fusion instruction; a Pallas kernel's custom call is its
     ``KERNEL_EVENTS`` name whatever scope encloses it; the expert
     layer's grouped product loses its scope in XLA (a ``ragged-dot``
     without metadata, on the TPU the custom calls ``ragged-dot-none.<n>``
     and ``ragged-dot-metadata.<n>`` under an ``op_name`` of their own, so
     also its pass: it is counted as ``fwd``) and is ``moe_experts``."""
-    scopes, enclosing = {}, set()
+    scopes, enclosing, runs = {}, set(), {}
     for line in text.splitlines():
         found = _INSTRUCTION.match(line)
         if found is None:
@@ -360,14 +372,17 @@ def manifest_of_text(text: str) -> dict:
         if opcode in _ENCLOSING_OPCODES:
             enclosing.add(name)
         path = _OP_NAME.search(line, found.end())
-        scope, phase = _scope_and_phase(path.group(1) if path else "")
+        scope, phase, run = _scope_and_phase(path.group(1) if path else "")
         if opcode == "custom-call":
             scope = next((k for k in KERNEL_EVENTS if name.startswith(k)),
                          scope)
         if scope is None and name.startswith("ragged-dot"):
             scope = "moe_experts"
-        scopes.setdefault(name, (scope, phase))
-    return {"scopes": scopes, "enclosing": enclosing}
+        if name not in scopes:
+            scopes[name] = (scope, phase)
+            if run is not None:
+                runs[name] = run
+    return {"scopes": scopes, "enclosing": enclosing, "runs": runs}
 
 
 class _Program:
@@ -436,7 +451,8 @@ def programs() -> Dict[str, _Program]:
 
 
 def device_time_by_scope(rows: Iterable[Tuple[str, float]],
-                         program: str = "train_step") -> dict:
+                         program: str = "train_step",
+                         within: Optional[str] = None) -> dict:
     """Device seconds by the program's own layers.  ``rows`` are
     ``(instruction name, seconds)`` of a device trace (any xplane's
     ``XLA Ops`` events, the name as the HLO has it, with or without
@@ -444,10 +460,14 @@ def device_time_by_scope(rows: Iterable[Tuple[str, float]],
     ..., "unknown": s}``: ``None`` holds what no scope of ``STEP_SCOPES``
     owns, ``"unknown"`` the rows of instructions the program does not
     have (another program's events in the window).  Instructions that
-    only enclose others are left out, so time is counted once.  Raises
-    ``KeyError`` where no such program is registered."""
+    only enclose others are left out, so time is counted once.
+    ``within``: one of ``RUN_SCOPES`` -- the program's instructions
+    under that run's scope alone (kernels' calls too: a call keeps the
+    path it was made under).  Raises ``KeyError`` where no such program
+    is registered."""
     entry = programs()[program]
     scopes, enclosing = entry["scopes"], entry["enclosing"]
+    runs = entry["runs"] if within is not None else {}
     out = {"unknown": 0.0}
     for name, seconds in rows:
         name = name.lstrip("%")
@@ -455,6 +475,8 @@ def device_time_by_scope(rows: Iterable[Tuple[str, float]],
             continue
         if name not in scopes:
             out["unknown"] += seconds
+            continue
+        if within is not None and runs.get(name) != within:
             continue
         scope, phase = scopes[name]
         out.setdefault(scope, dict.fromkeys(PHASES, 0.0))[phase] += seconds

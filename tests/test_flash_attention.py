@@ -898,6 +898,51 @@ def test_window_forward_and_backward_match_full_attention(window, shape):
     _assert_grads_match(jnp.float32, mask, shape)
 
 
+@pytest.mark.parametrize("heads,window", [(9, 160), (6, 128), (9, 640),
+                                          (6, None)],
+                         ids=["9-band", "6-band", "9-row", "6-causal"])
+def test_groups_of_nine_and_six_at_128_columns_match_full_attention(
+        heads, window):
+    """Both kernels with 9 and 6 query heads a K/V head (no power of
+    two: the backward's grid runs a group's heads in turn and sums
+    ``dk``/``dv`` over them) at 128 | 128 columns over five 128-tiles
+    each way, under windows above a tile, at one, as long as the row,
+    and causally, against ``full_attention`` and its ``jax.grad``."""
+    mask = CAUSAL if window is None else SlidingWindow(window)
+    shape = dict(B=1, L=640, H=3 * heads, D=128, kv_heads=3)
+    q, k, v, _ = _qkvd(jnp.float32, **shape)
+    got = flash_attention(q, k, v, mask=mask, interpret=True,
+                          block_q=128, block_k=128)
+    assert _max_err(got, full_attention(q, k, v, mask=mask)) \
+        <= _TOL[jnp.float32]
+    if window is not None and window < 640:
+        assert _max_err(got, full_attention(q, k, v)) > 1e-3
+    # a query head reads its own K/V head: head 2 * heads is the third's
+    other = full_attention(q[:, :, 2 * heads:2 * heads + 1], k[:, :, :1],
+                           v[:, :, :1], mask=mask)
+    assert _max_err(got[:, :, 2 * heads:2 * heads + 1], other) > 1e-3
+    _assert_grads_match(jnp.float32, mask, shape)
+
+
+def test_window_and_full_gradients_compile_for_the_chip_at_the_cell_width(
+        one_v5e_chip):
+    """Both kernels at the two attention shapes of the cell whose layers
+    are of two kinds (1 row x 16,384 positions, 128 | 128 columns over 8
+    K/V heads, bfloat16): 72 query heads under the window of 512 and 48
+    under the causal mask.  K and V whole are 16.8 MB double-buffered in
+    the forward, as at 256-wide heads over 8,192 positions."""
+    def spec(heads):
+        return jax.ShapeDtypeStruct((1, 16384, heads, 128), jnp.bfloat16,
+                                    sharding=one_v5e_chip)
+
+    for heads, mask in ((72, SlidingWindow(512)), (48, CAUSAL)):
+        text = jax.jit(jax.grad(lambda q, k, v: jnp.sum(flash_attention(
+            q, k, v, mask=mask).astype(jnp.float32)), (0, 1, 2))).lower(
+                spec(heads), spec(8), spec(8)).compile().as_text()
+        assert _kernel_calls(text) == (1, 1)
+        assert text.count("tpu_custom_call") == 2
+
+
 def test_differential_attention_gradient_compiles_for_the_chip_at_the_cell_width(
         one_v5e_chip):
     """Both kernels at the decoder-hybrid-decoder cell's attention shape

@@ -274,7 +274,7 @@ def test_the_pattern_says_what_each_run_reads_and_writes():
      "no earlier run writes"),
     ((("mamba:writes=kv", "dense", 1),), "does not take"),
     ((("diff:window", "dense", 1),), "invalid literal|does not take"),
-    ((("mha:window=8", "dense", 1),), "does not take"),
+    ((("mha:writes=kv", "dense", 1),), "does not take"),
     ((("diff:reads=kv,writes=kv", "dense", 1),), "reads the slot it writes"),
     ((((("mamba:writes=memory", "dense", 1), ("gmu", "dense", 1)), 2),),
      "in a period"),

@@ -404,7 +404,9 @@ def test_every_scope_literal_is_declared_and_every_declared_scope_is_used():
     assert kernels == {*tracing.KERNEL_EVENTS, *KERNELS_UNDER_THEIR_SCOPE}
     assert not set(tracing.KERNEL_EVENTS) & set(KERNELS_UNDER_THEIR_SCOPE)
     assert set(KERNELS_UNDER_THEIR_SCOPE.values()) <= set(tracing.STEP_SCOPES)
-    assert len(set(tracing.STEP_SCOPES)) == len(tracing.STEP_SCOPES) == 30
+    assert len(set(tracing.STEP_SCOPES)) == len(tracing.STEP_SCOPES) == 31
+    assert set(tracing.RUN_SCOPES) == {"mha_window"} < set(
+        tracing.STEP_SCOPES)
     assert tracing.KERNEL_EVENTS == (
         "flash_attention_fwd", "flash_attention_bwd", "gated_delta_fwd",
         "gated_delta_bwd", "selective_scan_fwd", "selective_scan_bwd")
@@ -415,9 +417,13 @@ def test_every_declared_scope_feeds_one_metric():
     benchmark's group metrics -- ``step_scopes.GROUPS``, or the list in
     the file of a metric that came after it (``ssm_layers_ms``);
     ``optimizer`` is left to the rest (``train_step_device_ms`` less the
-    groups), and ``diff_attn`` to ``step_attributed_pct`` alone."""
+    groups), and ``diff_attn`` to ``step_attributed_pct`` alone.  A
+    run's scope owns no instruction and is no group's: it is what one
+    reader keeps rows by (``window_layers_ms``)."""
     from benchmarks import run as bench_run
     from benchmarks.harness import step_scopes
+    assert (bench_run._reader("window_layers_ms").__globals__["RUN"],) == \
+        tracing.RUN_SCOPES
     ssm = bench_run._reader("ssm_layers_ms").__globals__["SCOPES"]
     assert {"ssm_proj", "ssm_conv", "ssm_scan", "ssm_out", "gmu",
             "selective_scan_fwd", "selective_scan_bwd"} == set(ssm)
@@ -425,15 +431,18 @@ def test_every_declared_scope_feeds_one_metric():
     grouped += list(ssm)
     assert len(grouped) == len(set(grouped))
     assert set(grouped) | {"optimizer", "diff_attn"} == \
-        set(tracing.STEP_SCOPES) | set(tracing.KERNEL_EVENTS)
+        (set(tracing.STEP_SCOPES) - set(tracing.RUN_SCOPES)) \
+        | set(tracing.KERNEL_EVENTS)
     for metric in step_scopes.GROUPS:
         assert os.path.exists(os.path.join(
             ROOT, "benchmarks", "layer_metrics", metric + ".py")), metric
 
 
 def _tiny_step(kind):
-    """-> (step, state, batch) of one of the five tiny configurations the
+    """-> (step, state, batch) of one of the six tiny configurations the
     tests of the models build, as the benchmark's drivers build them."""
+    import importlib
+
     import jax
     import jax.numpy as jnp
 
@@ -461,8 +470,9 @@ def _tiny_step(kind):
         cfg = tiny._cfg()
         over = functools.partial(mtp.loss_fn, cfg=cfg, coeff=0.3)
         batch = {"tokens": jnp.asarray(tiny._batches(3)[0])}
-    elif kind == "sambay":
-        import test_phi4_flash as tiny
+    elif kind in ("sambay", "windowed"):
+        tiny = importlib.import_module(
+            "test_phi4_flash" if kind == "sambay" else "test_laguna")
         cfg = tiny._cfg()
         batch = {"tokens": jnp.asarray(tiny._batches(3)[0])}
     else:
@@ -497,6 +507,8 @@ MANIFESTS = {
     "sambay": dict(attention=ALL, ffn=ALL, ssm_proj=ALL, ssm_conv=ALL,
                    ssm_scan=ALL, ssm_out=ALL, gmu=ALL, diff_attn=ALL,
                    head_loss=ONCE, optimizer=OUTSIDE),
+    "windowed": dict(MOE, attention=ALL, ffn=ALL, attn_gate=ALL,
+                     moe_shared=ALL, head_loss=ONCE, optimizer=OUTSIDE),
 }
 
 
@@ -517,6 +529,17 @@ def test_the_manifest_has_each_scope_of_the_configuration(kind):
     assert {k: v for k, v in phases.items() if k is not None} == \
         MANIFESTS[kind]
     assert phases[None] == ONCE
+    # the runs under a window, and they alone, say so: their attention
+    # halves (the gate's scope among them) in all three passes, nothing
+    # of an FFN, and no scope of their own
+    under = {}
+    for name, run in entry["runs"].items():
+        assert run == "mha_window"
+        scope, phase = entry["scopes"][name]
+        under.setdefault(scope, set()).add(phase)
+    assert under == ({"attention": ALL, "attn_gate": ALL}
+                     if kind == "windowed" else {})
+    assert "mha_window" not in phases
     # (XLA:CPU outlines a small ``while`` as a ``call``: the hybrid
     # step's scan over a period's counters since PR 41)
     assert entry["enclosing"] and all(
@@ -584,7 +607,10 @@ def test_the_registry_entrys_memory_carries_the_plan(kind, monkeypatch):
         "hybrid": ["gdn+moe", "mha+moe"],
         "sambay": ["diff:reads=kv+dense", "diff:window=8+dense",
                    "diff:writes=kv+dense", "gmu+dense", "mamba+dense",
-                   "mamba:writes=memory+dense"]}[kind]
+                   "mamba:writes=memory+dense"],
+        "windowed": ["mha:heads=6,rope=global+dense",
+                     "mha:heads=6,rope=global+moe",
+                     "mha:heads=9,window=8,rope=local+moe"]}[kind]
     assert 0 < plan["plan_seconds"] < 5 and plan["trace_seconds"] > 0
     exposed = get_metrics_registry().render_prometheus().splitlines()
     for name in ("kept_bytes", "budget_bytes", "plan_seconds"):
@@ -745,6 +771,85 @@ def test_a_kernel_that_is_not_listed_keeps_the_scope_it_is_called_in(
             f'"tpu_custom_call", metadata={{op_name="{op_name}" '
             f'stack_frame_id=72}}')
     assert tracing.manifest_of_text(line)["scopes"] == {name: want}
+
+
+_WINDOW_RUN = "jit(train_step)/{}/while/body/closed_call/while/body/" \
+    "closed_call/{}attention/mha_window/{}"
+# A window run's instructions as the step's text names them (compiled
+# for a described v5e at PR 42), and one of a run that sees everything.
+RUNS_TEXT = "\n".join(
+    f'  %{name} = f32[2]{{0}} {opcode}(%a), metadata={{op_name="{path}"}}'
+    for name, opcode, path in (
+        ("fusion.1", "fusion",
+         _WINDOW_RUN.format("jvp()", "", "bsd,dhk->bshk/dot_general")),
+        ("flash_attention_fwd.24", "custom-call", _WINDOW_RUN.format(
+            "jvp()", "", "jit(flash_attention)/flash_attention_fwd/"
+            "pallas_call")),
+        ("flash_attention_bwd.20", "custom-call", _WINDOW_RUN.format(
+            "transpose(jvp())", "checkpoint/", "jit(flash_attention)/"
+            "flash_attention_bwd/pallas_call")),
+        ("fusion.2", "fusion", _WINDOW_RUN.format(
+            "transpose(jvp())", "checkpoint/rematted_computation/",
+            "attn_gate/logistic")),
+        ("fusion.3", "fusion",
+         "jit(train_step)/jvp()/while/body/closed_call/attention/attn_gate/"
+         "logistic"),
+        ("flash_attention_fwd.22", "custom-call",
+         "jit(train_step)/jvp()/while/body/closed_call/attention/"
+         "jit(flash_attention)/flash_attention_fwd/pallas_call"),
+        ("fusion.4", "fusion", "jit(train_step)/transpose(jvp(mha_window))/"
+         "mul")))
+
+
+def test_a_runs_scope_owns_nothing_and_keeps_rows_apart():
+    """``mha_window`` is never an instruction's scope -- the part's is
+    (``attention``, ``attn_gate``, the kernels' events) -- and the
+    manifest's ``runs`` says which instructions lie under it, the
+    kernels' calls among them; ``device_time_by_scope(within=)`` sums
+    those alone."""
+    tracing.clear()
+    manifest = tracing.manifest_of_text(RUNS_TEXT)
+    assert manifest["scopes"] == {
+        "fusion.1": ("attention", "fwd"),
+        "flash_attention_fwd.24": ("flash_attention_fwd", "fwd"),
+        "flash_attention_bwd.20": ("flash_attention_bwd", "bwd"),
+        "fusion.2": ("attn_gate", "recompute"),
+        "fusion.3": ("attn_gate", "fwd"),
+        "flash_attention_fwd.22": ("flash_attention_fwd", "fwd"),
+        "fusion.4": (None, "bwd")}
+    assert manifest["runs"] == dict.fromkeys(
+        ("fusion.1", "flash_attention_fwd.24", "flash_attention_bwd.20",
+         "fusion.2", "fusion.4"), "mha_window")
+    assert tracing.manifest_of_text(STEP_TEXT)["runs"] == {}
+    tracing.register_program("train_step", _Executable(RUNS_TEXT))
+    rows = [("fusion.1", 1.0), ("flash_attention_fwd.24", 2.0),
+            ("%flash_attention_bwd.20", 4.0), ("fusion.2", 8.0),
+            ("fusion.3", 16.0), ("flash_attention_fwd.22", 32.0),
+            ("fusion.4", 64.0), ("fusion.999", 128.0)]
+    everything = tracing.device_time_by_scope(rows)
+    assert everything["flash_attention_fwd"]["fwd"] == 34.0
+    assert everything["attn_gate"] == {"fwd": 16.0, "bwd": 0.0,
+                                       "recompute": 8.0}
+    window = tracing.device_time_by_scope(rows, within="mha_window")
+    assert window == {
+        "unknown": 128.0,
+        "attention": {"fwd": 1.0, "bwd": 0.0, "recompute": 0.0},
+        "flash_attention_fwd": {"fwd": 2.0, "bwd": 0.0, "recompute": 0.0},
+        "flash_attention_bwd": {"fwd": 0.0, "bwd": 4.0, "recompute": 0.0},
+        "attn_gate": {"fwd": 0.0, "bwd": 0.0, "recompute": 8.0},
+        None: {"fwd": 0.0, "bwd": 64.0, "recompute": 0.0}}
+    # the benchmark's reader: milliseconds a step, both steps' rows
+    trace = {"device_ops": {"/device:TPU:0": [
+        [name.lstrip("%"), 0.0, seconds * 1e6] for name, seconds in rows]},
+        "host_spans": []}
+    read = _reader("window_layers_ms")
+    assert read({"trace": trace, "facts": {"steps": 2}}) == pytest.approx(
+        (1 + 2 + 4 + 8 + 64) / 2)
+    # a step without such a run, and no step at all: nothing
+    tracing.register_program("train_step", _Executable(STEP_TEXT))
+    assert read({"trace": STEP_TRACE, "facts": {"steps": 2}}) is None
+    tracing.clear()
+    assert read({"trace": STEP_TRACE, "facts": {"steps": 2}}) is None
 
 
 def test_the_rules_of_the_manifest_on_a_hand_made_text(hand_made_step):

@@ -410,6 +410,10 @@ def step_jaxpr_hash(cell: str, root: str = ROOT) -> str:
     if "mamba" in kwargs:
         from ray_tpu.models.mamba import MambaConfig
         kwargs["mamba"] = MambaConfig(**kwargs["mamba"])
+    if "rope_tables" in kwargs:
+        from ray_tpu.models.transformer import RopeTable
+        kwargs["rope_tables"] = {name: RopeTable(**table) for name, table
+                                 in kwargs["rope_tables"].items()}
     cfg = TransformerConfig(dtype=jnp.dtype(config["dtype"]), **kwargs)
     if wl["driver"] == "trainer_blockdiff_steps":
         from ray_tpu.models import block_diffusion
@@ -464,6 +468,9 @@ PARENT_STEPS = {
         "e839d08e1a72a3af1560c39f74eb027a1d48694f6df0cac4797cb272512a9636",
     "train-phi4-mini-flash.pack16k":
         "ef676578e0020595e3ae2f5d9b63594d720dd9670c02ef45bb2f7c366b65b715",
+    # PR 42's own: the cell whose ``mha`` runs differ
+    "train-laguna-s.pack16k":
+        "5fe7ef972f83374cf835d6bf618feac02657421feaf0d07daf7e77a230f9cdc2",
 }
 
 

@@ -74,6 +74,14 @@ KINDS = {
                          rope="none"),
                     {"mid_residual", "diff_q", "diff_kv", "ffn_gate",
                      "ffn_up"}),
+    "mha-window": (("mha:heads=6,window=8,rope=near", "dense"),
+                   dict(layer_pattern=(("mha", "dense", 1),
+                                       ("mha:heads=6,window=8,rope=near",
+                                        "dense", 2)),
+                        n_kv_heads=2, head_dim=8, attn_out_gate="head",
+                        rope_tables={"near": transformer.RopeTable(100.0)}),
+                   {"mid_residual", "attn_q", "attn_k", "attn_v",
+                    "attn_head_gate", "ffn_gate", "ffn_up"}),
     "diff-cross": (("diff:reads=kv", "dense"),
                    dict(layer_pattern=(("diff:writes=kv", "dense", 1),
                                        ("diff:reads=kv", "dense", 2)),
@@ -490,13 +498,17 @@ def test_every_named_cut_point_is_a_candidate_some_survey_sees():
     assert not seen & set(RESIDUAL_NAMES)
 
 
-STEPS = ("dense", "block_diffusion", "latent", "hybrid", "sambay")
+STEPS = ("dense", "block_diffusion", "latent", "hybrid", "sambay",
+         "windowed")
 RUNS = {"dense": ["mha+dense"], "block_diffusion": ["mha+moe"],
         "latent": ["mla+dense", "mla+moe", "mla+moe"],
         "hybrid": ["gdn+moe", "mha+moe"],
         "sambay": ["diff:reads=kv+dense", "diff:window=8+dense",
                    "diff:writes=kv+dense", "gmu+dense", "mamba+dense",
-                   "mamba:writes=memory+dense"]}
+                   "mamba:writes=memory+dense"],
+        "windowed": ["mha:heads=6,rope=global+dense",
+                     "mha:heads=6,rope=global+moe",
+                     "mha:heads=9,window=8,rope=local+moe"]}
 
 
 def _counting(monkeypatch, module, name, counts):
@@ -544,6 +556,53 @@ def test_planning_a_step_calls_no_function_more_often(kind, monkeypatch):
     step.lower(state, batch)
     assert counts == {"_apply_layer": runs, "make_jaxpr": 1}
     assert {**step._kept, "trace_seconds": 0} == {**first, "trace_seconds": 0}
+
+
+def test_two_mha_runs_of_unequal_shapes_are_two_surveys(monkeypatch):
+    """The step whose ``mha`` runs differ (9 heads under a window, 6
+    over everything before, a dense and an expert FFN): a survey a run,
+    each with its own bytes under the names the runs share, one order
+    over all of them, and a budget that holds a part of it refuses the
+    rest run by run."""
+    from test_program_spans import _tiny_step
+    seen, real = [], remat.survey
+
+    def kept(*args, **kwargs):
+        seen.append(real(*args, **kwargs))
+        return seen[-1]
+
+    monkeypatch.setattr(remat, "survey", kept)
+    _device(monkeypatch, ROOM)
+    step, state, batch = _tiny_step("windowed")
+    step.lower(state, batch)
+    by_kind = {"+".join(s.kind): s for s in seen}
+    assert sorted(by_kind) == RUNS["windowed"]
+    dense, full, window = (by_kind[k] for k in RUNS["windowed"])
+    assert (dense.layers, full.layers, window.layers) == (1, 1, 3)
+    # q as it enters the kernel: 2 rows x 32 positions x heads x 16, f32
+    assert window.names["attn_q"] == 2 * 32 * 9 * 16 * 4
+    assert full.names["attn_q"] == dense.names["attn_q"] == 2 * 32 * 6 * 16 * 4
+    assert window.names["attn_head_gate"] == 2 * 32 * 9 * 4
+    assert window.names["attn_k"] == full.names["attn_k"]
+    assert "ffn_gate" in dense.names and "moe_scores" not in dense.names
+    plan = step._kept
+    assert [r["kind"] for r in plan["runs"]] == [
+        "+".join(s.kind) for s in seen]
+    assert all("attn_q" in r["names"] and r["refused"] == []
+               for r in plan["runs"])
+    # half the room: some of one order is refused, by the run it is of
+    used = plan["bytes_limit"] - plan["budget_bytes"] / remat.SAFETY
+    _device(monkeypatch, (int(used + plan["kept_bytes"] / 2 / remat.SAFETY),
+                          0))
+    step, state, batch = _tiny_step("windowed")
+    step.lower(state, batch)
+    tight = step._kept
+    assert 0 < tight["kept_bytes"] <= tight["budget_bytes"] \
+        < plan["kept_bytes"]
+    assert any(r["refused"] for r in tight["runs"])
+    for r, whole in zip(tight["runs"], plan["runs"]):
+        assert {n for n, _ in r["refused"]} | set(r["names"]) == \
+            set(whole["names"])
 
 
 @pytest.mark.parametrize("kind", STEPS)
