@@ -301,7 +301,7 @@ def _train_func(config: dict) -> dict:
     import jax.numpy as jnp
 
     from ray_tpu import train
-    from ray_tpu.models.transformer import (TransformerConfig,
+    from ray_tpu.models.transformer import (TransformerConfig, is_period,
                                             make_train_state,
                                             make_train_step)
 
@@ -368,6 +368,15 @@ def _train_func(config: dict) -> dict:
             "flash_bwd_calls_in_step": len(re.findall(
                 r'%flash_attention_bwd[.\d]* = [^\n]*"tpu_custom_call"',
                 text)),
+            # the expert layers' grouped products: three forward and
+            # eight backward (remat's second forward of them is dead
+            # code) in each of a scanned run's two bodies, the first
+            # chunk's and the loop's over further chunks; as many scans
+            # hold an expert layer
+            "ragged_dot_calls_in_step": mosaic_calls("ragged-dot-none")[0],
+            "expert_scans": cfg.mtp_depth + sum(
+                ffn == "moe" for entry in cfg.layer_pattern
+                for _, ffn, _ in (entry[0] if is_period(entry) else [entry])),
             "param_platforms": sorted({d.platform for d in leaf.devices()}),
             "n_params": sum(int(np.prod(x.shape))
                             for x in jax.tree.leaves(state["params"]))}
@@ -495,6 +504,18 @@ def leg_trainer(platform: str = "tpu", model: dict = None, batch: int = None,
             "flash_tol": flash_tol}
 
 
+def _ragged_dot_calls_a_layer(result: dict, on_chip: bool) -> int:
+    """The grouped products an expert layer's chunk makes in the compiled
+    step, forward and backward: 11 (``models/moe.py``: the backward takes
+    the gates' gradient from ``dy @ w2^T``, not from the output made
+    again); 0 off the chip, where the product is no custom call."""
+    calls, scans = result["ragged_dot_calls_in_step"], result["expert_scans"]
+    check(calls == 2 * 11 * scans * int(on_chip),
+          f"11 ragged-dot calls a body in the {scans} scans that hold an "
+          f"expert layer, two bodies each: {calls} in the compiled step")
+    return calls // (2 * scans)
+
+
 #: The mixed stack at a size the chip takes in seconds: latent
 #: attention at the published head sizes (128 + 64 score columns, 128
 #: value columns), one dense-FFN layer, two expert layers (8 of 32
@@ -596,6 +617,7 @@ def leg_latent_trainer(platform: str = "tpu", model: dict = None,
           f"{result['mosaic_bwd_in_step']} (expected {on_chip})")
     check(counters["moe_dropped_choices"] == 0.0,
           f"no choice dropped: {counters}")
+    ragged_dot_calls = _ragged_dot_calls_a_layer(result, on_chip)
     check(abs(counters["moe_bias_abs_max"]
               - steps * model["moe_bias_rate"]) < 1e-6,
           f"the correction bias moves by its rate a step: {counters}")
@@ -605,6 +627,7 @@ def leg_latent_trainer(platform: str = "tpu", model: dict = None,
             "counters": {k: round(v, 5) for k, v in counters.items()},
             "flash_fwd_calls_in_step": result["flash_fwd_calls_in_step"],
             "flash_bwd_in_step": result["mosaic_bwd_in_step"],
+            "ragged_dot_calls_a_layer": ragged_dot_calls,
             "latent_flash_vs_full_max_abs_err": fwd_err,
             "latent_flash_bwd_max_rel_err": bwd_err,
             "flash_tol": flash_tol}
@@ -766,6 +789,7 @@ def leg_hybrid_trainer(platform: str = "tpu", model: dict = None,
           f"{result['mosaic_bwd_in_step']}")
     check(counters["moe_dropped_choices"] == 0.0,
           f"no choice dropped: {counters}")
+    ragged_dot_calls = _ragged_dot_calls_a_layer(result, on_chip)
     for name in ("attn_gate_mean", "moe_shared_gate_mean", "gdn_beta_mean"):
         check(0.3 < counters[name] < 0.7, f"{name} near a half: {counters}")
     check(0.0 < counters["gdn_decay_mean"] < 1.0
@@ -778,6 +802,7 @@ def leg_hybrid_trainer(platform: str = "tpu", model: dict = None,
             "gated_delta_calls_in_step": result["gated_delta_calls_in_step"],
             "causal_conv_calls_in_step": result["causal_conv_calls_in_step"],
             "flash_fwd_calls_in_step": result["flash_fwd_calls_in_step"],
+            "ragged_dot_calls_a_layer": ragged_dot_calls,
             "causal_conv_max_rel_err": conv_err,
             "gated_delta_vs_scan_max_rel_err": fwd_err,
             "gated_delta_bwd_max_rel_err": bwd_err, "rule_tol": rule_tol}

@@ -27,18 +27,31 @@ Nothing is dropped whatever the imbalance: the chunks cover all N
 choices, so memory is bounded by the chunk and work follows the number
 of chunks the held choices fill.  The rows return to their tokens by
 one float32 scatter-add a chunk and direction, and that add is the only
-place where a chunk's rows are widened: the backward (written by hand,
-``_held_experts_bwd``) gathers the tokens' cotangent in the tokens'
-dtype, and makes the rows' cotangent and the gates' gradient in one
-pass over the rows.  The other way round -- every token gathering the
-rows of its ``top_k`` slots through the inverse of the sort and summing
-them -- is no faster on the chip: a gathered row of 2,048 bfloat16 costs
-35 ns and a scattered float32 one 83 ns, and the slots are ``top_k``
-a token where a first chunk holds ``m`` (PERF.md section 6, PR 34).  A
-chunk is sized for tokens that
-route alike (``chunk_rows``): ``m`` choices of every token, with ``m``
-the most of a token's ``top_k`` experts that fall among the ``count``
-held in all but one case in a hundred.  That is the share's worst
+place where a chunk's rows are widened.  The backward is written by hand
+(``_held_experts_bwd``); a chunk of it, with ``dys = dy[tok]`` gathered
+in the tokens' dtype and left in it:
+    h        = silu(ragged_dot(xs, w1)) * ragged_dot(xs, w3)    again
+    u        = ragged_dot(dys, w2^T)    [rows, F]: ``out`` is NOT made
+    d gate   = sum_F float32(h) * float32(u)
+    dh, gh   = gate * u, gate * h       one pass over [rows, F]
+    d w2     = ragged_dot^T(gh, dys)
+    d xs, d w1, d w3 from dh            four products
+Eleven grouped products a layer (three forward, eight backward).  The
+gate is a scalar a row, so it multiplies AFTER ``dys @ w2^T`` and on the
+F-wide operand of ``w2``'s gradient: the one product serves the hidden
+rows' cotangent and the gates' gradient (``h . u`` is ``out . dys``),
+and nothing is made, scaled or masked at [rows, D] but ``d xs`` on its
+way into the scatter-add (PERF.md section 6, PR 43).  The other way
+round for the rows' return -- every token gathering the rows of its
+``top_k`` slots through the inverse of the sort and summing them -- is
+no faster on the chip: a gathered row of 2,048 bfloat16 costs 35 ns and
+a scattered float32 one 83 ns, and the slots are ``top_k`` a token where
+a first chunk holds ``m`` (PERF.md section 6, PR 34).
+
+A chunk is sized for tokens that route alike (``chunk_rows``): ``m``
+choices of every token, with ``m`` the most of a token's ``top_k``
+experts that fall among the ``count`` held in all but one case in a
+hundred.  That is the share's worst
 common case, not its average: a block of identical tokens (a mask
 token, a separator, a model whose features have collapsed) puts ``j``
 choices of every one of them here at once, for a ``j`` drawn once for
@@ -177,13 +190,19 @@ def _chunk_inputs(rows, top_k, n_tokens, order, ends, sizes, start):
     return idx, tok, valid, group
 
 
+def _chunk_hidden(xs, w1, w3, group):
+    """[rows, D] sorted token rows -> their experts' hidden rows
+    [rows, F], in the products' dtype."""
+    return jax.nn.silu(jax.lax.ragged_dot(xs, w1, group)) * \
+        jax.lax.ragged_dot(xs, w3, group)
+
+
 def _chunk_experts(xs, w1, w3, w2, group):
     """[rows, D] sorted token rows -> their experts' output, unweighted,
     in the products' dtype."""
     with jax.named_scope("moe_experts"):
-        h = jax.nn.silu(jax.lax.ragged_dot(xs, w1, group)) * \
-            jax.lax.ragged_dot(xs, w3, group)
-        return jax.lax.ragged_dot(h, w2, group)
+        return jax.lax.ragged_dot(_chunk_hidden(xs, w1, w3, group), w2,
+                                  group)
 
 
 def _token_rows(a, tok):
@@ -278,24 +297,39 @@ def _held_experts_bwd(chunks, top_k, res, cotangent):
             idx, tok, valid, group = _chunk_inputs(
                 rows, top_k, xt.shape[0], order, ends, sizes, start)
             xs = _token_rows(xt, tok)
-            dys = _token_rows(dy, tok).astype(f32)
-        out, vjp = jax.vjp(
-            lambda xs, w1, w3, w2: _chunk_experts(xs, w1, w3, w2, group),
-            xs, w1, w3, w2)
+            dys = _token_rows(dy, tok)
+        with jax.named_scope("moe_experts"):
+            h, vjp = jax.vjp(
+                lambda xs, w1, w3: _chunk_hidden(xs, w1, w3, group),
+                xs, w1, w3)
+            # ``h @ w2`` itself is not made: its transpose in ``h``, on
+            # the tokens' cotangent WITHOUT the gate, serves both the
+            # hidden rows' cotangent and the gate's gradient
+            u, = jax.linear_transpose(
+                lambda h: jax.lax.ragged_dot(h, w2, group), h)(dys)
         with jax.named_scope("moe_combine"):
-            # a row's cotangent is its token's times the gate, the
-            # gate's the row's output along its token's cotangent: one
-            # pass over the rows; those past the last held choice carry
-            # no gradient (a padded row repeats choice 0)
+            # the gate is a scalar a row, so it multiplies after the
+            # product, and on the F-wide side of both: a row's hidden
+            # cotangent is ``g * u``, the gate's gradient ``h . u`` (the
+            # row's output along its token's cotangent), ``w2``'s takes
+            # ``g * h``: one pass over [rows, F], none over [rows, D];
+            # the rows past the last held choice carry no gradient (a
+            # padded row repeats choice 0)
             g = jax.lax.dynamic_slice(gates, (start,), (rows,))
-            dout = jnp.where(valid[:, None], dys * g[:, None], 0.0)
-            dg = jnp.where(valid, jnp.sum(out.astype(f32) * dys, axis=-1),
-                           0.0)
-        dxs, *dw = vjp(dout.astype(out.dtype))
+            g = jnp.where(valid, g, 0.0)[:, None]
+            dg = jnp.where(valid, jnp.sum(h.astype(f32) * u.astype(f32),
+                                          axis=-1), 0.0)
+            dh = (g * u).astype(h.dtype)
+            gh = (g * h).astype(h.dtype)
+        with jax.named_scope("moe_experts"):
+            dw2, = jax.linear_transpose(
+                lambda w2: jax.lax.ragged_dot(gh, w2, group), w2)(dys)
+            dxs, dw1, dw3 = vjp(dh)
         with jax.named_scope("moe_combine"):
             dxs = jnp.where(valid[:, None], dxs.astype(f32), 0.0)
             return (dxt.at[tok].add(dxs), dgate.at[idx].add(dg),
-                    [a + d.astype(f32) for a, d in zip(dws, dw)])
+                    [a + d.astype(f32)
+                     for a, d in zip(dws, (dw1, dw3, dw2))])
 
     zero = (jnp.zeros(xt.shape, f32), jnp.zeros((xt.shape[0] * top_k,), f32),
             [jnp.zeros(w.shape, f32) for w in (w1, w3, w2)])

@@ -77,6 +77,7 @@ def test_leg_latent_trainer(smoke):
     assert facts["flash_fwd_calls_in_step"] == 0  # no Mosaic call to count
     assert facts["counters"]["moe_dropped_choices"] == 0.0
     assert facts["counters"]["mtp_loss"] > 0
+    assert facts["ragged_dot_calls_a_layer"] == 0  # no custom call here
     assert facts["latent_flash_bwd_max_rel_err"] <= 1e-4
 
 
@@ -94,6 +95,7 @@ def test_leg_hybrid_trainer(smoke):
     assert facts["losses"][-1] < facts["losses"][0]
     assert facts["gated_delta_calls_in_step"] == [0, 0]   # no Mosaic call
     assert facts["causal_conv_calls_in_step"] == [0, 0]
+    assert facts["ragged_dot_calls_a_layer"] == 0
     assert facts["counters"]["gdn_conv_fallback_passes"] == 1.0
     # 64 of every 96 columns a key head: no shape of the kernels'
     assert facts["causal_conv_max_rel_err"] is None

@@ -47,13 +47,21 @@ def _loop(x, lp, first=0, count=E, top_k=K, norm=True, scoring="softmax",
     if norm:
         gate = gate / gate.sum(-1, keepdims=True)
     gate = gate * route_scale
-    y = jnp.zeros_like(xt)
-    for j in range(count):
-        g = jnp.sum(jnp.where(chosen == first + j, gate, 0.0), -1)
-        e = first + j
-        y = y + g[:, None] * ((jax.nn.silu(xt @ lp["w1"][e])
-                               * (xt @ lp["w3"][e])) @ lp["w2"][e])
+    y = _experts_loop(xt, gate, chosen, _held(lp, first, count), first)
     return y.reshape(x.shape), chosen
+
+
+def _experts_loop(xt, gate, chosen, w, first):
+    """What the held experts ``w`` (``w1``/``w3``/``w2`` of the experts
+    from ``first`` on) give for tokens ``xt`` [T, D] whose choices
+    ``chosen`` [T, k] have the gates ``gate`` [T, k]: a function of the
+    gates, so ``jax.grad`` of it has the gates' gradient too."""
+    y = jnp.zeros_like(xt)
+    for j in range(w["w1"].shape[0]):
+        g = jnp.sum(jnp.where(chosen == first + j, gate, 0.0), -1)
+        y = y + g[:, None] * ((jax.nn.silu(xt @ w["w1"][j])
+                               * (xt @ w["w3"][j])) @ w["w2"][j])
+    return y
 
 
 def _held(lp, first, count):
@@ -189,17 +197,23 @@ def test_the_balance_loss_is_one_at_balance_and_pushes_towards_it():
 # -- PR 34: how a chunk's rows return to their tokens, and what the
 # backward reads on the way --------------------------------------------
 
+def _inner_jaxprs(eqn):
+    """The jaxprs inside an equation (loops, conditionals, hand-written
+    derivatives, ``jit``)."""
+    for value in eqn.params.values():
+        for inner in (value if isinstance(value, (list, tuple))
+                      else [value]):
+            inner = getattr(inner, "jaxpr", inner)
+            if hasattr(inner, "eqns"):
+                yield inner
+
+
 def _equations(jaxpr):
-    """Every equation of a jaxpr and of the jaxprs inside it (loops,
-    conditionals, hand-written derivatives)."""
+    """Every equation of a jaxpr and of the jaxprs inside it."""
     for eqn in jaxpr.eqns:
         yield eqn
-        for value in eqn.params.values():
-            for inner in (value if isinstance(value, (list, tuple))
-                          else [value]):
-                inner = getattr(inner, "jaxpr", inner)
-                if hasattr(inner, "eqns"):
-                    yield from _equations(inner)
+        for inner in _inner_jaxprs(eqn):
+            yield from _equations(inner)
 
 
 @pytest.mark.parametrize("scoring", ["softmax", "sigmoid"])
@@ -226,7 +240,8 @@ def test_what_the_layer_gathers_and_scatters(direction, scoring):
 
     fn = layer if direction == "forward" else jax.grad(
         lambda x, lp: jnp.sum(layer(x, lp).astype(jnp.float32) ** 2), (0, 1))
-    eqns = list(_equations(jax.make_jaxpr(fn)(x, _held(lp, 8, 4)).jaxpr))
+    jaxpr = jax.make_jaxpr(fn)(x, _held(lp, 8, 4)).jaxpr
+    eqns = list(_equations(jaxpr))
     wide_adds = [e for e in eqns if e.primitive.name == "scatter-add"
                  and e.invars[0].aval.shape[-1:] == (D,)]
     # the first chunk and the loop's body, in each direction traced
@@ -250,6 +265,50 @@ def test_what_the_layer_gathers_and_scatters(direction, scoring):
         shape = e.invars[0].aval.shape
         assert shape != (tokens * K,) or e.primitive.name == "scatter-add", e
         assert shape != (tokens, E), e
+    # (e) PR 43: a chunk makes three grouped products forward and eight
+    # backward -- ``h @ w2`` is not made again for the gates' gradient,
+    # which comes with the hidden rows' cotangent from one product of the
+    # tokens' cotangent with ``w2`` transposed -- in two bodies, the first
+    # chunk's and the loop's; and the gathered rows, the cotangent's too,
+    # are read by grouped products alone: nothing widens, scales or masks
+    # them at [rows, D]
+    products = [e for e in eqns if e.primitive.name == "ragged_dot_general"]
+    assert len(products) == (2 * 3 if direction == "forward"
+                             else 2 * (3 + 8))
+    readers = list(_readers_of_gathered_rows(jaxpr, (tokens, D)))
+    # the tokens' rows into ``w1`` and ``w3``, forward; backward those and
+    # their two transposes, the cotangent's into ``w2``'s two
+    assert len(readers) == (2 * 2 if direction == "forward"
+                            else 2 * 2 + 2 * (4 + 2))
+    assert {e.primitive.name for e in readers} == {"ragged_dot_general"}
+    rows = set(chunk_rows(tokens, E, 4, K))
+    wide = [e for e in eqns if e.primitive.name == "convert_element_type"
+            and e.outvars[0].aval.dtype == jnp.float32
+            and e.outvars[0].aval.shape in {(r, D) for r in rows}]
+    # a chunk's rows are widened where they are added to their tokens,
+    # once a body and direction (the parent's backward: three times)
+    assert len(wide) == (2 if direction == "forward" else 4)
+
+
+def _readers_of_gathered_rows(jaxpr, shape):
+    """The equations, at any depth, that read what a gather of rows out
+    of an array of ``shape`` gave (the gather itself, or the ``jnp.take``
+    around it)."""
+    def gathers(eqn):
+        return (eqn.primitive.name == "gather"
+                and eqn.invars[0].aval.shape == shape)
+
+    gathered = set()
+    for eqn in jaxpr.eqns:
+        if any(id(v) in gathered for v in eqn.invars):
+            yield eqn
+        inner = list(_inner_jaxprs(eqn))
+        if gathers(eqn) or (eqn.primitive.name == "jit" and any(
+                gathers(e) for j in inner for e in j.eqns)):
+            gathered.update(id(v) for v in eqn.outvars)
+        else:
+            for j in inner:
+                yield from _readers_of_gathered_rows(j, shape)
 
 
 @pytest.mark.parametrize("traced", [False, True])
@@ -367,3 +426,94 @@ def test_the_rows_return_to_their_tokens_as_in_the_plain_loop(name):
             w = w[getattr(k, "key", getattr(k, "idx", None))]
         assert float(jnp.max(jnp.abs(g - w))) <= tol * max(
             float(jnp.max(jnp.abs(w))), 1e-30), (path, name)
+
+
+# -- PR 43: the backward takes the gates' gradient and the hidden rows'
+# cotangent from one product ---------------------------------------------
+
+def _routed(load, scoring):
+    """-> (tokens [T, D], the whole layer's ``lp``, held range, gates
+    [T, k] and choices [T, k] as the router of ``scoring`` gives them)."""
+    if load == "padded_rows_in_the_last_group":
+        # under the first chunk's rows: the rows past the last held
+        # choice ride in the last group
+        x, lp, held = _tokens(), _layer(), (8, 4)
+    elif load == "a_second_chunk_filled_to_its_last_row":
+        # every token takes all four held experts: 3 x 512 rows, then 512
+        x, held = _tokens(4, 128, key=2), (8, 4)
+        lp = _layer(skew={8 + j: 30.0 - j for j in range(4)})
+    elif load == "a_second_and_a_third_chunk_the_last_nearly_empty":
+        x, held = _tokens(3, 100, key=2), (8, 6)
+        lp = _layer(skew={8 + j: 30.0 - j for j in range(6)})
+    xt = x.reshape(-1, D)
+    if scoring == "softmax":
+        gate, chosen = jax.lax.top_k(jax.nn.softmax(xt @ lp["wr"], -1), K)
+        gate = gate / gate.sum(-1, keepdims=True)
+    else:
+        # not renormalised, times a route scale: gates that sum to ~5
+        gate, chosen = jax.lax.top_k(jax.nn.sigmoid(xt @ lp["wr"] / 5.0), K)
+        gate = 2.5 * gate
+    return xt, lp, held, gate, chosen
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("load", [
+    "padded_rows_in_the_last_group",
+    "a_second_chunk_filled_to_its_last_row",
+    "a_second_and_a_third_chunk_the_last_nearly_empty"])
+@pytest.mark.parametrize("scoring", ["softmax", "sigmoid"])
+def test_the_held_experts_five_gradients_are_the_plain_loops(
+        scoring, load, dtype):
+    """``_held_experts`` itself, under either router's gates: the
+    gradients in the gates, the tokens and the three weights equal
+    ``jax.grad`` of the plain loop (float32 to 1e-5; bfloat16 inputs
+    against the float32 loop over the same rounded numbers within
+    bfloat16's rounding, as above: the sums are float32)."""
+    xt, lp, (first, count), gate, chosen = _routed(load, scoring)
+    tokens = xt.shape[0]
+    dtype = jnp.dtype(dtype)
+    tol = 1e-5 if dtype == jnp.float32 else 2e-2
+    w = {k: lp[k][first:first + count] for k in ("w1", "w3", "w2")}
+    xt, w = jax.tree.map(lambda a: a.astype(dtype).astype(jnp.float32),
+                         (xt, w))
+    chunks = chunk_rows(tokens, E, count, K)
+    local = chosen.reshape(-1) - first
+    key = jnp.where((local >= 0) & (local < count), local,
+                    count).astype(jnp.int32)
+    sizes = jnp.bincount(key, length=count + 1)[:count].astype(jnp.int32)
+    held = int(sizes.sum())
+    if load == "padded_rows_in_the_last_group":
+        assert 0 < held < chunks[0]
+    elif load == "a_second_chunk_filled_to_its_last_row":
+        assert held == chunks[0] + chunks[1]
+    else:
+        assert chunks[0] + chunks[1] < held < chunks[0] + 2 * chunks[1]
+
+    def held_experts(xt, gate, w):
+        xt, w = jax.tree.map(lambda a: a.astype(dtype), (xt, w))
+        y, done = moe._held_experts(
+            chunks, K, xt, gate.reshape(-1), w["w1"], w["w3"], w["w2"], key,
+            jnp.cumsum(sizes), sizes)
+        return y.astype(jnp.float32), done
+
+    def loop(xt, gate, w):
+        return _experts_loop(xt, gate, chosen, w, first)
+
+    got, done = jax.jit(held_experts)(xt, gate, w)
+    want = loop(xt, gate, w)
+    assert int(done) == held
+    assert float(jnp.max(jnp.abs(got - want))) <= tol * float(
+        jnp.max(jnp.abs(want)))
+    got_g = jax.jit(jax.grad(lambda *a: jnp.sum(held_experts(*a)[0] ** 2),
+                             (0, 1, 2)))(xt, gate, w)
+    want_g = jax.grad(lambda *a: jnp.sum(loop(*a) ** 2), (0, 1, 2))(
+        xt, gate, w)
+    for name, g, w_g in zip(
+            ("xt", "gate", "w1", "w2", "w3"), jax.tree.leaves(got_g),
+            jax.tree.leaves(want_g)):
+        assert g.shape == w_g.shape
+        assert float(jnp.max(jnp.abs(g - w_g))) <= tol * float(
+            jnp.max(jnp.abs(w_g))), name
+    # a choice of an expert that is not held has no gradient here
+    absent = np.asarray(key).reshape(tokens, K) == count
+    assert not np.asarray(got_g[1])[absent].any()

@@ -447,30 +447,34 @@ def step_jaxpr_hash(cell: str, root: str = ROOT) -> str:
         re.sub(r"0x[0-9a-f]+", "0x", text).encode()).hexdigest()
 
 
-# The three older cells' steps at the commit 50ae53a (PR 34), which they
-# still equal, and the state-space cell's at 7f1202d (PR 40: its
-# convolution still calls the plain ``causal_conv``).  Since
+# The dense cell's step at the commit 50ae53a (PR 34), which it still
+# equals, and the state-space cell's at 7f1202d (PR 40: its convolution
+# still calls the plain ``causal_conv``).  Since
 # PR 39 the layers' cut points carry names (``checkpoint_name``: metadata
 # that lowers to nothing), which are equations of the jaxpr: the test
 # below takes the names out and finds these hashes, so the names are all
-# that differs where no device reports a limit.  The hybrid cell's step
-# is PR 41's own: its delta layers' convolution is now the kernel pair of
-# ``ops/causal_conv.py`` (traced as on a TPU here) and the layer counts
-# one more thing (7627867e... at PR 36 to PR 40).
+# that differs where no device reports a limit.  The four sparse cells'
+# steps are PR 43's own, made by ``step_jaxpr_hash`` on PR 43's tree with
+# the names taken out as the test does: the expert layer's backward makes
+# eight grouped products a chunk for nine (``models/moe.py``), so every
+# step that runs ``_held_experts_bwd`` differs from its parent's
+# (f137e57d..., 7acc7897..., e839d08e... -- PR 41's own, its convolution
+# the kernel pair -- and 5fe7ef97... at PR 42).  The two cells that run no
+# line of ``moe.py`` keep the hashes they had: that they still pass says
+# those two steps are the parent's.
 PARENT_STEPS = {
     "train-dscoder-1b3.pack4k":
         "593226c3e798e87962790db2f4055938f8862739301114386319090f98a5021b",
     "train-sdar-30b-a3b.blockdiff4k":
-        "f137e57d6e107e1b1d4548271d9d82c16616f18b7ff8e23ff678874c522a24ba",
+        "94e7170b4118a422fbb3bc1f7e0e180ccbf94a731de725dedf91c6ebfddb3e28",
     "train-joyai-flash.pack8k":
-        "7acc7897f37b96e284f7c4873a1eb0820aeee4b6254a698c29781d9c3a3b91aa",
+        "6923b2157b98658fa86976e72d246b878723b0aecb85cc49a5b8ea97a28833a3",
     "train-qwen3-next.pack8k":
-        "e839d08e1a72a3af1560c39f74eb027a1d48694f6df0cac4797cb272512a9636",
+        "3fc5faa16f1d8fb3a23e10c65f21b40531e08f94730cae2fbd06db1d9e38d44a",
     "train-phi4-mini-flash.pack16k":
         "ef676578e0020595e3ae2f5d9b63594d720dd9670c02ef45bb2f7c366b65b715",
-    # PR 42's own: the cell whose ``mha`` runs differ
     "train-laguna-s.pack16k":
-        "5fe7ef972f83374cf835d6bf618feac02657421feaf0d07daf7e77a230f9cdc2",
+        "610498e3e64b55cef825b379a27ea789e9e7062a46139dd7907e4ede4144784b",
 }
 
 
@@ -488,7 +492,10 @@ def test_the_older_cells_steps_are_traced_as_the_parent_traced_them(
     flash kernel's two stay): where no device reports a memory limit --
     here -- nothing is planned, every ``remat_layer`` has the parent's
     policy and the step is differentiated as the parent's was, so the
-    names are the whole difference, and they lower to nothing."""
+    names are the whole difference, and they lower to nothing.  Since
+    PR 43 the four sparse cells' hashes are that PR's steps (the expert
+    layer's backward changed); the dense and the state-space cell's are
+    the ones they had."""
     import importlib
     from ray_tpu.models import gdn, mla, moe, transformer
     for module in (gdn, mla, moe, transformer):
