@@ -31,13 +31,14 @@ to shard).
 
 What the rule leaves in HBM on a TPU: its operands as this layer makes
 them (q, k at ``Hk`` heads, v, g, beta), ``o`` and, between the forward
-rule and the backward one, each chunk's entering state in float32; no
+rule and the backward one, the state entering every eighth chunk in
+float32 (those two kept by a rematerialised layer whatever its plan); no
 copy of q and k a value head, no ``[C, C]`` tensor, no ``W``, ``U`` or
-``V'`` (``ops/gated_delta.py``).  What the convolution reads and writes
-there: ``qkvz`` as the projection left it (the ``q | k | v`` columns of
-every key head through the kernels' block index: no sliced copy, no
-padded float32 copy, no sum before SiLU as an array) and ``mixed`` in
-float32, once each; backward ``qkvz``, ``mixed``'s cotangent and
+``V'``, no state a chunk (``ops/gated_delta.py``).  What the convolution
+reads and writes there: ``qkvz`` as the projection left it (the ``q |
+k | v`` columns of every key head through the kernels' block index: no
+sliced copy, no padded float32 copy, no sum before SiLU as an array) and
+``mixed`` in float32, once each; backward ``qkvz``, ``mixed``'s cotangent and
 ``qkvz``'s (zeros in ``z``'s columns), once each, and the taps' gradient
 as a sum a tile (``ops/causal_conv.py``).  Off the TPU, or at channels
 that are not whole 128-lane blocks, the plain ``causal_conv`` and XLA's

@@ -3,14 +3,18 @@
 
 A layer scanned under ``jax.checkpoint`` keeps its input and whatever
 its policy names; the backward pass computes the rest a second time.
-The flash kernel's ``out`` and ``lse`` (``BASE_NAMES``) are kept always.
-Beyond them the layers name their cut points (``checkpoint_name``: the
-middle residual, q, k and v as they enter the kernel, the FFN's
-products, the latents, the delta layer's projections and convolution,
-the state-space layer's projections, convolution and scan output, the
-memory unit's gate, differential attention's q and keys and values, the
-router's scores ...), and this module decides, where the step is
-traced, which of those names the device has room for.
+What the kernels name of their own outputs (``BASE_NAMES``: the flash
+kernel's ``out`` and ``lse``, the delta rule's ``o`` and the state
+entering each of its grid steps) is kept always: with them a layer's
+backward pass does not run the forward kernel again, for about the
+bytes of the layer's input a kernel.  Beyond them the layers name their
+cut points (``checkpoint_name``: the middle residual, q, k and v as they
+enter the kernel, the FFN's products, the latents, the delta layer's
+projections and convolution, the state-space layer's projections,
+convolution and scan output, the memory unit's gate, differential
+attention's q and keys and values, the router's scores ...), and this
+module decides, where the step is traced, which of those names the
+device has room for.
 
 **What is traced when** (``value_and_grad``, which ``make_train_step``
 calls in the place of ``jax.value_and_grad``):
@@ -66,13 +70,11 @@ The plan is per run (its kind of layer, its shapes, its length); it is
 an observation of shapes and of the device, not a setting: there is
 nothing to configure.  What it cannot see: under a mesh the working
 sets are counted whole, not a device's share (less is kept than would
-fit); the delta rule's states, which cannot carry a name
-(``ops/gated_delta.py``), and the selective scan's entering states,
-which carry none (``ops/selective_scan.py``: a layer's backward runs the
-forward kernel again).  What one run hands to later runs (the shared
-slot of ``models.transformer.run_stacks``) is no candidate: it is an
-output of its scan and a constant of its readers', kept once whatever
-the plan.
+fit); the selective scan's entering states, which carry no name
+(``ops/selective_scan.py``: a layer's backward runs the forward kernel
+again).  What one run hands to later runs (the shared slot of
+``models.transformer.run_stacks``) is no candidate: it is an output of
+its scan and a constant of its readers', kept once whatever the plan.
 """
 
 from __future__ import annotations
@@ -87,7 +89,13 @@ import jax
 import numpy as np
 from jax.extend import core as jex_core
 
-from ray_tpu.ops.flash_attention import RESIDUAL_NAMES as BASE_NAMES
+from ray_tpu.ops.flash_attention import RESIDUAL_NAMES as _FLASH_NAMES
+from ray_tpu.ops.gated_delta import RESIDUAL_NAMES as _DELTA_NAMES
+
+#: The kernels' own names: kept by every ``remat_layer`` whatever the
+#: plan, counted in a run's stacks, no candidate.  A name occurs only in
+#: the layers that run its kernel.
+BASE_NAMES = _FLASH_NAMES + _DELTA_NAMES
 
 #: Of the room the count leaves, the share the plan may fill.  A byte
 #: kept costs XLA's heap about 1.11 (2.92 GB of stacks raised the
@@ -113,7 +121,7 @@ _FLOPS_A_BYTE = 240.0
 # Equations that carry a jaxpr to be read in their place.
 _INLINED = {"jit": "jaxpr", "closed_call": "call_jaxpr",
             "custom_jvp_call": "call_jaxpr", "custom_vjp_call": "call_jaxpr",
-            "remat_opt": "fwd_jaxpr", "remat2": "jaxpr"}
+            "remat2": "jaxpr"}
 # Primitives whose operands and results reach memory whatever surrounds
 # them; everything else is taken to fuse with its consumers.
 _HEAVY = frozenset({

@@ -27,11 +27,12 @@ scratch.  A grid step first makes what its chunks make of their own rows
 (``K K^T``, ``Q K^T``, the decay masks, ``T``, ``W``, ``U``: no state in
 it, so the chunks' short chains of small products overlap), then walks
 the state through them.  It reads q, k, v, ``G`` (a ``jnp.cumsum``
-outside: [B, H, L] float32) and ``beta``, and writes ``o`` and the state
-entering the row's last chunk: no ``[C, C]`` tensor, no ``W``, ``U``,
-``Kd`` or ``V'`` and no per-chunk state reaches HBM.  q and k may come
-with fewer heads than v (``Hk`` dividing ``H``): value head ``h`` reads
-key head ``h // (H / Hk)`` through the block index, nothing is repeated.
+outside: [B, H, L] float32) and ``beta``, and writes ``o``, the state
+entering each grid step and the one entering the row's last chunk: no
+``[C, C]`` tensor, no ``W``, ``U``, ``Kd`` or ``V'`` and no state a
+chunk reaches HBM.  q and k may come with fewer heads than v (``Hk``
+dividing ``H``): value head ``h`` reads key head ``h // (H / Hk)``
+through the block index, nothing is repeated.
 
 The kernels work on tiles of two chunks side by side (128 rows, the
 MXU's width), every ``[128, 128]`` operand block-diagonal by chunk, so a
@@ -53,35 +54,39 @@ exponent is ever positive and nothing is divided by a decay, so a head
 that forgets within a position (``g`` of -30) and one that never does
 run alike.
 
-The gradient is by hand (``jax.custom_vjp`` over the whole rule).  Its
-``fwd`` rule is the same kernel also writing every chunk's entering
-state (float32, [B H, N, Dk, Dv]: the one per-chunk array between the
-two rules); ``gated_delta_bwd`` walks the chunks backwards carrying
-``dS`` in float32, rebuilds a chunk's ``K K^T``, ``T``, ``W``, ``U``,
-``V'`` in VMEM and makes ``dq``, ``dk``, ``dv``, ``dG`` and ``dbeta``
-there (``dA = -T^T dT T^T`` at full precision); ``dq`` and ``dk`` leave
-a value head at a time and are summed over a key head's value heads
-outside, as the reverse running sum that turns ``dG`` into ``dg`` is.
+The gradient is by hand (``jax.custom_vjp`` over the whole rule).  The
+one forward kernel also writes the state entering each of its grid steps
+(float32, [B H, N / step, Dk, Dv], ``step`` = 8 chunks where the row's
+chunk count allows: the one array between the two rules that is not an
+operand).  ``gated_delta_bwd`` takes the forward's grid the other way
+round.  A grid step first makes its tiles' chunk-local parts (``K K^T``,
+``T``, ``W``, ``U`` ...: once a tile), then walks the state forward
+through its chunks from the one it was handed, by the forward's own two
+lines (``V' = U - W S``, ``S <- exp(G_C) S + Kd^T V'``: the same casts
+in the same order, so the states are the forward's), holding the chunks'
+entering states in a VMEM scratch ([step, Dk, Dv] float32, 512 KB); then
+it walks the chunks backwards carrying ``dS`` in float32 and makes
+``dq``, ``dk``, ``dv``, ``dG`` and ``dbeta`` (``dA = -T^T dT T^T`` at
+full precision); ``dq`` and ``dk`` leave a value head at a time and are
+summed over a key head's value heads outside, as the reverse running sum
+that turns ``dG`` into ``dg`` is.
 
 Off the TPU, and under ``use_pallas=False``, the same lines are batched
 ``jnp`` differentiated by JAX (the inverse by hand) around a
 ``lax.scan`` over chunks (``_chunked_rule``): the oracle the kernels are
 held to in interpret mode.
 
-Under ``models.transformer.remat_layer`` nothing of the rule is kept,
-whatever room the step's plan finds (``models/remat.py``): the layer's
-backward runs the forward kernel again, as the ``fwd`` rule (7.7 ms a
-call on the v5e at [2 x 32 heads, 8,192, 128], the backward kernel 11.5:
-23 + 23 + 34 ms of a 565 ms step over three layers; keeping ``o`` and the
-entering states would hold 0.6 GB a layer to save the second 23:
-PERF.md section 6, PR 35, PR 36 and PR 39).  ``optimize_remat`` lets the
-forward scan, which needs no residual, run the kernel that writes ``o``
-alone; it also wraps the ``fwd`` rule in one equation that a checkpoint
-policy cannot look into, so ``o`` and the states cannot carry names for
-the plan to keep: the two are one choice, and the lean forward is the
-one made.  What the plan may keep of a delta layer lies around the rule
-(``models/gdn.py``: the fused projections; the convolution's sum and
-output are named and priced, and not worth their float32 bytes).
+``o`` and the step states carry the names ``RESIDUAL_NAMES`` where the
+``custom_vjp`` makes them its residuals, and in the primal function too.
+A layer rematerialised under ``models.transformer.remat_layer`` keeps
+those two always, as it keeps the flash kernel's ``out`` and ``lse``
+(``models/remat.py``: ``BASE_NAMES``), and so runs this forward kernel
+once a layer, not again in the backward pass: at [2 x 32 heads, 8,192,
+128] 134 MB of ``o`` and 67 MB of states a layer (a state a chunk would
+be 537 MB; PERF.md section 6, PR 44).  q, k, v, ``G`` and beta carry no
+name: they are made again from what the step's plan keeps around the
+rule (``models/gdn.py``: the fused projections; the convolution's output
+is named and priced, and not worth its float32 bytes).
 """
 
 from __future__ import annotations
@@ -91,6 +96,7 @@ import types
 
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -99,6 +105,14 @@ CHUNK = 64
 # them, their blocks are fetched together and their chunk-local chains
 # overlap.
 _CHUNKS_A_STEP = (8, 4, 2, 1)
+# What the forward kernel writes for the backward pass, named where the
+# custom_vjp makes them its residuals: ``o`` [B H, L, Dv] in the inputs'
+# dtype (the layer after the rule reads it) and the state entering each
+# grid step [B H, N / step, Dk, Dv] float32 (``gated_delta_bwd`` reads
+# it).  A ``jax.checkpoint`` whose policy saves these names
+# (``models.transformer.remat_layer``) does not run the forward kernel
+# again in the backward pass.  q, k, v, ``G`` and beta carry no name.
+RESIDUAL_NAMES = ("delta_out", "delta_step_states")
 _MXU_ROWS = 128
 _HIGHEST = jax.lax.Precision.HIGHEST
 _NN = (((1,), (0,)), ((), ()))
@@ -369,14 +383,14 @@ def _tile_local(q, k, v, g_row, b_row, m):
         qg=(q.astype(f32) * gamma_col).astype(dt))
 
 
-def _fwd_kernel(q_ref, k_ref, v_ref, g_ref, b_ref, o_ref, s_ref, state, *,
-                step: int, chunk: int, tile: int, every_state: bool):
+def _fwd_kernel(q_ref, k_ref, v_ref, g_ref, b_ref, o_ref, steps_ref, last_ref,
+                state, *, step: int, chunk: int, tile: int):
     # Grid (row x head, block of ``step`` chunks, in order).  q_ref,
     # k_ref: [step C, Dk]; v_ref, o_ref: [step C, Dv]; g_ref, b_ref:
     # [N C / R, R] float32, a row's G and beta a tile a row, resident
-    # across the head's blocks; state: [Dk, Dv] float32.  s_ref: each
-    # chunk's entering state [step, Dk, Dv] float32 (``every_state``:
-    # what the backward reads), else the row's last chunk's alone.
+    # across the head's blocks; state: [Dk, Dv] float32.  steps_ref: the
+    # state entering this grid step, last_ref: the one entering the
+    # row's last chunk, [Dk, Dv] float32 each.
     f32, dt = jnp.float32, v_ref.dtype
     blk = pl.program_id(1)
     tiles, per = step * chunk // tile, tile // chunk
@@ -392,15 +406,14 @@ def _fwd_kernel(q_ref, k_ref, v_ref, g_ref, b_ref, o_ref, s_ref, state, *,
         local.append(_tile_local(q_ref[at], k_ref[at], v_ref[at],
                                  g_ref[row, :], b_ref[row, :], m))
     s = state[...]
+    steps_ref[...] = s
     for j, x in enumerate(local):
         written, read = [], []
         for i in range(per):
-            if every_state:
-                s_ref[j * per + i] = s
-            elif (j, i) == (tiles - 1, per - 1):
+            if (j, i) == (tiles - 1, per - 1):
                 @pl.when(blk == pl.num_programs(1) - 1)
                 def _():
-                    s_ref[...] = s
+                    last_ref[...] = s
             at = slice(i * chunk, (i + 1) * chunk)
             # W S and (gamma Q) S: the state is the MXU's operand once
             both = _dot(jnp.concatenate([x.w[at], x.qg[at]], axis=0),
@@ -415,33 +428,49 @@ def _fwd_kernel(q_ref, k_ref, v_ref, g_ref, b_ref, o_ref, s_ref, state, *,
 
 
 def _bwd_kernel(q_ref, k_ref, v_ref, g_ref, b_ref, s_ref, do_ref, dq_ref,
-                dk_ref, dv_ref, dg_ref, db_ref, dstate, *, step: int,
+                dk_ref, dv_ref, dg_ref, db_ref, dstate, walked, *, step: int,
                 chunk: int, tile: int):
     # The forward's grid with the blocks, and the chunks of a block, the
     # other way round; dstate [Dk, Dv] float32 is the cotangent of the
-    # state LEAVING the chunk at hand.  s_ref: the entering states as the
-    # ``fwd`` rule wrote them; dg_ref, db_ref: the cotangents of G and
-    # beta, laid out and resident as g_ref and b_ref are.
+    # state LEAVING the chunk at hand.  s_ref [Dk, Dv]: the state
+    # entering this grid step as ``gated_delta_fwd`` wrote it; walked
+    # [step, Dk, Dv] float32: each of its chunks' entering states, made
+    # here by the forward's own walk.  dg_ref, db_ref: the cotangents of
+    # G and beta, laid out and resident as g_ref and b_ref are.
     f32, dt = jnp.float32, v_ref.dtype
     turn = pl.program_id(1)
     blk = pl.num_programs(1) - 1 - turn
     tiles, per = step * chunk // tile, tile // chunk
+    parts = [slice(i * chunk, (i + 1) * chunk) for i in range(per)]
 
     @pl.when(turn == 0)
     def _():
         dstate[...] = jnp.zeros_like(dstate)
 
     m = _tile_masks(tile, chunk, v_ref.shape[1])
+    local = []
+    for j in range(tiles):
+        at, row = slice(j * tile, (j + 1) * tile), pl.ds(blk * tiles + j, 1)
+        local.append(_tile_local(q_ref[at], k_ref[at], v_ref[at],
+                                 g_ref[row, :], b_ref[row, :], m))
+    # the forward's walk through the step's chunks: its two lines, its
+    # casts, its order, so the states are the forward's
+    s, wrote = s_ref[...], []
+    for j, x in enumerate(local):
+        written = []
+        for i, at in enumerate(parts):
+            walked[j * per + i] = s
+            written.append((x.u[at].astype(f32)
+                            - _dot(x.w[at], s.astype(dt))).astype(dt))
+            if (j, i) != (tiles - 1, per - 1):
+                s = s * x.c[i] + _dot(x.kd[at], written[-1], _TN)
+        wrote.append(_stack(written))
     ds = dstate[...]
     for j in reversed(range(tiles)):
         here, row = slice(j * tile, (j + 1) * tile), pl.ds(blk * tiles + j, 1)
         q, k, v, do = (ref[here] for ref in (q_ref, k_ref, v_ref, do_ref))
-        x = _tile_local(q, k, v, g_ref[row, :], b_ref[row, :], m)
-        parts = [slice(i * chunk, (i + 1) * chunk) for i in range(per)]
-        entering = [s_ref[j * per + i] for i in range(per)]
-        vp = _stack([
-            (x.u[at].astype(f32) - _dot(x.w[at], s.astype(dt))).astype(dt)
-            for at, s in zip(parts, entering)])
+        x, vp = local[j], wrote[j]
+        entering = [walked[j * per + i] for i in range(per)]
         # the two lines that hold the state, transposed, a chunk at a
         # time, the last first
         from_o = _dot(x.p, do, _TN)                         # P^T dO
@@ -511,10 +540,11 @@ def _tile_rows(chunk: int, step: int) -> int:
     return chunk * min(step, max(1, _MXU_ROWS // chunk))
 
 
-def _kernel_forward(q, k, v, total, beta, chunk, every_state, interpret):
+def _kernel_forward(q, k, v, total, beta, chunk, interpret):
     """q, k [B Hk, L, Dk], v [B H, L, Dv], total (``G``), beta [B H, N,
-    C] float32 -> (o [B H, L, Dv], the entering states: every chunk's
-    [B H, N, Dk, Dv] or the last chunk's [B H, Dk, Dv], float32)."""
+    C] float32 -> (o [B H, L, Dv], the state entering each grid step [B
+    H, N / step, Dk, Dv] and the one entering the row's last chunk [B H,
+    Dk, Dv], float32)."""
     bh, length, dv = v.shape
     dk, n = q.shape[-1], length // chunk
     group = bh // q.shape[0]
@@ -524,20 +554,16 @@ def _kernel_forward(q, k, v, total, beta, chunk, every_state, interpret):
                          lambda b, i: (b // group, i, 0))
     valued = pl.BlockSpec((None, step * chunk, dv), lambda b, i: (b, i, 0))
     whole = pl.BlockSpec((None, length // tile, tile), lambda b, i: (b, 0, 0))
-    if every_state:
-        states = pl.BlockSpec((None, step, dk, dv), lambda b, i: (b, i, 0, 0))
-        states_shape = (bh, n, dk, dv)
-    else:
-        states = pl.BlockSpec((None, dk, dv), lambda b, i: (b, 0, 0))
-        states_shape = (bh, dk, dv)
+    steps = pl.BlockSpec((None, None, dk, dv), lambda b, i: (b, i, 0, 0))
+    last = pl.BlockSpec((None, dk, dv), lambda b, i: (b, 0, 0))
     return pl.pallas_call(
-        functools.partial(_fwd_kernel, step=step, chunk=chunk, tile=tile,
-                          every_state=every_state),
+        functools.partial(_fwd_kernel, step=step, chunk=chunk, tile=tile),
         grid=(bh, n // step),
         in_specs=[keyed, keyed, valued, whole, whole],
-        out_specs=[valued, states],
+        out_specs=[valued, steps, last],
         out_shape=[jax.ShapeDtypeStruct(v.shape, v.dtype),
-                   jax.ShapeDtypeStruct(states_shape, jnp.float32)],
+                   jax.ShapeDtypeStruct((bh, n // step, dk, dv), jnp.float32),
+                   jax.ShapeDtypeStruct((bh, dk, dv), jnp.float32)],
         scratch_shapes=[pltpu.VMEM((dk, dv), jnp.float32)],
         # the state passes from a block of chunks to the next
         compiler_params=pltpu.CompilerParams(
@@ -548,10 +574,10 @@ def _kernel_forward(q, k, v, total, beta, chunk, every_state, interpret):
 
 
 @jax.named_scope("gated_delta_bwd")
-def _kernel_backward(q, k, v, total, beta, states, do, chunk, interpret):
-    """The operands of ``_kernel_forward``, every chunk's entering state
-    and ``o``'s cotangent -> those of q, k (a value head each: [B H, L,
-    Dk]), v, ``G`` and beta."""
+def _kernel_backward(q, k, v, total, beta, steps, do, chunk, interpret):
+    """The operands of ``_kernel_forward``, the state entering each of
+    its grid steps and ``o``'s cotangent -> those of q, k (a value head
+    each: [B H, L, Dk]), v, ``G`` and beta."""
     bh, length, dv = v.shape
     dk, n = q.shape[-1], length // chunk
     group = bh // q.shape[0]
@@ -565,23 +591,24 @@ def _kernel_backward(q, k, v, total, beta, states, do, chunk, interpret):
     valued = pl.BlockSpec((None, step * chunk, dv),
                           lambda b, i: (b, last - i, 0))
     whole = pl.BlockSpec((None, length // tile, tile), lambda b, i: (b, 0, 0))
-    states_spec = pl.BlockSpec((None, step, dk, dv),
-                               lambda b, i: (b, last - i, 0, 0))
+    entering = pl.BlockSpec((None, None, dk, dv),
+                            lambda b, i: (b, last - i, 0, 0))
     per_head = jax.ShapeDtypeStruct((bh, length, dk), q.dtype)
     tiled = jax.ShapeDtypeStruct((bh, length // tile, tile), jnp.float32)
     dq, dk_, dv_, dtotal, dbeta = pl.pallas_call(
         functools.partial(_bwd_kernel, step=step, chunk=chunk, tile=tile),
         grid=(bh, n // step),
-        in_specs=[keyed, keyed, valued, whole, whole, states_spec, valued],
+        in_specs=[keyed, keyed, valued, whole, whole, entering, valued],
         out_specs=[own, own, valued, whole, whole],
         out_shape=[per_head, per_head,
                    jax.ShapeDtypeStruct(v.shape, v.dtype), tiled, tiled],
-        scratch_shapes=[pltpu.VMEM((dk, dv), jnp.float32)],
+        scratch_shapes=[pltpu.VMEM((dk, dv), jnp.float32),
+                        pltpu.VMEM((step, dk, dv), jnp.float32)],
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
         name="gated_delta_bwd",
-    )(q, k, v, total.reshape(tiled.shape), beta.reshape(tiled.shape), states,
+    )(q, k, v, total.reshape(tiled.shape), beta.reshape(tiled.shape), steps,
       do)
     return (dq, dk_, dv_, dtotal.reshape(total.shape),
             dbeta.reshape(beta.shape))
@@ -589,13 +616,16 @@ def _kernel_backward(q, k, v, total, beta, states, do, chunk, interpret):
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6))
 def _rule_kernels(q, k, v, total, beta, chunk, interpret):
-    return tuple(_kernel_forward(q, k, v, total, beta, chunk, False,
-                                 interpret))
+    # (the names in the primal too: ``models/remat.py`` reads the
+    # forward's jaxpr alone, as ``flash_attention._kept_out`` has it)
+    return _rule_fwd(q, k, v, total, beta, chunk, interpret)[0]
 
 
 def _rule_fwd(q, k, v, total, beta, chunk, interpret):
-    o, states = _kernel_forward(q, k, v, total, beta, chunk, True, interpret)
-    return (o, states[:, -1]), (q, k, v, total, beta, states)
+    o, steps, last = _kernel_forward(q, k, v, total, beta, chunk, interpret)
+    o = checkpoint_name(o, RESIDUAL_NAMES[0])
+    steps = checkpoint_name(steps, RESIDUAL_NAMES[1])
+    return (o, last), (q, k, v, total, beta, steps)
 
 
 def _rule_bwd(chunk, interpret, res, cotangent):
@@ -609,7 +639,7 @@ def _rule_bwd(chunk, interpret, res, cotangent):
     return dq, dk, dv, dtotal, dbeta
 
 
-_rule_kernels.defvjp(_rule_fwd, _rule_bwd, optimize_remat=True)
+_rule_kernels.defvjp(_rule_fwd, _rule_bwd)
 
 
 def _fused_rule(q, k, v, g, beta, chunk, interpret):

@@ -732,9 +732,16 @@ def _delta_calls(text):
         text)) for name in ("gated_delta_fwd", "gated_delta_bwd"))
 
 
-@pytest.mark.parametrize("wrap,calls", [(lambda f: f, (1, 1)),
-                                        (jax.checkpoint, (2, 1))],
-                         ids=["kept", "remat"])
+def _keeping_the_rules_residuals(f):
+    from ray_tpu.ops.gated_delta import RESIDUAL_NAMES
+    return jax.checkpoint(
+        f, policy=jax.checkpoint_policies.save_only_these_names(
+            *RESIDUAL_NAMES))
+
+
+@pytest.mark.parametrize("wrap,calls", [
+    (lambda f: f, (1, 1)), (_keeping_the_rules_residuals, (1, 1)),
+    (jax.checkpoint, (2, 1))], ids=["kept", "named", "remat"])
 @pytest.mark.parametrize("key_heads", [32, 16])
 def test_gated_delta_gradient_compiles_for_the_chip_at_the_cell_width(
         one_v5e_chip, key_heads, wrap, calls):
@@ -744,10 +751,11 @@ def test_gated_delta_gradient_compiles_for_the_chip_at_the_cell_width(
     this file because one process may describe the chip
     (``one_v5e_chip``).  What the compiled programs hold in HBM: no
     float32 array that ends in a chunk's ``[64, 64]`` (nor a tile's
-    ``[128, 128]`` a pair of chunks), forward no array of the states'
-    shape, under the gradient the entering states from the ``fwd`` rule's
-    kernel to ``gated_delta_bwd`` and nothing else a chunk; under remat
-    the forward pass runs the kernel that writes ``o`` alone."""
+    ``[128, 128]`` a pair of chunks) but the state entering each grid
+    step of 8 chunks, written by the one forward kernel and read by
+    ``gated_delta_bwd``, and the row's last; no state a chunk.  Under a
+    checkpoint that saves the rule's two names (``remat_layer``) the
+    forward kernel runs once; under one that saves nothing, twice."""
     from ray_tpu.ops.gated_delta import gated_delta_rule
 
     def spec(shape, dtype=jnp.bfloat16):
@@ -756,11 +764,12 @@ def test_gated_delta_gradient_compiles_for_the_chip_at_the_cell_width(
     keyed, wide = spec((2, 8192, key_heads, 128)), spec((2, 8192, 32, 128))
     gate = spec((2, 8192, 32), jnp.float32)
     operands = (keyed, keyed, wide, gate, gate)
-    states = "f32[64,128,128,128]"
-    # a row's last entering state, as the kernel writes it and as the
-    # rule returns it: the one float32 array the forward may hold that
-    # ends in a square (Dk x Dv is a tile's size)
-    last = {"f32[64,128,128]", "f32[2,32,128,128]"}
+    every_chunk, steps = "f32[64,128,128,128]", "f32[64,16,128,128]"
+    # the state entering each grid step, and a row's last entering state
+    # as the kernel writes it and as the rule returns it: the float32
+    # arrays the rule may hold that end in a square (Dk x Dv is a tile's
+    # size)
+    states = {steps, "f32[64,128,128]", "f32[2,32,128,128]"}
 
     def squares(text):
         return {m.group(1) for m in re.finditer(
@@ -772,7 +781,7 @@ def test_gated_delta_gradient_compiles_for_the_chip_at_the_cell_width(
     forward = jax.jit(rule).lower(*operands).compile().as_text()
     assert _delta_calls(forward) == (1, 0)
     assert forward.count("tpu_custom_call") == 1
-    assert squares(forward) <= last
+    assert squares(forward) <= states
 
     # the value too, so that remat's forward pass has a reader
     text = jax.jit(jax.value_and_grad(lambda *xs: jnp.sum(wrap(rule)(
@@ -781,12 +790,16 @@ def test_gated_delta_gradient_compiles_for_the_chip_at_the_cell_width(
     assert _delta_calls(text) == calls
     assert text.count("tpu_custom_call") == sum(calls)
     assert " while(" not in text
-    # the entering states, written by one kernel: the fwd rule's
-    assert states in squares(text) <= last | {states}
-    writers = [line for line in text.splitlines()
-               if "tpu_custom_call" in line
-               and states in line.split(" custom-call(")[0]]
-    assert len(writers) == 1 and "%gated_delta_fwd" in writers[0]
+    assert every_chunk not in text
+    # (XLA may fetch the kept step states for the backward kernel a
+    # slice of the heads at a time)
+    assert steps in squares(text) and all(
+        shape in states or re.fullmatch(r"f32\[\d+,16,128,128\]", shape)
+        for shape in squares(text))
+    writers = {line.split(" = ")[0].strip().split(".")[0]
+               for line in text.splitlines() if "tpu_custom_call" in line
+               and steps in line.split(" custom-call(")[0]}
+    assert writers == {"%gated_delta_fwd"}
 
 
 @pytest.mark.parametrize("how,wrap,want", [

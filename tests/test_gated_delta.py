@@ -83,13 +83,20 @@ def test_chunked_is_the_recurrence_values_and_all_five_gradients(length,
 @pytest.mark.parametrize("key_heads", [4, 2], ids=["Hk=H", "Hk=H/2"])
 @pytest.mark.parametrize("decay", ["fast", "slow", "mixed"])
 @pytest.mark.parametrize("length,chunk", [(64, 64), (320, 64), (512, 16),
-                                          (128, 32)])
+                                          (128, 32), (1024, 64), (256, 64),
+                                          (384, 64), (192, 64)])
 def test_both_kernels_interpreted_are_the_chunked_form(length, chunk, decay,
                                                        key_heads):
     """Values, all five gradients (by hand in ``gated_delta_bwd``) and
     the counter's state: one chunk, five chunks a block of one, 32 chunks
-    of 16 rows in blocks of 8, four chunks of two halves; q and k with a
+    of 16 rows in blocks of 8, four chunks of two halves; then chunks of
+    64 rows whose count gives grid steps of 8 (two steps of four tiles:
+    the cell's arrangement), 4, 2 and 1 chunks, the backward kernel
+    walking a step's states from the one it is handed; q and k with a
     head a value head and with one for two."""
+    n = length // chunk
+    assert gated_delta._chunks_a_step(n) == {
+        1: 1, 5: 1, 32: 8, 4: 4, 16: 8, 6: 2, 3: 1}[n]
     args, dout = inputs(7 + length, length, decay, h=4, hk=key_heads)
     chunked = lambda *a: gated_delta_rule(*a, chunk=chunk, use_pallas=False,
                                           with_state=True)
@@ -104,6 +111,37 @@ def test_both_kernels_interpreted_are_the_chunked_form(length, chunk, decay,
     for name, a, b in zip(NAMES, got, want):
         assert a.shape == b.shape and bool(jnp.all(jnp.isfinite(a))), name
         close(a, b, 5e-6)
+
+
+@pytest.mark.parametrize("length,step", [(1024, 8), (256, 4), (384, 2),
+                                         (192, 1)])
+def test_the_forward_writes_every_step_th_entering_state(length, step,
+                                                         monkeypatch):
+    """What ``gated_delta_fwd`` hands ``gated_delta_bwd``: the state
+    entering each grid step, which is every ``step``-th entering state
+    of the chunked form's scan over chunks; the last of the scan's is the
+    counter's."""
+    (q, k, v, g, beta), _ = inputs(31 + length, length, "mixed", h=4, hk=2)
+    scanned = []
+    scan = gated_delta._state_pass_scan
+
+    def kept(*operands):
+        scanned.append(scan(*operands))
+        return scanned[-1]
+    monkeypatch.setattr(gated_delta, "_state_pass_scan", kept)
+    gated_delta._chunked_rule(q, k, v, g, beta, 64)
+    (states, _), = scanned                          # [B H, N, Dk, Dv]
+
+    heads_first = lambda x: jnp.moveaxis(x, 2, 1).reshape(
+        -1, length, x.shape[-1])
+    rows = lambda x: jnp.moveaxis(x, 2, 1).reshape(-1, length // 64, 64)
+    _, steps, last = gated_delta._kernel_forward(
+        heads_first(q), heads_first(k), heads_first(v),
+        jnp.cumsum(rows(g), axis=-1), rows(beta), 64, True)
+    assert steps.shape == (2 * 4, length // 64 // step, 16, 8)
+    assert steps.dtype == last.dtype == jnp.float32
+    close(steps, states[:, ::step], 5e-6)
+    close(last, states[:, -1], 5e-6)
 
 
 @pytest.mark.parametrize("how", [dict(use_pallas=False),
@@ -129,13 +167,16 @@ def test_the_grouped_call_is_the_repeated_one(how):
         rule(q, k, *(x[:, :, :3] for x in (v, g, beta)))
 
 
-def test_remat_runs_the_lean_kernel_forward_and_the_states_backward():
-    """Under ``jax.checkpoint`` the forward pass needs no residual, so it
-    runs the kernel that writes ``o`` alone (``optimize_remat``); the
-    kernel that also writes every chunk's entering state runs once, in
-    the backward pass beside ``gated_delta_bwd``."""
-    args, dout = inputs(9, 128, "mixed")
-    states = (2 * 3, 128 // 64, 16, 8)
+def test_remat_runs_the_forward_kernel_once_where_its_names_are_kept():
+    """One forward kernel, whoever asks: it writes ``o`` and the state
+    entering each grid step, never a state a chunk.  Plain ``grad`` runs
+    it once beside ``gated_delta_bwd``; so does a ``jax.checkpoint``
+    whose policy saves ``RESIDUAL_NAMES`` (what ``remat_layer`` always
+    does); one that saves nothing runs it again in the backward pass.
+    The gradients are the same three ways."""
+    args, dout = inputs(9, 1024, "mixed")
+    every_chunk = (2 * 3, 1024 // 64, 16, 8)
+    steps = (2 * 3, 1024 // 64 // 8, 16, 8)
 
     def loss(*a):
         return jnp.sum(gated_delta_rule(*a, use_pallas=True,
@@ -147,23 +188,45 @@ def test_remat_runs_the_lean_kernel_forward_and_the_states_backward():
         def walk(jaxpr):
             for eqn in jaxpr.eqns:
                 if eqn.primitive.name == "pallas_call":
-                    found.append((eqn.params["name"], any(
-                        v.aval.shape == states for v in eqn.outvars)))
+                    shapes = [v.aval.shape for v in eqn.outvars]
+                    assert every_chunk not in shapes
+                    found.append((eqn.params["name"], steps in shapes))
                 for sub in jax.core.jaxprs_in_params(eqn.params):
                     walk(sub)
         walk(jax.make_jaxpr(fn)(*args).jaxpr)
-        return found
+        return sorted(found)
 
-    assert calls(loss) == [("gated_delta_fwd", False)]
-    kept = jax.grad(loss, argnums=range(5))
-    assert sorted(calls(kept)) == [("gated_delta_bwd", False),
-                                   ("gated_delta_fwd", True)]
-    again = jax.grad(jax.checkpoint(loss), argnums=range(5))
-    assert sorted(calls(again)) == [("gated_delta_bwd", False),
-                                    ("gated_delta_fwd", False),
-                                    ("gated_delta_fwd", True)]
-    for a, b in zip(again(*args), kept(*args)):
-        close(a, b, 1e-7)
+    once = [("gated_delta_bwd", False), ("gated_delta_fwd", True)]
+    assert calls(loss) == once[1:]
+    plain = jax.grad(loss, argnums=range(5))
+    assert calls(plain) == once
+    named = jax.grad(jax.checkpoint(
+        loss, policy=jax.checkpoint_policies.save_only_these_names(
+            *gated_delta.RESIDUAL_NAMES)), argnums=range(5))
+    assert calls(named) == once
+    nothing = jax.grad(jax.checkpoint(loss), argnums=range(5))
+    assert calls(nothing) == once + once[1:]
+    want = plain(*args)
+    for got in (named(*args), nothing(*args)):
+        for a, b in zip(got, want):
+            close(a, b, 1e-7)
+
+
+def test_the_rules_residuals_carry_their_names_forward_and_differentiated():
+    """``o`` and the step states are named in the ``fwd`` rule, for a
+    checkpoint's policy, and in the primal function, for whoever reads
+    the forward's jaxpr alone (``models/remat.py``)."""
+    args, dout = inputs(4, 128, "slow")
+
+    def loss(*a):
+        return jnp.sum(gated_delta_rule(*a, use_pallas=True,
+                                        interpret=True) * dout)
+
+    for fn in (loss, jax.grad(loss, argnums=range(5))):
+        text = str(jax.make_jaxpr(fn)(*args))
+        for name in gated_delta.RESIDUAL_NAMES:
+            assert f"name[name={name}]" in text, name
+    assert gated_delta.RESIDUAL_NAMES == ("delta_out", "delta_step_states")
 
 
 def test_the_kernels_carry_their_names_into_the_traced_program():

@@ -461,7 +461,12 @@ def step_jaxpr_hash(cell: str, root: str = ROOT) -> str:
 # (f137e57d..., 7acc7897..., e839d08e... -- PR 41's own, its convolution
 # the kernel pair -- and 5fe7ef97... at PR 42).  The two cells that run no
 # line of ``moe.py`` keep the hashes they had: that they still pass says
-# those two steps are the parent's.
+# those two steps are the parent's.  The hybrid cell's is PR 44's own, made
+# the same way (3fc5faa1... at PR 43): the delta rule's forward kernel is
+# one kernel that also writes the state entering each grid step, its
+# backward walks a step's states itself, and the rule's two names stay in
+# as the flash kernel's do; the five cells without a delta layer keep
+# PR 43's hashes.
 PARENT_STEPS = {
     "train-dscoder-1b3.pack4k":
         "593226c3e798e87962790db2f4055938f8862739301114386319090f98a5021b",
@@ -470,7 +475,7 @@ PARENT_STEPS = {
     "train-joyai-flash.pack8k":
         "6923b2157b98658fa86976e72d246b878723b0aecb85cc49a5b8ea97a28833a3",
     "train-qwen3-next.pack8k":
-        "3fc5faa16f1d8fb3a23e10c65f21b40531e08f94730cae2fbd06db1d9e38d44a",
+        "279ed63714177eae4ded5732ad3a5db2c6b44799f7434a36f8f7b719fa08bd1f",
     "train-phi4-mini-flash.pack16k":
         "ef676578e0020595e3ae2f5d9b63594d720dd9670c02ef45bb2f7c366b65b715",
     "train-laguna-s.pack16k":
@@ -495,7 +500,9 @@ def test_the_older_cells_steps_are_traced_as_the_parent_traced_them(
     names are the whole difference, and they lower to nothing.  Since
     PR 43 the four sparse cells' hashes are that PR's steps (the expert
     layer's backward changed); the dense and the state-space cell's are
-    the ones they had."""
+    the ones they had.  Since PR 44 the hybrid cell's is that PR's (the
+    delta rule's kernels changed; nothing else did: the other five
+    pass as they stood)."""
     import importlib
     from ray_tpu.models import gdn, mla, moe, transformer
     for module in (gdn, mla, moe, transformer):

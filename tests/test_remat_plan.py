@@ -10,6 +10,7 @@ import ast
 import collections
 import functools
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -22,6 +23,7 @@ from ray_tpu.models.mamba import MambaConfig
 from ray_tpu.models.mla import MLAConfig
 from ray_tpu.models.transformer import (TransformerConfig, apply_layer,
                                         init_stack, run_stack)
+from ray_tpu.ops import gated_delta
 from ray_tpu.ops.flash_attention import RESIDUAL_NAMES
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -268,10 +270,11 @@ def _surveys():
 
 def test_no_budget_is_exactly_todays_two_names(monkeypatch):
     """Budget 0, none or negative (a step that fills the device
-    already): every run keeps ``RESIDUAL_NAMES``, nothing else, and no
+    already): every run keeps the kernels' own names (``BASE_NAMES``: the
+    flash kernel's two and the delta rule's two), nothing else, and no
     candidate is even ordered; a device that reports no limit is not
-    even planned for: its policy is ``save_only_these_names`` of the
-    two, the parent's."""
+    even planned for: its policy is ``save_only_these_names`` of
+    those."""
     surveys = _surveys()
     for budget in (0, None, -5):
         names, report = remat.make_plan(surveys, budget)
@@ -280,15 +283,64 @@ def test_no_budget_is_exactly_todays_two_names(monkeypatch):
         assert all(r["names"] == [] for r in report["runs"])
     assert remat.no_plan() == {"budget_bytes": None, "kept_bytes": 0,
                                "runs": []}
-    assert remat.BASE_NAMES == RESIDUAL_NAMES
+    assert remat.BASE_NAMES == RESIDUAL_NAMES + gated_delta.RESIDUAL_NAMES
+    assert len(set(remat.BASE_NAMES)) == 4
     assert remat.device_memory() is None
     policy = remat.policy(("mha", "dense"))
     assert not isinstance(policy, remat.Keeps)
     assert "save_only_these_names" in policy.__qualname__
     keeps = remat.Keeps(("mha", "dense"), 1)
-    assert keeps.names == RESIDUAL_NAMES
+    assert keeps.names == remat.BASE_NAMES
     keeps.keep(["attn_v"])
-    assert keeps.names == RESIDUAL_NAMES + ("attn_v",)
+    assert keeps.names == remat.BASE_NAMES + ("attn_v",)
+
+
+def _interpreted_kernels(monkeypatch):
+    """The flash kernel and the delta rule's pair in the layers, as the
+    chip's dispatch has them, interpreted."""
+    import importlib
+    fa = importlib.import_module("ray_tpu.ops.flash_attention")
+    monkeypatch.setattr(transformer, "flash_or_ref_attention",
+                        functools.partial(fa.flash_attention,
+                                          interpret=True))
+    monkeypatch.setattr(gated_delta, "gated_delta_rule", functools.partial(
+        gated_delta.gated_delta_rule, use_pallas=True, interpret=True))
+
+
+@pytest.mark.parametrize("kind,more,kernels,names", [
+    ("mha", dict(length=128, head_dim=64),
+     ["flash_attention_bwd", "flash_attention_fwd"], RESIDUAL_NAMES),
+    ("gdn", dict(length=128),
+     ["gated_delta_bwd", "gated_delta_fwd"], gated_delta.RESIDUAL_NAMES)])
+def test_a_layer_saves_its_own_kernels_names_and_runs_each_kernel_once(
+        kind, more, kernels, names, monkeypatch):
+    """Of ``BASE_NAMES`` a layer's jaxpr holds those of the kernel it
+    runs and no other: a layer without a delta rule saves exactly the
+    flash kernel's two arrays, a delta layer the rule's two.  Under
+    today's policy (no device to ask) and under a plan with no room the
+    gradient of the two-layer scan holds each kernel once -- the forward
+    scan's call; the backward scan runs the backward kernel alone -- and
+    its numbers are those without remat."""
+    _interpreted_kernels(monkeypatch)
+    got = {}
+    for how, memory in (("today", None), ("full", FULL), ("no remat", None)):
+        _, loss, x, stack = _two_layers(kind, remat_on=how != "no remat",
+                                        **more)
+        _device(monkeypatch, memory)
+        fn = _planned(loss)
+        jaxpr = jax.make_jaxpr(fn)(x, stack).jaxpr
+        assert {n for n in _names_in(jaxpr) if n in remat.BASE_NAMES} \
+            == set(names)
+        text = str(jaxpr)
+        assert sorted(re.findall(r"name=(\w+_(?:fwd|bwd))\b", text)) \
+            == kernels
+        got[how] = jax.jit(fn)(x, stack)
+    for other in ("today", "full"):
+        for a, b in zip(jax.tree.leaves(got[other]),
+                        jax.tree.leaves(got["no remat"])):
+            assert bool(jnp.all(jnp.isfinite(a)))
+            np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                       rtol=2e-5, atol=2e-5)
 
 
 def test_a_growing_budget_keeps_a_growing_prefix_of_the_worth_order():
@@ -443,14 +495,10 @@ def test_a_name_has_to_spare_more_than_keeping_it_costs(monkeypatch):
         assert all(c.work / c.bytes > remat._KEPT_BYTE_MOVES for c in order)
 
 
-def test_the_convolutions_output_is_not_worth_its_bytes_at_the_cell(
-        monkeypatch):
-    """At the hybrid cell's widths, traced as on a TPU (the convolution
-    is its kernel pair: shapes alone, nothing is compiled), the delta
-    layer's ``gdn_mixed`` -- 537 MB of float32 a layer -- stays out of
-    the order: keeping it would spare one pass of the forward kernel,
-    which takes ``qkvz`` twice (a tile and the positions before it) and
-    reads it once, so it is priced once; ``gdn_qkvz``, a product, is in."""
+def _the_cells_delta_layer(monkeypatch):
+    """The forward jaxpr of one delta layer at the hybrid cell's widths,
+    traced as on a TPU (the rule and the convolution are their kernel
+    pairs: shapes alone, nothing is compiled)."""
     monkeypatch.undo()
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     cfg = TransformerConfig(**{
@@ -461,10 +509,20 @@ def test_the_convolutions_output_is_not_worth_its_bytes_at_the_cell(
         lambda: init_stack(jax.random.PRNGKey(0), cfg, "gdn", "dense", 1))
     x = jax.ShapeDtypeStruct((2, 8192, 2048), jnp.bfloat16)
     positions = jnp.zeros((2, 8192), jnp.int32)
-    jaxpr = jax.make_jaxpr(
+    return jax.make_jaxpr(
         lambda x, lp: apply_layer(x, lp, positions, cfg))(
             x, jax.tree.map(lambda a: jax.ShapeDtypeStruct(
                 a.shape[1:], a.dtype), stack))
+
+
+def test_the_convolutions_output_is_not_worth_its_bytes_at_the_cell(
+        monkeypatch):
+    """At the hybrid cell's widths the delta layer's ``gdn_mixed`` -- 537
+    MB of float32 a layer -- stays out of the order: keeping it would
+    spare one pass of the forward kernel, which takes ``qkvz`` twice (a
+    tile and the positions before it) and reads it once, so it is priced
+    once; ``gdn_qkvz``, a product, is in."""
+    jaxpr = _the_cells_delta_layer(monkeypatch)
     assert "causal_conv_fwd" in str(jaxpr)
     survey = remat.survey(jaxpr.jaxpr)
     assert survey.names["gdn_mixed"] == 2 * 8192 * 16 * 512 * 4
@@ -472,11 +530,42 @@ def test_the_convolutions_output_is_not_worth_its_bytes_at_the_cell(
     assert "gdn_qkvz" in ordered and "gdn_mixed" not in ordered
 
 
+def test_the_rules_two_names_are_stack_bytes_and_no_candidate_at_the_cell(
+        monkeypatch):
+    """At the hybrid cell's widths the rule's ``o`` (134 MB a layer) and
+    the state entering each grid step of 8 chunks (67 MB) are counted in
+    what a delta layer's run stacks whatever the plan, beside its carry;
+    no plan is offered them; and with them kept the forward kernel is not
+    among what the backward makes again, while what feeds its operands
+    (the convolution's kernel) still is."""
+    jaxpr = _the_cells_delta_layer(monkeypatch)
+    assert "gated_delta_fwd" in str(jaxpr)
+    carry = 2 * 8192 * 2048 * 2
+    survey = remat.survey(jaxpr.jaxpr, layers=3, carry_bytes=carry)
+    o, steps = 2 * 32 * 8192 * 128 * 2, 2 * 32 * (8192 // 64 // 8) * 128 \
+        * 128 * 4
+    assert (o, steps) == (134217728, 67108864)
+    assert survey.stack_bytes == carry + o + steps
+    assert not set(survey.names) & set(remat.BASE_NAMES)
+    assert {c.name for c in remat.worth_order([survey])} \
+        >= {"gdn_qkvz", "gdn_ba"}
+    for kept in ((), tuple(survey.names)):
+        again = survey.recomputed(kept)
+        kernels = [e for e in again if e.prim == "pallas_call"]
+        # (the rule's kernel is the one with a [Dk, Dv] state among its
+        # results)
+        assert not any(v.bytes == steps for e in kernels for v in e.outs)
+        assert bool(kernels) == (kept == ())
+    # under a dp x sp mesh: a device's share
+    shared = remat.survey(jaxpr.jaxpr, carry_bytes=carry, shards=2)
+    assert shared.stack_bytes == (carry + o + steps) // 2
+
+
 def test_every_named_cut_point_is_a_candidate_some_survey_sees():
     """Every ``checkpoint_name`` literal in ``models/`` and ``ops/`` is
-    a candidate in the survey of some kind of layer here (the flash
-    kernel's two are ``BASE_NAMES``, not literals): a name that no
-    survey sees is never kept."""
+    a candidate in the survey of some kind of layer here (the kernels'
+    own are ``BASE_NAMES``, not literals): a name that no survey sees is
+    never kept."""
     literals = set()
     for part in ("models", "ops"):
         folder = os.path.join(ROOT, "ray_tpu", part)
@@ -495,7 +584,7 @@ def test_every_named_cut_point_is_a_candidate_some_survey_sees():
     for kind in KINDS:
         seen |= set(_survey_of(kind).names)
     assert literals - seen == set()
-    assert not seen & set(RESIDUAL_NAMES)
+    assert not seen & set(remat.BASE_NAMES)
 
 
 STEPS = ("dense", "block_diffusion", "latent", "hybrid", "sambay",
