@@ -31,8 +31,8 @@ from typing import Dict
 import jax
 import jax.numpy as jnp
 
-from ray_tpu.models.transformer import (TransformerConfig, _rms_norm,
-                                        norm_weight, run_layers,
+from ray_tpu.models.common import _rms_norm, norm_weight
+from ray_tpu.models.transformer import (TransformerConfig, run_layers,
                                         with_balance_loss)
 from ray_tpu.ops.attention_mask import BlockDiffusion
 from ray_tpu.util import tracing

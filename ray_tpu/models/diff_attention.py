@@ -18,13 +18,13 @@ query heads and ``K`` K/V heads of ``Dh`` (both even), with biases:
     out         = [o_0 ... o_{H/2-1}] wo + bo
 
 No positional encoding of any kind, and no ``tp`` or ``sp`` layout yet
-(``models/transformer.py`` refuses such a mesh for the kind).  The two maps are two calls of the
-flash kernel pair (``ops/flash_attention.py``: ``H / 2`` query heads of
+(the kind refuses such a mesh).  The two maps are two calls of the flash
+kernel pair (``ops/flash_attention.py``: ``H / 2`` query heads of
 ``Dh`` over ``K / 2`` key heads of ``Dh`` and value heads of ``2 Dh``,
 the widths the latent-attention path already gives the kernels); one
 call over all ``H`` heads would have to hold ``V`` twice in HBM.
 
-What a run's options say (``models/transformer.py``):
+What a run's options say (``kinds.run_options``):
 
 * ``window=w``: the mask is ``SlidingWindow(w)``, else causal;
 * ``writes=kv``: the layer also hands ``(k1, k2, V)`` to later layers;
@@ -35,13 +35,14 @@ What a run's options say (``models/transformer.py``):
 
 from __future__ import annotations
 
-from typing import Dict, Optional, Tuple
+from typing import Dict
 
 import jax
 import jax.numpy as jnp
 from jax.ad_checkpoint import checkpoint_name
-from jax.sharding import PartitionSpec as P
 
+from ray_tpu.models.common import (LayerCall, LayerKind, on_one_device,
+                                   replicated, stacked_normal)
 from ray_tpu.ops.attention_mask import CAUSAL, SlidingWindow
 from ray_tpu.ops.flash_attention import attention as flash_or_ref_attention
 
@@ -53,19 +54,15 @@ def lambda_init(index):
     return 0.8 - 0.6 * jnp.exp(-0.3 * jnp.asarray(index, jnp.float32))
 
 
-def init_diff_params(rng: jax.Array, n_layers: int, cfg, cross: bool) -> Dict:
+def _init(key: jax.Array, n_layers: int, cfg, options: Dict) -> Dict:
     """Matrices N(0, 0.02), biases 0, the four lambda vectors N(0, 0.1),
-    ``subln`` 1."""
+    ``subln`` 1.  A run that reads the keys and values projects none."""
     d, h, kv, dh = cfg.d_model, cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
     if h % 2 or kv % 2 or (h // 2) % (kv // 2):
         raise ValueError(f"differential attention pairs heads: {h} query "
                          f"heads over {kv} K/V heads")
-    init = jax.nn.initializers.normal(0.02)
-    keys = jax.random.split(rng, 8)
-    f32 = jnp.float32
-
-    def stacked(key, shape):
-        return init(key, (n_layers, *shape), f32).astype(cfg.dtype)
+    keys = jax.random.split(jax.random.fold_in(key, 13), 8)
+    f32, stacked = jnp.float32, stacked_normal(n_layers, cfg.dtype)
 
     def lam(key):
         return 0.1 * jax.random.normal(key, (n_layers, dh), f32)
@@ -79,23 +76,14 @@ def init_diff_params(rng: jax.Array, n_layers: int, cfg, cross: bool) -> Dict:
         "lambda_q2": lam(keys[6]), "lambda_k2": lam(keys[7]),
         "subln": jnp.ones((n_layers, 2 * dh), f32),
     }
-    if not cross:
+    if "reads" not in options:
         out.update({
             "wk": stacked(keys[1], (d, kv, dh)),
             "bk": jnp.zeros((n_layers, kv, dh), f32),
             "wv": stacked(keys[2], (d, kv, dh)),
             "bv": jnp.zeros((n_layers, kv, dh), f32),
         })
-    return out
-
-
-def diff_param_specs(cross: bool) -> Dict:
-    """Replicated: the kind has no ``tp`` layout yet."""
-    names = ["wq", "bq", "wo", "bo", "lambda_q1", "lambda_k1", "lambda_q2",
-             "lambda_k2", "subln"]
-    if not cross:
-        names += ["wk", "bk", "wv", "bv"]
-    return {name: P() for name in names}
+    return {"diff": out}
 
 
 def _project(u, w, b):
@@ -115,16 +103,17 @@ def keys_and_values(u, lp: Dict):
     return k1, k2, v.reshape(b, s, kv // 2, 2 * dh)
 
 
-def diff_attention(u, lp: Dict, index, window: Optional[int] = None,
-                   kv: Optional[Tuple] = None):
+def _diff(u, lp: Dict, call: LayerCall):
     """The layer's normed input ``u [B, S, d]`` -> (what the layer adds
     to the residual, what it counted -- ``diff_lambda`` --, the keys and
-    values it attended over ``(k1, k2, V)``).  ``index``: the layer's
-    index (``lambda_init`` reads it); ``window``: positions a query
-    sees, its own among them (None: all before it); ``kv``: another
-    layer's keys and values, and then this one projects none."""
-    mask = CAUSAL if window is None else SlidingWindow(window)
-    f32 = jnp.float32
+    values it attended over ``(k1, k2, V)`` where its run writes them).
+    ``lambda_init`` reads the layer's index; the run's ``window``:
+    positions a query sees, its own among them (none: all before it); a
+    run that reads attends over another layer's keys and values."""
+    on_one_device(call)
+    options, lp, f32 = call.options, lp["diff"], jnp.float32
+    mask = SlidingWindow(options["window"]) if "window" in options else CAUSAL
+    kv = call.shared["kv"] if "reads" in options else None
     q1, q2 = _project(u, lp["wq"], lp["bq"])
     q1 = checkpoint_name(q1, "diff_q")
     q2 = checkpoint_name(q2, "diff_q")
@@ -135,7 +124,7 @@ def diff_attention(u, lp: Dict, index, window: Optional[int] = None,
     a1 = flash_or_ref_attention(q1, k1, v, mask=mask)
     a2 = flash_or_ref_attention(q2, k2, v, mask=mask)
     with jax.named_scope("diff_attn"):
-        first = lambda_init(index)
+        first = lambda_init(call.index)
         lam = (jnp.exp(jnp.sum(lp["lambda_q1"] * lp["lambda_k1"]))
                - jnp.exp(jnp.sum(lp["lambda_q2"] * lp["lambda_k2"])) + first)
         o = a1.astype(f32) - lam * a2.astype(f32)
@@ -143,6 +132,12 @@ def diff_attention(u, lp: Dict, index, window: Optional[int] = None,
                               + _SUBLN_EPS) * lp["subln"]
         o = (o * (1.0 - first)).astype(u.dtype)
     out = jnp.einsum("bshk,hkd->bsd", o, lp["wo"]) + lp["bo"]
-    return (out.astype(u.dtype),
-            {"diff_lambda": jax.lax.stop_gradient(lam)}, kv)
+    return (out.astype(u.dtype), {"diff_lambda": jax.lax.stop_gradient(lam)},
+            kv if "writes" in options else None)
+
+
+DIFF = LayerKind(
+    "diff", _init, replicated(_init), _diff,
+    options={"window": int, "writes": ("kv",), "reads": ("kv",)},
+    single_device=True, indexed=True)
 

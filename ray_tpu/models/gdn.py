@@ -55,8 +55,10 @@ import jax.numpy as jnp
 from jax.ad_checkpoint import checkpoint_name
 from jax.sharding import PartitionSpec as P
 
+from ray_tpu.models.common import LayerCall, LayerKind, stacked_normal
 from ray_tpu.ops import causal_conv as conv_op
-# ``models/mamba.py`` and the tests take the plain filter from here
+from ray_tpu.ops.attention_mask import CAUSAL
+# (the tests take the plain filter from here)
 from ray_tpu.ops.causal_conv import causal_conv  # noqa: F401
 
 _L2_EPS = 1e-6
@@ -80,13 +82,9 @@ def init_gdn_params(rng: jax.Array, n_layers: int, d_model: int,
                     g: GDNConfig, dtype) -> Dict:
     """Matrices and taps N(0, 0.02); ``A_log = log U(0, 16)``,
     ``dt_bias`` and ``norm`` 1, as the published modelling code."""
-    init = jax.nn.initializers.normal(0.02)
     keys = jax.random.split(rng, 5)
     hk, r, dk, dv = g.num_key_heads, g.ratio, g.key_head_dim, g.value_head_dim
-
-    def stacked(key, shape):
-        return init(key, (n_layers, *shape), jnp.float32).astype(dtype)
-
+    stacked = stacked_normal(n_layers, dtype)
     return {
         "w_qkvz": stacked(keys[0], (d_model, hk, 2 * dk + 2 * r * dv)),
         "w_ba": stacked(keys[1], (d_model, hk, 2 * r)),
@@ -99,10 +97,10 @@ def init_gdn_params(rng: jax.Array, n_layers: int, d_model: int,
     }
 
 
-def gdn_param_specs() -> Dict:
+def _specs(cfg, options: Dict) -> Dict:
     """Everything that has a key head axis over ``tp`` by it; the output
     norm replicated."""
-    return {
+    return {"gdn": {
         "w_qkvz": P(None, None, "tp", None),
         "w_ba": P(None, None, "tp", None),
         "conv": P(None, "tp", None, None),
@@ -110,7 +108,7 @@ def gdn_param_specs() -> Dict:
         "dt_bias": P(None, "tp", None),
         "norm": P(None, None),
         "wo": P(None, "tp", None, None),
-    }
+    }}
 
 
 def _l2norm(x):
@@ -178,3 +176,17 @@ def gdn_attention(h, lp: Dict, cfg, mesh=None):
         out = jnp.einsum("bshk,hkd->bsd", o.reshape(b, s, hk, r * dv),
                          lp["wo"])
     return out, counted
+
+
+def _init(key: jax.Array, n_layers: int, cfg, options: Dict) -> Dict:
+    return {"gdn": init_gdn_params(jax.random.fold_in(key, 10), n_layers,
+                                   cfg.d_model, cfg.gdn, cfg.dtype)}
+
+
+def _gdn(h, lp: Dict, call: LayerCall):
+    if call.mask != CAUSAL:
+        raise ValueError("a delta layer is causal")
+    return (*gdn_attention(h, lp["gdn"], call.cfg, call.mesh), None)
+
+
+GDN = LayerKind("gdn", _init, _specs, _gdn, needs="gdn")

@@ -30,8 +30,8 @@ A layer whose run says ``writes=memory`` also hands ``y`` -- with the
 A row is one causal sequence: the state and the convolution cross
 whatever separators it holds.  Neither kind has a ``tp`` or ``sp``
 layout yet (the state would have to pass from shard to shard):
-``param_specs`` replicate, ``models/transformer.py`` refuses such a mesh
-for them and the pipeline schedule refuses them.
+the specs replicate, the kinds refuse such a mesh
+(``common.on_one_device``) and the pipeline schedule refuses them.
 """
 
 from __future__ import annotations
@@ -43,10 +43,11 @@ from typing import Dict
 import jax
 import jax.numpy as jnp
 from jax.ad_checkpoint import checkpoint_name
-from jax.sharding import PartitionSpec as P
 
-from ray_tpu.models.gdn import causal_conv
+from ray_tpu.models.common import (LayerCall, LayerKind, on_one_device,
+                                   replicated, stacked_normal)
 from ray_tpu.ops import selective_scan as scan_op
+from ray_tpu.ops.causal_conv import causal_conv
 
 
 @dataclasses.dataclass(frozen=True)
@@ -66,23 +67,18 @@ def rank_of(m: MambaConfig, d_model: int) -> int:
     return m.dt_rank or -(-d_model // 16)
 
 
-def init_mamba_params(rng: jax.Array, n_layers: int, d_model: int,
-                      m: MambaConfig, dtype) -> Dict:
+def _init_mamba(key: jax.Array, n_layers: int, cfg, options: Dict) -> Dict:
     """Matrices and taps N(0, 0.02), ``w_dt`` U(+-R^-1/2); ``A_log =
     log(1 .. N)`` a channel, ``D`` 1, the convolution's bias 0, ``dt_b``
     the inverse softplus of ``exp(U(log dt_min, log dt_max))``: the
     published modelling code's."""
-    init = jax.nn.initializers.normal(0.02)
-    keys = jax.random.split(rng, 6)
+    m, d_model, dtype = cfg.mamba, cfg.d_model, cfg.dtype
+    keys = jax.random.split(jax.random.fold_in(key, 11), 6)
     e, n, r = m.d_inner, m.d_state, rank_of(m, d_model)
-    f32 = jnp.float32
-
-    def stacked(key, shape):
-        return init(key, (n_layers, *shape), f32).astype(dtype)
-
+    f32, stacked = jnp.float32, stacked_normal(n_layers, dtype)
     step = jnp.exp(jax.random.uniform(
         keys[4], (n_layers, e), f32, math.log(m.dt_min), math.log(m.dt_max)))
-    return {
+    return {"mamba": {
         "w_in": stacked(keys[0], (d_model, 2 * e)),
         "conv": stacked(keys[1], (e, m.d_conv)),
         "conv_b": jnp.zeros((n_layers, e), f32),
@@ -94,38 +90,25 @@ def init_mamba_params(rng: jax.Array, n_layers: int, d_model: int,
                                   (n_layers, e, n)),
         "D": jnp.ones((n_layers, e), f32),
         "w_out": stacked(keys[5], (e, d_model)),
-    }
+    }}
 
 
-def init_gmu_params(rng: jax.Array, n_layers: int, d_model: int,
-                    m: MambaConfig, dtype) -> Dict:
-    init = jax.nn.initializers.normal(0.02)
-    k_in, k_out = jax.random.split(rng)
-    return {
-        "w_in": init(k_in, (n_layers, d_model, m.d_inner),
-                     jnp.float32).astype(dtype),
-        "w_out": init(k_out, (n_layers, m.d_inner, d_model),
-                      jnp.float32).astype(dtype),
-    }
+def _init_gmu(key: jax.Array, n_layers: int, cfg, options: Dict) -> Dict:
+    k_in, k_out = jax.random.split(jax.random.fold_in(key, 12))
+    d_model, e = cfg.d_model, cfg.mamba.d_inner
+    stacked = stacked_normal(n_layers, cfg.dtype)
+    return {"gmu": {"w_in": stacked(k_in, (d_model, e)),
+                    "w_out": stacked(k_out, (e, d_model))}}
 
 
-def mamba_param_specs() -> Dict:
-    """Replicated: the kinds have no ``tp`` layout yet."""
-    return {name: P() for name in ("w_in", "conv", "conv_b", "w_x", "w_dt",
-                                   "dt_b", "A_log", "D", "w_out")}
-
-
-def gmu_param_specs() -> Dict:
-    return {"w_in": P(), "w_out": P()}
-
-
-def mamba_mixer(h, lp: Dict, cfg):
+def _mamba(h, lp: Dict, call: LayerCall):
     """The layer's normed input ``h [B, S, d]`` -> (what the Mamba layer
     adds to the residual, what it counted -- ``ssm_scan_fallback_passes``:
     1 where the scan ran as the ``jnp`` scans, ``ssm_delta_mean``: the
-    mean step size -- and the scan's output ``y [B, S, E]`` before the
-    gate: the memory a later layer may read).  ``lp``: this layer's
-    ``mamba`` parameters."""
+    mean step size -- and, where the run writes the memory, the scan's
+    output ``y [B, S, E]`` before the gate)."""
+    on_one_device(call)
+    cfg, lp = call.cfg, lp["mamba"]
     m = cfg.mamba
     e, n, r = m.d_inner, m.d_state, rank_of(m, cfg.d_model)
     f32 = jnp.float32
@@ -162,16 +145,24 @@ def mamba_mixer(h, lp: Dict, cfg):
     with jax.named_scope("ssm_out"):
         gated = (y.astype(f32) * jax.nn.silu(z.astype(f32))).astype(h.dtype)
         out = jnp.einsum("bse,ed->bsd", gated, lp["w_out"])
-    return out, counted, y
+    return out, counted, y if "writes" in call.options else None
 
 
-def gmu_mixer(h, lp: Dict, memory, cfg):
-    """The layer's normed input and the memory ``[B, S, E]`` it reads ->
-    what the Gated Memory Unit adds to the residual."""
-    f32 = jnp.float32
+def _gmu(h, lp: Dict, call: LayerCall):
+    """The layer's normed input -> what the Gated Memory Unit adds to
+    the residual: the memory ``[B, S, E]`` it reads, gated."""
+    on_one_device(call)
+    lp, memory, f32 = lp["gmu"], call.shared["memory"], jnp.float32
     with jax.named_scope("gmu"):
         gate = checkpoint_name(jnp.einsum("bsd,de->bse", h, lp["w_in"]),
                                "gmu_gate")
         gated = (memory.astype(f32)
                  * jax.nn.silu(gate.astype(f32))).astype(h.dtype)
-        return jnp.einsum("bse,ed->bsd", gated, lp["w_out"])
+        return jnp.einsum("bse,ed->bsd", gated, lp["w_out"]), {}, None
+
+
+MAMBA = LayerKind("mamba", _init_mamba, replicated(_init_mamba), _mamba,
+                  options={"writes": ("memory",)}, needs="mamba",
+                  single_device=True)
+GMU = LayerKind("gmu", _init_gmu, replicated(_init_gmu), _gmu,
+                implied={"reads": "memory"}, needs="mamba", single_device=True)

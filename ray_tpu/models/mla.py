@@ -38,6 +38,10 @@ import jax.numpy as jnp
 from jax.ad_checkpoint import checkpoint_name
 from jax.sharding import PartitionSpec as P
 
+from ray_tpu.models.common import (LayerCall, LayerKind, RopeTable, _rms_norm,
+                                   _rope, stacked_normal)
+from ray_tpu.ops.flash_attention import attention
+
 
 @dataclasses.dataclass(frozen=True)
 class MLAConfig:
@@ -49,16 +53,12 @@ class MLAConfig:
     rope_interleave: bool = True
 
 
-def init_mla_params(rng: jax.Array, n_layers: int, d_model: int,
-                    n_heads: int, m: MLAConfig, dtype) -> Dict:
-    init = jax.nn.initializers.normal(0.02)
-    keys = jax.random.split(rng, 5)
+def _init(key: jax.Array, n_layers: int, cfg, options: Dict) -> Dict:
+    m, d_model, n_heads = cfg.mla, cfg.d_model, cfg.n_heads
+    keys = jax.random.split(jax.random.fold_in(key, 9), 5)
     dn, dr, dv = m.qk_nope_head_dim, m.qk_rope_head_dim, m.v_head_dim
-
-    def stacked(key, shape):
-        return init(key, (n_layers, *shape), jnp.float32).astype(dtype)
-
-    return {
+    stacked = stacked_normal(n_layers, cfg.dtype)
+    return {"mla": {
         "wq_a": stacked(keys[0], (d_model, m.q_lora_rank)),
         "q_norm": jnp.ones((n_layers, m.q_lora_rank), jnp.float32),
         "wq_b": stacked(keys[1], (m.q_lora_rank, n_heads, dn + dr)),
@@ -66,14 +66,14 @@ def init_mla_params(rng: jax.Array, n_layers: int, d_model: int,
         "kv_norm": jnp.ones((n_layers, m.kv_lora_rank), jnp.float32),
         "wkv_b": stacked(keys[3], (m.kv_lora_rank, n_heads, dn + dv)),
         "wo": stacked(keys[4], (n_heads, dv, d_model)),
-    }
+    }}
 
 
-def mla_param_specs() -> Dict:
+def _specs(cfg, options: Dict) -> Dict:
     """The up-projections and the output projection by heads over
     ``tp``; the two down-projections and their norms replicated (a
     latent is whole on every shard)."""
-    return {
+    return {"mla": {
         "wq_a": P(None, None, None),
         "q_norm": P(None, None),
         "wq_b": P(None, None, "tp", None),
@@ -81,23 +81,21 @@ def mla_param_specs() -> Dict:
         "kv_norm": P(None, None),
         "wkv_b": P(None, None, "tp", None),
         "wo": P(None, "tp", None, None),
-    }
+    }}
 
 
 def rope(x, positions, theta: float, interleave: bool):
     """x [B, S, H, R] -> the rotated pairs, de-interleaved."""
-    from ray_tpu.models.transformer import RopeTable, _rope
     if interleave:
         pairs = x.reshape(*x.shape[:-1], x.shape[-1] // 2, 2)
         x = jnp.concatenate([pairs[..., 0], pairs[..., 1]], axis=-1)
     return _rope(x, positions, RopeTable(theta))
 
 
-def mla_attention(h, lp: Dict, positions, cfg, mesh=None, mask=None):
-    """The layer's normed input ``h [B, S, d]`` -> what attention adds
-    to the residual.  ``lp``: this layer's ``mla`` parameters."""
-    from ray_tpu.models.transformer import _rms_norm
-    from ray_tpu.ops.flash_attention import attention
+def _mla(h, lp: Dict, call: LayerCall):
+    """The layer's normed input ``h [B, S, d]`` -> (what attention adds
+    to the residual, nothing counted, nothing handed on)."""
+    cfg, mesh, positions, lp = call.cfg, call.mesh, call.positions, lp["mla"]
     m, eps = cfg.mla, cfg.norm_eps
     if (cfg.context_parallel and mesh is not None
             and mesh.shape.get("sp", 1) > 1):
@@ -130,6 +128,10 @@ def mla_attention(h, lp: Dict, positions, cfg, mesh=None, mask=None):
         kv = checkpoint_name(jnp.einsum("bsr,rhk->bshk", c_kv, lp["wkv_b"]),
                              "mla_kv")
         k_nope, v = kv[..., :dn], kv[..., dn:]
-    o = attention(q_nope, k_nope, v, mask=mask, q_rope=q_rope, k_rope=k_rope)
+    o = attention(q_nope, k_nope, v, mask=call.mask, q_rope=q_rope,
+                  k_rope=k_rope)
     with jax.named_scope("mla_out"):
-        return jnp.einsum("bshk,hkd->bsd", o, lp["wo"])
+        return jnp.einsum("bshk,hkd->bsd", o, lp["wo"]), {}, None
+
+
+MLA = LayerKind("mla", _init, _specs, _mla, needs="mla")

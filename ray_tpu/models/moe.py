@@ -90,6 +90,8 @@ import jax.numpy as jnp
 from jax.ad_checkpoint import checkpoint_name
 from jax.sharding import PartitionSpec as P
 
+from ray_tpu.models.common import LayerCall, LayerKind, stacked_normal
+
 _CHUNK_ALIGN = 256
 #: The share of blocks of alike tokens a chunk may fall short of.
 _ALIKE_TAIL = 0.01
@@ -101,12 +103,8 @@ def init_moe_params(rng: jax.Array, n_layers: int, d_model: int,
     """Router over all ``n_experts``; weights of the ``n_held`` held;
     a shared expert of ``shared_width`` where that is not 0, with its
     gate ``wsg`` [d_model, 1] under ``shared_gate``."""
-    init = jax.nn.initializers.normal(0.02)
     keys = jax.random.split(rng, 4)
-
-    def stacked(key, shape):
-        return init(key, (n_layers, *shape), jnp.float32).astype(dtype)
-
+    stacked = stacked_normal(n_layers, dtype)
     params = {
         "wr": stacked(keys[0], (d_model, n_experts)),
         "w1": stacked(keys[1], (n_held, d_model, d_ff)),
@@ -568,3 +566,49 @@ def moe_ffn_sharded(x: jax.Array, lp: Dict, top_k: int, norm_topk: bool,
                     "expert_load": P("ep"), "router_load": P(),
                     "router_prob": P(), "tokens": P(), "choices": placed}),
         check_vma=False)(x, lp["wr"], lp["w1"], lp["w3"], lp["w2"], *bias)
+
+
+def _init(key: jax.Array, n_layers: int, cfg, options: Dict) -> Dict:
+    held = cfg.moe_experts_held or (0, cfg.moe_experts)
+    return {"moe": init_moe_params(
+        jax.random.fold_in(key, 8), n_layers, cfg.d_model,
+        cfg.moe_d_ff or cfg.d_ff, cfg.moe_experts, held[1], cfg.dtype,
+        cfg.moe_shared_width, cfg.moe_shared_gate)}
+
+
+def _specs(cfg, options: Dict) -> Dict:
+    return {"moe": moe_param_specs(shared=cfg.moe_shared_width > 0,
+                                   shared_gate=cfg.moe_shared_gate)}
+
+
+def _moe_block(h, lp: Dict, call: LayerCall):
+    """The expert layer on [B, S, D] -> (y, what it counted, None): with
+    ``_init`` and ``_specs`` what reads the flat ``moe_*`` fields."""
+    cfg, mesh, lp = call.cfg, call.mesh, lp["moe"]
+    router = dict(scoring=cfg.moe_scoring, route_scale=cfg.moe_route_scale,
+                  alike_tail=cfg.moe_alike_tail)
+    if mesh is not None and mesh.shape.get("ep", 1) > 1:
+        if cfg.moe_experts_held is not None:
+            raise ValueError("moe_experts_held is one chip's share; an "
+                             "ep mesh shares the experts itself")
+        y, stats = moe_ffn_sharded(h, lp, cfg.moe_top_k, cfg.moe_norm_topk,
+                                   mesh, **router)
+    else:
+        y, stats = moe_ffn(h, lp, cfg.moe_top_k, cfg.moe_norm_topk,
+                           held=cfg.moe_experts_held, **router)
+    gated = {}
+    if cfg.moe_shared_width:
+        # once, whoever holds which experts
+        shared = shared_expert(h, lp)
+        if cfg.moe_shared_gate:
+            gate = shared_gate(h, lp)
+            shared = shared * gate
+            gated["moe_shared_gate_mean"] = jnp.mean(gate.astype(jnp.float32))
+        y = y + shared
+    counted = {**counters(stats, with_load="bias" in lp), **gated}
+    if cfg.moe_report_choices:
+        counted["moe_choices"] = stats["choices"]
+    return y, counted, None
+
+
+MOE = LayerKind("moe", _init, _specs, _moe_block, needs="moe_experts")

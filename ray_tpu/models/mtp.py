@@ -31,34 +31,11 @@ from typing import Dict
 
 import jax
 import jax.numpy as jnp
-from jax.sharding import PartitionSpec as P
 
-from ray_tpu.models.transformer import (TransformerConfig, _rms_norm,
-                                        embed_tokens, init_stack,
+from ray_tpu.models.common import _rms_norm
+from ray_tpu.models.transformer import (TransformerConfig, embed_tokens,
                                         reduce_counters, run_stack,
-                                        run_stacks, stack_specs,
-                                        with_balance_loss)
-
-
-def init_mtp_params(rng: jax.Array, cfg: TransformerConfig) -> Dict:
-    d = cfg.d_model
-    k_proj, k_layer = jax.random.split(rng)
-    attention, ffn, _ = cfg.layer_pattern[-1]
-    return {
-        "hnorm": jnp.ones((d,), jnp.float32),
-        "enorm": jnp.ones((d,), jnp.float32),
-        "w_eh": jax.nn.initializers.normal(0.02)(
-            k_proj, (2 * d, d), jnp.float32).astype(cfg.dtype),
-        # a stack of one: it runs as the pattern's runs do
-        "layers": init_stack(k_layer, cfg, attention, ffn, 1),
-        "ln_f": jnp.ones((d,), jnp.float32),
-    }
-
-
-def mtp_param_specs(cfg: TransformerConfig) -> Dict:
-    attention, ffn, _ = cfg.layer_pattern[-1]
-    return {"hnorm": P(None), "enorm": P(None), "w_eh": P(None, None),
-            "layers": stack_specs(cfg, attention, ffn), "ln_f": P(None)}
+                                        run_stacks, with_balance_loss)
 
 
 def _cross_entropy(x, norm, head, targets, eps, weight=None):

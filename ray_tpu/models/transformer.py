@@ -12,37 +12,29 @@ graft entry exercise):
     activation sharding between blocks.
   * ``lax.scan`` over stacked layer params — one compilation regardless
     of depth; optional ``jax.checkpoint`` rematerialisation that keeps
-    each layer's input, the flash kernel's ``out`` and ``lse`` and as
-    many of the layer's named products as the step's plan found room
-    for on the device (``remat_layer``, ``models/remat.py``), and
-    recomputes the rest in the backward pass.
+    what the step's plan found room for on the device (``remat_layer``,
+    ``models/remat.py``) and recomputes the rest in the backward pass.
   * bf16 activations/params with f32 RMSNorm + softmax + Adam moments.
   * A model is a LAYER PATTERN (``TransformerConfig.layer_pattern``):
     runs of layers of one kind, each run one scanned stack with its own
-    ``remat_layer``.  A kind is an attention module (``"mha"``: rotary
-    multi-head / grouped attention, below; ``"mla"``: latent attention,
-    ``models/mla.py``) and an FFN module (``"dense"``: SwiGLU, below;
-    ``"moe"``: the expert layer, ``models/moe.py``), each with its own
-    parameters and its own code: a model pays for the kinds it names.
-    With one run ``params["layers"]`` is that stack's tree (as it always
+    ``remat_layer``.  A kind is an attention module and an FFN module,
+    each with its own parameters and its own code in its own file: a
+    model pays for the kinds it names.  ``models/kinds.py`` lists them,
+    name -> the record the kind's module ends in
+    (``common.LayerKind``), and this file asks that table: it names no
+    kind.  With one run ``params["layers"]`` is that stack's tree (as it always
     was); with several it is a tuple of them, in order.  An entry of the
     pattern may also be a PERIOD, ``((run, run, ...), repeats)``: the
     runs in turn, that many times over, as one scan over periods whose
     body is the runs' own scans (a 3 : 1 pattern at 48 layers is one
     compiled body, not 24).  Its tree is a tuple of its runs' stacks,
-    each with the periods as a further leading axis.  ``"gdn"`` is the
-    third attention kind: Gated DeltaNet's linear attention,
-    ``models/gdn.py``.  ``"mamba"`` and ``"gmu"`` (``models/mamba.py``:
-    a selective state-space mixer, and the unit that gates an earlier
-    one's scan output) and ``"diff"`` (``models/diff_attention.py``:
-    differential attention) are the others.
+    each with the periods as a further leading axis.
   * A run may say more than its kind, ``"kind:option,option"``
-    (``run_options``): ``window=512`` (a ``diff`` layer's mask),
-    ``writes=memory`` (a ``mamba`` run hands its last layer's scan
-    output to the runs after it), ``writes=kv`` / ``reads=kv`` (a
-    ``diff`` run hands on its keys and values / attends over those it
-    is handed and projects none); a ``gmu`` run reads the memory.  What
-    is handed on lives in a SHARED SLOT that ``run_stacks`` threads
+    (``kinds.run_options``; the kind's record says which): a window, a
+    count of heads, a rotary table, or ``writes=<slot>`` /
+    ``reads=<slot>``: its last layer hands something to the runs after
+    it / its layers read what an earlier run handed on.  What is handed
+    on lives in a SHARED SLOT that ``run_stacks`` threads
     beside ``x``: computed once in the forward pass, kept for its
     readers, their cotangents summed into the writer.  A run that reads
     a slot no earlier run wrote is refused when the configuration is
@@ -51,85 +43,21 @@ graft entry exercise):
 
 from __future__ import annotations
 
-import contextlib
 import dataclasses
-import functools
-import math
-from typing import Any, Dict, Optional, Tuple
+from typing import Any, Dict, Optional, Tuple, Union
 
 import jax
 import jax.numpy as jnp
-import numpy as np
 from jax.ad_checkpoint import checkpoint_name
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from ray_tpu.models import remat
-from ray_tpu.ops.attention_mask import CAUSAL, SlidingWindow
-from ray_tpu.ops.flash_attention import attention as flash_or_ref_attention
-from ray_tpu.ops.ring_attention import ring_attention
+from ray_tpu.models.common import (LayerCall, RopeTable, model_norm,
+                                   norm_start)
+from ray_tpu.models.kinds import ATTENTION, FFN, run_options
+from ray_tpu.models.moe import update_bias
+from ray_tpu.ops.attention_mask import CAUSAL
 from ray_tpu.util import tracing
-
-
-#: The kinds a run of the layer pattern is made of.
-_ATTENTION = ("mha", "mla", "gdn", "mamba", "gmu", "diff")
-_FFN = ("dense", "moe")
-#: What a run of each kind may say beside its kind (``run_options``):
-#: a whole number, a name, or one of the slots the word is about.
-_OPTIONS = {"mamba": {"writes": ("memory",)},
-            "diff": {"window": int, "writes": ("kv",), "reads": ("kv",)},
-            "mha": {"heads": int, "window": int, "rope": str}}
-#: The kinds that run on one device's rows whole: no ``tp``, ``sp`` or
-#: pipeline layout yet.
-SINGLE_DEVICE_KINDS = ("mamba", "gmu", "diff")
-#: The kinds whose layers read their index in the model.
-_INDEXED_KINDS = ("diff",)
-
-
-@dataclasses.dataclass(frozen=True)
-class RopeTable:
-    """One rotary table: what a run of the ``mha`` kind turns q and k by
-    (``TransformerConfig.rope_tables``, named by the run's ``rope=``).
-    ``factor`` > 1 is YaRN (arXiv:2309.00071): with ``d`` the rotated
-    columns and ``f_i = theta ** (-2 i / d)``, the frequencies whose
-    wavelength the ``original_max_position`` positions hold fewer than
-    ``beta_slow`` times are divided by ``factor``, those they hold more
-    than ``beta_fast`` times stay, a linear ramp between; cos and sin
-    are multiplied by ``attention_factor``."""
-    theta: float = 10_000.0
-    #: >0: the first ``rotary_dim`` columns of a head turn (the halves
-    #: of that slice), the others pass.
-    rotary_dim: int = 0
-    factor: float = 1.0
-    original_max_position: int = 0
-    beta_fast: float = 32.0
-    beta_slow: float = 1.0
-    attention_factor: float = 1.0
-
-    def __post_init__(self):
-        if self.factor != 1.0 and self.original_max_position < 1:
-            raise ValueError("a scaled rotary table says which positions "
-                             "it was first trained for")
-
-    def frequencies(self, width: int):
-        """-> the ``width // 2`` angles a position, float32."""
-        half = width // 2
-        if self.factor == 1.0:
-            return jnp.exp(-jnp.log(self.theta) *
-                           jnp.arange(0, half, dtype=jnp.float32) / half)
-        i = np.arange(half, dtype=np.float64)
-        plain = self.theta ** (-i / half)
-
-        def turns_at(turns):
-            # the index whose wavelength the first positions hold
-            # ``turns`` times
-            return width * math.log(self.original_max_position / (
-                2 * math.pi * turns)) / (2 * math.log(self.theta))
-
-        low = max(math.floor(turns_at(self.beta_fast)), 0)
-        high = min(math.ceil(turns_at(self.beta_slow)), width - 1)
-        ramp = np.clip((i - low) / max(high - low, 1e-3), 0.0, 1.0)
-        return jnp.asarray(plain / self.factor * ramp + plain * (1.0 - ramp),
-                           jnp.float32)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -142,11 +70,9 @@ class TransformerConfig:
     max_seq_len: int = 2048
     rope_theta: float = 10_000.0
     dtype: Any = jnp.bfloat16
-    #: Recompute each layer in the backward pass from its input, the
-    #: flash kernel's ``out`` and ``lse`` and what else of the layer the
-    #: device has room to keep (``remat_layer``: planned when the step
-    #: is traced, from the device's memory; nothing to set).  False:
-    #: keep everything.
+    #: Recompute each layer in the backward pass from its input and what
+    #: of it the device has room to keep (``remat_layer``: planned when
+    #: the step is traced; nothing to set).  False: keep everything.
     remat: bool = True
     #: Use ring attention over the "sp" mesh axis when its size > 1.
     context_parallel: bool = True
@@ -221,10 +147,9 @@ class TransformerConfig:
     #: ``k_norm``) scale by ``1 + w`` with ``w`` nought at the start.
     norm_plus_one: bool = False
     #: Entries ``(attention, ffn, count)``, a run of layers of one kind
-    #: (attention ``"mha"`` | ``"mla"`` | ``"gdn"``, ffn ``"dense"`` |
-    #: ``"moe"``), or ``((run, ...), repeats)``, a period of runs;
-    #: ``n_layers`` is then their sum.  None: ``n_layers`` of the one
-    #: kind the other fields describe.
+    #: (names of ``models/kinds.py``), or ``((run, ...), repeats)``, a
+    #: period of runs; ``n_layers`` is then their sum.  None:
+    #: ``n_layers`` of the one kind the other fields describe.
     layer_pattern: Optional[Tuple[Any, ...]] = None
     #: Multi-token-prediction modules after the stack (0 or 1):
     #: ``models/mtp.py``, whose loss hooks in by ``loss_override``.
@@ -249,7 +174,8 @@ class TransformerConfig:
     #: The rotary tables an ``mha`` run may name (``rope=<name>``):
     #: ``((name, RopeTable), ...)`` or a dict.  A run that names none
     #: turns by ``rope_theta`` over ``rotary_dim``.
-    rope_tables: Any = ()
+    rope_tables: Union[Dict[str, RopeTable],
+                       Tuple[Tuple[str, RopeTable], ...]] = ()
 
     def __post_init__(self):
         if not self.n_kv_heads:
@@ -278,26 +204,20 @@ class TransformerConfig:
             object.__setattr__(self, "layer_pattern", pattern)
             object.__setattr__(self, "n_layers",
                                sum(count for _, _, count in runs_of(pattern)))
-        kinds = {kind for attention, ffn, _ in runs_of(self.layer_pattern)
-                 for kind in (run_options(attention)[0], ffn)}
-        if "mla" in kinds and self.mla is None:
-            raise ValueError("an \"mla\" layer needs the mla sizes")
-        if "gdn" in kinds and self.gdn is None:
-            raise ValueError("a \"gdn\" layer needs the gdn sizes")
-        if "moe" in kinds and self.moe_experts < 1:
-            raise ValueError("a \"moe\" layer needs moe_experts")
-        if kinds & {"mamba", "gmu"} and self.mamba is None:
-            raise ValueError("a \"mamba\" or \"gmu\" layer needs the "
-                             "mamba sizes")
+        for attention, ffn, _ in runs_of(self.layer_pattern):
+            for kind in (ATTENTION[run_options(attention)[0]], FFN[ffn]):
+                # (unset: None for a kind's sizes, 0 for a count)
+                if kind.needs and not getattr(self, kind.needs):
+                    what = kind.needs if getattr(self, kind.needs) == 0 \
+                        else f"the {kind.needs} sizes"
+                    raise ValueError(f"a \"{kind.name}\" layer needs {what}")
         if self.norm not in ("rms", "layernorm") or \
                 self.rope not in ("rotary", "none"):
             raise ValueError(f"norm {self.norm!r}, rope {self.rope!r}")
         if self.norm == "layernorm" and self.norm_plus_one:
             raise ValueError("a LayerNorm's weight scales as it is")
         _check_slots(self.layer_pattern)
-        for attention, _, _ in runs_of(self.layer_pattern):
-            if run_options(attention)[0] == "mha":
-                mha_run(self, attention)
+        _check_mesh(self, None)
         if (self.tie_embeddings or self.norm != "rms") and self.mtp_depth:
             raise ValueError("the multi-token-prediction module has its "
                              "own head and RMSNorms")
@@ -331,74 +251,19 @@ def _checked_entry(entry):
             raise ValueError(f"layer pattern period {entry!r}")
         return runs, repeats
     attention, ffn, count = entry
-    if ffn not in _FFN or count < 1:
+    if ffn not in FFN or count < 1:
         raise ValueError(f"layer pattern run {attention!r}, {ffn!r}, {count}")
     run_options(attention)
     return attention, ffn, count
 
 
-def run_options(attention: str) -> Tuple[str, Dict[str, Any]]:
-    """A run's first word, ``"kind"`` or ``"kind:option,option"`` ->
-    (kind, its options): ``window`` an int, ``writes`` / ``reads`` the
-    slot's name.  Anything a kind does not take is refused."""
-    kind, _, rest = str(attention).partition(":")
-    if kind not in _ATTENTION:
-        raise ValueError(f"layer pattern kind {attention!r}: one of "
-                         f"{_ATTENTION}")
-    options: Dict[str, Any] = {}
-    for word in filter(None, rest.split(",")):
-        name, _, value = word.partition("=")
-        takes = _OPTIONS.get(kind, {})
-        if name not in takes or name in options or not value or (
-                isinstance(takes[name], tuple) and value not in takes[name]):
-            raise ValueError(f"a {kind!r} run does not take {word!r}")
-        options[name] = int(value) if takes[name] is int else value
-    if "reads" in options and "writes" in options:
-        raise ValueError(f"{attention!r} reads the slot it writes")
-    if kind == "gmu":
-        options["reads"] = "memory"
-    return kind, options
-
-
-def mha_run(cfg: TransformerConfig, attention: str):
-    """What an ``mha`` run's layers are -> (query heads, the mask they
-    bring or None for the caller's, the rotary table).  A run whose
-    heads the K/V heads do not divide, or that names a table the
-    configuration lacks, is refused by its name."""
-    options = run_options(attention)[1]
-    heads = options.get("heads", cfg.n_heads)
-    if heads < 1 or heads % cfg.n_kv_heads:
-        raise ValueError(f"{attention!r}: {heads} query heads over "
-                         f"{cfg.n_kv_heads} K/V heads")
-    tables = dict(cfg.rope_tables)
-    if "rope" not in options:
-        table = RopeTable(cfg.rope_theta, cfg.rotary_dim)
-    elif options["rope"] in tables:
-        table = tables[options["rope"]]
-    else:
-        raise ValueError(f"{attention!r}: the configuration's rotary "
-                         f"tables are {sorted(tables)}")
-    mask = SlidingWindow(options["window"]) if "window" in options else None
-    return heads, mask, table
-
-
 def _check_mesh(cfg: TransformerConfig, mesh) -> None:
-    """An ``mha`` run's heads split over ``tp`` by its K/V heads (which
-    divide every run's query heads, so a ``tp`` that takes them takes
-    the run); the ring over ``sp`` is causal and multi-head only, and a
-    run it cannot take is refused by its name where the state is laid
-    out for the mesh."""
-    tp, sp = mesh.shape.get("tp", 1), mesh.shape.get("sp", 1)
-    runs = [attention for attention, _, _ in runs_of(cfg.layer_pattern)
-            if run_options(attention)[0] == "mha"]
-    if runs and cfg.n_kv_heads % tp:
-        raise ValueError(f"{cfg.n_kv_heads} K/V heads on tp={tp}")
-    for attention in runs:
-        heads, mask, _ = mha_run(cfg, attention)
-        if sp > 1 and cfg.context_parallel and (
-                mask is not None or heads != cfg.n_kv_heads):
-            raise ValueError(f"{attention!r} on sp={sp}: ring attention "
-                             f"is causal and multi-head only")
+    """Every run's own check (``LayerKind.check``): of the configuration
+    where it is made (``mesh`` None), and of the mesh where the state is
+    laid out for one -- a run it cannot take is refused by its name."""
+    for run, _, _ in runs_of(cfg.layer_pattern):
+        kind, options = run_options(run)
+        ATTENTION[kind].check(LayerCall(cfg, run, options, mesh=mesh))
 
 
 def _check_slots(pattern) -> None:
@@ -432,81 +297,20 @@ def runs_of(pattern):
             yield entry
 
 
-def stacks_of(layers) -> Tuple[Dict, ...]:
-    """``params["layers"]`` as a tuple, one element an entry of the
-    layer pattern: a run's stack, or a period's tuple of stacks."""
-    return (layers,) if isinstance(layers, dict) else tuple(layers)
-
-
 def init_stack(key: jax.Array, cfg: TransformerConfig, attention: str,
                ffn: str, nl: int) -> Dict:
-    """The stacked parameters of ``nl`` layers of one kind."""
-    d, dh, f = cfg.d_model, cfg.head_dim, cfg.d_ff
-    kv = cfg.n_kv_heads
-    init = jax.nn.initializers.normal(0.02)
-    lkeys = jax.random.split(key, 6)
-
-    def stacked(key, shape):
-        return init(key, (nl,) + shape, jnp.float32).astype(cfg.dtype)
-
-    # a norm's weight at the start: 1, or 0 where it scales by 1 + w
-    unit = jnp.zeros if cfg.norm_plus_one else jnp.ones
-    layers: Dict = {
-        "ln1": unit((nl, d), jnp.float32),
-        "ln2": unit((nl, d), jnp.float32),
-    }
+    """The stacked parameters of ``nl`` layers of one kind: the two
+    norms, then what the run's kinds bring (each folds its own constant
+    into the stack's key)."""
+    d = cfg.d_model
+    layers: Dict = {"ln1": norm_start(cfg, (nl, d)),
+                    "ln2": norm_start(cfg, (nl, d))}
     if cfg.norm == "layernorm":
         layers["ln1_b"] = jnp.zeros((nl, d), jnp.float32)
         layers["ln2_b"] = jnp.zeros((nl, d), jnp.float32)
-    run = attention
-    attention, options = run_options(attention)
-    if attention == "mamba":
-        from ray_tpu.models.mamba import init_mamba_params
-        layers["mamba"] = init_mamba_params(jax.random.fold_in(key, 11), nl,
-                                            d, cfg.mamba, cfg.dtype)
-    elif attention == "gmu":
-        from ray_tpu.models.mamba import init_gmu_params
-        layers["gmu"] = init_gmu_params(jax.random.fold_in(key, 12), nl, d,
-                                        cfg.mamba, cfg.dtype)
-    elif attention == "diff":
-        from ray_tpu.models.diff_attention import init_diff_params
-        layers["diff"] = init_diff_params(jax.random.fold_in(key, 13), nl,
-                                          cfg, cross="reads" in options)
-    elif attention == "mla":
-        from ray_tpu.models.mla import init_mla_params
-        layers["mla"] = init_mla_params(jax.random.fold_in(key, 9), nl, d,
-                                        cfg.n_heads, cfg.mla, cfg.dtype)
-    elif attention == "gdn":
-        from ray_tpu.models.gdn import init_gdn_params
-        layers["gdn"] = init_gdn_params(jax.random.fold_in(key, 10), nl, d,
-                                        cfg.gdn, cfg.dtype)
-    else:
-        h = mha_run(cfg, run)[0]
-        layers.update({
-            "wq": stacked(lkeys[0], (d, h, (2 if cfg.attn_out_gate is True
-                                            else 1) * dh)),
-            "wk": stacked(lkeys[1], (d, kv, dh)),
-            "wv": stacked(lkeys[2], (d, kv, dh)),
-            "wo": stacked(lkeys[3], (h, dh, d)),
-        })
-        if cfg.attn_out_gate == "head":
-            layers["wg"] = stacked(jax.random.fold_in(key, 14), (d, h))
-        if cfg.qk_norm:
-            layers["q_norm"] = unit((nl, dh), jnp.float32)
-            layers["k_norm"] = unit((nl, dh), jnp.float32)
-    if ffn == "moe":
-        from ray_tpu.models.moe import init_moe_params
-        held = cfg.moe_experts_held or (0, cfg.moe_experts)
-        layers["moe"] = init_moe_params(
-            jax.random.fold_in(key, 8), nl, d, cfg.moe_d_ff or f,
-            cfg.moe_experts, held[1], cfg.dtype, cfg.moe_shared_width,
-            cfg.moe_shared_gate)
-    else:
-        layers.update({
-            "w1": stacked(lkeys[4], (d, f)),
-            "w3": stacked(lkeys[5], (d, f)),
-            "w2": stacked(jax.random.fold_in(key, 7), (f, d)),
-        })
+    kind, options = run_options(attention)
+    layers.update(ATTENTION[kind].init(key, nl, cfg, options))
+    layers.update(FFN[ffn].init(key, nl, cfg, {}))
     return layers
 
 
@@ -524,8 +328,7 @@ def init_params(rng: jax.Array, cfg: TransformerConfig) -> Dict:
         "embed": init(k_embed, (cfg.vocab_size, d), jnp.float32
                       ).astype(cfg.dtype),
         "layers": layers,
-        "ln_f": (jnp.zeros if cfg.norm_plus_one else jnp.ones)(
-            (d,), jnp.float32),
+        "ln_f": norm_start(cfg, (d,)),
     }
     if cfg.norm == "layernorm":
         params["ln_f_b"] = jnp.zeros((d,), jnp.float32)
@@ -533,8 +336,16 @@ def init_params(rng: jax.Array, cfg: TransformerConfig) -> Dict:
         params["lm_head"] = init(k_head, (d, cfg.vocab_size), jnp.float32
                                  ).astype(cfg.dtype)
     if cfg.mtp_depth:
-        from ray_tpu.models.mtp import init_mtp_params
-        params["mtp"] = init_mtp_params(jax.random.fold_in(rng, 3), cfg)
+        # the multi-token-prediction module (``models/mtp.py`` runs it):
+        # two norms, a projection, one more layer of the pattern's LAST
+        # kind -- a stack of one: it runs as the pattern's runs do -- and
+        # a final norm of its own
+        k_proj, k_layer = jax.random.split(jax.random.fold_in(rng, 3))
+        params["mtp"] = {
+            **{name: jnp.ones((d,), jnp.float32)
+               for name in ("hnorm", "enorm", "ln_f")},
+            "w_eh": init(k_proj, (2 * d, d), jnp.float32).astype(cfg.dtype),
+            "layers": init_stack(k_layer, cfg, *pattern[-1][:2], 1)}
     return params
 
 
@@ -552,52 +363,15 @@ def _init_entry(key, cfg: TransformerConfig, entry):
 
 
 def stack_specs(cfg: TransformerConfig, attention: str, ffn: str) -> Dict:
-    """PartitionSpecs of one kind's stack: Megatron TP on heads and
-    FFN-hidden; MoE expert weights shard over "ep"."""
-    layers: Dict = {
-        "ln1": P(None, None),
-        "ln2": P(None, None),
-    }
+    """PartitionSpecs of one kind's stack: the norms replicated, then
+    the kinds' own (Megatron TP on heads and FFN-hidden; MoE expert
+    weights over "ep")."""
+    layers: Dict = {"ln1": P(None, None), "ln2": P(None, None)}
     if cfg.norm == "layernorm":
         layers["ln1_b"] = layers["ln2_b"] = P(None, None)
-    attention, options = run_options(attention)
-    if attention == "mamba":
-        from ray_tpu.models.mamba import mamba_param_specs
-        layers["mamba"] = mamba_param_specs()
-    elif attention == "gmu":
-        from ray_tpu.models.mamba import gmu_param_specs
-        layers["gmu"] = gmu_param_specs()
-    elif attention == "diff":
-        from ray_tpu.models.diff_attention import diff_param_specs
-        layers["diff"] = diff_param_specs(cross="reads" in options)
-    elif attention == "mla":
-        from ray_tpu.models.mla import mla_param_specs
-        layers["mla"] = mla_param_specs()
-    elif attention == "gdn":
-        from ray_tpu.models.gdn import gdn_param_specs
-        layers["gdn"] = gdn_param_specs()
-    else:
-        layers.update({
-            "wq": P(None, None, "tp", None),
-            "wk": P(None, None, "tp", None),
-            "wv": P(None, None, "tp", None),
-            "wo": P(None, "tp", None, None),
-        })
-        if cfg.attn_out_gate == "head":
-            layers["wg"] = P(None, None, "tp")
-        if cfg.qk_norm:
-            layers["q_norm"] = P(None, None)
-            layers["k_norm"] = P(None, None)
-    if ffn == "moe":
-        from ray_tpu.models.moe import moe_param_specs
-        layers["moe"] = moe_param_specs(shared=cfg.moe_shared_width > 0,
-                                        shared_gate=cfg.moe_shared_gate)
-    else:
-        layers.update({
-            "w1": P(None, None, "tp"),
-            "w3": P(None, None, "tp"),
-            "w2": P(None, "tp", None),
-        })
+    kind, options = run_options(attention)
+    layers.update(ATTENTION[kind].specs(cfg, options))
+    layers.update(FFN[ffn].specs(cfg, {}))
     return layers
 
 
@@ -624,263 +398,51 @@ def param_specs(cfg: TransformerConfig) -> Dict:
     if not cfg.tie_embeddings:
         specs["lm_head"] = P(None, "tp")
     if cfg.mtp_depth:
-        from ray_tpu.models.mtp import mtp_param_specs
-        specs["mtp"] = mtp_param_specs(cfg)
+        attention, ffn, _ = cfg.layer_pattern[-1]
+        specs["mtp"] = {"hnorm": P(None), "enorm": P(None),
+                        "w_eh": P(None, None), "ln_f": P(None),
+                        "layers": stack_specs(cfg, attention, ffn)}
     return specs
 
 
-def batch_spec() -> P:
-    return P("dp", "sp")
-
-
-def _rms_norm(x, w, eps):
-    xf = x.astype(jnp.float32)
-    norm = xf * jax.lax.rsqrt(jnp.mean(xf * xf, axis=-1, keepdims=True) + eps)
-    return (norm * w).astype(x.dtype)
-
-
-def _layer_norm(x, w, b, eps):
-    xf = x.astype(jnp.float32)
-    xf = xf - jnp.mean(xf, axis=-1, keepdims=True)
-    norm = xf * jax.lax.rsqrt(jnp.mean(xf * xf, axis=-1, keepdims=True) + eps)
-    return (norm * w + b).astype(x.dtype)
-
-
-def model_norm(x, tree: Dict, name: str, cfg: TransformerConfig):
-    """The model's norm ``name`` (``ln1``, ``ln2``, ``ln_f``) of
-    ``tree``: RMSNorm, or LayerNorm with the bias ``<name>_b``."""
-    if cfg.norm == "layernorm":
-        return _layer_norm(x, tree[name], tree[name + "_b"], cfg.norm_eps)
-    return _rms_norm(x, norm_weight(tree[name], cfg), cfg.norm_eps)
-
-
-def norm_weight(w, cfg: TransformerConfig):
-    """A model norm's weight as it scales: ``1 + w`` under
-    ``norm_plus_one``."""
-    return w + 1.0 if cfg.norm_plus_one else w
-
-
-def _rope(x, positions, table: RopeTable):
-    # x: [B, S, H, D]; rotate pairs: of all D columns, or of the first
-    # ``table.rotary_dim`` with the others passed through.
-    rotary_dim = table.rotary_dim
-    if rotary_dim and rotary_dim < x.shape[-1]:
-        return jnp.concatenate(
-            [_rope(x[..., :rotary_dim], positions,
-                   dataclasses.replace(table, rotary_dim=0)),
-             x[..., rotary_dim:]], axis=-1)
-    d = x.shape[-1]
-    half = d // 2
-    freqs = table.frequencies(d)
-    angles = positions[..., None].astype(jnp.float32) * freqs  # [B, S, half]
-    cos = jnp.cos(angles)[:, :, None, :]
-    sin = jnp.sin(angles)[:, :, None, :]
-    if table.attention_factor != 1.0:
-        cos, sin = cos * table.attention_factor, sin * table.attention_factor
-    x1, x2 = x[..., :half], x[..., half:]
-    return jnp.concatenate(
-        [x1 * cos - x2 * sin, x1 * sin + x2 * cos], axis=-1).astype(x.dtype)
-
-
-def _attention_core(q, k, v, mesh, cfg: TransformerConfig, mask=CAUSAL):
-    if (cfg.context_parallel and mesh is not None and
-            mesh.shape.get("sp", 1) > 1):
-        if mask != CAUSAL or k.shape[2] != q.shape[2]:
-            raise ValueError("ring attention is causal and multi-head only")
-        fn = jax.shard_map(
-            functools.partial(ring_attention, axis_name="sp", causal=True),
-            mesh=mesh,
-            in_specs=(P("dp", "sp", "tp", None),) * 3,
-            out_specs=P("dp", "sp", "tp", None),
-            check_vma=False)
-        return fn(q, k, v)
-    return flash_or_ref_attention(q, k, v, mask=mask)
-
-
-def _moe_block(h, lp, cfg: TransformerConfig, mesh):
-    """The expert layer on [B, S, D] -> (y, what the layer counted)."""
-    from ray_tpu.models import moe
-    router = dict(scoring=cfg.moe_scoring, route_scale=cfg.moe_route_scale,
-                  alike_tail=cfg.moe_alike_tail)
-    if mesh is not None and mesh.shape.get("ep", 1) > 1:
-        if cfg.moe_experts_held is not None:
-            raise ValueError("moe_experts_held is one chip's share; an "
-                             "ep mesh shares the experts itself")
-        y, stats = moe.moe_ffn_sharded(h, lp, cfg.moe_top_k,
-                                       cfg.moe_norm_topk, mesh, **router)
-    else:
-        y, stats = moe.moe_ffn(h, lp, cfg.moe_top_k, cfg.moe_norm_topk,
-                               held=cfg.moe_experts_held, **router)
-    gated = {}
-    if cfg.moe_shared_width:
-        # once, whoever holds which experts
-        shared = moe.shared_expert(h, lp)
-        if cfg.moe_shared_gate:
-            gate = moe.shared_gate(h, lp)
-            shared = shared * gate
-            gated["moe_shared_gate_mean"] = jnp.mean(gate.astype(jnp.float32))
-        y = y + shared
-    counted = {**moe.counters(stats, with_load="bias" in lp), **gated}
-    if cfg.moe_report_choices:
-        counted["moe_choices"] = stats["choices"]
-    return y, counted
-
-
-def _mha(h, lp, positions, cfg: TransformerConfig, mesh, mask,
-         run: str = "mha"):
-    """Rotary multi-head / grouped attention on the layer's normed
-    input -> (what it adds to the residual, what it counted: the mean
-    output gate where it has one).  ``run``: the run's first word; its
-    heads are ``wq``'s, its window and rotary table ``mha_run``'s."""
-    eps = cfg.norm_eps
-    _, window, table = mha_run(cfg, run)
-    if window is not None:
-        if mask != CAUSAL:
-            raise ValueError(f"{run!r} brings its own mask")
-        mask = window
-    q = jnp.einsum("bsd,dhk->bshk", h, lp["wq"])
-    k = jnp.einsum("bsd,dhk->bshk", h, lp["wk"])
-    # The names here and below are cut points a rematerialised layer may
-    # keep (``models/remat.py``): q, k and v as they enter the kernel.
-    # The projections before a norm carry none: kept, q's cost the
-    # block-diffusion step 38 ms of copies between layouts to spare 16
-    # (PERF.md section 6, PR 38).
-    v = checkpoint_name(jnp.einsum("bsd,dhk->bshk", h, lp["wv"]), "attn_v")
-    if cfg.attn_out_gate is True:
-        q, gate = q[..., :cfg.head_dim], q[..., cfg.head_dim:]
-    if cfg.qk_norm:
-        q = _rms_norm(q, norm_weight(lp["q_norm"], cfg), eps)
-        k = _rms_norm(k, norm_weight(lp["k_norm"], cfg), eps)
-    if cfg.rope == "rotary":
-        q = _rope(q, positions, table)
-        k = _rope(k, positions, table)
-    q = checkpoint_name(q, "attn_q")
-    k = checkpoint_name(k, "attn_k")
-    o = _attention_core(q, k, v, mesh, cfg, mask)
-    counted = {}
-    if cfg.attn_out_gate:
-        # (a window run's gates are counted apart from the others')
-        name = "attn_gate_mean" if window is None else "attn_window_gate_mean"
-        with jax.named_scope("attn_gate"):
-            if cfg.attn_out_gate == "head":
-                gate = checkpoint_name(jnp.einsum(
-                    "bsd,dh->bsh", h, lp["wg"],
-                    preferred_element_type=jnp.float32), "attn_head_gate")
-                gate = jax.nn.sigmoid(gate)[..., None]
-            else:
-                gate = jax.nn.sigmoid(gate.astype(jnp.float32))
-            o = (o.astype(jnp.float32) * gate).astype(o.dtype)
-            counted[name] = jnp.mean(gate)
-    return jnp.einsum("bshk,hkd->bsd", o, lp["wo"]), counted
-
-
-def _dense_ffn(h, lp):
-    gate = jax.nn.silu(checkpoint_name(
-        jnp.einsum("bsd,df->bsf", h, lp["w1"]), "ffn_gate"))
-    up = checkpoint_name(jnp.einsum("bsd,df->bsf", h, lp["w3"]), "ffn_up")
-    return jnp.einsum("bsf,fd->bsd", gate * up, lp["w2"])
-
-
 def apply_layer(x, lp, positions, cfg: TransformerConfig, mesh=None,
-                mask=CAUSAL, kind: Optional[Tuple[str, str]] = None):
-    """One transformer block on [B, S, D] activations with this
-    layer's params ``lp``; returns (x, what the layer counted: nothing
-    for a dense one).  ``kind``: the layer's (attention, ffn) modules;
-    by default the pattern's first run's.  Shared by the scan forward,
-    the block-diffusion and multi-token objectives and the
-    pipeline-parallel stage executor.  (A layer that reads or writes the
-    shared slot runs through ``run_stack``.)"""
-    return _apply_layer(x, lp, positions, cfg, mesh, mask, kind)[:2]
-
-
-def _single_device_mixer(kind, options, h, lp, index, shared, cfg, mesh,
-                         mask):
-    """A ``SINGLE_DEVICE_KINDS`` mixer on the layer's normed input ->
-    (what it adds to the residual, what it counted, what it hands on:
-    None, or the value of the slot its run writes)."""
-    if mask != CAUSAL:
-        raise ValueError(f"a {kind!r} layer brings its own mask")
-    if mesh is not None and max(mesh.shape.get("tp", 1),
-                                mesh.shape.get("sp", 1)) > 1:
-        raise ValueError(f"a {kind!r} layer has no tp or sp layout")
-    if kind == "mamba":
-        from ray_tpu.models.mamba import mamba_mixer
-        y, counted, memory = mamba_mixer(h, lp["mamba"], cfg)
-        return y, counted, memory if "writes" in options else None
-    if kind == "gmu":
-        from ray_tpu.models.mamba import gmu_mixer
-        return gmu_mixer(h, lp["gmu"], shared["memory"], cfg), {}, None
-    from ray_tpu.models.diff_attention import diff_attention
-    y, counted, kv = diff_attention(
-        h, lp["diff"], index, window=options.get("window"),
-        kv=shared["kv"] if "reads" in options else None)
-    return y, counted, kv if "writes" in options else None
-
-
-def _apply_layer(x, lp, positions, cfg: TransformerConfig, mesh=None,
-                 mask=CAUSAL, kind: Optional[Tuple[str, str]] = None,
-                 index=None, shared: Optional[Dict] = None):
-    """``apply_layer`` -> (x, what the layer counted, what it hands to
-    later layers or None).  ``index``: the layer's index in the model;
-    ``shared``: the slots written so far."""
+                mask=CAUSAL, kind: Optional[Tuple[str, str]] = None,
+                index=None, shared: Optional[Dict] = None):
+    """One block on [B, S, D] activations with this layer's params
+    ``lp`` -> (x, what the layer counted, what it hands to later layers
+    or None).  ``kind``: the layer's (attention, ffn), by default the
+    pattern's first run's; ``index``: the layer's index in the model;
+    ``shared``: the slots written so far.  Every objective's scan and
+    the pipeline-parallel stage executor run this one."""
     # The named scopes here and in loss_fn / train_step are metadata
     # only: stable names for a device trace to group time by.
     run, ffn = kind or next(runs_of(cfg.layer_pattern))[:2]
     attention, options = run_options(run)
-    counted, handed_on = {}, None
-    # (a run under a window says so to the device trace: the step's
-    # manifest tells its layers' time from the other ``mha`` runs')
-    windowed = jax.named_scope("mha_window") \
-        if attention == "mha" and "window" in options \
-        else contextlib.nullcontext()
-    with jax.named_scope("attention"), windowed:
+    record = ATTENTION[attention]
+    call = LayerCall(cfg, run, options, positions, mesh, mask, index, shared)
+    with jax.named_scope("attention"), record.run_scope(options):
         h = model_norm(x, lp, "ln1", cfg)
-        if attention in SINGLE_DEVICE_KINDS:
-            y, counted, handed_on = _single_device_mixer(
-                attention, options, h, lp, index, shared, cfg, mesh, mask)
-            x = x + y
-        elif attention == "mla":
-            from ray_tpu.models.mla import mla_attention
-            x = x + mla_attention(h, lp["mla"], positions, cfg, mesh, mask)
-        elif attention == "gdn":
-            from ray_tpu.models.gdn import gdn_attention
-            if mask != CAUSAL:
-                raise ValueError("a delta layer is causal")
-            y, counted = gdn_attention(h, lp["gdn"], cfg, mesh)
-            x = x + y
-        else:
-            y, counted = _mha(h, lp, positions, cfg, mesh, mask, run)
-            x = x + y
-        x = checkpoint_name(x, "mid_residual")
+        y, counted, handed_on = record.apply(h, lp, call)
+        x = checkpoint_name(x + y, "mid_residual")
     with jax.named_scope("ffn"):
         h = model_norm(x, lp, "ln2", cfg)
-        if ffn == "moe":
-            y, moe_counted = _moe_block(h, lp["moe"], cfg, mesh)
-            counted = {**counted, **moe_counted}
-            x = x + y
-        else:
-            x = x + _dense_ffn(h, lp)
+        y, ffn_counted, _ = FFN[ffn].apply(h, lp, call)
+        x = x + y
     if mesh is not None:
         x = jax.lax.with_sharding_constraint(
             x, NamedSharding(mesh, P("dp", "sp", None)))
-    return x, counted, handed_on
+    return x, {**counted, **ffn_counted}, handed_on
 
 
 def remat_layer(layer, cfg: TransformerConfig,
                 kind: Tuple[str, ...] = (), mesh=None):
     """``layer(carry, scanned)`` as a scan over the stacked layers runs
     it: under ``cfg.remat`` its backward pass recomputes the layer from
-    its input, all but what its policy keeps.  Always the flash kernel's
-    ``out`` and ``lse`` (the kernel's call is the dearest thing in a
-    layer per byte it leaves, and its backward kernel reads just these
-    two); beyond them, those of the layer's named cut points that the
-    step's plan found room for on the device, in the order of work
-    avoided per byte (``models/remat.py``; ``make_train_step`` plans,
-    from the step's own trace).  Where nothing plans (a CPU, a trace
-    outside a step) it is those two names.  ``kind`` and ``mesh``: what
-    the plan calls the run, and the mesh its token axes are split over.
-    The jnp and ring attention paths make no such two names and are
-    recomputed from q, k and v."""
+    its input, all but what its policy keeps (``models/remat.py``: the
+    kernels' own residuals always; beyond them those of the layer's
+    named cut points that the step's plan found room for on the device).
+    ``kind`` and ``mesh``: what the plan calls the run, and the mesh its
+    token axes are split over."""
     if not cfg.remat:
         return layer
     return jax.checkpoint(layer, policy=remat.policy(kind, mesh))
@@ -888,7 +450,7 @@ def remat_layer(layer, cfg: TransformerConfig,
 
 def _reads_index(runs) -> bool:
     """Whether a layer of ``runs`` reads its index in the model."""
-    return any(run_options(run[0])[0] in _INDEXED_KINDS for run in runs)
+    return any(ATTENTION[run_options(run[0])[0]].indexed for run in runs)
 
 
 def run_stack(x, stack: Dict, kind, positions, cfg: TransformerConfig,
@@ -909,8 +471,8 @@ def run_stack(x, stack: Dict, kind, positions, cfg: TransformerConfig,
         lp, bias, index = scanned
         if bias is not None:
             lp = dict(lp, moe=dict(lp["moe"], bias=bias))
-        x, counted, handed_on = _apply_layer(x, lp, positions, cfg, mesh,
-                                             mask, kind, index, reads)
+        x, counted, handed_on = apply_layer(x, lp, positions, cfg, mesh,
+                                            mask, kind, index, reads)
         return x, (counted, handed_on)
 
     # (only a kind that reads its index is given one: the others' scans
@@ -961,7 +523,9 @@ def run_stacks(x, layers, positions, cfg: TransformerConfig, mesh=None,
     of each entry counted, stacked by layer]).  The shared slot starts
     empty here and passes from run to run beside ``x``."""
     if pattern is None:
-        pattern, layers = cfg.layer_pattern, stacks_of(layers)
+        # (``params["layers"]``: one run's stack is the tree itself)
+        pattern = cfg.layer_pattern
+        layers = (layers,) if isinstance(layers, dict) else tuple(layers)
     if first_index is None:
         first_index = cfg.first_layer_index
     counted, row, shared = [], 0, {}
@@ -1090,15 +654,9 @@ def make_train_state(rng, cfg: TransformerConfig, mesh=None,
     if mesh is not None:
         _check_mesh(cfg, mesh)
         specs = specs_override or param_specs(cfg)
-        state_specs = {
-            "params": specs,
-            "opt": jax.tree.map(
-                lambda _: P(), opt_state,
-                is_leaf=lambda x: isinstance(x, jnp.ndarray)),
-            "step": P(),
-        }
         # Adam moments mirror the param tree's specs.
-        state_specs["opt"] = _opt_specs(opt_state, specs)
+        state_specs = {"params": specs, "step": P(),
+                       "opt": _opt_specs(opt_state, specs)}
         if "moe_bias" in state:
             state_specs["moe_bias"] = P()
         state = jax.device_put(
@@ -1131,11 +689,7 @@ def make_train_step(cfg: TransformerConfig, tx, mesh=None,
     them.
 
     Under ``cfg.remat``, what the layer scans keep for the backward pass
-    is planned where the step is traced (``remat.value_and_grad``): on a
-    device that reports a memory limit the objective is traced once,
-    forward only, the plan is read from that jaxpr and the device's free
-    bytes, and the jaxpr is differentiated; on one that reports none the
-    objective is differentiated as it always was."""
+    is planned where the step is traced (``remat.value_and_grad``)."""
     plans: Dict = {}      # the plans of this step's traces, by shapes
     kept: Dict = {}       # ... and the last one, as published
 
@@ -1161,7 +715,6 @@ def make_train_step(cfg: TransformerConfig, tx, mesh=None,
         new_state = {"params": new_params, "opt": new_opt,
                      "step": state["step"] + 1}
         if bias:
-            from ray_tpu.models.moe import update_bias
             with jax.named_scope("moe_bias"):
                 counters = dict(counters)
                 new_state["moe_bias"] = update_bias(
@@ -1173,8 +726,7 @@ def make_train_step(cfg: TransformerConfig, tx, mesh=None,
                    **counters}
         return new_state, metrics
 
-    donate = (0,)
-    return _TracedStep(jax.jit(train_step, donate_argnums=donate), kept)
+    return _TracedStep(jax.jit(train_step, donate_argnums=(0,)), kept)
 
 
 class _TracedStep:

@@ -24,11 +24,12 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from ray_tpu.models.transformer import (SINGLE_DEVICE_KINDS,
-                                        TransformerConfig, _rms_norm,
-                                        apply_layer, is_period, norm_weight,
-                                        param_specs, remat_layer,
-                                        run_options, runs_of)
+from ray_tpu.models.common import _rms_norm, norm_weight
+from ray_tpu.models.kinds import ATTENTION, run_options
+from ray_tpu.models.transformer import (TransformerConfig, apply_layer,
+                                        is_period, make_train_state,
+                                        make_train_step, param_specs,
+                                        remat_layer, runs_of)
 
 
 def pp_param_specs(cfg: TransformerConfig) -> Dict:
@@ -53,8 +54,8 @@ def make_pp_loss_fn(cfg: TransformerConfig, mesh, n_micro: int):
     pp = mesh.shape["pp"]
     dp = mesh.shape.get("dp", 1)
     kinds = {run_options(run[0])[0] for run in runs_of(cfg.layer_pattern)}
-    if kinds & set(SINGLE_DEVICE_KINDS) or cfg.tie_embeddings \
-            or cfg.norm != "rms":
+    if any(ATTENTION[kind].single_device for kind in kinds) \
+            or cfg.tie_embeddings or cfg.norm != "rms":
         raise ValueError(
             f"the pipeline schedule runs rotary attention stages with "
             f"RMSNorms and a head of their own: not {sorted(kinds)}, a tied "
@@ -150,7 +151,6 @@ def make_pp_train_step(cfg: TransformerConfig, tx, mesh,
     """Full pipeline-parallel train step: GPipe loss + AD through the
     shard_map (ppermute transposes to the reverse rotation) — the
     shared update rule/metrics come from the transformer factory."""
-    from ray_tpu.models.transformer import make_train_step
     pp_loss = make_pp_loss_fn(cfg, mesh, n_micro)
     return make_train_step(cfg, tx, mesh=mesh, loss_override=pp_loss)
 
@@ -159,7 +159,6 @@ def make_pp_train_state(rng, cfg: TransformerConfig, mesh,
                         learning_rate: float = 3e-4):
     """Train state placed with pp-sharded layer stacks (shared
     optimizer/placement logic; only the layer specs differ)."""
-    from ray_tpu.models.transformer import make_train_state
     specs = param_specs(cfg)
     specs["layers"] = pp_param_specs(cfg)["layers"]
     return make_train_state(rng, cfg, mesh=mesh,

@@ -330,9 +330,9 @@ def test_remat_that_keeps_the_flash_residuals_changes_no_number(
     model (interpreted here; the chip's dispatch) the gradient holds the
     forward kernel once: the policy reaches it through ``run_layers``."""
     if attention == "kernel":
-        from ray_tpu.models import transformer
+        from ray_tpu.models import mha
         from ray_tpu.ops.flash_attention import flash_attention
-        monkeypatch.setattr(transformer, "flash_or_ref_attention",
+        monkeypatch.setattr(mha, "flash_or_ref_attention",
                             functools.partial(flash_attention,
                                               interpret=True))
     got = {}

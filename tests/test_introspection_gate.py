@@ -5,14 +5,17 @@ parity, not just benched after the fact.
 
 The gate itself (``bench_runtime.py --introspection-gate``) runs both
 arms as fresh subprocesses, min-of-k per arm (1-core CI runners bounce
-3-27 ms at this percentile); here it runs with a small burst so tier-1
-stays fast.
+3-27 ms at this percentile).  Tier-1 holds what is exact: the row of one
+run at a small burst, and the gate's logic on stubbed arms; the measured
+ratio is asserted by the test marked ``slow``.
 """
 
 import json
 import os
 import subprocess
 import sys
+
+import pytest
 
 _REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 _BENCH = os.path.join(_REPO, "bench_runtime.py")
@@ -38,44 +41,62 @@ def _clean_env():
     return env
 
 
-def test_introspection_gate_passes():
-    """rc=0 and a well-formed row: ratio <= 1.10, parity in every
-    attempt's arms.  One extra whole-gate retry on top of the gate's
-    internal rounds — compounded, a flake needs ~6 consecutive unlucky
-    min-of-3 draws."""
-    last = None
-    for _ in range(2):
-        out = subprocess.run(
-            [sys.executable, _BENCH, "--introspection-gate",
-             "--n", "150", "--gate-samples", "3",
-             "--gate-retries", "2"],
-            capture_output=True, text=True, timeout=540,
-            env=_clean_env(), cwd=_REPO)
-        last = out
-        if out.returncode == 0:
-            break
-    assert last.returncode == 0, (
-        f"introspection gate failed:\n{last.stdout[-3000:]}\n"
-        f"{last.stderr[-2000:]}")
-    row = None
-    for line in reversed(last.stdout.strip().splitlines()):
+def _run_gate(samples, retries):
+    """``bench_runtime.py --introspection-gate`` at its smallest burst
+    -> (the completed process, its ``introspection_gate`` row or None)."""
+    out = subprocess.run(
+        [sys.executable, _BENCH, "--introspection-gate", "--n", "150",
+         "--gate-samples", str(samples), "--gate-retries", str(retries)],
+        capture_output=True, text=True, timeout=540,
+        env=_clean_env(), cwd=_REPO)
+    for line in reversed(out.stdout.strip().splitlines()):
         try:
             cand = json.loads(line)
         except ValueError:
             continue
         if cand.get("metric") == "introspection_gate":
-            row = cand
-            break
-    assert row is not None, last.stdout[-2000:]
-    assert row["passed"] is True
-    assert row["attempts"][-1]["ratio"] <= row["max_ratio"]
-    assert row["attempts"][-1]["stage_parity"] is True
-    # The striped hot-path locks are present and visible to the
-    # contention profiler (the ISSUE 17 reduction is measured on
-    # exactly these rollups).
+            return out, cand
+    return out, None
+
+
+def test_introspection_gate_row_is_exact_whatever_the_clock_said():
+    """The gate run once, one fresh process an arm: what holds whatever
+    the host's clock read -- a well-formed row (the exit code is the
+    row's ``passed``), stage parity in the attempt, the striped hot-path
+    locks visible to the contention profiler (the ISSUE 17 reduction is
+    measured on exactly these rollups).  The measured half, the ratio
+    itself, is ``test_introspection_gate_passes`` (``slow``); its logic
+    is held by the two stubbed tests below."""
+    out, row = _run_gate(samples=1, retries=0)
+    assert row is not None, (out.stdout[-2000:], out.stderr[-2000:])
+    assert out.returncode == (0 if row["passed"] else 1)
+    assert (row["n"], row["max_ratio"], row["unit"]) == (150, 1.10, "ratio")
+    (attempt,) = row["attempts"]
+    assert len(attempt["armed_runs_ms"]) == len(attempt["unarmed_runs_ms"]) == 1
+    assert attempt["armed_p99_ms"] > 0 and attempt["unarmed_p99_ms"] > 0
+    assert attempt["stage_parity"] is True
     striped = row.get("striped_locks") or {}
     assert "TaskEventBuffer._lock" in striped
     assert "ReferenceCounter._lock" in striped
+
+
+@pytest.mark.slow
+def test_introspection_gate_passes():
+    """rc=0 and ratio <= 1.10 with parity: a p99 ratio of host latencies,
+    which a shared box fails about one whole run in two (ROADMAP D17), so
+    not tier-1's to assert.  One extra whole-gate retry on top of the
+    gate's internal rounds -- compounded, a flake needs ~6 consecutive
+    unlucky min-of-3 draws."""
+    for _ in range(2):
+        out, row = _run_gate(samples=3, retries=2)
+        if out.returncode == 0:
+            break
+    assert out.returncode == 0, (
+        f"introspection gate failed:\n{out.stdout[-3000:]}\n"
+        f"{out.stderr[-2000:]}")
+    assert row["passed"] is True
+    assert row["attempts"][-1]["ratio"] <= row["max_ratio"]
+    assert row["attempts"][-1]["stage_parity"] is True
 
 
 def test_gate_trips_on_broken_stage_parity(monkeypatch):

@@ -264,7 +264,7 @@ def test_the_yarn_table_is_the_formula(width, theta, factor, first, fast,
 
 
 def test_rotary_by_table_scales_cos_and_sin():
-    from ray_tpu.models.transformer import _rope
+    from ray_tpu.models.common import _rope
     x = jax.random.normal(jax.random.PRNGKey(0), (1, 32, 2, 16))
     positions = jnp.arange(32)[None]
     table = RopeTable(5000.0, 8, 8.0, 16, 4.0, 1.0, 1.25)

@@ -391,13 +391,13 @@ def test_pipeline_stage_remat_matches_no_remat(attention, monkeypatch):
     are named once a tick (the forward), under today's twice."""
     import functools
 
-    from ray_tpu.models import remat as remat_plan, transformer
+    from ray_tpu.models import mha, remat as remat_plan, transformer
     from ray_tpu.ops.flash_attention import flash_attention
     from ray_tpu.parallel.mesh import MeshConfig, build_mesh
     from ray_tpu.parallel.pipeline import (make_pp_loss_fn,
                                            make_pp_train_state)
     if attention == "kernel":
-        monkeypatch.setattr(transformer, "flash_or_ref_attention",
+        monkeypatch.setattr(mha, "flash_or_ref_attention",
                             functools.partial(flash_attention,
                                               interpret=True))
     # (at this width no product is dearer to make again than to keep)

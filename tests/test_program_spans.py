@@ -14,7 +14,6 @@ readers that sum a traced window's device time by it.
 
 import ast
 import contextlib
-import functools
 import gc
 import glob
 import json
@@ -31,6 +30,7 @@ import pytest
 import ray_tpu
 from ray_tpu._private.worker import global_worker
 from ray_tpu.util import tracing
+from tiny_steps import _tiny_step
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 if ROOT not in sys.path:
@@ -436,51 +436,6 @@ def test_every_declared_scope_feeds_one_metric():
     for metric in step_scopes.GROUPS:
         assert os.path.exists(os.path.join(
             ROOT, "benchmarks", "layer_metrics", metric + ".py")), metric
-
-
-def _tiny_step(kind):
-    """-> (step, state, batch) of one of the six tiny configurations the
-    tests of the models build, as the benchmark's drivers build them."""
-    import importlib
-
-    import jax
-    import jax.numpy as jnp
-
-    from ray_tpu.models.transformer import (TransformerConfig,
-                                            make_train_state,
-                                            make_train_step)
-    over = None
-    if kind == "dense":
-        cfg = TransformerConfig(vocab_size=128, d_model=64, n_layers=2,
-                                n_heads=4, d_ff=96, max_seq_len=32,
-                                dtype=jnp.float32, remat=True)
-        batch = {"tokens": jnp.zeros((2, 33), jnp.int32)}
-    elif kind == "block_diffusion":
-        import test_block_diffusion as tiny
-        from benchmarks.drivers import trainer_blockdiff_steps as driver
-        from ray_tpu.models import block_diffusion
-        cfg = TransformerConfig(dtype=jnp.float32, **driver._model_kwargs(
-            tiny.CONFIG, tiny.TRAFFIC["seq_len"]))
-        over = functools.partial(block_diffusion.loss_fn, cfg=cfg, block=4)
-        batch = {k: jnp.asarray(v) for k, v in driver.make_batches(
-            tiny.CONFIG, tiny.TRAFFIC, 7)[0].items()}
-    elif kind == "latent":
-        import test_mla_moe_mtp as tiny
-        from ray_tpu.models import mtp
-        cfg = tiny._cfg()
-        over = functools.partial(mtp.loss_fn, cfg=cfg, coeff=0.3)
-        batch = {"tokens": jnp.asarray(tiny._batches(3)[0])}
-    elif kind in ("sambay", "windowed"):
-        tiny = importlib.import_module(
-            "test_phi4_flash" if kind == "sambay" else "test_laguna")
-        cfg = tiny._cfg()
-        batch = {"tokens": jnp.asarray(tiny._batches(3)[0])}
-    else:
-        import test_qwen3_next as tiny
-        cfg = tiny._cfg()
-        batch = {"tokens": jnp.asarray(tiny._batches(3)[0])}
-    state, tx = make_train_state(jax.random.PRNGKey(1), cfg)
-    return make_train_step(cfg, tx, loss_override=over), state, batch
 
 
 ALL, ONCE, OUTSIDE = {"fwd", "bwd", "recompute"}, {"fwd", "bwd"}, {"fwd"}

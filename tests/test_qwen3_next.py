@@ -504,8 +504,8 @@ def test_the_older_cells_steps_are_traced_as_the_parent_traced_them(
     delta rule's kernels changed; nothing else did: the other five
     pass as they stood)."""
     import importlib
-    from ray_tpu.models import gdn, mla, moe, transformer
-    for module in (gdn, mla, moe, transformer):
+    from ray_tpu.models import common, gdn, mha, mla, moe, transformer
+    for module in (common, gdn, mha, mla, moe, transformer):
         monkeypatch.setattr(module, "checkpoint_name", lambda x, name: x)
     monkeypatch.setattr(importlib.import_module(
         "ray_tpu.ops.flash_attention"), "_kept_out", lambda out: out)

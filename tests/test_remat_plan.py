@@ -1,10 +1,10 @@
 """What a rematerialised layer keeps (``ray_tpu/models/remat.py``): the
 planner as a pure function of surveys and a budget; for each kind of
-layer a two-layer scan, and for each of the four tiny steps the step
-itself, whose numbers under a generous plan equal those under today's
-two names and under ``remat=False``; and what the plan costs as a
-count: a planned trace calls the objective, each layer's function and
-each kernel's forward no more often than an unplanned one."""
+layer a two-layer scan whose numbers under a generous plan equal those
+under today's two names and under ``remat=False``, and what the plan
+keeps, refuses and counts.  The six tiny steps themselves, planned
+against unplanned, are ``tests/test_remat_plan_steps.py``: a file of
+their own so that ``--dist loadfile`` can give them another worker."""
 
 import ast
 import collections
@@ -17,7 +17,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from ray_tpu.models import remat, transformer
+from ray_tpu.models import mha, remat, transformer
 from ray_tpu.models.gdn import GDNConfig
 from ray_tpu.models.mamba import MambaConfig
 from ray_tpu.models.mla import MLAConfig
@@ -25,6 +25,8 @@ from ray_tpu.models.transformer import (TransformerConfig, apply_layer,
                                         init_stack, run_stack)
 from ray_tpu.ops import gated_delta
 from ray_tpu.ops.flash_attention import RESIDUAL_NAMES
+from tiny_steps import (FULL, ROOM, RUNS, _device,  # noqa: F401
+                        _tiny_step, every_candidate_that_spares_anything)
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 _BASE = dict(vocab_size=64, d_model=32, n_heads=2, d_ff=48, max_seq_len=32,
@@ -98,24 +100,6 @@ SLOTS = {
         jax.random.normal(jax.random.PRNGKey(7 + i), (2, 32, 1, w))
         for i, w in enumerate((8, 8, 16)))},
 }
-ROOM = (1 << 44, 0)          # a device with room for everything
-FULL = (1 << 20, 1 << 20)    # ... and one that is full already
-
-
-@pytest.fixture(autouse=True)
-def every_candidate_that_spares_anything(monkeypatch):
-    """At these widths (32 columns) no product is dearer to make again
-    than an array is to keep (``_KEPT_BYTE_MOVES``: that takes some 500
-    columns in bfloat16), so the tests order and keep whatever spares
-    any work at all; ``test_a_name_has_to_spare_more_than_keeping_it_
-    costs`` holds the threshold itself, at a cell's widths."""
-    monkeypatch.setattr(remat, "_KEPT_BYTE_MOVES", 0.0)
-
-
-def _device(monkeypatch, memory):
-    monkeypatch.setattr(remat, "device_memory", lambda mesh=None: memory)
-
-
 def _two_layers(kind, remat_on=True, length=32, **more):
     """-> (cfg, loss(x, stack), x, stack): the scan over two layers of
     the kind, as ``run_layers`` runs it."""
@@ -218,7 +202,7 @@ def test_a_generous_plan_keeps_the_interpreted_kernels_residuals_too(
     equal today's within the kernel tests' tolerance."""
     import importlib
     fa = importlib.import_module("ray_tpu.ops.flash_attention")
-    monkeypatch.setattr(transformer, "flash_or_ref_attention",
+    monkeypatch.setattr(mha, "flash_or_ref_attention",
                         functools.partial(fa.flash_attention,
                                           interpret=True))
     forwards = []
@@ -256,7 +240,7 @@ def _survey_of(kind, layers=1, **more):
     run = KINDS[kind][0]
     positions = jnp.zeros(x.shape[:2], jnp.int32)
     one = jax.tree.map(lambda a: a[0], stack)
-    jaxpr = jax.make_jaxpr(lambda x, lp: transformer._apply_layer(
+    jaxpr = jax.make_jaxpr(lambda x, lp: transformer.apply_layer(
         x, lp, positions, cfg, kind=run, index=3,
         shared=SLOTS.get(kind, dict)())[:2])(x, one)
     return remat.survey(jaxpr.jaxpr, layers, run, x.size * 4)
@@ -300,7 +284,7 @@ def _interpreted_kernels(monkeypatch):
     chip's dispatch has them, interpreted."""
     import importlib
     fa = importlib.import_module("ray_tpu.ops.flash_attention")
-    monkeypatch.setattr(transformer, "flash_or_ref_attention",
+    monkeypatch.setattr(mha, "flash_or_ref_attention",
                         functools.partial(fa.flash_attention,
                                           interpret=True))
     monkeypatch.setattr(gated_delta, "gated_delta_rule", functools.partial(
@@ -587,73 +571,12 @@ def test_every_named_cut_point_is_a_candidate_some_survey_sees():
     assert not seen & set(remat.BASE_NAMES)
 
 
-STEPS = ("dense", "block_diffusion", "latent", "hybrid", "sambay",
-         "windowed")
-RUNS = {"dense": ["mha+dense"], "block_diffusion": ["mha+moe"],
-        "latent": ["mla+dense", "mla+moe", "mla+moe"],
-        "hybrid": ["gdn+moe", "mha+moe"],
-        "sambay": ["diff:reads=kv+dense", "diff:window=8+dense",
-                   "diff:writes=kv+dense", "gmu+dense", "mamba+dense",
-                   "mamba:writes=memory+dense"],
-        "windowed": ["mha:heads=6,rope=global+dense",
-                     "mha:heads=6,rope=global+moe",
-                     "mha:heads=9,window=8,rope=local+moe"]}
-
-
-def _counting(monkeypatch, module, name, counts):
-    real = getattr(module, name)
-
-    @functools.wraps(real)
-    def counted(*args, **kwargs):
-        counts[name] += 1
-        return real(*args, **kwargs)
-
-    monkeypatch.setattr(module, name, counted)
-
-
-@pytest.mark.parametrize("kind", STEPS)
-def test_planning_a_step_calls_no_function_more_often(kind, monkeypatch):
-    """The plan's cost as a count, not a clock: tracing a planned step
-    calls each layer's function (``_apply_layer``), the loss
-    (``loss_and_counters`` or the override) and the reader of a layer
-    (``survey``: once a run, Python over a jaxpr that exists) as often
-    as the runs are, and the objective once -- what the unplanned trace
-    does.  A second trace of the same shapes reads no layer again and
-    gets the plan of the first."""
-    from test_program_spans import _tiny_step
-    counts = collections.Counter()
-    _counting(monkeypatch, transformer, "_apply_layer", counts)
-    _counting(monkeypatch, remat, "survey", counts)
-    real = jax.make_jaxpr
-    monkeypatch.setattr(remat.jax, "make_jaxpr", lambda *a, **k: (
-        counts.update(["make_jaxpr"]), real(*a, **k))[1])
-    calls = {}
-    for how, memory in (("today", None), ("planned", ROOM)):
-        _device(monkeypatch, memory)
-        step, state, batch = _tiny_step(kind)
-        counts.clear()
-        step.lower(state, batch)
-        calls[how] = dict(counts)
-    runs = len(RUNS[kind])
-    assert calls["today"] == {"_apply_layer": runs}
-    assert calls["planned"] == {"_apply_layer": runs, "survey": runs,
-                                "make_jaxpr": 1}
-    # the same step traced again (other arguments' weak types, say)
-    counts.clear()
-    first = dict(step._kept)
-    jax.clear_caches()
-    step.lower(state, batch)
-    assert counts == {"_apply_layer": runs, "make_jaxpr": 1}
-    assert {**step._kept, "trace_seconds": 0} == {**first, "trace_seconds": 0}
-
-
 def test_two_mha_runs_of_unequal_shapes_are_two_surveys(monkeypatch):
     """The step whose ``mha`` runs differ (9 heads under a window, 6
     over everything before, a dense and an expert FFN): a survey a run,
     each with its own bytes under the names the runs share, one order
     over all of them, and a budget that holds a part of it refuses the
     rest run by run."""
-    from test_program_spans import _tiny_step
     seen, real = [], remat.survey
 
     def kept(*args, **kwargs):
@@ -692,38 +615,3 @@ def test_two_mha_runs_of_unequal_shapes_are_two_surveys(monkeypatch):
     for r, whole in zip(tight["runs"], plan["runs"]):
         assert {n for n, _ in r["refused"]} | set(r["names"]) == \
             set(whole["names"])
-
-
-@pytest.mark.parametrize("kind", STEPS)
-def test_a_planned_step_is_the_step_without_remat(kind, monkeypatch):
-    """The four tiny steps (dense; block-diffusion experts; latent
-    attention, shared expert and the multi-token module; delta layers
-    beside gated attention in a period) under a plan with room for
-    everything, under today's two names and with ``remat_layer`` taken
-    out: the same loss, gradient norm and counters, to the tolerance of
-    ``tests/test_block_diffusion.py``'s remat test, and the same new
-    parameters to a thirtieth of one AdamW update (a gradient near zero
-    moves its update by more than its own rounding)."""
-    from test_program_spans import _tiny_step
-    got = {}
-    for how in ("planned", "today", "no remat"):
-        _device(monkeypatch, ROOM if how == "planned" else None)
-        if how == "no remat":
-            monkeypatch.setattr(transformer, "remat_layer",
-                                lambda layer, cfg, *a, **k: layer)
-        step, state, batch = _tiny_step(kind)
-        new, metrics = step(state, batch)
-        got[how] = jax.device_get((metrics, new["params"]))
-        if how == "planned":
-            plan = step._kept
-            assert sorted(r["kind"] for r in plan["runs"]) == RUNS[kind]
-            assert all("mid_residual" in r["names"] and r["refused"] == []
-                       for r in plan["runs"])
-        else:
-            assert step._kept == remat.no_plan()
-    for other in ("today", "no remat"):
-        for part, atol in ((0, 1e-6), (1, 1e-5)):
-            for a, b in zip(jax.tree.leaves(got["planned"][part]),
-                            jax.tree.leaves(got[other][part])):
-                assert np.all(np.isfinite(a))
-                np.testing.assert_allclose(a, b, rtol=2e-5, atol=atol)
