@@ -47,8 +47,9 @@ class LayerKind:
     specs: Callable
     #: ``apply(h, lp, call)`` on the layer's normed input and whole tree
     #: -> (what it adds to the residual, what it counted, what it hands
-    #: on: None, or the value of the slot its run writes).
-    apply: Callable
+    #: on: None, or the value of the slot its run writes).  None (an FFN
+    #: kind): the layer is its mixer alone, with no second norm.
+    apply: Optional[Callable]
     #: What a run may say after the kind (``kinds.run_options``: a whole
     #: number, a name, or one of the slots named), and what it implies.
     options: Mapping = dataclasses.field(default_factory=dict)
@@ -217,3 +218,8 @@ DENSE = LayerKind(
     "dense", _dense_init,
     lambda cfg, options: {"w1": P(None, None, "tp"), "w3": P(None, None, "tp"),
                           "w2": P(None, "tp", None)}, _dense_ffn)
+#: The ``"none"`` kind of FFN: a layer that is a mixer alone (Nemotron-H's
+#: ``M`` before an attention layer): no leaves, no second norm, nothing
+#: added.
+NONE = LayerKind("none", lambda key, n_layers, cfg, options: {},
+                 lambda cfg, options: {}, None)

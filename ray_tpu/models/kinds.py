@@ -6,16 +6,18 @@ from __future__ import annotations
 
 from typing import Any, Dict, Tuple
 
-from ray_tpu.models.common import DENSE
+from ray_tpu.models.common import DENSE, NONE
 from ray_tpu.models.diff_attention import DIFF
 from ray_tpu.models.gdn import GDN
 from ray_tpu.models.mamba import GMU, MAMBA
+from ray_tpu.models.mamba2 import MAMBA2
 from ray_tpu.models.mha import MHA
 from ray_tpu.models.mla import MLA
 from ray_tpu.models.moe import MOE
 
-ATTENTION = {kind.name: kind for kind in (MHA, MLA, GDN, MAMBA, GMU, DIFF)}
-FFN = {kind.name: kind for kind in (DENSE, MOE)}
+ATTENTION = {kind.name: kind for kind in (MHA, MLA, GDN, MAMBA, GMU, DIFF,
+                                           MAMBA2)}
+FFN = {kind.name: kind for kind in (DENSE, MOE, NONE)}
 
 
 def run_options(attention: str) -> Tuple[str, Dict[str, Any]]:

@@ -20,9 +20,17 @@ Per layer, with T tokens, N = T * top_k token-choices:
     chunks   = the sorted list cut into chunks of a fixed number of
                rows; the loop ends with the last held choice (the
                backward walks the same chunks: ``_held_experts``)
-    h        = silu(ragged_dot(xs, w1)) * ragged_dot(xs, w3)
+    h        = silu(ragged_dot(xs, w1)) * ragged_dot(xs, w3)   SwiGLU,
+               or relu(ragged_dot(xs, w1))^2        squared ReLU, no w3
     out      = ragged_dot(h, w2)        [rows, D], the products' dtype
     y[tok]  += gate * float32(out)      per chunk: a scatter-add
+The experts are of one form a model (``moe_act``): SwiGLU (two matrices
+in, one out) or gate-less squared ReLU (one in, one out, as Nemotron-H
+has them).  Either form may work in a LATENT narrower than the model
+(``moe_latent``): ``xs`` are then rows of ``x w_down`` and the share's
+sum goes up through ``w_up`` -- a linear map, so the shares of an
+expert-parallel deployment still add up -- while the router and the
+shared expert read ``x`` at the model's width.
 Nothing is dropped whatever the imbalance: the chunks cover all N
 choices, so memory is bounded by the chunk and work follows the number
 of chunks the held choices fill.  The rows return to their tokens by
@@ -30,13 +38,14 @@ one float32 scatter-add a chunk and direction, and that add is the only
 place where a chunk's rows are widened.  The backward is written by hand
 (``_held_experts_bwd``); a chunk of it, with ``dys = dy[tok]`` gathered
 in the tokens' dtype and left in it:
-    h        = silu(ragged_dot(xs, w1)) * ragged_dot(xs, w3)    again
+    h        = the hidden rows again (either form)
     u        = ragged_dot(dys, w2^T)    [rows, F]: ``out`` is NOT made
     d gate   = sum_F float32(h) * float32(u)
     dh, gh   = gate * u, gate * h       one pass over [rows, F]
     d w2     = ragged_dot^T(gh, dys)
-    d xs, d w1, d w3 from dh            four products
-Eleven grouped products a layer (three forward, eight backward).  The
+    d xs, d w1, d w3 from dh            four products (two without w3)
+Eleven grouped products a layer for SwiGLU (three forward, eight
+backward), seven for squared ReLU (two and five).  The
 gate is a scalar a row, so it multiplies AFTER ``dys @ w2^T`` and on the
 F-wide operand of ``w2``'s gradient: the one product serves the hidden
 rows' cotangent and the gates' gradient (``h . u`` is ``out . dys``),
@@ -73,7 +82,7 @@ step ``update_bias`` moves it by ``rate`` towards the experts that step
 loaded least.  A layer that has one also reports every expert's load.
 
 A shared expert (``ws1``/``ws3``/``ws2``, one SwiGLU every token
-passes) is added by ``shared_expert`` OUTSIDE the share's partial sum:
+passes; ``ws1``/``ws2`` in the squared-ReLU form) is added by ``shared_expert`` OUTSIDE the share's partial sum:
 on one chip's share and under an ``ep`` axis alike it counts once.  It
 may have a gate of its own (``wsg`` [D, 1], ``shared_gate``): the token's
 ``sigmoid(x . wsg)`` multiplies its output.
@@ -99,43 +108,58 @@ _ALIKE_TAIL = 0.01
 
 def init_moe_params(rng: jax.Array, n_layers: int, d_model: int,
                     d_ff: int, n_experts: int, n_held: int, dtype,
-                    shared_width: int = 0, shared_gate: bool = False) -> Dict:
-    """Router over all ``n_experts``; weights of the ``n_held`` held;
-    a shared expert of ``shared_width`` where that is not 0, with its
+                    shared_width: int = 0, shared_gate: bool = False,
+                    act: str = "swiglu", latent: int = 0) -> Dict:
+    """Router over all ``n_experts``; weights of the ``n_held`` held
+    (``w3`` under ``act="swiglu"`` alone), in a latent of ``latent``
+    columns between ``w_down`` and ``w_up`` where that is not 0; a
+    shared expert of ``shared_width`` where that is not 0, with its
     gate ``wsg`` [d_model, 1] under ``shared_gate``."""
     keys = jax.random.split(rng, 4)
     stacked = stacked_normal(n_layers, dtype)
+    d_in = latent or d_model
     params = {
         "wr": stacked(keys[0], (d_model, n_experts)),
-        "w1": stacked(keys[1], (n_held, d_model, d_ff)),
-        "w3": stacked(keys[2], (n_held, d_model, d_ff)),
-        "w2": stacked(keys[3], (n_held, d_ff, d_model)),
+        "w1": stacked(keys[1], (n_held, d_in, d_ff)),
+        "w2": stacked(keys[3], (n_held, d_ff, d_in)),
     }
+    if act == "swiglu":
+        params["w3"] = stacked(keys[2], (n_held, d_in, d_ff))
+    if latent:
+        down, up = jax.random.split(jax.random.fold_in(rng, 6))
+        params.update({"w_down": stacked(down, (d_model, latent)),
+                       "w_up": stacked(up, (latent, d_model))})
     if shared_width:
         shared = jax.random.split(jax.random.fold_in(rng, 4), 3)
         params.update({
             "ws1": stacked(shared[0], (d_model, shared_width)),
-            "ws3": stacked(shared[1], (d_model, shared_width)),
             "ws2": stacked(shared[2], (shared_width, d_model)),
         })
+        if act == "swiglu":
+            params["ws3"] = stacked(shared[1], (d_model, shared_width))
         if shared_gate:
             params["wsg"] = stacked(jax.random.fold_in(rng, 5), (d_model, 1))
     return params
 
 
-def moe_param_specs(shared: bool = False, shared_gate: bool = False) -> Dict:
-    """Experts sharded over ``ep``; router replicated; the shared
-    expert's width over ``tp``, replicated over ``ep``; its gate
-    replicated."""
+def moe_param_specs(shared: bool = False, shared_gate: bool = False,
+                    act: str = "swiglu", latent: int = 0) -> Dict:
+    """Experts sharded over ``ep``; router and latent pair replicated;
+    the shared expert's width over ``tp``, replicated over ``ep``; its
+    gate replicated."""
+    gated = act == "swiglu"
     specs = {
         "wr": P(None, None),
         "w1": P(None, "ep", None, None),
-        "w3": P(None, "ep", None, None),
+        **({"w3": P(None, "ep", None, None)} if gated else {}),
         "w2": P(None, "ep", None, None),
     }
+    if latent:
+        specs.update({"w_down": P(None, None, None),
+                      "w_up": P(None, None, None)})
     if shared:
-        specs.update({"ws1": P(None, None, "tp"), "ws3": P(None, None, "tp"),
-                      "ws2": P(None, "tp", None)})
+        specs.update({"ws1": P(None, None, "tp"), "ws2": P(None, "tp", None),
+                      **({"ws3": P(None, None, "tp")} if gated else {})})
         if shared_gate:
             specs["wsg"] = P(None, None, None)
     return specs
@@ -188,18 +212,21 @@ def _chunk_inputs(rows, top_k, n_tokens, order, ends, sizes, start):
     return idx, tok, valid, group
 
 
-def _chunk_hidden(xs, w1, w3, group):
+def _chunk_hidden(xs, hidden, group):
     """[rows, D] sorted token rows -> their experts' hidden rows
-    [rows, F], in the products' dtype."""
-    return jax.nn.silu(jax.lax.ragged_dot(xs, w1, group)) * \
-        jax.lax.ragged_dot(xs, w3, group)
+    [rows, F], in the products' dtype: ``silu(xs w1) . xs w3`` for
+    ``hidden = (w1, w3)``, ``relu(xs w1)^2`` for ``(w1,)``."""
+    if len(hidden) == 2:
+        return jax.nn.silu(jax.lax.ragged_dot(xs, hidden[0], group)) * \
+            jax.lax.ragged_dot(xs, hidden[1], group)
+    return jnp.square(jax.nn.relu(jax.lax.ragged_dot(xs, hidden[0], group)))
 
 
-def _chunk_experts(xs, w1, w3, w2, group):
+def _chunk_experts(xs, ws, group):
     """[rows, D] sorted token rows -> their experts' output, unweighted,
-    in the products' dtype."""
+    in the products' dtype.  ``ws``: the hidden weights, then ``w2``."""
     with jax.named_scope("moe_experts"):
-        return jax.lax.ragged_dot(_chunk_hidden(xs, w1, w3, group), w2,
+        return jax.lax.ragged_dot(_chunk_hidden(xs, ws[:-1], group), ws[-1],
                                   group)
 
 
@@ -238,7 +265,7 @@ def _sorted_choices(chunks, key, gate):
     return jnp.pad(order, pad), jnp.pad(gates, pad)
 
 
-def _forward(chunks, top_k, xt, gate, w1, w3, w2, key, ends, sizes):
+def _forward(chunks, top_k, xt, gate, ws, key, ends, sizes):
     """``_held_experts`` and the sorted list it walked."""
     with jax.named_scope("moe_dispatch"):
         order, gates = _sorted_choices(chunks, key, gate)
@@ -249,7 +276,7 @@ def _forward(chunks, top_k, xt, gate, w1, w3, w2, key, ends, sizes):
             _, tok, valid, group = _chunk_inputs(
                 rows, top_k, xt.shape[0], order, ends, sizes, start)
             xs = _token_rows(xt, tok)
-        out = _chunk_experts(xs, w1, w3, w2, group)
+        out = _chunk_experts(xs, ws, group)
         with jax.named_scope("moe_combine"):
             # rows past the last held choice give nought (their output
             # may be undefined)
@@ -268,24 +295,27 @@ def _forward(chunks, top_k, xt, gate, w1, w3, w2, key, ends, sizes):
 # chunk's rows are widened to float32 once in each direction, where they
 # are added to their tokens.
 @functools.partial(jax.custom_vjp, nondiff_argnums=(0, 1))
-def _held_experts(chunks, top_k, xt, gate, w1, w3, w2, key, ends, sizes):
+def _held_experts(chunks, top_k, xt, gate, ws, key, ends, sizes):
     """-> (y [T, D], summed in float32 and returned in ``xt``'s dtype,
     so that its cotangent arrives in it and is gathered in it; rows
     processed).  ``chunks``: the rows of the first chunk and of each
-    further one; ``gate`` [N] float32 and ``key`` [N], every choice's
+    further one; ``ws``: the experts' weights, ``(w1, w3, w2)`` or
+    ``(w1, w2)`` (``_chunk_hidden``); ``gate`` [N] float32 and ``key``
+    [N], every choice's
     sort key (``_sorted_choices``); ``ends``/``sizes``: the held
     experts' groups in the sorted list."""
-    return _forward(chunks, top_k, xt, gate, w1, w3, w2, key, ends, sizes)[0]
+    return _forward(chunks, top_k, xt, gate, ws, key, ends, sizes)[0]
 
 
-def _held_experts_fwd(chunks, top_k, xt, gate, w1, w3, w2, key, ends, sizes):
-    out, (order, gates) = _forward(chunks, top_k, xt, gate, w1, w3, w2, key,
-                                   ends, sizes)
-    return out, (xt, w1, w3, w2, order, gates, ends, sizes)
+def _held_experts_fwd(chunks, top_k, xt, gate, ws, key, ends, sizes):
+    out, (order, gates) = _forward(chunks, top_k, xt, gate, ws, key, ends,
+                                   sizes)
+    return out, (xt, ws, order, gates, ends, sizes)
 
 
 def _held_experts_bwd(chunks, top_k, res, cotangent):
-    xt, w1, w3, w2, order, gates, ends, sizes = res
+    xt, ws, order, gates, ends, sizes = res
+    w2 = ws[-1]
     dy, _ = cotangent
     f32 = jnp.float32
 
@@ -298,8 +328,8 @@ def _held_experts_bwd(chunks, top_k, res, cotangent):
             dys = _token_rows(dy, tok)
         with jax.named_scope("moe_experts"):
             h, vjp = jax.vjp(
-                lambda xs, w1, w3: _chunk_hidden(xs, w1, w3, group),
-                xs, w1, w3)
+                lambda xs, *hidden: _chunk_hidden(xs, hidden, group),
+                xs, *ws[:-1])
             # ``h @ w2`` itself is not made: its transpose in ``h``, on
             # the tokens' cotangent WITHOUT the gate, serves both the
             # hidden rows' cotangent and the gate's gradient
@@ -322,18 +352,18 @@ def _held_experts_bwd(chunks, top_k, res, cotangent):
         with jax.named_scope("moe_experts"):
             dw2, = jax.linear_transpose(
                 lambda w2: jax.lax.ragged_dot(gh, w2, group), w2)(dys)
-            dxs, dw1, dw3 = vjp(dh)
+            dxs, *dhidden = vjp(dh)
         with jax.named_scope("moe_combine"):
             dxs = jnp.where(valid[:, None], dxs.astype(f32), 0.0)
             return (dxt.at[tok].add(dxs), dgate.at[idx].add(dg),
                     [a + d.astype(f32)
-                     for a, d in zip(dws, (dw1, dw3, dw2))])
+                     for a, d in zip(dws, (*dhidden, dw2))])
 
     zero = (jnp.zeros(xt.shape, f32), jnp.zeros((xt.shape[0] * top_k,), f32),
-            [jnp.zeros(w.shape, f32) for w in (w1, w3, w2)])
+            [jnp.zeros(w.shape, f32) for w in ws])
     dxt, dgate, dws = _over_chunks(chunks, ends[-1], run, zero)
     return (dxt.astype(xt.dtype), dgate.astype(gates.dtype),
-            *(d.astype(w.dtype) for d, w in zip(dws, (w1, w3, w2))),
+            tuple(d.astype(w.dtype) for d, w in zip(dws, ws)),
             None, None, None)
 
 
@@ -380,7 +410,9 @@ def moe_ffn(x: jax.Array, lp: Dict, top_k: int, norm_topk: bool = True,
     (nor the shared expert: ``shared_expert``).
     ``lp`` holds this layer's ``wr`` [D, E] and ``w1``/``w3``/``w2`` of
     the ``count`` experts ``held = (first, count)`` (all by default;
-    ``first`` may be traced).  ``stats``: ``held_choices``,
+    ``first`` may be traced); without ``w3`` the experts are
+    ``relu(x w1)^2 w2``; with ``w_down`` / ``w_up`` they work in that
+    latent, the held share's sum projected up.  ``stats``: ``held_choices``,
     ``expert_load`` [count] (each held expert's number of choices),
     ``dropped_choices`` (held choices less the rows the chunks
     processed: 0 by construction), ``choices`` [..., top_k] (every
@@ -446,8 +478,19 @@ def moe_ffn(x: jax.Array, lp: Dict, top_k: int, norm_topk: bool = True,
         ends = jnp.cumsum(sizes)                               # [count]
         chunks = chunk_rows(T, n_experts, count, top_k, alike_tail)
 
-    y, done = _held_experts(chunks, top_k, xt, gate.reshape(N), lp["w1"],
-                            lp["w3"], lp["w2"], key, ends, sizes)
+        if "w_down" in lp:          # the experts' latent (``moe_latent``)
+            xt = checkpoint_name(jnp.einsum("td,dl->tl", xt, lp["w_down"]),
+                                 "moe_latent")
+
+    ws = tuple(lp[k] for k in ("w1", "w3", "w2") if k in lp)
+    y, done = _held_experts(chunks, top_k, xt, gate.reshape(N), ws, key,
+                            ends, sizes)
+    if "w_up" in lp:
+        with jax.named_scope("moe_combine"):
+            # (``moe_latent_out``: kept, it spares the backward pass the
+            # experts' forward, which ``w_up``'s gradient would make again)
+            y = jnp.einsum("tl,ld->td", checkpoint_name(y, "moe_latent_out"),
+                           lp["w_up"])
     stats = {"held_choices": ends[-1], "expert_load": sizes,
              "dropped_choices": jnp.sum(is_held.astype(jnp.int32)) - done,
              "choices": expert.astype(jnp.int32).reshape(*lead, top_k),
@@ -472,8 +515,14 @@ def balance_loss(stats: Dict) -> jax.Array:
 
 
 def shared_expert(x: jax.Array, lp: Dict) -> jax.Array:
-    """The SwiGLU every token passes, on x [B, S, D]."""
+    """The SwiGLU every token passes, on x [B, S, D]; without ``ws3``
+    ``relu(x ws1)^2 ws2``."""
     with jax.named_scope("moe_shared"):
+        if "ws3" not in lp:
+            up = checkpoint_name(jnp.einsum("bsd,df->bsf", x, lp["ws1"]),
+                                 "moe_shared_up")
+            return jnp.einsum("bsf,fd->bsd", jnp.square(jax.nn.relu(up)),
+                              lp["ws2"])
         gate = jax.nn.silu(checkpoint_name(
             jnp.einsum("bsd,df->bsf", x, lp["ws1"]), "moe_shared_gate"))
         up = checkpoint_name(jnp.einsum("bsd,df->bsf", x, lp["ws3"]),
@@ -533,6 +582,9 @@ def moe_ffn_sharded(x: jax.Array, lp: Dict, top_k: int, norm_topk: bool,
     tokens (``dp`` x ``sp``), and the partial results are summed over
     ``ep``.  x [B, S, D].  The shared expert is not in here: it would
     be summed once a shard."""
+    if "w3" not in lp or "w_down" in lp:
+        raise ValueError("an ep mesh shares SwiGLU experts in the model's "
+                         "width: no other form yet")
     count = lp["w1"].shape[0] // mesh.shape["ep"]
     tokens = ("dp", "sp")
     # the router's correction bias, where the layer has one: replicated
@@ -573,12 +625,14 @@ def _init(key: jax.Array, n_layers: int, cfg, options: Dict) -> Dict:
     return {"moe": init_moe_params(
         jax.random.fold_in(key, 8), n_layers, cfg.d_model,
         cfg.moe_d_ff or cfg.d_ff, cfg.moe_experts, held[1], cfg.dtype,
-        cfg.moe_shared_width, cfg.moe_shared_gate)}
+        cfg.moe_shared_width, cfg.moe_shared_gate, cfg.moe_act,
+        cfg.moe_latent)}
 
 
 def _specs(cfg, options: Dict) -> Dict:
     return {"moe": moe_param_specs(shared=cfg.moe_shared_width > 0,
-                                   shared_gate=cfg.moe_shared_gate)}
+                                   shared_gate=cfg.moe_shared_gate,
+                                   act=cfg.moe_act, latent=cfg.moe_latent)}
 
 
 def _moe_block(h, lp: Dict, call: LayerCall):

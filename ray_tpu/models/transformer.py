@@ -99,6 +99,15 @@ class TransformerConfig:
     #: ``moe_choices`` [n_layers, B, S, moe_top_k] int32, so that a
     #: reference can follow the routing it checks.
     moe_report_choices: bool = False
+    #: The experts' form: ``"swiglu"`` (``silu(x w1) . x w3``, then
+    #: ``w2``) or ``"relu2"`` (``relu(x w1)^2``, then ``w2``: no ``w3``);
+    #: the shared expert's too.
+    moe_act: str = "swiglu"
+    #: >0: the routed experts work in a latent of this width, between a
+    #: down projection (``w_down [d_model, latent]``) and an up one
+    #: (``w_up``) of the held share's sum; the router and the shared
+    #: expert read the full width.
+    moe_latent: int = 0
     #: ``(first, count)``: the experts this program holds of a layer
     #: shared with other chips (None: all).  The router stays
     #: ``moe_experts`` wide; what absent experts would add is left out.
@@ -157,6 +166,9 @@ class TransformerConfig:
     #: The state-space layers' sizes (``models.mamba.MambaConfig``) for
     #: the ``"mamba"`` and ``"gmu"`` kinds.
     mamba: Any = None
+    #: The Mamba-2 mixers' sizes (``models.mamba2.Mamba2Config``) for the
+    #: ``"mamba2"`` kind.
+    mamba2: Any = None
     #: ``"rms"``, or ``"layernorm"``: the model's norms (``ln1``,
     #: ``ln2``, ``ln_f``) subtract the mean and have a bias beside the
     #: weight (``ln1_b``, ...); ``norm_eps`` is theirs.
@@ -211,6 +223,8 @@ class TransformerConfig:
                     what = kind.needs if getattr(self, kind.needs) == 0 \
                         else f"the {kind.needs} sizes"
                     raise ValueError(f"a \"{kind.name}\" layer needs {what}")
+        if self.moe_act not in ("swiglu", "relu2"):
+            raise ValueError(f"moe_act {self.moe_act!r}")
         if self.norm not in ("rms", "layernorm") or \
                 self.rope not in ("rotary", "none"):
             raise ValueError(f"norm {self.norm!r}, rope {self.rope!r}")
@@ -303,11 +317,11 @@ def init_stack(key: jax.Array, cfg: TransformerConfig, attention: str,
     norms, then what the run's kinds bring (each folds its own constant
     into the stack's key)."""
     d = cfg.d_model
-    layers: Dict = {"ln1": norm_start(cfg, (nl, d)),
-                    "ln2": norm_start(cfg, (nl, d))}
+    norms = ("ln1", "ln2") if FFN[ffn].apply else ("ln1",)
+    layers: Dict = {name: norm_start(cfg, (nl, d)) for name in norms}
     if cfg.norm == "layernorm":
-        layers["ln1_b"] = jnp.zeros((nl, d), jnp.float32)
-        layers["ln2_b"] = jnp.zeros((nl, d), jnp.float32)
+        layers.update({name + "_b": jnp.zeros((nl, d), jnp.float32)
+                       for name in norms})
     kind, options = run_options(attention)
     layers.update(ATTENTION[kind].init(key, nl, cfg, options))
     layers.update(FFN[ffn].init(key, nl, cfg, {}))
@@ -366,9 +380,10 @@ def stack_specs(cfg: TransformerConfig, attention: str, ffn: str) -> Dict:
     """PartitionSpecs of one kind's stack: the norms replicated, then
     the kinds' own (Megatron TP on heads and FFN-hidden; MoE expert
     weights over "ep")."""
-    layers: Dict = {"ln1": P(None, None), "ln2": P(None, None)}
+    norms = ("ln1", "ln2") if FFN[ffn].apply else ("ln1",)
+    layers: Dict = {name: P(None, None) for name in norms}
     if cfg.norm == "layernorm":
-        layers["ln1_b"] = layers["ln2_b"] = P(None, None)
+        layers.update({name + "_b": P(None, None) for name in norms})
     kind, options = run_options(attention)
     layers.update(ATTENTION[kind].specs(cfg, options))
     layers.update(FFN[ffn].specs(cfg, {}))
@@ -423,11 +438,15 @@ def apply_layer(x, lp, positions, cfg: TransformerConfig, mesh=None,
     with jax.named_scope("attention"), record.run_scope(options):
         h = model_norm(x, lp, "ln1", cfg)
         y, counted, handed_on = record.apply(h, lp, call)
-        x = checkpoint_name(x + y, "mid_residual")
-    with jax.named_scope("ffn"):
-        h = model_norm(x, lp, "ln2", cfg)
-        y, ffn_counted, _ = FFN[ffn].apply(h, lp, call)
         x = x + y
+        if FFN[ffn].apply:           # (a layer may be its mixer alone:
+            x = checkpoint_name(x, "mid_residual")    # x is its output)
+    ffn_counted = {}
+    if FFN[ffn].apply:
+        with jax.named_scope("ffn"):
+            h = model_norm(x, lp, "ln2", cfg)
+            y, ffn_counted, _ = FFN[ffn].apply(h, lp, call)
+            x = x + y
     if mesh is not None:
         x = jax.lax.with_sharding_constraint(
             x, NamedSharding(mesh, P("dp", "sp", None)))

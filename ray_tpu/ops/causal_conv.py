@@ -130,6 +130,13 @@ def _dot(wins, w):
     return functools.reduce(jnp.add, (win * tap for win, tap in zip(wins, w)))
 
 
+def _pre(wins, w):
+    """The filter's sum before SiLU: ``_dot``, and the bias where ``w``
+    holds one more column than there are windows."""
+    pre = _dot(wins, w)
+    return pre + w[len(wins)] if len(w) > len(wins) else pre
+
+
 def _weights(w_ref, channels, taps):
     """The taps of ``channels`` (a slice of 64), each along the lanes."""
     return [jnp.broadcast_to(w_ref[channels, j:j + 1], (_GROUP, _LANES))
@@ -159,22 +166,24 @@ def _along(block_s, body, carry):
     return jax.lax.fori_loop(0, groups // unroll, step, carry)
 
 
-def _fwd_kernel(x_ref, before_ref, w_ref, y_ref, *, taps: int):
+def _fwd_kernel(x_ref, before_ref, w_ref, y_ref, *, taps: int,
+                biased: bool):
     # Grid (row, channel block, tile).  x_ref [block, block_s] in the
     # input's dtype, positions along the lanes; before_ref [block, 128]:
     # the positions before the tile, any at a row's first tile; w_ref
-    # [block, K] float32; y_ref [block, block_s] float32.
+    # [block, K] float32 (and the bias in a column K where ``biased``);
+    # y_ref [block, block_s] float32.
     block, block_s = x_ref.shape
     first = pl.program_id(2) == 0
     masks = _masks(x_ref.dtype, taps)
 
     def walk(g, _):
         at = _group(g)
-        w = _weights(w_ref, at, taps)
+        w = _weights(w_ref, at, taps + biased)
 
         def forward(here, prev):
             cur = _words(x_ref[at, here])
-            pre = _dot(_windows(prev, cur, masks, x_ref.dtype), w)
+            pre = _pre(_windows(prev, cur, masks, x_ref.dtype), w)
             y_ref[at, here] = pre * jax.lax.logistic(pre)
             return cur
 
@@ -185,18 +194,19 @@ def _fwd_kernel(x_ref, before_ref, w_ref, y_ref, *, taps: int):
 
 
 def _bwd_kernel(x_ref, before_ref, after_ref, dy_ref, dy_after_ref, w_ref,
-                dx_ref, dw_ref, dpre_scr, *, taps: int, per: int,
-                stride: int):
+                dx_ref, dw_ref, dpre_scr, *, taps: int, biased: bool,
+                per: int, stride: int):
     # Grid (row, channel block of x, tile).  As the forward's refs, and:
     # after_ref / dy_after_ref [block, 128]: the positions after the
     # tile, any at a row's last tile; dy_ref [block, block_s] float32;
     # dx_ref [block, block_s] in x's dtype; dw_ref [block, K] float32:
-    # this tile's share of the taps' gradient; dpre_scr [block, block_s
-    # + 128] float32.  A channel block the filter skips (``per`` of
+    # this tile's share of the taps' gradient (and of the bias', in a
+    # column K where ``biased``); dpre_scr [block, block_s + 128]
+    # float32.  A channel block the filter skips (``per`` of
     # every ``stride`` are its) gets zeros.
     tile = functools.partial(
         _bwd_tile, x_ref, before_ref, after_ref, dy_ref, dy_after_ref, w_ref,
-        dx_ref, dw_ref, dpre_scr, taps, pl.program_id(2) == 0,
+        dx_ref, dw_ref, dpre_scr, taps, biased, pl.program_id(2) == 0,
         pl.program_id(2) == pl.num_programs(2) - 1)
     if per == stride:
         return tile()
@@ -210,7 +220,7 @@ def _bwd_kernel(x_ref, before_ref, after_ref, dy_ref, dy_after_ref, w_ref,
 
 
 def _bwd_tile(x_ref, before_ref, after_ref, dy_ref, dy_after_ref, w_ref,
-              dx_ref, dw_ref, dpre_scr, taps, first, last):
+              dx_ref, dw_ref, dpre_scr, taps, biased, first, last):
     f32 = jnp.float32
     block, block_s = x_ref.shape
     nought = jnp.zeros((_GROUP, _LANES), f32)
@@ -220,11 +230,11 @@ def _bwd_tile(x_ref, before_ref, after_ref, dy_ref, dy_after_ref, w_ref,
 
     def walk(g, _):
         at = _group(g)
-        w = _weights(w_ref, at, taps)
+        w = _weights(w_ref, at, taps + biased)
 
         def dpre_of(prev, cur, dy):
             wins = _windows(prev, cur, behind, x_ref.dtype)
-            pre = _dot(wins, w)
+            pre = _pre(wins, w)
             s = jax.lax.logistic(pre)
             return dy * (s * (1.0 + pre * (1.0 - s))), wins
 
@@ -233,11 +243,14 @@ def _bwd_tile(x_ref, before_ref, after_ref, dy_ref, dy_after_ref, w_ref,
             cur = _words(x_ref[at, here])
             dpre, wins = dpre_of(prev, cur, dy_ref[at, here])
             dpre_scr[at, here] = dpre
-            return (cur, *(a + dpre * win for a, win in zip(acc, wins)))
+            # (the bias' gradient: dpre against a window of ones)
+            return (cur, *(a + dpre * win for a, win in zip(
+                acc, wins + [1.0] * biased)))
 
         tail, *acc = _along(
             block_s, forward,
-            (_words(_unless(first, before_ref[at, :])),) + (nought,) * taps)
+            (_words(_unless(first, before_ref[at, :])),)
+            + (nought,) * (taps + biased))
         # the positions after the tile see its last ones: their dpre is
         # the next tile's, needed here for the filter run backwards
         after, _ = dpre_of(tail, _words(after_ref[at, :]),
@@ -245,11 +258,11 @@ def _bwd_tile(x_ref, before_ref, after_ref, dy_ref, dy_after_ref, w_ref,
         dpre_scr[at, block_s:] = _unless(last, after)
         dw_ref[at, :] = functools.reduce(jnp.add, (
             jnp.where(lanes == j, jnp.sum(a, axis=1, keepdims=True), 0.0)
-            for j, a in enumerate(acc)))[:, :taps]
+            for j, a in enumerate(acc)))[:, :taps + biased]
 
         def backward(here, cur):
             nxt = dpre_scr[at, pl.ds(here.start + _LANES, _LANES)]
-            dx = _dot(_shifted(nxt, cur, ahead, ahead=True), w)
+            dx = _dot(_shifted(nxt, cur, ahead, ahead=True), w[:taps])
             dx_ref[at, here] = dx.astype(dx_ref.dtype)
             return nxt
 
@@ -266,11 +279,12 @@ def _block_of(width: int, pitch: int) -> int:
         else _LANES
 
 
-def _kernel_forward(x, w, width, pitch, block_s, interpret):
-    """x [B, G pitch, S], w [G width, K] float32 -> y [B, G width, S]
-    float32: the first ``width`` channels of every ``pitch`` filtered."""
+def _kernel_forward(x, w, width, pitch, block_s, interpret, biased):
+    """x [B, G pitch, S], w [G width, K] float32 (``biased``: [G width,
+    K + 1], the bias last) -> y [B, G width, S] float32: the first
+    ``width`` channels of every ``pitch`` filtered."""
     b, _, s = x.shape
-    cout, taps = w.shape
+    cout, cols = w.shape
     block = _block_of(width, pitch)
     per, stride = width // block, pitch // block
     tiles, ratio = s // block_s, block_s // _LANES
@@ -279,7 +293,7 @@ def _kernel_forward(x, w, width, pitch, block_s, interpret):
         return (j // per) * stride + j % per
 
     return pl.pallas_call(
-        functools.partial(_fwd_kernel, taps=taps),
+        functools.partial(_fwd_kernel, taps=cols - biased, biased=biased),
         grid=(b, cout // block, tiles),
         in_specs=[
             pl.BlockSpec((None, block, block_s),
@@ -287,7 +301,7 @@ def _kernel_forward(x, w, width, pitch, block_s, interpret):
             pl.BlockSpec((None, block, _LANES),
                          lambda r, j, i: (r, chan(j),
                                           jnp.maximum(i * ratio - 1, 0))),
-            pl.BlockSpec((block, taps), lambda r, j, i: (j, 0))],
+            pl.BlockSpec((block, cols), lambda r, j, i: (j, 0))],
         out_specs=pl.BlockSpec((None, block, block_s),
                                lambda r, j, i: (r, j, i)),
         out_shape=jax.ShapeDtypeStruct((b, cout, s), jnp.float32),
@@ -299,7 +313,7 @@ def _kernel_forward(x, w, width, pitch, block_s, interpret):
     )(x, x, w)
 
 
-def _kernel_backward(x, w, dy, width, pitch, block_s, interpret):
+def _kernel_backward(x, w, dy, width, pitch, block_s, interpret, biased):
     """-> (dx as ``x``, zeros in the channels the filter skips; dw [G
     width, K] float32)."""
     b, cin, s = x.shape
@@ -333,7 +347,8 @@ def _kernel_backward(x, w, dy, width, pitch, block_s, interpret):
         return index_map
 
     dx, dw = pl.pallas_call(
-        functools.partial(_bwd_kernel, taps=taps, per=per, stride=stride),
+        functools.partial(_bwd_kernel, taps=taps - biased, biased=biased,
+                          per=per, stride=stride),
         grid=(b, cin // block, tiles),
         in_specs=[
             pl.BlockSpec((None, block, block_s), index(0)),
@@ -359,17 +374,19 @@ def _kernel_backward(x, w, dy, width, pitch, block_s, interpret):
     return dx, dw[:, :width].reshape(cout, taps)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(2, 3, 4, 5))
-def _conv_kernels(x, w, width, pitch, block_s, interpret):
-    return _kernel_forward(x, w, width, pitch, block_s, interpret)
+@functools.partial(jax.custom_vjp, nondiff_argnums=(2, 3, 4, 5, 6))
+def _conv_kernels(x, w, width, pitch, block_s, interpret, biased):
+    return _kernel_forward(x, w, width, pitch, block_s, interpret, biased)
 
 
-def _conv_fwd(x, w, width, pitch, block_s, interpret):
-    return _kernel_forward(x, w, width, pitch, block_s, interpret), (x, w)
+def _conv_fwd(x, w, width, pitch, block_s, interpret, biased):
+    return _kernel_forward(x, w, width, pitch, block_s, interpret,
+                           biased), (x, w)
 
 
-def _conv_bwd(width, pitch, block_s, interpret, res, dy):
-    return _kernel_backward(*res, dy, width, pitch, block_s, interpret)
+def _conv_bwd(width, pitch, block_s, interpret, biased, res, dy):
+    return _kernel_backward(*res, dy, width, pitch, block_s, interpret,
+                            biased)
 
 
 _conv_kernels.defvjp(_conv_fwd, _conv_bwd)
@@ -412,30 +429,38 @@ def fallback_passes(x_shape, taps_shape) -> int:
     return 0 if kernels_by_default(x_shape, taps_shape) else 1
 
 
-def in_kernels(x, taps, block_s: int | None = None, interpret: bool = False):
+def in_kernels(x, taps, block_s: int | None = None, interpret: bool = False,
+               bias=None):
     """``causal_conv_silu`` by the kernels whatever the backend, a tile
     ``block_s`` positions: what ``causal_conv_silu`` calls on a TPU, and
-    the tests in the Pallas interpreter."""
+    the tests in the Pallas interpreter.  ``bias [..., Cw]`` rides as
+    one more column of the taps."""
     if not fits(x.shape, taps.shape, block_s):
         raise ValueError(f"x {x.shape}, taps {taps.shape}, tiles of "
                          f"{block_s} positions: not the kernels' shapes")
     width, pitch = taps.shape[-2], x.shape[-1]
     groups = math.prod(taps.shape[:-2])
     # positions along the lanes: as XLA lays the projection out
-    y = _conv_kernels(
-        jnp.swapaxes(x.reshape(*x.shape[:2], groups * pitch), 1, 2),
-        taps.astype(jnp.float32).reshape(groups * width, -1), width, pitch,
-        block_s or tile_of(x.shape[1]), interpret)
+    x_t = jnp.swapaxes(x.reshape(*x.shape[:2], groups * pitch), 1, 2)
+    w = taps.astype(jnp.float32).reshape(groups * width, -1)
+    if bias is not None:
+        w = jnp.concatenate(
+            [w, bias.astype(jnp.float32).reshape(groups * width, 1)], axis=1)
+    y = _conv_kernels(x_t, w, width, pitch, block_s or tile_of(x.shape[1]),
+                      interpret, bias is not None)
     return jnp.swapaxes(y, 1, 2).reshape(*x.shape[:-1], width)
 
 
-def causal_conv_silu(x: jax.Array, taps: jax.Array) -> jax.Array:
-    """``silu(causal_conv(x[..., :Cw], taps))`` in float32 for x [B, S,
-    ..., W] and taps [..., Cw, K], ``Cw <= W``: each of the first ``Cw``
-    channels of a group over its own last ``K`` positions, zeros before
-    the row's start.  The two kernels where ``kernels_by_default`` says
-    so (``x`` is then never sliced in HBM), ``causal_conv`` and XLA's
-    SiLU elsewhere."""
+def causal_conv_silu(x: jax.Array, taps: jax.Array,
+                     bias: jax.Array | None = None) -> jax.Array:
+    """``silu(causal_conv(x[..., :Cw], taps) + bias)`` in float32 for x
+    [B, S, ..., W], taps [..., Cw, K] and bias [..., Cw] (none by
+    default), ``Cw <= W``: each of the first ``Cw`` channels of a group
+    over its own last ``K`` positions, zeros before the row's start.
+    The two kernels where ``kernels_by_default`` says so (``x`` is then
+    never sliced in HBM; the bias is one more column of the taps),
+    ``causal_conv`` and XLA's SiLU elsewhere."""
     if kernels_by_default(x.shape, taps.shape):
-        return in_kernels(x, taps)
-    return jax.nn.silu(causal_conv(x[..., :taps.shape[-2]], taps))
+        return in_kernels(x, taps, bias=bias)
+    pre = causal_conv(x[..., :taps.shape[-2]], taps)
+    return jax.nn.silu(pre if bias is None else pre + bias)

@@ -75,6 +75,32 @@ def test_values_and_both_gradients_against_the_jnp_form(shape, dtype):
 
 
 @pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("shape", ["four_tiles", "one_tile", "strided"])
+def test_a_bias_before_silu_and_its_gradient(shape, dtype):
+    """The Mamba-2 layer's form, ``silu(conv(x) + bias)``: the bias as a
+    column beside the taps, its gradient the sum of ``dpre`` over the
+    row's positions; no bias is the form above."""
+    x_shape, taps_shape, block_s = SHAPES[shape]
+    x, taps, dy = inputs(4, x_shape, taps_shape, jnp.dtype(dtype))
+    bias = jax.random.normal(jax.random.PRNGKey(5), taps_shape[:-1])
+
+    def biased(x, t, b):
+        return jax.nn.silu(gdn.causal_conv(x[..., :t.shape[-2]], t) + b)
+
+    want, want_vjp = jax.vjp(biased, x, taps, bias)
+    got, got_vjp = jax.vjp(lambda x, t, b: cc.in_kernels(
+        x, t, block_s, interpret=True, bias=b), x, taps, bias)
+    np.testing.assert_allclose(got, want, rtol=0, atol=5e-6 * float(
+        jnp.max(jnp.abs(want))))
+    for name, g, w in zip(("dx", "dtaps", "dbias"), got_vjp(dy),
+                          want_vjp(dy)):
+        assert g.dtype == w.dtype and g.shape == w.shape, name
+        assert gap(g, w) < ASKED[dtype], (name, gap(g, w))
+    np.testing.assert_array_equal(
+        cc.causal_conv_silu(x, taps, bias), biased(x, taps, bias))
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
 @pytest.mark.parametrize("shape", ["four_tiles", "strided"])
 def test_gradients_through_a_following_reduction(shape, dtype):
     """As the delta layer follows it: an l2-norm over blocks of columns
@@ -145,9 +171,9 @@ def test_which_path_runs_is_read_from_the_input(case, monkeypatch):
         monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     called = []
 
-    def interpreted(x, taps):
+    def interpreted(x, taps, **bias):
         called.append(x.shape)
-        return kernels(x, taps, interpret=True)
+        return kernels(x, taps, interpret=True, **bias)
 
     kernels = cc.in_kernels
     monkeypatch.setattr(cc, "in_kernels", interpreted)

@@ -492,8 +492,8 @@ def test_the_held_experts_five_gradients_are_the_plain_loops(
     def held_experts(xt, gate, w):
         xt, w = jax.tree.map(lambda a: a.astype(dtype), (xt, w))
         y, done = moe._held_experts(
-            chunks, K, xt, gate.reshape(-1), w["w1"], w["w3"], w["w2"], key,
-            jnp.cumsum(sizes), sizes)
+            chunks, K, xt, gate.reshape(-1), (w["w1"], w["w3"], w["w2"]),
+            key, jnp.cumsum(sizes), sizes)
         return y.astype(jnp.float32), done
 
     def loop(xt, gate, w):

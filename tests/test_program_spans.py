@@ -404,18 +404,20 @@ def test_every_scope_literal_is_declared_and_every_declared_scope_is_used():
     assert kernels == {*tracing.KERNEL_EVENTS, *KERNELS_UNDER_THEIR_SCOPE}
     assert not set(tracing.KERNEL_EVENTS) & set(KERNELS_UNDER_THEIR_SCOPE)
     assert set(KERNELS_UNDER_THEIR_SCOPE.values()) <= set(tracing.STEP_SCOPES)
-    assert len(set(tracing.STEP_SCOPES)) == len(tracing.STEP_SCOPES) == 31
+    assert len(set(tracing.STEP_SCOPES)) == len(tracing.STEP_SCOPES) == 36
     assert set(tracing.RUN_SCOPES) == {"mha_window"} < set(
         tracing.STEP_SCOPES)
     assert tracing.KERNEL_EVENTS == (
         "flash_attention_fwd", "flash_attention_bwd", "gated_delta_fwd",
-        "gated_delta_bwd", "selective_scan_fwd", "selective_scan_bwd")
+        "gated_delta_bwd", "selective_scan_fwd", "selective_scan_bwd",
+        "ssd_fwd", "ssd_bwd")
 
 
 def test_every_declared_scope_feeds_one_metric():
     """Each scope and kernel event is summed by exactly one of the
     benchmark's group metrics -- ``step_scopes.GROUPS``, or the list in
-    the file of a metric that came after it (``ssm_layers_ms``);
+    the file of a metric that came after it (``ssm_layers_ms``,
+    ``mamba2_layers_ms``);
     ``optimizer`` is left to the rest (``train_step_device_ms`` less the
     groups), and ``diff_attn`` to ``step_attributed_pct`` alone.  A
     run's scope owns no instruction and is no group's: it is what one
@@ -427,8 +429,11 @@ def test_every_declared_scope_feeds_one_metric():
     ssm = bench_run._reader("ssm_layers_ms").__globals__["SCOPES"]
     assert {"ssm_proj", "ssm_conv", "ssm_scan", "ssm_out", "gmu",
             "selective_scan_fwd", "selective_scan_bwd"} == set(ssm)
+    mamba2 = bench_run._reader("mamba2_layers_ms").__globals__["SCOPES"]
+    assert {"ssd_proj", "ssd_conv", "ssd_rule", "ssd_norm", "ssd_out",
+            "ssd_fwd", "ssd_bwd"} == set(mamba2)
     grouped = [s for group in step_scopes.GROUPS.values() for s in group]
-    grouped += list(ssm)
+    grouped += list(ssm) + list(mamba2)
     assert len(grouped) == len(set(grouped))
     assert set(grouped) | {"optimizer", "diff_attn"} == \
         (set(tracing.STEP_SCOPES) - set(tracing.RUN_SCOPES)) \
@@ -464,6 +469,13 @@ MANIFESTS = {
                    head_loss=ONCE, optimizer=OUTSIDE),
     "windowed": dict(MOE, attention=ALL, ffn=ALL, attn_gate=ALL,
                      moe_shared=ALL, head_loss=ONCE, optimizer=OUTSIDE),
+    # (the experts' latent sum is read by ``w_up``'s gradient: without a
+    # plan the experts' forward runs again in the backward pass)
+    "nemotron": dict(MOE, moe_experts=ALL, moe_combine=ALL, attention=ALL,
+                     ffn=ALL, ssd_proj=ALL,
+                     ssd_conv=ALL, ssd_rule=ALL, ssd_norm=ALL, ssd_out=ALL,
+                     moe_shared=ALL, moe_bias=OUTSIDE, head_loss=ONCE,
+                     optimizer=OUTSIDE),
 }
 
 
@@ -552,8 +564,9 @@ def test_the_registry_entrys_memory_carries_the_plan(kind, monkeypatch):
     assert plan["budget_bytes"] == int(remat.SAFETY * (
         10 ** 9 - plan["step_bytes"]))
     assert plan["runs"] and all(
-        "mid_residual" in run["names"] and run["refused"] == []
-        and run["bytes_a_layer"] > 0 for run in plan["runs"])
+        ("mid_residual" in run["names"]) != run["kind"].endswith("+none")
+        and run["refused"] == [] and run["bytes_a_layer"] > 0
+        for run in plan["runs"])
     assert plan["kept_bytes"] == sum(
         run["layers"] * run["bytes_a_layer"] for run in plan["runs"])
     assert sorted(run["kind"] for run in plan["runs"]) == {
@@ -565,7 +578,9 @@ def test_the_registry_entrys_memory_carries_the_plan(kind, monkeypatch):
                    "mamba:writes=memory+dense"],
         "windowed": ["mha:heads=6,rope=global+dense",
                      "mha:heads=6,rope=global+moe",
-                     "mha:heads=9,window=8,rope=local+moe"]}[kind]
+                     "mha:heads=9,window=8,rope=local+moe"],
+        "nemotron": ["mamba2+moe", "mamba2+moe", "mamba2+none",
+                     "mha+moe"]}[kind]
     assert 0 < plan["plan_seconds"] < 5 and plan["trace_seconds"] > 0
     exposed = get_metrics_registry().render_prometheus().splitlines()
     for name in ("kept_bytes", "budget_bytes", "plan_seconds"):

@@ -410,6 +410,9 @@ def step_jaxpr_hash(cell: str, root: str = ROOT) -> str:
     if "mamba" in kwargs:
         from ray_tpu.models.mamba import MambaConfig
         kwargs["mamba"] = MambaConfig(**kwargs["mamba"])
+    if "mamba2" in kwargs:
+        from ray_tpu.models.mamba2 import Mamba2Config
+        kwargs["mamba2"] = Mamba2Config(**kwargs["mamba2"])
     if "rope_tables" in kwargs:
         from ray_tpu.models.transformer import RopeTable
         kwargs["rope_tables"] = {name: RopeTable(**table) for name, table
@@ -480,6 +483,9 @@ PARENT_STEPS = {
         "ef676578e0020595e3ae2f5d9b63594d720dd9670c02ef45bb2f7c366b65b715",
     "train-laguna-s.pack16k":
         "610498e3e64b55cef825b379a27ea789e9e7062a46139dd7907e4ede4144784b",
+    # PR 46's own: the step the cell was added with
+    "train-nemotron-3-super.row8k":
+        "0f387e113c0d6e8cd9f4741114c09d39b30f960cd057222db4d5cbfe856d38b7",
 }
 
 
@@ -502,7 +508,9 @@ def test_the_older_cells_steps_are_traced_as_the_parent_traced_them(
     layer's backward changed); the dense and the state-space cell's are
     the ones they had.  Since PR 44 the hybrid cell's is that PR's (the
     delta rule's kernels changed; nothing else did: the other five
-    pass as they stood)."""
+    pass as they stood).  PR 46 added its cell's step and left the six
+    as they were: the squared-ReLU and latent experts, the convolution's
+    bias and the layer that is a mixer alone are behind defaults."""
     import importlib
     from ray_tpu.models import common, gdn, mha, mla, moe, transformer
     for module in (common, gdn, mha, mla, moe, transformer):

@@ -20,10 +20,11 @@ import pytest
 from ray_tpu.models import mha, remat, transformer
 from ray_tpu.models.gdn import GDNConfig
 from ray_tpu.models.mamba import MambaConfig
+from ray_tpu.models.mamba2 import Mamba2Config
 from ray_tpu.models.mla import MLAConfig
 from ray_tpu.models.transformer import (TransformerConfig, apply_layer,
                                         init_stack, run_stack)
-from ray_tpu.ops import gated_delta
+from ray_tpu.ops import gated_delta, ssd
 from ray_tpu.ops.flash_attention import RESIDUAL_NAMES
 from tiny_steps import (FULL, ROOM, RUNS, _device,  # noqa: F401
                         _tiny_step, every_candidate_that_spares_anything)
@@ -86,6 +87,16 @@ KINDS = {
                         rope_tables={"near": transformer.RopeTable(100.0)}),
                    {"mid_residual", "attn_q", "attn_k", "attn_v",
                     "attn_head_gate", "ffn_gate", "ffn_up"}),
+    "mamba2-latent": (("mamba2", "moe"),
+                      dict(layer_pattern=(("mamba2", "moe", 2),),
+                           mamba2=Mamba2Config(num_heads=4, head_dim=8,
+                                               n_groups=2, state_size=8,
+                                               chunk=16, norm_groups=2),
+                           moe_experts=4, moe_top_k=2, moe_shared_width=16,
+                           moe_act="relu2", moe_latent=16,
+                           moe_scoring="sigmoid", moe_bias_rate=0.01),
+                      {"mid_residual", "ssd_z", "ssd_xbc", "moe_scores",
+                       "moe_latent", "moe_latent_out"}),
     "diff-cross": (("diff:reads=kv", "dense"),
                    dict(layer_pattern=(("diff:writes=kv", "dense", 1),
                                        ("diff:reads=kv", "dense", 2)),
@@ -267,8 +278,9 @@ def test_no_budget_is_exactly_todays_two_names(monkeypatch):
         assert all(r["names"] == [] for r in report["runs"])
     assert remat.no_plan() == {"budget_bytes": None, "kept_bytes": 0,
                                "runs": []}
-    assert remat.BASE_NAMES == RESIDUAL_NAMES + gated_delta.RESIDUAL_NAMES
-    assert len(set(remat.BASE_NAMES)) == 4
+    assert remat.BASE_NAMES == RESIDUAL_NAMES + gated_delta.RESIDUAL_NAMES \
+        + ssd.RESIDUAL_NAMES
+    assert len(set(remat.BASE_NAMES)) == 6
     assert remat.device_memory() is None
     policy = remat.policy(("mha", "dense"))
     assert not isinstance(policy, remat.Keeps)
