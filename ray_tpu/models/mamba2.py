@@ -25,7 +25,10 @@ heads of ``P`` channels, ``G`` groups of ``N`` states:
 
 The published fused input projection ``[z | xBC | dt]`` is three
 matrices here (the same parameters): the convolution's kernels read
-``x | B | C`` whole, and nothing is sliced out of a wider product.  A row
+``x | B | C`` whole, and nothing is sliced out of a wider product.  The
+convolution's output, ``dt`` and the rule's ``y`` are held positions-minor
+(``[B, channels, S]``), as the kernels lay them out: the rule's kernels
+read ``x``, ``B`` and ``C`` out of that one array (``ssd.ssd_mixed``).  A row
 is one causal sequence: the state and the convolution cross whatever
 separators it holds.  The kind has no ``tp`` or ``sp`` layout yet (the
 state would pass from shard to shard): the specs replicate and the kind
@@ -124,7 +127,7 @@ def _mamba2(h, lp: Dict, call: LayerCall):
     m = cfg.mamba2
     b, s, _ = h.shape
     f32 = jnp.float32
-    hp, gn = m.inner, m.n_groups * m.state_size
+    hp = m.inner
     # The names: cut points a rematerialised layer may keep
     # (``models/remat.py``): the three projections and the
     # convolution's output.  The rule's output and its step states are
@@ -133,19 +136,24 @@ def _mamba2(h, lp: Dict, call: LayerCall):
         z = checkpoint_name(jnp.einsum("bsd,de->bse", h, lp["w_z"]), "ssd_z")
         xbc = checkpoint_name(jnp.einsum("bsd,de->bse", h, lp["w_xbc"]),
                               "ssd_xbc")
-        dt = checkpoint_name(jnp.einsum("bsd,dh->bsh", h, lp["w_dt"],
+        # positions minor, [B, H, S], as the rule reads the step sizes
+        dt = checkpoint_name(jnp.einsum("bsd,dh->bhs", h, lp["w_dt"],
                                         preferred_element_type=f32), "ssd_dt")
     with jax.named_scope("ssd_conv"):
-        mixed = checkpoint_name(conv_op.causal_conv_silu(
-            xbc, lp["conv"], lp["conv_b"]).astype(h.dtype), "ssd_conv")
+        # positions minor, [B, conv_dim, S]: as the convolution's kernels
+        # write it and the rule's read it
+        mixed = checkpoint_name(jnp.swapaxes(conv_op.causal_conv_silu(
+            xbc, lp["conv"], lp["conv_b"]), 1, 2).astype(h.dtype), "ssd_conv")
     with jax.named_scope("ssd_rule"):
-        x = mixed[..., :hp].reshape(b, s, m.num_heads, m.head_dim)
-        bm = mixed[..., hp:hp + gn].reshape(b, s, m.n_groups, m.state_size)
-        cm = mixed[..., hp + gn:].reshape(b, s, m.n_groups, m.state_size)
-        delta = jax.nn.softplus(dt + lp["dt_bias"])
-        y = ssd_op.ssd_rule(x, delta, -jnp.exp(lp["A_log"]), bm, cm,
-                            chunk=min(m.chunk, s))
-        y = y.astype(f32) + lp["D"][:, None] * x.astype(f32)
+        # the rule reads x, B and C out of ``mixed`` itself and writes y
+        # [B, H, P, S]; the D skip is XLA's, fused into the norm's first
+        # pass
+        delta = jax.nn.softplus(dt + lp["dt_bias"][:, None])
+        y = ssd_op.ssd_mixed(mixed, delta, -jnp.exp(lp["A_log"]), m.n_groups,
+                             m.state_size, chunk=min(m.chunk, s))
+        x = mixed[:, :hp].reshape(y.shape)
+        y = (y.astype(f32) + lp["D"][:, None, None] * x.astype(f32)
+             ).reshape(b, hp, s)
         counted = {
             "ssd_fallback_passes": jnp.asarray(max(
                 ssd_op.fallback_passes(),
@@ -153,7 +161,7 @@ def _mamba2(h, lp: Dict, call: LayerCall):
             "ssd_dt_mean": jax.lax.stop_gradient(jnp.mean(delta)),
         }
     with jax.named_scope("ssd_norm"):
-        normed = gated_group_norm(y.reshape(b, s, hp), z, lp["norm"],
+        normed = gated_group_norm(jnp.swapaxes(y, 1, 2), z, lp["norm"],
                                   m.norm_groups, cfg.norm_eps)
     with jax.named_scope("ssd_out"):
         out = jnp.einsum("bse,ed->bsd", normed.astype(h.dtype), lp["w_out"])

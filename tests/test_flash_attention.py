@@ -4,6 +4,7 @@ mode on the CPU, against ``full_attention`` and its ``jax.grad``.
 tier-1 reaches it; chip_smoke.py asks the chip the same question at
 full width."""
 
+import math
 import re
 
 import jax
@@ -1046,3 +1047,50 @@ def test_causal_conv_gradient_compiles_for_the_chip_at_the_cell_width(
         assert "8195" not in compiled
         assert not re.findall(r"f32\[2,(?:8192,12288|12288,8192|"
                               r"8192,16,768)\]", compiled)
+
+
+def test_mamba2_mixer_compiles_for_the_chip_with_no_copy_around_the_rule(
+        one_v5e_chip, monkeypatch):
+    """One Mamba-2 mixer at the Mamba-2 cell's widths (1 x 8,192
+    positions, d 4,096, 128 heads of 64 over 8 groups of 128 states,
+    bfloat16), forward and backward under the kernels' remat policy:
+    ``ops/ssd.py``'s two kernels compile, the forward runs once, and the
+    compiled program holds no copy of the rule's operands or results (x,
+    B, C, y or a cotangent of one: 16 MB and more) -- the rule reads and
+    writes them where the convolution and the norm have them.  (What
+    copies are left: the convolution's taps, and the step sizes' 4 MB
+    retiled once, where the backward makes them again.)"""
+    import types
+    from ray_tpu.models import mamba2, remat
+    from ray_tpu.models.common import LayerCall
+    m = mamba2.Mamba2Config(num_heads=128, head_dim=64, n_groups=8,
+                            state_size=128, norm_groups=8)
+    cfg = types.SimpleNamespace(mamba2=m, d_model=4096, dtype=jnp.bfloat16,
+                                norm_eps=1e-5)
+    lp = jax.tree.map(
+        lambda v: jax.ShapeDtypeStruct(v.shape[1:], v.dtype,
+                                       sharding=one_v5e_chip),
+        jax.eval_shape(lambda k: mamba2._init(k, 1, cfg, {}),
+                       jax.random.PRNGKey(0)))
+    h = jax.ShapeDtypeStruct((1, 8192, 4096), jnp.bfloat16,
+                             sharding=one_v5e_chip)
+    layer = jax.checkpoint(
+        lambda h, lp: mamba2._mamba2(h, lp, LayerCall(cfg, "mamba2"))[0],
+        policy=jax.checkpoint_policies.save_only_these_names(
+            *remat.BASE_NAMES))
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    text = jax.jit(jax.grad(lambda h, lp: jnp.sum(jnp.square(
+        layer(h, lp).astype(jnp.float32))), (0, 1))).lower(
+            h, lp).compile().as_text()
+    calls = {name: len(re.findall(
+        rf"%{name}[.\d]* = [^\n]*custom_call_target=\"tpu_custom_call\"",
+        text)) for name in ("ssd_fwd", "ssd_bwd")}
+    assert calls == {"ssd_fwd": 1, "ssd_bwd": 1}
+    entry = text[text.index("\nENTRY "):]
+    copies = []
+    for kind, dims in re.findall(
+            r"%copy[.\d]* = (bf16|f32)\[([\d,]+)\]\S* copy\(", entry):
+        size = math.prod(int(d) for d in dims.split(","))
+        if size * (2 if kind == "bf16" else 4) >= 16 * 2 ** 20:
+            copies.append((kind, dims))
+    assert copies == [], copies
