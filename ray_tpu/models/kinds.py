@@ -9,6 +9,7 @@ from typing import Any, Dict, Tuple
 from ray_tpu.models.common import DENSE, NONE
 from ray_tpu.models.diff_attention import DIFF
 from ray_tpu.models.gdn import GDN
+from ray_tpu.models.kda import KDA
 from ray_tpu.models.mamba import GMU, MAMBA
 from ray_tpu.models.mamba2 import MAMBA2
 from ray_tpu.models.mha import MHA
@@ -16,7 +17,7 @@ from ray_tpu.models.mla import MLA
 from ray_tpu.models.moe import MOE
 
 ATTENTION = {kind.name: kind for kind in (MHA, MLA, GDN, MAMBA, GMU, DIFF,
-                                           MAMBA2)}
+                                           MAMBA2, KDA)}
 FFN = {kind.name: kind for kind in (DENSE, MOE, NONE)}
 
 

@@ -72,10 +72,15 @@ choice of every token, as many as it fills.
 ``balance_loss`` is the load-balance auxiliary of top-k routing.
 
 Two routers.  ``scoring="softmax"`` is the one above.  ``"sigmoid"`` is
-the auxiliary-loss-free one (DeepSeek-V3's ``noaux_tc`` with one group):
+the auxiliary-loss-free one (DeepSeek-V3's ``noaux_tc``):
     score    = sigmoid(x @ wr)                      [T, E]  float32
-    e        = top_k(score + bias)      the bias only chooses
+    group    = sum of the two largest score + bias in each of
+               ``n_group`` equal groups of experts  [T, n_group]
+    e        = top_k(score + bias) within the ``topk_group`` best
+               groups                   the bias only chooses
     gate     = score[e] / (sum over e + 1e-20) * route_scale
+With one group (the default) the group step is left out: ``e`` is the
+``top_k`` of ``score + bias`` over all experts.
 ``bias`` [E] is no parameter: it is state beside the parameters, handed
 to the layer as ``lp["bias"]`` (no gradient reaches it), and after each
 step ``update_bias`` moves it by ``rate`` towards the experts that step
@@ -378,9 +383,9 @@ _held_experts.defvjp(_held_experts_fwd, _held_experts_bwd)
 @functools.partial(jax.custom_vjp, nondiff_argnums=(1,))
 def _choose(probs, k, offset=None):
     """The ``k`` experts every token takes -- those with the largest
-    ``probs`` [T, E], plus ``offset`` [E] where given (it only chooses:
-    no gradient reaches it) -- and their ``probs``: ([T, k] float32,
-    [T, k] int32)."""
+    ``probs`` [T, E], plus ``offset`` [E] or [T, E] where given (it only
+    chooses: no gradient reaches it) -- and their ``probs``: ([T, k]
+    float32, [T, k] int32)."""
     if offset is None:
         return tuple(jax.lax.top_k(probs, k))
     _, expert = jax.lax.top_k(probs + offset, k)
@@ -403,9 +408,30 @@ def _choose_bwd(k, res, cotangent):
 _choose.defvjp(_choose_fwd, _choose_bwd)
 
 
+def _group_offset(score, bias, n_group: int, topk_group: int):
+    """What the sigmoid router adds to ``score`` [T, E] to choose: the
+    ``bias`` [E] (where given), and ``-inf`` on every expert of a group
+    the token does not keep.  The experts are ``n_group`` equal groups,
+    each scored by the sum of its two largest ``score + bias``; a token
+    keeps its ``topk_group`` best.  It only chooses: no gradient."""
+    chooses = jax.lax.stop_gradient(score if bias is None else score + bias)
+    tokens, n_experts = chooses.shape
+    size = n_experts // n_group
+    grouped = chooses.reshape(tokens, n_group, size)
+    # the two largest by two maxima (a top-k is a sort on the chip)
+    first = jnp.argmax(grouped, axis=-1)[..., None] == jnp.arange(size)
+    best_two = jnp.max(grouped, axis=-1) + jnp.max(
+        jnp.where(first, -jnp.inf, grouped), axis=-1)
+    _, kept = jax.lax.top_k(best_two, topk_group)
+    keep = jnp.any(kept[..., None] == jnp.arange(n_group), axis=-2)
+    offset = jnp.where(jnp.repeat(keep, size, axis=-1), 0.0, -jnp.inf)
+    return offset if bias is None else offset + bias
+
+
 def moe_ffn(x: jax.Array, lp: Dict, top_k: int, norm_topk: bool = True,
             held: Tuple = None, scoring: str = "softmax",
-            route_scale: float = 1.0, alike_tail: float = _ALIKE_TAIL):
+            route_scale: float = 1.0, alike_tail: float = _ALIKE_TAIL,
+            n_group: int = 1, topk_group: int = 1):
     """x [..., D] -> (y [..., D], stats); the residual is NOT included
     (nor the shared expert: ``shared_expert``).
     ``lp`` holds this layer's ``wr`` [D, E] and ``w1``/``w3``/``w2`` of
@@ -424,7 +450,9 @@ def moe_ffn(x: jax.Array, lp: Dict, top_k: int, norm_topk: bool = True,
     ``scoring="sigmoid"``: the scores are sigmoids, the choice is by
     score plus ``lp["bias"]`` [E] (where the layer has one).
     ``alike_tail``: the share of blocks of alike tokens the first chunk
-    may fall short of (``chunk_rows``)."""
+    may fall short of (``chunk_rows``).  ``n_group`` > 1 (sigmoid
+    scores): the choice is within each token's ``topk_group`` best of
+    ``n_group`` groups of experts (``_group_offset``)."""
     lead, D = x.shape[:-1], x.shape[-1]
     xt = x.reshape(-1, D)
     T = xt.shape[0]
@@ -454,7 +482,10 @@ def moe_ffn(x: jax.Array, lp: Dict, top_k: int, norm_topk: bool = True,
                 gate = gate * route_scale
         elif scoring == "sigmoid":
             probs = jax.nn.sigmoid(logits)
-            gate, expert = _choose(probs, top_k, lp.get("bias"))
+            offset = lp.get("bias")
+            if n_group > 1:
+                offset = _group_offset(probs, offset, n_group, topk_group)
+            gate, expert = _choose(probs, top_k, offset)
             gate = checkpoint_name(gate, "moe_choice")
             if norm_topk:
                 gate = gate / (jnp.sum(gate, axis=-1, keepdims=True) + 1e-20)
@@ -640,7 +671,8 @@ def _moe_block(h, lp: Dict, call: LayerCall):
     ``_init`` and ``_specs`` what reads the flat ``moe_*`` fields."""
     cfg, mesh, lp = call.cfg, call.mesh, lp["moe"]
     router = dict(scoring=cfg.moe_scoring, route_scale=cfg.moe_route_scale,
-                  alike_tail=cfg.moe_alike_tail)
+                  alike_tail=cfg.moe_alike_tail, n_group=cfg.moe_n_group,
+                  topk_group=cfg.moe_topk_group)
     if mesh is not None and mesh.shape.get("ep", 1) > 1:
         if cfg.moe_experts_held is not None:
             raise ValueError("moe_experts_held is one chip's share; an "

@@ -6,9 +6,9 @@ its policy names; the backward pass computes the rest a second time.
 What the kernels name of their own outputs (``BASE_NAMES``: the flash
 kernel's ``out`` and ``lse``, the delta rule's ``o`` and the state
 entering each of its grid steps, the state-space rule's ``y`` and its
-step states) is kept always: with them a layer's
-backward pass does not run the forward kernel again, for about the
-bytes of the layer's input a kernel.  Beyond them the layers name their
+step states, KDA's ``o`` and its step states) is kept always: with them
+a layer's backward pass does not run the forward kernel again, for
+about the bytes of the layer's input a kernel.  Beyond them the layers name their
 cut points (``checkpoint_name``: the middle residual, q, k and v as they
 enter the kernel, the FFN's products, the latents, the delta layer's
 projections and convolution, the state-space layer's projections,
@@ -93,12 +93,13 @@ from jax.extend import core as jex_core
 
 from ray_tpu.ops.flash_attention import RESIDUAL_NAMES as _FLASH_NAMES
 from ray_tpu.ops.gated_delta import RESIDUAL_NAMES as _DELTA_NAMES
+from ray_tpu.ops.kda import RESIDUAL_NAMES as _KDA_NAMES
 from ray_tpu.ops.ssd import RESIDUAL_NAMES as _SSD_NAMES
 
 #: The kernels' own names: kept by every ``remat_layer`` whatever the
 #: plan, counted in a run's stacks, no candidate.  A name occurs only in
 #: the layers that run its kernel.
-BASE_NAMES = _FLASH_NAMES + _DELTA_NAMES + _SSD_NAMES
+BASE_NAMES = _FLASH_NAMES + _DELTA_NAMES + _SSD_NAMES + _KDA_NAMES
 
 #: Of the room the count leaves, the share the plan may fill.  A byte
 #: kept costs XLA's heap about 1.11 (2.92 GB of stacks raised the

@@ -120,6 +120,12 @@ class TransformerConfig:
     #: times ``moe_route_scale``.
     moe_scoring: str = "softmax"
     moe_route_scale: float = 1.0
+    #: The sigmoid router's group step (DeepSeek-V3's ``noaux_tc``): the
+    #: experts in ``moe_n_group`` equal groups, each scored by the sum of
+    #: its two largest ``score + bias``, the choice within the
+    #: ``moe_topk_group`` best groups.  1 and 1: no group step.
+    moe_n_group: int = 1
+    moe_topk_group: int = 1
     #: The share of blocks of tokens that route alike which the expert
     #: layer's first, always computed chunk may fall short of
     #: (``moe.chunk_rows``): the smaller, the rarer a step that runs
@@ -142,6 +148,9 @@ class TransformerConfig:
     #: The delta layers' sizes (``models.gdn.GDNConfig``) for the
     #: ``"gdn"`` kind.
     gdn: Any = None
+    #: Kimi Delta Attention's sizes (``models.kda.KDAConfig``) for the
+    #: ``"kda"`` kind.
+    kda: Any = None
     #: Gated attention (the ``"mha"`` kind).  True: ``wq`` is twice as
     #: wide, query | gate a head, and the heads' output is multiplied
     #: elementwise by ``sigmoid(gate)`` before ``wo``.  ``"head"``: one

@@ -296,7 +296,8 @@ STEP_SCOPES = (
     "block_diffusion_loss", "flash_attention_bwd", "gated_delta_bwd",
     "ssm_proj", "ssm_conv", "ssm_scan", "ssm_out", "gmu", "diff_attn",
     "selective_scan_bwd", "mha_window",
-    "ssd_proj", "ssd_conv", "ssd_rule", "ssd_norm", "ssd_out")
+    "ssd_proj", "ssd_conv", "ssd_rule", "ssd_norm", "ssd_out",
+    "kda_proj", "kda_conv", "kda_core", "kda_out")
 #: Of those, the ones that say which RUN of layers an instruction
 #: belongs to, not which part of a layer: they own no instruction (the
 #: part's scope does), and ``device_time_by_scope(..., within=)`` keeps
@@ -308,7 +309,7 @@ RUN_SCOPES = ("mha_window",)
 KERNEL_EVENTS = ("flash_attention_fwd", "flash_attention_bwd",
                  "gated_delta_fwd", "gated_delta_bwd",
                  "selective_scan_fwd", "selective_scan_bwd",
-                 "ssd_fwd", "ssd_bwd")
+                 "ssd_fwd", "ssd_bwd", "kda_fwd", "kda_bwd")
 #: The passes of a step an instruction can belong to: the forward pass,
 #: the backward pass, and the forward that ``jax.checkpoint`` runs again
 #: inside the backward pass.

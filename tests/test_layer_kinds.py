@@ -17,6 +17,7 @@ from ray_tpu.models.common import LayerCall
 from ray_tpu.models.gdn import GDNConfig
 from ray_tpu.models.kinds import ATTENTION, FFN, run_options
 from ray_tpu.models.mamba import MambaConfig
+from ray_tpu.models.kda import KDAConfig
 from ray_tpu.models.mamba2 import Mamba2Config
 from ray_tpu.models.mla import MLAConfig
 from ray_tpu.models.transformer import TransformerConfig, init_params
@@ -41,6 +42,7 @@ RUNS = {
     "mamba2": ("mamba2", dict(mamba2=Mamba2Config(
         num_heads=4, head_dim=8, n_groups=2, state_size=8, chunk=8,
         norm_groups=2))),
+    "kda": ("kda", dict(kda=KDAConfig(num_heads=2, head_dim=16, chunk=16))),
     "dense": ("mha", {}),
     "moe": ("mha", dict(moe_experts=4, moe_top_k=2, moe_shared_width=16)),
 }
@@ -168,7 +170,7 @@ def test_the_parameter_tree_is_the_parents_leaf_for_leaf(name):
 # Who may import whom under ``models/``: a module imports only modules of
 # a lower level.
 LEVELS = {"common": 0, "remat": 0,
-          "mha": 1, "mla": 1, "gdn": 1, "mamba": 1, "mamba2": 1,
+          "mha": 1, "mla": 1, "gdn": 1, "mamba": 1, "mamba2": 1, "kda": 1,
           "diff_attention": 1,
           "moe": 1, "kinds": 2, "transformer": 3,
           "mtp": 4, "block_diffusion": 4, "pipeline": 4, "__init__": 4}

@@ -404,20 +404,20 @@ def test_every_scope_literal_is_declared_and_every_declared_scope_is_used():
     assert kernels == {*tracing.KERNEL_EVENTS, *KERNELS_UNDER_THEIR_SCOPE}
     assert not set(tracing.KERNEL_EVENTS) & set(KERNELS_UNDER_THEIR_SCOPE)
     assert set(KERNELS_UNDER_THEIR_SCOPE.values()) <= set(tracing.STEP_SCOPES)
-    assert len(set(tracing.STEP_SCOPES)) == len(tracing.STEP_SCOPES) == 36
+    assert len(set(tracing.STEP_SCOPES)) == len(tracing.STEP_SCOPES) == 40
     assert set(tracing.RUN_SCOPES) == {"mha_window"} < set(
         tracing.STEP_SCOPES)
     assert tracing.KERNEL_EVENTS == (
         "flash_attention_fwd", "flash_attention_bwd", "gated_delta_fwd",
         "gated_delta_bwd", "selective_scan_fwd", "selective_scan_bwd",
-        "ssd_fwd", "ssd_bwd")
+        "ssd_fwd", "ssd_bwd", "kda_fwd", "kda_bwd")
 
 
 def test_every_declared_scope_feeds_one_metric():
     """Each scope and kernel event is summed by exactly one of the
     benchmark's group metrics -- ``step_scopes.GROUPS``, or the list in
     the file of a metric that came after it (``ssm_layers_ms``,
-    ``mamba2_layers_ms``);
+    ``mamba2_layers_ms``, ``kda_layers_ms``);
     ``optimizer`` is left to the rest (``train_step_device_ms`` less the
     groups), and ``diff_attn`` to ``step_attributed_pct`` alone.  A
     run's scope owns no instruction and is no group's: it is what one
@@ -432,8 +432,11 @@ def test_every_declared_scope_feeds_one_metric():
     mamba2 = bench_run._reader("mamba2_layers_ms").__globals__["SCOPES"]
     assert {"ssd_proj", "ssd_conv", "ssd_rule", "ssd_norm", "ssd_out",
             "ssd_fwd", "ssd_bwd"} == set(mamba2)
+    kda = bench_run._reader("kda_layers_ms").__globals__["SCOPES"]
+    assert {"kda_proj", "kda_conv", "kda_core", "kda_out", "kda_fwd",
+            "kda_bwd"} == set(kda)
     grouped = [s for group in step_scopes.GROUPS.values() for s in group]
-    grouped += list(ssm) + list(mamba2)
+    grouped += list(ssm) + list(mamba2) + list(kda)
     assert len(grouped) == len(set(grouped))
     assert set(grouped) | {"optimizer", "diff_attn"} == \
         (set(tracing.STEP_SCOPES) - set(tracing.RUN_SCOPES)) \
@@ -476,6 +479,10 @@ MANIFESTS = {
                      ssd_conv=ALL, ssd_rule=ALL, ssd_norm=ALL, ssd_out=ALL,
                      moe_shared=ALL, moe_bias=OUTSIDE, head_loss=ONCE,
                      optimizer=OUTSIDE),
+    "ling": dict(MOE, attention=ALL, ffn=ALL, kda_proj=ALL, kda_conv=ALL,
+                 kda_core=ALL, kda_out=ALL, mla_q=ALL, mla_kv=ALL,
+                 mla_out=ALL, moe_shared=ALL, moe_bias=OUTSIDE,
+                 head_loss=ONCE, optimizer=OUTSIDE),
 }
 
 
@@ -580,7 +587,8 @@ def test_the_registry_entrys_memory_carries_the_plan(kind, monkeypatch):
                      "mha:heads=6,rope=global+moe",
                      "mha:heads=9,window=8,rope=local+moe"],
         "nemotron": ["mamba2+moe", "mamba2+moe", "mamba2+none",
-                     "mha+moe"]}[kind]
+                     "mha+moe"],
+        "ling": ["kda+dense", "kda+moe", "mla+moe"]}[kind]
     assert 0 < plan["plan_seconds"] < 5 and plan["trace_seconds"] > 0
     exposed = get_metrics_registry().render_prometheus().splitlines()
     for name in ("kept_bytes", "budget_bytes", "plan_seconds"):

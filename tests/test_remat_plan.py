@@ -19,12 +19,13 @@ import pytest
 
 from ray_tpu.models import mha, remat, transformer
 from ray_tpu.models.gdn import GDNConfig
+from ray_tpu.models.kda import KDAConfig
 from ray_tpu.models.mamba import MambaConfig
 from ray_tpu.models.mamba2 import Mamba2Config
 from ray_tpu.models.mla import MLAConfig
 from ray_tpu.models.transformer import (TransformerConfig, apply_layer,
                                         init_stack, run_stack)
-from ray_tpu.ops import gated_delta, ssd
+from ray_tpu.ops import gated_delta, kda, ssd
 from ray_tpu.ops.flash_attention import RESIDUAL_NAMES
 from tiny_steps import (FULL, ROOM, RUNS, _device,  # noqa: F401
                         _tiny_step, every_candidate_that_spares_anything)
@@ -97,6 +98,20 @@ KINDS = {
                            moe_scoring="sigmoid", moe_bias_rate=0.01),
                       {"mid_residual", "ssd_z", "ssd_xbc", "moe_scores",
                        "moe_latent", "moe_latent_out"}),
+    # (heads of 8: at 16 the three programs' ``w_qkv`` gradients, whose
+    # largest entry is 54.6, differ by up to 9.5e-6 on entries near 0.4,
+    # 1.7e-7 of the leaf's scale, a float32 rounding of sums XLA orders
+    # differently (the GDN case differs by 1.3e-5 of its scale), which the
+    # elementwise atol of 5e-6 does not scale to)
+    "kda-grouped": (("kda", "moe"),
+                    dict(layer_pattern=(("kda", "moe", 2),),
+                         kda=KDAConfig(num_heads=2, head_dim=8, chunk=16),
+                         moe_experts=8, moe_top_k=2, moe_shared_width=16,
+                         moe_scoring="sigmoid", moe_bias_rate=0.01,
+                         moe_n_group=4, moe_topk_group=2),
+                    {"mid_residual", "kda_qkv", "kda_alpha",
+                     "kda_beta_gate", "moe_scores", "moe_shared_gate",
+                     "moe_shared_up"}),
     "diff-cross": (("diff:reads=kv", "dense"),
                    dict(layer_pattern=(("diff:writes=kv", "dense", 1),
                                        ("diff:reads=kv", "dense", 2)),
@@ -279,8 +294,8 @@ def test_no_budget_is_exactly_todays_two_names(monkeypatch):
     assert remat.no_plan() == {"budget_bytes": None, "kept_bytes": 0,
                                "runs": []}
     assert remat.BASE_NAMES == RESIDUAL_NAMES + gated_delta.RESIDUAL_NAMES \
-        + ssd.RESIDUAL_NAMES
-    assert len(set(remat.BASE_NAMES)) == 6
+        + ssd.RESIDUAL_NAMES + kda.RESIDUAL_NAMES
+    assert len(set(remat.BASE_NAMES)) == 8
     assert remat.device_memory() is None
     policy = remat.policy(("mha", "dense"))
     assert not isinstance(policy, remat.Keeps)

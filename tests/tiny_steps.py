@@ -1,5 +1,5 @@
 """What the tests of the remat plan and of the program's spans share:
-the seven tiny steps, as the benchmark's drivers build them, and a stand-in
+the eight tiny steps, as the benchmark's drivers build them, and a stand-in
 for the device's memory.  A plain module: it holds no test."""
 
 import functools
@@ -35,11 +35,12 @@ RUNS = {"dense": ["mha+dense"], "block_diffusion": ["mha+moe"],
         "windowed": ["mha:heads=6,rope=global+dense",
                      "mha:heads=6,rope=global+moe",
                      "mha:heads=9,window=8,rope=local+moe"],
-        "nemotron": ["mamba2+moe", "mamba2+moe", "mamba2+none", "mha+moe"]}
+        "nemotron": ["mamba2+moe", "mamba2+moe", "mamba2+none", "mha+moe"],
+        "ling": ["kda+dense", "kda+moe", "mla+moe"]}
 
 
 def _tiny_step(kind):
-    """-> (step, state, batch) of one of the seven tiny configurations the
+    """-> (step, state, batch) of one of the eight tiny configurations the
     tests of the models build, as the benchmark's drivers build them."""
     import importlib
 
@@ -70,10 +71,10 @@ def _tiny_step(kind):
         cfg = tiny._cfg()
         over = functools.partial(mtp.loss_fn, cfg=cfg, coeff=0.3)
         batch = {"tokens": jnp.asarray(tiny._batches(3)[0])}
-    elif kind in ("sambay", "windowed", "nemotron"):
+    elif kind in ("sambay", "windowed", "nemotron", "ling"):
         tiny = importlib.import_module({
             "sambay": "test_phi4_flash", "windowed": "test_laguna",
-            "nemotron": "test_nemotron_h"}[kind])
+            "nemotron": "test_nemotron_h", "ling": "test_ling"}[kind])
         cfg = tiny._cfg()
         batch = {"tokens": jnp.asarray(tiny._batches(3)[0])}
     else:
