@@ -40,20 +40,27 @@ first row takes what its rows give), and factors rounded to bfloat16
 left ``dg`` four times as far from the recurrence as ``dq``.
 
 On a TPU the rule runs inside ``kda_fwd``: a grid over (row x head,
-blocks of four chunks in order), the chunks of a block in an unrolled
-loop (a chunk's own parts hang on no state, so they overlap the state
-line of the chunk before), ``S`` in a float32 VMEM scratch.  It reads q, k, v and ``g`` where the layer left
-them (``[B, L, H D]``: a head's 128 columns through the block index, no
-heads-first copy), ``beta`` a head's row of positions, and forms ``G``
-itself (a product with a triangle of ones at full precision): no
-float32 ``G`` reaches HBM.  It writes ``o`` into ``[B, L, H Dv]`` and the
-state entering each grid step.  The gradient is by hand
+blocks of four chunks in order), ``S`` in a float32 VMEM scratch.  A
+grid step works on tiles of two chunks side by side (128 rows, the
+MXU's width; one chunk of 64 where the row's chunk count is odd), every
+``[128, 128]`` operand block-diagonal by chunk, so that one product
+serves both chunks: ``G`` (a product with a block of triangles of
+ones at full precision: no float32 ``G`` reaches HBM), ``KK`` and ``QK``
+(block ``a``'s rows of both chunks stacked against one right factor
+whose rows carry their own chunk's anchor ``G_a``), the inverse
+(``ops/gated_delta.py``'s, on the tile), ``W`` and ``U``.  The state
+then walks the tile's chunks one by one.  It reads q, k, v and ``g``
+where the layer left them (``[B, L, H D]``: a head's 128 columns
+through the block index, no heads-first copy) and ``beta`` a head's
+row of positions, a tile a row; it writes ``o`` into ``[B, L, H Dv]``
+and the state entering each grid step.  The gradient is by hand
 (``jax.custom_vjp`` over the whole rule): ``kda_bwd`` takes the
-forward's grid with the blocks the other way round, walks a block's
-chunks forward from the state the forward wrote by the forward's own
-lines, holding each chunk's entering state in VMEM, then walks them
-backwards carrying ``dS`` in float32 and writes ``dq``, ``dk``, ``dv``,
-``dg`` (the reverse running sum of ``dG``, made inside) and ``dbeta``.
+forward's grid with the blocks the other way round, makes each tile's
+own parts once, walks the step's chunks forward from the state the
+forward wrote by the forward's own lines, holding each chunk's entering
+state in VMEM, then walks the tiles backwards carrying ``dS`` in
+float32 and writes ``dq``, ``dk``, ``dv``, ``dg`` (the reverse running
+sum of ``dG``, made inside) and ``dbeta``.
 
 Off the TPU, and under ``use_pallas=False``, the same lines are batched
 ``jnp`` differentiated by JAX (the inverse by hand) around a
@@ -78,7 +85,8 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from ray_tpu.ops.gated_delta import (_NT, _TN, _dot, _dot32, _kernel_inverse,
-                                     _to_col, _to_row, unit_lower_inverse)
+                                     _stack, _tile_rows, _to_col, _to_row,
+                                     unit_lower_inverse)
 
 CHUNK = 64
 #: The rows a decay pair is factored over: ``-LOWER * (BLOCK - 1)`` is
@@ -189,50 +197,82 @@ def _chunked_rule(q, k, v, g, beta, chunk):
 
 
 # --------------------------------------------------------------------------
-# The kernels: a chunk of 64 rows at a time, its channels along the lanes.
+# The kernels: a tile of ``R`` rows at a time, its channels along the
+# lanes.  ``R`` is two chunks side by side (128 rows, the MXU's width)
+# where a grid step holds two or more, one chunk where the row's chunk
+# count is odd (``gated_delta._tile_rows``).  Every ``[R, R]`` operand is
+# block-diagonal by chunk, so one product serves the tile's chunks.
 
-def _masks(chunk: int, dk: int):
-    rows = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 0)
-    cols = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 1)
-    tall = jax.lax.broadcasted_iota(jnp.int32, (chunk, dk), 0)
+def _masks(tile: int, chunk: int, dk: int):
+    rows = jax.lax.broadcasted_iota(jnp.int32, (tile, tile), 0)
+    cols = jax.lax.broadcasted_iota(jnp.int32, (tile, tile), 1)
+    pos = jax.lax.broadcasted_iota(jnp.int32, (tile, dk), 0)
+    lower, strict = rows >= cols, rows > cols
+    if tile > chunk:                    # chunk is a power of two
+        shift = chunk.bit_length() - 1
+        same = (rows >> shift) == (cols >> shift)
+        lower, strict, pos = same & lower, same & strict, pos & (chunk - 1)
     return types.SimpleNamespace(
-        rows=rows, cols=cols, eye=rows == cols, lower=rows >= cols,
-        strict=rows > cols, ones=jnp.where(rows >= cols, 1.0, 0.0),
+        rows=rows, cols=cols, eye=rows == cols, lower=lower, strict=strict,
+        ones=jnp.where(lower, 1.0, 0.0),
         eye_k=(jax.lax.broadcasted_iota(jnp.int32, (dk, dk), 0)
                == jax.lax.broadcasted_iota(jnp.int32, (dk, dk), 1)),
-        tall=tall, chunk=chunk)
+        pos=pos,                        # a row's place in its chunk
+        firsts=range(0, tile, chunk),   # each chunk's first row
+        starts=range(0, chunk, BLOCK),  # each block's, in a chunk
+        chunk=chunk, tile=tile)
+
+
+def _spread(rows, n):
+    """Each row [1, D] of ``rows`` over ``n`` rows, stacked."""
+    return _stack([jnp.broadcast_to(r, (n, r.shape[1])) for r in rows])
+
+
+def _blocks(x, a, m):
+    """Block ``a`` of every chunk of the tile, stacked: [R / C 16, D]."""
+    return _stack([x[c + a:c + a + BLOCK] for c in m.firsts])
+
+
+def _pairs_of(left, right, m, dot):
+    """The decayed pairs [R, R] by blocks: for each block ``a``, its rows
+    in every chunk against one right factor whose rows carry their own
+    chunk's anchor; a chunk's rows keep their own chunk's columns (the
+    caller's mask drops the others)."""
+    out = [dot(_blocks(left, a, m), r, _NT) for a, r in zip(m.starts, right)]
+    return _stack([part[i * BLOCK:(i + 1) * BLOCK]
+                   for i in range(len(m.firsts)) for part in out])
 
 
 def _local(q, k, v, g, b_row, m):
-    """A chunk's own rows in VMEM: q, k [C, Dk], v [C, Dv], g [C, Dk]
-    float32, b_row (beta) [1, C] float32; ``_chunk_local``'s lines."""
+    """A tile's own rows in VMEM: q, k [R, Dk], v [R, Dv], g [R, Dk]
+    float32, b_row (beta) [1, R] float32; ``_chunk_local``'s lines for
+    each of its chunks."""
     f32, dt, chunk = _F32, v.dtype, m.chunk
     total = _dot32(m.ones, g)                                # G
-    starts = range(0, chunk, BLOCK)
-    per_row = jnp.concatenate([jnp.broadcast_to(
-        total[a:a + 1], (BLOCK, total.shape[1])) for a in starts], axis=0)
-    lo = jnp.exp(total - per_row)
-    right = [jnp.exp(jnp.where(m.tall < a + BLOCK, total[a:a + 1] - total,
-                               -jnp.inf)) for a in starts]
+    lo = jnp.exp(total - _spread(
+        [total[a:a + 1] for a in range(0, m.tile, BLOCK)], BLOCK))
+    right = [jnp.exp(jnp.where(
+        m.pos < a + BLOCK,
+        _spread([total[c + a:c + a + 1] for c in m.firsts], chunk) - total,
+        -jnp.inf)) for a in m.starts]
     kf32, qf32 = k.astype(f32), q.astype(f32)
     lk, lq = kf32 * lo, qf32 * lo
     rk = [kf32 * r for r in right]
-    kk = jnp.concatenate([_dot32(lk[a:a + BLOCK], r, _NT)
-                          for a, r in zip(starts, rk)], axis=0)
-    qk = jnp.concatenate([_dot(lq[a:a + BLOCK].astype(dt), r.astype(dt), _NT)
-                          for a, r in zip(starts, rk)], axis=0)
-    kk, qk = jnp.where(m.strict, kk, 0.0), jnp.where(m.lower, qk, 0.0)
+    kk = jnp.where(m.strict, _pairs_of(lk, rk, m, _dot32), 0.0)
+    qk = jnp.where(m.lower, _pairs_of(lq.astype(dt),
+                                      [r.astype(dt) for r in rk], m, _dot),
+                   0.0)
     b_col = _to_col(b_row, m.eye)
     t = _kernel_inverse(b_col * kk, m.rows, m.cols, chunk)
     tb = t * b_row
     gamma = jnp.exp(total)
-    end = total[chunk - 1:chunk]                             # [1, Dk]
-    to_end = jnp.exp(end - total)
+    ends = [total[c + chunk - 1:c + chunk] for c in m.firsts]   # [1, Dk]
+    to_end = jnp.exp(_spread(ends, chunk) - total)
     ke = kf32 * gamma
     return types.SimpleNamespace(
         lo=lo, right=right, lk=lk, lq=lq, rk=rk, kk=kk, t=t, tb=tb,
         b_row=b_row, b_col=b_col, gamma=gamma, to_end=to_end, ke=ke,
-        decay=_to_col(jnp.exp(end), m.eye_k),                # [Dk, 1]
+        decay=[_to_col(jnp.exp(end), m.eye_k) for end in ends],  # [Dk, 1]
         w=_dot(tb.astype(dt), ke.astype(dt)).astype(dt),
         u=_dot(tb.astype(dt), v).astype(dt),
         p=qk.astype(dt), qg=(qf32 * gamma).astype(dt),
@@ -240,50 +280,67 @@ def _local(q, k, v, g, b_row, m):
 
 
 def _fwd_kernel(q_ref, k_ref, v_ref, g_ref, b_ref, o_ref, steps_ref, state,
-                *, step: int, chunk: int):
+                *, step: int, chunk: int, tile: int):
     # Grid (row x head, block of ``step`` chunks, in order).  q_ref,
-    # k_ref, g_ref: [step C, Dk]; v_ref, o_ref: [step C, Dv]; b_ref: [N,
-    # C] float32, a row's beta a chunk a row, resident across the head's
-    # blocks; state: [Dk, Dv] float32; steps_ref: the state entering
-    # this grid step.
-    dt = v_ref.dtype
+    # k_ref, g_ref: [step C, Dk]; v_ref, o_ref: [step C, Dv]; b_ref: [N C
+    # / R, R] float32, a row's beta a tile a row, resident across the
+    # head's blocks; state: [Dk, Dv] float32; steps_ref: the state
+    # entering this grid step.
+    f32, dt = _F32, v_ref.dtype
     blk = pl.program_id(1)
+    tiles = step * chunk // tile
 
     @pl.when(blk == 0)
     def _():
         state[...] = jnp.zeros_like(state)
 
-    m = _masks(chunk, q_ref.shape[1])
+    m = _masks(tile, chunk, q_ref.shape[1])
     steps_ref[...] = state[...]
 
-    def one(c, s):
-        at = pl.multiple_of(c * chunk, chunk)
-        q, k, v, g = (ref[pl.ds(at, chunk)]
-                      for ref in (q_ref, k_ref, v_ref, g_ref))
-        x = _local(q, k, v, g, b_ref[pl.ds(blk * step + c, 1), :], m)
-        both = _dot(jnp.concatenate([x.w, x.qg], axis=0), s.astype(dt))
-        vp = (x.u.astype(_F32) - both[:chunk]).astype(dt)
-        o_ref[pl.ds(at, chunk)] = (_dot(x.p, vp) + both[chunk:]).astype(dt)
-        return s * x.decay + _dot(x.kd, vp, _TN)
+    def one(j, s):
+        at = pl.ds(pl.multiple_of(j * tile, tile), tile)
+        x = _local(q_ref[at], k_ref[at], v_ref[at], g_ref[at],
+                   b_ref[pl.ds(blk * tiles + j, 1), :], m)
+        written, read = [], []
+        for i, c in enumerate(m.firsts):
+            rows = slice(c, c + chunk)
+            # W S and (gamma Q) S: the state is the MXU's operand once
+            both = _dot(jnp.concatenate([x.w[rows], x.qg[rows]], axis=0),
+                        s.astype(dt))
+            written.append((x.u[rows].astype(f32) - both[:chunk]).astype(dt))
+            read.append(both[chunk:])
+            s = s * x.decay[i] + _dot(x.kd[rows], written[-1], _TN)
+        o_ref[at] = (_dot(x.p, _stack(written)) + _stack(read)).astype(dt)
+        return s
 
-    state[...] = jax.lax.fori_loop(0, step, one, state[...], unroll=True)
+    state[...] = jax.lax.fori_loop(0, tiles, one, state[...], unroll=True)
 
 
-def _backward_chunk(q, k, v, do, x, s, ds, m):
-    """One chunk's cotangents from its parts ``x`` (``_local``), the
-    state entering it ``s`` and the cotangent of the one leaving it
-    ``ds`` -> (dq, dk, dv, dg [C, Dk] float32, dbeta [1, C] float32, the
-    cotangent of ``s``)."""
-    f32, dt, chunk = _F32, v.dtype, m.chunk
-    low = ds.astype(dt)
-    vp = (x.u.astype(f32) - _dot(x.w, s.astype(dt))).astype(dt)
-    dvp = (_dot(x.p, do, _TN) + _dot(x.kd, low)).astype(dt)  # [C, Dv]
-    dkd = _dot(vp, low, _NT)                                 # [C, Dk]
-    dend = jnp.sum(s * ds, axis=1, keepdims=True) * x.decay  # [Dk, 1]
-    ds = ds * x.decay + _dot(jnp.concatenate([x.qg, -x.w], axis=0),
-                             jnp.concatenate([do, dvp], axis=0), _TN)
-    by_state = _dot(jnp.concatenate([do, dvp], axis=0), s.astype(dt), _NT)
-    dqg, dw = by_state[:chunk], -by_state[chunk:]            # [C, Dk]
+def _backward_tile(q, k, v, do, x, vp, entering, ds, m):
+    """A tile's cotangents from its parts ``x`` (``_local``), its V' [R,
+    Dv], the states entering its chunks and the cotangent of the one
+    leaving it ``ds`` -> (dq, dk, dv, dg [R, Dk] float32, dbeta [1, R]
+    float32, the cotangent of the state entering it)."""
+    f32, dt, chunk, tile = _F32, v.dtype, m.chunk, m.tile
+    # the two lines that hold the state, transposed, a chunk at a time,
+    # the last first
+    from_o = _dot(x.p, do, _TN)                              # P^T dO
+    n = len(m.firsts)
+    dvp, dkd, dend, by_state = [None] * n, [None] * n, [None] * n, [None] * n
+    for i in reversed(range(n)):
+        at, low = slice(m.firsts[i], m.firsts[i] + chunk), ds.astype(dt)
+        dvp[i] = (from_o[at] + _dot(x.kd[at], low)).astype(dt)
+        dkd[i] = _dot(vp[at], low, _NT)                      # [C, Dk]
+        dend[i] = jnp.sum(entering[i] * ds, axis=1,
+                          keepdims=True) * x.decay[i]        # [Dk, 1]
+        by_o = jnp.concatenate([do[at], dvp[i]], axis=0)
+        ds = ds * x.decay[i] + _dot(
+            jnp.concatenate([x.qg[at], -x.w[at]], axis=0), by_o, _TN)
+        # dO S^T and dV' S^T: the state is the MXU's operand once
+        by_state[i] = _dot(by_o, entering[i].astype(dt), _NT)
+    dqg = _stack([b[:chunk] for b in by_state])              # [R, Dk]
+    dw = _stack([-b[chunk:] for b in by_state])
+    dvp, dkd = _stack(dvp), _stack(dkd)
     dqk = jnp.where(m.lower, _dot(do, vp, _NT), 0.0)
     dtb = _dot(dvp, v, _NT) + _dot(dw.astype(dt), x.ke.astype(dt), _NT)
     dv = _dot(x.tb.astype(dt), dvp, _TN)
@@ -293,23 +350,25 @@ def _backward_chunk(q, k, v, do, x, s, ds, m):
     da = jnp.where(m.strict, da, 0.0)
     dkk = da * x.b_col
     db_col = jnp.sum(da * x.kk, axis=1, keepdims=True)
-    # the decayed pairs, a 16-row block of rows at a time: the row
+    # the decayed pairs, block ``a`` of every chunk at a time: the row
     # factors' cotangents, the right factors' summed over the blocks
-    dlq, dlk, dk_right, dg_right, anchors = [], [], 0.0, 0.0, []
-    for i, a in enumerate(range(0, chunk, BLOCK)):
-        rows = slice(a, a + BLOCK)
-        both = _dot32(jnp.concatenate([dqk[rows], dkk[rows]], axis=0),
-                      x.rk[i])
-        dlq.append(both[:BLOCK])
-        dlk.append(both[BLOCK:])
-        drk = _dot32(jnp.concatenate([dqk[rows], dkk[rows]], axis=0),
-                     jnp.concatenate([x.lq[rows], x.lk[rows]], axis=0),
-                     _TN)                                      # [C, Dk]
-        dk_right = dk_right + drk * x.right[i]
-        by_right = drk * x.rk[i]
+    dlq, dlk, dk_right, dg_right, anchors = {}, {}, 0.0, 0.0, {}
+    for a, right, rk in zip(m.starts, x.right, x.rk):
+        grads = jnp.concatenate([_blocks(dqk, a, m), _blocks(dkk, a, m)],
+                                axis=0)                      # [2 R / C 16, R]
+        both = _dot32(grads, rk)
+        drk = _dot32(grads, jnp.concatenate(
+            [_blocks(x.lq, a, m), _blocks(x.lk, a, m)], axis=0), _TN)
+        dk_right = dk_right + drk * right
+        by_right = drk * rk
         dg_right = dg_right - by_right
-        anchors.append(jnp.sum(by_right, axis=0, keepdims=True))
-    dlq, dlk = (jnp.concatenate(parts, axis=0) for parts in (dlq, dlk))
+        for i, c in enumerate(m.firsts):
+            dlq[c + a] = both[i * BLOCK:(i + 1) * BLOCK]
+            dlk[c + a] = both[(n + i) * BLOCK:(n + i + 1) * BLOCK]
+            anchors[c + a] = jnp.sum(by_right[c:c + chunk], axis=0,
+                                     keepdims=True)
+    dlq, dlk = (_stack([d[r] for r in range(0, tile, BLOCK)])
+                for d in (dlq, dlk))
     by_left = dlq * x.lq + dlk * x.lk
     kf32, qf32 = k.astype(f32), q.astype(f32)
     dq = dlq * x.lo + dqg * x.gamma
@@ -317,15 +376,15 @@ def _backward_chunk(q, k, v, do, x, s, ds, m):
     by_end = dkd * kf32 * x.to_end
     dtotal = (by_left + dg_right + dqg * qf32 * x.gamma
               + dke * x.ke - by_end)
-    # what each block's anchor row and the chunk's last row take
-    first = (m.tall & (BLOCK - 1)) == 0
-    anchor_rows = jnp.concatenate([jnp.broadcast_to(
-        row - jnp.sum(by_left[a:a + BLOCK], axis=0, keepdims=True),
-        (BLOCK, row.shape[1])) for a, row in zip(range(0, chunk, BLOCK),
-                                                 anchors)], axis=0)
-    dtotal = dtotal + jnp.where(first, anchor_rows, 0.0) + jnp.where(
-        m.tall == chunk - 1, jnp.sum(by_end, axis=0, keepdims=True)
-        + _to_row(dend, m.eye_k), 0.0)
+    # what each block's anchor row and each chunk's last row take
+    anchor_rows = _spread([
+        anchors[r] - jnp.sum(by_left[r:r + BLOCK], axis=0, keepdims=True)
+        for r in range(0, tile, BLOCK)], BLOCK)
+    end_rows = _spread([
+        jnp.sum(by_end[c:c + chunk], axis=0, keepdims=True)
+        + _to_row(dend[i], m.eye_k) for i, c in enumerate(m.firsts)], chunk)
+    dtotal = (dtotal + jnp.where((m.pos & (BLOCK - 1)) == 0, anchor_rows, 0.0)
+              + jnp.where(m.pos == chunk - 1, end_rows, 0.0))
     dg = _dot32(m.ones, dtotal, _TN)          # the reverse running sum
     db = db_row + _to_row(db_col, m.eye)
     return dq, dk, dv, dg, db, ds
@@ -333,57 +392,60 @@ def _backward_chunk(q, k, v, do, x, s, ds, m):
 
 def _bwd_kernel(q_ref, k_ref, v_ref, g_ref, b_ref, s_ref, do_ref, dq_ref,
                 dk_ref, dv_ref, dg_ref, db_ref, dstate, walked, *, step: int,
-                chunk: int):
-    # The forward's grid with the blocks, and the chunks of a block, the
+                chunk: int, tile: int):
+    # The forward's grid with the blocks, and the tiles of a block, the
     # other way round; dstate [Dk, Dv] float32 is the cotangent of the
-    # state LEAVING the chunk at hand.  s_ref: the state entering this
+    # state LEAVING the tile at hand.  s_ref: the state entering this
     # grid step as ``kda_fwd`` wrote it; walked [step, Dk, Dv] float32:
     # each of its chunks' entering states, made here by the forward's
     # own lines.  db_ref: beta's cotangent, laid out as b_ref.
-    dt = v_ref.dtype
+    f32, dt = _F32, v_ref.dtype
     turn = pl.program_id(1)
     blk = pl.num_programs(1) - 1 - turn
+    tiles, per = step * chunk // tile, tile // chunk
 
     @pl.when(turn == 0)
     def _():
         dstate[...] = jnp.zeros_like(dstate)
 
-    m = _masks(chunk, q_ref.shape[1])
+    m = _masks(tile, chunk, q_ref.shape[1])
+    here = [slice(j * tile, (j + 1) * tile) for j in range(tiles)]
+    # each tile's own parts, once
+    local = [_local(q_ref[at], k_ref[at], v_ref[at], g_ref[at],
+                    b_ref[pl.ds(blk * tiles + j, 1), :], m)
+             for j, at in enumerate(here)]
+    # the forward's walk through the step's chunks: its lines, its
+    # casts, its order, so the states are the forward's
+    s, wrote = s_ref[...], []
+    for j, x in enumerate(local):
+        written = []
+        for i, c in enumerate(m.firsts):
+            rows = slice(c, c + chunk)
+            walked[j * per + i] = s
+            written.append((x.u[rows].astype(f32)
+                            - _dot(x.w[rows], s.astype(dt))).astype(dt))
+            if (j, i) != (tiles - 1, per - 1):
+                s = s * x.decay[i] + _dot(x.kd[rows], written[-1], _TN)
+        wrote.append(_stack(written))
+    ds = dstate[...]
+    for j in reversed(range(tiles)):
+        at = here[j]
+        dq, dk, dv, dg, db, ds = _backward_tile(
+            q_ref[at], k_ref[at], v_ref[at], do_ref[at], local[j], wrote[j],
+            [walked[j * per + i] for i in range(per)], ds, m)
+        dq_ref[at] = dq.astype(dt)
+        dk_ref[at] = dk.astype(dt)
+        dv_ref[at] = dv.astype(dt)
+        dg_ref[at] = dg
+        db_ref[pl.ds(blk * tiles + j, 1), :] = db
+    dstate[...] = ds
 
-    def parts(c):
-        at = pl.multiple_of(c * chunk, chunk)
-        q, k, v, g = (ref[pl.ds(at, chunk)]
-                      for ref in (q_ref, k_ref, v_ref, g_ref))
-        return at, q, k, v, _local(q, k, v, g,
-                                   b_ref[pl.ds(blk * step + c, 1), :], m)
 
-    def forward(c, s):
-        walked[c] = s
-        _, _, _, _, x = parts(c)
-        vp = (x.u.astype(_F32) - _dot(x.w, s.astype(dt))).astype(dt)
-        return s * x.decay + _dot(x.kd, vp, _TN)
-
-    walked[step - 1] = jax.lax.fori_loop(0, step - 1, forward, s_ref[...],
-                                         unroll=True)
-
-    def backward(i, ds):
-        c = step - 1 - i
-        at, q, k, v, x = parts(c)
-        dq, dk, dv, dg, db, ds = _backward_chunk(
-            q, k, v, do_ref[pl.ds(at, chunk)], x, walked[c], ds, m)
-        dq_ref[pl.ds(at, chunk)] = dq.astype(dt)
-        dk_ref[pl.ds(at, chunk)] = dk.astype(dt)
-        dv_ref[pl.ds(at, chunk)] = dv.astype(dt)
-        dg_ref[pl.ds(at, chunk)] = dg
-        db_ref[pl.ds(blk * step + c, 1), :] = db
-        return ds
-
-    dstate[...] = jax.lax.fori_loop(0, step, backward, dstate[...],
-                                    unroll=True)
-
-
-def _chunks_a_step(n: int) -> int:
-    return next(s for s in _CHUNKS_A_STEP if n % s == 0)
+def _layout(length: int, chunk: int):
+    """-> (chunks a row, chunks a grid step, rows a tile)."""
+    n = length // chunk
+    step = next(s for s in _CHUNKS_A_STEP if n % s == 0)
+    return n, step, _tile_rows(chunk, step)
 
 
 def _params():
@@ -391,7 +453,7 @@ def _params():
     return pltpu.CompilerParams(dimension_semantics=("parallel", "arbitrary"))
 
 
-def _specs(heads, step, chunk, dk, dv, n, reverse=False):
+def _specs(heads, step, chunk, tile, dk, dv, n, reverse=False):
     last = n // step - 1
 
     def blk(i):
@@ -403,22 +465,22 @@ def _specs(heads, step, chunk, dk, dv, n, reverse=False):
 
     return types.SimpleNamespace(
         keyed=head(dk), valued=head(dv),
-        whole=pl.BlockSpec((None, n, chunk), lambda b, i: (b, 0, 0)),
+        whole=pl.BlockSpec((None, n * chunk // tile, tile),
+                           lambda b, i: (b, 0, 0)),
         steps=pl.BlockSpec((None, None, dk, dv),
                            lambda b, i: (b, blk(i), 0, 0)))
 
 
 def _kernel_forward(q, k, v, g, beta, heads, chunk, interpret):
-    """q, k, g [B, L, H Dk], v [B, L, H Dv], beta [B H, N, C] float32 ->
-    (o [B, L, H Dv], the state entering each grid step [B H, N / step,
-    Dk, Dv] float32)."""
+    """q, k, g [B, L, H Dk], v [B, L, H Dv], beta [B H, L / R, R]
+    float32 -> (o [B, L, H Dv], the state entering each grid step [B H,
+    N / step, Dk, Dv] float32)."""
     bsz, length = v.shape[:2]
     dk, dv = q.shape[-1] // heads, v.shape[-1] // heads
-    n = length // chunk
-    step = _chunks_a_step(n)
-    s = _specs(heads, step, chunk, dk, dv, n)
+    n, step, tile = _layout(length, chunk)
+    s = _specs(heads, step, chunk, tile, dk, dv, n)
     return pl.pallas_call(
-        functools.partial(_fwd_kernel, step=step, chunk=chunk),
+        functools.partial(_fwd_kernel, step=step, chunk=chunk, tile=tile),
         grid=(bsz * heads, n // step),
         in_specs=[s.keyed, s.keyed, s.valued, s.keyed, s.whole],
         out_specs=[s.valued, s.steps],
@@ -435,11 +497,10 @@ def _kernel_backward(q, k, v, g, beta, steps, do, heads, chunk, interpret):
     its grid steps and ``o``'s cotangent -> those of q, k, v, g, beta."""
     bsz, length = v.shape[:2]
     dk, dv = q.shape[-1] // heads, v.shape[-1] // heads
-    n = length // chunk
-    step = _chunks_a_step(n)
-    s = _specs(heads, step, chunk, dk, dv, n, reverse=True)
+    n, step, tile = _layout(length, chunk)
+    s = _specs(heads, step, chunk, tile, dk, dv, n, reverse=True)
     return pl.pallas_call(
-        functools.partial(_bwd_kernel, step=step, chunk=chunk),
+        functools.partial(_bwd_kernel, step=step, chunk=chunk, tile=tile),
         grid=(bsz * heads, n // step),
         in_specs=[s.keyed, s.keyed, s.valued, s.keyed, s.whole, s.steps,
                   s.valued],
@@ -482,8 +543,9 @@ def _fused_rule(q, k, v, g, beta, chunk, interpret):
     def flat(x):                        # [B, L, H, D] -> [B, L, H D]: free
         return x.reshape(b, length, -1)
 
+    tile = _layout(length, chunk)[2]
     rows = jnp.moveaxis(beta.astype(_F32), 2, 1).reshape(
-        b * h, length // chunk, chunk)
+        b * h, length // tile, tile)
     o = _rule_kernels(flat(q), flat(k), flat(v), flat(g.astype(_F32)), rows,
                       h, chunk, interpret)
     return o.reshape(b, length, h, dv)

@@ -803,6 +803,39 @@ def test_gated_delta_gradient_compiles_for_the_chip_at_the_cell_width(
     assert writers == {"%gated_delta_fwd"}
 
 
+def test_kda_gradient_compiles_for_the_chip_at_the_cell_width(one_v5e_chip):
+    """Mosaic takes both KDA kernels at the Ling cell's KDA layers' shape
+    (2 rows x 8,192 positions, 32 heads of 128 x 128, chunks of 64,
+    bfloat16, g float32): tiles of two chunks (beta handed a 128-row
+    tile a row), VMEM, the transposed products.  What the compiled
+    program holds in HBM: no float32 state a chunk, and the state
+    entering each grid step of four chunks written by ``kda_fwd``
+    alone."""
+    from ray_tpu.ops.kda import kda_rule
+
+    def spec(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_v5e_chip)
+
+    wide = spec((2, 8192, 32, 128))
+    operands = (wide, wide, wide, spec((2, 8192, 32, 128), jnp.float32),
+                spec((2, 8192, 32), jnp.float32))
+    text = jax.jit(jax.value_and_grad(lambda *xs: jnp.sum(kda_rule(
+        *xs, use_pallas=True).astype(jnp.float32)), (0, 1, 2, 3, 4))).lower(
+            *operands).compile().as_text()
+    calls = [line for line in text.splitlines()
+             if "tpu_custom_call" in line]
+    assert sorted(re.match(r"\s*%(\w+)[.\d]* = ", line).group(1)
+                  for line in calls) == ["kda_bwd", "kda_fwd"]
+    assert " while(" not in text
+    assert "f32[64,128,128,128]" not in text
+    steps = "f32[64,32,128,128]"
+    writers = {re.match(r"\s*%(\w+)", line).group(1) for line in calls
+               if steps in line.split(" custom-call(")[0]}
+    assert writers == {"kda_fwd"}
+    (forward,) = [line for line in calls if "%kda_fwd" in line]
+    assert "f32[64,64,128]" in forward.split("operand_layout_constraints")[1]
+
+
 @pytest.mark.parametrize("how,wrap,want", [
     ("kept", _keeping_the_residuals, (1, 1)),
     ("plain", jax.checkpoint, (2, 1))])

@@ -100,12 +100,28 @@ def test_the_chunked_form_is_the_recurrence(mixed):
 
 
 def test_both_kernels_interpreted_are_the_chunked_form(mixed):
-    """Two grid steps of two chunks each way (256 positions), every
-    off-diagonal pair of 16-row blocks, a head that never forgets."""
+    """One grid step of four chunks each way (256 positions), two tiles
+    of two chunks, every off-diagonal pair of 16-row blocks, a head that
+    never forgets."""
     args, do, ref, oracle = mixed
     kernels = _vjp(_kernels, args, do)
     _close(kernels, oracle, {"*": 5e-5}, "kernels")
     _close(kernels, ref, {"*": 5e-5}, "kernels against the recurrence")
+
+
+@pytest.mark.parametrize("chunks", [8, 3, 1])
+def test_the_kernels_tiles_are_the_chunked_form_and_the_recurrence(chunks):
+    """The tile a row's chunk count gives: 8 chunks are two grid steps
+    of two 128-row tiles (the state handed from one grid step to the
+    next, each way); 3, an odd count, and 1 run a chunk of 64 rows a
+    tile.  Forward and all five gradients."""
+    args, do = inputs(5, chunks * kda.CHUNK, "mixed", dk=64, dv=64)
+    assert kda._layout(chunks * kda.CHUNK, kda.CHUNK)[2] == (
+        2 * kda.CHUNK if chunks % 2 == 0 else kda.CHUNK)
+    kernels = _vjp(_kernels, args, do)
+    _close(kernels, _vjp(_oracle, args, do), {"*": 5e-5}, "kernels")
+    _close(kernels, _vjp(recurrence, args, do), {"*": 5e-5},
+           "kernels against the recurrence")
 
 
 def test_every_channel_at_the_gates_bound_stays_finite_and_right():
